@@ -49,6 +49,7 @@ from ..trace import (
     axis_names_of,
     collective_axes,
     scan_split,
+    shard_map_names,
 )
 
 _CALL_KEYS = ("jaxpr", "call_jaxpr", "fun_jaxpr")
@@ -685,11 +686,11 @@ class JaxprWalker:
                 [_ones(len(_aval(v).shape)) for v in body.invars],
             )
             outs = []
-            for ov, names in zip(eqn.outvars, eqn.params.get("out_names")
-                                 or [None] * len(eqn.outvars)):
+            for ov, names in zip(eqn.outvars,
+                                 shard_map_names(eqn, "out_specs")):
                 nd = len(_aval(ov).shape)
                 spec = [1] * nd
-                for dim, axes in (names or {}).items():
+                for dim, axes in names.items():
                     if dim < nd:
                         div = 1
                         for a in axes:

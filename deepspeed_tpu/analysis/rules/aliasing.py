@@ -107,7 +107,7 @@ def donation_aliasing(ctx: LintContext) -> List[Finding]:
                         ),
                         where=f"{path}/{eqn.primitive.name}",
                     ))
-            if eqn.primitive.name == "pjit":
+            if eqn.primitive.name == "jit":  # an inner jax.jit call
                 for a, don in zip(eqn.invars,
                                   eqn.params.get("donated_invars") or ()):
                     if don and not isinstance(a, Literal):

@@ -6,8 +6,8 @@ activation live-set high-water mark, collective scratch. When the
 context carries an HBM budget (``tools/shardplan.py --hbm-gb``, the
 ``SHARDPLAN_HBM_GB`` env, or an explicit ``hbm_budget_bytes``), a peak
 above it is an error finding *before anything compiles* — the OOM that
-used to surface minutes into a TPU run (or as a cryptic RESOURCE_EXHAUSTED
-from the remote compile helper) becomes a one-second CPU lint.
+used to surface minutes into a TPU run (as a cryptic RESOURCE_EXHAUSTED
+from the compiler) becomes a one-second CPU lint.
 
 No budget in the context → the rule is silent: generic lints (the test
 suite's captured configs, ``shardlint --all-examples`` without flags)

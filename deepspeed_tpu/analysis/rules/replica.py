@@ -28,6 +28,7 @@ from ..trace import (
     iter_jaxprs,
     names_spec_axes,
     shard_map_manual_axes,
+    shard_map_names,
 )
 from . import register_rule
 
@@ -60,8 +61,8 @@ def replica_divergence(ctx: LintContext) -> List[Finding]:
                 continue
             where = f"{path}/shard_map"
             body = as_jaxpr(eqn.params["jaxpr"])
-            in_names = eqn.params.get("in_names") or ()
-            out_names = eqn.params.get("out_names") or ()
+            in_names = shard_map_names(eqn, "in_specs")
+            out_names = shard_map_names(eqn, "out_specs")
             manual = shard_map_manual_axes(eqn)
             for axis, size in manual.items():
                 if size <= 1:

@@ -186,6 +186,29 @@ def trace_train_step(engine):
     return closed, arg_shardings, master_pairs, out_shape, meta
 
 
+def lower_train_step(engine):
+    """The engine's jitted train step, lowered for its own state and batch
+    shapes (abstract: nothing materializes, nothing is donated). Works on
+    a live engine and on an ``abstract_init`` one; ``.compile()`` gives
+    XLA's memory accounting and the compiled text (is the kernel really a
+    ``tpu_custom_call``? is the collective there?)."""
+    from ..models.sharding import use_topology
+
+    state = engine.state
+    # the same scope train_batch traces under: the kernels read the mesh
+    # from it (a bare pallas_call cannot be partitioned by GSPMD)
+    with use_topology(engine.topology):
+        return engine._jit_train.lower(
+            jax.tree.map(_as_sds, state.params),
+            jax.tree.map(_as_sds, state.opt_state),
+            state.loss_scale,
+            jax.ShapeDtypeStruct((), jnp.int32),
+            _batch_sds(engine),
+            jax.random.PRNGKey(0),
+            None,
+        )
+
+
 def compiled_train_memory_peak(engine):
     """``(peak_bytes, memory_analysis)`` from XLA's own accounting of
     the engine's train step (peak = argument + temp + output − alias),
@@ -194,17 +217,7 @@ def compiled_train_memory_peak(engine):
     This is the ONE definition of the cross-check anchor the planner's
     peak band is measured against (tests/test_shardplan.py,
     tools/autoplan.py --check)."""
-    state = engine.state
-    lowered = engine._jit_train.lower(
-        jax.tree.map(_as_sds, state.params),
-        jax.tree.map(_as_sds, state.opt_state),
-        state.loss_scale,
-        jax.ShapeDtypeStruct((), jnp.int32),
-        _batch_sds(engine),
-        jax.random.PRNGKey(0),
-        None,
-    )
-    ma = lowered.compile().memory_analysis()
+    ma = lower_train_step(engine).compile().memory_analysis()
     if not getattr(ma, "temp_size_in_bytes", 0):
         return None, None
     peak = (

@@ -13,11 +13,7 @@ from __future__ import annotations
 from typing import Any, Dict, Iterator, List, Tuple
 
 import jax
-
-_core = jax.core
-Jaxpr = _core.Jaxpr
-ClosedJaxpr = _core.ClosedJaxpr
-Literal = _core.Literal
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 # primitives that wrap exactly one jaxpr consuming the eqn inputs 1:1
 _CALL_LIKE_KEYS = ("jaxpr", "call_jaxpr", "fun_jaxpr")
@@ -88,21 +84,38 @@ def collective_axes(eqn) -> Tuple[str, ...]:
 
 
 def shard_map_manual_axes(eqn) -> Dict[str, int]:
-    """{axis: size} the shard_map body is Manual over (mesh minus auto)."""
+    """{axis: size} the shard_map body is Manual over (the eqn's
+    ``manual_axes``, sized from its mesh)."""
     mesh = eqn.params.get("mesh")
-    auto = eqn.params.get("auto") or frozenset()
+    manual = eqn.params.get("manual_axes") or frozenset()
     if mesh is None:
         return {}
     try:
         shape = dict(mesh.shape)
     except Exception:  # noqa: BLE001 — AbstractMesh without concrete shape
         return {}
-    return {a: n for a, n in shape.items() if a not in auto}
+    return {a: n for a, n in shape.items() if a in manual}
+
+
+def shard_map_names(eqn, key: str) -> List[Dict[int, Tuple[str, ...]]]:
+    """A shard_map eqn's ``in_specs``/``out_specs`` (PartitionSpecs, one per
+    operand/result) as {dim: (axes,)} maps of the partitioned dims only."""
+    out = []
+    for spec in eqn.params.get(key) or ():
+        names = {}
+        for dim, entry in enumerate(spec):
+            if entry is None:
+                continue
+            names[dim] = (
+                tuple(entry) if isinstance(entry, (tuple, list)) else (entry,)
+            )
+        out.append(names)
+    return out
 
 
 def names_spec_axes(names_entry) -> Tuple[str, ...]:
-    """Flatten a shard_map in_names/out_names entry ({dim: (axes,)}) to
-    the set of mesh axes the value is partitioned over."""
+    """Flatten one :func:`shard_map_names` entry ({dim: (axes,)}) to the
+    set of mesh axes the value is partitioned over."""
     axes: List[str] = []
     for dim_axes in (names_entry or {}).values():
         axes.extend(str(a) for a in dim_axes)

@@ -129,9 +129,7 @@ def permute(x, axis_name: AxisName, perm, *, validate: bool = True):
     if validate:
         n = None
         try:
-            from ..utils.jax_compat import axis_size
-
-            n = int(axis_size(axis_name))
+            n = int(jax.lax.axis_size(axis_name))
         except Exception:  # noqa: BLE001 — unbound/odd axis env: lint-only
             n = None
         if n is not None:

@@ -443,9 +443,7 @@ def rs_wire_hier_local(x: jax.Array, outer: str, inner: str, n_o: int,
 
 # -------------------------------------------------------- global wrappers
 def _shard_map_full(body, topo, in_specs, out_specs):
-    from ..utils.jax_compat import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=topo.mesh,
         in_specs=in_specs,

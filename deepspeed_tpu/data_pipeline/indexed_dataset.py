@@ -26,13 +26,13 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 from typing import Optional, Sequence
 
 import numpy as np
 
 from ..utils.logging import log_dist, warning_once
+from ..utils.native_build import build_shared_lib
 
 _MAGIC = b"DSTPUIDX"
 _CSRC = os.path.join(
@@ -44,13 +44,9 @@ _LIB_FAILED = False
 
 
 def _build_lib() -> str:
-    src = os.path.abspath(os.path.join(_CSRC, "indexed_reader.cpp"))
-    out = os.path.abspath(os.path.join(_CSRC, "libdsidx.so"))
-    if os.path.exists(out) and os.path.getmtime(out) >= os.path.getmtime(src):
-        return out
-    cmd = ["g++", "-O2", "-shared", "-fPIC", src, "-o", out]
-    subprocess.run(cmd, check=True, capture_output=True)
-    return out
+    return build_shared_lib(
+        os.path.join(_CSRC, "indexed_reader.cpp"), "dsidx"
+    )
 
 
 def _lib() -> Optional[ctypes.CDLL]:

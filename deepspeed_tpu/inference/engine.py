@@ -313,8 +313,7 @@ class InferenceEngine:
         else:
             # commit to the serving device: params= may arrive as host
             # numpy arrays (e.g. exported from a training engine), and an
-            # uncommitted tree re-uploads per jitted call — on a relayed
-            # backend that is tens of seconds of transfer per generate()
+            # uncommitted tree re-uploads per jitted call
             params = jax.device_put(params, topology.devices[0])
         self.params = params
         # speculative decoding (greedy, B=1): a draft proposes, the main

@@ -35,6 +35,17 @@ def use_topology(topology: Optional[MeshTopology]):
         _local.topology = prev
 
 
+def manual_axis_names() -> set:
+    """Mesh axes that are Manual right now — non-empty only while tracing
+    inside a ``shard_map`` (the pipeline schedule, the 1-bit wire path)."""
+    am = jax.sharding.get_abstract_mesh()
+    return {
+        name
+        for name, t in zip(am.axis_names, am.axis_types)
+        if t == jax.sharding.AxisType.Manual
+    }
+
+
 def _filter_spec(spec: PartitionSpec, topo: MeshTopology) -> PartitionSpec:
     """Drop axes of size 1 so specs stay valid on degenerate meshes."""
 
@@ -59,20 +70,7 @@ def constrain(x, *spec_entries):
     if topo is None or topo.world_size == 1:
         return x
     spec = _filter_spec(PartitionSpec(*spec_entries), topo)
-    from ..utils.jax_compat import bound_axis_names, get_abstract_mesh
-
-    am = get_abstract_mesh()
-    if am is not None and not am.empty:
-        manual = {
-            name
-            for name, t in zip(am.axis_names, am.axis_types)
-            if t == jax.sharding.AxisType.Manual
-        }
-    else:
-        # legacy jax (no abstract mesh): probe the bound-axis env (legacy
-        # shard_map is always fully manual — jax_compat.shard_map refuses
-        # partial-manual there — so every bound axis is Manual)
-        manual = bound_axis_names(topo.mesh.axis_names)
+    manual = manual_axis_names()
     if manual:
         def drop(entry):
             if entry is None:
@@ -83,8 +81,8 @@ def constrain(x, *spec_entries):
             return None if entry in manual else entry
 
         spec = PartitionSpec(*(drop(e) for e in spec))
-        mesh = am if am is not None and not am.empty else topo.mesh
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+        am = jax.sharding.get_abstract_mesh()
+        return jax.lax.with_sharding_constraint(x, NamedSharding(am, spec))
     return jax.lax.with_sharding_constraint(x, NamedSharding(topo.mesh, spec))
 
 

@@ -1,18 +1,20 @@
 """ctypes bindings for the C++ async file-IO backend (csrc/aio).
 
 Parity: deepspeed/ops/aio (AsyncIOBuilder + aio_handle). Built on first use
-with g++ (no pybind11 in this image); the .so is cached next to the source.
+with g++ (no pybind11 in this image); the .so is cached next to the source,
+keyed on the source's content (utils/native_build.py).
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 from typing import Dict, Optional
 
 import numpy as np
+
+from ..utils.native_build import build_shared_lib
 
 _CSRC = os.path.join(os.path.dirname(__file__), "..", "..", "csrc", "aio")
 _LOCK = threading.Lock()
@@ -20,13 +22,9 @@ _LIB: Optional[ctypes.CDLL] = None
 
 
 def _build_lib() -> str:
-    src = os.path.abspath(os.path.join(_CSRC, "aio.cpp"))
-    out = os.path.abspath(os.path.join(_CSRC, "libdsaio.so"))
-    if os.path.exists(out) and os.path.getmtime(out) >= os.path.getmtime(src):
-        return out
-    cmd = ["g++", "-O2", "-shared", "-fPIC", "-pthread", src, "-o", out]
-    subprocess.run(cmd, check=True, capture_output=True)
-    return out
+    return build_shared_lib(
+        os.path.join(_CSRC, "aio.cpp"), "dsaio", flags=("-pthread",)
+    )
 
 
 def _lib() -> ctypes.CDLL:

@@ -300,9 +300,7 @@ def build_onebit_wire_optimizer(name, cfg, lr_schedule, topo, axes):
             upd = jax.tree.map(lambda u: (-lr * u).astype(jnp.float32), upd)
             return upd, mu2, nu2, e_w2, e_s2
 
-        from ..utils.jax_compat import shard_map
-
-        run = shard_map(
+        run = jax.shard_map(
             body,
             mesh=topo.mesh,
             in_specs=(P(ax_entry), P(), P(), P(ax_entry), P(ax_entry), P(), P()),

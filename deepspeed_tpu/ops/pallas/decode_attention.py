@@ -23,9 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...utils.jax_compat import pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
 
 LANES = 128
 NEG_INF = -1e30
@@ -83,8 +80,9 @@ def _decode_kernel(*refs, scale, block_s, has_scales=False):
     si = pl.program_id(2)
     ns = pl.num_programs(2)
     # this batch row's new-token position == its cached-token count (the
-    # cl operand is per-row [B, 1]; the grid's b axis picks the row)
-    cl = cl_ref[0, 0]
+    # cl operand is the whole per-row [B] vector in SMEM; the grid's b
+    # axis picks the row)
+    cl = cl_ref[pl.program_id(0)]
 
     @pl.when(si == 0)
     def _init():
@@ -179,11 +177,9 @@ def decode_attention_kernel(q, k_cache, v_cache, cache_len, *,
         interpret = jax.default_backend() != "tpu"
     scale = 1.0 / (hd**0.5)
     qg = q.reshape(B, KV, G, hd)
-    # per-row [B, 1] in SMEM: scalars broadcast so every row predicates
-    # on the same frontier, serving batches bring one frontier per slot
-    cl = jnp.broadcast_to(
-        jnp.asarray(cache_len, jnp.int32).reshape(-1, 1), (B, 1)
-    )
+    # per-row [B] in SMEM: scalars broadcast so every row predicates on
+    # the same frontier, serving batches bring one frontier per slot
+    cl = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32).reshape(-1), (B,))
     ns = Smax // bs
     has_scales = k_scale is not None
 
@@ -214,10 +210,10 @@ def decode_attention_kernel(q, k_cache, v_cache, cache_len, *,
             pl.BlockSpec((1, 1, bs, SL), lambda b, kv, si: (b, kv, si, 0)),
             pl.BlockSpec((1, 1, bs, SL), lambda b, kv, si: (b, kv, si, 0)),
         ]
+    # whole-array operand: a per-row (1, 1) block of a [B, 1] SMEM array
+    # is refused by the chip's compiler
     operands.append(cl)
-    in_specs.append(
-        pl.BlockSpec((1, 1), lambda b, kv, si: (b, 0), memory_space=pltpu.SMEM)
-    )
+    in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
 
     out = pl.pallas_call(
         functools.partial(
@@ -232,7 +228,7 @@ def decode_attention_kernel(q, k_cache, v_cache, cache_len, *,
             pltpu.VMEM((G, LANES), jnp.float32),
             pltpu.VMEM((G, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -312,7 +308,7 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, cache_len, page_table,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -387,8 +383,6 @@ def decode_attention(q, k_cache, v_cache, cache_len, *,
 
     from jax.sharding import PartitionSpec as P
 
-    from ...utils.jax_compat import shard_map
-
     batch_axes = tuple(a for a in ("dp", "fsdp") if topo.sizes[a] > 1)
     b_ax = batch_axes if batch_axes else None
     h_ax = "tp" if tp > 1 else None
@@ -437,7 +431,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *,
             q, kc, vc, cl, k_scale=ks, v_scale=vs, interpret=interp
         )
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=topo.mesh,
         in_specs=tuple(in_specs),

@@ -31,9 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...utils.jax_compat import pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
 
 # Measured on v5e (llama-410M, S=2048, bf16): 512x512 tiles beat 256x256
 # by 24% end-to-end train throughput (the 256 grid left the MXU ~10%
@@ -157,16 +154,22 @@ def _offs(offsets_ref):
     return offsets_ref[0, 0], offsets_ref[0, 1]
 
 
-def _tile_mask_args(seg_q_ref, seg_k_ref, slopes_ref, bias_ref=None):
+def _head_slope(slopes_ref, head):
+    """This head's ALiBi slope from the whole-array [H] SMEM operand (a
+    per-head (1,1) block of an [H,1] array is refused by the chip's
+    compiler); None when the kernel has no ALiBi."""
+    return slopes_ref[head] if slopes_ref is not None else None
+
+
+def _tile_mask_args(seg_q_ref, seg_k_ref, bias_ref=None):
     seg_q = seg_q_ref[0][:, :1] if seg_q_ref is not None else None  # [bq,1]
     seg_k = seg_k_ref[0][:1, :] if seg_k_ref is not None else None  # [1,bk]
-    slope = slopes_ref[0, 0] if slopes_ref is not None else None
     # bias stays in its storage dtype in HBM (no fp32 shadow copy of a
     # [*,*,S,S] tensor); the [bq,bk] tile upcasts in VMEM
     dense = (
         bias_ref[0, 0].astype(jnp.float32) if bias_ref is not None else None
     )
-    return seg_q, seg_k, slope, dense
+    return seg_q, seg_k, dense
 
 
 # -----------------------------------------------------------------------------
@@ -183,6 +186,7 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
     )
     o_ref, lse_ref, m_scr, l_scr, acc_scr = extra
     qoff, koff = _offs(offsets_ref)
+    slope = _head_slope(slopes_ref, pl.program_id(1))
     qi, step = pl.program_id(2), pl.program_id(3)
     nstep = pl.num_programs(3)
     if sparse:
@@ -216,9 +220,7 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # [bq, bk] fp32
-        seg_q, seg_k, slope, dense = _tile_mask_args(
-            seg_q_ref, seg_k_ref, slopes_ref, bias_ref
-        )
+        seg_q, seg_k, dense = _tile_mask_args(seg_q_ref, seg_k_ref, bias_ref)
         s = _mask_and_bias(
             s, qi, ki, block_q, block_k, causal=causal,
             seg_q=seg_q, seg_k=seg_k, slope=slope, dense=dense,
@@ -296,12 +298,8 @@ def _mask_specs(has_seg, has_alibi, block_q, block_k, *, swap_grid=False,
             )
         )
     if has_alibi:
-        specs.append(
-            pl.BlockSpec(
-                (1, 1), lambda b, h, x, y, *pf: (h, 0),
-                memory_space=pltpu.SMEM
-            )
-        )
+        # the whole [H] slopes vector, indexed by head inside the kernel
+        specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     if has_offsets:
         specs.append(
             pl.BlockSpec(
@@ -368,7 +366,7 @@ def _flash_fwd(q, k, v, bias, seg, slopes, tables, offsets=None, *, causal,
         seg_q, seg_k = _broadcast_segment_ids(seg, S)
         operands += [seg_q, seg_k]
     if has_alibi:
-        operands.append(slopes.reshape(H, 1).astype(jnp.float32))
+        operands.append(slopes.astype(jnp.float32))
     if has_offsets:
         operands.append(offsets)
     in_specs += _mask_specs(has_seg, has_alibi, block_q, block_k,
@@ -390,7 +388,7 @@ def _flash_fwd(q, k, v, bias, seg, slopes, tables, offsets=None, *, causal,
         pltpu.VMEM((block_q, LANES), jnp.float32),
         pltpu.VMEM((block_q, D), jnp.float32),
     ]
-    compiler_params = _CompilerParams(
+    compiler_params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
     )
     if sparse:
@@ -421,7 +419,7 @@ def _flash_fwd(q, k, v, bias, seg, slopes, tables, offsets=None, *, causal,
 # -----------------------------------------------------------------------------
 # backward
 # -----------------------------------------------------------------------------
-def _recompute_p_dp(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slopes_ref,
+def _recompute_p_dp(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slope,
                     bias_ref, do_ref, lse_ref, delta_ref, qi, ki, *, scale,
                     causal, block_q, block_k, qoff=0, koff=0):
     """The backward kernels' shared logit recompute: returns
@@ -436,9 +434,7 @@ def _recompute_p_dp(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slopes_ref,
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale
-    seg_q, seg_k, slope, dense = _tile_mask_args(
-        seg_q_ref, seg_k_ref, slopes_ref, bias_ref
-    )
+    seg_q, seg_k, dense = _tile_mask_args(seg_q_ref, seg_k_ref, bias_ref)
     s = _mask_and_bias(
         s, qi, ki, block_q, block_k, causal=causal,
         seg_q=seg_q, seg_k=seg_k, slope=slope, dense=dense,
@@ -468,6 +464,7 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
         do_ref, lse_ref, delta_ref, dq_ref, dq_scr = extra
         dbias_ref = None
     qoff, koff = _offs(offsets_ref)
+    slope = _head_slope(slopes_ref, pl.program_id(1))
     qi, step = pl.program_id(2), pl.program_id(3)
     nstep = pl.num_programs(3)
     if sparse:
@@ -489,7 +486,7 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
     @pl.when(should_run)
     def _body():
         p, dp, delta, do, q, k, v = _recompute_p_dp(
-            q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slopes_ref, bias_ref,
+            q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slope, bias_ref,
             do_ref, lse_ref, delta_ref, qi, ki, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, qoff=qoff, koff=koff,
         )
@@ -525,6 +522,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
     )
     do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr = extra
     qoff, koff = _offs(offsets_ref)
+    slope = _head_slope(slopes_ref, pl.program_id(1))
     ki, step = pl.program_id(2), pl.program_id(3)
     nstep = pl.num_programs(3)
     if sparse:
@@ -547,7 +545,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
     @pl.when(should_run)
     def _body():
         p, dp, delta, do, q, k, v = _recompute_p_dp(
-            q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slopes_ref, bias_ref,
+            q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slope, bias_ref,
             do_ref, lse_ref, delta_ref, qi, ki, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, qoff=qoff, koff=koff,
         )
@@ -588,8 +586,11 @@ def _bias_grad_kernel(*refs, scale, causal, block_q, block_k, has_seg,
         inner, inner_n = t % B, B          # b sweeps fastest
         if Hb == 1:
             inner, inner_n = t, B * H      # everything accumulates
+        head = t // B
     else:  # (B, 1): h sweeps fastest
         inner, inner_n = t % H, H
+        head = t % H
+    slope = _head_slope(slopes_ref, head)
 
     @pl.when(inner == 0)
     def _init():
@@ -600,7 +601,7 @@ def _bias_grad_kernel(*refs, scale, causal, block_q, block_k, has_seg,
     @pl.when(should_run)
     def _body():
         p, dp, delta, _, _, _, _ = _recompute_p_dp(
-            q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slopes_ref, bias_ref,
+            q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slope, bias_ref,
             do_ref, lse_ref, delta_ref, qi, ki, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k,
         )
@@ -649,10 +650,8 @@ def _bias_grad_call(q, k, v, bias, seg, slopes, do, lse, delta, *,
                          lambda qi, ki, t: (b_of(t), 0, ki)),
         ]
     if has_alibi:
-        operands.append(slopes.reshape(H, 1).astype(jnp.float32))
-        in_specs.append(pl.BlockSpec(
-            (1, 1), lambda qi, ki, t: (h_of(t), 0),
-            memory_space=pltpu.SMEM))
+        operands.append(slopes.astype(jnp.float32))
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     operands += [do, lse, delta]
     in_specs += [
         pl.BlockSpec((1, 1, block_q, D),
@@ -678,7 +677,7 @@ def _bias_grad_call(q, k, v, bias, seg, slopes, do, lse, delta, *,
         # output carries the bias dtype directly (no fp32 shadow + cast pass)
         out_shape=jax.ShapeDtypeStruct((Bb, Hb, S, S), bias.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, block_k), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -690,7 +689,7 @@ def _bwd_call(kernel, grid, in_specs, out_specs, out_shape, scratch_shapes,
               operands, sparse_tables, interpret):
     """Dispatch one backward pallas_call, with the scalar-prefetch grid
     spec when a compaction table drives the last grid dim."""
-    compiler_params = _CompilerParams(
+    compiler_params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
     )
     if sparse_tables is not None:
@@ -738,7 +737,7 @@ def _flash_bwd(q, k, v, out, lse, do, bias, seg, slopes, tables, offsets=None,
         seg_q, seg_k = _broadcast_segment_ids(seg, S)
         mask_operands += [seg_q, seg_k]
     if has_alibi:
-        mask_operands.append(slopes.reshape(H, 1).astype(jnp.float32))
+        mask_operands.append(slopes.astype(jnp.float32))
     if has_offsets:
         mask_operands.append(offsets)
     bias_bh = bias.shape[:2] if has_bias else None
@@ -1112,16 +1111,6 @@ def flash_attention(
             f"local heads {local_H} not a multiple of local kv {local_KV} "
             f"under tp*sp={head_div}"
         )
-    if distributed and not hasattr(jax, "shard_map"):
-        from ...utils.jax_compat import bound_axis_names
-
-        if bound_axis_names(topo.mesh.axis_names):
-            # nesting a shard_map inside a manual context makes legacy
-            # 0.4.x's SPMD partitioner hard-abort (CHECK IsManualSubgroup);
-            # the XLA impl partitions fine there
-            reasons.append(
-                "legacy jax: nested shard_map inside a manual context"
-            )
     if reasons:
         _log_fallback_once(reasons)
         if block_mask is not None:
@@ -1213,8 +1202,6 @@ def flash_attention(
     if distributed:
         from jax.sharding import PartitionSpec as P
 
-        from ...utils.jax_compat import shard_map
-
         batch_axes = tuple(a for a in ("dp", "fsdp") if topo.sizes[a] > 1)
         head_axes = tuple(
             a for a in (("tp",) if sp == 1 else ("tp", "sp"))
@@ -1224,21 +1211,13 @@ def flash_attention(
         # grads 1-bit path) some axes are already Manual: the nested
         # shard_map must use the context's abstract mesh and may only map
         # the still-Auto axes — arrays arrive already local on Manual ones
-        from ...utils.jax_compat import bound_axis_names, get_abstract_mesh
-
-        am = get_abstract_mesh()
-        if am is not None and not am.empty:
-            auto = {
-                name
-                for name, t in zip(am.axis_names, am.axis_types)
-                if t == jax.sharding.AxisType.Auto
-            }
-            in_manual = len(auto) < len(am.axis_names)
-        else:
-            # legacy jax (no abstract mesh): probe the bound-axis env
-            manual = bound_axis_names(topo.mesh.axis_names)
-            in_manual = bool(manual)
-            auto = set(topo.mesh.axis_names) - manual
+        am = jax.sharding.get_abstract_mesh()
+        auto = {
+            name
+            for name, t in zip(am.axis_names, am.axis_types)
+            if t == jax.sharding.AxisType.Auto
+        }
+        in_manual = len(auto) < len(am.axis_names)
         if in_manual:
             batch_axes = tuple(a for a in batch_axes if a in auto)
             head_axes = tuple(a for a in head_axes if a in auto)
@@ -1285,11 +1264,9 @@ def flash_attention(
         kw = {}
         if in_manual:
             kw["axis_names"] = mapped
-        out = shard_map(
+        out = jax.shard_map(
             body,
-            # legacy jax has no abstract mesh — the concrete mesh plus the
-            # axis_names→auto translation in jax_compat covers it
-            mesh=am if (in_manual and am is not None) else topo.mesh,
+            mesh=am if in_manual else topo.mesh,
             in_specs=(
                 spec_q, spec_q, spec_q,
                 bias_spec,

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .rmsnorm import BLOCK_ROWS, _interpret, _pad_rows
+from .rmsnorm import _block_rows, _interpret, _pad_rows
 
 
 def _fwd_kernel(x_ref, s_ref, b_ref, o_ref, *, eps):
@@ -63,7 +63,7 @@ def _bwd_kernel(x_ref, s_ref, g_ref, dx_ref, ds_ref, db_ref, *, eps):
 
 
 def _run_fwd(x2, scale, bias, eps):
-    block = min(x2.shape[0], BLOCK_ROWS)
+    block = _block_rows(*x2.shape)
     x2, valid_rows = _pad_rows(x2, block)
     rows, D = x2.shape
     return pl.pallas_call(
@@ -81,7 +81,7 @@ def _run_fwd(x2, scale, bias, eps):
 
 
 def _run_bwd(x2, scale, g2, eps):
-    block = min(x2.shape[0], BLOCK_ROWS)
+    block = _block_rows(*x2.shape)
     x2, valid_rows = _pad_rows(x2, block)
     g2, _ = _pad_rows(g2, block)
     rows, D = x2.shape

@@ -235,13 +235,11 @@ def _packed_matvec_sharded(x2d, w, topo):
     A bare pallas_call has no GSPMD partitioning rule, so without this
     wrapper the sharded qdata/scale operands dequantize full-width in
     XLA every decode step (measured 3x slower at 410M). Full-manual
-    shard_map over the whole mesh (runs on legacy jax 0.4.x): column
+    shard_map over the whole mesh: column
     shards emit their output slice with no collective; row (contraction)
     shards psum their partials — the same collective GSPMD would insert,
     but the HBM stream per shard is the int8/int4 bytes."""
     from jax.sharding import PartitionSpec as P
-
-    from ...utils.jax_compat import shard_map
 
     row_e, col_e = _matvec_pspec_entries(w)
     row_axes = _spec_axes(row_e)
@@ -267,7 +265,7 @@ def _packed_matvec_sharded(x2d, w, topo):
             y = jax.lax.psum(y.astype(jnp.float32), row_axes).astype(y.dtype)
         return y
 
-    run = shard_map(
+    run = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(None, row_e), qspec, sspec),
@@ -351,8 +349,6 @@ def _packed_expert_sharded(x3d, w, topo):
     :func:`_packed_matvec_sharded`."""
     from jax.sharding import PartitionSpec as P
 
-    from ...utils.jax_compat import shard_map
-
     e_entry, row_e, col_e = _expert_pspec_entries(w)
     row_axes = _spec_axes(row_e)
     mesh = topo.mesh
@@ -372,7 +368,7 @@ def _packed_expert_sharded(x3d, w, topo):
             y = jax.lax.psum(y.astype(jnp.float32), row_axes).astype(y.dtype)
         return y
 
-    run = shard_map(
+    run = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ...utils.jax_compat import axis_size as _axis_size
 
 from .flash_attention import (
     AUX_LANES,
@@ -82,7 +81,7 @@ def _ring_flash_bhsd(q, k, v, seg_q, seg_k, slopes, causal, axis, block_q,
 
 def _rf_fwd(q, k, v, seg_q, seg_k, slopes, causal, axis, block_q, block_k,
             block_q_bwd, block_k_bwd, interpret):
-    sp = _axis_size(axis)
+    sp = jax.lax.axis_size(axis)
     i = lax.axis_index(axis)
     B, H, S_loc, D = q.shape
     scale = 1.0 / (D**0.5)
@@ -120,7 +119,7 @@ def _rf_fwd(q, k, v, seg_q, seg_k, slopes, causal, axis, block_q, block_k,
 def _rf_bwd(causal, axis, block_q, block_k, block_q_bwd, block_k_bwd,
             interpret, res, do):
     q, k, v, seg_q, seg_k, slopes, out, lse = res
-    sp = _axis_size(axis)
+    sp = jax.lax.axis_size(axis)
     i = lax.axis_index(axis)
     B, H, S_loc, D = q.shape
     scale = 1.0 / (D**0.5)

@@ -17,7 +17,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-BLOCK_ROWS = 256
+# fp32 elements one row block may hold. The backward keeps about a dozen
+# block-sized fp32 values live next to the double-buffered operands, and the
+# chip's compiler refuses the kernel once they outgrow VMEM: 256 rows compile
+# at D=1024 and not at D=4096, so the row block shrinks as D grows.
+_BLOCK_ELEMS = 256 * 1024
 
 
 def _fwd_kernel(x_ref, s_ref, o_ref, *, eps):
@@ -53,6 +57,13 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _block_rows(rows: int, D: int) -> int:
+    """Rows per grid step, from the shape alone: at most ``_BLOCK_ELEMS``
+    elements, a multiple of the 16-row bf16 sublane tile, never more than
+    the array has (a block equal to the full dim is always legal)."""
+    return min(rows, max(16, _BLOCK_ELEMS // D // 16 * 16))
+
+
 def _pad_rows(a, block):
     """Zero-pad rows to a whole number of blocks: zero rows contribute zero
     to the dscale partial (g=0), so no masking is needed in-kernel."""
@@ -62,7 +73,7 @@ def _pad_rows(a, block):
 
 
 def _run_fwd(x2, scale, eps):
-    block = min(x2.shape[0], BLOCK_ROWS)
+    block = _block_rows(*x2.shape)
     x2, valid_rows = _pad_rows(x2, block)
     rows, D = x2.shape
     grid = (rows // block,)
@@ -80,7 +91,7 @@ def _run_fwd(x2, scale, eps):
 
 
 def _run_bwd(x2, scale, g2, eps):
-    block = min(x2.shape[0], BLOCK_ROWS)
+    block = _block_rows(*x2.shape)
     x2, valid_rows = _pad_rows(x2, block)
     g2, _ = _pad_rows(g2, block)
     rows, D = x2.shape

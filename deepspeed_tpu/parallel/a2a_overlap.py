@@ -41,7 +41,7 @@ tests/test_moe_a2a_overlap.py pins; for ``top_k > 2`` the per-chunk
 grouping of a token's combine terms is still shared by both paths).
 
 Everything here is a FULL-manual ``shard_map`` over the whole mesh
-(legacy jax 0.4.x safe) and every hop goes through
+and every hop goes through
 :func:`deepspeed_tpu.comm.collectives.permute`, so the shardlint R3
 ring contract is enforced at construction time and the comms logger
 sees every hop's bytes.
@@ -67,8 +67,8 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..comm import collectives
-from ..models.sharding import current_topology
-from .tensor_overlap import _in_manual_context, _row_chunks, _shard_map_full
+from ..models.sharding import current_topology, manual_axis_names
+from .tensor_overlap import _row_chunks, _shard_map_full
 
 __all__ = [
     "a2a_scope",
@@ -485,8 +485,7 @@ def moe_decode_a2a(tokens, tok_of_slot, slot_valid, slot_of_tok, w_of_tok,
     program — the tests/test_serving_moe.py oracle. GSPMD re-replicates
     the tiny [N, D] result at the boundary.
 
-    Full-manual shard_map over the whole mesh (legacy jax 0.4.x safe);
-    every hop goes through ``comm.collectives.permute`` so the shardlint
+    Full-manual shard_map over the whole mesh; every hop goes through ``comm.collectives.permute`` so the shardlint
     R3 ring contract is enforced at construction (the seeded corpus pair
     ``moe_decode_ring_malformed``/``_clean`` pins the hazard form).
     """
@@ -608,7 +607,7 @@ def moe_decode_a2a_applicable(topo, *, E: int, F: int,
         return False
     if any(topo.sizes.get(a, 1) > 1 for a in ("dp", "fsdp", "sp", "pp")):
         return False
-    if _in_manual_context(topo):
+    if manual_axis_names():
         return False
     return True
 
@@ -654,7 +653,7 @@ def moe_a2a_applicable(topo, *, B: int, S: int, E: int, F: int) -> bool:
         return False
     if topo.tp_size > 1 and F % topo.tp_size != 0:
         return False
-    if _in_manual_context(topo):
+    if manual_axis_names():
         return False
     return True
 

@@ -25,10 +25,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..utils.jax_compat import axis_size as _axis_size
 from jax.sharding import PartitionSpec as P
 
-from ..models.sharding import constrain, current_topology
+from ..models.sharding import (constrain, current_topology,
+                               manual_axis_names)
 
 _SP_MODE = "ulysses"  # process default; engines attach sp_mode to their topology
 
@@ -48,17 +48,6 @@ def get_sp_mode() -> str:
     topo = current_topology()
     mode = getattr(topo, "sp_mode", None) if topo is not None else None
     return mode or _SP_MODE
-
-
-def _in_manual_context() -> bool:
-    from ..utils.jax_compat import get_abstract_mesh
-
-    am = get_abstract_mesh()
-    return (
-        am is not None
-        and not am.empty
-        and any(t == jax.sharding.AxisType.Manual for t in am.axis_types)
-    )
 
 
 def ulysses_attention(q, k, v, *, causal=True, bias=None, segment_ids=None,
@@ -132,7 +121,7 @@ def _ring_attention_local(q, k, v, seg_q, seg_k, slopes, *, causal: bool,
     q/k/v: local blocks [B, S_loc, H|KV, hd]; positions are globalized from
     the ring index, so causal masking is exact across blocks.
     """
-    sp = _axis_size(axis)
+    sp = jax.lax.axis_size(axis)
     i = lax.axis_index(axis)
     B, Sq, H, hd = q.shape
     KV = k.shape[2]
@@ -260,9 +249,7 @@ def ring_attention(q, k, v, *, causal=True, segment_ids=None,
             sl if has_alibi else None, causal=causal, axis=axis,
         )
 
-    from ..utils.jax_compat import shard_map
-
-    run = shard_map(
+    run = jax.shard_map(
         body,
         mesh=topo.mesh,
         in_specs=(
@@ -288,7 +275,7 @@ def sp_attention(q, k, v, *, causal=True, bias=None, segment_ids=None,
     when the installed topology has sp_size > 1."""
     mode = get_sp_mode()
     if mode == "ring":
-        if bias is None and not _in_manual_context():
+        if bias is None and not manual_axis_names():
             return ring_attention(
                 q, k, v, causal=causal, segment_ids=segment_ids,
                 alibi_slopes=alibi_slopes,

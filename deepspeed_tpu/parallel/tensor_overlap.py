@@ -46,9 +46,8 @@ Variants:
   all-to-all formulation), which pins the fp32 summation order so the
   unquantized unidirectional ring is *bitwise* comparable.
 
-Every program here is a FULL-manual ``shard_map`` over the whole mesh
-(runs on legacy jax 0.4.x, where partial-manual programs are refused by
-utils/jax_compat); the rings are built through
+Every program here is a FULL-manual ``shard_map`` over the whole mesh;
+the rings are built through
 :func:`deepspeed_tpu.comm.collectives.permute`, which validates the
 permutation against the shardlint R3 ring/chain contract at construction
 time and reports hop bytes to the comms logger.
@@ -75,7 +74,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..comm import collectives
-from ..models.sharding import current_topology
+from ..models.sharding import current_topology, manual_axis_names
 
 __all__ = [
     "allgather_matmul",
@@ -114,19 +113,6 @@ def overlap_scope(cfg):
         yield
     finally:
         _local.overlap = prev
-
-
-def _in_manual_context(topo) -> bool:
-    """True while tracing inside a manual shard_map (the pipeline schedule)
-    — the decomposed matmul cannot nest there; callers fall back."""
-    from ..utils.jax_compat import bound_axis_names, get_abstract_mesh
-
-    am = get_abstract_mesh()
-    if am is not None and not am.empty:
-        return any(
-            t == jax.sharding.AxisType.Manual for t in am.axis_types
-        )
-    return bool(bound_axis_names(topo.mesh.axis_names))
 
 
 # ------------------------------------------------------------ ring plumbing
@@ -378,12 +364,8 @@ def _ref_matmul_reducescatter(x, w, axis: str, tp: int, *, quantized: bool,
 
 # ----------------------------------------------------------- public wrappers
 def _shard_map_full(body, topo, in_specs, out_specs):
-    """Full-manual shard_map over the WHOLE mesh: every axis is manual, so
-    the program runs on legacy jax 0.4.x (utils/jax_compat refuses
-    partial-manual there) and needs no abstract-mesh support."""
-    from ..utils.jax_compat import shard_map
-
-    return shard_map(
+    """Full-manual shard_map over the WHOLE mesh: every axis is manual."""
+    return jax.shard_map(
         body,
         mesh=topo.mesh,
         in_specs=in_specs,
@@ -520,7 +502,7 @@ def _active(topo):
         return None
     if topo is None or topo.tp_size <= 1:
         return None
-    if _in_manual_context(topo):
+    if manual_axis_names():
         return None  # pipeline manual shard_map: cannot nest, fall back
     return cfg
 

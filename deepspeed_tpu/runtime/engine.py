@@ -444,19 +444,10 @@ class TpuEngine:
         data_axes_live = tuple(
             a for a in ("dp", "fsdp") if topology.sizes[a] > 1
         )
-        # the wire path shard_maps ONLY the data axes; on legacy jax 0.4.x
-        # a further live axis makes that partial-manual, which its SPMD
-        # partitioner cannot compile (jax_compat.shard_map refuses it) —
-        # degrade to the numerics-only variant instead of dying
-        wire_shardable = hasattr(jax, "shard_map") or all(
-            topology.sizes[a] <= 1 or a in data_axes_live
-            for a in topology.sizes
-        )
         if (
             opt_name in ("onebitadam", "onebitlamb")
             and optimizer is None
             and data_axes_live
-            and wire_shardable
             and config.zero_config.stage <= 1
             and config.pipeline.stages <= 1
             and not getattr(model, "is_pipeline_module", False)
@@ -485,9 +476,6 @@ class TpuEngine:
                 # the numerics-only variant compresses nothing on the wire
                 why = (
                     "no >1-size data axis" if not data_axes_live
-                    else "legacy jax cannot compile the partial-manual "
-                         "wire shard_map beside other live mesh axes"
-                    if not wire_shardable
                     else "ZeRO stage > 1" if config.zero_config.stage > 1
                     else "pipeline parallelism"
                 )
@@ -622,12 +610,6 @@ class TpuEngine:
                     "zero_optimization.grad_wire: no >1-size data axis on "
                     "this mesh — nothing to compress, the full-width "
                     "reduction runs"
-                )
-            elif not wire_shardable:
-                log_dist(
-                    "zero_optimization.grad_wire: legacy jax cannot "
-                    "compile the partial-manual wire shard_map beside "
-                    "other live mesh axes; the full-width reduction runs"
                 )
             else:
                 self._wired_grad_axes = data_axes_live
@@ -1694,9 +1676,7 @@ class TpuEngine:
             loss = jax.lax.pmean(loss, axes)
             return jax.tree.map(lambda g: g[None], grads), loss
 
-        from ..utils.jax_compat import shard_map
-
-        run = shard_map(
+        run = jax.shard_map(
             local_fn,
             mesh=topo.mesh,
             in_specs=(P(), P(None, ax_entry), P(), P(), P()),
@@ -1857,9 +1837,7 @@ class TpuEngine:
             grads = jax.tree_util.tree_structure(params).unflatten(reduced)
             return grads, jax.lax.pmean(loss, axes)
 
-        from ..utils.jax_compat import shard_map
-
-        run = shard_map(
+        run = jax.shard_map(
             local_fn,
             mesh=topo.mesh,
             in_specs=(P(), P(None, ax_entry), P(), P(), P()),
@@ -2111,9 +2089,8 @@ class TpuEngine:
         Fields that already arrived staged (device arrays in the prepared
         [accum, micro, ...] layout with the right sharding — see
         :meth:`prepare_batch`) pass through untouched: no np.asarray
-        readback, no re-upload. On a relayed backend every device_put is a
-        blocking host RPC before the step can dispatch, so a steady-state
-        loop re-feeding one staged batch skips that cost entirely."""
+        readback, no re-upload: a steady-state loop re-feeding one staged
+        batch skips the upload before each dispatch entirely."""
         accum = self.config.gradient_accumulation_steps
         expect = self.config.train_batch_size
         out = {}
@@ -2448,7 +2425,7 @@ class TpuEngine:
     def train_batch_chain(self, batch=None, data_iter=None, steps: int = 1):
         """Run ``steps`` optimizer steps as ONE jitted program: a
         ``lax.scan`` over the train step, so the whole chain costs a single
-        host dispatch (and, through a network relay, a single RPC).
+        host dispatch.
 
         The reference amortizes per-step launch overhead with CUDA graphs
         and fused multi-tensor ops; on TPU the native equivalent is

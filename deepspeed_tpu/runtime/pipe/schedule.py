@@ -169,9 +169,7 @@ def pipelined_stack(
         aux_total = lax.psum(jnp.sum(auxs), "pp")  # sum over stages+ticks
         return ys, aux_total / M
 
-    from ...utils.jax_compat import shard_map
-
-    run = shard_map(
+    run = jax.shard_map(
         body,
         mesh=topo.mesh,
         in_specs=(P("pp"), P(), P(), P()),

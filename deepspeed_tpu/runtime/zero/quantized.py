@@ -137,9 +137,7 @@ def make_leaf_gather(topo, pspec: P, tpspec: P, shape: Tuple[int, ...],
         return _gather_leaf(x, _axes, _dim, _n, param_wire, grad_wire,
                             _hier)
 
-    from ...utils.jax_compat import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         _bound,
         mesh=topo.mesh,
         in_specs=in_spec,

@@ -1116,6 +1116,48 @@ class ServingEngine:
             steps += 1
         return finished
 
+    def lower_step(self):
+        """The ONE jitted step, lowered for this engine's own argument
+        shapes (abstract: nothing runs, the arena is not donated).
+        ``.compile().as_text()`` answers whether a kernel really is in the
+        served program (``tpu_custom_call``) — a config value cannot."""
+        def sds(a):
+            return jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=getattr(a, "sharding", None)
+            )
+
+        N, W = self.max_slots, self.token_budget
+
+        def vec(dt, *tail):
+            return jax.ShapeDtypeStruct((N, *tail), dt)
+
+        paged_args = ()
+        if self.paged:
+            paged_args = (vec(jnp.int32, self.pages_per_slot), vec(jnp.int32))
+            if self.tiered:
+                paged_args += (
+                    jax.tree.map(sds, self._stage_zero_np),
+                    sds(self._stage_dst_null),
+                )
+        from ..parallel.a2a_overlap import a2a_scope
+
+        traces = self.step_traces
+        try:
+            with use_topology(self.topology), self.engine._impl_ctx(), \
+                    a2a_scope(self._a2a_cfg):
+                return self._step.lower(
+                    jax.tree.map(sds, self.engine.params),
+                    jax.tree.map(sds, self._caches), sds(self._seen),
+                    vec(jnp.int32, W), vec(jnp.int32), vec(jnp.int32),
+                    *paged_args,
+                    vec(jnp.bool_), vec(jnp.bool_), vec(jnp.int32),
+                    vec(jnp.int32), vec(jnp.uint32, 2), vec(jnp.float32),
+                    vec(jnp.int32), vec(jnp.float32), vec(jnp.float32),
+                )
+        finally:
+            # lowering may re-trace; that is not a recompile of the step
+            self.step_traces = traces
+
     # --------------------------------------------------------- steptrace
     def trace_export(self, path: Optional[str] = None) -> str:
         """Write the Chrome trace-event JSON (Perfetto-loadable). Before

@@ -74,8 +74,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deepspeed_tpu.utils.jax_compat import shard_map
-
 
 def corpus_mesh() -> Mesh:
     devs = np.array(jax.devices()[:8]).reshape(4, 2)
@@ -248,7 +246,7 @@ def _grad_step(mesh, reduce_grads: bool):
             g = lax.pmean(g, "dp")
         return p - 0.1 * g  # claimed-replicated "updated params"
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P("dp"), P()),
@@ -276,7 +274,7 @@ def _pp_ring(mesh, perm):
     def body(x):
         return lax.psum(lax.ppermute(x, "dp", perm), "dp")
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=P("dp"),
@@ -426,7 +424,7 @@ def tp_overlap_malformed_ring():
                 src = (src - 1) % tp
         return out
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=topo.mesh,
         in_specs=(P(("dp",), "tp", None), P(None, "tp")),
@@ -485,7 +483,7 @@ def moe_a2a_malformed_ring():
             acc = acc + part((i - 1 - s) % ep)
         return lax.psum(acc, ("dp",))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=topo.mesh,
         in_specs=(P(("dp", "ep"), None, None), P(("dp", "ep"), None)),
@@ -555,7 +553,7 @@ def moe_decode_ring_malformed():
                 buf = lax.ppermute(buf, "ep", perm)
         return full
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=topo.mesh,
         in_specs=(P("ep", None, None),),
@@ -706,7 +704,7 @@ def hier_wire_bad_split():
         # hop 2: the inter-group reduction over dp
         return lax.psum(acc, "dp")
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=topo.mesh,
         in_specs=P(("dp", "fsdp")),

@@ -7,8 +7,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from deepspeed_tpu.utils.jax_compat import shard_map
-
 import deepspeed_tpu.comm as comm
 from deepspeed_tpu.comm import collectives as col
 from deepspeed_tpu.comm.topology import MeshTopology, ParallelDims
@@ -22,7 +20,7 @@ def test_all_reduce_matches_numpy(devices8):
     mesh = _mesh1d()
     x = jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4)
 
-    f = shard_map(
+    f = jax.shard_map(
         lambda a: col.all_reduce(a, "dp"), mesh=mesh, in_specs=P("dp"), out_specs=P("dp")
     )
     out = jax.jit(f)(x)
@@ -41,7 +39,7 @@ def test_reduce_scatter_all_gather_roundtrip(devices8):
         full = col.all_gather(shard, "dp")  # [16]
         return full.reshape(1, 16)
 
-    out = jax.jit(shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp")))(x)
+    out = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp")))(x)
     expected = np.tile(np.asarray(x).sum(axis=0, keepdims=True), (8, 1))
     np.testing.assert_allclose(np.asarray(out), expected)
 
@@ -51,7 +49,7 @@ def test_broadcast_from_src(devices8):
     x = jnp.arange(8, dtype=jnp.float32).reshape(8, 1) + 1.0
 
     out = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda a: col.broadcast(a, "dp", src=3),
             mesh=mesh,
             in_specs=P("dp"),
@@ -71,7 +69,7 @@ def test_all_to_all_transpose(devices8):
         swapped = col.all_to_all(v, "dp", split_axis=0, concat_axis=0)  # column i
         return swapped.reshape(1, 8)
 
-    out = jax.jit(shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp")))(x)
+    out = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp")))(x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x).T)
 
 
@@ -80,7 +78,7 @@ def test_send_forward_shifts(devices8):
     x = jnp.arange(8, dtype=jnp.float32).reshape(8, 1)
 
     out = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda a: col.send_forward(a, "dp", 8),
             mesh=mesh,
             in_specs=P("dp"),
@@ -97,7 +95,7 @@ def test_comm_hook_records_ops(devices8):
     col.register_comm_hook(lambda op, axis, nbytes: records.append((op, axis, nbytes)))
     x = jnp.ones((8, 4), jnp.float32)
     jax.jit(
-        shard_map(lambda a: col.all_reduce(a, "dp"), mesh=mesh, in_specs=P("dp"), out_specs=P("dp"))
+        jax.shard_map(lambda a: col.all_reduce(a, "dp"), mesh=mesh, in_specs=P("dp"), out_specs=P("dp"))
     )(x)
     assert ("all_reduce", "dp", 16) in records  # 1x4 f32 per-shard view
 
@@ -120,7 +118,7 @@ def test_permute_contract_rejects_malformed_rings(devices8):
     mesh = _mesh1d()
 
     def run(perm, **kw):
-        f = shard_map(
+        f = jax.shard_map(
             lambda a: col.permute(a, "dp", perm, **kw),
             mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
         )
@@ -152,7 +150,7 @@ def test_send_wrappers_satisfy_the_permute_contract(devices8):
     mesh = _mesh1d()
     for fn in (col.send_forward, col.send_backward):
         for wrap in (False, True):
-            f = shard_map(
+            f = jax.shard_map(
                 lambda a, _fn=fn, _w=wrap: _fn(a, "dp", 8, wrap=_w),
                 mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
             )

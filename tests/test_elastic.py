@@ -101,9 +101,7 @@ def test_round_env_reaches_workers(tmp_path):
 def test_preemption_oracle_end_to_end(tmp_path):
     """The full oracle: baseline vs twice-preempted elastic run, bitwise
     loss trajectory, preemption-save resume point, validated
-    postmortems. Self-degrades to single-worker rounds on legacy jax
-    (no multi-process CPU collectives there); ci.yml runs the
-    multi-worker resharding form."""
+    postmortems, two workers dropping to one."""
     env = {**os.environ, "PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu"}
     rc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "elastic_run.py"),

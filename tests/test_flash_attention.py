@@ -277,11 +277,6 @@ def test_flash_inside_manual_context_all_axes_manual(devices8):
     comm.destroy_process_group()
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="legacy jax can't compile the partial-manual wire shard_map "
-    "(the engine degrades to the numerics-only 1-bit variant there)",
-)
 def test_flash_under_onebit_stacked_grads(devices8):
     """1-bit wire path manualizes the dp axis; flash's nested shard_map must
     only map the still-Auto axes (r3 review repro)."""

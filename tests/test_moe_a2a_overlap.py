@@ -279,7 +279,6 @@ def test_malformed_ring_raises_at_construction(devices8):
     hand-built perm raises at trace time (the R3 contract), so no
     a2a-overlap program can ever carry a hang-shaped exchange."""
     topo = MeshTopology(dims=ParallelDims(dp=2, ep=4))
-    from deepspeed_tpu.utils.jax_compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     bad = [(0, 1), (1, 2), (2, 3), (3, 1)]
@@ -287,7 +286,7 @@ def test_malformed_ring_raises_at_construction(devices8):
     def body(v):
         return comm.collectives.permute(v, "ep", bad)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=topo.mesh, in_specs=P("ep"), out_specs=P("ep"),
         axis_names=set(topo.mesh.axis_names), check_vma=False,
     )

@@ -49,10 +49,9 @@ def test_comms_logger_records_shard_map_ops():
     logger = CommsLogger()
     x = jnp.ones((8, 4), jnp.float32)
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:4]), ("dp",))
-    f = shard_map(
+    f = jax.shard_map(
         lambda a: collectives.all_reduce(a, "dp"),
         mesh=mesh,
         in_specs=P("dp"),

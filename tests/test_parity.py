@@ -92,13 +92,11 @@ def test_missing_reduction_is_r10(devices8):
 
     from jax.sharding import Mesh, PartitionSpec as P
     import numpy as np
-    from deepspeed_tpu.utils.jax_compat import shard_map
-
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(4, 2), ("dp", "tp"))
     x = jax.ShapeDtypeStruct((8, 4), jnp.float32)
-    fa = shard_map(with_psum, mesh=mesh, in_specs=P("dp"), out_specs=P(),
+    fa = jax.shard_map(with_psum, mesh=mesh, in_specs=P("dp"), out_specs=P(),
                    axis_names={"dp", "tp"}, check_vma=False)
-    fb = shard_map(without, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
+    fb = jax.shard_map(without, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
                    axis_names={"dp", "tp"}, check_vma=False)
     pair = FormPair(
         name="unit/psum-dropped", contract="unit", form_a="a", form_b="b",

@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu
-from conftest import xfail_legacy_partial_manual
 from deepspeed_tpu.comm.topology import MeshTopology, ParallelDims
 from deepspeed_tpu.models import gpt2
 from deepspeed_tpu.models.transformer import apply_layer_stack, make_lm_batch
@@ -45,7 +44,6 @@ def test_partition_helpers():
     assert bounds[1] == 1  # the 10-weight layer alone
 
 
-@xfail_legacy_partial_manual
 def test_pipelined_stack_matches_sequential():
     model = tiny_model(num_layers=4)
     cfg = model.config
@@ -76,7 +74,6 @@ def test_pipelined_stack_matches_sequential():
     assert float(aux) == 0.0
 
 
-@xfail_legacy_partial_manual
 def test_pipelined_stack_grads_match_sequential():
     model = tiny_model(num_layers=2)
     cfg = model.config
@@ -106,7 +103,6 @@ def test_pipelined_stack_grads_match_sequential():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-4)
 
 
-@xfail_legacy_partial_manual
 @pytest.mark.parametrize("tick_chunk", [2, 3])
 def test_pipelined_stack_tick_chunk_exact(tick_chunk):
     """The 1f1b chunked-remat schedule (VERDICT r4 #6) is numerically the
@@ -138,7 +134,6 @@ def test_pipelined_stack_tick_chunk_exact(tick_chunk):
                                    rtol=1e-5, atol=1e-6)
 
 
-@xfail_legacy_partial_manual
 def test_pipelined_stack_tick_chunk_bounds_stash_growth():
     """Memory contract of the 1f1b schedule: the per-microbatch growth of
     compiled temp memory (XLA's own accounting — where grad-of-scan stashes
@@ -198,15 +193,6 @@ def make_engines():
     return piped, dense
 
 
-_OLD_JAX = tuple(map(int, jax.__version__.split(".")[:2])) < (0, 5)
-
-
-@pytest.mark.skipif(
-    _OLD_JAX,
-    reason="jaxlib 0.4.x's CPU compiler hard-aborts (SIGABRT, no Python "
-    "error) on the compiled pipeline schedule, killing the whole pytest "
-    "process and every test after it",
-)
 def test_pipeline_engine_parity_with_dense():
     piped, dense = make_engines()
     from deepspeed_tpu.runtime.pipe.engine import PipelineEngine
@@ -234,7 +220,6 @@ def test_pipeline_engine_parity_with_dense():
         np.testing.assert_allclose(a, b, rtol=5e-3, atol=5e-4)
 
 
-@xfail_legacy_partial_manual
 def test_pipelined_stack_segment_ids():
     """Packed sequences: segment mask must ride the pipeline with its mb."""
     model = tiny_model(num_layers=2)
@@ -292,7 +277,6 @@ def test_zero2_plus_pipeline_rejected():
         )
 
 
-@xfail_legacy_partial_manual
 def test_pipeline_with_flash_kernel(devices8):
     """The flash kernel nests inside the pipeline's manual shard_map (r3:
     previously crashed with a mesh mismatch on real-TPU default config)."""

@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import xfail_legacy_partial_manual
 from deepspeed_tpu.comm.topology import MeshTopology, ParallelDims
 from deepspeed_tpu.ops.attention import attention_impl, xla_attention
 from deepspeed_tpu.parallel.sequence import ring_attention
@@ -28,7 +27,6 @@ def ring_flash(q, k, v, topo, **kw):
         return ring_attention(q, k, v, topo=topo, **kw)
 
 
-@xfail_legacy_partial_manual
 @pytest.mark.parametrize("sp,causal", [(4, True), (4, False), (2, True)])
 def test_ring_flash_matches_dense(sp, causal):
     q, k, v = rand_qkv()
@@ -41,7 +39,6 @@ def test_ring_flash_matches_dense(sp, causal):
                                rtol=2e-5, atol=2e-5)
 
 
-@xfail_legacy_partial_manual
 def test_ring_flash_grads_match_dense():
     q, k, v = rand_qkv(seed=1)
     topo = MeshTopology(dims=ParallelDims(sp=4, dp=2))
@@ -61,7 +58,6 @@ def test_ring_flash_grads_match_dense():
         )
 
 
-@xfail_legacy_partial_manual
 def test_ring_flash_alibi_global_positions():
     q, k, v = rand_qkv(seed=2)
     slopes = np.geomspace(1.0, 0.125, q.shape[2]).astype(np.float32)
@@ -75,7 +71,6 @@ def test_ring_flash_alibi_global_positions():
                                rtol=2e-5, atol=2e-5)
 
 
-@xfail_legacy_partial_manual
 def test_ring_flash_segment_ids_cross_chunk():
     q, k, v = rand_qkv(seed=3)
     r = np.random.RandomState(3)
@@ -103,7 +98,6 @@ def test_small_chunks_keep_dense_ring():
                                rtol=1e-5, atol=1e-5)
 
 
-@xfail_legacy_partial_manual
 def test_ring_flash_bwd_tiles_scope():
     """Scoped bwd tile overrides reach the ring path's dq/dkv kernels:
     sp=2 gives S_loc=256, so fwd tiles pinned at 128 and bwd tiles at 256

@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu
-from conftest import xfail_legacy_partial_manual
 from deepspeed_tpu.comm.topology import MeshTopology, ParallelDims
 from deepspeed_tpu.models import llama
 from deepspeed_tpu.models.sharding import use_topology
@@ -27,7 +26,6 @@ def rand_qkv(B=2, S=32, H=4, KV=4, hd=8, seed=0):
     return q, k, v
 
 
-@xfail_legacy_partial_manual
 @pytest.mark.parametrize("kv_heads", [4, 2])
 def test_ring_attention_matches_dense(kv_heads):
     q, k, v = rand_qkv(KV=kv_heads)
@@ -49,7 +47,6 @@ def test_ring_attention_non_causal():
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-5, atol=1e-5)
 
 
-@xfail_legacy_partial_manual
 def test_ring_attention_segment_ids():
     q, k, v = rand_qkv(seed=2)
     r = np.random.RandomState(2)
@@ -78,7 +75,6 @@ def tiny_llama():
     )
 
 
-@xfail_legacy_partial_manual
 @pytest.mark.parametrize("mode", ["ulysses", "ring"])
 def test_sp_engine_parity_with_dp(mode):
     """Same data/seed: sp=4 engine loss tracks the dp-only engine loss."""
@@ -111,7 +107,6 @@ def test_sp_engine_parity_with_dp(mode):
         set_sp_mode("ulysses")
 
 
-@xfail_legacy_partial_manual
 def test_ring_attention_alibi():
     """ALiBi slopes applied from global positions inside the ring (r3: the
     ring path no longer falls back to ulysses for BLOOM-style models)."""

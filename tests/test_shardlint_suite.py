@@ -3,9 +3,8 @@ examples, and the bench legs (via the CLI — the tier-1 flow hook).
 
 conftest records every (config, model, topology) the suite constructs an
 engine from; here each unique one is rebuilt as an abstract engine
-(ShapeDtypeStruct state — no compute) and linted. Configs whose step
-cannot trace on this jax image (legacy partial-manual shard_map) are
-skipped loudly, never passed silently.
+(ShapeDtypeStruct state — no compute) and linted. Configs abstract_init
+refuses are skipped loudly, never passed silently.
 """
 
 import json
@@ -78,9 +77,6 @@ def _lint_one(name, cfg, model, topology, failures, skipped):
         return
     try:
         report = lint_engine(engine, source=name)
-    except NotImplementedError as e:  # legacy-jax shard_map trace refusal
-        skipped.append((name, str(e).splitlines()[0]))
-        return
     finally:
         engine.destroy()
     if not report.ok:
@@ -141,10 +137,9 @@ def test_captured_suite_configs_lint_clean(devices8):
         "\n".join(failures)
         + (f"\n(+{over} configs beyond the lint cap)" if over > 0 else "")
     )
-    # legacy-image skips are expected (partial-manual shard_map legs);
-    # anything else skipping deserves eyes
+    # only what abstract_init refuses may skip
     for name, why in skipped:
-        assert "shard_map" in why or "abstract_init" in why, (name, why)
+        assert "abstract_init" in why, (name, why)
 
 
 def test_cli_all_examples_clean_and_fast(devices8, tmp_path):

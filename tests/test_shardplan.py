@@ -206,8 +206,6 @@ def test_r7_flags_put_chain_and_gather_slice(devices8):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from deepspeed_tpu.analysis import lint_jaxpr
-    from deepspeed_tpu.utils.jax_compat import shard_map
-
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(4, 2), ("dp", "tp"))
     s = NamedSharding(mesh, P("dp"))
 
@@ -225,7 +223,7 @@ def test_r7_flags_put_chain_and_gather_slice(devices8):
                 full, (jax.lax.axis_index("dp"), 0, 0), (1,) + xs.shape
             )[0]
 
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
             axis_names={"dp", "tp"}, check_vma=False,
         )
@@ -247,7 +245,7 @@ def test_r7_flags_put_chain_and_gather_slice(devices8):
                 full, (nxt, 0, 0), (1,) + xs.shape
             )[0]
 
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
             axis_names={"dp", "tp"}, check_vma=False,
         )
@@ -355,11 +353,8 @@ def test_pipeline_estimator_tracks_measured_row(devices8):
     sys.path.insert(0, os.path.join(REPO, "tools"))
     import pipe_memory
 
-    try:
-        t = pipe_memory.measure(2, 4, "full", mb=2, S=128, D=64,
-                                tick_chunk=auto_chunk(2, 4))
-    except NotImplementedError as e:  # legacy-jax partial-manual refusal
-        pytest.skip(str(e).splitlines()[0])
+    t = pipe_memory.measure(2, 4, "full", mb=2, S=128, D=64,
+                            tick_chunk=auto_chunk(2, 4))
     pred = pipeline_temp_bytes(2, 4, 2, 128, 64, policy="1f1b",
                                tick_chunk=auto_chunk(2, 4))
     assert 0.5 <= pred / t <= 2.0, (pred, t)

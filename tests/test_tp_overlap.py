@@ -46,14 +46,23 @@ def test_allgather_matmul_bitwise_vs_reference(tp, devices8):
     dp = topo.dp_size
     B, S, K, N = 2 * dp, 12 * tp, 24, 8 * tp
     x, w = rand((B, S, K)), rand((K, N), seed=1)
-    dense = jnp.einsum("bsk,kn->bsn", x, w)
+    # the plain einsum, one tp column block of w at a time — the columns a
+    # shard owns. Row blocks of a dot are independent, but XLA:CPU (jaxlib
+    # 0.9) picks its dot kernel from the column count, and the N=32 and N=8
+    # kernels sum over K in different orders (both as close to float64):
+    # whole-w einsum vs the reference differs at tp=4, per block it is exact
+    n = N // tp
+    dense = jnp.concatenate(
+        [jnp.einsum("bsk,kn->bsn", x, w[:, i * n:(i + 1) * n])
+         for i in range(tp)], axis=-1,
+    )
     ref = jax.jit(
         lambda a, b: to.allgather_matmul(a, b, topo, reference=True)
     )(x, w)
     ring = jax.jit(lambda a, b: to.allgather_matmul(a, b, topo))(x, w)
-    # the pure-XLA reference path itself equals the plain einsum bitwise
-    # (row blocks of a dot are independent), and the unquantized
-    # unidirectional ring matches it bitwise — the acceptance oracle
+    # the pure-XLA reference path itself equals the plain einsum bitwise,
+    # and the unquantized unidirectional ring matches it bitwise — the
+    # acceptance oracle
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(dense))
     np.testing.assert_array_equal(np.asarray(ring), np.asarray(ref))
 
@@ -109,10 +118,14 @@ def test_uneven_chunks_change_nothing(bidirectional, devices8):
 
 def test_bidirectional_gather_still_bitwise(devices8):
     """The two-stream gather writes each row from exactly one dot — still
-    bitwise against the reference, odd and even ring sizes."""
+    bitwise against the reference, odd and even ring sizes. Two batch rows
+    per dp member: with one, the 1-row half is an M=1 dot, for which
+    XLA:CPU (jaxlib 0.9) emits a matrix-vector kernel that sums over K in
+    another order than the reference's GEMM."""
     for tp in (4, 3):
         topo = topo_for(tp)
-        x = rand((2, 3 * tp, 16), seed=6)  # 3 rows/shard → halves 2 + 1
+        # 3 rows/shard → halves 2 + 1
+        x = rand((2 * topo.dp_size, 3 * tp, 16), seed=6)
         w = rand((16, 8 * tp), seed=7)
         ref = jax.jit(
             lambda a, b: to.allgather_matmul(a, b, topo, reference=True)
@@ -121,6 +134,23 @@ def test_bidirectional_gather_still_bitwise(devices8):
             lambda a, b: to.allgather_matmul(a, b, topo, bidirectional=True)
         )(x, w)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+def test_overlap_wrapper_requests_every_mesh_axis(monkeypatch, devices8):
+    """The decomposed matmul is a FULL-manual program: the wrapper must
+    hand jax.shard_map EVERY mesh axis and switch the vma check off."""
+    seen = {}
+
+    def fake_shard_map(f, mesh, in_specs, out_specs, **kw):
+        seen.update(kw)
+        raise RuntimeError("stop after capture")
+
+    monkeypatch.setattr(jax, "shard_map", fake_shard_map)
+    topo = topo_for(4)
+    with pytest.raises(RuntimeError, match="stop after capture"):
+        to.allgather_matmul(jnp.zeros((2, 8, 16)), jnp.zeros((16, 8)), topo)
+    assert seen["axis_names"] == set(topo.mesh.axis_names)
+    assert seen["check_vma"] is False
 
 
 def test_quantized_hops(devices8):
@@ -292,7 +322,6 @@ def test_overlap_noop_outside_scope_and_inside_manual(devices8):
         assert to.current_overlap() is cfg
         assert to._active(topo) is cfg
         # inside a manual mapped context the guard must refuse
-        from deepspeed_tpu.utils.jax_compat import shard_map
         from jax.sharding import PartitionSpec as P
 
         flags = {}
@@ -302,7 +331,7 @@ def test_overlap_noop_outside_scope_and_inside_manual(devices8):
                 flags["active"] = to._active(topo)
             return a
 
-        jax.jit(shard_map(
+        jax.jit(jax.shard_map(
             body, mesh=topo.mesh, in_specs=P(("dp",)), out_specs=P("dp"),
             axis_names=set(topo.mesh.axis_names), check_vma=False,
         ))(jnp.ones((8,)))

@@ -1,0 +1,227 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed here and compiles for a v5e that is
+described, not attached. Every Pallas kernel on chip_smoke.py's two paths
+is compiled with ``interpret=False`` at the widths the smoke runs (BLOOM-560m
+for training, Mixtral-8x7B widths for serving), and the compiled text must
+hold the kernel as a ``tpu_custom_call``. Interpret-mode tests pin the
+numerics; these pin that the chip would accept the kernel at all — block
+shapes off the (8, 128) tiling, SMEM operands and VMEM budgets are only
+refused here.
+
+One file on purpose: only one process may load libtpu, and the worker that
+is handed this file keeps it. The topology is described inside a fixture,
+never at import (on-chip-measurement guide, section 2).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.models.decoding import SCALE_LANES
+from deepspeed_tpu.models.transformer import alibi_slopes
+from deepspeed_tpu.ops.pallas import (
+    decode_attention as da,
+    flash_attention as fa,
+    fused_adam,
+    layernorm as ln,
+    rmsnorm as rn,
+)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever libtpu raises here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep the cache off around these
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_chip_norms(monkeypatch):
+    """The norm kernels pick interpret mode from the backend, which is the
+    CPU here: steer them from the test, as the chip would."""
+    monkeypatch.setattr(rn, "_interpret", lambda: False)
+    monkeypatch.setattr(ln, "_interpret", lambda: False)
+
+
+def _compile(fn, one_chip, *shapes):
+    """Compile ``fn`` for the described chip and return the compiled text;
+    raises whatever the chip's compiler would raise."""
+    args = [
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes
+    ]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+
+# ----------------------------------------------------------------- flash
+@pytest.mark.parametrize(
+    "B,S,H,KV,hd,alibi,seg",
+    [
+        (1, 2048, 16, 16, 64, True, False),   # BLOOM-560m: the train phase
+        (1, 4096, 32, 8, 128, False, False),  # Mixtral/Llama-8B GQA widths
+        (2, 2048, 8, 4, 128, False, True),    # packed sequences (segment ids)
+    ],
+    ids=["bloom560m-alibi-hd64", "gqa-h32kv8-hd128-s4096", "segment-ids"],
+)
+def test_flash_fwd_bwd_compiles(one_chip, B, S, H, KV, hd, alibi, seg):
+    slopes = jnp.asarray(alibi_slopes(H), F32) if alibi else None
+
+    def loss(q, k, v, seg_ids):
+        out = fa.flash_attention(
+            q, k, v, causal=True, alibi_slopes=slopes,
+            segment_ids=seg_ids if seg else None, interpret=False,
+        )
+        return out.astype(F32).sum()
+
+    text = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+        ((B, S, H, hd), BF16), ((B, S, KV, hd), BF16),
+        ((B, S, KV, hd), BF16), ((B, S), I32),
+    )
+    # forward + dq + dk/dv kernels
+    assert text.count("tpu_custom_call") >= 3, text[:2000]
+
+
+# ---------------------------------------------------------------- decode
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_dense_decode_compiles(one_chip, int8):
+    B, Smax, H, KV, hd = 8, 2048, 32, 8, 128
+    cache_dt = I8 if int8 else BF16
+
+    def step(q, k, v, cl, ks, vs):
+        return da.decode_attention_kernel(
+            q, k, v, cl, k_scale=ks if int8 else None,
+            v_scale=vs if int8 else None, interpret=False,
+        )
+
+    text = _compile(
+        step, one_chip,
+        ((B, 1, H, hd), BF16), ((B, Smax, KV, hd), cache_dt),
+        ((B, Smax, KV, hd), cache_dt), ((B,), I32),
+        ((B, KV, Smax, SCALE_LANES), F32), ((B, KV, Smax, SCALE_LANES), F32),
+    )
+    assert "tpu_custom_call" in text, text[:2000]
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_paged_decode_compiles(one_chip, int8):
+    B, H, KV, hd, ps, pages = 8, 32, 8, 128, 16, 1025
+    per_slot = 2048 // ps
+    cache_dt = I8 if int8 else BF16
+
+    def step(q, k, v, cl, pt, ks, vs):
+        return da.paged_decode_attention_kernel(
+            q, k, v, cl, pt, k_scale=ks if int8 else None,
+            v_scale=vs if int8 else None, interpret=False,
+        )
+
+    text = _compile(
+        step, one_chip,
+        ((B, 1, H, hd), BF16), ((pages, ps, KV, hd), cache_dt),
+        ((pages, ps, KV, hd), cache_dt), ((B,), I32), ((B, per_slot), I32),
+        ((pages, KV, ps, SCALE_LANES), F32),
+        ((pages, KV, ps, SCALE_LANES), F32),
+    )
+    assert "tpu_custom_call" in text, text[:2000]
+
+
+# ----------------------------------------------------------------- norms
+@pytest.mark.parametrize("D", [1024, 4096])
+@pytest.mark.parametrize("kind", ["rmsnorm", "layernorm"])
+def test_norm_fwd_bwd_compiles(one_chip, on_chip_norms, kind, D):
+    rows = (2, 2048)  # [batch, seq] of one micro-batch
+
+    if kind == "rmsnorm":
+        def loss(x, s, b):
+            return rn.rmsnorm(x, s, 1e-5).astype(F32).sum()
+    else:
+        def loss(x, s, b):
+            return ln.layernorm(x, s, b, 1e-5).astype(F32).sum()
+
+    # value_and_grad: the value keeps the forward kernel alive
+    text = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1)), one_chip,
+        (rows + (D,), BF16), ((D,), F32), ((D,), F32),
+    )
+    assert text.count("tpu_custom_call") >= 2, text[:2000]  # fwd + bwd
+
+
+def test_norm_row_block_follows_width():
+    """The rule itself: the row block shrinks as D grows, stays on the
+    bf16 sublane tile, and never exceeds the rows there are."""
+    assert rn._block_rows(4096, 1024) == 256
+    assert rn._block_rows(4096, 4096) == 64
+    assert rn._block_rows(4096, 1 << 20) == 16
+    assert rn._block_rows(8, 1024) == 8
+    for D in (128, 1024, 4096, 8192, 14336):
+        assert rn._block_rows(1 << 20, D) % 16 == 0
+
+
+# ------------------------------------------------------------ fused adam
+def test_fused_adam_compiles(one_chip):
+    n = 1024 * 4096  # one BLOOM-560m mlp weight [1024, 4096]
+
+    step = functools.partial(
+        fused_adam._fused_adam_flat, b1=0.9, b2=0.999, eps=1e-8,
+        interpret=False,
+    )
+    text = _compile(
+        step, one_chip, ((n,), F32), ((n,), F32), ((n,), F32), ((2,), F32),
+    )
+    assert "tpu_custom_call" in text, text[:2000]
+
+
+def test_interpret_numerics_of_repaired_operands():
+    """The two repaired SMEM operands, read back through interpret mode at
+    a small size: per-head ALiBi slopes and per-row decode frontiers must
+    pick THEIR head/row out of the whole-array operand."""
+    from deepspeed_tpu.ops.attention import xla_attention
+
+    rs = np.random.RandomState(0)
+    B, S, H, hd = 2, 128, 4, 64
+    q, k, v = (jnp.asarray(rs.randn(B, S, H, hd), F32) for _ in range(3))
+    slopes = jnp.asarray(alibi_slopes(H), F32)
+    got = fa.flash_attention(q, k, v, causal=True, alibi_slopes=slopes,
+                             interpret=True)
+    want = xla_attention(q, k, v, causal=True, alibi_slopes=slopes)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+    Smax, KV = 256, 2
+    qd = jnp.asarray(rs.randn(B, 1, H, hd), F32)
+    kc, vc = (jnp.asarray(rs.randn(B, Smax, KV, hd), F32) for _ in range(2))
+    cl = jnp.asarray([5, 200], I32)  # two different frontiers
+    got = da.decode_attention_kernel(qd, kc, vc, cl, interpret=True)
+    for b in range(B):
+        n = int(cl[b]) + 1
+        kk = jnp.repeat(kc[b, :n], H // KV, axis=1)
+        vv = jnp.repeat(vc[b, :n], H // KV, axis=1)
+        s = jnp.einsum("hd,shd->hs", qd[b, 0], kk) / np.sqrt(hd)
+        want = jnp.einsum("hs,shd->hd", jax.nn.softmax(s, axis=-1), vv)
+        np.testing.assert_allclose(np.asarray(got[b, 0]), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)

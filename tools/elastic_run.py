@@ -85,10 +85,7 @@ def run_worker(args) -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:  # modern spelling; legacy 0.4.x uses the XLA flag above
-        jax.config.update("jax_num_cpu_devices", devices_per_proc)
-    except AttributeError:
-        pass
+    jax.config.update("jax_num_cpu_devices", devices_per_proc)
 
     import numpy as np
 
@@ -211,20 +208,9 @@ def run_oracle(args) -> int:
 
     workdir = os.path.abspath(args.workdir)
     os.makedirs(workdir, exist_ok=True)
-    import jax  # version probe only; workers are fresh interpreters
-
-    legacy = not hasattr(jax.config, "jax_num_cpu_devices")
-    if legacy:
-        # jax 0.4.x CPU cannot run cross-process collectives (the same
-        # pre-existing limit tests/test_multiprocess xfails on): degrade
-        # to single-worker rounds — both kills hit the lone rank, so the
-        # preemption-save path fires TWICE and the restart loop still
-        # runs; the cross-mesh resharding legs live in tests/test_ckpt.py
-        # and the full multi-worker oracle runs on CI's modern jax.
-        num_workers = 1
-        dpp = args.devices_per_proc * args.num_workers
-    else:
-        num_workers, dpp = args.num_workers, args.devices_per_proc
+    # the supervisor stays off jax: workers are fresh interpreters, and a
+    # parent that has touched jax holds the chip they need
+    num_workers, dpp = args.num_workers, args.devices_per_proc
     total_devices = dpp * num_workers
     die_mid = args.steps // 2          # inside an interval, after a commit
     die_late = args.steps - 2          # lone survivor: final preempt save
@@ -280,12 +266,10 @@ def run_oracle(args) -> int:
     )
     assert rounds == {0, 1, 2}, f"expected 3 rounds, saw {sorted(rounds)}"
     # round 1 resumes from round 0's death: multi-worker rounds restart
-    # at the last committed interval tag (die_mid sits right on one);
-    # a single-worker round 0 was preemption-SAVED one step further
+    # at the last committed interval tag (die_mid sits right on one)
     r1_start = min(e["step"] for e in elas if e["round"] == 1)
-    want_r1 = die_mid + 1 if legacy else die_mid
-    assert r1_start == want_r1, (
-        f"oracle: round 1 resumed at {r1_start}, expected {want_r1}"
+    assert r1_start == die_mid, (
+        f"oracle: round 1 resumed at {r1_start}, expected {die_mid}"
     )
     # round 1's lone survivor completes step die_late, then SIGTERMs:
     # the preemption save commits die_late+1 steps, so round 2 must
@@ -312,8 +296,6 @@ def run_oracle(args) -> int:
                 f"{rc.stderr}"
             )
     mode = (
-        "single-worker legacy-jax mode (resharding legs: tests/test_ckpt.py)"
-        if legacy else
         f"resumed rounds resharded {num_workers}x{dpp}dev -> "
         f"1x{total_devices}dev at constant dp={total_devices}"
     )
