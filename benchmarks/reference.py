@@ -6,8 +6,11 @@ A family is ``benchmarks/families/<family>.py``, named by a configuration
 file's ``family`` key. It exports ``shape_of(config) -> flops.Shape`` and the
 plain reference of what the configuration computes: ``loss(params, ids,
 shape, device=None)`` for training cells, ``logits(params, ids, shape,
-device=None, last=None, with_margin=False)`` for serving cells. A new family
-is a new file; nothing here names one.
+device=None, last=None, with_margin=False, ...)`` for serving cells, with
+``FAULTS``, the names of the ways the reference can be broken on purpose,
+and ``faulted(params, fault, shape, device)``, which turns a name into the
+keywords ``logits`` is then handed; ``run.py --inject`` and the tests use
+them to show that the serving comparison refuses each. A new family is a new file; nothing here names one.
 
 A reference is written from the published description in ``jax.numpy``
 float32: no kernel, no cache, no batching, no sharding, and no import from
@@ -87,11 +90,12 @@ def causal_attention(q, k, v, bias_fn=None, block: int = 512):
     return jnp.concatenate(out, axis=0)
 
 
-def rope(x, theta):
-    """x [S,H,hd], positions 0..S-1; rotate-half pairing (i, i + hd/2)."""
+def rope(x, theta, first: int = 0):
+    """x [S,H,hd], positions first..first+S-1; rotate-half pairing
+    (i, i + hd/2)."""
     S, _, hd = x.shape
     inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=F32) / hd))
-    ang = jnp.arange(S, dtype=F32)[:, None] * inv[None, :]
+    ang = jnp.arange(first, first + S, dtype=F32)[:, None] * inv[None, :]
     cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
     x1, x2 = x[..., : hd // 2], x[..., hd // 2:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
@@ -119,18 +123,58 @@ def served_token_gaps(logits, tokens) -> np.ndarray:
 
 def judge_served(gaps, margins, cc: dict):
     """The serving comparison: ``gaps`` and ``margins`` per served token,
-    ``cc`` the mix's ``correctness`` block. A token is judged where the
-    reference's routing margin is at least ``min_margin``. Returns (the
-    faults found, worst judged gap, number judged); no fault = correct."""
-    gaps, margins = np.asarray(gaps), np.asarray(margins)
-    judged = margins >= float(cc["min_margin"])
-    n = int(judged.sum())
-    worst = float(gaps[judged].max()) if n else float("inf")
+    ``cc`` a block of limits from the mix's ``correctness``. Each part is a
+    count against its limit, and is judged where ``cc`` gives the limit:
+
+    * share (``min_near_share``): of ALL served tokens, near-ties in the
+      routing included, at least that share lie within ``logit_tol`` of the
+      reference's maximum logit. Catches what moves most tokens; needs no
+      margin.
+    * outlier (``outlier_tol``): a token clear of a near-tie (the
+      reference's routing margin at least ``min_margin``) lies within it.
+      Catches a fault confined to a few tokens, which the share rule lets
+      through. With it, enough: at least ``min_judged`` tokens are clear.
+    * exact (``min_argmax_share``): at least that share are exactly the
+      reference's argmax. Over the many tokens of the precision sample it
+      tells the served precision from the one below it, which the two
+      other parts, over 48 tokens, cannot.
+
+    Returns (the faults found, the counts); no fault = correct."""
+    gaps, margins = np.asarray(gaps, float), np.asarray(margins, float)
+    n = dict(tokens=len(gaps), argmax=int((gaps == 0).sum()))
     faults = []
-    if n and worst > float(cc["logit_tol"]):
-        faults.append("a served token is not a near-argmax of the reference")
-    if n < int(cc["min_judged"]):
-        faults.append(f"only {n} served tokens could be judged")
-    if 1.0 - n / max(len(gaps), 1) > float(cc["max_unjudged_share"]):
-        faults.append("too many served tokens were set aside")
-    return faults, worst, n
+    if "min_argmax_share" in cc and (
+            n["argmax"] < float(cc["min_argmax_share"]) * n["tokens"]):
+        faults.append("too few served tokens are the reference's argmax")
+    if "min_near_share" in cc:
+        n["near"] = int((gaps <= float(cc["logit_tol"])).sum())
+        if n["near"] < float(cc["min_near_share"]) * n["tokens"]:
+            faults.append("too few served tokens are near-argmaxes of the "
+                          "reference")
+    if "outlier_tol" in cc:
+        clear = margins >= float(cc["min_margin"])
+        n.update(clear=int(clear.sum()),
+                 outliers=int((gaps[clear] > float(cc["outlier_tol"])).sum()),
+                 worst_clear=float(gaps[clear].max()) if clear.any() else 0.0)
+        if n["outliers"]:
+            faults.append("a served token clear of a near-tie is far from "
+                          "the reference's argmax")
+        if n["clear"] < int(cc["min_judged"]):
+            faults.append(f"only {n['clear']} served tokens are clear of a "
+                          "near-tie")
+    return faults, n
+
+
+def swap_one_token(tokens, margins, min_margin: float, clear: bool, vocab: int):
+    """A fault in one token (``--inject token_clear`` / ``token_tied``): the
+    first served token whose position is clear of a near-tie (or, with
+    ``clear`` false, inside one) replaced by its successor in the
+    vocabulary, as unrelated to the context as a token read from the wrong
+    row. Only the judged token changes, not the context after it: a program
+    that emits one wrong token goes on from that token, so the rest of its
+    answer still follows its context."""
+    tokens = np.array(tokens)
+    at = np.flatnonzero((np.asarray(margins) >= min_margin) == clear)
+    if len(at):
+        tokens[at[0]] = (tokens[at[0]] + 1) % vocab
+    return tokens

@@ -9,8 +9,10 @@ configuration (``benchmarks/configs/<config>.json``) and its family
 (``benchmarks/traffic/<traffic>.json``) and per-layer readers
 (``benchmarks/layer_metrics/<metric>.py``) by name, builds the engine
 through the program's own entry points, warms up exactly the cell's shapes,
-checks correctness against ``reference.py`` outside the window, measures for
-``--seconds`` and prints ONE JSON object as the last line of stdout.
+checks correctness against ``reference.py`` outside the window (a serving
+cell: a small sample in set-up, and after the window the mix's precision
+sample), measures for ``--seconds`` and prints ONE JSON object as the last
+line of stdout.
 Everything else it says goes to earlier lines. A new cell is new data files
 and a manifest entry; nothing in this file names a cell, a configuration, a
 family, a mix or a metric.
@@ -24,6 +26,17 @@ it exits non-zero and prints no result.
                       rehearsal and carries no metric
     --sweep 1,2,3     (open-loop cells) one engine, one window per rate, to
                       find the knee; prints a table, no metric line
+    --check-seeds a,b (serving cells) set-up and both correctness checks
+                      only, one engine, each seed with its own weights and
+                      prompts; prints the checks' lines and one ``correct`` a
+                      seed, no window and no metric line; exits 1 if any was
+                      incorrect
+    --inject x,y      (with --check-seeds only) judge each seed again with the
+                      reference broken on purpose: a name from the family's
+                      FAULTS, or token_clear / token_tied (one served token a
+                      request replaced, at a position clear of / inside a
+                      near-tie in the routing). Each has to read incorrect,
+                      but token_tied, the rule's known limit
 """
 
 from __future__ import annotations
@@ -36,6 +49,7 @@ import argparse  # noqa: E402
 import importlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import statistics  # noqa: E402
@@ -46,6 +60,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 OUT_DIR = os.path.join(ROOT, ".bench_out")  # git-ignored; traces land here
 EXIT_NO_DEVICE = 3
+# --inject names that change one served token and not the reference
+TOKEN_FAULTS = ("token_clear", "token_tied")
 
 
 def say(msg: str) -> None:
@@ -351,39 +367,54 @@ def make_submit(srv, Request):
     return submit
 
 
-def check_served(ctx, srv, submit, model) -> bool:
-    """Correctness 2: serve the mix's sample requests (which also warms the
-    one slot-step program), then teacher-force prompt + answer through the
-    plain reference. Every served token has to be a near-argmax of the
-    reference's logits at its position (within ``logit_tol``).
-
-    One kind of position is not judged: where the reference's own router
-    chose between its last expert in and its first expert out by less than
-    ``min_margin`` of probability. The program routes from bf16 activations,
-    the reference from float32; inside that margin the two may pick another
-    expert, and with random weights one other expert moves that position's
-    logits by about their own spread. No more than ``max_unjudged_share`` of
-    the served tokens may be set aside so, and at least ``min_judged`` have
-    to be judged (``reference.judge_served``)."""
+def serve_sample(ctx, srv, submit, model, cc: dict, stream: int = 8):
+    """Serve the sample requests of ``cc``, a block of the mix's
+    ``correctness`` (the first one also warms the one slot-step program):
+    the same requests a cell, greedy, prompts from the seed."""
     import numpy as np
 
-    from benchmarks import loadgen, reference
+    from benchmarks import loadgen
 
-    cc = ctx.mix["correctness"]
-    rng = loadgen.rng_for(ctx.seed, 8)
+    rng = loadgen.rng_for(ctx.seed, stream)
     specs = [loadgen.RequestSpec(
-        f"check{i}", 0.0, rng.integers(0, model.config.vocab_size, size=int(n),
-                                       dtype=np.int32), int(cc["new_tokens"]))
-        for i, n in enumerate(cc["prompts"])]
+        f"check{stream}.{i}", 0.0, rng.integers(
+            0, model.config.vocab_size, size=int(n), dtype=np.int32),
+        int(cc["new_tokens"])) for i, n in enumerate(cc["prompts"])]
     t0 = time.monotonic()
     states = [submit(s) for s in specs]
     srv.step()
-    say(f"serve: first slot step {time.monotonic() - t0:.1f} s (compile or "
-        "cache load included)")
+    say(f"serve: {len(specs)} sample requests, first slot step "
+        f"{time.monotonic() - t0:.1f} s (compile or cache load included)")
     srv.run_until_idle()
+    say(f"serve: sample served in {time.monotonic() - t0:.1f} s")
+    return specs, states
+
+
+def judge_sample(ctx, srv, model, specs, states, cc: dict, inject=None):
+    """Teacher-force prompt + answer of served sample requests through the
+    plain reference, one pass a request, and judge every served token by
+    ``reference.judge_served`` against the limits ``cc`` gives. For the
+    sample of set-up: the share of all tokens that are near-argmaxes of the
+    reference's logits, and no token far from the argmax where the
+    reference's routing is clear of a near-tie (the program routes from
+    bf16 activations, the reference from float32; inside a small margin the
+    two may pick another expert, and with random weights one other expert
+    moves that position's logits by about their own spread). For the
+    precision sample, after the window: the share that are exactly the
+    argmax. ``inject`` (developer only, never in a measured run) hands the
+    reference something other than what the program served. Returns
+    (correct, the comparison's counts)."""
+    import numpy as np
+
+    from benchmarks import reference
+
     ok = True
     gaps, margins = [], []
     t0 = time.monotonic()
+    handed = dict(params=srv.engine.params)
+    if inject in ctx.family_faults:
+        handed = ctx.family.faulted(srv.engine.params, inject, ctx.shape,
+                                    ctx.devices[0])
     for spec, st in zip(specs, states):
         if len(st.tokens) != spec.new_tokens:
             say(f"INCORRECT: {spec.rid} produced {len(st.tokens)} of "
@@ -394,27 +425,88 @@ def check_served(ctx, srv, submit, model) -> bool:
         n = spec.new_tokens
         # logits at positions P-1 .. P+n-2 predict the n served tokens
         logits, margin = ctx.family.logits(
-            srv.engine.params, ids[:-1], ctx.shape, device=ctx.devices[0],
-            last=n, with_margin=True)
-        gaps.append(reference.served_token_gaps(logits, st.tokens))
+            ids=ids[:-1], shape=ctx.shape, device=ctx.devices[0], last=n,
+            with_margin=True, **handed)
+        tokens = st.tokens
+        if inject in TOKEN_FAULTS and "min_margin" in cc:
+            tokens = reference.swap_one_token(
+                tokens, margin, float(cc["min_margin"]),
+                inject == "token_clear", model.config.vocab_size)
+        gaps.append(reference.served_token_gaps(logits, tokens))
         margins.append(np.asarray(margin))
     if not gaps:
-        return False
+        return False, {}
     gaps, margins = np.concatenate(gaps), np.concatenate(margins)
-    faults, worst, judged = reference.judge_served(gaps, margins, cc)
-    say(f"serve: {len(gaps)} served tokens, {judged} judged (at least "
-        f"{cc['min_judged']}): within {worst:.4f} of the reference's "
-        f"max logit (tolerance {cc['logit_tol']}); "
-        f"{1 - judged / len(gaps):.0%} set aside for a routing margin under "
-        f"{cc['min_margin']} (at most "
-        f"{cc['max_unjudged_share']:.0%}); reference took "
+    faults, n = reference.judge_served(gaps, margins, cc)
+    parts = []
+    if "min_argmax_share" in cc:
+        parts.append(
+            f"exact rule: at least {cc['min_argmax_share']:.1%} = "
+            f"{math.ceil(cc['min_argmax_share'] * n['tokens'])}")
+    if "min_near_share" in cc:
+        parts.append(
+            f"share rule: {n['near']} within {cc['logit_tol']} of its max "
+            f"logit (at least {cc['min_near_share']:.0%} = "
+            f"{math.ceil(cc['min_near_share'] * n['tokens'])})")
+    if "outlier_tol" in cc:
+        parts.append(
+            f"outlier rule: {n['clear']} clear of a near-tie (routing margin "
+            f"at least {cc['min_margin']}; at least {cc['min_judged']}), the "
+            f"worst of them {n['worst_clear']:.4f} from the max logit, "
+            f"{n['outliers']} over {cc['outlier_tol']} (at most 0)")
+    say(f"serve: {n['tokens']} served tokens, {n['argmax']} the reference's "
+        f"argmax; {'; '.join(parts)}; reference took "
         f"{time.monotonic() - t0:.1f} s")
     say("serve: (gap, margin) per served token: " + " ".join(
         f"({g:.3f},{m:.3f})" for g, m in zip(gaps, margins)))
-    ctx.counters["logit_gap"] = worst
     for fault in faults:
         say(f"INCORRECT: {fault}")
-    return ok and not faults
+    return ok and not faults, n
+
+
+def check_precision(ctx, srv, submit, model, injects=(None,)):
+    """Correctness 3, after the window (or alone under ``--check-seeds``):
+    the mix's precision sample, if it has one: many tokens from as many
+    requests as the cell keeps in flight, served by the same slot-step
+    program, so nothing compiles and set-up does not grow; judged by the
+    exact rule. Returns [(correct, counts)] for each of ``injects``."""
+    pc = ctx.mix["correctness"].get("precision")
+    if not pc:
+        return [(True, {})] * len(injects)
+    srv.run_until_idle()  # what the window left in flight
+    sample = serve_sample(ctx, srv, submit, model, pc, stream=9)
+    return [judge_sample(ctx, srv, model, *sample, pc, inject)
+            for inject in injects]
+
+
+def check_seeds(ctx, srv, submit, model, dtype) -> dict:
+    """``--check-seeds``: set-up and the checks only, one engine, for each
+    seed its own weights and prompts as a run with that seed draws them; no
+    window. With ``--inject`` every seed is judged once as served and once
+    for each fault named."""
+    cc = ctx.mix["correctness"]
+    injects = [None, *ctx.inject]
+    rows = []
+    for i, seed in enumerate(ctx.check_seeds):
+        ctx.seed = seed
+        if i:  # the engine was built on the first seed's weights; the old
+            # ones go before the new are drawn: the chip holds one set
+            srv.engine.params = None
+            srv.engine.params = draw_params(model, seed, dtype, ctx.devices[0])
+            # the keys and values the prefix cache holds are the old
+            # weights': a prompt that begins with a cached request's first
+            # token would attend to them (chip run, PR 27: seed 3165000286)
+            if srv.scheduler.prefix_cache is not None:
+                srv.scheduler.prefix_cache.clear()
+        sample = serve_sample(ctx, srv, submit, model, cc)
+        first = [judge_sample(ctx, srv, model, *sample, cc, inject)
+                 for inject in injects]
+        after = check_precision(ctx, srv, submit, model, injects)
+        for inject, (ok1, n1), (ok2, n2) in zip(injects, first, after):
+            rows.append(dict(seed=seed, inject=inject, correct=ok1 and ok2,
+                             **n1, precision=n2))
+            say("check " + json.dumps(rows[-1]))
+    return dict(checks=rows)
 
 
 def serve_window(ctx, srv, submit, model, seconds: float, tracer,
@@ -509,7 +601,11 @@ def run_serve(ctx) -> dict:
         f"built in {time.monotonic() - t0:.1f} s; slots {srv.max_slots} x "
         f"budget {srv.token_budget}, {srv.num_pages} pages x {srv.page_size}")
     submit = make_submit(srv, Request)
-    ok = check_served(ctx, srv, submit, model)
+    if ctx.check_seeds:
+        return check_seeds(ctx, srv, submit, model, dtype)
+    cc = ctx.mix["correctness"]
+    ok, _ = judge_sample(ctx, srv, model,
+                         *serve_sample(ctx, srv, submit, model, cc), cc)
     traces_before = srv.step_traces
 
     if ctx.sweep:
@@ -541,6 +637,7 @@ def run_serve(ctx) -> dict:
     if in_window or retraced:
         say("INCORRECT: something compiled inside the window")
         ok = False
+    ok = check_precision(ctx, srv, submit, model)[0][0] and ok
     ctx.reduced = tracer.reduced()
     return dict(correct=ok, attempted=w.res.attempted(), failed=w.res.failed(),
                 setup_s=setup_s, end_to_end=e2e)
@@ -613,7 +710,13 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--sweep", default="")
+    ap.add_argument("--check-seeds", default="")
+    ap.add_argument("--inject", default="")
     args = ap.parse_args(argv)
+    if args.inject and not args.check_seeds:
+        die("--inject is for --check-seeds only: a measured run is never an "
+            "injected one")
+    seeds = [int(x) for x in args.check_seeds.split(",") if x]
 
     manifest = load_json(ROOT, "BENCHMARK.json")
     cells = {w["name"]: w for w in manifest["workloads"]}
@@ -669,11 +772,22 @@ def main(argv=None) -> int:
     cache_dir = enable_compile_cache()
     ctx = SimpleNamespace(
         root=ROOT, manifest=manifest, cell=cell, config=config, mix=mix,
-        seed=int(args.seed), seconds=seconds, trace=bool(args.trace),
+        seed=seeds[0] if seeds else int(args.seed), seconds=seconds,
+        trace=bool(args.trace),
         rehearse=args.rehearse, chips=chips, devices=devices, device=device,
         peak=peaks.get(device["kind"]), family=family,
-        shape=family.shape_of(config), flops=flops, counters={}, reduced=None, compiles=CompileCounter(),
-        sweep=[float(x) for x in args.sweep.split(",") if x])
+        shape=family.shape_of(config), flops=flops, counters={}, reduced=None,
+        compiles=CompileCounter(),
+        sweep=[float(x) for x in args.sweep.split(",") if x],
+        check_seeds=seeds,
+        inject=[x for x in args.inject.split(",") if x],
+        family_faults=getattr(family, "FAULTS", ()))
+    if seeds and mix["kind"] == "train_stream":
+        die("--check-seeds is for serving cells")
+    for name in ctx.inject:
+        if name not in (*ctx.family_faults, *TOKEN_FAULTS):
+            die(f"no fault {name!r} to inject (have "
+                f"{(*ctx.family_faults, *TOKEN_FAULTS)})")
     say(f"cell {cell['name']}: config {cell['config']}, traffic "
         f"{cell['traffic']} ({mix['kind']}), {chips} chip(s), seed "
         f"{ctx.seed}, {seconds:g} s, trace {int(ctx.trace)}; device {device}; "
@@ -688,6 +802,9 @@ def main(argv=None) -> int:
     if "sweep" in result:
         print(json.dumps({"sweep": result["sweep"], "device": device}))
         return 0
+    if "checks" in result:
+        print(json.dumps({"checks": result["checks"], "device": device}))
+        return int(not all(r["correct"] for r in result["checks"]))
 
     e2e = dict(result["end_to_end"], setup_s=result["setup_s"])
     units = {m["name"]: m["unit"] for m in

@@ -1,5 +1,7 @@
 """run.py end to end on the CPU: the rehearsal says rehearsal and carries no
-metric; a measurement without a TPU fails and prints no result."""
+metric; a measurement without a TPU fails and prints no result; the serving
+comparison passes what was served and refuses the reference broken on
+purpose (--check-seeds with --inject, the control, at the rehearsal's size)."""
 
 import json
 import os
@@ -35,6 +37,49 @@ def test_rehearsal_ends_in_a_line_that_says_so(cell, trace):
     assert "metrics" not in last and "correct" not in last
     assert all(isinstance(n, str) for n in last["metric_names"])
     assert "compilations inside the window: 0" in p.stdout
+    if cell == "mixtral8x7b-chat":  # each part of the rule, count and limit
+        assert "share rule: 8 within 0.05" in p.stdout
+        assert "outlier rule: 8 clear of a near-tie" in p.stdout
+        assert "(gap, margin) per served token: (" in p.stdout
+        # the precision sample, after the window: 4 requests of 4 tokens
+        assert "16 served tokens" in p.stdout
+        assert "exact rule: at least 75.0% = 12" in p.stdout
+        assert p.stdout.index("compilations inside the window") < (
+            p.stdout.index("exact rule"))
+
+
+def test_the_check_alone_passes_what_was_served_and_refuses_the_faults():
+    # the control: the reference broken on purpose has to read incorrect
+    p = run("--workload", "mixtral8x7b-longdoc", "--rehearse", "--check-seeds",
+            "3000000041,7", "--inject", "gqa_mispaired,token_clear,token_tied")
+    checks = json.loads(p.stdout.strip().splitlines()[-1])["checks"]
+    assert [(c["seed"], c["inject"]) for c in checks] == [
+        (seed, inject) for seed in (3000000041, 7)
+        for inject in (None, "gqa_mispaired", "token_clear", "token_tied")]
+    for c in checks:
+        # the rehearsal calls no position near-tied, so token_tied finds none
+        assert c["correct"] == (c["inject"] in (None, "token_tied")), c
+        assert c["tokens"] == 8
+        # the precision sample is judged by the exact rule alone: a swapped
+        # token of the first sample is no business of it
+        assert c["precision"]["tokens"] == 16
+        assert (c["precision"]["argmax"] >= 12) == (
+            c["inject"] != "gqa_mispaired"), c
+    assert p.returncode == 1  # something read incorrect
+    assert "metrics" not in p.stdout and "window" not in p.stdout
+
+
+@pytest.mark.parametrize("args,why", [
+    (("--inject", "gqa_mispaired"), "--check-seeds only"),
+    (("--inject", "token_clear", "--seed", "5"), "--check-seeds only"),
+    (("--check-seeds", "5", "--inject", "nope", "--rehearse"), "no fault"),
+    (("--check-seeds", "5", "--rehearse", "--workload",
+      "bloom560m-pretrain-2k"), "serving cells"),
+])
+def test_a_measured_run_is_never_an_injected_one(args, why):
+    p = run("--workload", "mixtral8x7b-chat", *args, timeout=120)
+    assert p.returncode == 2 and why in p.stderr
+    assert not any(line.startswith("{") for line in p.stdout.splitlines())
 
 
 def test_without_a_tpu_nothing_runs_and_no_result_is_printed():
