@@ -353,8 +353,9 @@ def phase_serve(spec, dev, hbm_bytes: float, on_chip: bool) -> None:
 
     n = kernel_calls(srv.lower_step().compile().as_text(), "serve", on_chip)
     say(f"serve: slot-step program holds {n} tpu_custom_call(s) (the norm "
-        "kernels; attention in a [slots, token_budget>1] step is XLA by "
-        "construction, models/decoding.py gates the decode kernel on S==1)")
+        f"kernels and the attention); attention path {srv.attention_path}"
+        + (f", because: {'; '.join(srv.attention_fallback)}"
+           if srv.attention_fallback else ""))
 
     # the reference: lockstep generate on the SAME weights. It runs the
     # flash prefill kernel and the dense decode kernel (KV8 hd128).

@@ -13,6 +13,7 @@ that XLA maps onto the MXU as a batched matvec.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -33,6 +34,30 @@ from .transformer import (
 Cache = Dict[str, jax.Array]
 
 SCALE_LANES = 8  # redundant scale copies (min sublane tile; kernels read col 0)
+
+
+_path_recorders: list = []  # active record_attention_path() scopes
+
+
+@contextlib.contextmanager
+def record_attention_path():
+    """Scope that learns which attention the cached forward traced inside
+    it took: yields a dict whose ``path`` reads ``paged_kernel`` (a Pallas
+    kernel reads the page pool through the table), ``decode_kernel`` (the
+    contiguous single-token Pallas kernel) or ``dense`` (the XLA lines),
+    with the ``reasons`` for a dense path. The choice is made at trace
+    time, so the serving engine opens this around its step's trace."""
+    rec = {"path": None, "reasons": ()}
+    _path_recorders.append(rec)
+    try:
+        yield rec
+    finally:
+        _path_recorders.remove(rec)
+
+
+def _note_attention_path(path: str, reasons=()) -> None:
+    for rec in _path_recorders:
+        rec["path"], rec["reasons"] = path, tuple(reasons)
 
 
 def _is_ragged(cache_len) -> bool:
@@ -274,7 +299,8 @@ def _qkv(cfg: TransformerConfig, p: Params, x: jax.Array, positions: jax.Array):
 def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
                       positions: jax.Array, k_cache: jax.Array,
                       v_cache: jax.Array, cache_len,
-                      k_scale=None, v_scale=None, page_table=None):
+                      k_scale=None, v_scale=None, page_table=None,
+                      num_new=None):
     """Attend new tokens (x, [B,S,D]) against cache[:cache_len] + themselves.
 
     Returns (out, new_k_cache, new_v_cache[, new_k_scale, new_v_scale]).
@@ -295,9 +321,14 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
     block-paged form: ``k_cache``/``v_cache`` are page POOLS
     [P+1, page_size, KV, hd] (scales [P+1, KV, page_size, SL]) shared by
     every slot. The chunk scatters to per-token (physical page, offset)
-    destinations FIRST, then attention reads a per-slot gathered view —
-    so the view holds bitwise the bytes the contiguous arena would, and
-    the attention math below is byte-for-byte the dense path.
+    destinations FIRST, then attention reads the slot's pages: through
+    the table inside a Pallas kernel whose work follows the slot's length
+    when the registered attention is the kernel one (rotary or learned
+    positions, unquantized pool, shapes the kernel takes), else through a
+    per-slot gathered view — which holds bitwise the bytes the contiguous
+    arena would, so the attention math below is byte-for-byte the dense
+    path. ``num_new`` [B] (optional) counts each row's real tokens: the
+    kernel stops at the last key a real token needs.
     """
     B, S, _ = x.shape
     nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.hd
@@ -339,26 +370,54 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
             return out, k_cache, v_cache, k_scale, v_scale
         return out, k_cache, v_cache
 
+    def project(out):
+        out = out.astype(x.dtype).reshape(B, S, nh * hd)
+        out = _out_proj(out, p["wo"])
+        if cfg.use_bias:
+            out = out + p["bo"]
+        return ret(out)
+
+    from ..ops.attention import _resolve
+
+    # kernel injection: the Pallas cached-KV kernels when the registered
+    # impl is the kernel one; ALiBi stays on the XLA lines below
+    why_dense = []
+    if _resolve() != "flash":
+        why_dense.append("the registered attention is not the kernel one")
+    if cfg.pos_embedding == "alibi":
+        why_dense.append("ALiBi positions")
+    kernel_ok = not why_dense
+
     if paged:
-        if S == 1 and cfg.pos_embedding != "alibi":
+        out = None
+        if kernel_ok and S == 1:
             # single-token paged decode: the Pallas kernel gathers K/V
             # page-by-page through the table (scalar prefetch drives the
             # block index map) — no [B, capacity] view materializes
-            from ..ops.attention import _resolve
+            from ..ops.pallas.decode_attention import decode_attention
 
-            if _resolve() == "flash":
-                from ..ops.pallas.decode_attention import decode_attention
+            out = decode_attention(
+                q, k_cache, v_cache, cache_len,
+                k_scale=k_scale, v_scale=v_scale, page_table=page_table,
+            )
+            if out is None:
+                why_dense.append("shapes the decode kernel does not take")
+        elif kernel_ok and quantized:
+            why_dense.append("int8 KV cache")
+        elif kernel_ok:
+            # a [slots, chunk] block: each slot's pages come in through the
+            # table inside the kernel, whose loop follows the slot's
+            # length — no per-slot view, no repeated head, no
+            # capacity-wide score tensor
+            from ..ops.pallas.paged_attention import paged_attention
 
-                out = decode_attention(
-                    q, k_cache, v_cache, cache_len,
-                    k_scale=k_scale, v_scale=v_scale, page_table=page_table,
-                )
-                if out is not None:
-                    out = out.astype(x.dtype).reshape(B, S, nh * hd)
-                    out = _out_proj(out, p["wo"])
-                    if cfg.use_bias:
-                        out = out + p["bo"]
-                    return ret(out)
+            out, why_dense = paged_attention(
+                q, k_cache, v_cache, cache_len, page_table, num_new=num_new,
+            )
+        if out is not None:
+            _note_attention_path("paged_kernel")
+            return project(out)
+        _note_attention_path("dense", why_dense)
         # XLA path: gather the per-slot contiguous views (post-write, so
         # they reproduce the dense arena bitwise) and fall through to the
         # shared attention math below
@@ -370,7 +429,6 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
             else None
     else:
         k_att, v_att, ks_att, vs_att = k_cache, v_cache, k_scale, v_scale
-    S_max = k_att.shape[1]
 
     if not paged and isinstance(cache_len, int) and cache_len == 0 and S > 1:
         # fresh prefill: the new tokens only attend among themselves, so the
@@ -385,34 +443,39 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
             if cfg.pos_embedding == "alibi"
             else None
         )
-        out = attn_op(q, k, v, causal=True, alibi_slopes=slopes)
-        out = out.reshape(B, S, nh * hd)
-        out = _out_proj(out, p["wo"])
-        if cfg.use_bias:
-            out = out + p["bo"]
-        return ret(out)
-    if S == 1 and cfg.pos_embedding != "alibi":
-        # fused decode path (kernel injection): Pallas cached-KV attention
-        # when the registered impl is the kernel one and shapes fit
-        from ..ops.attention import _resolve
+        return project(attn_op(q, k, v, causal=True, alibi_slopes=slopes))
+    if S == 1 and kernel_ok:
+        # fused decode path: Pallas cached-KV attention over the contiguous
+        # (or gathered) view when shapes fit
+        from ..ops.pallas.decode_attention import decode_attention
 
-        if _resolve() == "flash":
-            from ..ops.pallas.decode_attention import decode_attention
+        out = decode_attention(
+            q, k_att, v_att, cache_len, k_scale=ks_att, v_scale=vs_att,
+        )
+        if out is not None:
+            _note_attention_path("decode_kernel")
+            return project(out)
+    if not paged:
+        _note_attention_path("dense", why_dense or ["a contiguous cache"])
+    return project(_dense_cached_attention(
+        cfg, q, k_att, v_att, cache_len, ks_att, vs_att
+    ))
 
-            out = decode_attention(
-                q, k_att, v_att, cache_len,
-                k_scale=ks_att, v_scale=vs_att,
-            )
-            if out is not None:
-                out = out.astype(x.dtype).reshape(B, S, nh * hd)
-                out = _out_proj(out, p["wo"])
-                if cfg.use_bias:
-                    out = out + p["bo"]
-                return ret(out)
 
+def _dense_cached_attention(cfg: TransformerConfig, q: jax.Array,
+                            k_att: jax.Array, v_att: jax.Array, cache_len,
+                            ks_att=None, vs_att=None) -> jax.Array:
+    """The XLA lines: q [B,S,H,hd] against a per-row contiguous K/V view
+    [B, S_max, KV, hd] (int8 with its [B, KV, S_max, SL] scales), each row
+    masked at its own frontier ``kpos <= cache_len + i``. float32
+    throughout; the oracle for the Pallas kernels and the path for ALiBi,
+    int8 and contiguous caches. Returns [B,S,H,hd] float32."""
+    S = q.shape[1]
+    nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.hd
+    S_max = k_att.shape[1]
     kf = k_att.astype(jnp.float32)
     vf = v_att.astype(jnp.float32)
-    if quantized:
+    if ks_att is not None:
         # scale cache is [B, KV, Smax, SL]; align to the [B, Smax, KV, hd]
         # value layout for the dense dequant (fallback path only)
         kf = kf * jnp.swapaxes(ks_att, 1, 2)[..., :1]
@@ -436,12 +499,7 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
         )
     logits = jnp.where(kpos <= qpos, logits, -1e30)  # causal + cache bound
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs, vf).astype(x.dtype)
-    out = out.reshape(B, S, nh * hd)
-    out = _out_proj(out, p["wo"])
-    if cfg.use_bias:
-        out = out + p["bo"]
-    return ret(out)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, vf)
 
 
 def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Array,
@@ -449,6 +507,7 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
                        dtype=jnp.bfloat16,
                        page_table=None,
                        token_valid=None,
+                       num_new=None,
                        return_moe_stats: bool = False):
     """Run new tokens through all layers against the cache.
 
@@ -460,7 +519,9 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
 
     ``page_table`` [B, max_pages] switches ``cache`` to the block-paged
     pool form (init_paged_cache): every layer scatters its chunk through
-    the shared table and attends a gathered per-slot view.
+    the shared table and attends the slot's pages (_cached_attention).
+    ``num_new`` [B] (the serving engine's real tokens per row) lets the
+    paged kernel stop at the last key a real token needs.
 
     MoE models route the MLP through the serving expert path
     (moe/sharded_moe.moe_serving_mlp): slot-ragged gather dispatch over
@@ -505,13 +566,14 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
             a, kc, vc, ks, vs = _cached_attention(
                 cfg, layer["attn"], _norm(cfg, layer["ln1"], h), positions,
                 kc, vc, cache_len, ks, vs, page_table=page_table,
+                num_new=num_new,
             )
             new_cache = (kc, vc, ks, vs)
         else:
             layer, kc, vc = scanned
             a, kc, vc = _cached_attention(
                 cfg, layer["attn"], _norm(cfg, layer["ln1"], h), positions,
-                kc, vc, cache_len, page_table=page_table,
+                kc, vc, cache_len, page_table=page_table, num_new=num_new,
             )
             new_cache = (kc, vc)
         h = h + a

@@ -33,7 +33,9 @@ def _tile_update(q, k, v, ks, vs, start, cl, scale, m_scr, l_scr, acc_scr):
     """One [block_s, hd] K/V tile's contribution to the fp32 online
     softmax (shared by the dense and paged kernels): dequantize when
     scales ride along, mask past the row's frontier, fold into the
-    running (max, sum, acc) scratches."""
+    running (max, sum, acc) scratches. ``cl`` is the frontier: a scalar,
+    a per-(row, key) array (paged_attention's chunk rows), or None for a
+    tile wholly below every row's frontier."""
     if ks is not None:
         # int8 cache: dequantize the tile with its per-token scales
         k = (k.astype(jnp.float32) * ks[:, :1]).astype(q.dtype)
@@ -46,8 +48,9 @@ def _tile_update(q, k, v, ks, vs, start, cl, scale, m_scr, l_scr, acc_scr):
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale  # [G, block_s]
-    kpos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(kpos <= cl, s, NEG_INF)
+    if cl is not None:
+        kpos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(kpos <= cl, s, NEG_INF)
 
     m_prev = m_scr[:, :1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))

@@ -1,0 +1,375 @@
+"""Pallas paged attention for the serving step's [slots, chunk] block.
+
+Parity: the blocked-KV attention of DeepSpeed-FastGen / vLLM's block-table
+kernels, for the one ``[max_slots, token_budget]`` step the serving engine
+compiles. The XLA fallback (models/decoding.py) gathers a per-slot
+``[B, capacity]`` view, repeats K/V to every query head and builds a
+float32 ``[B, H, S, capacity]`` score tensor whatever a slot holds; this
+kernel reads each slot's own pages through the table and its work —
+loop trips and DMA included — follows the slot's length.
+
+Shape of the kernel: one program per slot (grid ``(B,)``). The pools stay
+in HBM; a loop whose trip count is read from the slot's frontier fetches
+``pages_per_block`` pages a trip (whole pages, in the pool's own
+``[page_size, KV, hd]`` layout: all KV heads of a page are contiguous and
+nothing is relaid out in HBM) by async copy into double-buffered VMEM, the
+next block in flight while this one is computed; each head's ``[block_k,
+hd]`` tile is then a strided read of that buffer. The G query heads of
+one KV head stack as ``[S * G, hd]`` rows against one ``[block_k, hd]`` K
+tile (no head is repeated); fp32 online softmax in VMEM via
+decode_attention's ``_tile_update``. Blocks wholly below the chunk's first
+row skip the causal mask.
+
+Layouts: q ``[B, S, H, hd]``, pools ``[P+1, page_size, KV, hd]``
+(init_paged_cache), page_table ``[B, max_pages]`` and the per-row frontiers
+in SMEM. Row ``i`` of slot ``b`` attends ``kpos <= cache_len[b] + i``; the
+chunk's own keys are already in the pool (the caller scatters first).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import List, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .decode_attention import LANES, NEG_INF, _tile_update
+
+# keys a loop trip: 512 beat 256 at every length tried on the v5e (by 15 %
+# with 16 slots at 300 tokens, by 27 % with 16 at 8k; PERF.md, PR 28)
+DEFAULT_BLOCK_K = 512
+# the chip's VMEM is 128 MiB; the scoped default (16 MiB) is below what the
+# per-head (m, l, acc) scratches of a 128-row chunk take
+VMEM_LIMIT_BYTES = 96 * 1024 * 1024
+VMEM_BUDGET_BYTES = 64 * 1024 * 1024
+# the page table rides in SMEM (1 MiB on the v5e; 512 KiB compiles, 1 MiB
+# does not)
+SMEM_TABLE_BYTES = 512 * 1024
+
+
+def _head_tiles(buf, KV: int):
+    """The per-head [block_k, hd] tiles of one fetched block: ``buf`` is a
+    VMEM ref [block_k, KV, hd] in the pool's layout, so head ``kv`` is
+    every KV-th row of its [block_k * KV, hd] view. Yields (kv, tile) in
+    head order. A bf16 buffer packs two rows to a 32-bit sublane: one
+    strided read of the uint32 view brings a pair of heads, split by shift
+    and mask (exact: a bf16 is the top half of its float32)."""
+    bk, _, hd = buf.shape
+    flat = buf.reshape(bk * KV, hd)
+    if KV == 1:
+        yield 0, flat[...]
+    elif buf.dtype == jnp.bfloat16:
+        words = flat.bitcast(jnp.uint32)  # [bk * KV / 2, hd]
+        for pair in range(KV // 2):
+            w = words[pair::KV // 2, :]
+            yield 2 * pair, pltpu.bitcast(
+                w << 16, jnp.float32).astype(jnp.bfloat16)
+            yield 2 * pair + 1, pltpu.bitcast(
+                w & jnp.uint32(0xFFFF0000), jnp.float32).astype(jnp.bfloat16)
+    else:
+        for kv in range(KV):
+            yield kv, flat[kv::KV, :]
+
+
+def _paged_attention_kernel(pt_ref, cl_ref, nn_ref, q_ref, k_hbm, v_hbm,
+                            o_ref, k_buf, v_buf, sems, kh_scr, vh_scr,
+                            m_scr, l_scr, acc_scr,
+                            *, scale, page_size, pages_per_block, group):
+    KV, SG, hd = q_ref.shape[1:]
+    ps, ppb = page_size, pages_per_block
+    bk = ps * ppb
+    mp = pt_ref.shape[1]
+    b = pl.program_id(0)
+    cl = cl_ref[b]
+    nn = nn_ref[b]
+
+    def page_copies(slot, j, page):
+        dst = pl.ds(j * ps, ps)
+        return (
+            pltpu.make_async_copy(
+                k_hbm.at[page], k_buf.at[slot, dst], sems.at[0, slot]),
+            pltpu.make_async_copy(
+                v_hbm.at[page], v_buf.at[slot, dst], sems.at[1, slot]),
+        )
+
+    def start_fetch(blk, slot):
+        def one(j, carry):
+            # logical pages past the table (a last block that overhangs
+            # it) re-read the last entry: those keys lie past every row's
+            # frontier
+            page = pt_ref[b, jnp.minimum(blk * ppb + j, mp - 1)]
+            for c in page_copies(slot, j, page):
+                c.start()
+            return carry
+
+        lax.fori_loop(0, ppb, one, 0)
+
+    def wait_fetch(slot):
+        def one(j, carry):
+            # a wait needs the copy's shape and semaphore, not its source
+            for c in page_copies(slot, j, 0):
+                c.wait()
+            return carry
+
+        lax.fori_loop(0, ppb, one, 0)
+
+    @pl.when(nn == 0)
+    def _idle():
+        # a slot with nothing scheduled: its rows are padding (the frontier
+        # invariant in models/decoding._cached_attention); keep them finite
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(nn > 0)
+    def _attend():
+        # keys needed: 0 .. cl + nn - 1 (rows past nn are padding and may
+        # see less than their frontier)
+        n_blocks = jnp.minimum(pl.cdiv(cl + nn, bk), pl.cdiv(mp * ps, bk))
+        # blocks wholly at or below row 0's frontier need no mask
+        n_full = jnp.minimum((cl + 1) // bk, n_blocks)
+
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        start_fetch(0, 0)
+
+        def block(i, carry, *, masked):
+            slot = lax.rem(i, 2)
+
+            @pl.when(i + 1 < n_blocks)
+            def _prefetch():
+                start_fetch(i + 1, 1 - slot)
+
+            wait_fetch(slot)
+            # head-major copies of the block, so that one rolled loop over
+            # the KV heads serves them all: a body a head runs the long
+            # contexts a third faster (3.8 against 5.6 ms with 16 slots at
+            # 8k) but octuples the kernel's code, and the engine's first
+            # step then takes 4 s longer even from a warm compile cache
+            # (PERF.md, PR 28)
+            for kv, tile in _head_tiles(k_buf.at[slot], KV):
+                kh_scr[kv] = tile
+            for kv, tile in _head_tiles(v_buf.at[slot], KV):
+                vh_scr[kv] = tile
+            start = i * bk
+
+            def head(kv, c):
+                frontier = None
+                if masked:
+                    # row r of the [S * G, hd] stack is query (r // G)
+                    frontier = cl + lax.broadcasted_iota(
+                        jnp.int32, (SG, bk), 0
+                    ) // group
+                _tile_update(
+                    q_ref[0, kv], kh_scr[kv], vh_scr[kv], None, None, start,
+                    frontier, scale,
+                    m_scr.at[kv], l_scr.at[kv], acc_scr.at[kv],
+                )
+                return c
+
+            lax.fori_loop(0, KV, head, 0)
+            return carry
+
+        lax.fori_loop(0, n_full, functools.partial(block, masked=False), 0)
+        lax.fori_loop(n_full, n_blocks,
+                      functools.partial(block, masked=True), 0)
+
+        def finish(kv, c):
+            l = l_scr[kv, :, :1]
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, kv] = (acc_scr[kv] / l_safe).astype(o_ref.dtype)
+            return c
+
+        lax.fori_loop(0, KV, finish, 0)
+
+
+def _block_pages(block_k: int, page_size: int, max_pages: int) -> int:
+    return max(1, min(block_k // page_size, max_pages))
+
+
+def _vmem_bytes(S, G, KV, hd, page_size, pages_per_block, q_bytes, kv_bytes):
+    """What one program keeps in VMEM: the (m, l, acc) scratches of every
+    KV head, the double-buffered K and V blocks and their head-major
+    copies, the pipelined q and out
+    blocks, and the [S * G, block_k] fp32 score/probability temporaries."""
+    SG, bk = S * G, page_size * pages_per_block
+    scratch = KV * SG * (2 * LANES + hd) * 4
+    kv_bufs = 3 * 2 * bk * KV * hd * kv_bytes  # two fetch slots + head-major
+    q_out = 2 * 2 * KV * SG * hd * q_bytes
+    temps = 4 * SG * bk * 4
+    return scratch + kv_bufs + q_out + temps
+
+
+def _frontiers(B: int, S: int, cache_len, num_new):
+    """Per-slot int32 [B] (frontier, real rows): a scalar frontier
+    broadcasts, no ``num_new`` means every row is real."""
+    cl = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32).reshape(-1), (B,))
+    nn = (
+        jnp.full((B,), S, jnp.int32) if num_new is None
+        else jnp.clip(jnp.asarray(num_new, jnp.int32).reshape(-1), 0, S)
+    )
+    return cl, nn
+
+
+def paged_attention_kernel(q, k_pool, v_pool, cache_len, page_table, *,
+                           num_new=None, block_k: int = DEFAULT_BLOCK_K,
+                           interpret: Optional[bool] = None):
+    """q [B,S,H,hd] chunk queries vs a block-paged KV pool
+    k/v_pool [P+1, page_size, KV, hd] addressed through per-slot page
+    tables [B, max_pages]. ``cache_len`` [B] is each slot's frontier BEFORE
+    the chunk (row i attends kpos <= cache_len[b] + i; the caller has
+    already scattered the chunk's keys). ``num_new`` [B] (optional) is the
+    count of real rows: the loop stops at the last key a real row needs,
+    and a slot with none is skipped (its output rows are zeros). Returns
+    [B,S,H,hd]."""
+    B, S, H, hd = q.shape
+    ps, KV = k_pool.shape[1], k_pool.shape[2]
+    mp = page_table.shape[1]
+    G = H // KV
+    SG = S * G
+    ppb = _block_pages(block_k, ps, mp)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    pt = jnp.asarray(page_table, jnp.int32)
+    cl, nn = _frontiers(B, S, cache_len, num_new)
+    # the G query heads of a KV head stack beside each query: [S * G, hd]
+    qg = q.reshape(B, S, KV, G, hd).swapaxes(1, 2).reshape(B, KV, SG, hd)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,  # page_table, cache_len, num_new
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1, KV, SG, hd), lambda b, *_: (b, 0, 0, 0)),
+            # the pools stay in HBM; whole pages ([ps, KV, hd], all heads
+            # contiguous) come in by async copy
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, KV, SG, hd), lambda b, *_: (b, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb * ps, KV, hd), k_pool.dtype),
+            pltpu.VMEM((2, ppb * ps, KV, hd), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((KV, ppb * ps, hd), k_pool.dtype),
+            pltpu.VMEM((KV, ppb * ps, hd), v_pool.dtype),
+            pltpu.VMEM((KV, SG, LANES), jnp.float32),
+            pltpu.VMEM((KV, SG, LANES), jnp.float32),
+            pltpu.VMEM((KV, SG, hd), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _paged_attention_kernel, scale=1.0 / (hd**0.5), page_size=ps,
+            pages_per_block=ppb, group=G,
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, SG, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
+        interpret=interpret,
+        name="paged_attention",
+    )(pt, cl, nn, qg, k_pool, v_pool)
+    return out.reshape(B, KV, S, G, hd).swapaxes(1, 2).reshape(B, S, H, hd)
+
+
+def paged_attention(q, k_pool, v_pool, cache_len, page_table, *,
+                    num_new=None, interpret: Optional[bool] = None
+                    ) -> Tuple[Optional[jax.Array], List[str]]:
+    """Shard-map-aware wrapper (heads over tp, slots over dp/fsdp — the
+    layout of decode_attention's). Returns ``(out, reasons)``: ``out`` is
+    None when the shapes don't fit the kernel, ``reasons`` says why (logged
+    once) and the caller falls back to the dense XLA lines."""
+    from ...models.sharding import current_topology
+
+    B, S, H, hd = q.shape
+    ps, KV = k_pool.shape[1], k_pool.shape[2]
+    mp = page_table.shape[1]
+    topo = current_topology()
+    distributed = topo is not None and topo.world_size > 1
+    tp = topo.tp_size if distributed else 1
+    interp = interpret if interpret is not None else (
+        jax.default_backend() != "tpu"
+    )
+    reasons = []
+    if H % KV != 0:
+        reasons.append(f"H={H} not a multiple of KV={KV}")
+    if distributed and (H % tp != 0 or KV % tp != 0):
+        reasons.append(f"H={H}/KV={KV} not divisible by tp={tp}")
+    kv_local = KV // tp if KV % tp == 0 else KV
+    if k_pool.dtype not in (jnp.bfloat16, jnp.float32):
+        reasons.append(f"{jnp.dtype(k_pool.dtype).name} KV pool")
+    elif k_pool.dtype == jnp.bfloat16 and kv_local > 1 and kv_local % 2:
+        reasons.append(f"{kv_local} local KV heads do not pair in bf16")
+    if not interp:
+        # what the chip's compiler asks of the tiles (interpret mode has no
+        # such constraint): head_dim on the lanes, and a page's [KV, hd]
+        # rows filling whole sublane tiles (as jax's
+        # ragged_paged_attention asks of its combined heads)
+        sublanes = kv_local * jnp.dtype(k_pool.dtype).itemsize // 4
+        if hd % LANES != 0:
+            reasons.append(f"head_dim {hd} not {LANES}-aligned")
+        if sublanes not in (1, 2, 4) and (sublanes == 0 or sublanes % 8):
+            reasons.append(
+                f"{kv_local} local KV heads in "
+                f"{jnp.dtype(k_pool.dtype).name} do not fill a sublane tile"
+            )
+    slots = B
+    if distributed:
+        for a in ("dp", "fsdp"):
+            slots //= max(topo.sizes[a], 1)
+    if not interp and slots * mp * 4 > SMEM_TABLE_BYTES:
+        reasons.append(
+            f"a [{slots}, {mp}] page table is over the "
+            f"{SMEM_TABLE_BYTES >> 10} KiB of SMEM it may take"
+        )
+    if not reasons:
+        need = _vmem_bytes(
+            S, H // KV, KV // tp, hd, ps, _block_pages(DEFAULT_BLOCK_K, ps, mp),
+            jnp.dtype(q.dtype).itemsize, jnp.dtype(k_pool.dtype).itemsize,
+        )
+        if need > VMEM_BUDGET_BYTES:
+            reasons.append(
+                f"a [{S} x {H // KV}]-row chunk of {KV // tp} KV heads needs "
+                f"{need >> 20} MiB of VMEM (budget "
+                f"{VMEM_BUDGET_BYTES >> 20} MiB)"
+            )
+    if reasons:
+        from ...utils.logging import log_fallback_once
+
+        log_fallback_once("paged_attention", reasons)
+        return None, reasons
+
+    if not distributed:
+        return paged_attention_kernel(
+            q, k_pool, v_pool, cache_len, page_table, num_new=num_new,
+            interpret=interp,
+        ), reasons
+
+    from jax.sharding import PartitionSpec as P
+
+    batch_axes = tuple(a for a in ("dp", "fsdp") if topo.sizes[a] > 1)
+    b_ax = batch_axes if batch_axes else None
+    h_ax = "tp" if tp > 1 else None
+    # page pools are slot-agnostic: heads over tp, pages replicated; the
+    # table and the frontiers ride with the (slot) batch
+    q_spec = P(b_ax, None, h_ax, None)
+    kv_spec = P(None, None, h_ax, None)
+
+    def body(q, kc, vc, cl, nn, pt):
+        return paged_attention_kernel(
+            q, kc, vc, cl, pt, num_new=nn, interpret=interp
+        )
+
+    return jax.shard_map(
+        body,
+        mesh=topo.mesh,
+        in_specs=(q_spec, kv_spec, kv_spec, P(b_ax), P(b_ax), P(b_ax, None)),
+        out_specs=q_spec,
+        check_vma=False,
+    )(q, k_pool, v_pool, *_frontiers(B, S, cache_len, num_new),
+      jnp.asarray(page_table, jnp.int32)), reasons

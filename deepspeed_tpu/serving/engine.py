@@ -48,7 +48,7 @@ see docs/serving.md "Speculative decoding" and tests/test_serving_spec.py.
 from __future__ import annotations
 
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -60,7 +60,7 @@ from ..inference.engine import (InferenceEngine, _align_cache,
                                 init_inference)
 from ..models.decoding import (SCALE_LANES, forward_with_cache, init_cache,
                                init_paged_cache, paged_cow_copy,
-                               staged_promote)
+                               record_attention_path, staged_promote)
 from ..models.sharding import use_topology
 from ..utils.logging import log_dist
 from .metrics import ServingMetrics
@@ -480,7 +480,7 @@ def make_paged_step_fn(cfg, dtype, vocab: int, cache_shardings=None,
         )
         fw = forward_with_cache(
             cfg, params, tokens, caches, start_pos, dtype=dtype,
-            page_table=page_table,
+            page_table=page_table, num_new=num_new,
             token_valid=token_valid, return_moe_stats=moe,
         )
         if moe:
@@ -809,10 +809,23 @@ class ServingEngine:
         # the recompile counter: a trace-time side effect fires once per
         # XLA compile — the zero-recompiles-after-warmup assertion
         self.step_traces = 0
+        # which attention the compiled step took — "paged_kernel" (Pallas,
+        # work follows each slot's length), "decode_kernel" or "dense" (the
+        # XLA lines, with the reasons) — chosen at trace time from what the
+        # step can observe, so recorded by the same side effect
+        self.attention_path: Optional[str] = None
+        self.attention_fallback: Tuple[str, ...] = ()
 
         def counting_step(*args):
             self.step_traces += 1
-            return step_fn(*args)
+            with record_attention_path() as rec:
+                out = step_fn(*args)
+            self.attention_path = rec["path"]
+            self.attention_fallback = rec["reasons"]
+            self.metrics.attention_paged_kernel = float(
+                rec["path"] == "paged_kernel"
+            )
+            return out
 
         self._step = jax.jit(counting_step, donate_argnums=(1, 2))
         # lazily-jitted fleet-handoff page scatter (pool donated; one

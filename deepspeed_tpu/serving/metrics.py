@@ -130,6 +130,9 @@ class ServingMetrics:
         self.moe_a2a_bytes = 0        # cumulative expert-exchange wire
         #   bytes (the analytic moe_decode_a2a stream; 0 without ep)
         # gauges (last observed)
+        self.attention_paged_kernel = 0.0  # 1 when the compiled step's
+        #   attention is the paged Pallas kernel (ServingEngine
+        #   .attention_path; 0 = the dense XLA lines or not compiled yet)
         self.queue_depth = 0
         self.slot_occupancy = 0.0
         self.pages_in_use = 0
@@ -376,6 +379,7 @@ class ServingMetrics:
             "acceptance_rate": self.acceptance_rate,
             "mean_accepted_tokens_per_step":
                 self.mean_accepted_tokens_per_step,
+            "attention_paged_kernel": self.attention_paged_kernel,
         }
         if (self._host_pages or self.pages_spilled or self.pages_promoted
                 or self.host_pages_resident):

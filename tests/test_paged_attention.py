@@ -1,0 +1,162 @@
+"""The paged Pallas attention of the serving step's [slots, chunk] block
+(ops/pallas/paged_attention.py), in interpret mode: the kernel against the
+dense XLA lines of models/decoding.py on the same pools."""
+
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.models.decoding import (_dense_cached_attention,
+                                           _paged_gather)
+from deepspeed_tpu.ops.pallas.paged_attention import (paged_attention,
+                                                      paged_attention_kernel)
+
+PS, MP, NULL = 8, 32, 40  # page size, pages a slot, the NULL page's index
+
+
+def _pools_and_table(r, KV, hd, dtype):
+    """40 pages + NULL, five slots: ragged frontiers off every page and
+    block boundary, a shuffled table, two slots sharing prefix pages, a
+    table partly on the NULL page, an idle slot, a context of 3+ blocks."""
+    k_pool = jnp.asarray(r.randn(NULL + 1, PS, KV, hd), jnp.float32)
+    v_pool = jnp.asarray(r.randn(NULL + 1, PS, KV, hd), jnp.float32)
+    pt = np.full((5, MP), NULL, np.int32)
+    pt[0, :7] = [5, 2, 7, 11, 30, 1, 9]
+    # slot 1 shares slot 0's first three pages, then diverges
+    pt[1, :6] = [5, 2, 7, 12, 13, 3]
+    # slot 2 is idle: every entry on the NULL page
+    # slot 3 holds a long context: 14 pages = 112 positions
+    pt[3, :14] = r.permutation(np.arange(14, 28))
+    # slot 4: a short prompt from empty, its tail on the NULL page
+    pt[4, :1] = [0]
+    cache_len = np.asarray([37, 29, 0, 91, 0], np.int32)
+    return (k_pool.astype(dtype), v_pool.astype(dtype), jnp.asarray(pt),
+            jnp.asarray(cache_len))
+
+
+def _case(S, G, dtype, seed):
+    dtype = jnp.dtype(dtype)
+    KV, hd = 2, 32
+    H = KV * G
+    r = np.random.RandomState(seed)
+    k_pool, v_pool, pt, cache_len = _pools_and_table(r, KV, hd, dtype)
+    q = jnp.asarray(r.randn(pt.shape[0], S, H, hd), jnp.float32).astype(dtype)
+    cfg = types.SimpleNamespace(num_heads=H, kv_heads=KV, hd=hd,
+                                pos_embedding="rope")
+    ref = np.asarray(_dense_cached_attention(
+        cfg, q, _paged_gather(k_pool, pt), _paged_gather(v_pool, pt),
+        cache_len,
+    ))
+    # float32 to 1e-5; bf16 operands, probabilities and output each round
+    # to 2^-9 relative
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    return q, k_pool, v_pool, pt, cache_len, ref, tol
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("G", [1, 4])
+@pytest.mark.parametrize("S", [1, 8, 128])
+def test_paged_attention_kernel_matches_dense_lines(S, G, dtype):
+    q, k_pool, v_pool, pt, cache_len, ref, tol = _case(
+        S, G, dtype, S * 10 + G
+    )
+    num_new = jnp.asarray([S, max(S - 3, 1), 0, S, min(S, 5)], jnp.int32)
+    # block_k 32 = 4 pages a block: slot 3 walks 3 blocks or more, slot 0 two
+    out = np.asarray(paged_attention_kernel(
+        q, k_pool, v_pool, cache_len, pt, num_new=num_new, block_k=32,
+    ).astype(jnp.float32))
+    assert out.shape == ref.shape
+    for b in range(pt.shape[0]):
+        n = int(num_new[b])
+        np.testing.assert_allclose(out[b, :n], ref[b, :n], atol=tol, rtol=tol)
+    # rows past num_new and an idle slot's are padding: finite, no more
+    assert np.isfinite(out).all()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_paged_attention_wrapper_every_row_real(dtype):
+    """Without num_new every row is real: the whole block matches, through
+    the wrapper and its default block (one block holds every context)."""
+    q, k_pool, v_pool, pt, cache_len, ref, tol = _case(8, 4, dtype, 5)
+    out, reasons = paged_attention(q, k_pool, v_pool, cache_len, pt)
+    assert reasons == []
+    np.testing.assert_allclose(
+        np.asarray(out.astype(jnp.float32)), ref, atol=tol, rtol=tol
+    )
+
+
+def test_paged_attention_work_follows_length():
+    """The loop's trip count comes from the frontier: pages past the last
+    key a real row needs are never read, so poisoning them changes
+    nothing — and a table pointing past a short slot's length costs no
+    NaN."""
+    KV, G, hd, S = 2, 2, 32, 8
+    r = np.random.RandomState(7)
+    k_pool, v_pool, pt, cache_len = _pools_and_table(r, KV, hd, jnp.float32)
+    q = jnp.asarray(r.randn(pt.shape[0], S, KV * G, hd), jnp.float32)
+    num_new = jnp.asarray([S, S, 0, S, 4], jnp.int32)
+    out = paged_attention_kernel(q, k_pool, v_pool, cache_len, pt,
+                                 num_new=num_new, block_k=16)
+    # slot 0 needs keys 0..44: blocks 0-2 of 16 = pages 0-5; its 7th page
+    # (physical 9) and the NULL page are never fetched for it
+    poisoned_k = k_pool.at[9].set(jnp.nan).at[NULL].set(jnp.nan)
+    out2 = paged_attention_kernel(q, poisoned_k, v_pool, cache_len, pt,
+                                  num_new=num_new, block_k=16)
+    np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(out2[0]))
+    np.testing.assert_array_equal(np.asarray(out[3]), np.asarray(out2[3]))
+
+
+def test_paged_attention_under_tp_matches_one_device():
+    """Heads over tp through the shard_map wrapper: the same numbers."""
+    from deepspeed_tpu.comm.topology import MeshTopology, ParallelDims
+    from deepspeed_tpu.models.sharding import use_topology
+
+    KV, G, hd, S = 2, 2, 32, 8
+    r = np.random.RandomState(3)
+    k_pool, v_pool, pt, cache_len = _pools_and_table(r, KV, hd, jnp.float32)
+    q = jnp.asarray(r.randn(pt.shape[0], S, KV * G, hd), jnp.float32)
+    one, _ = paged_attention(q, k_pool, v_pool, cache_len, pt)
+    topo = MeshTopology(dims=ParallelDims(tp=2), devices=jax.devices()[:2])
+    with use_topology(topo):
+        two, reasons = jax.jit(
+            lambda *a: paged_attention(*a)
+        )(q, k_pool, v_pool, cache_len, pt)
+    assert reasons == []
+    np.testing.assert_allclose(np.asarray(two), np.asarray(one), atol=1e-6)
+
+
+@pytest.mark.parametrize(
+    "kw,why",
+    [
+        (dict(hd=32), "head_dim 32 not 128-aligned"),
+        (dict(KV=1, H=4), "1 local KV heads in bfloat16 do not fill"),
+        (dict(pool_dtype="int8"), "int8 KV pool"),
+        (dict(mp=16384), "page table is over the 512 KiB of SMEM"),
+        (dict(S=1024), "MiB of VMEM"),
+        (dict(H=6, KV=4), "H=6 not a multiple of KV=4"),
+    ],
+    ids=["head-dim", "one-bf16-head", "int8", "smem", "vmem", "ragged-gqa"],
+)
+def test_paged_attention_steps_aside_with_reasons(kw, why):
+    """What the chip's compiler would refuse is refused by the wrapper:
+    no kernel, the reason named (and logged once), nothing raised."""
+    cell = dict(B=16, S=128, H=32, KV=8, hd=128, mp=528,
+                pool_dtype="bfloat16")
+    B, S, H, KV, hd, mp, pool_dtype = {**cell, **kw}.values()
+    ps, sds = 16, jax.ShapeDtypeStruct
+
+    def fn(q, k, v, cl, pt):
+        out, reasons = paged_attention(q, k, v, cl, pt, interpret=False)
+        assert out is None
+        assert any(why in r for r in reasons), reasons
+        return cl
+
+    jax.eval_shape(
+        fn, sds((B, S, H, hd), jnp.bfloat16),
+        sds((65, ps, KV, hd), jnp.dtype(pool_dtype)),
+        sds((65, ps, KV, hd), jnp.dtype(pool_dtype)),
+        sds((B,), jnp.int32), sds((B, mp), jnp.int32),
+    )

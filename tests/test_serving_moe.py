@@ -407,3 +407,98 @@ def test_moe_decode_stream_declared(devices8):
         "max_slots": 2, "token_budget": 8, "max_tokens": 32,
     })
     assert "moe_decode_a2a" not in srv1.analytic_streams()
+
+
+# ---------------------------------------------------------------------------
+# the paged attention kernel through the engine (ISSUE 28)
+# ---------------------------------------------------------------------------
+def _mixed_replay(srv):
+    """Chunked prefills beside decodes, a shared prefix that diverges
+    inside a page (copy-on-write) and speculative verify windows, on one
+    paged tiny-Mixtral engine. Returns every request's greedy tokens."""
+    r = np.random.RandomState(11)
+    # repetitive prompts so the n-gram drafts land verify windows; 20 and
+    # 27 tokens prefill over 3-4 chunks of the 8-token budget
+    base = np.tile(r.randint(0, 64, size=(4,)), 8)
+    prompts = [base[:20], base[:27], r.randint(0, 64, size=(5,)), base[:20]]
+    states = [srv.submit(Request(request_id=f"k{i}", prompt=prompts[i],
+                                 max_new_tokens=n))
+              for i, n in ((0, 7), (1, 5))]
+    srv.step()
+    srv.step()
+    states.append(srv.submit(Request(request_id="k2", prompt=prompts[2],
+                                     max_new_tokens=6)))
+    srv.run_until_idle()
+    # the same prompt again: its pages come from the prefix cache and its
+    # first write lands inside a shared partial page
+    states.append(srv.submit(Request(request_id="k3", prompt=prompts[3],
+                                     max_new_tokens=7)))
+    srv.run_until_idle()
+    traces = srv.step_traces
+    states.append(srv.submit(Request(request_id="k4", prompt=prompts[1],
+                                     max_new_tokens=4)))
+    srv.run_until_idle()
+    assert srv.step_traces == traces == 1, "recompiled after warm-up"
+    assert srv.metrics.cow_copies >= 1
+    assert srv.metrics.spec_steps >= 1
+    assert srv.metrics.prefill_chunks >= 6
+    return [list(s.tokens) for s in states]
+
+
+@pytest.mark.parametrize(
+    "fallback", [None, "int8"], ids=["paged_kernel", "int8-falls-back"]
+)
+def test_paged_attention_kernel_through_engine(fallback):
+    """Under the kernel attention impl the compiled paged step takes the
+    Pallas paged attention and serves the dense path's greedy tokens; an
+    int8 KV cache falls back to the dense lines and says why. The path is
+    recorded on the engine and in the metrics snapshot."""
+    from deepspeed_tpu.ops.attention import attention_impl
+
+    serving = {"max_slots": 3, "token_budget": 8, "max_tokens": 48,
+               "paged": True, "page_size": 8,
+               "spec": {"enabled": True, "max_draft": 3}}
+    kw = {"kv_cache_dtype": "int8"} if fallback == "int8" else {}
+    dense_srv = ServingEngine(engine=_engine(**kw), serving=serving)
+    want = _mixed_replay(dense_srv)
+    assert dense_srv.attention_path == "dense"
+    assert "not the kernel one" in dense_srv.attention_fallback[0]
+    assert dense_srv.metrics.snapshot()["attention_paged_kernel"] == 0.0
+
+    srv = ServingEngine(engine=_engine(**kw), serving=serving)
+    assert srv.attention_path is None  # nothing compiled yet
+    with attention_impl("flash"):
+        got = _mixed_replay(srv)
+    assert got == want
+    if fallback is None:
+        assert srv.attention_path == "paged_kernel"
+        assert srv.attention_fallback == ()
+        assert srv.metrics.snapshot()["attention_paged_kernel"] == 1.0
+    else:
+        assert srv.attention_path == "dense"
+        assert srv.attention_fallback == ("int8 KV cache",)
+        assert srv.metrics.snapshot()["attention_paged_kernel"] == 0.0
+
+
+def test_paged_attention_alibi_falls_back_to_dense():
+    """BLOOM's ALiBi positions stay on the dense lines under the kernel
+    impl, with the reason on the engine."""
+    from deepspeed_tpu.models import bloom
+    from deepspeed_tpu.ops.attention import attention_impl
+
+    model = bloom("bloom-tiny", vocab_size=64, max_seq_len=64)
+    eng = deepspeed_tpu.init_inference(
+        model, dtype=jnp.float32, max_tokens=48, rng=jax.random.PRNGKey(2)
+    )
+    srv = ServingEngine(engine=eng, serving={
+        "max_slots": 2, "token_budget": 8, "max_tokens": 48,
+        "paged": True, "page_size": 8,
+    })
+    with attention_impl("flash"):
+        st = srv.submit(Request(request_id="b0", prompt=np.arange(11) % 64,
+                                max_new_tokens=3))
+        srv.run_until_idle()
+    assert len(st.tokens) == 3
+    assert srv.attention_path == "dense"
+    assert srv.attention_fallback == ("ALiBi positions",)
+    assert srv.step_traces == 1

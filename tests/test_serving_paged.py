@@ -119,6 +119,32 @@ def test_paged_equals_contiguous_sampled_tp2_int8_bitwise():
     assert srv_p.step_traces == 1
 
 
+def test_paged_attention_kernel_tp2_serves_the_dense_tokens():
+    """The [slots, chunk] Pallas attention under the kernel impl on a
+    tp=2 mesh (heads over tp through shard_map, pools sharded on their
+    head axis): greedy tokens equal the XLA path's, one trace, and the
+    engine says which attention its step took."""
+    from deepspeed_tpu.ops.attention import attention_impl
+
+    model = tiny_llama(num_heads=4, num_kv_heads=4)
+    topo = MeshTopology(dims=ParallelDims(tp=2), devices=jax.devices()[:2])
+    eng = _engine(model, topology=topo, rng=jax.random.PRNGKey(4))
+    r = np.random.RandomState(8)
+    prompts = [r.randint(0, 128, size=(n,)) for n in (5, 19, 11)]
+    news = [6, 4, 5]
+    dense_srv = _serving(eng, paged=True)
+    dense = _drive(dense_srv, prompts, news)
+    assert dense_srv.attention_path == "dense"
+    srv = _serving(eng, paged=True)
+    with attention_impl("flash"):
+        kernel = _drive(srv, prompts, news)
+    for i, (d, k) in enumerate(zip(dense, kernel)):
+        np.testing.assert_array_equal(d.output(), k.output(),
+                                      err_msg=f"r{i}")
+    assert srv.attention_path == "paged_kernel"
+    assert srv.step_traces == 1
+
+
 # ---------------------------------------------------------------------------
 # prefix cache + copy-on-write
 # ---------------------------------------------------------------------------

@@ -29,6 +29,7 @@ from deepspeed_tpu.ops.pallas import (
     flash_attention as fa,
     fused_adam,
     layernorm as ln,
+    paged_attention as pa,
     rmsnorm as rn,
 )
 
@@ -146,6 +147,33 @@ def test_paged_decode_compiles(one_chip, int8):
         ((pages, ps, KV, hd), cache_dt), ((B,), I32), ((B, per_slot), I32),
         ((pages, KV, ps, SCALE_LANES), F32),
         ((pages, KV, ps, SCALE_LANES), F32),
+    )
+    assert "tpu_custom_call" in text, text[:2000]
+
+
+# ------------------------------------------------- paged chunk attention
+@pytest.mark.parametrize(
+    "B,S,pages,per_slot",
+    [
+        (16, 128, 4097, 528),  # the benchmark's serving cells (Mixtral)
+        (8, 128, 1025, 66),    # chip_smoke's serve phase (1056 tokens a slot)
+        (8, 16, 257, 24),      # a small token budget (the rehearsal's)
+    ],
+    ids=["cell-16x128", "smoke-8x128", "budget-16"],
+)
+def test_paged_attention_compiles(one_chip, B, S, pages, per_slot):
+    H, KV, hd, ps = 32, 8, 128, 16
+
+    def step(q, k, v, cl, nn, pt):
+        return pa.paged_attention_kernel(
+            q, k, v, cl, pt, num_new=nn, interpret=False,
+        )
+
+    text = _compile(
+        step, one_chip,
+        ((B, S, H, hd), BF16), ((pages, ps, KV, hd), BF16),
+        ((pages, ps, KV, hd), BF16), ((B,), I32), ((B,), I32),
+        ((B, per_slot), I32),
     )
     assert "tpu_custom_call" in text, text[:2000]
 
