@@ -1,0 +1,12 @@
+"""Kernels on the serve path, window layers: device time of the paged
+attention calls of the window layers (the Pallas call the program names
+``paged_attention_window``, once a window layer) per traced step. Source:
+device trace."""
+
+from benchmarks import kinds_trace
+
+
+def read(ctx):
+    steps = kinds_trace.traced_steps(ctx)
+    s = ctx.reduced.op_seconds(r"^paged_attention_window") if steps else 0
+    return 1e3 * s / steps if s > 0 else None

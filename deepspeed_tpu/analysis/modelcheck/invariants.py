@@ -84,13 +84,15 @@ def _holders(sched) -> Tuple[Dict[int, int], List]:
     return exp, live_states
 
 
-def _check_pool(world, rid: int, sched) -> None:
-    pool = sched.pool
+def _check_counts(rid: int, pool, exp: Dict[int, int], what: str = "pool"
+                  ) -> None:
+    """H1 and H3 for one PagePool against independently recomputed
+    holders ``exp`` (page -> references)."""
     n = pool.num_pages
     # H1: conservation
     if pool.free_count + pool.live_count != n:
         raise CheckFailure(
-            "H1", f"r{rid}: pool conservation broken — free "
+            "H1", f"r{rid}: {what} conservation broken — free "
                   f"{pool.free_count} + live {pool.live_count} != {n}"
         )
     for p in pool._free:
@@ -100,10 +102,9 @@ def _check_pool(world, rid: int, sched) -> None:
                       f"{int(pool.refcount[p])}"
             )
     if (pool.refcount < 0).any():
-        raise CheckFailure("H1", f"r{rid}: negative refcount in pool")
+        raise CheckFailure("H1", f"r{rid}: negative refcount in {what}")
 
     # H3: refcount parity against independently recomputed holders
-    exp, live_states = _holders(sched)
     for p in range(n):
         actual = int(pool.refcount[p])
         want = exp.get(p, 0)
@@ -112,8 +113,40 @@ def _check_pool(world, rid: int, sched) -> None:
                     else "dangling holder (holder with no ref)")
             raise CheckFailure(
                 "H3", f"r{rid}: refcount parity broken on page {p}: "
-                      f"pool says {actual}, holders say {want} — {kind}"
+                      f"{what} says {actual}, holders say {want} — {kind}"
             )
+
+
+def _check_window_pool(rid: int, sched) -> None:
+    """H1, H3 and H4 for the window layers' pool (a model with window
+    attention layers keeps pages by layer kind): its only holders are the
+    slotted requests' ``win_pages``."""
+    pool = sched.window_pool
+    exp: Dict[int, int] = {}
+    for st in sched.slots:
+        for p in (st.win_pages if st is not None else ()):
+            if not (0 <= p < pool.num_pages):
+                raise CheckFailure(
+                    "H4", f"r{rid}: {st.request.request_id} references "
+                          f"out-of-range window page {p}"
+                )
+            exp[p] = exp.get(p, 0) + 1
+    _check_counts(rid, pool, exp, "window pool")
+    for st in sched.queue:
+        if st.win_pages:
+            raise CheckFailure(
+                "H4", f"r{rid}: queued {st.request.request_id} holds "
+                      f"window pages"
+            )
+
+
+def _check_pool(world, rid: int, sched) -> None:
+    pool = sched.pool
+    n = pool.num_pages
+    exp, live_states = _holders(sched)
+    _check_counts(rid, pool, exp)
+    if getattr(sched, "window_pool", None) is not None:
+        _check_window_pool(rid, sched)
 
     # H4: reference validity
     for st in live_states:

@@ -554,7 +554,8 @@ class ServingConfig:
                                  # shardplan prices the pool (R6)
     prefix_cache: bool = True    # hash-of-prefix → shared read-only pages
                                  # with refcounts + copy-on-write (paged
-                                 # mode only)
+                                 # mode only; off for a model with window
+                                 # layers, whose windows a hit would lack)
     host_pages: int = 0          # tiered KV (ISSUE 18): pinned-host page
                                  # capacity behind the HBM pool. 0 = off;
                                  # > 0 demotes cold/evicted pages to host

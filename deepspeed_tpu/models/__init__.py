@@ -1,4 +1,5 @@
 from .transformer import (  # noqa: F401
+    RopeTable,
     TransformerConfig,
     TransformerModel,
     make_lm_batch,
@@ -7,12 +8,14 @@ from .gpt2 import gpt2, gpt2_config  # noqa: F401
 from .llama import llama, llama_config  # noqa: F401
 from .bloom import bloom, bloom_config  # noqa: F401
 from .mixtral import mixtral, mixtral_config  # noqa: F401
+from .mellum import mellum, mellum_config  # noqa: F401
 
 MODEL_REGISTRY = {
     "gpt2": gpt2,
     "llama": llama,
     "bloom": bloom,
     "mixtral": mixtral,
+    "mellum": mellum,
 }
 
 

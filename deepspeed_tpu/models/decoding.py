@@ -26,6 +26,7 @@ from .transformer import (
     TransformerConfig,
     _mlp,
     _norm,
+    _qk_norm,
     _rope,
     alibi_slopes,
     lm_head_logits,
@@ -45,9 +46,10 @@ def record_attention_path():
     it took: yields a dict whose ``path`` reads ``paged_kernel`` (a Pallas
     kernel reads the page pool through the table), ``decode_kernel`` (the
     contiguous single-token Pallas kernel) or ``dense`` (the XLA lines),
-    with the ``reasons`` for a dense path. The choice is made at trace
-    time, so the serving engine opens this around its step's trace."""
-    rec = {"path": None, "reasons": ()}
+    with the ``reasons`` for a dense path, and under ``kinds`` the path of
+    each layer kind. The choice is made at trace time, so the serving
+    engine opens this around its step's trace."""
+    rec = {"path": None, "reasons": (), "kinds": {}}
     _path_recorders.append(rec)
     try:
         yield rec
@@ -55,9 +57,10 @@ def record_attention_path():
         _path_recorders.remove(rec)
 
 
-def _note_attention_path(path: str, reasons=()) -> None:
+def _note_attention_path(path: str, reasons=(), kind: str = "full") -> None:
     for rec in _path_recorders:
         rec["path"], rec["reasons"] = path, tuple(reasons)
+        rec["kinds"][kind] = path
 
 
 def _is_ragged(cache_len) -> bool:
@@ -108,8 +111,12 @@ def gather_verify_window(logits: jax.Array, num_new, spec_len,
     return jnp.take_along_axis(logits, idx[:, :, None], axis=1)
 
 
+WIN = "_win"  # suffix of the window layers' pool leaves and page table
+
+
 def init_paged_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
-                     dtype=jnp.bfloat16, quantized: bool = False) -> Cache:
+                     dtype=jnp.bfloat16, quantized: bool = False,
+                     window_pages: Optional[int] = None) -> Cache:
     """Block-paged KV pool for all layers (the serving engine's paged
     arena): ``k``/``v`` are [L, num_pages + 1, page_size, KV, hd] — one
     extra physical page at index ``num_pages`` is the NULL page, where
@@ -117,21 +124,34 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
     (its bytes are garbage by design and never attendable: every query
     masks at its own frontier). int8 storage carries per-(token, head)
     scales in the pre-transposed [L, P+1, KV, page_size, SL] layout the
-    decode kernel consumes."""
-    P1 = int(num_pages) + 1
-    shape = (cfg.num_layers, P1, page_size, cfg.kv_heads, cfg.hd)
-    if quantized:
-        sshape = (cfg.num_layers, P1, cfg.kv_heads, page_size, SCALE_LANES)
+    decode kernel consumes.
+
+    A model with window layers (``cfg.has_window``) keeps pages by layer
+    kind: ``k``/``v`` hold the full layers alone, and ``k_win``/``v_win``
+    [L_window, window_pages + 1, ...] the window layers, whose pages a
+    slot gives back once every query still to come is past them."""
+    def pool(layers, pages, sfx=""):
+        P1 = int(pages) + 1
+        shape = (layers, P1, page_size, cfg.kv_heads, cfg.hd)
+        if quantized:
+            sshape = (layers, P1, cfg.kv_heads, page_size, SCALE_LANES)
+            return {
+                "k" + sfx: jnp.zeros(shape, jnp.int8),
+                "v" + sfx: jnp.zeros(shape, jnp.int8),
+                "k_scale" + sfx: jnp.zeros(sshape, jnp.float32),
+                "v_scale" + sfx: jnp.zeros(sshape, jnp.float32),
+            }
         return {
-            "k": jnp.zeros(shape, jnp.int8),
-            "v": jnp.zeros(shape, jnp.int8),
-            "k_scale": jnp.zeros(sshape, jnp.float32),
-            "v_scale": jnp.zeros(sshape, jnp.float32),
+            "k" + sfx: jnp.zeros(shape, dtype),
+            "v" + sfx: jnp.zeros(shape, dtype),
         }
-    return {
-        "k": jnp.zeros(shape, dtype),
-        "v": jnp.zeros(shape, dtype),
-    }
+
+    if not cfg.has_window:
+        return pool(cfg.num_layers, num_pages)
+    if window_pages is None:
+        raise ValueError("a model with window layers needs window_pages")
+    return {**pool(cfg.kind_count("full"), num_pages),
+            **pool(cfg.kind_count("window"), window_pages, WIN)}
 
 
 def _page_indices(cache_len: jax.Array, S: int, page_table: jax.Array,
@@ -276,7 +296,8 @@ def _out_proj(x: jax.Array, w) -> jax.Array:
     return tp_out_proj(x, w)
 
 
-def _qkv(cfg: TransformerConfig, p: Params, x: jax.Array, positions: jax.Array):
+def _qkv(cfg: TransformerConfig, p: Params, x: jax.Array, positions: jax.Array,
+         kind: str = "full"):
     from ..parallel.tensor_overlap import tp_in_proj
 
     B, S, _ = x.shape
@@ -291,8 +312,10 @@ def _qkv(cfg: TransformerConfig, p: Params, x: jax.Array, positions: jax.Array):
         q = q + p["bq"].reshape(1, 1, nh, hd)
         k = k + p["bk"].reshape(1, 1, nkv, hd)
         v = v + p["bv"].reshape(1, 1, nkv, hd)
+    if cfg.qk_norm:
+        q, k = _qk_norm(cfg, p, q, k)
     if cfg.pos_embedding == "rope":
-        q, k = _rope(q, k, positions, cfg.rope_theta)
+        q, k = _rope(q, k, positions, cfg.rope_of(kind))
     return q, k, v
 
 
@@ -300,7 +323,7 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
                       positions: jax.Array, k_cache: jax.Array,
                       v_cache: jax.Array, cache_len,
                       k_scale=None, v_scale=None, page_table=None,
-                      num_new=None):
+                      num_new=None, kind: str = "full"):
     """Attend new tokens (x, [B,S,D]) against cache[:cache_len] + themselves.
 
     Returns (out, new_k_cache, new_v_cache[, new_k_scale, new_v_scale]).
@@ -329,10 +352,17 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
     arena would, so the attention math below is byte-for-byte the dense
     path. ``num_new`` [B] (optional) counts each row's real tokens: the
     kernel stops at the last key a real token needs.
+
+    ``kind`` "window" (a layer of ``cfg.layer_pattern``) bounds every
+    query to its last ``cfg.attn_window`` keys: the paged kernel then
+    starts at the page of its first row's oldest visible key, and the
+    other kernels (which know no window) leave such a layer to the XLA
+    lines.
     """
     B, S, _ = x.shape
     nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.hd
-    q, k, v = _qkv(cfg, p, x, positions)
+    window = cfg.window_of(kind)
+    q, k, v = _qkv(cfg, p, x, positions, kind)
 
     quantized = k_scale is not None
     paged = page_table is not None
@@ -390,7 +420,7 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
 
     if paged:
         out = None
-        if kernel_ok and S == 1:
+        if kernel_ok and S == 1 and window is None:
             # single-token paged decode: the Pallas kernel gathers K/V
             # page-by-page through the table (scalar prefetch drives the
             # block index map) — no [B, capacity] view materializes
@@ -413,11 +443,13 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
 
             out, why_dense = paged_attention(
                 q, k_cache, v_cache, cache_len, page_table, num_new=num_new,
+                window=window,
+                name="paged_attention_" + kind if cfg.has_window else None,
             )
         if out is not None:
-            _note_attention_path("paged_kernel")
+            _note_attention_path("paged_kernel", kind=kind)
             return project(out)
-        _note_attention_path("dense", why_dense)
+        _note_attention_path("dense", why_dense, kind)
         # XLA path: gather the per-slot contiguous views (post-write, so
         # they reproduce the dense arena bitwise) and fall through to the
         # shared attention math below
@@ -430,7 +462,9 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
     else:
         k_att, v_att, ks_att, vs_att = k_cache, v_cache, k_scale, v_scale
 
-    if not paged and isinstance(cache_len, int) and cache_len == 0 and S > 1:
+    if window is not None:
+        kernel_ok = False  # the contiguous kernels know no window
+    elif not paged and isinstance(cache_len, int) and cache_len == 0 and S > 1:
         # fresh prefill: the new tokens only attend among themselves, so the
         # registered attention impl applies (kernel injection: Pallas flash
         # prefill on TPU); the decode matvec below stays the einsum path
@@ -453,21 +487,24 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
             q, k_att, v_att, cache_len, k_scale=ks_att, v_scale=vs_att,
         )
         if out is not None:
-            _note_attention_path("decode_kernel")
+            _note_attention_path("decode_kernel", kind=kind)
             return project(out)
     if not paged:
-        _note_attention_path("dense", why_dense or ["a contiguous cache"])
+        _note_attention_path("dense", why_dense or ["a contiguous cache"],
+                             kind)
     return project(_dense_cached_attention(
-        cfg, q, k_att, v_att, cache_len, ks_att, vs_att
+        cfg, q, k_att, v_att, cache_len, ks_att, vs_att, window=window
     ))
 
 
 def _dense_cached_attention(cfg: TransformerConfig, q: jax.Array,
                             k_att: jax.Array, v_att: jax.Array, cache_len,
-                            ks_att=None, vs_att=None) -> jax.Array:
+                            ks_att=None, vs_att=None,
+                            window: Optional[int] = None) -> jax.Array:
     """The XLA lines: q [B,S,H,hd] against a per-row contiguous K/V view
     [B, S_max, KV, hd] (int8 with its [B, KV, S_max, SL] scales), each row
-    masked at its own frontier ``kpos <= cache_len + i``. float32
+    masked at its own frontier ``kpos <= cache_len + i`` and, with
+    ``window``, below it ``kpos > cache_len + i - window``. float32
     throughout; the oracle for the Pallas kernels and the path for ALiBi,
     int8 and contiguous caches. Returns [B,S,H,hd] float32."""
     S = q.shape[1]
@@ -497,7 +534,10 @@ def _dense_cached_attention(cfg: TransformerConfig, q: jax.Array,
         logits = logits + slopes[None, :, None, None] * (
             -jnp.abs(kpos.astype(jnp.float32) - qpos.astype(jnp.float32))
         )
-    logits = jnp.where(kpos <= qpos, logits, -1e30)  # causal + cache bound
+    seen = kpos <= qpos  # causal + cache bound
+    if window is not None:
+        seen = seen & (kpos > qpos - window)
+    logits = jnp.where(seen, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, vf)
 
@@ -506,6 +546,7 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
                        cache: Cache, cache_len, *,
                        dtype=jnp.bfloat16,
                        page_table=None,
+                       page_table_win=None,
                        token_valid=None,
                        num_new=None,
                        return_moe_stats: bool = False):
@@ -521,7 +562,9 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
     pool form (init_paged_cache): every layer scatters its chunk through
     the shared table and attends the slot's pages (_cached_attention).
     ``num_new`` [B] (the serving engine's real tokens per row) lets the
-    paged kernel stop at the last key a real token needs.
+    paged kernel stop at the last key a real token needs. A model with
+    window layers brings ``page_table_win``, the table of the window
+    layers' pool (entries behind a slot's window point at the NULL page).
 
     MoE models route the MLP through the serving expert path
     (moe/sharded_moe.moe_serving_mlp): slot-ragged gather dispatch over
@@ -555,64 +598,84 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
 
     layers = cast(params["layers"])
 
-    quantized = "k_scale" in cache
     moe = cfg.is_moe
     collect_moe = bool(return_moe_stats) and moe
+    # Layers of several kinds scan whole periods of the pattern, a period's
+    # layers unrolled with their kinds static; one kind scans single layers
+    # with the leaves as they are. Under a page table a model with window
+    # layers keeps two pools (init_paged_cache): a layer reads the leaves
+    # and the table of its kind.
+    kinds = cfg.layer_pattern or ("full",)
+    period = len(kinds)
+    split = page_table is not None and cfg.has_window
+    tables = {"": page_table, WIN: page_table_win}
+    place = []  # (leaf suffix, index inside the period's share of that pool)
+    for j, kind in enumerate(kinds):
+        sfx = WIN if split and kind == "window" else ""
+        place.append((sfx, sum(1 for s, _ in place if s == sfx)))
 
-    def body(carry, scanned):
-        h = carry
-        if quantized:
-            layer, kc, vc, ks, vs = scanned
-            a, kc, vc, ks, vs = _cached_attention(
+    def grouped(a):
+        return a if period == 1 else a.reshape(
+            cfg.num_layers // period, -1, *a.shape[1:])
+
+    def body(h, scanned):
+        group, pools = scanned
+        new = {name: [] for name in pools}
+        stats = []
+        for j, (kind, (sfx, at)) in enumerate(zip(kinds, place)):
+            pick = (lambda a: a) if period == 1 else (lambda a, i=at: a[i])
+            # a period's layers are read from the whole stack one at a
+            # time (``group`` is then the period's index): a period-sized
+            # slice of the weights would be copied every trip
+            layer = group if period == 1 else jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(
+                    a, group * period + j, 0, keepdims=False), layers)
+            names = [n + sfx for n in ("k", "v", "k_scale", "v_scale")
+                     if n + sfx in pools]
+            a, *updated = _cached_attention(
                 cfg, layer["attn"], _norm(cfg, layer["ln1"], h), positions,
-                kc, vc, cache_len, ks, vs, page_table=page_table,
-                num_new=num_new,
+                *(pick(pools[n]) for n in names[:2]), cache_len,
+                *(pick(pools[n]) for n in names[2:]),
+                page_table=tables[sfx], num_new=num_new, kind=kind,
             )
-            new_cache = (kc, vc, ks, vs)
-        else:
-            layer, kc, vc = scanned
-            a, kc, vc = _cached_attention(
-                cfg, layer["attn"], _norm(cfg, layer["ln1"], h), positions,
-                kc, vc, cache_len, page_table=page_table, num_new=num_new,
-            )
-            new_cache = (kc, vc)
-        h = h + a
-        normed = _norm(cfg, layer["ln2"], h)
-        if moe:
-            from ..moe.sharded_moe import moe_serving_mlp
+            for n, leaf in zip(names, updated):
+                new[n].append(leaf)
+            h = h + a
+            normed = _norm(cfg, layer["ln2"], h)
+            if moe:
+                from ..moe.sharded_moe import moe_serving_mlp
 
-            # the routed decode path: capacity from the STATIC budget
-            # (token_budget for the slot engine, B·S for lockstep),
-            # padded rows to the null expert
-            m, lstats = moe_serving_mlp(
-                cfg, layer["mlp"], normed, token_valid=token_valid,
-                budget_tokens=S if token_valid is not None else B * S,
-            )
-        else:
-            m, _aux = _mlp(cfg, layer["mlp"], normed, rng=None, train=False)
-            lstats = None
-        h = h + m
-        h = constrain(h, ("dp", "fsdp"), None, None)
-        ys = new_cache + (lstats,) if collect_moe else new_cache
-        return h, ys
+                # the routed decode path: capacity from the STATIC budget
+                # (token_budget for the slot engine, B·S for lockstep),
+                # padded rows to the null expert
+                m, lstats = moe_serving_mlp(
+                    cfg, layer["mlp"], normed, token_valid=token_valid,
+                    budget_tokens=S if token_valid is not None else B * S,
+                )
+                stats.append(lstats)
+            else:
+                m, _aux = _mlp(cfg, layer["mlp"], normed, rng=None,
+                               train=False)
+            h = h + m
+            h = constrain(h, ("dp", "fsdp"), None, None)
+        out = {n: v[0] if period == 1 else jnp.stack(v)
+               for n, v in new.items()}
+        if collect_moe:
+            lstats = stats[0] if period == 1 else jax.tree.map(
+                lambda *t: jnp.stack(t), *stats)
+            return h, (out, lstats)
+        return h, out
 
-    if quantized:
-        scanned = (layers, cache["k"], cache["v"], cache["k_scale"],
-                   cache["v_scale"])
-        x, ys = lax.scan(body, x, scanned)
-        if collect_moe:
-            k_new, v_new, ks_new, vs_new, lstats = ys
-        else:
-            k_new, v_new, ks_new, vs_new = ys
-        new_cache = {"k": k_new, "v": v_new, "k_scale": ks_new,
-                     "v_scale": vs_new}
-    else:
-        x, ys = lax.scan(body, x, (layers, cache["k"], cache["v"]))
-        if collect_moe:
-            k_new, v_new, lstats = ys
-        else:
-            k_new, v_new = ys
-        new_cache = {"k": k_new, "v": v_new}
+    x, ys = lax.scan(
+        body, x, (layers if period == 1
+                  else jnp.arange(cfg.num_layers // period),
+                  {n: grouped(a) for n, a in cache.items()}))
+    if collect_moe:
+        ys, lstats = ys
+        if period > 1:  # [periods, period, ...] -> one row a layer
+            lstats = jax.tree.map(
+                lambda a: a.reshape(-1, *a.shape[2:]), lstats)
+    new_cache = {n: a.reshape(cache[n].shape) for n, a in ys.items()}
     x = _norm(cfg, cast(params["final_norm"]), x)
     logits = lm_head_logits(cfg, params, x)
     if return_moe_stats:

@@ -34,6 +34,41 @@ from .sharding import constrain, current_topology
 Params = Dict[str, Any]
 
 
+LAYER_KINDS = ("full", "window")
+
+
+@dataclass(frozen=True)
+class RopeTable:
+    """One rotary table: plain (``theta`` alone) or YaRN-scaled (Peng et
+    al. 2023, as HF's ``_compute_yarn_parameters``): frequencies above the
+    ``beta_fast`` rotations of the original length are kept, those below
+    ``beta_slow`` divided by ``factor``, a linear ramp between; cos and
+    sin are both multiplied by ``attention_factor``."""
+
+    theta: float = 10000.0
+    factor: float = 1.0           # 1.0 = plain rotary
+    original_len: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def inv_freq(self, hd: int) -> np.ndarray:
+        extra = self.theta ** -(np.arange(0, hd, 2, dtype=np.float64) / hd)
+        if self.factor == 1.0:
+            return extra.astype(np.float32)
+
+        def dim(rotations):
+            return hd * math.log(self.original_len / (
+                rotations * 2 * math.pi)) / (2 * math.log(self.theta))
+
+        low = max(math.floor(dim(self.beta_fast)), 0)
+        high = min(math.ceil(dim(self.beta_slow)), hd - 1)
+        ramp = np.clip((np.arange(hd // 2) - low) / max(high - low, 1e-3),
+                       0.0, 1.0)
+        return (extra / self.factor * ramp + extra * (1 - ramp)
+                ).astype(np.float32)
+
+
 @dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
@@ -64,7 +99,29 @@ class TransformerConfig:
     # PR-MoE paper): a dense MLP runs alongside the routed experts and a
     # learned 2-way per-token coefficient mixes the two outputs.
     moe_use_residual: bool = False
+    # Layer kinds: a repeating period of "window" | "full" attention layers
+    # (() = every layer full). A window layer's query i sees key j iff
+    # 0 <= i - j < attn_window. ``rope_tables`` gives a kind its own rotary
+    # table (absent = the plain table of ``rope_theta``).
+    layer_pattern: Tuple[str, ...] = ()
+    attn_window: int = 0
+    rope_tables: Tuple[Tuple[str, "RopeTable"], ...] = ()
+    qk_norm: bool = False  # RMSNorm over each head of q and k, before rotary
     name: str = "transformer"
+
+    def __post_init__(self):
+        bad = set(self.layer_pattern) - set(LAYER_KINDS)
+        if bad:
+            raise ValueError(
+                f"layer_pattern kinds {sorted(bad)} (must be of {LAYER_KINDS})"
+            )
+        if self.layer_pattern and self.num_layers % len(self.layer_pattern):
+            raise ValueError(
+                f"num_layers {self.num_layers} is not whole periods of the "
+                f"{len(self.layer_pattern)}-layer pattern"
+            )
+        if "window" in self.layer_pattern and self.attn_window < 1:
+            raise ValueError("a window layer needs attn_window >= 1")
 
     @property
     def kv_heads(self) -> int:
@@ -81,6 +138,23 @@ class TransformerConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def has_window(self) -> bool:
+        return "window" in self.layer_pattern
+
+    def kind_count(self, kind: str) -> int:
+        """Layers of ``kind`` in the whole stack."""
+        if not self.layer_pattern:
+            return self.num_layers if kind == "full" else 0
+        return self.layer_pattern.count(kind) * (
+            self.num_layers // len(self.layer_pattern))
+
+    def window_of(self, kind: str) -> Optional[int]:
+        return self.attn_window if kind == "window" else None
+
+    def rope_of(self, kind: str) -> "RopeTable":
+        return dict(self.rope_tables).get(kind) or RopeTable(self.rope_theta)
 
     def num_params(self) -> int:
         """Analytic parameter count (for flops profiler / partition planner)."""
@@ -102,6 +176,8 @@ class TransformerConfig:
             biases += self.num_heads * self.hd + 2 * self.kv_heads * self.hd + d
             if not self.is_moe and self.activation != "swiglu":
                 biases += self.ffn + d
+        if self.qk_norm:
+            biases += 2 * self.hd
         per_layer = qkvo + mlp + biases + 2 * ln_width
         embed = v * d + (self.max_seq_len * d if self.pos_embedding == "learned" else 0)
         if self.embed_norm:
@@ -152,6 +228,9 @@ def init(cfg: TransformerConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
     if cfg.use_bias:
         for nm, width in (("bq", nh * hd), ("bk", nkv * hd), ("bv", nkv * hd), ("bo", d)):
             attn[nm] = jnp.zeros((L, width), dtype)
+    if cfg.qk_norm:
+        attn["q_norm"] = {"scale": jnp.ones((L, hd), dtype)}
+        attn["k_norm"] = {"scale": jnp.ones((L, hd), dtype)}
 
     if cfg.is_moe:
         E = cfg.num_experts
@@ -202,19 +281,43 @@ def _norm(cfg: TransformerConfig, p: Params, x: jax.Array) -> jax.Array:
     ).astype(x.dtype)
 
 
-def _rope(q: jax.Array, k: jax.Array, positions: jax.Array, theta: float):
-    """Rotary embeddings; q/k: [B, S, H, hd], positions: [B, S]."""
+def _rope(q: jax.Array, k: jax.Array, positions: jax.Array,
+          table: RopeTable):
+    """Rotary embeddings by ``table`` (a layer kind's own, see
+    :class:`RopeTable`); q/k: [B, S, H, hd], positions: [B, S]."""
     hd = q.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    mscale = table.attention_factor
+    if table.factor == 1.0:
+        # the plain table stays the float32 expression it was before tables
+        # had kinds (``inv_freq`` works in float64 and differs in the last
+        # bit), so a one-kind model's compiled program is unchanged
+        freqs = 1.0 / (
+            table.theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    else:
+        freqs = jnp.asarray(table.inv_freq(hd))
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, hd/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
 
     def rot(x):
         x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
         return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
     return rot(q), rot(k)
+
+
+def _qk_norm(cfg: TransformerConfig, p: Params, q: jax.Array, k: jax.Array):
+    """RMSNorm over the head dim of every q and k head (``cfg.qk_norm``),
+    each with its learned [hd] vector, before the rotary embedding."""
+    def one(x, scale):
+        x32 = x.astype(jnp.float32)
+        ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+        return (x32 * lax.rsqrt(ms + cfg.norm_eps)
+                * scale.astype(jnp.float32)).astype(x.dtype)
+
+    return one(q, p["q_norm"]["scale"]), one(k, p["k_norm"]["scale"])
 
 
 def alibi_slopes(num_heads: int) -> np.ndarray:
@@ -230,7 +333,7 @@ def alibi_slopes(num_heads: int) -> np.ndarray:
 
 def _attention(cfg: TransformerConfig, p: Params, x: jax.Array, positions: jax.Array,
                segment_ids: Optional[jax.Array],
-               pos_default: bool = True) -> jax.Array:
+               pos_default: bool = True, kind: str = "full") -> jax.Array:
     from ..ops.attention import attention as attn_op
     from ..parallel.tensor_overlap import tp_in_proj, tp_out_proj
 
@@ -246,8 +349,10 @@ def _attention(cfg: TransformerConfig, p: Params, x: jax.Array, positions: jax.A
         q = q + p["bq"].reshape(1, 1, nh, hd)
         k = k + p["bk"].reshape(1, 1, nkv, hd)
         v = v + p["bv"].reshape(1, 1, nkv, hd)
+    if cfg.qk_norm:
+        q, k = _qk_norm(cfg, p, q, k)
     if cfg.pos_embedding == "rope":
-        q, k = _rope(q, k, positions, cfg.rope_theta)
+        q, k = _rope(q, k, positions, cfg.rope_of(kind))
 
     # ALiBi rides as per-head slopes: the flash kernel and the ring path
     # build -slope*|Δpos| from sequence indices in-kernel, so the [B,H,S,S]
@@ -264,6 +369,13 @@ def _attention(cfg: TransformerConfig, p: Params, x: jax.Array, positions: jax.A
             bias = jnp.asarray(alibi_slopes(nh))[None, :, None, None] * (
                 -jnp.abs(rel)
             )[:, None, :, :]  # [B,H,S,S]
+    if kind == "window":
+        # the lower edge of a window layer as an additive mask (the causal
+        # upper edge stays the attention op's): this path is apply's and
+        # training's; serving masks inside the paged kernel
+        dist = positions[:, :, None] - positions[:, None, :]  # query - key
+        edge = jnp.where(dist < cfg.attn_window, 0.0, -1e30)[:, None]
+        bias = edge if bias is None else bias + edge
 
     topo = current_topology()
     if topo is not None and topo.sp_size > 1:
@@ -322,7 +434,7 @@ def _mlp(cfg: TransformerConfig, p: Params, x: jax.Array, rng: Optional[jax.Arra
 
 def _block(cfg: TransformerConfig, layer: Params, x: jax.Array, positions: jax.Array,
            segment_ids: Optional[jax.Array], rng: Optional[jax.Array], train: bool,
-           pos_default: bool = True):
+           pos_default: bool = True, kind: str = "full"):
     from jax.ad_checkpoint import checkpoint_name
 
     from ..parallel.tensor_overlap import seq_shard_axes
@@ -333,7 +445,7 @@ def _block(cfg: TransformerConfig, layer: Params, x: jax.Array, positions: jax.A
     # collectives between projections (Megatron-SP boundaries)
     seq_ax = seq_shard_axes(x)
     h = _attention(cfg, layer["attn"], _norm(cfg, layer["ln1"], x), positions,
-                   segment_ids, pos_default)
+                   segment_ids, pos_default, kind)
     h = checkpoint_name(h, "attn_out")  # selective remat anchor (attn_only)
     x = x + h
     x = constrain(x, ("dp", "fsdp"), seq_ax, None)
@@ -379,45 +491,62 @@ def apply_layer_stack(cfg: TransformerConfig, layers: Params, x: jax.Array,
     if use_ltd and rng is None:
         raise ValueError("random_ltd needs an rng to sample token subsets")
 
+    # One body for every model: it runs a whole period of the layer pattern,
+    # the kinds static and the layers of the period unrolled. A one-kind
+    # model is the period of one: its stack, keys and keep probabilities are
+    # scanned as they are (no reshape, no index), so its program is what it
+    # was before patterns existed.
+    pattern = cfg.layer_pattern or ("full",)
+    period = len(pattern)
+    groups = num_layers // period
+
+    def grouped(a):
+        return a if period == 1 else a.reshape(groups, period, *a.shape[1:])
+
+    def member(a, j):
+        return a if period == 1 else a[j]
+
     def body(carry, inp, *, ltd: bool = False):
         x, aux = carry
-        if use_pld:
-            layer, key, keep_p = inp
-        else:
-            layer, key = inp
-        if ltd:
-            from ..data_pipeline.random_ltd import (
-                gather_tokens,
-                sample_token_subset,
-                scatter_tokens,
-            )
+        for j, kind in enumerate(pattern):
+            layer = jax.tree.map(lambda t: member(t, j), inp[0])
+            key = member(inp[1], j)
+            if ltd:
+                from ..data_pipeline.random_ltd import (
+                    gather_tokens,
+                    sample_token_subset,
+                    scatter_tokens,
+                )
 
-            B, S = x.shape[:2]
-            idx = sample_token_subset(
-                jax.random.fold_in(key, 11), B, S, int(ltd_keep)
-            )
-            x_kept = gather_tokens(x, idx)
-            pos_kept = jnp.take_along_axis(positions, idx, axis=1)
-            seg_kept = (
-                jnp.take_along_axis(segment_ids, idx, axis=1)
-                if segment_ids is not None
-                else None
-            )
-            # gathered positions are no longer sequence indices: pos_default
-            # False routes ALiBi through the exact positions-derived bias
-            out_kept, a = _block(
-                cfg, layer, x_kept, pos_kept, seg_kept, key, train,
-                pos_default=False,
-            )
-            out = scatter_tokens(x, out_kept, idx)
-        else:
-            out, a = _block(cfg, layer, x, positions, segment_ids, key, train,
-                            pos_default=pos_default)
-        if use_pld:
-            keep = jax.random.bernoulli(jax.random.fold_in(key, 7), keep_p)
-            out = jnp.where(keep, out, x)
-            a = jnp.where(keep, a, 0.0)
-        return (out, aux + a), None
+                B, S = x.shape[:2]
+                idx = sample_token_subset(
+                    jax.random.fold_in(key, 11), B, S, int(ltd_keep)
+                )
+                x_kept = gather_tokens(x, idx)
+                pos_kept = jnp.take_along_axis(positions, idx, axis=1)
+                seg_kept = (
+                    jnp.take_along_axis(segment_ids, idx, axis=1)
+                    if segment_ids is not None
+                    else None
+                )
+                # gathered positions are no longer sequence indices:
+                # pos_default False routes ALiBi through the exact
+                # positions-derived bias
+                out_kept, a = _block(
+                    cfg, layer, x_kept, pos_kept, seg_kept, key, train,
+                    pos_default=False, kind=kind,
+                )
+                out = scatter_tokens(x, out_kept, idx)
+            else:
+                out, a = _block(cfg, layer, x, positions, segment_ids, key,
+                                train, pos_default=pos_default, kind=kind)
+            if use_pld:
+                keep = jax.random.bernoulli(
+                    jax.random.fold_in(key, 7), member(inp[2], j))
+                out = jnp.where(keep, out, x)
+                a = jnp.where(keep, a, 0.0)
+            x, aux = out, aux + a
+        return (x, aux), None
 
     import functools
 
@@ -435,11 +564,16 @@ def apply_layer_stack(cfg: TransformerConfig, layers: Params, x: jax.Array,
         if rng is not None
         else jnp.zeros((num_layers, 2), jnp.uint32)
     )
+    xs_all = (jax.tree.map(grouped, layers), grouped(keys)) + (
+        (grouped(pld_keep),) if use_pld else ())
 
-    def seg_xs(lo, hi):
-        sl = lambda a: a[lo:hi]
-        parts = (jax.tree.map(sl, layers), keys[lo:hi])
-        return parts + ((pld_keep[lo:hi],) if use_pld else ())
+    def seg_xs(lo, hi):  # layers lo..hi, whole periods
+        if lo % period or hi % period:
+            raise ValueError(
+                f"layers {lo}..{hi} cut a period of the layer pattern "
+                f"{pattern}: a scan runs whole periods"
+            )
+        return jax.tree.map(lambda a: a[lo // period:hi // period], xs_all)
 
     # ZeRO-3 one-layer-ahead parameter prefetch (runtime/zero/prefetch.py):
     # with the scope active, the scan carries a rotating gathered-params
@@ -448,6 +582,11 @@ def apply_layer_stack(cfg: TransformerConfig, layers: Params, x: jax.Array,
     from ..runtime.zero.prefetch import current_prefetch
 
     z3_puts = current_prefetch()
+    if z3_puts is not None and period > 1:
+        raise NotImplementedError(
+            "ZeRO-3 layer prefetch gathers one layer's slice a tick; a model "
+            f"whose layers follow the pattern {pattern} scans periods"
+        )
 
     def seg_scan(bodyfn, carry, lo, hi):
         xs = seg_xs(lo, hi)
@@ -645,6 +784,9 @@ def tp_partition_specs(cfg: TransformerConfig, tp_divides_kv: bool = True) -> Pa
     if cfg.use_bias:
         attn.update({"bq": P(None, "tp"), "bk": P(None, kv_tp),
                      "bv": P(None, kv_tp), "bo": P(None, None)})
+    if cfg.qk_norm:
+        attn["q_norm"] = {"scale": P(None, None)}
+        attn["k_norm"] = {"scale": P(None, None)}
     if cfg.is_moe:
         mlp = {
             "router": P(None, None, None),

@@ -18,7 +18,10 @@ hd]`` tile is then a strided read of that buffer. The G query heads of
 one KV head stack as ``[S * G, hd]`` rows against one ``[block_k, hd]`` K
 tile (no head is repeated); fp32 online softmax in VMEM via
 decode_attention's ``_tile_update``. Blocks wholly below the chunk's first
-row skip the causal mask.
+row skip the causal mask. With ``window`` (a window layer: row ``i`` sees
+only its last ``window`` keys) the loop starts at the block of row 0's
+oldest visible key, and the blocks that a window's lower edge crosses are
+masked there too.
 
 Layouts: q ``[B, S, H, hd]``, pools ``[P+1, page_size, KV, hd]``
 (init_paged_cache), page_table ``[B, max_pages]`` and the per-row frontiers
@@ -33,6 +36,7 @@ from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -78,7 +82,8 @@ def _head_tiles(buf, KV: int):
 def _paged_attention_kernel(pt_ref, cl_ref, nn_ref, q_ref, k_hbm, v_hbm,
                             o_ref, k_buf, v_buf, sems, kh_scr, vh_scr,
                             m_scr, l_scr, acc_scr,
-                            *, scale, page_size, pages_per_block, group):
+                            *, scale, page_size, pages_per_block, group,
+                            window=None):
     KV, SG, hd = q_ref.shape[1:]
     ps, ppb = page_size, pages_per_block
     bk = ps * ppb
@@ -130,11 +135,22 @@ def _paged_attention_kernel(pt_ref, cl_ref, nn_ref, q_ref, k_hbm, v_hbm,
         n_blocks = jnp.minimum(pl.cdiv(cl + nn, bk), pl.cdiv(mp * ps, bk))
         # blocks wholly at or below row 0's frontier need no mask
         n_full = jnp.minimum((cl + 1) // bk, n_blocks)
+        if window is None:
+            first = lo_end = 0
+        else:
+            # row 0 sees keys from cl - window + 1 on; the last real row's
+            # lower edge, cl + nn - window, is the highest: a block that
+            # starts at or past it is below no real row's window
+            first = jnp.maximum(cl - (window - 1), 0) // bk
+            lo_end = jnp.clip(
+                pl.cdiv(jnp.maximum(cl + nn - window, 0), bk), first,
+                n_blocks)
+            n_full = jnp.clip(n_full, lo_end, n_blocks)
 
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
-        start_fetch(0, 0)
+        start_fetch(first, 0 if window is None else lax.rem(first, 2))
 
         def block(i, carry, *, masked):
             slot = lax.rem(i, 2)
@@ -167,13 +183,19 @@ def _paged_attention_kernel(pt_ref, cl_ref, nn_ref, q_ref, k_hbm, v_hbm,
                     q_ref[0, kv], kh_scr[kv], vh_scr[kv], None, None, start,
                     frontier, scale,
                     m_scr.at[kv], l_scr.at[kv], acc_scr.at[kv],
+                    lo=frontier - window
+                    if masked and window is not None else None,
                 )
                 return c
 
             lax.fori_loop(0, KV, head, 0)
             return carry
 
-        lax.fori_loop(0, n_full, functools.partial(block, masked=False), 0)
+        if window is not None:
+            lax.fori_loop(first, lo_end,
+                          functools.partial(block, masked=True), 0)
+        lax.fori_loop(lo_end, n_full,
+                      functools.partial(block, masked=False), 0)
         lax.fori_loop(n_full, n_blocks,
                       functools.partial(block, masked=True), 0)
 
@@ -203,6 +225,30 @@ def _vmem_bytes(S, G, KV, hd, page_size, pages_per_block, q_bytes, kv_bytes):
     return scratch + kv_bufs + q_out + temps
 
 
+def key_counts(cache_len, num_new, page_size: int, max_pages: int,
+               window: Optional[int] = None,
+               block_k: int = DEFAULT_BLOCK_K) -> Tuple[int, int]:
+    """What one call of the kernel has to do for host vectors ``cache_len``
+    and ``num_new`` [B], by the same arithmetic as its loop: (attended, the
+    keys visible to every real query row, summed; fetched, the keys of the
+    blocks the slots' loops read). With ``block_k`` the page size, fetched
+    is the keys of the pages that hold a visible key: what any paged read
+    needs, which is what the engine books."""
+    cl = np.asarray(cache_len, np.int64)
+    nn = np.asarray(num_new, np.int64)
+    bk = page_size * _block_pages(block_k, page_size, max_pages)
+    attended = nn * cl + nn * (nn + 1) // 2
+    first = np.zeros_like(cl)
+    if window is not None:
+        # rows whose frontier passes the window see ``window`` keys
+        over = np.clip(cl + nn - window, 0, nn)
+        attended -= over * (cl + nn - window) - over * (over - 1) // 2
+        first = np.maximum(cl - (window - 1), 0) // bk
+    n_blocks = np.minimum(-(-(cl + nn) // bk), -(-(max_pages * page_size) // bk))
+    fetched = np.where(nn > 0, (n_blocks - first) * bk, 0)
+    return int(attended.sum()), int(fetched.sum())
+
+
 def _frontiers(B: int, S: int, cache_len, num_new):
     """Per-slot int32 [B] (frontier, real rows): a scalar frontier
     broadcasts, no ``num_new`` means every row is real."""
@@ -216,15 +262,18 @@ def _frontiers(B: int, S: int, cache_len, num_new):
 
 def paged_attention_kernel(q, k_pool, v_pool, cache_len, page_table, *,
                            num_new=None, block_k: int = DEFAULT_BLOCK_K,
-                           interpret: Optional[bool] = None):
+                           interpret: Optional[bool] = None,
+                           window: Optional[int] = None,
+                           name: Optional[str] = None):
     """q [B,S,H,hd] chunk queries vs a block-paged KV pool
     k/v_pool [P+1, page_size, KV, hd] addressed through per-slot page
     tables [B, max_pages]. ``cache_len`` [B] is each slot's frontier BEFORE
     the chunk (row i attends kpos <= cache_len[b] + i; the caller has
     already scattered the chunk's keys). ``num_new`` [B] (optional) is the
     count of real rows: the loop stops at the last key a real row needs,
-    and a slot with none is skipped (its output rows are zeros). Returns
-    [B,S,H,hd]."""
+    and a slot with none is skipped (its output rows are zeros). ``window``
+    (static) bounds row i to ``kpos > cache_len[b] + i - window`` as well;
+    ``name`` is the call's name in a device trace. Returns [B,S,H,hd]."""
     B, S, H, hd = q.shape
     ps, KV = k_pool.shape[1], k_pool.shape[2]
     mp = page_table.shape[1]
@@ -263,7 +312,7 @@ def paged_attention_kernel(q, k_pool, v_pool, cache_len, page_table, *,
     out = pl.pallas_call(
         functools.partial(
             _paged_attention_kernel, scale=1.0 / (hd**0.5), page_size=ps,
-            pages_per_block=ppb, group=G,
+            pages_per_block=ppb, group=G, window=window,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, SG, hd), q.dtype),
@@ -272,13 +321,14 @@ def paged_attention_kernel(q, k_pool, v_pool, cache_len, page_table, *,
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
-        name="paged_attention",
+        name=name or "paged_attention",
     )(pt, cl, nn, qg, k_pool, v_pool)
     return out.reshape(B, KV, S, G, hd).swapaxes(1, 2).reshape(B, S, H, hd)
 
 
 def paged_attention(q, k_pool, v_pool, cache_len, page_table, *,
-                    num_new=None, interpret: Optional[bool] = None
+                    num_new=None, interpret: Optional[bool] = None,
+                    window: Optional[int] = None, name: Optional[str] = None
                     ) -> Tuple[Optional[jax.Array], List[str]]:
     """Shard-map-aware wrapper (heads over tp, slots over dp/fsdp — the
     layout of decode_attention's). Returns ``(out, reasons)``: ``out`` is
@@ -347,7 +397,7 @@ def paged_attention(q, k_pool, v_pool, cache_len, page_table, *,
     if not distributed:
         return paged_attention_kernel(
             q, k_pool, v_pool, cache_len, page_table, num_new=num_new,
-            interpret=interp,
+            interpret=interp, window=window, name=name,
         ), reasons
 
     from jax.sharding import PartitionSpec as P
@@ -362,7 +412,8 @@ def paged_attention(q, k_pool, v_pool, cache_len, page_table, *,
 
     def body(q, kc, vc, cl, nn, pt):
         return paged_attention_kernel(
-            q, kc, vc, cl, pt, num_new=nn, interpret=interp
+            q, kc, vc, cl, pt, num_new=nn, interpret=interp, window=window,
+            name=name,
         )
 
     return jax.shard_map(

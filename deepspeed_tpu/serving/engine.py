@@ -58,8 +58,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..comm.topology import MeshTopology, ParallelDims
 from ..inference.engine import (InferenceEngine, _align_cache,
                                 init_inference)
-from ..models.decoding import (SCALE_LANES, forward_with_cache, init_cache,
-                               init_paged_cache, paged_cow_copy,
+from ..models.decoding import (SCALE_LANES, WIN, forward_with_cache,
+                               init_cache, init_paged_cache, paged_cow_copy,
                                record_attention_path, staged_promote)
 from ..models.sharding import use_topology
 from ..utils.logging import log_dist
@@ -70,15 +70,19 @@ from .scheduler import Scheduler, StepPlan
 from .spec import spec_verify_stream, verify_window
 
 
-def cache_partition_specs(quantized: bool) -> Dict[str, P]:
+def cache_partition_specs(quantized: bool, window_pool: bool = False
+                          ) -> Dict[str, P]:
     """KV-arena specs: cache heads over tp (slots stay unsharded — the
-    scheduler owns placement); the per-layer leading dim is stacked."""
+    scheduler owns placement); the per-layer leading dim is stacked.
+    ``window_pool`` adds the window layers' pool leaves, laid out alike."""
     value = P(None, None, None, "tp", None)
     specs = {"k": value, "v": value}
     if quantized:
         scale = P(None, None, "tp", None, None)
         specs["k_scale"] = scale
         specs["v_scale"] = scale
+    if window_pool:
+        specs.update({k + WIN: v for k, v in list(specs.items())})
     return specs
 
 
@@ -470,17 +474,20 @@ def make_paged_step_fn(cfg, dtype, vocab: int, cache_shardings=None,
 
     def step(params, caches, seen, tokens, num_new, start_pos, page_table,
              cow_src, fresh, sample_flag, spec_len, eos_id, rng, temperature,
-             top_k, top_p, rep_penalty):
+             top_k, top_p, rep_penalty, page_table_win=None):
         live = sample_flag & (num_new > 0)
         seen = _book_seen(seen, tokens, num_new, spec_len, fresh, vocab)
-        caches = paged_cow_copy(caches, page_table, start_pos, cow_src)
+        if page_table_win is None:
+            # (a model with window layers shares no page: nothing to copy)
+            caches = paged_cow_copy(caches, page_table, start_pos, cow_src)
         token_valid = (
             jnp.arange(tokens.shape[1])[None, :] < num_new[:, None]
             if moe else None
         )
         fw = forward_with_cache(
             cfg, params, tokens, caches, start_pos, dtype=dtype,
-            page_table=page_table, num_new=num_new,
+            page_table=page_table, page_table_win=page_table_win,
+            num_new=num_new,
             token_valid=token_valid, return_moe_stats=moe,
         )
         if moe:
@@ -500,6 +507,15 @@ def make_paged_step_fn(cfg, dtype, vocab: int, cache_shardings=None,
             return caches, seen, out_tok, n_emit, new_rng, moe_stats
         return caches, seen, out_tok, n_emit, new_rng
 
+    if cfg.has_window:
+        # pages by layer kind: the window layers' table rides beside the
+        # full layers'
+        def kinds_step(params, caches, seen, tokens, num_new, start_pos,
+                       page_table, page_table_win, *rest):
+            return step(params, caches, seen, tokens, num_new, start_pos,
+                        page_table, *rest, page_table_win=page_table_win)
+
+        return kinds_step
     if not tiered:
         return step
 
@@ -645,6 +661,36 @@ class ServingEngine:
         else:
             self.page_size = self.num_pages = self.pages_per_slot = None
             self.capacity = _align_cache(self.max_tokens + W)
+        # ---- pages by layer kind: a model with window layers keeps a
+        # second pool for them, whose pages a slot gives back behind its
+        # window (docs/serving.md "Layer kinds") ------------------------
+        self.kinds_paged = self.paged and bool(mcfg.has_window)
+        self.window_pages_per_slot = self.window_num_pages = None
+        prefix_cache = bool(serving.prefix_cache) if self.paged else False
+        if self.kinds_paged:
+            from ..config import DeepSpeedConfigError
+
+            why = (
+                f"the model has window layers ({mcfg.attn_window} keys): a "
+                "page that is kept, spilled or handed over holds the full "
+                "layers' keys alone, and the window layers' last "
+                f"{mcfg.attn_window} keys would be missing"
+            )
+            if int(getattr(serving, "host_pages", 0) or 0) > 0:
+                raise DeepSpeedConfigError(
+                    f"serving.host_pages is refused: {why}")
+            if int(serving.fleet.prefill_replicas) > 0:
+                raise DeepSpeedConfigError(
+                    f"serving.fleet.prefill_replicas is refused: {why}")
+            if prefix_cache:
+                log_dist(f"serving: prefix cache off: {why}")
+                prefix_cache = False
+            # a slot's window pages: the keys row 0 of a chunk still sees
+            # up to the chunk's last key, and one page of misalignment;
+            # the pool holds every slot's most, so it never runs dry
+            self.window_pages_per_slot = (
+                -(-(mcfg.attn_window + W) // self.page_size) + 1)
+            self.window_num_pages = N * self.window_pages_per_slot
         # ---- tiered KV (serving.host_pages > 0, ISSUE 18): a pinned-
         # host second tier behind the HBM pool. The ENGINE owns the
         # store + spiller (movement needs device access: export/encode on
@@ -735,10 +781,12 @@ class ServingEngine:
             page_size=self.page_size if self.paged else None,
             num_pages=self.num_pages if self.paged else None,
             pages_per_slot=self.pages_per_slot if self.paged else None,
-            prefix_cache=bool(serving.prefix_cache) if self.paged else False,
+            prefix_cache=prefix_cache,
             spec_max_draft=self.max_draft,
             spec_ngram_n=self.spec_ngram_n,
             spiller=self._spiller,
+            window=mcfg.attn_window if self.kinds_paged else 0,
+            window_num_pages=self.window_num_pages,
         )
 
         # ---- the KV arena (contiguous slots, or a paged pool) ----------
@@ -747,6 +795,7 @@ class ServingEngine:
                 self.config, self.num_pages, self.page_size,
                 engine.kv_cache_storage_dtype,
                 quantized=engine.kv_cache_quantized,
+                window_pages=self.window_num_pages,
             )
         else:
             caches = init_cache(
@@ -760,7 +809,7 @@ class ServingEngine:
             self._cache_shardings = {
                 k: NamedSharding(mesh, spec)
                 for k, spec in cache_partition_specs(
-                    engine.kv_cache_quantized
+                    engine.kv_cache_quantized, self.kinds_paged
                 ).items()
             }
             caches = jax.device_put(caches, self._cache_shardings)
@@ -825,6 +874,10 @@ class ServingEngine:
             self.metrics.attention_paged_kernel = float(
                 rec["path"] == "paged_kernel"
             )
+            self.metrics.attention_paged_kernel_kinds = {
+                kind: float(path == "paged_kernel")
+                for kind, path in rec["kinds"].items()
+            }
             return out
 
         self._step = jax.jit(counting_step, donate_argnums=(1, 2))
@@ -845,6 +898,11 @@ class ServingEngine:
         arena = (
             f"pages={self.num_pages}x{self.page_size}tok "
             f"({self.pages_per_slot}/slot)"
+            + (
+                f" +window={self.window_num_pages} "
+                f"({self.window_pages_per_slot}/slot)"
+                if self.kinds_paged else ""
+            )
             + (
                 f" +host={self.host_pages}@{serving.spill_codec}"
                 + ("+nvme" if serving.spill_dir else "")
@@ -959,11 +1017,18 @@ class ServingEngine:
             # them an all-NULL page-table row, so their padded W-wide
             # writes land in the NULL sink page by construction
             start_pos = plan.start_pos
-            paged_args = (jnp.asarray(plan.page_table),
-                          jnp.asarray(plan.cow_src))
+            tables = (plan.page_table, plan.page_table_win)[
+                :1 + self.kinds_paged]
+            paged_args = (*tables, plan.cow_src)
             if self.tiered:
                 paged_args += self._stage_args(plan)
+            # a one-kind model pays for the count only under the tracer
+            keys = (self._count_keys(plan)
+                    if self.kinds_paged or dispatch_sp is not None else {})
+            if dispatch_sp is not None:
+                dispatch_sp.annotate(**keys)
         else:
+            keys = {}
             # rows the plan left idle (num_new == 0) still get a W-wide
             # padded cache write — repoint it at the DEAD TAIL margin
             # [capacity - W, capacity), which by construction never holds
@@ -980,16 +1045,18 @@ class ServingEngine:
         from ..parallel.a2a_overlap import a2a_scope
 
         moe_stats = None
+        # the step's attention work rides the profiler's host trace with the
+        # call it describes (free while no trace is being taken)
         with use_topology(self.topology), self.engine._impl_ctx(), \
-                a2a_scope(self._a2a_cfg):
+                a2a_scope(self._a2a_cfg), \
+                jax.profiler.TraceAnnotation("serve/device_step", **keys):
+            # the plan's numpy vectors go to the jitted call as they are:
+            # it uploads them itself, without a device_put apiece
             outs = self._step(
                 self.engine.params, self._caches, self._seen,
-                jnp.asarray(plan.tokens), jnp.asarray(plan.num_new),
-                jnp.asarray(start_pos), *paged_args,
-                jnp.asarray(plan.fresh), jnp.asarray(plan.sample),
-                jnp.asarray(spec_len), jnp.asarray(eos),
-                jnp.asarray(rng), jnp.asarray(temp), jnp.asarray(top_k),
-                jnp.asarray(top_p), jnp.asarray(penalty),
+                plan.tokens, plan.num_new, start_pos, *paged_args,
+                plan.fresh, plan.sample, spec_len, eos, rng, temp, top_k,
+                top_p, penalty,
             )
         if self.moe_serving:
             caches, seen, out_tok, n_emit, new_rng, moe_stats = outs
@@ -1011,17 +1078,22 @@ class ServingEngine:
                     )
             complete_sp = tr.begin("serve/complete", "serve")
         self._caches, self._seen = caches, seen
+        # one fetch for everything the host reads (the copies start
+        # together; a fetch apiece waited a round trip each)
+        out_tok, new_rng, n_emit, moe_stats = jax.device_get((
+            out_tok, new_rng, n_emit,
+            moe_stats and (moe_stats["tokens_per_expert"],
+                           moe_stats["drop_fraction"]),
+        ))
         finished = self.scheduler.complete(
-            plan, np.asarray(out_tok), np.asarray(new_rng),
-            n_emit=np.asarray(n_emit),
+            plan, out_tok, new_rng, n_emit=n_emit,
         )
         self.metrics.on_step()
         if moe_stats is not None:
             # expert load-balance counters (ISSUE 14 satellite): the step
-            # already computed them on device — one tiny [E] transfer
+            # already computed them on device
             self.metrics.on_moe(
-                np.asarray(moe_stats["tokens_per_expert"]),
-                float(moe_stats["drop_fraction"]),
+                moe_stats[0], float(moe_stats[1]),
                 a2a_bytes=self._moe_a2a_step_bytes,
             )
         if self.comm_logger is not None:
@@ -1029,6 +1101,24 @@ class ServingEngine:
         if tr is not None:
             complete_sp.end()
         return finished
+
+    def _count_keys(self, plan: StepPlan) -> Dict[str, int]:
+        """The step's attention work by layer kind, from the plan (one
+        layer of the kind: ``attended_<kind>`` the keys visible to every
+        real query token, ``fetched_<kind>`` the keys in the pages that hold
+        one of them, whatever block the kernel reads them in; ``rows`` the
+        real query tokens), booked on the metrics."""
+        from ..ops.pallas.paged_attention import key_counts
+
+        out = {"rows": int(plan.num_new.sum())}
+        for kind in ("full", "window")[:1 + self.kinds_paged]:
+            attended, fetched = key_counts(
+                plan.start_pos, plan.num_new, self.page_size,
+                self.pages_per_slot, self.config.window_of(kind),
+                block_k=self.page_size)
+            self.metrics.on_keys(kind, attended, fetched)
+            out["attended_" + kind], out["fetched_" + kind] = attended, fetched
+        return out
 
     def _stage_args(self, plan: StepPlan) -> tuple:
         """Decode this step's promotions into the rotating staging buffer
@@ -1067,6 +1157,15 @@ class ServingEngine:
         return (bufs, stage_dst)
 
     # ------------------------------------------------- fleet KV handoff
+    def _refuse_page_moves(self, what: str) -> None:
+        if self.kinds_paged:
+            raise RuntimeError(
+                f"{what}: a page id names the full layers' pool alone; the "
+                "window layers' keys of the same tokens lie in their own "
+                "pool (or were given back), so a hand-off would serve a "
+                "model with window layers keys it does not hold"
+            )
+
     def export_kv_pages(self, page_ids) -> Dict[str, Any]:
         """Snapshot the payload of physical ``page_ids`` out of this
         replica's paged pool (serving/paging.py export_pages) — the
@@ -1078,6 +1177,7 @@ class ServingEngine:
                 "export_kv_pages needs the paged arena (serving.paged) — "
                 "the fleet KV handoff is a page transfer"
             )
+        self._refuse_page_moves("export_kv_pages")
         return export_pages(self._caches, page_ids)
 
     def import_kv_pages(self, payload: Dict[str, Any], dst_page_ids
@@ -1095,6 +1195,7 @@ class ServingEngine:
             raise RuntimeError(
                 "import_kv_pages needs the paged arena (serving.paged)"
             )
+        self._refuse_page_moves("import_kv_pages")
         ids = np.asarray(dst_page_ids, np.int32)
         check_page_payload(self._caches, payload, ids.size)
         if self._import_pages_fn is None:
@@ -1147,6 +1248,8 @@ class ServingEngine:
         paged_args = ()
         if self.paged:
             paged_args = (vec(jnp.int32, self.pages_per_slot), vec(jnp.int32))
+            if self.kinds_paged:
+                paged_args = (paged_args[0], *paged_args)
             if self.tiered:
                 paged_args += (
                     jax.tree.map(sds, self._stage_zero_np),

@@ -140,6 +140,18 @@ class ServingMetrics:
         self.arena_utilization = 0.0
         self.prefix_cache_entries = 0
         self.host_pages_resident = 0  # host-store keys alive (gauge)
+        # pages and keys by layer kind (a model with window layers keeps a
+        # second pool; ``pages_in_use`` / ``pages_free`` above are the full
+        # layers' pool)
+        self.window_pages_in_use = 0
+        self.window_pages_free = 0
+        self.window_pages_released = 0  # given back behind the window
+        # per kind, for ONE layer of the kind, summed over steps from the
+        # plan: the keys visible to every real query token, and the keys
+        # in the blocks the paged kernel's loops read for them
+        self.attended_keys: Dict[str, int] = defaultdict(int)
+        self.fetched_keys: Dict[str, int] = defaultdict(int)
+        self.attention_paged_kernel_kinds: Dict[str, float] = {}
         self._max_slots = 1
         self._num_pages = 0
         self._host_pages = 0
@@ -282,6 +294,13 @@ class ServingMetrics:
             return 0.0
         return max(hist) / (total / len(hist))
 
+    def on_window_pages(self, pool, released: int) -> None:
+        """The window layers' pool after a tick (a model that keeps pages
+        by layer kind)."""
+        self.window_pages_free = pool.free_count
+        self.window_pages_in_use = pool.num_pages - pool.free_count
+        self.window_pages_released = int(released)
+
     def on_pages(self, pool, cache_entries: int = 0,
                  host_resident: int = 0) -> None:
         """Pool gauges from the scheduler's PagePool after a tick."""
@@ -338,6 +357,11 @@ class ServingMetrics:
     def on_step(self) -> None:
         self.steps += 1
 
+    def on_keys(self, kind: str, attended: int, fetched: int) -> None:
+        """One step's attention work in one layer of ``kind``."""
+        self.attended_keys[kind] += int(attended)
+        self.fetched_keys[kind] += int(fetched)
+
     # ------------------------------------------------------ reporting
     @property
     def elapsed(self) -> float:
@@ -381,6 +405,18 @@ class ServingMetrics:
                 self.mean_accepted_tokens_per_step,
             "attention_paged_kernel": self.attention_paged_kernel,
         }
+        for kind in self.attended_keys:
+            snap[f"attended_keys_{kind}"] = self.attended_keys[kind]
+            snap[f"fetched_keys_{kind}"] = self.fetched_keys[kind]
+        for kind, on in self.attention_paged_kernel_kinds.items():
+            snap[f"attention_paged_kernel_{kind}"] = on
+        if "window" in self.attended_keys:
+            snap.update({
+                "pages_free": self.pages_free,
+                "window_pages_in_use": self.window_pages_in_use,
+                "window_pages_free": self.window_pages_free,
+                "window_pages_released": self.window_pages_released,
+            })
         if (self._host_pages or self.pages_spilled or self.pages_promoted
                 or self.host_pages_resident):
             snap.update({

@@ -42,7 +42,7 @@ from __future__ import annotations
 
 import zlib
 from collections import OrderedDict
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -470,24 +470,30 @@ class PagePool:
     def live_count(self) -> int:
         return int((self.refcount > 0).sum())
 
-    def check_leaks(self, expected: Optional[Dict[int, int]] = None) -> None:
+    def check_leaks(self, held: Optional[Iterable[int]] = None) -> None:
         """The leak invariant: ``free + live == num_pages``, and (when the
         caller supplies its own view) the pool's refcounts match the
-        references the scheduler believes exist, page for page."""
+        references the scheduler believes exist, page for page. ``held``
+        is that view: the page ids the holders name, one a reference. The
+        scheduler audits every tick, so they are counted in numpy (a dict
+        cost a step's host time in proportion to the pages alive)."""
         if self.free_count + self.live_count != self.num_pages:
             raise AssertionError(
                 f"page leak: free {self.free_count} + live "
                 f"{self.live_count} != num_pages {self.num_pages}"
             )
-        if expected is not None:
-            mine = {
-                int(p): int(self.refcount[p])
-                for p in np.nonzero(self.refcount)[0]
-            }
-            if mine != expected:
-                raise AssertionError(
-                    f"page refcount drift: pool {mine} != holders {expected}"
-                )
+        if held is None:
+            return
+        counts = np.bincount(np.fromiter(held, np.int64),
+                             minlength=self.num_pages)
+        if not np.array_equal(counts, self.refcount):
+            mine, theirs = (
+                {int(p): int(c[p]) for p in np.nonzero(c)[0]}
+                for c in (self.refcount, counts)
+            )
+            raise AssertionError(
+                f"page refcount drift: pool {mine} != holders {theirs}"
+            )
 
 
 class PrefixCache:

@@ -111,6 +111,11 @@ class RequestState:
     #   prefix-cache refs) — a write into one triggers copy-on-write
     owned_from: int = 0          # first logical page this request owns
     cached_tokens: int = 0       # prompt tokens skipped via the prefix cache
+    # ---- window layers' pool (models with window attention layers) ----
+    win_pages: List[int] = field(default_factory=list)  # physical page per
+    #   logical page from ``win_lo`` on: the pages behind the window were
+    #   given back (no query still to come can see their keys)
+    win_lo: int = 0              # logical index of win_pages[0]
     # ---- tiered KV (host spill; empty when serving.host_pages == 0) ----
     host_pages: Dict[int, Tuple[int, bool]] = field(default_factory=dict)
     #   logical page index -> (HostPageStore key, owned). While any entry

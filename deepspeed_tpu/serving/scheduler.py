@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
+from itertools import chain
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -83,6 +84,9 @@ class StepPlan:
     page_table: Optional[np.ndarray] = None  # [max_slots, pages_per_slot]
     #   int32 physical page per logical page; unmapped entries (and whole
     #   idle rows) point at the NULL sink page
+    page_table_win: Optional[np.ndarray] = None  # the same for the window
+    #   layers' pool (a model with window layers): entries behind a slot's
+    #   window point at that pool's NULL page too
     cow_src: Optional[np.ndarray] = None     # [max_slots] int32 physical
     #   page to copy-on-write onto the slot's frontier page (-1 = none)
     spec_len: Optional[np.ndarray] = None    # [max_slots] int32 draft
@@ -116,6 +120,8 @@ class Scheduler:
         spec_max_draft: int = 0,
         spec_ngram_n: int = 3,
         spiller=None,
+        window: int = 0,
+        window_num_pages: Optional[int] = None,
     ):
         self.max_slots = int(max_slots)
         self.token_budget = int(token_budget)
@@ -168,6 +174,21 @@ class Scheduler:
             )
         else:
             self.pool = self.prefix_cache = None
+        # ---- pages by layer kind: window layers (window > 0) keep their
+        # own pool, and a slot holds only the pages a query still to come
+        # can see: at most ceil((window + token_budget) / page_size) + 1
+        self.window = int(window) if self.paged else 0
+        self.window_pool = None
+        self.window_pages_released = 0
+        if self.window:
+            if self.prefix_cache is not None or self.spiller is not None:
+                raise ValueError(
+                    "a prefix hit or a host page holds full-layer pages "
+                    "alone, without the window layers' last keys: neither "
+                    "serves a model with window layers"
+                )
+            self.window_pool = PagePool(int(window_num_pages))
+            self.null_page_win = self.window_pool.num_pages
 
     # -------------------------------------------------------------- intake
     def submit(self, request: Request) -> RequestState:
@@ -260,6 +281,9 @@ class Scheduler:
 
     # ------------------------------------------------------------- pages
     def _release_pages(self, state: RequestState, insert: bool) -> None:
+        for p in state.win_pages:
+            self.window_pool.decref(p)
+        state.win_pages, state.win_lo = [], 0
         pages, state.pages = state.pages, []
         host, state.host_pages = state.host_pages, {}
         state.owned_from = 0
@@ -439,6 +463,11 @@ class Scheduler:
         allocated FROM THIS scheduler's pool via :meth:`alloc_pages`).
         Returns the slot. The slot is marked fresh so its first decode
         feed clears the previous occupant's stale ``seen`` row."""
+        if self.window:
+            raise RuntimeError(
+                "adopt: a handed-off request brings full-layer pages alone; "
+                "a model with window layers needs its window pages too"
+            )
         if not self._free:
             raise RuntimeError("adopt: no free slot")
         if state.status is not RequestStatus.DECODE:
@@ -472,6 +501,8 @@ class Scheduler:
                 break
             state.pages.append(p)
         n = min(n, len(state.pages) * ps - start)
+        if n > 0 and self.window:
+            n = self._prepare_window_pages(state, start, n)
         if n <= 0:
             return 0, -1
         cow = -1
@@ -494,6 +525,30 @@ class Scheduler:
                 self.metrics.on_cow()
         return n, cow
 
+    def _prepare_window_pages(self, state: RequestState, start: int,
+                              n: int) -> int:
+        """The window layers' side of :meth:`_prepare_pages`: give back the
+        pages no query from ``start`` on can see (the oldest key a window
+        layer's query ``i`` sees is ``i - window + 1``), then map pages up
+        to the end of the span. Returns the tokens writable (pool pressure
+        may shrink the chunk, as on the full side)."""
+        ps = self.page_size
+        keep_from = max(start - self.window + 1, 0) // ps
+        drop = min(max(keep_from - state.win_lo, 0), len(state.win_pages))
+        for p in state.win_pages[:drop]:
+            self.window_pool.decref(p)
+        del state.win_pages[:drop]
+        self.window_pages_released += drop
+        state.win_lo = max(state.win_lo + drop, keep_from) \
+            if state.win_pages else keep_from
+        need = min(-(-(start + n) // ps), self.pages_per_slot)
+        while state.win_lo + len(state.win_pages) < need:
+            p = self.window_pool.alloc()
+            if p is None:
+                break
+            state.win_pages.append(p)
+        return min(n, (state.win_lo + len(state.win_pages)) * ps - start)
+
     def assert_page_invariants(self) -> None:
         """The leak invariant after every tick: ``free + live ==
         num_pages``, and every live page's refcount equals exactly the
@@ -508,17 +563,16 @@ class Scheduler:
         including the rollback path."""
         if not self.paged:
             return
-        expected: dict = {}
-        for st in self.slots:
-            if st is None:
-                continue
-            for p in st.pages:
-                if p != -1:
-                    expected[p] = expected.get(p, 0) + 1
+        live = [st for st in self.slots if st is not None]
+        held = chain.from_iterable(st.pages for st in live)
+        if self.spiller is not None:
+            held = (p for p in held if p != -1)  # demoted to the host tier
         if self.prefix_cache is not None:
-            for p in self.prefix_cache.held_pages:
-                expected[p] = expected.get(p, 0) + 1
-        self.pool.check_leaks(expected)
+            held = chain(held, self.prefix_cache.held_pages)
+        self.pool.check_leaks(held)
+        if self.window_pool is not None:
+            self.window_pool.check_leaks(
+                chain.from_iterable(st.win_pages for st in live))
         if self.spiller is not None:
             store = self.spiller.store
             exp_keys = set(self._inflight)
@@ -698,6 +752,9 @@ class Scheduler:
                         if self.spiller is not None else 0
                     ),
                 )
+                if self.window:
+                    self.metrics.on_window_pages(
+                        self.window_pool, self.window_pages_released)
         if plan is not None and self.metrics is not None:
             self.metrics.on_plan(plan, now, queue_depth=len(self.queue),
                                  occupancy=self.active_count)
@@ -715,6 +772,11 @@ class Scheduler:
             page_table=(
                 np.full((N, self.pages_per_slot), self.null_page, np.int32)
                 if self.paged else None
+            ),
+            page_table_win=(
+                np.full((N, self.pages_per_slot), self.null_page_win,
+                        np.int32)
+                if self.window else None
             ),
             cow_src=np.full(N, -1, np.int32) if self.paged else None,
             spec_len=np.zeros(N, np.int32),
@@ -776,6 +838,10 @@ class Scheduler:
             if self.paged:
                 plan.cow_src[slot] = cow
                 plan.page_table[slot, :len(state.pages)] = state.pages
+                if self.window:
+                    plan.page_table_win[
+                        slot, state.win_lo:state.win_lo + len(state.win_pages)
+                    ] = state.win_pages
             plan.work.append(ScheduledWork(slot, state, n, True,
                                            spec_len=n - 1))
         # leftover budget to prompt chunks, FCFS by prefill start
@@ -812,6 +878,10 @@ class Scheduler:
             if self.paged:
                 plan.cow_src[slot] = cow
                 plan.page_table[slot, :len(state.pages)] = state.pages
+                if self.window:
+                    plan.page_table_win[
+                        slot, state.win_lo:state.win_lo + len(state.win_pages)
+                    ] = state.win_pages
             if self.metrics is not None:
                 # a fully-cached prompt's only feed is its final token
                 # (the sampling feed) — that is NOT a prefill chunk

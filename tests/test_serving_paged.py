@@ -221,14 +221,39 @@ def test_page_pool_refcounts_and_leak_check():
     a, b = pool.alloc(), pool.alloc()
     pool.incref(a)
     assert pool.free_count == 2 and pool.live_count == 2
-    pool.check_leaks({a: 2, b: 1})
+    pool.check_leaks([a, b, a])
     pool.decref(a)
     pool.decref(a)
     assert pool.free_count == 3
     with pytest.raises(AssertionError, match="dead page"):
         pool.decref(a)
     with pytest.raises(AssertionError, match="refcount drift"):
-        pool.check_leaks({b: 2})
+        pool.check_leaks([b, b])
+
+
+@pytest.mark.parametrize("held, drift", [
+    ("a a b", None),          # one id a reference: what the holders name
+    ("a b", "a"),             # a reference the holders do not know
+    ("a a b b", "b"),         # one the pool does not know
+    ("a a b c", "c"),         # a dead page held
+    ("a a b 9", "9"),         # an id past the pool
+])
+def test_page_pool_leak_check_by_held_ids(held, drift):
+    """The scheduler's audit of every tick: the holders' page ids, counted
+    in numpy, against the refcounts."""
+    pool = PagePool(4)
+    a, b, c = pool.alloc(), pool.alloc(), pool.alloc()
+    pool.incref(a)
+    pool.decref(c)
+    ids = {"a": a, "b": b, "c": c, "9": 9}
+    view = (ids[x] for x in held.split())  # any iterable, read once
+    if drift is None:
+        pool.check_leaks(view)
+        return
+    with pytest.raises(AssertionError, match="refcount drift") as err:
+        pool.check_leaks(view)
+    theirs = str(err.value).split("holders")[1]
+    assert f"{ids[drift]}:" in theirs
 
 
 def test_prefix_cache_eviction_frees_pages():

@@ -178,6 +178,60 @@ def test_paged_attention_compiles(one_chip, B, S, pages, per_slot):
     assert "tpu_custom_call" in text, text[:2000]
 
 
+# ------------------------------- a whole slot step, pages by layer kind
+def test_mellum_slot_step_compiles_beside_its_arena(one_chip, monkeypatch,
+                                                    capsys):
+    """The one [8, 128] serving step of Mellum2-12B-A2.5B at its published
+    widths and the benchmark's depth (12 layers, three periods of three
+    window layers and a full one), with the benchmark's arena: 8,384 pages
+    for the full layers, 592 for the window layers. The chip's compiler
+    has to take it beside the 16 GB, with both named attention kernels."""
+    from deepspeed_tpu.models import mellum
+    from deepspeed_tpu.models.decoding import init_paged_cache
+    from deepspeed_tpu.ops.attention import attention_impl
+    from deepspeed_tpu.serving.engine import make_paged_step_fn
+
+    # the kernels pick interpret mode from the backend, the CPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = mellum("mellum2-12b-a2.5b", num_layers=12)
+    cfg = model.config
+    N, W, ps, cap = 8, 128, 16, 16640
+    mp = -(-(cap + W) // ps)
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def vec(dt, *tail):
+        return jax.ShapeDtypeStruct((N, *tail), dt, sharding=one_chip)
+
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda k: model.init(k, dtype=BF16), jax.random.PRNGKey(0)))
+    caches = jax.tree.map(sds, jax.eval_shape(
+        lambda: init_paged_cache(cfg, N * mp, ps, BF16, window_pages=592)))
+    step = make_paged_step_fn(cfg, BF16, cfg.vocab_size)
+    with attention_impl("flash"):
+        compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+            params, caches, vec(jnp.bool_, cfg.vocab_size), vec(I32, W),
+            vec(I32), vec(I32), vec(I32, mp), vec(I32, mp), vec(I32),
+            vec(jnp.bool_), vec(jnp.bool_), vec(I32), vec(I32),
+            vec(jnp.uint32, 2), vec(F32), vec(I32), vec(F32), vec(F32),
+        ).compile()
+    m = compiled.memory_analysis()
+    gib = 2.0 ** 30
+    with capsys.disabled():
+        print(f"\nmellum slot step, depth 12, described v5e: arguments "
+              f"{m.argument_size_in_bytes / gib:.2f} GiB, temporaries "
+              f"{m.temp_size_in_bytes / gib:.2f} GiB, output "
+              f"{m.output_size_in_bytes / gib:.2f} GiB (aliased "
+              f"{m.alias_size_in_bytes / gib:.2f})")
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * gib
+    # a period-sized slice of the expert banks (3 x 0.98 GiB) must not be
+    # among the temporaries: the layers are read one at a time
+    assert m.temp_size_in_bytes < 2.5 * gib
+    text = compiled.as_text()
+    assert "paged_attention_window" in text and "paged_attention_full" in text
+
+
 # ----------------------------------------------------------------- norms
 @pytest.mark.parametrize("D", [1024, 4096])
 @pytest.mark.parametrize("kind", ["rmsnorm", "layernorm"])
