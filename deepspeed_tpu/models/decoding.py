@@ -69,26 +69,38 @@ def _is_ragged(cache_len) -> bool:
     return getattr(cache_len, "ndim", 0) == 1
 
 
-def _update_at(cache: jax.Array, new: jax.Array, cache_len) -> jax.Array:
-    """Write ``new`` [B, S, KV, hd] into ``cache`` [B, Smax, KV, hd] at
-    per-batch offset ``cache_len`` (scalar or [B] vector). The vector form
+def _update_at(cache: jax.Array, new: jax.Array, layer,
+               cache_len) -> jax.Array:
+    """Write ``new`` [B, S, KV, hd] into layer ``layer`` of the cache
+    stack [L, B, Smax, KV, hd] at per-batch offset ``cache_len`` (scalar
+    or [B] vector), in place: the stack comes back whole. The vector form
     is a vmapped per-row dynamic_update_slice — each slot of a ragged
     serving batch advances its own write frontier."""
     if _is_ragged(cache_len):
         return jax.vmap(
-            lambda c, u, off: lax.dynamic_update_slice(c, u, (off, 0, 0))
+            lambda c, u, off: lax.dynamic_update_slice(
+                c, u[None], (layer, off, 0, 0)),
+            in_axes=(1, 0, 0), out_axes=1,
         )(cache, new, cache_len)
-    return lax.dynamic_update_slice(cache, new, (0, cache_len, 0, 0))
+    return lax.dynamic_update_slice(
+        cache, new[None], (layer, 0, cache_len, 0, 0))
 
 
-def _update_scale_at(scale: jax.Array, new: jax.Array, cache_len) -> jax.Array:
+def _update_scale_at(scale: jax.Array, new: jax.Array, layer,
+                     cache_len) -> jax.Array:
     """Scale-cache twin of :func:`_update_at`: ``scale`` is stored
-    pre-transposed as [B, KV, Smax, SL]; ``new`` arrives [B, KV, S, SL]."""
+    pre-transposed as [L, B, KV, Smax, SL]; ``new`` arrives [B, S, KV, SL]
+    (the _quantize_kv layout) and is transposed here — tiny; the big int8
+    value caches never relayout."""
+    new = jnp.swapaxes(new, 1, 2)
     if _is_ragged(cache_len):
         return jax.vmap(
-            lambda c, u, off: lax.dynamic_update_slice(c, u, (0, off, 0))
+            lambda c, u, off: lax.dynamic_update_slice(
+                c, u[None], (layer, 0, off, 0)),
+            in_axes=(1, 0, 0), out_axes=1,
         )(scale, new, cache_len)
-    return lax.dynamic_update_slice(scale, new, (0, 0, cache_len, 0))
+    return lax.dynamic_update_slice(
+        scale, new[None], (layer, 0, 0, cache_len, 0))
 
 
 def gather_verify_window(logits: jax.Array, num_new, spec_len,
@@ -167,26 +179,27 @@ def _page_indices(cache_len: jax.Array, S: int, page_table: jax.Array,
     return phys, pos % page_size
 
 
-def _paged_write(pool: jax.Array, new: jax.Array, cache_len,
+def _paged_write(pool: jax.Array, new: jax.Array, layer, cache_len,
                  page_table: jax.Array) -> jax.Array:
-    """Scatter a chunk's new K/V [B, S, KV, hd] into the page pool
-    [P+1, page_size, KV, hd] through the per-slot page tables. Tokens
-    past a slot's mapped pages (padding) route to the NULL page the
-    tables point unmapped entries at."""
-    phys, off = _page_indices(cache_len, new.shape[1], page_table,
-                              pool.shape[1])
-    return pool.at[phys, off].set(new)
-
-
-def _paged_write_scale(pool: jax.Array, new: jax.Array, cache_len,
-                       page_table: jax.Array) -> jax.Array:
-    """Scale twin of :func:`_paged_write`: pool [P+1, KV, ps, SL], new
-    chunk scales [B, S, KV, SL] (the _quantize_kv layout)."""
+    """Scatter a chunk's new K/V [B, S, KV, hd] into layer ``layer`` of
+    the page pool stack [L, P+1, page_size, KV, hd] through the per-slot
+    page tables, in place: the stack comes back whole. Tokens past a
+    slot's mapped pages (padding) route to the NULL page the tables point
+    unmapped entries at."""
     phys, off = _page_indices(cache_len, new.shape[1], page_table,
                               pool.shape[2])
-    kv = jnp.arange(pool.shape[1])
+    return pool.at[layer, phys, off].set(new)
+
+
+def _paged_write_scale(pool: jax.Array, new: jax.Array, layer, cache_len,
+                       page_table: jax.Array) -> jax.Array:
+    """Scale twin of :func:`_paged_write`: pool [L, P+1, KV, ps, SL], new
+    chunk scales [B, S, KV, SL] (the _quantize_kv layout)."""
+    phys, off = _page_indices(cache_len, new.shape[1], page_table,
+                              pool.shape[3])
+    kv = jnp.arange(pool.shape[2])
     return pool.at[
-        phys[:, :, None], kv[None, None, :], off[:, :, None]
+        layer, phys[:, :, None], kv[None, None, :], off[:, :, None]
     ].set(new)
 
 
@@ -320,13 +333,18 @@ def _qkv(cfg: TransformerConfig, p: Params, x: jax.Array, positions: jax.Array,
 
 
 def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
-                      positions: jax.Array, k_cache: jax.Array,
+                      positions: jax.Array, layer, k_cache: jax.Array,
                       v_cache: jax.Array, cache_len,
                       k_scale=None, v_scale=None, page_table=None,
                       num_new=None, kind: str = "full"):
     """Attend new tokens (x, [B,S,D]) against cache[:cache_len] + themselves.
 
-    Returns (out, new_k_cache, new_v_cache[, new_k_scale, new_v_scale]).
+    ``k_cache``/``v_cache`` (and the scales) are the whole stacks of the
+    layer's pool, ``[L, ...]`` as init_cache / init_paged_cache give them,
+    and ``layer`` the (traced) index of this layer inside them: the new
+    keys are written in place at ``[layer, ...]`` and the stacks come back
+    whole, so the scan that carries them never copies a pool. Returns
+    (out, new_k_cache, new_v_cache[, new_k_scale, new_v_scale]).
     Works for prefill (S=prompt, cache_len=0) and decode (S=1,
     cache_len=pos). int8 caches carry per-(token, head) scales; the fresh
     prefill attends with the exact (unquantized) new k/v — only reads from
@@ -342,16 +360,17 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
 
     ``page_table`` [B, max_pages] switches the cache operands to the
     block-paged form: ``k_cache``/``v_cache`` are page POOLS
-    [P+1, page_size, KV, hd] (scales [P+1, KV, page_size, SL]) shared by
-    every slot. The chunk scatters to per-token (physical page, offset)
-    destinations FIRST, then attention reads the slot's pages: through
-    the table inside a Pallas kernel whose work follows the slot's length
-    when the registered attention is the kernel one (rotary or learned
-    positions, unquantized pool, shapes the kernel takes), else through a
-    per-slot gathered view — which holds bitwise the bytes the contiguous
-    arena would, so the attention math below is byte-for-byte the dense
-    path. ``num_new`` [B] (optional) counts each row's real tokens: the
-    kernel stops at the last key a real token needs.
+    [L, P+1, page_size, KV, hd] (scales [L, P+1, KV, page_size, SL]) shared
+    by every slot. The chunk scatters to per-token (layer, physical page,
+    offset) destinations FIRST, then attention reads the slot's pages:
+    through the table inside a Pallas kernel that takes the stack and the
+    layer's index and whose work follows the slot's length when the
+    registered attention is the kernel one (rotary or learned positions,
+    unquantized pool, shapes the kernel takes), else through a per-slot
+    gathered view of ``stack[layer]`` — which holds bitwise the bytes the
+    contiguous arena would, so the attention math below is byte-for-byte
+    the dense path. ``num_new`` [B] (optional) counts each row's real
+    tokens: the kernel stops at the last key a real token needs.
 
     ``kind`` "window" (a layer of ``cfg.layer_pattern``) bounds every
     query to its last ``cfg.attn_window`` keys: the paged kernel then
@@ -366,34 +385,26 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
 
     quantized = k_scale is not None
     paged = page_table is not None
+    if paged:
+        put, put_scale = _paged_write, _paged_write_scale
+        where = (layer, cache_len, page_table)
+    else:
+        put, put_scale = _update_at, _update_scale_at
+        where = (layer, cache_len)
     if quantized:
         kq, ks = _quantize_kv(k)
         vq, vs = _quantize_kv(v)
-        if paged:
-            k_cache = _paged_write(k_cache, kq, cache_len, page_table)
-            v_cache = _paged_write(v_cache, vq, cache_len, page_table)
-            k_scale = _paged_write_scale(k_scale, ks, cache_len, page_table)
-            v_scale = _paged_write_scale(v_scale, vs, cache_len, page_table)
-        else:
-            k_cache = _update_at(k_cache, kq, cache_len)
-            v_cache = _update_at(v_cache, vq, cache_len)
-            # new-token scales transpose into the [B, KV, S, SL] cache
-            # layout — tiny ([B,S,KV,SL]); the big int8 value caches never
-            # relayout
-            k_scale = _update_scale_at(
-                k_scale, jnp.swapaxes(ks, 1, 2), cache_len
-            )
-            v_scale = _update_scale_at(
-                v_scale, jnp.swapaxes(vs, 1, 2), cache_len
-            )
-    elif paged:
-        k_cache = _paged_write(k_cache, k.astype(k_cache.dtype), cache_len,
-                               page_table)
-        v_cache = _paged_write(v_cache, v.astype(v_cache.dtype), cache_len,
-                               page_table)
+        k_cache, v_cache = put(k_cache, kq, *where), put(v_cache, vq, *where)
+        k_scale = put_scale(k_scale, ks, *where)
+        v_scale = put_scale(v_scale, vs, *where)
     else:
-        k_cache = _update_at(k_cache, k.astype(k_cache.dtype), cache_len)
-        v_cache = _update_at(v_cache, v.astype(v_cache.dtype), cache_len)
+        k_cache = put(k_cache, k.astype(k_cache.dtype), *where)
+        v_cache = put(v_cache, v.astype(v_cache.dtype), *where)
+
+    def at_layer(stack):
+        # what cannot take a stack reads its layer (post-write) as a slice
+        return None if stack is None else lax.dynamic_index_in_dim(
+            stack, layer, 0, keepdims=False)
 
     def ret(out):
         if quantized:
@@ -427,8 +438,9 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
             from ..ops.pallas.decode_attention import decode_attention
 
             out = decode_attention(
-                q, k_cache, v_cache, cache_len,
-                k_scale=k_scale, v_scale=v_scale, page_table=page_table,
+                q, at_layer(k_cache), at_layer(v_cache), cache_len,
+                k_scale=at_layer(k_scale), v_scale=at_layer(v_scale),
+                page_table=page_table,
             )
             if out is None:
                 why_dense.append("shapes the decode kernel does not take")
@@ -442,8 +454,8 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
             from ..ops.pallas.paged_attention import paged_attention
 
             out, why_dense = paged_attention(
-                q, k_cache, v_cache, cache_len, page_table, num_new=num_new,
-                window=window,
+                q, k_cache, v_cache, cache_len, page_table, layer=layer,
+                num_new=num_new, window=window,
                 name="paged_attention_" + kind if cfg.has_window else None,
             )
         if out is not None:
@@ -453,14 +465,15 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
         # XLA path: gather the per-slot contiguous views (post-write, so
         # they reproduce the dense arena bitwise) and fall through to the
         # shared attention math below
-        k_att = _paged_gather(k_cache, page_table)
-        v_att = _paged_gather(v_cache, page_table)
-        ks_att = _paged_gather_scale(k_scale, page_table) if quantized \
-            else None
-        vs_att = _paged_gather_scale(v_scale, page_table) if quantized \
-            else None
+        k_att = _paged_gather(at_layer(k_cache), page_table)
+        v_att = _paged_gather(at_layer(v_cache), page_table)
+        ks_att = _paged_gather_scale(at_layer(k_scale), page_table) \
+            if quantized else None
+        vs_att = _paged_gather_scale(at_layer(v_scale), page_table) \
+            if quantized else None
     else:
-        k_att, v_att, ks_att, vs_att = k_cache, v_cache, k_scale, v_scale
+        k_att, v_att, ks_att, vs_att = map(
+            at_layer, (k_cache, v_cache, k_scale, v_scale))
 
     if window is not None:
         kernel_ok = False  # the contiguous kernels know no window
@@ -602,9 +615,12 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
     collect_moe = bool(return_moe_stats) and moe
     # Layers of several kinds scan whole periods of the pattern, a period's
     # layers unrolled with their kinds static; one kind scans single layers
-    # with the leaves as they are. Under a page table a model with window
-    # layers keeps two pools (init_paged_cache): a layer reads the leaves
-    # and the table of its kind.
+    # with the weights as xs. The cache rides the scan as its CARRY, every
+    # leaf whole and in the layout it came in: a layer writes its keys in
+    # place at its index inside its pool and reads from the same buffer, so
+    # no trip copies a pool. Under a page table a model with window layers
+    # keeps two pools (init_paged_cache): a layer reads the leaves and the
+    # table of its kind.
     kinds = cfg.layer_pattern or ("full",)
     period = len(kinds)
     split = page_table is not None and cfg.has_window
@@ -613,33 +629,31 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
     for j, kind in enumerate(kinds):
         sfx = WIN if split and kind == "window" else ""
         place.append((sfx, sum(1 for s, _ in place if s == sfx)))
+    # layers of a period in each pool: trip * share + place is a layer's
+    # index inside its pool
+    share = {sfx: sum(1 for s, _ in place if s == sfx) for sfx in tables}
 
-    def grouped(a):
-        return a if period == 1 else a.reshape(
-            cfg.num_layers // period, -1, *a.shape[1:])
-
-    def body(h, scanned):
-        group, pools = scanned
-        new = {name: [] for name in pools}
+    def body(carry, scanned):
+        h, pools = carry
+        group, layer = scanned  # the trip; one kind: its layer's weights
         stats = []
         for j, (kind, (sfx, at)) in enumerate(zip(kinds, place)):
-            pick = (lambda a: a) if period == 1 else (lambda a, i=at: a[i])
             # a period's layers are read from the whole stack one at a
-            # time (``group`` is then the period's index): a period-sized
-            # slice of the weights would be copied every trip
-            layer = group if period == 1 else jax.tree.map(
-                lambda a: lax.dynamic_index_in_dim(
-                    a, group * period + j, 0, keepdims=False), layers)
+            # time: a period-sized slice of the weights would be copied
+            # every trip
+            if period > 1:
+                layer = jax.tree.map(
+                    lambda a: lax.dynamic_index_in_dim(
+                        a, group * period + j, 0, keepdims=False), layers)
             names = [n + sfx for n in ("k", "v", "k_scale", "v_scale")
                      if n + sfx in pools]
             a, *updated = _cached_attention(
                 cfg, layer["attn"], _norm(cfg, layer["ln1"], h), positions,
-                *(pick(pools[n]) for n in names[:2]), cache_len,
-                *(pick(pools[n]) for n in names[2:]),
+                group * share[sfx] + at, *(pools[n] for n in names[:2]),
+                cache_len, *(pools[n] for n in names[2:]),
                 page_table=tables[sfx], num_new=num_new, kind=kind,
             )
-            for n, leaf in zip(names, updated):
-                new[n].append(leaf)
+            pools = {**pools, **dict(zip(names, updated))}
             h = h + a
             normed = _norm(cfg, layer["ln2"], h)
             if moe:
@@ -658,24 +672,16 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
                                train=False)
             h = h + m
             h = constrain(h, ("dp", "fsdp"), None, None)
-        out = {n: v[0] if period == 1 else jnp.stack(v)
-               for n, v in new.items()}
-        if collect_moe:
-            lstats = stats[0] if period == 1 else jax.tree.map(
-                lambda *t: jnp.stack(t), *stats)
-            return h, (out, lstats)
-        return h, out
+        if not collect_moe:
+            return (h, pools), None
+        return (h, pools), jax.tree.map(lambda *t: jnp.stack(t), *stats)
 
-    x, ys = lax.scan(
-        body, x, (layers if period == 1
-                  else jnp.arange(cfg.num_layers // period),
-                  {n: grouped(a) for n, a in cache.items()}))
-    if collect_moe:
-        ys, lstats = ys
-        if period > 1:  # [periods, period, ...] -> one row a layer
-            lstats = jax.tree.map(
-                lambda a: a.reshape(-1, *a.shape[2:]), lstats)
-    new_cache = {n: a.reshape(cache[n].shape) for n, a in ys.items()}
+    (x, new_cache), lstats = lax.scan(
+        body, (x, dict(cache)),
+        (jnp.arange(cfg.num_layers // period),
+         layers if period == 1 else None))
+    if collect_moe:  # [trips, period, ...] -> one row a layer
+        lstats = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), lstats)
     x = _norm(cfg, cast(params["final_norm"]), x)
     logits = lm_head_logits(cfg, params, x)
     if return_moe_stats:

@@ -23,10 +23,12 @@ only its last ``window`` keys) the loop starts at the block of row 0's
 oldest visible key, and the blocks that a window's lower edge crosses are
 masked there too.
 
-Layouts: q ``[B, S, H, hd]``, pools ``[P+1, page_size, KV, hd]``
-(init_paged_cache), page_table ``[B, max_pages]`` and the per-row frontiers
-in SMEM. Row ``i`` of slot ``b`` attends ``kpos <= cache_len[b] + i``; the
-chunk's own keys are already in the pool (the caller scatters first).
+Layouts: q ``[B, S, H, hd]``, pools ``[L, P+1, page_size, KV, hd]``: the
+whole stack of init_paged_cache, as stored, with the layer's index a scalar
+in SMEM beside page_table ``[B, max_pages]`` and the per-row frontiers (a
+layer sliced out of the stack would be a copy of the pool every call). Row
+``i`` of slot ``b`` attends ``kpos <= cache_len[b] + i``; the chunk's own
+keys are already in the pool (the caller scatters first).
 """
 
 from __future__ import annotations
@@ -79,9 +81,9 @@ def _head_tiles(buf, KV: int):
             yield kv, flat[kv::KV, :]
 
 
-def _paged_attention_kernel(pt_ref, cl_ref, nn_ref, q_ref, k_hbm, v_hbm,
-                            o_ref, k_buf, v_buf, sems, kh_scr, vh_scr,
-                            m_scr, l_scr, acc_scr,
+def _paged_attention_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, k_hbm,
+                            v_hbm, o_ref, k_buf, v_buf, sems, kh_scr,
+                            vh_scr, m_scr, l_scr, acc_scr,
                             *, scale, page_size, pages_per_block, group,
                             window=None):
     KV, SG, hd = q_ref.shape[1:]
@@ -91,14 +93,15 @@ def _paged_attention_kernel(pt_ref, cl_ref, nn_ref, q_ref, k_hbm, v_hbm,
     b = pl.program_id(0)
     cl = cl_ref[b]
     nn = nn_ref[b]
+    layer = layer_ref[0]
 
     def page_copies(slot, j, page):
         dst = pl.ds(j * ps, ps)
         return (
             pltpu.make_async_copy(
-                k_hbm.at[page], k_buf.at[slot, dst], sems.at[0, slot]),
+                k_hbm.at[layer, page], k_buf.at[slot, dst], sems.at[0, slot]),
             pltpu.make_async_copy(
-                v_hbm.at[page], v_buf.at[slot, dst], sems.at[1, slot]),
+                v_hbm.at[layer, page], v_buf.at[slot, dst], sems.at[1, slot]),
         )
 
     def start_fetch(blk, slot):
@@ -261,13 +264,15 @@ def _frontiers(B: int, S: int, cache_len, num_new):
 
 
 def paged_attention_kernel(q, k_pool, v_pool, cache_len, page_table, *,
-                           num_new=None, block_k: int = DEFAULT_BLOCK_K,
+                           layer, num_new=None,
+                           block_k: int = DEFAULT_BLOCK_K,
                            interpret: Optional[bool] = None,
                            window: Optional[int] = None,
                            name: Optional[str] = None):
-    """q [B,S,H,hd] chunk queries vs a block-paged KV pool
-    k/v_pool [P+1, page_size, KV, hd] addressed through per-slot page
-    tables [B, max_pages]. ``cache_len`` [B] is each slot's frontier BEFORE
+    """q [B,S,H,hd] chunk queries vs layer ``layer`` (a scalar, traced or
+    not) of a block-paged KV pool stack k/v_pool [L, P+1, page_size, KV, hd]
+    addressed through per-slot page tables [B, max_pages], the stack read
+    as stored. ``cache_len`` [B] is each slot's frontier BEFORE
     the chunk (row i attends kpos <= cache_len[b] + i; the caller has
     already scattered the chunk's keys). ``num_new`` [B] (optional) is the
     count of real rows: the loop stops at the last key a real row needs,
@@ -275,7 +280,7 @@ def paged_attention_kernel(q, k_pool, v_pool, cache_len, page_table, *,
     (static) bounds row i to ``kpos > cache_len[b] + i - window`` as well;
     ``name`` is the call's name in a device trace. Returns [B,S,H,hd]."""
     B, S, H, hd = q.shape
-    ps, KV = k_pool.shape[1], k_pool.shape[2]
+    ps, KV = k_pool.shape[2], k_pool.shape[3]
     mp = page_table.shape[1]
     G = H // KV
     SG = S * G
@@ -288,12 +293,12 @@ def paged_attention_kernel(q, k_pool, v_pool, cache_len, page_table, *,
     qg = q.reshape(B, S, KV, G, hd).swapaxes(1, 2).reshape(B, KV, SG, hd)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # page_table, cache_len, num_new
+        num_scalar_prefetch=4,  # page_table, cache_len, num_new, layer
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, KV, SG, hd), lambda b, *_: (b, 0, 0, 0)),
-            # the pools stay in HBM; whole pages ([ps, KV, hd], all heads
-            # contiguous) come in by async copy
+            # the pool stacks stay in HBM; whole pages ([ps, KV, hd], all
+            # heads contiguous) come in by async copy
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
@@ -322,11 +327,12 @@ def paged_attention_kernel(q, k_pool, v_pool, cache_len, page_table, *,
         ),
         interpret=interpret,
         name=name or "paged_attention",
-    )(pt, cl, nn, qg, k_pool, v_pool)
+    )(pt, cl, nn, jnp.asarray(layer, jnp.int32).reshape(1), qg, k_pool,
+      v_pool)
     return out.reshape(B, KV, S, G, hd).swapaxes(1, 2).reshape(B, S, H, hd)
 
 
-def paged_attention(q, k_pool, v_pool, cache_len, page_table, *,
+def paged_attention(q, k_pool, v_pool, cache_len, page_table, *, layer,
                     num_new=None, interpret: Optional[bool] = None,
                     window: Optional[int] = None, name: Optional[str] = None
                     ) -> Tuple[Optional[jax.Array], List[str]]:
@@ -337,7 +343,7 @@ def paged_attention(q, k_pool, v_pool, cache_len, page_table, *,
     from ...models.sharding import current_topology
 
     B, S, H, hd = q.shape
-    ps, KV = k_pool.shape[1], k_pool.shape[2]
+    ps, KV = k_pool.shape[2], k_pool.shape[3]
     mp = page_table.shape[1]
     topo = current_topology()
     distributed = topo is not None and topo.world_size > 1
@@ -396,8 +402,8 @@ def paged_attention(q, k_pool, v_pool, cache_len, page_table, *,
 
     if not distributed:
         return paged_attention_kernel(
-            q, k_pool, v_pool, cache_len, page_table, num_new=num_new,
-            interpret=interp, window=window, name=name,
+            q, k_pool, v_pool, cache_len, page_table, layer=layer,
+            num_new=num_new, interpret=interp, window=window, name=name,
         ), reasons
 
     from jax.sharding import PartitionSpec as P
@@ -408,19 +414,21 @@ def paged_attention(q, k_pool, v_pool, cache_len, page_table, *,
     # page pools are slot-agnostic: heads over tp, pages replicated; the
     # table and the frontiers ride with the (slot) batch
     q_spec = P(b_ax, None, h_ax, None)
-    kv_spec = P(None, None, h_ax, None)
+    kv_spec = P(None, None, None, h_ax, None)
 
-    def body(q, kc, vc, cl, nn, pt):
+    def body(q, kc, vc, cl, nn, pt, layer):
         return paged_attention_kernel(
-            q, kc, vc, cl, pt, num_new=nn, interpret=interp, window=window,
-            name=name,
+            q, kc, vc, cl, pt, layer=layer, num_new=nn, interpret=interp,
+            window=window, name=name,
         )
 
     return jax.shard_map(
         body,
         mesh=topo.mesh,
-        in_specs=(q_spec, kv_spec, kv_spec, P(b_ax), P(b_ax), P(b_ax, None)),
+        in_specs=(q_spec, kv_spec, kv_spec, P(b_ax), P(b_ax), P(b_ax, None),
+                  P()),
         out_specs=q_spec,
         check_vma=False,
     )(q, k_pool, v_pool, *_frontiers(B, S, cache_len, num_new),
-      jnp.asarray(page_table, jnp.int32)), reasons
+      jnp.asarray(page_table, jnp.int32), jnp.asarray(layer, jnp.int32)
+      ), reasons

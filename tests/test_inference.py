@@ -6,10 +6,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from layer_loop_oracle import (assert_bitwise, layer_loop_forward,
+                               random_cache)
+
 from deepspeed_tpu import init_inference
 from deepspeed_tpu.comm.topology import MeshTopology, ParallelDims
 from deepspeed_tpu.models import bloom, gpt2, llama
-from deepspeed_tpu.models.decoding import forward_with_cache, init_cache
+from deepspeed_tpu.models.decoding import (forward_with_cache, init_cache,
+                                           init_paged_cache)
 from deepspeed_tpu.ops.quantizer import (
     dequantize_blockwise,
     quantize_blockwise,
@@ -892,3 +896,100 @@ def test_speculative_verify_window_streams_with_configured_threshold(
     assert cfg_eng.matvec_max_rows == 16  # the "inference." config spelling
     assert 10 in cfg_rows  # the verify window streams now
     np.testing.assert_array_equal(base_out, cfg_out)
+
+
+# ---------------------------------------------- the cache rides the scan
+def _paged_setup(cfg, B, ps, mp, quantized, seed):
+    """A shuffled table over B x mp pages (unmapped tails on the NULL
+    page) and a pool stack filled with noise."""
+    pages = B * mp
+    table = np.random.default_rng(seed).permutation(pages).reshape(B, mp)
+    table[:, -1] = pages  # the NULL page
+    cache = random_cache(
+        init_paged_cache(cfg, pages, ps, jnp.float32, quantized=quantized),
+        seed)
+    return cache, jnp.asarray(table, jnp.int32)
+
+
+@pytest.mark.parametrize(
+    "family,layout,quantized,impl",
+    [
+        ("llama", "paged", False, "xla"),
+        ("llama", "paged", False, "flash"),  # the kernel on stack + layer
+        ("llama", "paged", True, "xla"),
+        ("llama", "contiguous", False, "xla"),
+        ("llama", "contiguous", True, "xla"),
+        ("llama", "scalar", False, "xla"),   # the lockstep engine's form
+        ("llama", "scalar", True, "xla"),
+        ("alibi", "paged", False, "flash"),  # ALiBi: dense lines on a slice
+        ("alibi", "contiguous", False, "xla"),
+        ("bloom", "paged", False, "flash"),
+        ("bloom", "contiguous", False, "xla"),
+    ],
+)
+def test_carried_cache_is_bitwise_the_layer_loop(family, layout, quantized,
+                                                 impl):
+    """forward_with_cache carries the cache stacks through its layer scan
+    and writes each layer in place at its index. Against a plain loop over
+    layers, every layer on a cache of its own: logits and every cache leaf
+    bit for bit, over two chunks (the second attends what the first
+    wrote), ragged frontiers, the other layers' bytes noise."""
+    from deepspeed_tpu.ops.attention import attention_impl
+
+    if family == "bloom":
+        model = bloom("bloom-tiny", vocab_size=128, max_seq_len=64,
+                      hidden_size=32, num_layers=3, num_heads=4)
+    else:
+        model = tiny_llama(num_layers=3, **(
+            dict(pos_embedding="alibi") if family == "alibi" else {}))
+    # BLOOM's LayerNorm is the one piece XLA's CPU backend sums in another
+    # order inside a scan's body than outside one: a few last bits there,
+    # where a wrong layer, page or offset reads noise of order one
+    exact = family != "bloom"
+    cfg = model.config
+    params = model.init(jax.random.PRNGKey(1), dtype=jnp.float32)
+    B, S, ps, mp = 3, 8, 4, 8
+    ids = np.random.RandomState(2).randint(0, 128, size=(2, B, S))
+    kw = {}
+    if layout == "paged":
+        cache, table = _paged_setup(cfg, B, ps, mp, quantized, seed=3)
+        kw = dict(page_table=table)
+    else:
+        cache = random_cache(
+            init_cache(cfg, B, 32, jnp.float32, quantized=quantized), 3)
+    frontier = 5 if layout == "scalar" else jnp.asarray([0, 5, 11], jnp.int32)
+    if layout != "scalar":
+        kw["num_new"] = jnp.asarray([S, 3, S], jnp.int32)
+    got = want = (None, cache)
+    with attention_impl(impl):
+        for chunk in ids:
+            args = (cfg, params, jnp.asarray(chunk))
+            got = jax.jit(lambda c, cl, a=args: forward_with_cache(
+                *a, c, cl, dtype=jnp.float32, **kw))(got[1], frontier)
+            want = layer_loop_forward(*args, want[1], frontier, **kw)
+            assert_bitwise(got, want, atol=0.0 if exact else 1e-6)
+            frontier = frontier + S
+    for n in cache:  # and the step did write: no leaf is what it was
+        assert not np.array_equal(np.asarray(got[1][n]), np.asarray(cache[n]))
+
+
+def test_donated_caches_are_consumed_by_the_step():
+    """Jitted with the caches donated (as both serving steps are), the
+    step takes the input buffers for its outputs: the inputs are deleted,
+    and XLA does not say a donated buffer went unused."""
+    import warnings
+
+    model = tiny_llama(num_layers=3)
+    cfg = model.config
+    params = model.init(jax.random.PRNGKey(1), dtype=jnp.float32)
+    cache, table = _paged_setup(cfg, 2, 4, 8, False, seed=0)
+    step = jax.jit(
+        lambda c, ids, cl: forward_with_cache(
+            cfg, params, ids, c, cl, dtype=jnp.float32, page_table=table),
+        donate_argnums=(0,))
+    ids = jnp.zeros((2, 8), jnp.int32)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        _, new = step(cache, ids, jnp.asarray([0, 3], jnp.int32))
+    assert all(a.is_deleted() for a in cache.values())
+    assert not any(a.is_deleted() for a in new.values())

@@ -16,6 +16,8 @@ import pytest
 
 import deepspeed_tpu
 from benchmarks.families import mellum as fam
+from layer_loop_oracle import (assert_bitwise, layer_loop_forward,
+                               random_cache)
 from deepspeed_tpu.config import DeepSpeedConfigError
 from deepspeed_tpu.models import RopeTable, mellum
 from deepspeed_tpu.models.decoding import (
@@ -209,6 +211,44 @@ def test_the_cached_forward_through_both_pools_is_the_reference(
         assert close(got, want)
 
 
+@pytest.mark.parametrize("impl", ["flash", "xla"])
+def test_two_carried_pools_are_bitwise_the_layer_loop(impl):
+    """Two periods, so a layer's index inside its pool is not its place in
+    the period: window layers 0-2 and 3-5 of a pool of six, full layers 0
+    and 1 of a pool of two, both pools carried through the scan and
+    written in place. Against the plain loop over layers, each on a cache
+    of its own: logits and all four leaves bit for bit, over two chunks,
+    ragged frontiers on both sides of the window, the pools filled with
+    noise; through the two named kernels (``flash``) and the dense lines."""
+    model = mellum("mellum-tiny", num_layers=8, initializer_range=0.2)
+    cfg = model.config
+    params = model.init(jax.random.PRNGKey(2), dtype=jnp.float32)
+    B, S, mp = 3, 8, 12
+    pages = B * mp
+    rng = np.random.default_rng(5)
+    cache = random_cache(init_paged_cache(
+        cfg, pages, PS, jnp.float32, window_pages=pages), seed=5)
+    assert cache["k"].shape[0] == 2 and cache["k" + WIN].shape[0] == 6
+    frontier = jnp.asarray([0, 21, 150], jnp.int32)
+    num_new = jnp.asarray([S, 3, S], jnp.int32)
+    kw = dict(
+        page_table=jnp.asarray(rng.permutation(pages).reshape(B, mp)),
+        page_table_win=jnp.asarray(rng.permutation(pages).reshape(B, mp)),
+        num_new=num_new, token_valid=jnp.arange(S)[None, :] < num_new[:, None])
+    got = want = (None, cache)
+    with attention_impl(impl):
+        for seed in (1, 2):
+            args = (cfg, params, jnp.asarray(
+                np.stack([ids_of(S, seed=10 * seed + b) for b in range(B)])))
+            got = jax.jit(lambda c, cl, a=args: forward_with_cache(
+                *a, c, cl, dtype=jnp.float32, **kw))(got[1], frontier)
+            want = layer_loop_forward(*args, want[1], frontier, **kw)
+            assert_bitwise(got, want)
+            frontier = frontier + S
+    for n in cache:
+        assert not np.array_equal(np.asarray(got[1][n]), np.asarray(cache[n]))
+
+
 @pytest.mark.parametrize("window", [None, 24, 30, 7, 64])
 @pytest.mark.parametrize("block_k", [16, 32])
 def test_windowed_paged_kernel_against_the_dense_lines(window, block_k):
@@ -225,9 +265,9 @@ def test_windowed_paged_kernel_against_the_dense_lines(window, block_k):
                    ([20, 33, 150, 0], [1, 8, 8, 0]),
                    ([23, 24, 25, 100], [8, 1, 2, 8])):
         cl, nn = jnp.asarray(cl, jnp.int32), jnp.asarray(nn, jnp.int32)
-        out = paged_attention_kernel(q, k, v, cl, pt, num_new=nn,
-                                     block_k=block_k, interpret=True,
-                                     window=window)
+        out = paged_attention_kernel(q, k[None], v[None], cl, pt, layer=0,
+                                     num_new=nn, block_k=block_k,
+                                     interpret=True, window=window)
         want = _dense_cached_attention(
             cfg, q, _paged_gather(k, pt), _paged_gather(v, pt), cl,
             window=window)

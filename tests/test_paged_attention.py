@@ -1,6 +1,8 @@
 """The paged Pallas attention of the serving step's [slots, chunk] block
 (ops/pallas/paged_attention.py), in interpret mode: the kernel against the
-dense XLA lines of models/decoding.py on the same pools."""
+dense XLA lines of models/decoding.py on the same pools. The kernel takes
+the pools as init_paged_cache stacks them, [L, P+1, ps, KV, hd], and a
+layer's index; the dense lines read ``stack[layer]``."""
 
 import types
 
@@ -20,9 +22,10 @@ PS, MP, NULL = 8, 32, 40  # page size, pages a slot, the NULL page's index
 def _pools_and_table(r, KV, hd, dtype):
     """40 pages + NULL, five slots: ragged frontiers off every page and
     block boundary, a shuffled table, two slots sharing prefix pages, a
-    table partly on the NULL page, an idle slot, a context of 3+ blocks."""
-    k_pool = jnp.asarray(r.randn(NULL + 1, PS, KV, hd), jnp.float32)
-    v_pool = jnp.asarray(r.randn(NULL + 1, PS, KV, hd), jnp.float32)
+    table partly on the NULL page, an idle slot, a context of 3+ blocks.
+    The pools are stacks of one layer."""
+    k_pool = jnp.asarray(r.randn(1, NULL + 1, PS, KV, hd), jnp.float32)
+    v_pool = jnp.asarray(r.randn(1, NULL + 1, PS, KV, hd), jnp.float32)
     pt = np.full((5, MP), NULL, np.int32)
     pt[0, :7] = [5, 2, 7, 11, 30, 1, 9]
     # slot 1 shares slot 0's first three pages, then diverges
@@ -47,7 +50,7 @@ def _case(S, G, dtype, seed):
     cfg = types.SimpleNamespace(num_heads=H, kv_heads=KV, hd=hd,
                                 pos_embedding="rope")
     ref = np.asarray(_dense_cached_attention(
-        cfg, q, _paged_gather(k_pool, pt), _paged_gather(v_pool, pt),
+        cfg, q, _paged_gather(k_pool[0], pt), _paged_gather(v_pool[0], pt),
         cache_len,
     ))
     # float32 to 1e-5; bf16 operands, probabilities and output each round
@@ -66,7 +69,8 @@ def test_paged_attention_kernel_matches_dense_lines(S, G, dtype):
     num_new = jnp.asarray([S, max(S - 3, 1), 0, S, min(S, 5)], jnp.int32)
     # block_k 32 = 4 pages a block: slot 3 walks 3 blocks or more, slot 0 two
     out = np.asarray(paged_attention_kernel(
-        q, k_pool, v_pool, cache_len, pt, num_new=num_new, block_k=32,
+        q, k_pool, v_pool, cache_len, pt, layer=0, num_new=num_new,
+        block_k=32,
     ).astype(jnp.float32))
     assert out.shape == ref.shape
     for b in range(pt.shape[0]):
@@ -81,7 +85,7 @@ def test_paged_attention_wrapper_every_row_real(dtype):
     """Without num_new every row is real: the whole block matches, through
     the wrapper and its default block (one block holds every context)."""
     q, k_pool, v_pool, pt, cache_len, ref, tol = _case(8, 4, dtype, 5)
-    out, reasons = paged_attention(q, k_pool, v_pool, cache_len, pt)
+    out, reasons = paged_attention(q, k_pool, v_pool, cache_len, pt, layer=0)
     assert reasons == []
     np.testing.assert_allclose(
         np.asarray(out.astype(jnp.float32)), ref, atol=tol, rtol=tol
@@ -98,19 +102,32 @@ def test_paged_attention_work_follows_length():
     k_pool, v_pool, pt, cache_len = _pools_and_table(r, KV, hd, jnp.float32)
     q = jnp.asarray(r.randn(pt.shape[0], S, KV * G, hd), jnp.float32)
     num_new = jnp.asarray([S, S, 0, S, 4], jnp.int32)
-    out = paged_attention_kernel(q, k_pool, v_pool, cache_len, pt,
+    out = paged_attention_kernel(q, k_pool, v_pool, cache_len, pt, layer=0,
                                  num_new=num_new, block_k=16)
     # slot 0 needs keys 0..44: blocks 0-2 of 16 = pages 0-5; its 7th page
     # (physical 9) and the NULL page are never fetched for it
-    poisoned_k = k_pool.at[9].set(jnp.nan).at[NULL].set(jnp.nan)
+    poisoned_k = k_pool.at[0, 9].set(jnp.nan).at[0, NULL].set(jnp.nan)
     out2 = paged_attention_kernel(q, poisoned_k, v_pool, cache_len, pt,
-                                  num_new=num_new, block_k=16)
+                                  layer=0, num_new=num_new, block_k=16)
     np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(out2[0]))
     np.testing.assert_array_equal(np.asarray(out[3]), np.asarray(out2[3]))
 
 
-def test_paged_attention_under_tp_matches_one_device():
-    """Heads over tp through the shard_map wrapper: the same numbers."""
+def _layer_cases():
+    return [(L, layer) for L in (1, 3) for layer in range(L)]
+
+
+def _stack_with_noise_elsewhere(pool, L, layer):
+    """[1, P+1, ...] -> [L, P+1, ...]: ``pool`` at ``layer``, NaN in every
+    other layer, so a read of the wrong layer shows."""
+    return jnp.full((L, *pool.shape[1:]), jnp.nan, pool.dtype).at[layer].set(
+        pool[0])
+
+
+@pytest.mark.parametrize("L,layer", _layer_cases())
+def test_paged_attention_under_tp_matches_one_device(L, layer):
+    """Heads over tp through shard_map: the stack's spec has the layer
+    axis unsharded in front, the layer's index rides replicated."""
     from deepspeed_tpu.comm.topology import MeshTopology, ParallelDims
     from deepspeed_tpu.models.sharding import use_topology
 
@@ -118,12 +135,15 @@ def test_paged_attention_under_tp_matches_one_device():
     r = np.random.RandomState(3)
     k_pool, v_pool, pt, cache_len = _pools_and_table(r, KV, hd, jnp.float32)
     q = jnp.asarray(r.randn(pt.shape[0], S, KV * G, hd), jnp.float32)
-    one, _ = paged_attention(q, k_pool, v_pool, cache_len, pt)
+    one, _ = paged_attention(q, k_pool, v_pool, cache_len, pt, layer=0)
+    k_stack = _stack_with_noise_elsewhere(k_pool, L, layer)
+    v_stack = _stack_with_noise_elsewhere(v_pool, L, layer)
     topo = MeshTopology(dims=ParallelDims(tp=2), devices=jax.devices()[:2])
     with use_topology(topo):
         two, reasons = jax.jit(
-            lambda *a: paged_attention(*a)
-        )(q, k_pool, v_pool, cache_len, pt)
+            lambda at: paged_attention(q, k_stack, v_stack, cache_len, pt,
+                                       layer=at)
+        )(jnp.int32(layer))
     assert reasons == []
     np.testing.assert_allclose(np.asarray(two), np.asarray(one), atol=1e-6)
 
@@ -149,14 +169,49 @@ def test_paged_attention_steps_aside_with_reasons(kw, why):
     ps, sds = 16, jax.ShapeDtypeStruct
 
     def fn(q, k, v, cl, pt):
-        out, reasons = paged_attention(q, k, v, cl, pt, interpret=False)
+        out, reasons = paged_attention(q, k, v, cl, pt, layer=1,
+                                       interpret=False)
         assert out is None
         assert any(why in r for r in reasons), reasons
         return cl
 
     jax.eval_shape(
         fn, sds((B, S, H, hd), jnp.bfloat16),
-        sds((65, ps, KV, hd), jnp.dtype(pool_dtype)),
-        sds((65, ps, KV, hd), jnp.dtype(pool_dtype)),
+        sds((3, 65, ps, KV, hd), jnp.dtype(pool_dtype)),
+        sds((3, 65, ps, KV, hd), jnp.dtype(pool_dtype)),
         sds((B,), jnp.int32), sds((B, mp), jnp.int32),
     )
+
+
+@pytest.mark.parametrize("name,window", [
+    (None, None), ("paged_attention_full", None),
+    ("paged_attention_window", 24),
+])
+@pytest.mark.parametrize("L,layer", _layer_cases())
+def test_kernel_reads_its_layer_of_the_stack(L, layer, name, window):
+    """The pools come stacked, [L, P+1, ps, KV, hd], with the layer's index
+    a traced scalar: the kernel attends ``stack[layer]`` as the dense lines
+    do on that slice, under each of its three names, and touches no other
+    layer (they hold NaN)."""
+    KV, G, hd, S = 2, 2, 32, 8
+    r = np.random.RandomState(11 * L + layer)
+    k_pool, v_pool, pt, cache_len = _pools_and_table(r, KV, hd, jnp.float32)
+    q = jnp.asarray(r.randn(pt.shape[0], S, KV * G, hd), jnp.float32)
+    num_new = jnp.asarray([S, S - 3, 0, S, 5], jnp.int32)
+    k_stack = _stack_with_noise_elsewhere(k_pool, L, layer)
+    v_stack = _stack_with_noise_elsewhere(v_pool, L, layer)
+    cfg = types.SimpleNamespace(num_heads=KV * G, kv_heads=KV, hd=hd,
+                                pos_embedding="rope")
+    ref = np.asarray(_dense_cached_attention(
+        cfg, q, _paged_gather(k_stack[layer], pt),
+        _paged_gather(v_stack[layer], pt), cache_len, window=window))
+    out = np.asarray(jax.jit(
+        lambda at: paged_attention_kernel(
+            q, k_stack, v_stack, cache_len, pt, layer=at, num_new=num_new,
+            block_k=32, window=window, name=name)
+    )(jnp.int32(layer)))
+    assert np.isfinite(out).all()
+    for b in range(pt.shape[0]):
+        n = int(num_new[b])
+        np.testing.assert_allclose(out[b, :n], ref[b, :n], atol=1e-5,
+                                   rtol=1e-5)

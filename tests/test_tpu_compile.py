@@ -15,6 +15,7 @@ never at import (on-chip-measurement guide, section 2).
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -164,39 +165,71 @@ def test_paged_decode_compiles(one_chip, int8):
 def test_paged_attention_compiles(one_chip, B, S, pages, per_slot):
     H, KV, hd, ps = 32, 8, 128, 16
 
-    def step(q, k, v, cl, nn, pt):
+    def step(q, k, v, cl, nn, pt, layer):
         return pa.paged_attention_kernel(
-            q, k, v, cl, pt, num_new=nn, interpret=False,
+            q, k, v, cl, pt, layer=layer, num_new=nn, interpret=False,
         )
 
     text = _compile(
         step, one_chip,
-        ((B, S, H, hd), BF16), ((pages, ps, KV, hd), BF16),
-        ((pages, ps, KV, hd), BF16), ((B,), I32), ((B,), I32),
-        ((B, per_slot), I32),
+        ((B, S, H, hd), BF16), ((3, pages, ps, KV, hd), BF16),
+        ((3, pages, ps, KV, hd), BF16), ((B,), I32), ((B,), I32),
+        ((B, per_slot), I32), ((), I32),
     )
     assert "tpu_custom_call" in text, text[:2000]
 
 
-# ------------------------------- a whole slot step, pages by layer kind
-def test_mellum_slot_step_compiles_beside_its_arena(one_chip, monkeypatch,
-                                                    capsys):
-    """The one [8, 128] serving step of Mellum2-12B-A2.5B at its published
-    widths and the benchmark's depth (12 layers, three periods of three
-    window layers and a full one), with the benchmark's arena: 8,384 pages
-    for the full layers, 592 for the window layers. The chip's compiler
-    has to take it beside the 16 GB, with both named attention kernels."""
-    from deepspeed_tpu.models import mellum
-    from deepspeed_tpu.models.decoding import init_paged_cache
+# --------------------------------- a whole slot step beside its arena
+GIB = 2.0 ** 30
+# the slot steps' temporaries before the caches rode the layer scan as its
+# carry (memory_analysis() for the described v5e of these same two tests
+# on PR 30's tree): each held a sliced layer and a rebuilt stack of every
+# pool
+PARENT_TEMP_GIB = {"mixtral": 1.32, "mellum": 1.89}
+
+
+def _pool_copies(text, caches):
+    """The instructions of a compiled step that re-materialise a cache:
+    a ``copy``, ``dynamic-slice`` or ``dynamic-update-slice``, or a fusion
+    that ends in one, whose result is a whole stack of ``caches``, one
+    layer of it or any other leading split of it (a period's share). An
+    in-place scatter and the Pallas call are what may touch a stack."""
+    tails = {",".join(map(str, a.shape[1:])) for a in caches.values()}
+    moves = ("copy", "dynamic-slice", "dynamic-update-slice")
+    roots, comp = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            comp = head.group(1)
+        root = re.match(r"^\s+ROOT %[\w.\-]+ = [^ ]+ ([\w\-]+)\(", line)
+        if root and comp:
+            roots[comp] = root.group(1)
+    found = []
+    for line in text.splitlines():
+        m = re.match(
+            r"^\s+(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]+)\]\S* ([\w\-]+)\(",
+            line)
+        if not m:
+            continue
+        name, dims, op = m.groups()
+        if not any(dims == t or dims.endswith("," + t) for t in tails):
+            continue
+        if op == "fusion":
+            called = re.search(r"calls=%([\w.\-]+)", line)
+            op = roots.get(called.group(1), op) if called else op
+        if op in moves:
+            found.append(f"{name}: {op} -> [{dims}]")
+    return found
+
+
+def _compile_slot_step(model, caches, one_chip, N, W, mp):
+    """``make_paged_step_fn`` of ``model`` jitted as the serving engine
+    jits it (caches and ``seen`` donated), compiled for the described
+    chip with the kernel attention registered."""
     from deepspeed_tpu.ops.attention import attention_impl
     from deepspeed_tpu.serving.engine import make_paged_step_fn
 
-    # the kernels pick interpret mode from the backend, the CPU here
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    model = mellum("mellum2-12b-a2.5b", num_layers=12)
     cfg = model.config
-    N, W, ps, cap = 8, 128, 16, 16640
-    mp = -(-(cap + W) // ps)
 
     def sds(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
@@ -206,28 +239,81 @@ def test_mellum_slot_step_compiles_beside_its_arena(one_chip, monkeypatch,
 
     params = jax.tree.map(sds, jax.eval_shape(
         lambda k: model.init(k, dtype=BF16), jax.random.PRNGKey(0)))
-    caches = jax.tree.map(sds, jax.eval_shape(
-        lambda: init_paged_cache(cfg, N * mp, ps, BF16, window_pages=592)))
     step = make_paged_step_fn(cfg, BF16, cfg.vocab_size)
+    # a model with window layers brings their table beside the full ones'
+    tables = [vec(I32, mp)] * (2 if cfg.has_window else 1)
     with attention_impl("flash"):
-        compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
-            params, caches, vec(jnp.bool_, cfg.vocab_size), vec(I32, W),
-            vec(I32), vec(I32), vec(I32, mp), vec(I32, mp), vec(I32),
+        return jax.jit(step, donate_argnums=(1, 2)).lower(
+            params, jax.tree.map(sds, caches),
+            vec(jnp.bool_, cfg.vocab_size), vec(I32, W),
+            vec(I32), vec(I32), *tables, vec(I32),
             vec(jnp.bool_), vec(jnp.bool_), vec(I32), vec(I32),
             vec(jnp.uint32, 2), vec(F32), vec(I32), vec(F32), vec(F32),
         ).compile()
+
+
+def _check_caches_stay_in_place(compiled, caches, family, capsys):
+    """No pool is re-materialised, the donated pools are the outputs'
+    buffers, and the temporaries fell by the copies no longer held."""
     m = compiled.memory_analysis()
-    gib = 2.0 ** 30
+    pools = sum(a.size * a.dtype.itemsize for a in caches.values())
     with capsys.disabled():
-        print(f"\nmellum slot step, depth 12, described v5e: arguments "
-              f"{m.argument_size_in_bytes / gib:.2f} GiB, temporaries "
-              f"{m.temp_size_in_bytes / gib:.2f} GiB, output "
-              f"{m.output_size_in_bytes / gib:.2f} GiB (aliased "
-              f"{m.alias_size_in_bytes / gib:.2f})")
-    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * gib
-    # a period-sized slice of the expert banks (3 x 0.98 GiB) must not be
-    # among the temporaries: the layers are read one at a time
-    assert m.temp_size_in_bytes < 2.5 * gib
+        print(f"\n{family} slot step, described v5e: arguments "
+              f"{m.argument_size_in_bytes / GIB:.2f} GiB, temporaries "
+              f"{m.temp_size_in_bytes / GIB:.2f} GiB (the parent's "
+              f"{PARENT_TEMP_GIB[family]:.2f}), output "
+              f"{m.output_size_in_bytes / GIB:.2f} GiB (aliased "
+              f"{m.alias_size_in_bytes / GIB:.2f}, the pools "
+              f"{pools / GIB:.2f})")
+    assert _pool_copies(compiled.as_text(), caches) == []
+    assert m.alias_size_in_bytes >= pools
+    assert m.temp_size_in_bytes < PARENT_TEMP_GIB[family] * GIB - pools
+    return m
+
+
+def test_mixtral_slot_step_keeps_its_pools_in_place(one_chip, monkeypatch,
+                                                    capsys):
+    """The one [16, 128] serving step of Mixtral-8x7B at the benchmark's
+    depth 3 over its arena of 4,096 pages: the K/V stacks ride the layer
+    scan as its carry, so the compiled step holds no copy, slice or
+    write-back of a pool's or a layer's size."""
+    from deepspeed_tpu.models import mixtral
+    from deepspeed_tpu.models.decoding import init_paged_cache
+
+    # the kernels pick interpret mode from the backend, the CPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = mixtral("mixtral-8x7b", num_layers=3, max_seq_len=32768,
+                    moe_capacity_factor=4.0)
+    N, W, ps, cap = 16, 128, 16, 8320
+    caches = jax.eval_shape(
+        lambda: init_paged_cache(model.config, 4096, ps, BF16))
+    compiled = _compile_slot_step(model, caches, one_chip, N, W,
+                                  -(-(cap + W) // ps))
+    _check_caches_stay_in_place(compiled, caches, "mixtral", capsys)
+    assert "paged_attention" in compiled.as_text()
+
+
+def test_mellum_slot_step_compiles_beside_its_arena(one_chip, monkeypatch,
+                                                    capsys):
+    """The one [8, 128] serving step of Mellum2-12B-A2.5B at its published
+    widths and the benchmark's depth (12 layers, three periods of three
+    window layers and a full one), with the benchmark's arena: 8,384 pages
+    for the full layers, 592 for the window layers. The chip's compiler
+    has to take it beside the 16 GB, with both named attention kernels,
+    and with neither pool re-materialised in any form (whole, a layer, a
+    period's share)."""
+    from deepspeed_tpu.models import mellum
+    from deepspeed_tpu.models.decoding import init_paged_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = mellum("mellum2-12b-a2.5b", num_layers=12)
+    N, W, ps, cap = 8, 128, 16, 16640
+    mp = -(-(cap + W) // ps)
+    caches = jax.eval_shape(lambda: init_paged_cache(
+        model.config, N * mp, ps, BF16, window_pages=592))
+    compiled = _compile_slot_step(model, caches, one_chip, N, W, mp)
+    m = _check_caches_stay_in_place(compiled, caches, "mellum", capsys)
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * GIB
     text = compiled.as_text()
     assert "paged_attention_window" in text and "paged_attention_full" in text
 
