@@ -5,9 +5,9 @@ compile-and-measure probe (autotuning/planner_search.py prunes and ranks
 on it). That substitution is only safe while predictions track reality,
 so every measured survivor banks a ``(predicted, measured)`` pair here:
 
-- ``bench.py`` appends one entry per BENCH run (``result["plan"]`` now
-  carries the drift verdict alongside the prediction);
 - the autotuner appends one entry per compiled top-k survivor;
+- ``tools/autoplan.py --campaign`` appends one entry per measured
+  lattice survivor, tagged ``campaign``;
 - ``tools/autoplan.py --check`` is the CI regression gate: it re-runs
   the search on the reduced 410M leg, banks fresh pairs, and exits 1
   when any pair leaves the documented band.
@@ -66,9 +66,9 @@ def check_pair(predicted: Optional[float], measured: Optional[float],
                ) -> Dict[str, Any]:
     """ONE (predicted, measured) pair against its generation's band —
     THE definition of "drifted", shared by the offline ledger gate
-    (:func:`check`), bench.py's per-run verdict and the healthwatch
-    live drift alarm (profiling/healthwatch.py ``plan_drift``), so the
-    band constants exist exactly once.
+    (:func:`check`) and the healthwatch live drift alarm
+    (profiling/healthwatch.py ``plan_drift``), so the band constants
+    exist exactly once.
 
     Returns ``{"ok", "ratio", "band", "gen"}``; an unmeasurable pair
     (measured <= 0 / None) yields ``ratio None, ok False``. Callers
@@ -95,8 +95,8 @@ def default_ledger_path() -> str:
     """``SHARDPLAN_DRIFT_LEDGER`` env override, else a stable per-user
     cache location — NOT the cwd: planner-mode autotuning auto-engages
     for library callers, and a library must not scatter perf/ dirs
-    wherever the process happens to run. bench.py and the CI gate pass
-    explicit repo-anchored paths."""
+    wherever the process happens to run. The CI gate passes an
+    explicit repo-anchored path."""
     return os.environ.get(
         "SHARDPLAN_DRIFT_LEDGER",
         os.path.join(os.path.expanduser("~"), ".cache", "deepspeed_tpu",
@@ -144,7 +144,7 @@ class DriftLedger:
         """Best-effort append: an unwritable ledger (read-only CI
         checkout, a path component that's a file, missing permissions)
         logs ONE warning and drops the entry — the ledger is evidence,
-        and evidence-keeping must never crash a bench or tuner run."""
+        and evidence-keeping must never crash a tuner run."""
         try:
             d = os.path.dirname(self.path)
             if d:
@@ -245,7 +245,7 @@ def check(entries: Sequence[Dict[str, Any]],
                             f"(ratio={ratio!r})")
             continue
         # the ONE drifted-pair predicate (shared with the healthwatch
-        # live alarm and bench's per-run verdict)
+        # live alarm)
         verdict = check_pair(None, None, r.get("gen", ""), ratio=ratio,
                              band=band)
         if not verdict["ok"]:

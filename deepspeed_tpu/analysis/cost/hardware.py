@@ -1,8 +1,8 @@
 """Hardware envelope the planner prices programs against.
 
-One dataclass, per-TPU-generation defaults (the one table bench.py and
-chip_smoke.py read as well), env-var overrides shared with the bench legs so
-a BENCH run and its shardplan prediction price the same machine:
+One dataclass, per-TPU-generation defaults (the one table chip_smoke.py
+reads as well), env-var overrides so a measured run and its shardplan
+prediction price the same machine:
 
 - ``DSTPU_TPU_GEN``          chip generation ("v4"/"v5e"/"v5p"/"v6e",
                              or "cpu" for the host-mesh envelope)
@@ -34,8 +34,8 @@ from typing import Any, Dict, Optional, Tuple
 
 _GIB = float(1 << 30)
 
-# (bf16 peak flops, HBM bytes, HBM GB/s) per generation. Peaks match
-# bench.peak_flops_per_chip; HBM bandwidth is the published spec number.
+# (bf16 peak flops, HBM bytes, HBM GB/s) per generation, the published
+# spec numbers.
 # The "cpu" row is the virtual-host-device envelope: ~3 GF/s effective
 # per device on a contended 8-device host mesh (measured, see
 # docs/autotuning.md "Drift bands"), 16 GiB as a neutral budget column.
@@ -48,7 +48,7 @@ _GEN_TABLE = {
 }
 
 # per-generation (ici GB/s, host-DMA GB/s, dcn GB/s) defaults when the
-# bench env overrides are unset; TPU gens share the historical 45/32
+# BENCH_*_BW_GBS overrides are unset; TPU gens share the historical 45/32
 # numbers. The DCN figure is deliberately conservative: ~25 Gbit/s of
 # per-device share on the inter-pod data-center network (a 4x-NIC host
 # divided over its chips), an order of magnitude under any ICI link —
@@ -251,7 +251,7 @@ class HardwareModel:
 
     @classmethod
     def detect(cls) -> "HardwareModel":
-        """Defaults for the local generation + the bench env overrides.
+        """Defaults for the local generation + the env overrides.
 
         ``DSTPU_TPU_GEN`` pins the generation; otherwise a live CPU
         backend selects the ``cpu`` envelope (so lint-mesh plans and drift

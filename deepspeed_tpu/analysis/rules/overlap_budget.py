@@ -3,9 +3,9 @@
 The engine's performance story for hidden streams — the double-buffered
 ZeRO-offload prefetch (PR 1) and the decomposed-TP ring hops (PR 3) — is
 an overlap *claim*: the stream's wall time hides under the compute the
-step provides. PERF_NOTES round 7 states the ceiling analytically
-(speedup ≈ 1/(1 − f·overlap_ratio) only while the hidden bytes fit the
-window); this rule enforces it statically.
+step provides. The ceiling is analytic (speedup ≈ 1/(1 − f·overlap_ratio)
+only while the hidden bytes fit the window); this rule enforces it
+statically.
 
 For every stream the engine declares as overlapped
 (``engine.analytic_streams()`` → ``overlapped: True``), the per-device
@@ -84,8 +84,8 @@ def overlap_budget(ctx: LintContext) -> List[Finding]:
                 f"({bw / 1e9:.0f} GB/s) needs {stream_s:.4f}s — more than "
                 f"the {window_s:.4f}s compute window the step provides "
                 f"(MXU {plan.compute_s:.4f}s, HBM {plan.hbm_s:.4f}s); the "
-                "bytes cannot be hidden even at full overlap (the PERF_NOTES "
-                "round-7 ceiling) — shrink the stream or drop the knob"
+                "bytes cannot be hidden even at full overlap — shrink the "
+                "stream or drop the knob"
             ),
             where="<plan>",
         ))

@@ -169,9 +169,8 @@ class Autotuner:
                  blocks: Tuple[int, int] = (0, 0),
                  cfg: Optional[Dict[str, Any]] = None) -> Optional[float]:
         """One candidate: fresh engine → compile+warmup → chained-dispatch
-        timing → tokens/sec. This is THE compile+measure loop — the operator
-        sweep (tools/sweep_train.py) is a CLI over it, so the two tuners
-        cannot drift.
+        timing → tokens/sec. This is THE compile+measure loop: the campaign
+        (autotuning/campaign.py) measures its survivors through it too.
 
         Timing: each trial dispatches a chain of steps with ONE blocking
         read at the end, and trials are reduced by median (a one-chip
@@ -190,9 +189,9 @@ class Autotuner:
             batch = self.sample_batch_fn(cfg["train_batch_size"])
             # stage once: no upload before each dispatch
             staged = engine.prepare_batch(dict(batch))
-            # the scanned chain is the program bench.py times: one dispatch
-            # and one readback per trial, and only ONE compile per candidate
-            # (the single-step program never compiles)
+            # the scanned chain: one dispatch and one readback per trial,
+            # and only ONE compile per candidate (the single-step program
+            # never compiles)
             chain = max(self.end_step - self.start_step, 1)
             engine.train_batch_chain(batch=staged, steps=chain)  # compile
             float(engine.state.step)  # settle before the timed region

@@ -988,7 +988,6 @@ class TpuKernelsConfig:
 
     flash_attention: Any = AUTO  # auto | True | False
     fused_rmsnorm: Any = False  # covers rmsnorm AND layernorm; opt-in
-    fused_adam: Any = False  # optax update already fuses into the step
     flash_block_q: int = 0  # 0 => kernel default
     flash_block_k: int = 0
     flash_block_q_bwd: int = 0  # 0 => inherit the fwd tile (dq/dkv kernels)
@@ -1006,7 +1005,6 @@ class TpuKernelsConfig:
         return TpuKernelsConfig(
             flash_attention=res(self.flash_attention),
             fused_rmsnorm=res(self.fused_rmsnorm),
-            fused_adam=res(self.fused_adam),
             flash_block_q=int(self.flash_block_q),
             flash_block_k=int(self.flash_block_k),
             flash_block_q_bwd=int(self.flash_block_q_bwd),

@@ -217,7 +217,7 @@ class CommsLogger:
         fully serialized, 1 = fully overlapped. ``stream_s`` is the
         estimated stream wall time (bytes / link bandwidth) — the
         offload A/B passes the host-DMA seconds, the decomposed-TP ring
-        A/B (bench.py BENCH_TP_OVERLAP_AB) the ring-wire seconds.
+        A/B the ring-wire seconds.
 
         This is THE hardened degenerate-input path (there is exactly
         one): an empty/zero-byte stream (stream_s 0), unmeasured step

@@ -487,11 +487,7 @@ class TpuEngine:
             self.optimizer_tx = (
                 optimizer
                 if isinstance(optimizer, optax.GradientTransformation)
-                else build_optimizer(
-                    config.optimizer,
-                    self.lr_schedule,
-                    use_pallas_adam=tk.fused_adam,
-                )
+                else build_optimizer(config.optimizer, self.lr_schedule)
             )
 
         # ---- sharding specs -------------------------------------------------
@@ -1159,7 +1155,7 @@ class TpuEngine:
         transformer-shaped). Reported to the comms logger per step — the
         trace-time hook bus under-counts scanned layers (a scan body
         traces once), so the analytic figure is the honest per-step
-        number. ``seq`` defaults to the model's max_seq_len (the bench
+        number. ``seq`` defaults to the model's max_seq_len (the static
         estimate); recording passes the actual batch length."""
         if self.tp_overlap is None:
             return None
@@ -2586,18 +2582,6 @@ class TpuEngine:
         return loss, trace_dir
 
     # --------------------------------------------------------- steptrace
-    def enable_tracing(self, max_spans: int = 100_000):
-        """Attach the steptrace registry AFTER construction (bench.py's
-        phase-table leg turns tracing on post-measurement so span fences
-        never perturb the banked number). Equivalent to building with
-        ``{"steptrace": {"enabled": true}}``."""
-        from ..profiling import steptrace as _steptrace
-
-        self.tracer = _steptrace.configure(max_spans=max_spans)
-        if self.comm_logger is not None:
-            self.comm_logger.registry = self.tracer
-        return self.tracer
-
     def trace_export(self, path: Optional[str] = None) -> str:
         """Write the Chrome trace-event JSON (Perfetto-loadable; see
         docs/observability.md). Every declared ``analytic_streams()``
@@ -2607,8 +2591,7 @@ class TpuEngine:
         if self.tracer is None:
             raise RuntimeError(
                 "steptrace is not enabled on this engine — set "
-                '{"steptrace": {"enabled": true}} in the config or call '
-                "enable_tracing() first"
+                '{"steptrace": {"enabled": true}} in the config'
             )
         measured = self.tracer.mean_dur("train/step")
         try:
@@ -2667,22 +2650,6 @@ class TpuEngine:
                 log_dist(f"healthwatch: ckpt budget skipped: {e}")
         return self.healthwatch
 
-    def enable_healthwatch(self, **overrides):
-        """Attach healthwatch AFTER construction (bench.py's goodput leg
-        turns it on post-measurement so the watchdog taps never perturb
-        the banked number). ``overrides`` merge over the config's
-        ``healthwatch`` section; ``enabled`` is forced on."""
-        if self.healthwatch is not None:
-            return self.healthwatch
-        from ..config import HealthwatchConfig, _parse_dc
-
-        section = dict(self.config.raw.get("healthwatch") or {})
-        section.update(overrides)
-        section["enabled"] = True
-        cfg = _parse_dc(HealthwatchConfig, section)
-        cfg.validate()
-        return self._build_healthwatch(cfg)
-
     def dump_postmortem(self, path: Optional[str] = None,
                         reason: str = "explicit") -> Optional[str]:
         """Write the flight-recorder postmortem JSON (render/validate
@@ -2690,8 +2657,7 @@ class TpuEngine:
         if self.healthwatch is None:
             raise RuntimeError(
                 "healthwatch is not enabled on this engine — set "
-                '{"healthwatch": {"enabled": true}} in the config or '
-                "call enable_healthwatch() first"
+                '{"healthwatch": {"enabled": true}} in the config'
             )
         return self.healthwatch.dump_postmortem(path=path, reason=reason)
 

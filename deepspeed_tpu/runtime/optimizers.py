@@ -2,9 +2,8 @@
 
 Parity: deepspeed/ops/adam (FusedAdam), lion, adagrad, lamb, sgd — the
 reference's fused CUDA multi-tensor kernels become optax transforms whose
-update math XLA fuses into the sharded train step; the Pallas fused-adam
-kernel (ops/pallas/fused_adam.py) is used on TPU for the flat update when
-enabled. 1-bit optimizers live in ops/onebit.py.
+update math XLA fuses into the sharded train step. 1-bit optimizers live
+in ops/onebit.py.
 """
 
 from __future__ import annotations
@@ -29,10 +28,7 @@ def _lamb(learning_rate, b1=0.9, b2=0.999, eps=1e-6, weight_decay=0.0):
 
 
 def build_optimizer(
-    cfg: OptimizerConfig,
-    lr_schedule: Callable,
-    *,
-    use_pallas_adam: bool = False,
+    cfg: OptimizerConfig, lr_schedule: Callable
 ) -> optax.GradientTransformation:
     """Build the optax transform from an "optimizer" config section.
 
@@ -47,20 +43,11 @@ def build_optimizer(
     common = dict(b1=betas[0], b2=betas[1], eps=cfg.eps)
 
     if name in ("adam", "adamw", "fusedadam"):
-        if use_pallas_adam:
-            from ..ops.pallas.fused_adam import scale_by_fused_adam
-
-            base = optax.chain(
-                scale_by_fused_adam(b1=betas[0], b2=betas[1], eps=cfg.eps),
-                optax.add_decayed_weights(cfg.weight_decay),
-                optax.scale(-1.0),
-            )
-        else:
-            base = optax.chain(
-                optax.scale_by_adam(**common),
-                optax.add_decayed_weights(cfg.weight_decay),
-                optax.scale(-1.0),
-            )
+        base = optax.chain(
+            optax.scale_by_adam(**common),
+            optax.add_decayed_weights(cfg.weight_decay),
+            optax.scale(-1.0),
+        )
         tx = optax.chain(base, _scale_by_schedule_positive(lr_schedule))
     elif name == "lion":
         tx = optax.chain(

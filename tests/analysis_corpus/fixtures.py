@@ -850,7 +850,7 @@ def _declared_stream(nbytes: float):
 def unhideable_offload_stream():
     # 64 GiB/step over a 32 GB/s host link is ~2 s of DMA; the tiny
     # matmul's compute window is microseconds — the overlap claim is
-    # statically false (the PERF_NOTES round-7 ceiling)
+    # statically false
     closed, kw = _declared_stream(64 * (1 << 30))
     return closed, kw, "R8"
 

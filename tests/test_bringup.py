@@ -60,9 +60,8 @@ def test_compile_cache_default_is_fixed_path_in_checkout(monkeypatch,
 
 
 def test_no_cache_path_under_tmp_in_entry_scripts():
-    for name in ("bench.py", "chip_smoke.py"):
-        with open(os.path.join(REPO, name)) as f:
-            assert "/tmp" not in f.read(), name
+    with open(os.path.join(REPO, "chip_smoke.py")) as f:
+        assert "/tmp" not in f.read()
 
 
 # ------------------------------------------------------------- native build
@@ -160,7 +159,6 @@ def test_chip_smoke_serve_depth_counts_the_float32_draw():
 
 # ------------------------------------------------ parents stay off jax
 @pytest.mark.parametrize("rel", ["tools/elastic_run.py",
-                                 "tools/sweep_train.py",
                                  "__graft_entry__.py"])
 def test_launcher_parent_does_not_import_jax(rel):
     """Importing the launcher (what its parent process does before it

@@ -1,4 +1,4 @@
-"""Optimizers (incl. 1-bit family) + Pallas fused-adam/rmsnorm kernels
+"""Optimizers (incl. 1-bit family) + Pallas rmsnorm kernels
 (SURVEY §2.1, §2.4). Kernels run interpret=True on the CPU mesh."""
 
 import jax
@@ -12,7 +12,6 @@ from deepspeed_tpu.comm.topology import MeshTopology, ParallelDims
 from deepspeed_tpu.config import OptimizerConfig
 from deepspeed_tpu.models import gpt2
 from deepspeed_tpu.ops.onebit import scale_by_onebit_adam
-from deepspeed_tpu.ops.pallas.fused_adam import _fused_adam_flat
 from deepspeed_tpu.ops.pallas.rmsnorm import rmsnorm as pallas_rmsnorm
 from deepspeed_tpu.runtime.lr_schedules import build_schedule
 from deepspeed_tpu.runtime.optimizers import build_optimizer
@@ -93,41 +92,6 @@ def test_onebit_engine_trains():
             batch={"input_ids": r.randint(0, 64, size=(8, 16))}
         )
         assert np.isfinite(float(loss))
-
-
-def test_fused_adam_kernel_matches_reference():
-    r = np.random.RandomState(0)
-    n = 1000  # deliberately unaligned
-    pad = (-n) % (128 * 8)
-    g = jnp.asarray(np.pad(r.randn(n).astype(np.float32), (0, pad)))
-    m = jnp.asarray(np.pad(r.randn(n).astype(np.float32) * 0.1, (0, pad)))
-    v = jnp.asarray(np.pad(np.abs(r.randn(n)).astype(np.float32) * 0.01, (0, pad)))
-    b1, b2, eps = 0.9, 0.999, 1e-8
-    bc = jnp.asarray([1 - b1**3, 1 - b2**3], jnp.float32)
-    out, m2, v2 = _fused_adam_flat(g, m, v, bc, b1=b1, b2=b2, eps=eps,
-                                   interpret=True)
-    m_ref = b1 * m + (1 - b1) * g
-    v_ref = b2 * v + (1 - b2) * g * g
-    out_ref = (m_ref / bc[0]) / (jnp.sqrt(v_ref / bc[1]) + eps)
-    np.testing.assert_allclose(np.asarray(m2), np.asarray(m_ref), rtol=1e-4, atol=1e-7)
-    np.testing.assert_allclose(np.asarray(v2), np.asarray(v_ref), rtol=1e-4, atol=1e-7)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref), rtol=1e-4, atol=1e-6)
-
-
-def test_pallas_adam_optimizer_trajectory():
-    """scale_by_fused_adam (jnp fallback on CPU) == optax.scale_by_adam."""
-    from deepspeed_tpu.ops.pallas.fused_adam import scale_by_fused_adam
-
-    fused, ref = scale_by_fused_adam(), optax.scale_by_adam()
-    params = {"w": jnp.ones((16, 8))}
-    s1, s2 = fused.init(params), ref.init(params)
-    r = np.random.RandomState(2)
-    for _ in range(4):
-        g = {"w": jnp.asarray(r.randn(16, 8), jnp.float32)}
-        u1, s1 = fused.update(g, s1, params)
-        u2, s2 = ref.update(g, s2, params)
-        np.testing.assert_allclose(np.asarray(u1["w"]), np.asarray(u2["w"]),
-                                   rtol=1e-5, atol=1e-6)
 
 
 def test_pallas_rmsnorm_uneven_rows():

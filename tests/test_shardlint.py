@@ -157,9 +157,9 @@ def test_lint_speed_budget(devices8):
     offload)."""
     import time
 
-    import bench
+    from deepspeed_tpu.analysis.targets import lint_targets
 
-    name, model, cfg = bench.lint_targets(len(jax.devices()))[-1]
+    name, model, cfg = lint_targets(len(jax.devices()))[-1]
     assert name == "bench-1b-offload-db"
     comm.destroy_process_group()
     t0 = time.time()

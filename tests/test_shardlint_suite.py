@@ -144,8 +144,8 @@ def test_captured_suite_configs_lint_clean(devices8):
 
 def test_cli_all_examples_clean_and_fast(devices8, tmp_path):
     """The tier-1 flow hook: tools/shardlint.py --all-examples must exit 0
-    with zero findings on every shipped examples/ config and the bench.py
-    410M/1.5B legs, each analyzed in < 30 s (ISSUE 2 acceptance)."""
+    with zero findings on every shipped examples/ config and the
+    analysis/targets.py 410M/1.5B targets, each analyzed in < 30 s (ISSUE 2 acceptance)."""
     out = tmp_path / "shardlint.json"
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "shardlint.py"),

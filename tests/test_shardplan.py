@@ -98,9 +98,9 @@ def test_planner_peak_within_10pct_of_xla_410m(devices8):
     bench leg (the exact program the lint traces — XLA CPU compiles it
     in seconds). Measured 1.04 with the fused-elementwise coalescing
     landed; the band leaves room for jax version drift only."""
-    import bench
+    from deepspeed_tpu.analysis.targets import lint_targets
 
-    name, model, cfg = bench.lint_targets(len(jax.devices()))[0]
+    name, model, cfg = lint_targets(len(jax.devices()))[0]
     assert name == "bench-410m"
     engine = _engine(cfg, model=model)
     plan = plan_engine(engine, source=name)
@@ -148,9 +148,9 @@ def test_plan_reports_offload_and_ring_streams(devices8):
     """The engine's declared analytic streams ride into the plan (and
     into R8): the double-buffered offload leg prices its host stream
     even on the CPU mesh (assumed), the tp-overlap leg its ring."""
-    import bench
+    from deepspeed_tpu.analysis.targets import lint_targets
 
-    targets = {n: (m, c) for n, m, c in bench.lint_targets(len(jax.devices()))}
+    targets = {n: (m, c) for n, m, c in lint_targets(len(jax.devices()))}
     model, cfg = targets["bench-1b-offload-db"]
     plan = plan_engine(_engine(cfg, model=model), source="db")
     off = plan.streams["offload"]

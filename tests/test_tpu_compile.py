@@ -28,7 +28,6 @@ from deepspeed_tpu.models.transformer import alibi_slopes
 from deepspeed_tpu.ops.pallas import (
     decode_attention as da,
     flash_attention as fa,
-    fused_adam,
     layernorm as ln,
     paged_attention as pa,
     rmsnorm as rn,
@@ -348,20 +347,6 @@ def test_norm_row_block_follows_width():
     assert rn._block_rows(8, 1024) == 8
     for D in (128, 1024, 4096, 8192, 14336):
         assert rn._block_rows(1 << 20, D) % 16 == 0
-
-
-# ------------------------------------------------------------ fused adam
-def test_fused_adam_compiles(one_chip):
-    n = 1024 * 4096  # one BLOOM-560m mlp weight [1024, 4096]
-
-    step = functools.partial(
-        fused_adam._fused_adam_flat, b1=0.9, b2=0.999, eps=1e-8,
-        interpret=False,
-    )
-    text = _compile(
-        step, one_chip, ((n,), F32), ((n,), F32), ((n,), F32), ((2,), F32),
-    )
-    assert "tpu_custom_call" in text, text[:2000]
 
 
 def test_interpret_numerics_of_repaired_operands():

@@ -23,8 +23,9 @@ drift ledger, cross-check the winner's predicted HBM peak against XLA's
 ``memory_analysis()``, and exit 1 when any pair leaves the documented
 band (docs/autotuning.md "Drift bands"). Legs:
 
-- ``410m``      the bench.py 410M leg (full size — minutes per measured
-                step on CPU; meant for TPU hosts or patient operators)
+- ``410m``      the 410M target of analysis/targets.py (full size —
+                minutes per measured step on CPU; meant for TPU hosts
+                or patient operators)
 - ``410m-lite`` the same llama family scaled to hidden 512 / 4 layers /
                 seq 256: the CPU-mesh CI leg (a couple of minutes total)
 - ``1b``        the 1.4B ZeRO-3 offload leg (static modes only)
@@ -58,7 +59,7 @@ import shardlint as shardlint_cli  # noqa: E402
 
 
 def leg_model(leg: str, seq: int = None):
-    """(model, base_seq) for a named bench leg. ``410m-lite`` is the
+    """(model, base_seq) for a named leg. ``410m-lite`` is the
     CPU-gate proxy: same llama family, scaled so a measured step is
     seconds, not minutes."""
     from deepspeed_tpu.models import llama
@@ -70,11 +71,9 @@ def leg_model(leg: str, seq: int = None):
             num_layers=4, num_heads=8, num_kv_heads=4, head_dim=64,
             intermediate_size=2048,
         ), S
-    import bench
+    from deepspeed_tpu.analysis import targets
 
-    tag = "1b" if leg == "1b" else "410m"
-    model, _B, S = bench.bench_model(smoke=False, tag=tag)
-    return model, S
+    return targets.target_model("1b" if leg == "1b" else "410m"), targets.SEQ
 
 
 def leg_base_config(args) -> dict:
@@ -362,7 +361,7 @@ def main(argv=None) -> int:
     )
     ap.add_argument("configs", nargs="*", help="ds_config.json paths")
     ap.add_argument("--leg", choices=["410m", "410m-lite", "1b"],
-                    help="search a named bench leg instead of a config")
+                    help="search a named leg instead of a config")
     ap.add_argument("--top-k", type=int, default=3, metavar="K",
                     help="survivors to compile+measure (default 3)")
     ap.add_argument("--hbm-gb", type=float, metavar="N",
@@ -420,8 +419,7 @@ def main(argv=None) -> int:
         ap.error(f"--{'check' if args.check else 'campaign'} needs a "
                  "--leg (it must build a runnable model + batch)")
     if args.gen:
-        # the planner's HardwareModel.detect() honors this env pin — the
-        # same knob bench.py uses, so a dryrun and a bench price alike
+        # the planner's HardwareModel.detect() honors this env pin
         os.environ["DSTPU_TPU_GEN"] = args.gen
 
     from deepspeed_tpu.config import DeepSpeedConfig

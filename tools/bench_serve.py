@@ -54,8 +54,8 @@ Prints tokens/s, p50/p95 TTFT/TPOT, queue/occupancy gauges, the KV-arena
 stream line (comm_logger intake), and the recompile counters — the
 zero-recompiles-after-warmup criterion is ``step traces == 1``.
 
-CPU numbers are NOT perf claims (PERF_NOTES.md protocol: nothing is
-banked until an on-chip A/B); this tool is the correctness/latency-shape
+CPU numbers are NOT perf claims (a speed comes from a cell of
+benchmarks/ on the chip); this tool is the correctness/latency-shape
 replay harness.
 """
 

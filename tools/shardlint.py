@@ -12,8 +12,9 @@ a jaxpr on a CPU mesh, and runs the R1–R11 rule registry
 (docs/shardlint.md; e.g. ``--rules R9,R10,R11`` for the paritylint
 subset). Exit code 1 on any error-severity finding — wire
 ``--all-examples`` into the tier-1 flow as the pre-TPU correctness gate
-(it covers every shipped examples/*.json plus the bench.py 410M and 1.5B
-legs, including the double-buffered offload stream).
+(it covers every shipped examples/*.json plus the 410M and 1.5B targets
+of deepspeed_tpu/analysis/targets.py, including the double-buffered
+offload stream).
 
 ``--report`` additionally prints the analysis/cost planner table per
 config (docs/memory_planner.md); ``--hbm-gb N`` arms rule R6 so a
@@ -88,14 +89,15 @@ def iter_targets(args):
             if fn.endswith(".json"):
                 with open(os.path.join(ex_dir, fn)) as f:
                     yield f"examples/{fn}", None, json.load(f)
-        import bench
         import jax
 
-        for name, model, cfg in bench.lint_targets(len(jax.devices())):
+        from deepspeed_tpu.analysis import targets
+
+        for name, model, cfg in targets.lint_targets(len(jax.devices())):
             yield name, model, cfg
         # the autotuner's ladder rungs are configs too (ISSUE 7): the
         # planner-driven search only measures rungs that lint clean
-        for name, model, cfg in bench.autotune_rung_targets(
+        for name, model, cfg in targets.autotune_rung_targets(
             len(jax.devices())
         ):
             yield name, model, cfg
@@ -149,7 +151,7 @@ def main(argv=None) -> int:
     ap.add_argument("configs", nargs="*", help="ds_config.json paths")
     ap.add_argument("--all-examples", action="store_true",
                     help="lint every shipped examples/*.json plus the "
-                         "bench.py 410M/1.5B legs")
+                         "analysis/targets.py 410M/1.5B targets")
     ap.add_argument("--json", metavar="PATH",
                     help="write the machine-readable report here "
                          "('-' for stdout)")

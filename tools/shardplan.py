@@ -43,7 +43,7 @@ def main(argv=None) -> int:
     ap.add_argument("configs", nargs="*", help="ds_config.json paths")
     ap.add_argument("--all-examples", action="store_true",
                     help="plan every shipped examples/*.json plus the "
-                         "bench.py 410M/1.5B legs")
+                         "analysis/targets.py 410M/1.5B targets")
     ap.add_argument("--hbm-gb", type=float, metavar="N",
                     help="per-device HBM budget in GiB; arms rule R6 — "
                          "exit 1 when a config's estimated peak exceeds "
@@ -62,7 +62,7 @@ def main(argv=None) -> int:
 
     # delegate to the shardlint CLI's shared lint loop (target iteration,
     # flag normalization, default model shaping, skip handling) — one
-    # definition of "every shipped config and bench leg", planner table
+    # definition of "every shipped config and standing target", planner table
     # always on
     report = shardlint_cli.run_lint(args, collect_plan=True)
     print(report.format())
