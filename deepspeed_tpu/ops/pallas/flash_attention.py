@@ -11,9 +11,17 @@ In-kernel masking (r3):
 - **segment_ids** (packed sequences): q ids ride lane-broadcast [B,S,LANES],
   kv ids sublane-broadcast [B,SUBLANES,S], so the [bq,bk] same-segment mask
   is two VMEM broadcasts and never a relayout.
-- **ALiBi** (BLOOM): per-head slope in SMEM; bias = -slope*|qpos-kpos| is
-  computed from block iotas, so the [B,H,S,S] bias tensor is never
-  materialized in HBM.
+- **ALiBi** (BLOOM): per-head slope in SMEM; under ``causal`` the bias
+  -slope*(qpos-kpos) is a [bq,1] column plus a [1,bk] row, two broadcast
+  adds a score; the [B,H,S,S] bias tensor is never materialized in HBM.
+  A score near the diagonal carries the rounding of slope*block_q (about
+  1.5e-5 at 512 for a slope that is no power of two), not of its own small
+  product: see :func:`_mask_and_bias`.
+- **Causal**: positions are a [bq,1] column and a [1,bk] row compared by
+  broadcast on every visible tile; tiles wholly above the diagonal are
+  skipped (:func:`_block_visible`). One body a kernel: a bare body for the
+  tiles wholly below the diagonal was built and measured (PR 33) and earned
+  under 1 ms of a 437 ms step, so it is not here.
 - **sp composition**: under a DS-Ulysses mesh the kernel shard_maps heads
   over ("tp","sp") — the all-to-alls happen outside (parallel/sequence.py),
   the kernel itself always sees full sequence.
@@ -32,10 +40,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-# Measured on v5e (llama-410M, S=2048, bf16): 512x512 tiles beat 256x256
-# by 24% end-to-end train throughput (the 256 grid left the MXU ~10%
-# utilized in the flash kernels); 512x1024 adds ~3% more but only divides
-# S >= 1024, so 512 is the safe default and sweeps override upward.
+# Measured on one v5e chip, the kernels alone (PR 33's chip runs, PERF.md
+# section 6): [4, 16, 2048, D] bf16, causal, ALiBi, 512x512 tiles, device ms
+# a call at D 64 | 128: fwd 0.90 | 0.96, dq 1.21 | 1.25, dk/dv 1.50 | 1.50.
+# 1024-wide tiles gain 0-8 % and Mosaic compiles them three times as long;
+# 256-wide tiles lose a fifth to a half. So 512x512 stays, for every kernel.
+# The time does not follow D: a 64-deep contraction occupies the 128 x 128
+# MXU as long as a 128-deep one. What a tile costs beside its matmuls is
+# cross-lane work on [bq, 1] columns, not the mask arithmetic: see
+# _fwd_kernel.
 # _pick_block degrades to 256/128 automatically when 512 doesn't divide S.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
@@ -88,26 +101,52 @@ def _compact_rows(layout):
     return idx, counts
 
 
-def _mask_and_bias(s, qi, ki, block_q, block_k, *, causal, seg_q, seg_k, slope,
-                   dense=None, qoff=0, koff=0):
-    """Apply causal + segment masks and ALiBi/dense bias to a [bq, bk] tile.
+def _mask_and_bias(s, rel, block_q, block_k, *, causal, seg_q, seg_k, slope,
+                   dense=None):
+    """Apply causal + segment masks and ALiBi/dense bias to a [bq, bk] fp32
+    score tile. ``rel`` is the tile's first query position minus its first
+    key position (a scalar, global: ring-hop offsets included); positions
+    are a [bq, 1] column and a [1, bk] row, broadcast, never two [bq, bk]
+    arrays.
 
     seg_q: [bq, 1] | None; seg_k: [1, bk] | None; slope: scalar | None;
-    dense: [bq, bk] fp32 additive bias tile | None; qoff/koff: global
-    position offsets of the q/kv blocks (ring attention hops)."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    qpos = qi * block_q + rows + qoff
-    kpos = ki * block_k + cols + koff
+    dense: [bq, bk] fp32 additive bias tile | None.
+
+    Causal ALiBi is a column plus a row, not a product a score: each term is
+    rounded at its own size, so a score near the diagonal is off by about one
+    ulp of slope*block_q (1.5e-5 at 512 for a slope that is no power of two,
+    as half of BLOOM-560m's sixteen are), far under the bf16 operands' noise;
+    tests/test_flash_attention.py holds it to 1e-4."""
     if dense is not None:
         s = s + dense
+    if slope is not None or causal:
+        # query position relative to the tile's first key / key column
+        qrel = jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0) + rel
+        kcol = jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
     if slope is not None:
-        s = s - slope * jnp.abs(qpos - kpos).astype(jnp.float32)
+        if causal:
+            # on visible keys |qpos - kpos| = qrel - kcol: two broadcast adds
+            # a score (the masked keys are overwritten below)
+            s = (s - slope * qrel.astype(jnp.float32)
+                 + slope * kcol.astype(jnp.float32))
+        else:
+            s = s - slope * jnp.abs(qrel - kcol).astype(jnp.float32)
     if causal:
-        s = jnp.where(qpos >= kpos, s, NEG_INF)
+        s = jnp.where(qrel >= kcol, s, NEG_INF)
     if seg_q is not None:
         s = jnp.where(seg_q == seg_k, s, NEG_INF)
     return s
+
+
+def _lanes_to(x, width):
+    """A lane-replicated [rows, LANES] column as [rows, width], without a
+    lane broadcast where the width allows (a multiple or a prefix of the
+    lanes: tiling whole vregs and slicing lanes off are free)."""
+    if width % LANES == 0:
+        return jnp.tile(x, (1, width // LANES))
+    if width <= LANES:
+        return x[:, :width]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
 
 
 def _parse_refs(refs, *, has_seg, has_alibi, has_bias=False, has_offsets=False):
@@ -132,19 +171,31 @@ def _parse_refs(refs, *, has_seg, has_alibi, has_bias=False, has_offsets=False):
             bias_ref, offsets_ref, extra)
 
 
-def _sparse_step(cols_ref, counts_ref, row, step, causal, block_q, block_k,
-                 swap):
-    """Compacted-grid step decode: (other-axis block index, run predicate).
+def _tile_step(tables, row, step, *, causal, block_q, block_k, swap, qoff=0,
+               koff=0):
+    """Decode one grid step: (other-axis block index, run predicate, rel).
 
-    row is the dense grid axis (qi for fwd/dq, ki for dkv); step indexes the
-    compaction table. Padded steps repeat the previous index (no DMA) and
-    predicate off via the count."""
-    other = cols_ref[row, step]
-    ok = step < counts_ref[row]
+    row is the dense grid axis (qi for fwd/dq, ki for dkv: ``swap``). On
+    the dense grid ``tables`` is None and step IS the other block index; on
+    the compacted grid step indexes the (cols, counts) table: padded steps
+    repeat the previous index (no DMA) and predicate off via the count.
+    Causal visibility comes from the block indices and the ring-hop offsets
+    (dynamic when those are traced; sparse never combines with offsets —
+    enforced at entry); ``rel`` is the tile's first query position minus its
+    first key position, what :func:`_mask_and_bias` places its column and
+    row by."""
+    if tables is None:
+        other, ok = step, True
+    else:
+        cols_ref, counts_ref = tables
+        other = cols_ref[row, step]
+        ok = step < counts_ref[row]
+    qi, ki = (other, row) if swap else (row, other)
     if causal:
-        qi, ki = (other, row) if swap else (row, other)
-        ok = jnp.logical_and(ok, _block_visible(qi, ki, block_q, block_k))
-    return other, ok
+        ok = jnp.logical_and(
+            ok, _block_visible(qi, ki, block_q, block_k, qoff, koff))
+    rel = qi * block_q + qoff - (ki * block_k + koff)
+    return other, ok, rel
 
 
 def _offs(offsets_ref):
@@ -189,21 +240,14 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
     slope = _head_slope(slopes_ref, pl.program_id(1))
     qi, step = pl.program_id(2), pl.program_id(3)
     nstep = pl.num_programs(3)
-    if sparse:
-        # compacted grid: step walks this q-row's active k-blocks only
-        # (sparse never combines with position offsets — enforced at entry)
-        ki, should_run = _sparse_step(
-            kcols_ref, kcounts_ref, qi, step, causal, block_q, block_k,
-            swap=False,
-        )
-    else:
-        ki = step
-        # causal: skip blocks fully above the diagonal (dynamic when the
-        # blocks carry ring-hop position offsets)
-        should_run = (
-            _block_visible(qi, ki, block_q, block_k, qoff, koff)
-            if causal else True
-        )
+    # compacted grid: step walks this q-row's active k-blocks only; dense
+    # grid: causal skips blocks fully above the diagonal (dynamic when the
+    # blocks carry ring-hop position offsets)
+    ki, should_run, rel = _tile_step(
+        (kcols_ref, kcounts_ref) if sparse else None, qi, step,
+        causal=causal, block_q=block_q, block_k=block_k, swap=False,
+        qoff=qoff, koff=koff,
+    )
 
     @pl.when(step == 0)
     def _init():
@@ -222,32 +266,38 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
         ) * scale  # [bq, bk] fp32
         seg_q, seg_k, dense = _tile_mask_args(seg_q_ref, seg_k_ref, bias_ref)
         s = _mask_and_bias(
-            s, qi, ki, block_q, block_k, causal=causal,
-            seg_q=seg_q, seg_k=seg_k, slope=slope, dense=dense,
-            qoff=qoff, koff=koff,
+            s, rel, block_q, block_k, causal=causal, seg_q=seg_q, seg_k=seg_k,
+            slope=slope, dense=dense,
         )
 
-        m_prev = m_scr[:, :1]  # [bq, 1] (lanes hold copies)
+        # running max / sum stay lane-replicated [bq, LANES]: the row
+        # reductions broadcast into them once, and everything after reads
+        # whole vregs (tiling a replicated column is free, a [bq, 1] column
+        # costs a lane broadcast every use)
+        m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         # rows with no visible key yet keep m=-inf; exp guard against inf-inf
         m_safe = jnp.where(m_new <= NEG_INF, 0.0, m_new)
-        p = jnp.exp(s - m_safe)  # [bq, bk]
-        corr = jnp.exp(m_prev - m_safe)  # [bq, 1]
-        l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        p = jnp.exp(s - _lanes_to(m_safe, block_k))  # [bq, bk]
+        corr = jnp.exp(m_prev - m_safe)
+        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * _lanes_to(corr, acc_scr.shape[1]) + (
+            jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
         )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        m_scr[...] = m_new
 
     @pl.when(step == nstep - 1)
     def _finalize():
-        l = l_scr[:, :1]
+        l = l_scr[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        lse = jnp.where(l == 0.0, NEG_INF, m_scr[:, :1] + jnp.log(l_safe))
-        lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
+        o_ref[0, 0] = (
+            acc_scr[...] / _lanes_to(l_safe, acc_scr.shape[1])
+        ).astype(o_ref.dtype)
+        lse = jnp.where(l == 0.0, NEG_INF, m_scr[...] + jnp.log(l_safe))
+        lse_ref[0, 0] = lse[:, :AUX_LANES]
 
 
 def _mask_specs(has_seg, has_alibi, block_q, block_k, *, swap_grid=False,
@@ -420,11 +470,12 @@ def _flash_fwd(q, k, v, bias, seg, slopes, tables, offsets=None, *, causal,
 # backward
 # -----------------------------------------------------------------------------
 def _recompute_p_dp(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slope,
-                    bias_ref, do_ref, lse_ref, delta_ref, qi, ki, *, scale,
-                    causal, block_q, block_k, qoff=0, koff=0):
+                    bias_ref, do_ref, lse_ref, delta_ref, rel, *, scale,
+                    causal, block_q, block_k):
     """The backward kernels' shared logit recompute: returns
     (p [bq,bk] fp32, dp [bq,bk] fp32, delta [bq,1] fp32, do, q, k, v).
-    ONE definition so dq, dk/dv, and dbias can never desynchronize."""
+    ONE definition so dq, dk/dv, and dbias can never desynchronize; ``rel``
+    places the tile, see :func:`_mask_and_bias`."""
     q = q_ref[0, 0]
     k = k_ref[0, 0]
     v = v_ref[0, 0]
@@ -436,9 +487,8 @@ def _recompute_p_dp(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slope,
     ) * scale
     seg_q, seg_k, dense = _tile_mask_args(seg_q_ref, seg_k_ref, bias_ref)
     s = _mask_and_bias(
-        s, qi, ki, block_q, block_k, causal=causal,
-        seg_q=seg_q, seg_k=seg_k, slope=slope, dense=dense,
-        qoff=qoff, koff=koff,
+        s, rel, block_q, block_k, causal=causal, seg_q=seg_q, seg_k=seg_k,
+        slope=slope, dense=dense,
     )
     p = jnp.exp(s - lse)  # fully-masked rows: lse=NEG_INF → guard below
     p = jnp.where(s <= NEG_INF, 0.0, p)
@@ -467,17 +517,11 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
     slope = _head_slope(slopes_ref, pl.program_id(1))
     qi, step = pl.program_id(2), pl.program_id(3)
     nstep = pl.num_programs(3)
-    if sparse:
-        ki, should_run = _sparse_step(
-            kcols_ref, kcounts_ref, qi, step, causal, block_q, block_k,
-            swap=False,
-        )
-    else:
-        ki = step
-        should_run = (
-            _block_visible(qi, ki, block_q, block_k, qoff, koff)
-            if causal else True
-        )
+    ki, should_run, rel = _tile_step(
+        (kcols_ref, kcounts_ref) if sparse else None, qi, step,
+        causal=causal, block_q=block_q, block_k=block_k, swap=False,
+        qoff=qoff, koff=koff,
+    )
 
     @pl.when(step == 0)
     def _init():
@@ -487,8 +531,8 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
     def _body():
         p, dp, delta, do, q, k, v = _recompute_p_dp(
             q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slope, bias_ref,
-            do_ref, lse_ref, delta_ref, qi, ki, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, qoff=qoff, koff=koff,
+            do_ref, lse_ref, delta_ref, rel, scale=scale, causal=causal,
+            block_q=block_q, block_k=block_k,
         )
         dst = p * (dp - delta)  # dL/d(logits): bias sees it unscaled
         if dbias_ref is not None:
@@ -525,17 +569,11 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
     slope = _head_slope(slopes_ref, pl.program_id(1))
     ki, step = pl.program_id(2), pl.program_id(3)
     nstep = pl.num_programs(3)
-    if sparse:
-        qi, should_run = _sparse_step(
-            qrows_ref, qcounts_ref, ki, step, causal, block_q, block_k,
-            swap=True,
-        )
-    else:
-        qi = step
-        should_run = (
-            _block_visible(qi, ki, block_q, block_k, qoff, koff)
-            if causal else True
-        )
+    qi, should_run, rel = _tile_step(
+        (qrows_ref, qcounts_ref) if sparse else None, ki, step,
+        causal=causal, block_q=block_q, block_k=block_k, swap=True,
+        qoff=qoff, koff=koff,
+    )
 
     @pl.when(step == 0)
     def _init():
@@ -546,8 +584,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
     def _body():
         p, dp, delta, do, q, k, v = _recompute_p_dp(
             q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slope, bias_ref,
-            do_ref, lse_ref, delta_ref, qi, ki, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, qoff=qoff, koff=koff,
+            do_ref, lse_ref, delta_ref, rel, scale=scale, causal=causal,
+            block_q=block_q, block_k=block_k,
         )
         dv_scr[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -596,13 +634,15 @@ def _bias_grad_kernel(*refs, scale, causal, block_q, block_k, has_seg,
     def _init():
         scr[:] = jnp.zeros_like(scr)
 
-    should_run = _block_visible(qi, ki, block_q, block_k) if causal else True
+    _, should_run, rel = _tile_step(
+        None, qi, ki, causal=causal, block_q=block_q, block_k=block_k,
+        swap=False)
 
     @pl.when(should_run)
     def _body():
         p, dp, delta, _, _, _, _ = _recompute_p_dp(
             q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slope, bias_ref,
-            do_ref, lse_ref, delta_ref, qi, ki, scale=scale, causal=causal,
+            do_ref, lse_ref, delta_ref, rel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k,
         )
         scr[:] += p * (dp - delta)

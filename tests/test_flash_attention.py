@@ -561,3 +561,155 @@ def test_bwd_tiles_scope_and_config():
             block_q=128, block_k=128, block_q_bwd=64, block_k_bwd=64) ** 2)
     out = jax.grad(gm)(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(base), atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# PR 33: positions are a [bq, 1] column and a [1, bk] row; causal ALiBi is a
+# column plus a row. Tiles wholly below the diagonal, tiles it crosses and
+# skipped tiles all ride one body
+# ---------------------------------------------------------------------------
+F32_TOL = dict(atol=1e-5, rtol=1e-5)
+# bf16 operands, fp32 scores/softmax, bf16 probabilities into PV: a bf16 ulp
+# of an O(1) output or gradient is 8e-3
+BF16_TOL = dict(atol=4e-2, rtol=4e-2)
+
+
+def _out_and_grads(fn, q, k, v, seed=99):
+    """(out, dq, dk, dv) as float32 numpy, under one fixed cotangent."""
+    out, vjp = jax.vjp(fn, q, k, v)
+    cot = jax.random.normal(jax.random.PRNGKey(seed), out.shape, out.dtype)
+    return [np.asarray(x, np.float32) for x in (out, *vjp(cot))]
+
+
+def _assert_all_close(got, want, tol):
+    for g, w, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        np.testing.assert_allclose(g, w, err_msg=name, **tol)
+
+
+@pytest.mark.parametrize("seg", [False, True], ids=["noseg", "seg"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("hd", [64, 128])
+def test_causal_alibi_over_every_kind_of_tile(hd, dtype, seg):
+    """4 x 4 tiles (S 512, tiles 128): 6 wholly below the diagonal, 4 the
+    diagonal crosses, 6 skipped a head; forward and all three gradients
+    against the dense reference. The slopes are powers of two
+    (``alibi_slopes(4)``), so column + row is exact."""
+    from deepspeed_tpu.models.transformer import alibi_slopes as make_slopes
+
+    H = 4
+    q, k, v = _qkv(jax.random.PRNGKey(33), B=1, S=512, H=H, D=hd, dtype=dtype)
+    kw = dict(causal=True, alibi_slopes=jnp.asarray(make_slopes(H)),
+              segment_ids=_segments(1, 512) if seg else None)
+    got = _out_and_grads(
+        lambda *a: flash_attention(*a, block_q=128, block_k=128, **kw),
+        q, k, v)
+    want = _out_and_grads(lambda *a: xla_attention(*a, **kw), q, k, v)
+    _assert_all_close(got, want, F32_TOL if dtype == jnp.float32 else BF16_TOL)
+
+
+def test_causal_alibi_column_plus_row_rounding():
+    """Slopes that are no power of two (BLOOM's 16 heads have eight): the
+    column and the row are rounded products of up to slope x 511, so a score
+    carries their float32 rounding (1.5e-5 at 0.707 x 511) where the
+    |qpos - kpos| form rounded a small product near the diagonal."""
+    slopes = jnp.asarray([0.70710678, 0.3, 0.0442, 0.011], jnp.float32)
+    q, k, v = _qkv(jax.random.PRNGKey(34), B=1, S=512, H=4, D=64)
+    kw = dict(causal=True, alibi_slopes=slopes)
+    got = _out_and_grads(
+        lambda *a: flash_attention(*a, block_q=128, block_k=128, **kw),
+        q, k, v)
+    want = _out_and_grads(lambda *a: xla_attention(*a, **kw), q, k, v)
+    _assert_all_close(got, want, dict(atol=1e-4, rtol=1e-4))
+
+
+@pytest.mark.parametrize("hd", [64, 128])
+def test_noncausal_alibi_abs_branch(hd):
+    """Without ``causal`` keys lie on both sides of a query: the static
+    ``abs`` form."""
+    from deepspeed_tpu.models.transformer import alibi_slopes as make_slopes
+
+    q, k, v = _qkv(jax.random.PRNGKey(35), B=1, S=256, H=4, D=hd)
+    kw = dict(causal=False, alibi_slopes=jnp.asarray(make_slopes(4)))
+    got = _out_and_grads(
+        lambda *a: flash_attention(*a, block_q=128, block_k=128, **kw),
+        q, k, v)
+    want = _out_and_grads(lambda *a: xla_attention(*a, **kw), q, k, v)
+    _assert_all_close(got, want, F32_TOL)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_alibi_over_block_sparse_layout(causal):
+    """A block-sparse layout decides which tiles run, the block indices where
+    each lies: ALiBi and the causal compare ride the compacted grid."""
+    from deepspeed_tpu.models.transformer import alibi_slopes as make_slopes
+
+    S, blk = 512, 128
+    layout = np.array([[1, 0, 0, 1],
+                       [1, 1, 0, 0],
+                       [0, 1, 1, 0],
+                       [1, 0, 1, 1]], np.int32)
+    q, k, v = _qkv(jax.random.PRNGKey(36), B=1, S=S, H=4, D=64)
+    slopes = jnp.asarray(make_slopes(4))
+    tok = np.repeat(np.repeat(layout, blk, axis=0), blk, axis=1)
+    mask_bias = jnp.where(jnp.asarray(tok) > 0, 0.0, -1e30)[None, None]
+    got = _out_and_grads(
+        lambda *a: flash_attention(*a, causal=causal, alibi_slopes=slopes,
+                                   block_mask=layout, block_q=blk,
+                                   block_k=blk),
+        q, k, v)
+    want = _out_and_grads(
+        lambda *a: xla_attention(*a, causal=causal, alibi_slopes=slopes,
+                                 bias=mask_bias),
+        q, k, v)
+    _assert_all_close(got, want, F32_TOL)
+
+
+@pytest.mark.parametrize("S,bq,bk,want", [
+    (2048, 512, 512, [1, 2, 3, 4]),   # both training cells: 10 of 16 tiles
+    (2048, 512, 1024, [1, 1, 2, 2]),
+    (2048, 1024, 512, [2, 4]),
+    (512, 128, 128, [1, 2, 3, 4]),
+    (512, 512, 512, [1]),
+], ids=lambda x: "x".join(map(str, x)) if isinstance(x, list) else str(x))
+def test_causal_tables_by_hand(S, bq, bk, want):
+    """The causal layout the wrapper compacts: ``_block_visible`` over the
+    block grid is the lower block triangle, row r of the table lists its
+    ``want[r]`` visible k-blocks in order and pads by repeating the last."""
+    from deepspeed_tpu.ops.pallas.flash_attention import (
+        _block_visible,
+        _compact_rows,
+    )
+
+    qi, ki = np.arange(S // bq)[:, None], np.arange(S // bk)[None, :]
+    layout = _block_visible(qi, ki, bq, bk).astype(np.int32)
+    cols, counts = _compact_rows(layout)
+    assert counts.tolist() == want
+    assert cols.shape == (S // bq, max(want))
+    for r, n in enumerate(want):
+        assert cols[r].tolist() == list(range(n)) + [n - 1] * (max(want) - n)
+    # the dk/dv kernel walks the transpose: k-block c is seen by every
+    # q-block from the first that reaches it
+    rows, rcounts = _compact_rows(layout.T)
+    assert rcounts.tolist() == [int(layout[:, c].sum())
+                                for c in range(S // bk)]
+    assert all(rows[c, 0] == np.argmax(layout[:, c])
+               for c in range(S // bk))
+
+
+@pytest.mark.parametrize("bq,bk", [(4, 4), (4, 8), (8, 4)])
+def test_block_visible_against_brute_force(bq, bk):
+    """``_block_visible`` over a grid of block indices and ring-hop offsets,
+    against the position mask written out."""
+    from deepspeed_tpu.ops.pallas.flash_attention import _block_visible
+
+    for qi in range(4):
+        for ki in range(4):
+            for qoff in (0, 3, 8, 17):
+                for koff in (0, 5, 8, 32):
+                    qpos = qi * bq + qoff + np.arange(bq)[:, None]
+                    kpos = ki * bk + koff + np.arange(bk)[None, :]
+                    sees = qpos >= kpos
+                    at = (qi, ki, qoff, koff)
+                    assert _block_visible(qi, ki, bq, bk, qoff, koff) == \
+                        sees.any(), at

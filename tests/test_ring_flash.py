@@ -118,3 +118,59 @@ def test_ring_flash_bwd_tiles_scope():
             np.asarray(gb), np.asarray(gs), rtol=5e-4, atol=5e-4,
             err_msg=f"d{name}",
         )
+
+
+@pytest.mark.parametrize("alibi", [False, True], ids=["plain", "alibi"])
+def test_hop_offsets_cover_every_kind_of_tile(alibi):
+    """One ring hop, by hand: 3 x 3 tiles of 128 with the queries 64
+    positions ahead of the keys. Tile (0,0) is crossed mid-tile, (0,2) is
+    invisible, (1,0) lies wholly below the diagonal — the offsets are traced,
+    so the kernel places each tile itself. Forward (out, lse) and dq/dk/dv
+    against the dense computation at global positions."""
+    from deepspeed_tpu.models.transformer import alibi_slopes as make_slopes
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    Bh, H, S_loc, D, blk = 1, 2, 384, 64, 128
+    qoff, koff = 64, 0
+    r = np.random.RandomState(5)
+    q, k, v, do = (jnp.asarray(r.randn(Bh, H, S_loc, D), jnp.float32)
+                   for _ in range(4))
+    slopes = jnp.asarray(make_slopes(H), jnp.float32) if alibi else None
+    scale = 1.0 / D ** 0.5
+
+    def sees(qi, ki):  # [blk, blk]: which queries of the tile see which keys
+        return (qi * blk + qoff + np.arange(blk)[:, None]
+                >= ki * blk + koff + np.arange(blk)[None, :])
+
+    assert sees(0, 0).any() and not sees(0, 0).all()  # crossed mid-tile
+    assert not sees(0, 2).any()                       # invisible
+    assert sees(1, 0).all()                           # wholly below
+    assert not fa._block_visible(0, 2, blk, blk, qoff, koff)
+
+    def dense(q, k, v):
+        qpos = qoff + jnp.arange(S_loc)[:, None]
+        kpos = koff + jnp.arange(S_loc)[None, :]
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+        if alibi:
+            s = s - slopes[None, :, None, None] * jnp.abs(qpos - kpos)
+        s = jnp.where(qpos >= kpos, s, fa.NEG_INF)
+        lse = jax.scipy.special.logsumexp(s, axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(s - lse[..., None]), v), lse
+
+    kw = dict(causal=True, scale=scale, block_q=blk, block_k=blk,
+              interpret=True)
+
+    @jax.jit
+    def hop(q, k, v, do, offsets):
+        out, lse = fa._flash_fwd(q, k, v, None, None, slopes, None, offsets,
+                                 **kw)
+        dq, dk, dv, _ = fa._flash_bwd(q, k, v, out, lse, do, None, None,
+                                      slopes, None, offsets, **kw)
+        return out, lse[..., 0], dq, dk, dv
+
+    got = hop(q, k, v, do, jnp.asarray([[qoff, koff]], jnp.int32))
+    (out_ref, lse_ref), vjp = jax.vjp(dense, q, k, v)
+    want = (out_ref, lse_ref, *vjp((do, jnp.zeros_like(lse_ref))))
+    for g, w, name in zip(got, want, ("out", "lse", "dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5,
+                                   atol=1e-5, err_msg=name)

@@ -86,8 +86,10 @@ BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
         (1, 2048, 16, 16, 64, True, False),   # BLOOM-560m: the train phase
         (1, 4096, 32, 8, 128, False, False),  # Mixtral/Llama-8B GQA widths
         (2, 2048, 8, 4, 128, False, True),    # packed sequences (segment ids)
+        (4, 2048, 16, 16, 128, True, False),  # BLOOM-1b7: a chip of the dp=4 cell
     ],
-    ids=["bloom560m-alibi-hd64", "gqa-h32kv8-hd128-s4096", "segment-ids"],
+    ids=["bloom560m-alibi-hd64", "gqa-h32kv8-hd128-s4096", "segment-ids",
+         "bloom1b7-alibi-hd128-b4"],
 )
 def test_flash_fwd_bwd_compiles(one_chip, B, S, H, KV, hd, alibi, seg):
     slopes = jnp.asarray(alibi_slopes(H), F32) if alibi else None
