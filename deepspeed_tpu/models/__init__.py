@@ -9,6 +9,7 @@ from .llama import llama, llama_config  # noqa: F401
 from .bloom import bloom, bloom_config  # noqa: F401
 from .mixtral import mixtral, mixtral_config  # noqa: F401
 from .mellum import mellum, mellum_config  # noqa: F401
+from .deepseek import deepseek, deepseek_config  # noqa: F401
 
 MODEL_REGISTRY = {
     "gpt2": gpt2,
@@ -16,6 +17,7 @@ MODEL_REGISTRY = {
     "bloom": bloom,
     "mixtral": mixtral,
     "mellum": mellum,
+    "deepseek": deepseek,
 }
 
 

@@ -27,6 +27,7 @@ from .transformer import (
     _mlp,
     _norm,
     _qk_norm,
+    _rms_last,
     _rope,
     alibi_slopes,
     lm_head_logits,
@@ -124,6 +125,13 @@ def gather_verify_window(logits: jax.Array, num_new, spec_len,
 
 
 WIN = "_win"  # suffix of the window layers' pool leaves and page table
+LATENT, INDEX = "kv", "ki"  # a latent model's pools: latent rows, indexer keys
+
+
+def latent_row_width(cfg: TransformerConfig) -> int:
+    """Values a row of the latent pool holds: ``cfg.latent_width`` in whole
+    128-lane tiles (the chip tiles a narrower row up to that anyway)."""
+    return -(-cfg.latent_width // 128) * 128
 
 
 def init_paged_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
@@ -142,6 +150,22 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
     kind: ``k``/``v`` hold the full layers alone, and ``k_win``/``v_win``
     [L_window, window_pages + 1, ...] the window layers, whose pages a
     slot gives back once every query still to come is past them."""
+    if cfg.is_latent:
+        if quantized:
+            from ..config import DeepSpeedConfigError
+
+            raise DeepSpeedConfigError(
+                "an int8 KV cache is refused: a latent cache (kv_latent_dim) "
+                "holds one normed latent a token for all heads, and no "
+                "per-head scale applies to it")
+        P1 = int(num_pages) + 1
+        pools = {LATENT: jnp.zeros(
+            (cfg.total_layers, P1, page_size, latent_row_width(cfg)), dtype)}
+        if cfg.index_topk:
+            pools[INDEX] = jnp.zeros(
+                (cfg.total_layers, P1, page_size, cfg.index_dim), dtype)
+        return pools
+
     def pool(layers, pages, sfx=""):
         P1 = int(pages) + 1
         shape = (layers, P1, page_size, cfg.kv_heads, cfg.hd)
@@ -159,7 +183,7 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
         }
 
     if not cfg.has_window:
-        return pool(cfg.num_layers, num_pages)
+        return pool(cfg.total_layers, num_pages)
     if window_pages is None:
         raise ValueError("a model with window layers needs window_pages")
     return {**pool(cfg.kind_count("full"), num_pages),
@@ -229,7 +253,7 @@ def paged_cow_copy(cache: Cache, page_table: jax.Array, start_pos: jax.Array,
     tokens without ever writing the shared page. Rows with
     ``cow_src == -1`` degrade to a self-copy of their frontier page
     (bitwise no-op), keeping the step at one trace for every COW mix."""
-    ps = cache["k"].shape[2]
+    ps = next(iter(cache.values())).shape[2]
     N, mp = page_table.shape
     rows = jnp.arange(N)
     dst = page_table[rows, jnp.clip(start_pos // ps, 0, mp - 1)]
@@ -271,12 +295,19 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
     halves KV HBM for long-context serving (reference: kv-cache quant in
     the inference engine family). Dequant happens at read (in-kernel on the
     Pallas decode path)."""
-    shape = (cfg.num_layers, batch, max_len, cfg.kv_heads, cfg.hd)
+    if cfg.is_latent:
+        from ..config import DeepSpeedConfigError
+
+        raise DeepSpeedConfigError(
+            "a contiguous KV arena is refused: latent attention "
+            "(kv_latent_dim) attends its cached latents through the page "
+            "table (serving.paged)")
+    shape = (cfg.total_layers, batch, max_len, cfg.kv_heads, cfg.hd)
     if quantized:
         # scales live pre-transposed as [B, KV, Smax, SL]: the Pallas decode
         # kernel consumes (Smax, SL) trailing blocks directly, so the
         # latency-critical decode step never pays a per-token relayout
-        sshape = (cfg.num_layers, batch, cfg.kv_heads, max_len, SCALE_LANES)
+        sshape = (cfg.total_layers, batch, cfg.kv_heads, max_len, SCALE_LANES)
         return {
             "k": jnp.zeros(shape, jnp.int8),
             "v": jnp.zeros(shape, jnp.int8),
@@ -510,6 +541,110 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
     ))
 
 
+def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
+                             positions: jax.Array, layer, pools: Cache,
+                             cache_len, page_table, num_new=None):
+    """Latent attention of new tokens ``x`` [B,S,D] over the paged latent
+    cache, in the absorbed form: returns (out [B,S,D], the pools with this
+    layer's rows written in place).
+
+    A token caches its normed ``kv_latent_dim``-wide latent and ONE rotated
+    ``qk_rope_dim``-wide key for all heads (``pools[LATENT]``); a head's
+    keys and values are up-projections of the latent, so its no-position
+    query is taken through the key half of ``wkv_b`` once and scores
+    against the latent itself, and the value half is applied after the
+    weighted sum of latents. With an indexer (``cfg.index_topk``) each
+    token also caches an index key (``pools[INDEX]``), every cached token at
+    or before a query is scored from it, and the query attends its
+    ``index_topk`` best alone.
+
+    With the kernel attention registered the three Pallas calls of
+    ops/pallas/sparse_latent_attention.py read the pools through the table;
+    otherwise the XLA lines gather a per-slot view."""
+    from ..ops.pallas import sparse_latent_attention as sla
+
+    B, S, _ = x.shape
+    H, kl, rd = cfg.num_heads, cfg.kv_latent_dim, cfg.qk_rope_dim
+    nope, vd, eps = cfg.qk_nope_dim, cfg.v_head_dim, cfg.norm_eps
+    table = cfg.rope_of("full")
+    c_q = _rms_last(x @ p["wq_a"], p["q_norm"]["scale"], eps)
+    q = (c_q @ p["wq_b"]).reshape(B, S, H, nope + rd)
+    kv_a = x @ p["wkv_a"]
+    c_kv = _rms_last(kv_a[..., :kl], p["kv_norm"]["scale"], eps)
+    q_pe, k_pe = _rope(q[..., nope:], kv_a[:, :, None, kl:], positions, table)
+    pad = latent_row_width(cfg) - cfg.latent_width
+
+    def row(latent, pe):  # [..., kl] + [..., rd] -> a row of the pool's width
+        parts = [latent, pe] + (
+            [jnp.zeros((*pe.shape[:-1], pad), pe.dtype)] if pad else [])
+        return jnp.concatenate(parts, axis=-1)
+
+    pools = dict(pools)
+    pools[LATENT] = _paged_write(
+        pools[LATENT], row(c_kv, k_pe[:, :, 0]).astype(pools[LATENT].dtype),
+        layer, cache_len, page_table)
+    # the key half of wkv_b absorbed into the query, the value half applied
+    # to the attended latents: [kl, H, nope | vd]
+    wkv_b = p["wkv_b"].reshape(kl, H, nope + vd)
+    q_abs = row(jnp.einsum("bshn,chn->bshc", q[..., :nope], wkv_b[..., :nope]),
+                q_pe)
+    scale = cfg.hd ** -0.5 * cfg.attn_scale_mult
+
+    q_idx = w_idx = None
+    if cfg.index_topk:
+        ix, Hi, Di = p["idx"], cfg.index_heads, cfg.index_dim
+        q_idx = (c_q @ ix["wq_b"]).reshape(B, S, Hi, Di)
+        from ..ops.normalization import layernorm
+
+        k_idx = layernorm(
+            (x @ ix["wk"]).astype(jnp.float32),
+            ix["k_norm"]["scale"].astype(jnp.float32),
+            ix["k_norm"]["bias"].astype(jnp.float32), eps,
+        ).astype(x.dtype)[:, :, None, :]
+        q_rot, k_rot = _rope(q_idx[..., :rd], k_idx[..., :rd], positions,
+                             table)
+        q_idx = jnp.concatenate([q_rot, q_idx[..., rd:]], axis=-1)
+        k_idx = jnp.concatenate([k_rot, k_idx[..., rd:]], axis=-1)[:, :, 0]
+        w_idx = jnp.einsum(
+            "bsd,dh->bsh", x.astype(jnp.float32),
+            ix["w_proj"].astype(jnp.float32)) * (Hi ** -0.5 * Di ** -0.5)
+        pools[INDEX] = _paged_write(
+            pools[INDEX], k_idx.astype(pools[INDEX].dtype), layer, cache_len,
+            page_table)
+
+    from ..ops.attention import _resolve
+
+    out, why_dense = None, []
+    if _resolve() != "flash":
+        why_dense.append("the registered attention is not the kernel one")
+    elif not cfg.index_topk:
+        why_dense.append("no indexer (the kernels attend a selection)")
+    else:
+        out, why_dense = sla.latent_sparse_attention(
+            q_abs, q_idx, w_idx, pools[LATENT], pools[INDEX], cache_len,
+            page_table, layer=layer, topk=cfg.index_topk, scale=scale,
+            v_width=kl, num_new=num_new)
+    if out is not None:
+        _note_attention_path("latent_sparse_kernel")
+    else:
+        _note_attention_path("dense", why_dense)
+
+        def view(name):
+            return _paged_gather(lax.dynamic_index_in_dim(
+                pools[name], layer, 0, keepdims=False), page_table)
+
+        kv_view = view(LATENT)
+        chosen = jnp.arange(kv_view.shape[1])[None, None, :] <= (
+            positions[..., None])
+        if cfg.index_topk:
+            chosen = sla.dense_selection(
+                sla.dense_index_scores(q_idx, w_idx, view(INDEX)), positions,
+                cfg.index_topk)
+        out = sla.dense_sparse_attention(q_abs, kv_view, chosen, scale, kl)
+    out = jnp.einsum("bshc,chv->bshv", out.astype(x.dtype), wkv_b[..., nope:])
+    return _out_proj(out.reshape(B, S, H * vd), p["wo"]), pools
+
+
 def _dense_cached_attention(cfg: TransformerConfig, q: jax.Array,
                             k_att: jax.Array, v_att: jax.Array, cache_len,
                             ks_att=None, vs_att=None,
@@ -609,8 +744,6 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
         x = _norm(cfg, cast(params["embed_norm"]), x)
     x = constrain(x, ("dp", "fsdp"), None, None)
 
-    layers = cast(params["layers"])
-
     moe = cfg.is_moe
     collect_moe = bool(return_moe_stats) and moe
     # Layers of several kinds scan whole periods of the pattern, a period's
@@ -620,7 +753,9 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
     # place at its index inside its pool and reads from the same buffer, so
     # no trip copies a pool. Under a page table a model with window layers
     # keeps two pools (init_paged_cache): a layer reads the leaves and the
-    # table of its kind.
+    # table of its kind. Leading dense layers (``lead_layers``, a stack of
+    # another parameter shape) are a scan of their own before the main one,
+    # over the same carry: their rows are the pools' first.
     kinds = cfg.layer_pattern or ("full",)
     period = len(kinds)
     split = page_table is not None and cfg.has_window
@@ -633,51 +768,71 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
     # index inside its pool
     share = {sfx: sum(1 for s, _ in place if s == sfx) for sfx in tables}
 
-    def body(carry, scanned):
-        h, pools = carry
-        group, layer = scanned  # the trip; one kind: its layer's weights
-        stats = []
-        for j, (kind, (sfx, at)) in enumerate(zip(kinds, place)):
-            # a period's layers are read from the whole stack one at a
-            # time: a period-sized slice of the weights would be copied
-            # every trip
-            if period > 1:
-                layer = jax.tree.map(
-                    lambda a: lax.dynamic_index_in_dim(
-                        a, group * period + j, 0, keepdims=False), layers)
-            names = [n + sfx for n in ("k", "v", "k_scale", "v_scale")
-                     if n + sfx in pools]
-            a, *updated = _cached_attention(
-                cfg, layer["attn"], _norm(cfg, layer["ln1"], h), positions,
-                group * share[sfx] + at, *(pools[n] for n in names[:2]),
-                cache_len, *(pools[n] for n in names[2:]),
-                page_table=tables[sfx], num_new=num_new, kind=kind,
-            )
-            pools = {**pools, **dict(zip(names, updated))}
-            h = h + a
-            normed = _norm(cfg, layer["ln2"], h)
-            if moe:
-                from ..moe.sharded_moe import moe_serving_mlp
+    def make_body(layers, base: int, routed: bool):
+        """The scan body over the stack ``layers``, whose first layer is
+        layer ``base`` of the pools; ``routed``: its MLP is the expert
+        layer."""
+        def body(carry, scanned):
+            h, pools = carry
+            group, layer = scanned  # the trip; one kind: its layer's weights
+            stats = []
+            for j, (kind, (sfx, at)) in enumerate(zip(kinds, place)):
+                # a period's layers are read from the whole stack one at a
+                # time: a period-sized slice of the weights would be copied
+                # every trip
+                if period > 1:
+                    layer = jax.tree.map(
+                        lambda a: lax.dynamic_index_in_dim(
+                            a, group * period + j, 0, keepdims=False), layers)
+                index = base + group * share[sfx] + at
+                normed = _norm(cfg, layer["ln1"], h)
+                if cfg.is_latent:
+                    a, pools = _latent_cached_attention(
+                        cfg, layer["attn"], normed, positions, index, pools,
+                        cache_len, page_table, num_new=num_new)
+                else:
+                    names = [n + sfx for n in ("k", "v", "k_scale", "v_scale")
+                             if n + sfx in pools]
+                    a, *updated = _cached_attention(
+                        cfg, layer["attn"], normed, positions, index,
+                        *(pools[n] for n in names[:2]),
+                        cache_len, *(pools[n] for n in names[2:]),
+                        page_table=tables[sfx], num_new=num_new, kind=kind,
+                    )
+                    pools = {**pools, **dict(zip(names, updated))}
+                h = h + a
+                normed = _norm(cfg, layer["ln2"], h)
+                if routed:
+                    from ..moe.sharded_moe import moe_serving_mlp
 
-                # the routed decode path: capacity from the STATIC budget
-                # (token_budget for the slot engine, B·S for lockstep),
-                # padded rows to the null expert
-                m, lstats = moe_serving_mlp(
-                    cfg, layer["mlp"], normed, token_valid=token_valid,
-                    budget_tokens=S if token_valid is not None else B * S,
-                )
-                stats.append(lstats)
-            else:
-                m, _aux = _mlp(cfg, layer["mlp"], normed, rng=None,
-                               train=False)
-            h = h + m
-            h = constrain(h, ("dp", "fsdp"), None, None)
-        if not collect_moe:
-            return (h, pools), None
-        return (h, pools), jax.tree.map(lambda *t: jnp.stack(t), *stats)
+                    # the routed decode path: capacity from the STATIC budget
+                    # (token_budget for the slot engine, B·S for lockstep),
+                    # padded rows to the null expert
+                    m, lstats = moe_serving_mlp(
+                        cfg, layer["mlp"], normed, token_valid=token_valid,
+                        budget_tokens=S if token_valid is not None else B * S,
+                    )
+                    stats.append(lstats)
+                else:
+                    m, _aux = _mlp(cfg, layer["mlp"], normed, rng=None,
+                                   train=False, dense=True)
+                h = h + m
+                h = constrain(h, ("dp", "fsdp"), None, None)
+            if not (collect_moe and routed):
+                return (h, pools), None
+            return (h, pools), jax.tree.map(lambda *t: jnp.stack(t), *stats)
 
+        return body
+
+    carry = (x, dict(cache))
+    if cfg.lead_dense_layers:
+        lead = cast(params["lead_layers"])
+        carry, _ = lax.scan(
+            make_body(lead, 0, False), carry,
+            (jnp.arange(cfg.lead_dense_layers), lead))
+    layers = cast(params["layers"])
     (x, new_cache), lstats = lax.scan(
-        body, (x, dict(cache)),
+        make_body(layers, cfg.lead_dense_layers, moe), carry,
         (jnp.arange(cfg.num_layers // period),
          layers if period == 1 else None))
     if collect_moe:  # [trips, period, ...] -> one row a layer
@@ -694,5 +849,8 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
                 ),
                 "drop_fraction": jnp.mean(lstats["drop_fraction"]),
             }
+            if "unrouted_tokens" in lstats:  # one member's share of a layer
+                moe_stats["unrouted_tokens"] = jnp.sum(
+                    lstats["unrouted_tokens"])
         return logits, new_cache, moe_stats
     return logits, new_cache

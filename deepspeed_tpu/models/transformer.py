@@ -107,6 +107,46 @@ class TransformerConfig:
     attn_window: int = 0
     rope_tables: Tuple[Tuple[str, "RopeTable"], ...] = ()
     qk_norm: bool = False  # RMSNorm over each head of q and k, before rotary
+    # Latent attention (``kv_latent_dim`` > 0): queries through a
+    # ``q_latent_dim``-wide latent, every head's keys and values
+    # up-projected from ONE ``kv_latent_dim``-wide latent a token, beside
+    # which one ``qk_rope_dim``-wide rotary key serves all heads. The cache
+    # holds that latent and that key alone; ``head_dim`` is the qk width
+    # (``qk_nope_dim + qk_rope_dim``) and ``num_kv_heads`` 1.
+    # ``attn_scale_mult`` multiplies the score scale ``head_dim ** -0.5``.
+    q_latent_dim: int = 0
+    kv_latent_dim: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    attn_scale_mult: float = 1.0
+    # Learned sparse attention (``index_topk`` > 0): an indexer of
+    # ``index_heads`` heads of ``index_dim`` scores every cached token for
+    # every query from its own cached key, and attention sees the
+    # ``index_topk`` best alone.
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
+    # Router: "softmax" (top-k of a softmax, with a capacity), or
+    # "sigmoid_groups": sigmoid scores plus a learned selection bias, the
+    # ``moe_groups_kept`` best of ``moe_groups`` groups by the sum of their
+    # two best, the top-k inside them, weights from the unbiased scores
+    # normalised and times ``moe_routed_scale``; no token dropped.
+    moe_gate: str = "softmax"
+    moe_groups: int = 1
+    moe_groups_kept: int = 1
+    moe_routed_scale: float = 1.0
+    moe_shared_width: int = 0    # a shared expert of this width, always on
+    # One member's share of an expert-parallel layer: the router's width
+    # (0 = ``num_experts``, all held) and the first expert of the
+    # ``num_experts`` held here; the layer returns its partial sum.
+    moe_routed_experts: int = 0
+    moe_first_expert: int = 0
+    # Leading dense layers: ``lead_dense_layers`` more layers BEFORE the
+    # ``num_layers`` of the main stack, with a dense MLP of width
+    # ``lead_dense_ffn``, in a stack of their own (``lead_layers``).
+    lead_dense_layers: int = 0
+    lead_dense_ffn: int = 0
     name: str = "transformer"
 
     def __post_init__(self):
@@ -122,6 +162,24 @@ class TransformerConfig:
             )
         if "window" in self.layer_pattern and self.attn_window < 1:
             raise ValueError("a window layer needs attn_window >= 1")
+        if self.moe_gate not in ("softmax", "sigmoid_groups"):
+            raise ValueError(
+                f"moe_gate {self.moe_gate!r} (softmax or sigmoid_groups)")
+        if self.index_topk and not self.is_latent:
+            raise ValueError("the indexer scores a latent cache's tokens: "
+                             "index_topk needs kv_latent_dim")
+        if self.is_latent and (
+                self.layer_pattern or self.qk_nope_dim + self.qk_rope_dim
+                != self.hd or self.kv_heads != 1):
+            raise ValueError(
+                "latent attention: head_dim is qk_nope_dim + qk_rope_dim, "
+                "num_kv_heads 1 (one latent a token), one layer kind")
+        if self.routed_experts % self.moe_groups or not (
+                0 <= self.moe_first_expert
+                <= self.routed_experts - self.num_experts):
+            raise ValueError(
+                f"experts {self.moe_first_expert}..+{self.num_experts} of "
+                f"{self.routed_experts} in {self.moe_groups} groups")
 
     @property
     def kv_heads(self) -> int:
@@ -143,6 +201,24 @@ class TransformerConfig:
     def has_window(self) -> bool:
         return "window" in self.layer_pattern
 
+    @property
+    def is_latent(self) -> bool:
+        return self.kv_latent_dim > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Values a token keeps in a latent cache."""
+        return self.kv_latent_dim + self.qk_rope_dim
+
+    @property
+    def routed_experts(self) -> int:
+        """Experts the router chooses among (those held: ``num_experts``)."""
+        return self.moe_routed_experts or self.num_experts
+
+    @property
+    def total_layers(self) -> int:
+        return self.lead_dense_layers + self.num_layers
+
     def kind_count(self, kind: str) -> int:
         """Layers of ``kind`` in the whole stack."""
         if not self.layer_pattern:
@@ -161,6 +237,15 @@ class TransformerConfig:
         d, v, L = self.hidden_size, self.vocab_size, self.num_layers
         ln_width = 2 * d if self.norm == "layernorm" else d  # scale (+bias)
         qkvo = d * self.num_heads * self.hd * 2 + d * self.kv_heads * self.hd * 2
+        if self.is_latent:
+            nh, ql, kl = self.num_heads, self.q_latent_dim, self.kv_latent_dim
+            qkvo = (d * ql + ql + ql * nh * self.hd + d * self.latent_width
+                    + kl + kl * nh * (self.qk_nope_dim + self.v_head_dim)
+                    + nh * self.v_head_dim * d)
+            if self.index_topk:
+                qkvo += (ql * self.index_heads * self.index_dim
+                         + d * self.index_dim + 2 * self.index_dim
+                         + d * self.index_heads)
         if self.activation == "swiglu":
             mlp = 3 * d * self.ffn
         else:
@@ -168,7 +253,10 @@ class TransformerConfig:
         if self.is_moe:
             dense_mlp = mlp
             mlp *= self.num_experts
-            mlp += d * self.num_experts  # router
+            mlp += d * self.routed_experts  # router
+            if self.moe_gate == "sigmoid_groups":
+                mlp += self.routed_experts  # selection bias
+            mlp += 3 * d * self.moe_shared_width
             if self.moe_use_residual:
                 mlp += dense_mlp + 2 * d  # residual dense branch + coef
         biases = 0
@@ -179,11 +267,40 @@ class TransformerConfig:
         if self.qk_norm:
             biases += 2 * self.hd
         per_layer = qkvo + mlp + biases + 2 * ln_width
+        lead = self.lead_dense_layers * (
+            qkvo + 3 * d * self.lead_dense_ffn + 2 * ln_width)
         embed = v * d + (self.max_seq_len * d if self.pos_embedding == "learned" else 0)
         if self.embed_norm:
             embed += ln_width
         head = 0 if self.tie_embeddings else v * d
-        return L * per_layer + embed + head + ln_width
+        return L * per_layer + lead + embed + head + ln_width
+
+
+def _latent_attn_params(cfg: "TransformerConfig", nrm, lk, L: int,
+                        out_scale: float, dtype) -> Params:
+    """Attention leaves of ``L`` latent-attention layers (and their
+    indexer's, under ``idx``), stacked."""
+    d, nh = cfg.hidden_size, cfg.num_heads
+    ql, kl = cfg.q_latent_dim, cfg.kv_latent_dim
+    attn = {
+        "wq_a": nrm(lk[0], L, d, ql),
+        "q_norm": {"scale": jnp.ones((L, ql), dtype)},
+        "wq_b": nrm(lk[1], L, ql, nh * cfg.hd),
+        "wkv_a": nrm(lk[2], L, d, cfg.latent_width),
+        "kv_norm": {"scale": jnp.ones((L, kl), dtype)},
+        "wkv_b": nrm(lk[10], L, kl, nh * (cfg.qk_nope_dim + cfg.v_head_dim)),
+        "wo": nrm(lk[3], L, nh * cfg.v_head_dim, d, scale=out_scale),
+    }
+    if cfg.index_topk:
+        ik = jax.random.split(lk[11], 3)
+        attn["idx"] = {
+            "wq_b": nrm(ik[0], L, ql, cfg.index_heads * cfg.index_dim),
+            "wk": nrm(ik[1], L, d, cfg.index_dim),
+            "k_norm": {"scale": jnp.ones((L, cfg.index_dim), dtype),
+                       "bias": jnp.zeros((L, cfg.index_dim), dtype)},
+            "w_proj": nrm(ik[2], L, d, cfg.index_heads),
+        }
+    return attn
 
 
 # -----------------------------------------------------------------------------
@@ -193,7 +310,6 @@ def init(cfg: TransformerConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
     std = cfg.initializer_range
     keys = jax.random.split(rng, 16)
     d, hd, nh, nkv, f = cfg.hidden_size, cfg.hd, cfg.num_heads, cfg.kv_heads, cfg.ffn
-    L = cfg.num_layers
 
     def nrm(key, *shape, scale=std):
         return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
@@ -217,25 +333,40 @@ def init(cfg: TransformerConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
         params["lm_head"] = nrm(keys[2], d, cfg.vocab_size)
 
     # residual-branch output projections get depth-scaled init (GPT-2 paper)
-    out_scale = std / math.sqrt(2 * L)
-    lk = jax.random.split(keys[3], 12)
-    attn = {
-        "wq": nrm(lk[0], L, d, nh * hd),
-        "wk": nrm(lk[1], L, d, nkv * hd),
-        "wv": nrm(lk[2], L, d, nkv * hd),
-        "wo": nrm(lk[3], L, nh * hd, d, scale=out_scale),
-    }
-    if cfg.use_bias:
-        for nm, width in (("bq", nh * hd), ("bk", nkv * hd), ("bv", nkv * hd), ("bo", d)):
-            attn[nm] = jnp.zeros((L, width), dtype)
-    if cfg.qk_norm:
-        attn["q_norm"] = {"scale": jnp.ones((L, hd), dtype)}
-        attn["k_norm"] = {"scale": jnp.ones((L, hd), dtype)}
+    out_scale = std / math.sqrt(2 * cfg.total_layers)
 
+    def attn_params(lk, L):
+        if cfg.is_latent:
+            return _latent_attn_params(cfg, nrm, lk, L, out_scale, dtype)
+        attn = {
+            "wq": nrm(lk[0], L, d, nh * hd),
+            "wk": nrm(lk[1], L, d, nkv * hd),
+            "wv": nrm(lk[2], L, d, nkv * hd),
+            "wo": nrm(lk[3], L, nh * hd, d, scale=out_scale),
+        }
+        if cfg.use_bias:
+            for nm, width in (("bq", nh * hd), ("bk", nkv * hd), ("bv", nkv * hd), ("bo", d)):
+                attn[nm] = jnp.zeros((L, width), dtype)
+        if cfg.qk_norm:
+            attn["q_norm"] = {"scale": jnp.ones((L, hd), dtype)}
+            attn["k_norm"] = {"scale": jnp.ones((L, hd), dtype)}
+        return attn
+
+    def dense_mlp(lk, L, f):
+        mlp = {"wi": nrm(lk[5], L, d, f), "wo": nrm(lk[6], L, f, d, scale=out_scale)}
+        if cfg.activation == "swiglu":
+            mlp["wg"] = nrm(lk[7], L, d, f)
+        if cfg.use_bias:
+            mlp["bi"] = jnp.zeros((L, f), dtype)
+            mlp["bo"] = jnp.zeros((L, d), dtype)
+        return mlp
+
+    lk = jax.random.split(keys[3], 12)
+    L = cfg.num_layers
     if cfg.is_moe:
         E = cfg.num_experts
         mlp = {
-            "router": nrm(lk[4], L, d, E),
+            "router": nrm(lk[4], L, d, cfg.routed_experts),
             "wi": nrm(lk[5], L, E, d, f),
             "wo": nrm(lk[6], L, E, f, d, scale=out_scale),
         }
@@ -247,20 +378,31 @@ def init(cfg: TransformerConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
             if cfg.activation == "swiglu":
                 mlp["res_wg"] = nrm(lk[10], L, d, f)
             mlp["coef"] = nrm(lk[11], L, d, 2)
+        if cfg.moe_gate == "sigmoid_groups":
+            # the selection bias: added to the scores to choose, never to
+            # weigh ("sel_": a leaf named b... is a bias drawn as zero)
+            mlp["sel_bias"] = nrm(lk[8], L, cfg.routed_experts)
+        if cfg.moe_shared_width:
+            sk = jax.random.split(lk[9], 8)
+            mlp["shared"] = dense_mlp(sk, L, cfg.moe_shared_width)
     else:
-        mlp = {"wi": nrm(lk[5], L, d, f), "wo": nrm(lk[6], L, f, d, scale=out_scale)}
-        if cfg.activation == "swiglu":
-            mlp["wg"] = nrm(lk[7], L, d, f)
-        if cfg.use_bias:
-            mlp["bi"] = jnp.zeros((L, f), dtype)
-            mlp["bo"] = jnp.zeros((L, d), dtype)
+        mlp = dense_mlp(lk, L, f)
 
     params["layers"] = {
         "ln1": norm_params(ln_bias, (L,)),
         "ln2": norm_params(ln_bias, (L,)),
-        "attn": attn,
+        "attn": attn_params(lk, L),
         "mlp": mlp,
     }
+    if cfg.lead_dense_layers:
+        dk = jax.random.split(keys[4], 12)
+        Ld = cfg.lead_dense_layers
+        params["lead_layers"] = {
+            "ln1": norm_params(ln_bias, (Ld,)),
+            "ln2": norm_params(ln_bias, (Ld,)),
+            "attn": attn_params(dk, Ld),
+            "mlp": dense_mlp(dk, Ld, cfg.lead_dense_ffn),
+        }
     return params
 
 
@@ -308,16 +450,20 @@ def _rope(q: jax.Array, k: jax.Array, positions: jax.Array,
     return rot(q), rot(k)
 
 
+def _rms_last(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm over the last dim by plain lines (a head's values, a latent:
+    widths the hidden-size norm kernel is not for)."""
+    x32 = x.astype(jnp.float32)
+    ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * lax.rsqrt(ms + eps) * scale.astype(jnp.float32)
+            ).astype(x.dtype)
+
+
 def _qk_norm(cfg: TransformerConfig, p: Params, q: jax.Array, k: jax.Array):
     """RMSNorm over the head dim of every q and k head (``cfg.qk_norm``),
     each with its learned [hd] vector, before the rotary embedding."""
-    def one(x, scale):
-        x32 = x.astype(jnp.float32)
-        ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-        return (x32 * lax.rsqrt(ms + cfg.norm_eps)
-                * scale.astype(jnp.float32)).astype(x.dtype)
-
-    return one(q, p["q_norm"]["scale"]), one(k, p["k_norm"]["scale"])
+    return (_rms_last(q, p["q_norm"]["scale"], cfg.norm_eps),
+            _rms_last(k, p["k_norm"]["scale"], cfg.norm_eps))
 
 
 def alibi_slopes(num_heads: int) -> np.ndarray:
@@ -408,9 +554,10 @@ def _act(cfg: TransformerConfig, x: jax.Array) -> jax.Array:
 
 
 def _mlp(cfg: TransformerConfig, p: Params, x: jax.Array, rng: Optional[jax.Array],
-         train: bool) -> Tuple[jax.Array, jax.Array]:
-    """Returns (output, aux_loss). Dense MLP or routed MoE expert layer."""
-    if cfg.is_moe:
+         train: bool, dense: bool = False) -> Tuple[jax.Array, jax.Array]:
+    """Returns (output, aux_loss). Dense MLP or routed MoE expert layer
+    (``dense``: a leading dense layer of a routed model)."""
+    if cfg.is_moe and not dense:
         from ..moe.sharded_moe import moe_layer
 
         return moe_layer(cfg, p, x, rng, train)
@@ -684,6 +831,27 @@ def masked_ce(logits: jax.Array, labels: jax.Array, num_mb_dims: int = 0):
     return nll.sum() / denom, denom
 
 
+def _refuse_uncached(cfg: TransformerConfig) -> None:
+    """What only the paged serving step computes (models/decoding.py) is
+    refused by the uncached forward, which training and ``forward`` use."""
+    why = [what for cond, what in (
+        (cfg.is_latent, "latent attention (kv_latent_dim): its keys exist "
+         "as cached latents alone"),
+        (cfg.lead_dense_layers, "leading dense layers (lead_dense_layers)"),
+        (cfg.moe_gate != "softmax", f"the {cfg.moe_gate} router"),
+        (cfg.moe_shared_width, "a shared expert (moe_shared_width)"),
+        (cfg.routed_experts != cfg.num_experts, "one member's share of an "
+         "expert-parallel layer (moe_routed_experts)"),
+    ) if cond]
+    if why:
+        from ..config import DeepSpeedConfigError
+
+        raise DeepSpeedConfigError(
+            "the uncached forward (training, evaluation, generate) does not "
+            "compute " + "; ".join(why) + ": serve this configuration "
+            "through init_serving with serving.paged")
+
+
 def apply(cfg: TransformerConfig, params: Params, input_ids: jax.Array, *,
           dtype=jnp.bfloat16, train: bool = False, rng: Optional[jax.Array] = None,
           positions: Optional[jax.Array] = None, segment_ids=None,
@@ -695,6 +863,7 @@ def apply(cfg: TransformerConfig, params: Params, input_ids: jax.Array, *,
     ``return_hidden`` the final normed hidden [B,S,d] instead of logits
     (the fused-CE path projects chunk-wise itself)."""
     B, S = input_ids.shape
+    _refuse_uncached(cfg)
     pos_default = positions is None
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
@@ -771,6 +940,11 @@ def tp_partition_specs(cfg: TransformerConfig, tp_divides_kv: bool = True) -> Pa
     Row-parallel: attn-out + mlp-out shard input dim over tp.
     Embeddings/lm_head shard vocab over tp (loss is vocab-parallel).
     """
+    if cfg.is_latent:
+        # one latent a token serves every head: nothing splits by head, and
+        # every leaf is whole on every device
+        shapes = jax.eval_shape(partial(init, cfg), jax.random.PRNGKey(0))
+        return jax.tree.map(lambda a: P(*([None] * a.ndim)), shapes)
     kv_tp = "tp" if tp_divides_kv else None
     ln = {"scale": P(None, None)}
     if cfg.norm == "layernorm":

@@ -209,6 +209,72 @@ def top_k_gating_indices(
     )
 
 
+def sigmoid_group_gate(logits: jax.Array, sel_bias: jax.Array, top_k: int,
+                       groups: int, groups_kept: int, routed_scale: float):
+    """The router of DeepSeek-V3 (``noaux_tc``): ``logits`` [N, E] float32
+    -> (expert idx [N, K] int32, weight [N, K] float32).
+
+    Scores are ``sigmoid(logits)``. ``sel_bias`` [E] is added to CHOOSE and
+    never to weigh: a group (E / groups consecutive experts) scores the sum
+    of its two best biased scores, the ``groups_kept`` best groups stay, the
+    ``top_k`` best biased scores inside them are chosen (ties to the lower
+    index), and the weights are the chosen experts' unbiased scores
+    normalised to one, times ``routed_scale``."""
+    N, E = logits.shape
+    scores = jax.nn.sigmoid(logits)
+    biased = scores + sel_bias.astype(jnp.float32)[None, :]
+    per = E // groups
+    best2, _ = jax.lax.top_k(biased.reshape(N, groups, per), min(2, per))
+    _, kept = jax.lax.top_k(jnp.sum(best2, axis=-1), groups_kept)  # [N, gk]
+    in_kept = jnp.any(
+        kept[:, :, None] == jnp.arange(groups)[None, None, :], axis=1)
+    masked = jnp.where(jnp.repeat(in_kept, per, axis=1), biased, -jnp.inf)
+    _, idx = jax.lax.top_k(masked, top_k)
+    w = jnp.take_along_axis(scores, idx, axis=1)
+    w = w / jnp.maximum(jnp.sum(w, axis=1, keepdims=True), 1e-20)
+    return idx.astype(jnp.int32), w * routed_scale
+
+
+def held_expert_tables(idx, w, valid, first: int, held: int, capacity: int):
+    """Index tables of ONE member's share of an expert-parallel layer, in
+    the layout of :func:`top_k_gating_indices`: of each token's chosen
+    experts ``idx`` [N, K] (weights ``w``), those in ``first .. first +
+    held`` get a row of their expert's ``capacity`` here; the others are
+    computed elsewhere (slot 0, weight zero). No token is dropped while
+    ``capacity`` is at least the real tokens (an expert is chosen once a
+    token). Also returns the tokens per held expert [held] and the real
+    tokens that chose no held expert."""
+    N, K = idx.shape
+    local = idx - first
+    here = (local >= 0) & (local < held)
+    if valid is not None:
+        here = here & valid[:, None]
+    tok_flat = jnp.zeros((held * capacity + 1,), jnp.int32)
+    valid_flat = jnp.zeros((held * capacity + 1,), jnp.bool_)
+    fill = jnp.zeros((held,), jnp.int32)
+    slots = []
+    arange_n = jnp.arange(N, dtype=jnp.int32)
+    for k in range(K):
+        onehot = jax.nn.one_hot(local[:, k], held, dtype=jnp.int32) * (
+            here[:, k, None].astype(jnp.int32))
+        pos = jnp.sum((jnp.cumsum(onehot, axis=0) - 1 + fill[None, :])
+                      * onehot, axis=-1)
+        keep = here[:, k] & (pos < capacity)
+        flat = jnp.clip(local[:, k], 0, held - 1) * capacity + jnp.minimum(
+            pos, capacity - 1)
+        target = jnp.where(keep, flat, held * capacity)
+        tok_flat = tok_flat.at[target].set(arange_n)
+        valid_flat = valid_flat.at[target].set(True)
+        slots.append(jnp.where(keep, flat, 0))
+        fill = fill + jnp.sum(onehot, axis=0)
+        here = here.at[:, k].set(keep)
+    real = jnp.ones((N,), bool) if valid is None else valid
+    unrouted = jnp.sum((real & ~jnp.any(here, axis=1)).astype(jnp.int32))
+    return (tok_flat[:-1].reshape(held, capacity),
+            valid_flat[:-1].reshape(held, capacity),
+            jnp.stack(slots, axis=1), w * here, fill, unrouted)
+
+
 def eval_capacity(cfg, n_tokens: int) -> int:
     """Per-expert capacity at inference for a program that feeds at most
     ``n_tokens`` real tokens: ``max(4, ceil(max(capacity_factor, 2.0) ·
@@ -438,7 +504,6 @@ def moe_serving_mlp(cfg, p: Dict, x: jax.Array,
     K = cfg.moe_top_k
     if budget_tokens is None:
         budget_tokens = S if token_valid is not None else N
-    capacity = eval_capacity(cfg, int(budget_tokens))
 
     tokens = x.reshape(N, D)
     valid = token_valid.reshape(N) if token_valid is not None else None
@@ -446,10 +511,30 @@ def moe_serving_mlp(cfg, p: Dict, x: jax.Array,
         "nd,de->ne", tokens.astype(jnp.float32),
         p["router"].astype(jnp.float32),
     )
-    tok_of_slot, slot_valid, slot_of_tok, w_of_tok, metrics = (
-        top_k_gating_indices(router_logits, K, capacity, rng=None,
-                             train=False, valid=valid)
-    )
+    if cfg.moe_gate == "sigmoid_groups":
+        # the router sees every expert of the layer; the ``E`` held here
+        # compute the tokens sent to them, with room for every real token
+        # (no drop), and the layer returns that partial sum
+        capacity = int(budget_tokens)
+        idx, w = sigmoid_group_gate(
+            router_logits, p["sel_bias"], K, cfg.moe_groups,
+            cfg.moe_groups_kept, cfg.moe_routed_scale)
+        tok_of_slot, slot_valid, slot_of_tok, w_of_tok, fill, unrouted = (
+            held_expert_tables(idx, w, valid, cfg.moe_first_expert, E,
+                               capacity))
+        metrics = {"tokens_per_expert": fill,
+                   "drop_fraction": jnp.zeros((), jnp.float32),
+                   "unrouted_tokens": unrouted}
+    else:
+        if cfg.routed_experts != E:
+            raise NotImplementedError(
+                "a share of an expert-parallel layer (moe_routed_experts) is "
+                "served under the sigmoid_groups router alone")
+        capacity = eval_capacity(cfg, int(budget_tokens))
+        tok_of_slot, slot_valid, slot_of_tok, w_of_tok, metrics = (
+            top_k_gating_indices(router_logits, K, capacity, rng=None,
+                                 train=False, valid=valid)
+        )
 
     ring_cfg = None
     topo = current_topology()
@@ -494,6 +579,11 @@ def moe_serving_mlp(cfg, p: Dict, x: jax.Array,
 
     if cfg.moe_use_residual:
         out = _residual_mix(cfg, p, x, out)
+    if cfg.moe_shared_width:
+        from ..models.transformer import _mlp
+
+        # the shared expert: a dense MLP every token takes, beside the routed
+        out = out + _mlp(cfg, p["shared"], x, None, False, dense=True)[0]
 
     # routed_tokens stays derivable (tokens_per_expert.sum()) — the
     # metrics layer re-derives it, so the step ships no redundant scalar
@@ -501,4 +591,6 @@ def moe_serving_mlp(cfg, p: Dict, x: jax.Array,
         "tokens_per_expert": metrics["tokens_per_expert"],
         "drop_fraction": metrics["drop_fraction"],
     }
+    if "unrouted_tokens" in metrics:
+        stats["unrouted_tokens"] = metrics["unrouted_tokens"]
     return out, stats

@@ -30,14 +30,15 @@ DEFAULT_BLOCK_S = 256
 
 
 def _tile_update(q, k, v, ks, vs, start, cl, scale, m_scr, l_scr, acc_scr,
-                 lo=None):
+                 lo=None, allowed=None):
     """One [block_s, hd] K/V tile's contribution to the fp32 online
     softmax (shared by the dense and paged kernels): dequantize when
     scales ride along, mask past the row's frontier, fold into the
     running (max, sum, acc) scratches. ``cl`` is the frontier: a scalar,
     a per-(row, key) array (paged_attention's chunk rows), or None for a
     tile wholly below every row's frontier. ``lo`` (with ``cl``; a window
-    layer) is the last key position a row no longer sees."""
+    layer) is the last key position a row no longer sees. ``allowed`` (a
+    per-(row, key) bool array; a learned selection) masks by itself."""
     if ks is not None:
         # int8 cache: dequantize the tile with its per-token scales
         k = (k.astype(jnp.float32) * ks[:, :1]).astype(q.dtype)
@@ -56,6 +57,8 @@ def _tile_update(q, k, v, ks, vs, start, cl, scale, m_scr, l_scr, acc_scr,
         if lo is not None:
             seen = seen & (kpos > lo)
         s = jnp.where(seen, s, NEG_INF)
+    if allowed is not None:
+        s = jnp.where(allowed, s, NEG_INF)
 
     m_prev = m_scr[:, :1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))

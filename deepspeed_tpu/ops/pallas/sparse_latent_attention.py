@@ -1,0 +1,524 @@
+"""Pallas kernels of learned sparse attention over a paged latent cache.
+
+Parity: DeepSeek-V3.2's lightning indexer and its sparse latent attention
+(``inference/model.py`` of the release: ``Indexer`` and the absorbed branch
+of ``MLA``), for the one ``[max_slots, token_budget]`` step the serving
+engine compiles. Three calls a layer, each named in a device trace:
+
+``indexer_scores``  For every real query row of a slot, the index score of
+    every cached token at or before it: ``sum_j w_j relu(q_j . k)`` over
+    the indexer's heads, the keys read page by page through the slot's
+    table from the indexer's own pool. One program a slot; a loop over key
+    blocks whose trip count follows the slot's length, and inside it a loop
+    over row tiles that follows the slot's real rows.
+``selection_topk``  The exact ``topk`` largest scores of each row, as a
+    threshold: 32 counting passes find the ``topk``-th largest value bit by
+    bit (the scores as order-preserving integers), 17 more the position up
+    to which its ties belong (ties go to the lower position). A row whose
+    context is within ``topk`` keeps it all. No sort, no index list.
+``sparse_latent_attention``  Softmax attention of the absorbed queries over
+    the chosen tokens of the latent pool. One latent row is key AND value of
+    all heads, so the heads of a query stack as the rows of one matmul
+    against one ``[block_k, latent]`` tile. The program walks every block of
+    the slot's context and masks what the selection left out: its work
+    follows the context, not ``topk`` (PERF.md says what that costs; a walk
+    over a per-row list of chosen rows is the open follow-up).
+
+Layouts: pools ``[L, P+1, page_size, width]`` as stored, the layer's index a
+scalar in SMEM beside ``page_table [B, max_pages]`` and the per-slot
+frontiers; ``width`` is a multiple of 128 lanes (a latent row is padded to
+it with zeros). Scores are float32 ``[B, blocks, S, block_k]``, key block
+major (a block of every row is one contiguous write of the scoring kernel
+and one leading index of the two that read it); only blocks a slot's loop
+reaches are written, and only columns at or before a row's own position are
+ever read.
+
+The ``dense_*`` functions compute the same three steps in plain
+``jax.numpy`` over a gathered per-slot view: the path of a CPU engine and
+the oracle of the kernels' tests.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import List, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .decode_attention import LANES, NEG_INF, _tile_update
+from .paged_attention import (SMEM_TABLE_BYTES, VMEM_LIMIT_BYTES,
+                              _block_pages, _frontiers)
+
+BLOCK_K = 512        # keys a loop trip of the scoring and attention kernels
+SCORE_ROWS = 16      # query rows a tile of the scoring kernel
+SELECT_ROWS = 8      # query rows a program of the selection kernel
+SELECT_BLOCKS = 8    # key blocks a counting step of the selection reads
+ATTN_ROWS = 8        # query rows a program of the attention kernel
+INT_MIN = -(2 ** 31)
+
+
+def score_blocks(max_pages: int, page_size: int) -> Tuple[int, int]:
+    """(key blocks of the score matrix, keys a block): the slot's mapped
+    tokens in whole blocks, the blocks in whole counting steps."""
+    bk = page_size * _block_pages(BLOCK_K, page_size, max_pages)
+    nb = -(-(max_pages * page_size) // bk)
+    if nb > SELECT_BLOCKS:
+        nb = -(-nb // SELECT_BLOCKS) * SELECT_BLOCKS
+    return nb, bk
+
+
+# --------------------------------------------------------------- paging
+def _page_fetch(pt_ref, hbm, buf, sems, b, layer, ps, ppb, mp):
+    """(start, wait) of the block fetches of one slot: ``ppb`` whole pages
+    of ``hbm[layer]`` through row ``b`` of the table into ``buf[slot]``."""
+    def copy(slot, j, page):
+        return pltpu.make_async_copy(
+            hbm.at[layer, page], buf.at[slot, pl.ds(j * ps, ps)],
+            sems.at[slot])
+
+    def start(blk, slot):
+        def one(j, c):
+            # pages past the table re-read its last entry: those keys lie
+            # past every row's frontier
+            copy(slot, j, pt_ref[b, jnp.minimum(blk * ppb + j, mp - 1)]
+                 ).start()
+            return c
+
+        lax.fori_loop(0, ppb, one, 0)
+
+    def wait(slot):
+        def one(j, c):
+            copy(slot, j, 0).wait()
+            return c
+
+        lax.fori_loop(0, ppb, one, 0)
+
+    return start, wait
+
+
+# -------------------------------------------------------------- indexer
+def _index_scores_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, w_ref,
+                         k_hbm, o_hbm, k_buf, o_buf, ksems, osems,
+                         *, page_size, pages_per_block, heads, rows):
+    ps, ppb = page_size, pages_per_block
+    bk = ps * ppb
+    mp = pt_ref.shape[1]
+    b = pl.program_id(0)
+    cl, nn, layer = cl_ref[b], nn_ref[b], layer_ref[0]
+    start_fetch, wait_fetch = _page_fetch(pt_ref, k_hbm, k_buf, ksems, b,
+                                          layer, ps, ppb, mp)
+
+    def out_copy(slot, blk):
+        return pltpu.make_async_copy(
+            o_buf.at[slot], o_hbm.at[b, blk], osems.at[slot])
+
+    @pl.when(nn > 0)
+    def _score():
+        n_blocks = jnp.minimum(pl.cdiv(cl + nn, bk), pl.cdiv(mp * ps, bk))
+        n_tiles = pl.cdiv(nn, rows)
+        start_fetch(0, 0)
+
+        def block(i, carry):
+            slot = lax.rem(i, 2)
+
+            @pl.when(i + 1 < n_blocks)
+            def _prefetch():
+                start_fetch(i + 1, 1 - slot)
+
+            wait_fetch(slot)
+
+            @pl.when(i >= 2)
+            def _drain():  # the write that last used this half
+                out_copy(slot, i - 2).wait()
+
+            k = k_buf[slot]
+
+            def tile(t, c):
+                r0 = pl.multiple_of(t * rows * heads, rows * heads)
+                q = q_ref[0, pl.ds(r0, rows * heads), :]
+                s = lax.dot_general(
+                    q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # [rows*heads, bk]
+                s = jnp.maximum(s, 0.0) * w_ref[0, pl.ds(r0, rows * heads), :1]
+                o_buf[slot, pl.ds(pl.multiple_of(t * rows, rows), rows), :] = (
+                    jnp.sum(s.reshape(rows, heads, bk), axis=1))
+                return c
+
+            lax.fori_loop(0, n_tiles, tile, 0)
+            out_copy(slot, i).start()
+            return carry
+
+        lax.fori_loop(0, n_blocks, block, 0)
+
+        @pl.when(n_blocks >= 2)
+        def _last_but_one():
+            out_copy(lax.rem(n_blocks, 2), n_blocks - 2).wait()
+
+        out_copy(lax.rem(n_blocks - 1, 2), n_blocks - 1).wait()
+
+
+def index_scores(q_idx, w_idx, ki_pool, cache_len, page_table, *, layer,
+                 num_new=None, interpret: Optional[bool] = None):
+    """Index scores float32 ``[B, blocks, S, block_k]`` (token ``s`` of row
+    ``i`` at ``[b, s // block_k, i, s % block_k]``). ``q_idx`` [B,S,Hi,Di]
+    rotated indexer queries, ``w_idx`` [B,S,Hi] float32 head weights (every
+    constant factor folded in), ``ki_pool`` [L,P+1,ps,Di] the indexer keys,
+    the chunk's own already written. An entry is defined for
+    ``s < cache_len[b] + num_new[b]`` and ``i < num_new[b]``."""
+    B, S, Hi, Di = q_idx.shape
+    ps, mp = ki_pool.shape[2], page_table.shape[1]
+    ppb = _block_pages(BLOCK_K, ps, mp)
+    NB, bk = score_blocks(mp, ps)
+    rows = min(SCORE_ROWS, S)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    cl, nn = _frontiers(B, S, cache_len, num_new)
+    q2 = q_idx.reshape(B, S * Hi, Di)
+    w2 = jnp.broadcast_to(
+        w_idx.astype(jnp.float32).reshape(B, S * Hi, 1), (B, S * Hi, LANES))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4, grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1, S * Hi, Di), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec((1, S * Hi, LANES), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((2, bk, Di), ki_pool.dtype),
+            pltpu.VMEM((2, S, bk), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_index_scores_kernel, page_size=ps,
+                          pages_per_block=ppb, heads=Hi, rows=rows),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, NB, S, bk), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret, name="indexer_scores",
+    )(jnp.asarray(page_table, jnp.int32), cl, nn,
+      jnp.asarray(layer, jnp.int32).reshape(1), q2, w2, ki_pool)
+
+
+# ------------------------------------------------------------ selection
+def _sort_key(x):
+    """float32 -> int32 of the same order (-0.0 counted as 0.0)."""
+    i = lax.bitcast_convert_type(jnp.where(x == 0.0, 0.0, x), jnp.int32)
+    return i ^ ((i >> 31) & jnp.int32(0x7FFFFFFF))
+
+
+def _selection_kernel(cl_ref, nn_ref, s_ref, thr_ref, tie_ref, key_scr,
+                      *, topk, rows, group, pos_bits):
+    b, t = pl.program_id(0), pl.program_id(1)
+    cl, nn = cl_ref[b], nn_ref[b]
+    bk = s_ref.shape[-1]
+    r0 = t * rows
+    # every key allowed: what a row inside ``topk`` (or a padded row) gets
+    thr_ref[0] = jnp.full(thr_ref.shape[1:], INT_MIN, jnp.int32)
+    tie_ref[0] = jnp.full(tie_ref.shape[1:], 2 ** 31 - 1, jnp.int32)
+
+    @pl.when((r0 < nn) & (cl + jnp.minimum(nn, r0 + rows) > topk))
+    def _search():
+        qpos = cl + r0 + lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1)
+        n_groups = pl.cdiv(cl + jnp.minimum(nn, r0 + rows), group * bk)
+        shape = (group, rows, bk)
+        in_group = lax.broadcasted_iota(jnp.int32, shape, 0) * bk + (
+            lax.broadcasted_iota(jnp.int32, shape, 2))
+
+        def fill(g, carry):
+            at = pl.ds(g * group, group)
+            pos = g * group * bk + in_group
+            key_scr[at] = jnp.where(
+                pos <= qpos, _sort_key(s_ref[0, at]), INT_MIN)
+            return carry
+
+        lax.fori_loop(0, n_groups, fill, 0)
+
+        def count(pred):
+            def one(g, acc):
+                pos = g * group * bk + in_group
+                hit = pred(key_scr[pl.ds(g * group, group)], pos)
+                return acc + jnp.sum(
+                    jnp.sum(hit.astype(jnp.int32), axis=0), axis=1,
+                    keepdims=True)
+
+            return lax.fori_loop(0, n_groups, one,
+                                 jnp.zeros((rows, 1), jnp.int32))
+
+        # the topk-th largest key, from its sign down: the largest T with
+        # count(key >= T) >= topk
+        lo = jnp.where(count(lambda k, p: k >= 0) >= topk, 0, INT_MIN)
+
+        def value_bit(i, lo):
+            cand = lo + jnp.left_shift(jnp.int32(1), 30 - i)
+            n = count(lambda k, p: k >= cand[None])
+            return jnp.where(n >= topk, cand, lo)
+
+        lo = lax.fori_loop(0, 31, value_bit, lo)
+        need = topk - count(lambda k, p: k > lo[None])  # of its ties
+
+        def pos_bit(i, at):  # the largest p with count(tie, pos < p) < need
+            cand = at + jnp.left_shift(jnp.int32(1), pos_bits - 1 - i)
+            n = count(lambda k, p: (k == lo[None]) & (p < cand[None]))
+            return jnp.where(n < need, cand, at)
+
+        tie = lax.fori_loop(0, pos_bits, pos_bit,
+                            jnp.zeros((rows, 1), jnp.int32))
+        inside = qpos[0] < topk  # such a row keeps its whole context
+        thr_ref[0] = jnp.broadcast_to(
+            jnp.where(inside, INT_MIN, lo), thr_ref.shape[1:])
+        tie_ref[0] = jnp.broadcast_to(
+            jnp.where(inside, 2 ** 31 - 1, tie), tie_ref.shape[1:])
+
+
+def select_topk(scores, cache_len, num_new, topk: int,
+                interpret: Optional[bool] = None):
+    """The selection of every row of ``scores`` (as :func:`index_scores`
+    lays them out) as (threshold key, tie position), int32 [B,S] each: row
+    ``i`` of slot ``b`` (at position ``cache_len[b] + i``) sees token ``s``
+    at or before it iff ``key(score) > thr or (key(score) == thr and
+    s <= tie)``, which are its ``topk`` best, ties to the lower position (all
+    of them inside ``topk`` tokens). See :func:`_sort_key` for ``key``."""
+    B, NB, S, bk = scores.shape
+    rows = min(SELECT_ROWS, S)
+    group = min(SELECT_BLOCKS, NB)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    cl, nn = _frontiers(B, S, cache_len, num_new)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(B, S // rows),
+        in_specs=[pl.BlockSpec((1, NB, rows, bk),
+                               lambda b, t, *_: (b, 0, t, 0))],
+        out_specs=[pl.BlockSpec((1, rows, LANES), lambda b, t, *_: (b, t, 0)),
+                   pl.BlockSpec((1, rows, LANES), lambda b, t, *_: (b, t, 0))],
+        scratch_shapes=[pltpu.VMEM((NB, rows, bk), jnp.int32)],
+    )
+    thr, tie = pl.pallas_call(
+        functools.partial(_selection_kernel, topk=int(topk), rows=rows,
+                          group=group, pos_bits=(NB * bk).bit_length()),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, S, LANES), jnp.int32)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret, name="selection_topk",
+    )(cl, nn, scores)
+    return thr[:, :, 0], tie[:, :, 0]
+
+
+# ------------------------------------------------------------ attention
+def _sparse_attention_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, s_ref,
+                             thr_ref, tie_ref, kv_hbm, o_ref, kv_buf, sems,
+                             m_scr, l_scr, acc_scr,
+                             *, scale, page_size, pages_per_block, heads,
+                             rows, v_width):
+    ps, ppb = page_size, pages_per_block
+    bk = ps * ppb
+    mp = pt_ref.shape[1]
+    b, t = pl.program_id(0), pl.program_id(1)
+    cl, nn, layer = cl_ref[b], nn_ref[b], layer_ref[0]
+    r0 = t * rows
+    start_fetch, wait_fetch = _page_fetch(pt_ref, kv_hbm, kv_buf, sems, b,
+                                          layer, ps, ppb, mp)
+
+    @pl.when(r0 >= nn)
+    def _padding():  # rows no token stands in: keep them finite
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(r0 < nn)
+    def _attend():
+        # keys this tile's last real row needs: 0 .. cl + min(nn, r0+rows)-1
+        n_blocks = jnp.minimum(
+            pl.cdiv(cl + jnp.minimum(nn, r0 + rows), bk),
+            pl.cdiv(mp * ps, bk))
+        qpos = cl + r0 + lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        thr, tie = thr_ref[0, :, :1], tie_ref[0, :, :1]
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        start_fetch(0, 0)
+
+        def block(i, carry):
+            slot = lax.rem(i, 2)
+
+            @pl.when(i + 1 < n_blocks)
+            def _prefetch():
+                start_fetch(i + 1, 1 - slot)
+
+            wait_fetch(slot)
+            q = q_ref[0]
+            kv = kv_buf[slot].astype(q.dtype)
+            key = _sort_key(s_ref[0, i])
+            pos = i * bk + lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
+            chosen = (pos <= qpos) & (
+                (key > thr) | ((key == thr) & (pos <= tie)))
+            # the heads of a query share its row of the selection
+            _tile_update(
+                q, kv, kv[:, :v_width], None, None, i * bk, None, scale,
+                m_scr, l_scr, acc_scr,
+                allowed=jnp.broadcast_to(
+                    chosen[:, None, :], (rows, heads, bk)
+                ).reshape(rows * heads, bk))
+            return carry
+
+        lax.fori_loop(0, n_blocks, block, 0)
+        l = l_scr[:, :1]
+        o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)
+                    ).astype(o_ref.dtype)
+
+
+def sparse_attention(q_abs, kv_pool, scores, thr, tie, cache_len, page_table,
+                     *, layer, scale: float, v_width: int, num_new=None,
+                     interpret: Optional[bool] = None):
+    """Absorbed queries ``q_abs`` [B,S,H,W] against the chosen latent rows of
+    ``kv_pool`` [L,P+1,ps,W] (key: the whole row; value: its first
+    ``v_width`` lanes). ``scores``/``thr``/``tie`` as :func:`select_topk`
+    gives them. Returns [B,S,H,v_width]; a tile of rows wholly past
+    ``num_new`` is zeros, padded rows beside real ones are finite."""
+    B, S, H, W = q_abs.shape
+    ps, mp = kv_pool.shape[2], page_table.shape[1]
+    ppb = _block_pages(BLOCK_K, ps, mp)
+    NB, bk = scores.shape[1], scores.shape[3]
+    rows = min(ATTN_ROWS, S)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    cl, nn = _frontiers(B, S, cache_len, num_new)
+
+    def lanes(a):
+        return jnp.broadcast_to(a[:, :, None], (B, S, LANES))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4, grid=(B, S // rows),
+        in_specs=[
+            pl.BlockSpec((1, rows * H, W), lambda b, t, *_: (b, t, 0)),
+            pl.BlockSpec((1, NB, rows, bk), lambda b, t, *_: (b, 0, t, 0)),
+            pl.BlockSpec((1, rows, LANES), lambda b, t, *_: (b, t, 0)),
+            pl.BlockSpec((1, rows, LANES), lambda b, t, *_: (b, t, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, rows * H, v_width),
+                               lambda b, t, *_: (b, t, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, bk, W), kv_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((rows * H, LANES), jnp.float32),
+            pltpu.VMEM((rows * H, LANES), jnp.float32),
+            pltpu.VMEM((rows * H, v_width), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _sparse_attention_kernel, scale=float(scale), page_size=ps,
+            pages_per_block=ppb, heads=H, rows=rows, v_width=v_width),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, S * H, v_width), q_abs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret, name="sparse_latent_attention",
+    )(jnp.asarray(page_table, jnp.int32), cl, nn,
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      q_abs.reshape(B, S * H, W), scores, lanes(thr), lanes(tie), kv_pool)
+    return out.reshape(B, S, H, v_width)
+
+
+def kernel_reasons(q_abs, q_idx, kv_pool, ki_pool, page_table,
+                   interpret: bool) -> List[str]:
+    """Why the three kernels cannot take these operands ([] = they can)."""
+    from ...models.sharding import current_topology
+
+    B, S = q_abs.shape[:2]
+    reasons = []
+    topo = current_topology()
+    if topo is not None and topo.world_size > 1:
+        reasons.append("a mesh of several devices (one latent serves every "
+                       "head: the kernels are written for one device)")
+    if kv_pool.dtype not in (jnp.bfloat16, jnp.float32):
+        reasons.append(f"{jnp.dtype(kv_pool.dtype).name} latent pool")
+    if S % SELECT_ROWS or S % ATTN_ROWS:
+        reasons.append(f"a chunk of {S} rows is not whole 8-row tiles")
+    if not interpret:
+        for what, width in (("latent row", kv_pool.shape[-1]),
+                            ("indexer key", ki_pool.shape[-1])):
+            if width % LANES:
+                reasons.append(f"{what} of {width} is not {LANES}-aligned")
+        if B * page_table.shape[1] * 4 > SMEM_TABLE_BYTES:
+            reasons.append(
+                f"a [{B}, {page_table.shape[1]}] page table is over the "
+                f"{SMEM_TABLE_BYTES >> 10} KiB of SMEM it may take")
+    return reasons
+
+
+def latent_sparse_attention(q_abs, q_idx, w_idx, kv_pool, ki_pool, cache_len,
+                            page_table, *, layer, topk: int, scale: float,
+                            v_width: int, num_new=None,
+                            interpret: Optional[bool] = None
+                            ) -> Tuple[Optional[jax.Array], List[str]]:
+    """Scores, selection and attention of one layer through the kernels.
+    Returns ``(out [B,S,H,v_width], [])``, or ``(None, reasons)`` when the
+    operands are not theirs (the caller takes the dense lines)."""
+    interp = interpret if interpret is not None else (
+        jax.default_backend() != "tpu")
+    reasons = kernel_reasons(q_abs, q_idx, kv_pool, ki_pool, page_table,
+                             interp)
+    if reasons:
+        from ...utils.logging import log_fallback_once
+
+        log_fallback_once("latent_sparse_attention", reasons)
+        return None, reasons
+    kw = dict(num_new=num_new, interpret=interp)
+    scores = index_scores(q_idx, w_idx, ki_pool, cache_len, page_table,
+                          layer=layer, **kw)
+    thr, tie = select_topk(scores, cache_len, num_new, topk, interpret=interp)
+    return sparse_attention(
+        q_abs, kv_pool, scores, thr, tie, cache_len, page_table, layer=layer,
+        scale=scale, v_width=v_width, **kw), reasons
+
+
+# ---------------------------------------------------------- dense lines
+def unblocked(scores):
+    """The kernels' ``[B, blocks, S, block_k]`` scores as ``[B, S, N]``."""
+    B, NB, S, bk = scores.shape
+    return scores.swapaxes(1, 2).reshape(B, S, NB * bk)
+
+
+def dense_index_scores(q_idx, w_idx, ki_view):
+    """[B,S,Hi,Di] x [B,N,Di] -> float32 [B,S,N]."""
+    s = jnp.einsum("bshd,bnd->bshn", q_idx.astype(jnp.float32),
+                   ki_view.astype(jnp.float32))
+    return jnp.sum(jnp.maximum(s, 0.0)
+                   * w_idx.astype(jnp.float32)[..., None], axis=2)
+
+
+def dense_selection(scores, qpos, topk: int):
+    """bool [B,S,N]: the ``topk`` best tokens at or before each row's
+    position ``qpos`` [B,S] (all of them inside ``topk``), ties to the lower
+    position (``lax.top_k`` is stable)."""
+    N = scores.shape[-1]
+    seen = jnp.arange(N)[None, None, :] <= qpos[..., None]
+    # (a sort tells -0.0 from 0.0; a comparison, and the kernel, do not)
+    scores = jnp.where(scores == 0.0, 0.0, scores)
+    _, idx = lax.top_k(jnp.where(seen, scores, -jnp.inf), min(topk, N))
+    B, S = scores.shape[:2]
+    chosen = jnp.zeros(scores.shape, bool).at[
+        jnp.arange(B)[:, None, None], jnp.arange(S)[None, :, None], idx
+    ].set(True)
+    return chosen & seen
+
+
+def dense_sparse_attention(q_abs, kv_view, chosen, scale: float,
+                           v_width: int):
+    """[B,S,H,W] x [B,N,W] under ``chosen`` [B,S,N] -> float32
+    [B,S,H,v_width]."""
+    kv = kv_view.astype(jnp.float32)
+    s = jnp.einsum("bshw,bnw->bshn", q_abs.astype(jnp.float32), kv) * scale
+    p = jax.nn.softmax(jnp.where(chosen[:, :, None, :], s, -1e30), axis=-1)
+    return jnp.einsum("bshn,bnv->bshv", p, kv[..., :v_width])

@@ -86,6 +86,19 @@ def cache_partition_specs(quantized: bool, window_pool: bool = False
     return specs
 
 
+def cache_token_bytes(cfg, storage_itemsize: int, quantized: bool) -> int:
+    """Bytes one token keeps in one layer of the cache, scales left out:
+    keys and values of every KV head, or for latent attention its one
+    latent row (as the pool pads it) and its indexer key."""
+    if cfg.is_latent:
+        from ..models.decoding import latent_row_width
+
+        width = latent_row_width(cfg) + (
+            cfg.index_dim if cfg.index_topk else 0)
+        return width * storage_itemsize
+    return 2 * cfg.kv_heads * cfg.hd * (1 if quantized else storage_itemsize)
+
+
 def serving_kv_stream(cfg, max_slots: int, capacity: int,
                       storage_itemsize: int, quantized: bool,
                       tp: int = 1) -> Dict[str, Any]:
@@ -94,9 +107,9 @@ def serving_kv_stream(cfg, max_slots: int, capacity: int,
     rule R8). Upper bound: the dense slot design streams the whole arena
     per step (k+v read + the chunk write); the Pallas decode kernel's
     per-tile predication reads less when frontiers are short."""
-    per_tok = cfg.kv_heads * cfg.hd * (1 if quantized else storage_itemsize)
-    arena_tokens = cfg.num_layers * max_slots * capacity
-    data = arena_tokens * per_tok * 2  # k + v
+    per_tok = cache_token_bytes(cfg, storage_itemsize, quantized)  # k + v
+    arena_tokens = cfg.total_layers * max_slots * capacity
+    data = arena_tokens * per_tok
     scales = (
         arena_tokens * SCALE_LANES * 4 * 2 if quantized else 0
     )
@@ -158,16 +171,15 @@ def paged_kv_stream(cfg, num_pages: int, page_size: int, max_slots: int,
     tokens, and the COW lane copies at most one page per slot. The POOL
     bytes themselves (the R6 capacity term) are priced from the traced
     step's invars — num_pages here is reported for the summary line."""
-    per_tok = cfg.kv_heads * cfg.hd * (1 if quantized else storage_itemsize)
-    scale_tok = SCALE_LANES * 4 if quantized else 0
-    view_tokens = cfg.num_layers * max_slots * pages_per_slot * page_size
-    gather = view_tokens * (per_tok + scale_tok) * 2          # k + v reads
-    scatter = cfg.num_layers * max_slots * token_budget * (
-        per_tok + scale_tok
-    ) * 2
-    cow = cfg.num_layers * max_slots * page_size * (per_tok + scale_tok) * 2
+    # k + v of a token, with their scales
+    per_tok = cache_token_bytes(cfg, storage_itemsize, quantized) + (
+        2 * SCALE_LANES * 4 if quantized else 0)
+    L = cfg.total_layers
+    gather = L * max_slots * pages_per_slot * page_size * per_tok
+    scatter = L * max_slots * token_budget * per_tok
+    cow = L * max_slots * page_size * per_tok
     total = gather + scatter + cow
-    pool_tokens = cfg.num_layers * (num_pages + 1) * page_size
+    pool_tokens = L * (num_pages + 1) * page_size
     return {
         "kind": "hbm",
         "bytes_per_step": total,
@@ -177,7 +189,7 @@ def paged_kv_stream(cfg, num_pages: int, page_size: int, max_slots: int,
         "page_size": page_size,
         "num_pages": num_pages,
         "pages_per_slot": pages_per_slot,
-        "pool_bytes": pool_tokens * (per_tok + scale_tok) * 2,
+        "pool_bytes": pool_tokens * per_tok,
         "slots": max_slots,
         "quantized": quantized,
     }
@@ -691,6 +703,21 @@ class ServingEngine:
             self.window_pages_per_slot = (
                 -(-(mcfg.attn_window + W) // self.page_size) + 1)
             self.window_num_pages = N * self.window_pages_per_slot
+        self.latent = self.paged and bool(mcfg.is_latent)
+        if self.latent:
+            from ..config import DeepSpeedConfigError
+
+            why = (
+                "the model caches latents (kv_latent_dim): a spilled or "
+                "handed-over page is laid out as the k and v of KV heads, "
+                "which a latent pool and its indexer keys are not"
+            )
+            if int(getattr(serving, "host_pages", 0) or 0) > 0:
+                raise DeepSpeedConfigError(
+                    f"serving.host_pages is refused: {why}")
+            if int(serving.fleet.prefill_replicas) > 0:
+                raise DeepSpeedConfigError(
+                    f"serving.fleet.prefill_replicas is refused: {why}")
         # ---- tiered KV (serving.host_pages > 0, ISSUE 18): a pinned-
         # host second tier behind the HBM pool. The ENGINE owns the
         # store + spiller (movement needs device access: export/encode on
@@ -859,9 +886,12 @@ class ServingEngine:
         # XLA compile — the zero-recompiles-after-warmup assertion
         self.step_traces = 0
         # which attention the compiled step took — "paged_kernel" (Pallas,
-        # work follows each slot's length), "decode_kernel" or "dense" (the
-        # XLA lines, with the reasons) — chosen at trace time from what the
-        # step can observe, so recorded by the same side effect
+        # work follows each slot's length), "latent_sparse_kernel" (the
+        # indexer, selection and sparse latent attention calls of
+        # ops/pallas/sparse_latent_attention.py), "decode_kernel" or "dense"
+        # (the XLA lines, with the reasons a kernel declined) — chosen at
+        # trace time from what the step can observe, so recorded by the same
+        # side effect
         self.attention_path: Optional[str] = None
         self.attention_fallback: Tuple[str, ...] = ()
 
@@ -872,7 +902,7 @@ class ServingEngine:
             self.attention_path = rec["path"]
             self.attention_fallback = rec["reasons"]
             self.metrics.attention_paged_kernel = float(
-                rec["path"] == "paged_kernel"
+                rec["path"] in ("paged_kernel", "latent_sparse_kernel")
             )
             self.metrics.attention_paged_kernel_kinds = {
                 kind: float(path == "paged_kernel")
@@ -1024,7 +1054,8 @@ class ServingEngine:
                 paged_args += self._stage_args(plan)
             # a one-kind model pays for the count only under the tracer
             keys = (self._count_keys(plan)
-                    if self.kinds_paged or dispatch_sp is not None else {})
+                    if self.kinds_paged or self.latent
+                    or dispatch_sp is not None else {})
             if dispatch_sp is not None:
                 dispatch_sp.annotate(**keys)
         else:
@@ -1083,7 +1114,8 @@ class ServingEngine:
         out_tok, new_rng, n_emit, moe_stats = jax.device_get((
             out_tok, new_rng, n_emit,
             moe_stats and (moe_stats["tokens_per_expert"],
-                           moe_stats["drop_fraction"]),
+                           moe_stats["drop_fraction"],
+                           moe_stats.get("unrouted_tokens")),
         ))
         finished = self.scheduler.complete(
             plan, out_tok, new_rng, n_emit=n_emit,
@@ -1094,7 +1126,7 @@ class ServingEngine:
             # already computed them on device
             self.metrics.on_moe(
                 moe_stats[0], float(moe_stats[1]),
-                a2a_bytes=self._moe_a2a_step_bytes,
+                a2a_bytes=self._moe_a2a_step_bytes, unrouted=moe_stats[2],
             )
         if self.comm_logger is not None:
             self.comm_logger.record_streams(self.analytic_streams())
@@ -1111,6 +1143,8 @@ class ServingEngine:
         from ..ops.pallas.paged_attention import key_counts
 
         out = {"rows": int(plan.num_new.sum())}
+        if self.latent:
+            return {**out, **self._count_selected(plan)}
         for kind in ("full", "window")[:1 + self.kinds_paged]:
             attended, fetched = key_counts(
                 plan.start_pos, plan.num_new, self.page_size,
@@ -1119,6 +1153,36 @@ class ServingEngine:
             self.metrics.on_keys(kind, attended, fetched)
             out["attended_" + kind], out["fetched_" + kind] = attended, fetched
         return out
+
+    def _count_selected(self, plan: StepPlan) -> Dict[str, int]:
+        """A latent model's attention work of one layer, from the plan
+        (host arithmetic, nothing read back): ``context_keys`` the cached
+        tokens at or before every real query token, which its indexer
+        scores; ``attended_sparse`` those of them a query attends, its
+        ``index_topk`` best; ``index_keys`` the tokens in the pages that
+        hold a slot's context, which the indexer reads once a slot;
+        ``chosen_min`` the fewest distinct latent rows a slot's queries can
+        have chosen between them (its last query's). Booked on the
+        metrics."""
+        cl = plan.start_pos.astype(np.int64)
+        nn = plan.num_new.astype(np.int64)
+        topk = int(self.config.index_topk) or (1 << 62)
+        context = nn * cl + nn * (nn + 1) // 2
+        # rows whose context passes topk attend topk of it
+        over = np.clip(cl + nn - topk, 0, nn)
+        attended = context - (over * (cl + nn - topk) - over * (over - 1) // 2)
+        ps = self.page_size
+        busy = nn > 0
+        counts = {
+            "context_keys": int(context.sum()),
+            "attended_sparse": int(attended.sum()),
+            "index_keys": int((-(-(cl + nn) // ps) * ps)[busy].sum()),
+            "chosen_min": int(np.minimum(cl + nn, topk)[busy].sum()),
+        }
+        self.metrics.on_keys("sparse", counts["attended_sparse"],
+                             counts["index_keys"])
+        self.metrics.context_keys += counts["context_keys"]
+        return counts
 
     def _stage_args(self, plan: StepPlan) -> tuple:
         """Decode this step's promotions into the rotating staging buffer

@@ -146,6 +146,13 @@ class ServingMetrics:
         self.window_pages_in_use = 0
         self.window_pages_free = 0
         self.window_pages_released = 0  # given back behind the window
+        # one member's share of an expert-parallel layer: real tokens (summed
+        # over layers) none of whose experts is held here
+        self.moe_unrouted_tokens = 0
+        # a latent model with an indexer: cached tokens at or before every
+        # real query token (what the indexer scores), beside
+        # attended_keys["sparse"], what its selection lets attention see
+        self.context_keys = 0
         # per kind, for ONE layer of the kind, summed over steps from the
         # plan: the keys visible to every real query token, and the keys
         # in the blocks the paged kernel's loops read for them
@@ -264,7 +271,7 @@ class ServingMetrics:
             self.prefill_chunks += 1
 
     def on_moe(self, tokens_per_expert, dropped_fraction,
-               a2a_bytes: int = 0) -> None:
+               a2a_bytes: int = 0, unrouted=None) -> None:
         """One MoE serving step's expert load-balance counters (ISSUE 14
         satellite): ``tokens_per_expert`` is the step's [E] capacity-slot
         histogram (summed over layers), ``dropped_fraction`` the valid
@@ -282,6 +289,8 @@ class ServingMetrics:
         self.moe_routed_tokens += sum(hist)
         self.moe_dropped_fraction = float(_finite(dropped_fraction))
         self.moe_a2a_bytes += int(_finite(a2a_bytes))
+        if unrouted is not None:
+            self.moe_unrouted_tokens += int(_finite(unrouted))
 
     @property
     def moe_load_imbalance(self) -> float:
@@ -410,6 +419,8 @@ class ServingMetrics:
             snap[f"fetched_keys_{kind}"] = self.fetched_keys[kind]
         for kind, on in self.attention_paged_kernel_kinds.items():
             snap[f"attention_paged_kernel_{kind}"] = on
+        if self.context_keys:
+            snap["context_keys"] = self.context_keys
         if "window" in self.attended_keys:
             snap.update({
                 "pages_free": self.pages_free,
@@ -437,6 +448,7 @@ class ServingMetrics:
                 "moe_dropped_fraction": self.moe_dropped_fraction,
                 "moe_load_imbalance": self.moe_load_imbalance,
                 "moe_a2a_bytes": self.moe_a2a_bytes,
+                "moe_unrouted_tokens": self.moe_unrouted_tokens,
             })
             # the per-expert histogram rides the snapshot (and the
             # serve/* bridge) as bounded scalar keys — E is small

@@ -1,0 +1,407 @@
+"""DeepSeek-V3.2's mechanisms at a small size on the CPU, float32, seeded
+weights: latent attention through the paged latent cache, the lightning
+indexer's own key cache and its exact selection, the sigmoid router with its
+selection bias and groups, one member's share of an expert-parallel layer,
+and a leading dense layer in a stack of its own, each against the plain
+reference ``benchmarks/families/deepseek.py``."""
+
+import dataclasses
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from benchmarks import reference
+from benchmarks.run import merged
+from deepspeed_tpu.config import DeepSpeedConfigError
+from deepspeed_tpu.models import deepseek
+from deepspeed_tpu.models.decoding import (INDEX, LATENT, _paged_gather,
+                                           forward_with_cache,
+                                           init_paged_cache)
+from deepspeed_tpu.moe import sharded_moe as sm
+from deepspeed_tpu.ops.attention import attention_impl
+from deepspeed_tpu.ops.pallas import sparse_latent_attention as sla
+from deepspeed_tpu.serving import Request
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+F32 = jnp.float32
+fam = reference.family("deepseek")
+
+
+@pytest.fixture(scope="module")
+def model():
+    return deepseek("deepseek-tiny")
+
+
+@pytest.fixture(scope="module")
+def params(model):
+    p = model.init(jax.random.PRNGKey(7), dtype=F32)
+    # a selection bias large enough to decide choices (init draws 0.02)
+    bias = jax.random.normal(jax.random.PRNGKey(8),
+                             p["layers"]["mlp"]["sel_bias"].shape) * 0.3
+    p["layers"]["mlp"]["sel_bias"] = bias
+    return p
+
+
+@pytest.fixture(scope="module")
+def shape():
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "deepseek-v3.2.json")) as f:
+        cfg = json.load(f)
+    return fam.shape_of(merged(cfg, cfg["rehearse"]))
+
+
+def test_the_tiny_preset_is_the_rehearsals_shape(model, shape):
+    c = model.config
+    assert (c.hidden_size, c.num_layers, c.num_heads, c.kv_heads, c.hd, c.ffn,
+            c.vocab_size, c.num_experts, c.moe_top_k) == (
+        shape.d, shape.layers, shape.heads, shape.kv_heads, shape.hd,
+        shape.ffn, shape.vocab, shape.experts, shape.top_k)
+    assert (c.lead_dense_layers, c.lead_dense_ffn, c.routed_experts,
+            c.index_topk, c.kv_latent_dim, c.q_latent_dim) == (
+        shape.dense_layers, shape.dense_ffn, shape.routed, shape.index_topk,
+        shape.kv_rank, shape.q_rank)
+    assert c.attn_scale_mult == pytest.approx(shape.mscale ** 2)
+    table = dict(c.rope_tables)["full"]
+    np.testing.assert_allclose(table.inv_freq(c.qk_rope_dim),
+                               fam.rope_table(shape), rtol=1e-6)
+
+
+def test_the_published_preset_is_the_catalogs_model():
+    c = deepseek("deepseek-v3.2").config
+    assert (c.total_layers, c.lead_dense_layers, c.hidden_size, c.num_heads,
+            c.q_latent_dim, c.kv_latent_dim, c.qk_nope_dim, c.qk_rope_dim,
+            c.v_head_dim, c.index_heads, c.index_dim, c.index_topk,
+            c.num_experts, c.moe_top_k, c.moe_groups, c.moe_groups_kept,
+            c.ffn, c.moe_shared_width, c.lead_dense_ffn, c.vocab_size) == (
+        61, 3, 7168, 128, 1536, 512, 128, 64, 128, 64, 128, 2048, 256, 8, 8,
+        4, 2048, 2048, 18432, 129280)
+    assert c.moe_routed_scale == 2.5 and c.norm_eps == 1e-6
+    # 671 B parameters (the release counts its MTP module too)
+    assert 6.55e11 < c.num_params() < 6.75e11
+
+
+def paged_logits(model, params, ids, W=16, ps=16, slot=1, slots=2,
+                 kernel=False):
+    """Prefill ``ids`` in chunks of ``W`` and nothing else: the logits of
+    every position through the paged latent cache, slot ``slot`` of
+    ``slots`` (the others idle), pages handed out in a scrambled order."""
+    cfg = model.config
+    mp = -(-(len(ids) + W) // ps)
+    cache = init_paged_cache(cfg, slots * mp, ps, F32)
+    order = np.random.default_rng(3).permutation(slots * mp)
+    table = jnp.asarray(order.reshape(slots, mp), jnp.int32)
+    out = []
+    step = jax.jit(lambda c, t, n, s: forward_with_cache(
+        cfg, params, t, c, s, dtype=F32, page_table=table, num_new=n,
+        token_valid=jnp.arange(W)[None, :] < n[:, None])[:2])
+    for lo in range(0, len(ids), W):
+        chunk = ids[lo:lo + W]
+        tokens = np.zeros((slots, W), np.int32)
+        tokens[slot, :len(chunk)] = chunk
+        n = np.zeros(slots, np.int32)
+        n[slot] = len(chunk)
+        start = np.zeros(slots, np.int32)
+        start[slot] = lo
+        with attention_impl("flash" if kernel else "xla"):
+            logits, cache = step(cache, jnp.asarray(tokens), jnp.asarray(n),
+                                 jnp.asarray(start))
+        out.append(np.asarray(logits[slot, :len(chunk)]))
+    return np.concatenate(out)
+
+
+@pytest.mark.parametrize("length", [21, 75], ids=["inside-topk", "past-topk"])
+def test_the_paged_latent_cache_computes_the_reference(model, params, shape,
+                                                       length):
+    """Chunks of 16 over pages of 16 (boundaries crossed, the last chunk
+    ragged), a context under and one three times over ``index_topk`` 24."""
+    ids = np.random.default_rng(length).integers(0, 512, length, np.int32)
+    want = np.asarray(fam.logits(params, ids, shape))
+    got = paged_logits(model, params, ids)
+    np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
+
+
+def test_the_kernels_serve_what_the_dense_lines_serve(model, params, shape):
+    """The three Pallas calls (interpret mode here) in the served forward:
+    the same logits as the reference past ``index_topk``, so the same chosen
+    sets."""
+    ids = np.random.default_rng(5).integers(0, 512, 60, np.int32)
+    want = np.asarray(fam.logits(params, ids, shape))
+    got = paged_logits(model, params, ids, kernel=True)
+    np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("fault", fam.FAULTS)
+def test_every_fault_moves_the_reference(params, shape, fault):
+    ids = np.random.default_rng(9).integers(0, 512, 75, np.int32)
+    clean = np.asarray(fam.logits(params, ids, shape))
+    broken = np.asarray(fam.logits(
+        ids=ids, shape=shape, **fam.faulted(params, fault, shape)))
+    assert np.abs(broken - clean).max() > 1e-3, fault
+
+
+def kernel_inputs(seed=0, B=3, S=16, Hi=2, Di=128, H=4, W=128, L=2, ps=16,
+                  mp=12):
+    rng = np.random.default_rng(seed)
+    P = B * mp
+    f = lambda *s: jnp.asarray(rng.normal(size=s), F32)
+    return dict(
+        ki=f(L, P + 1, ps, Di), kv=f(L, P + 1, ps, W),
+        pt=jnp.asarray(rng.permutation(P).reshape(B, mp), jnp.int32),
+        cl=jnp.asarray([0, 37, 150], jnp.int32),
+        nn=jnp.asarray([16, 1, 9], jnp.int32),
+        q_idx=f(B, S, Hi, Di), w_idx=f(B, S, Hi), q_abs=f(B, S, H, W))
+
+
+def chosen_by_kernel(scores, thr, tie, qpos):
+    key = sla._sort_key(sla.unblocked(scores))
+    pos = jnp.arange(key.shape[-1])[None, None]
+    return (pos <= qpos[..., None]) & (
+        (key > thr[..., None]) | ((key == thr[..., None])
+                                  & (pos <= tie[..., None])))
+
+
+@pytest.mark.parametrize("ties", [False, True], ids=["distinct", "ties"])
+def test_the_selection_kernel_chooses_the_references_set(ties):
+    """Scores through the table, then the exact top-24 of each real row as a
+    threshold and a tie position: the set ``lax.top_k`` chooses, ties to the
+    lower position; a row inside 24 tokens keeps them all."""
+    k = kernel_inputs()
+    topk, layer, S = 24, 1, 16
+    scores = sla.index_scores(k["q_idx"], k["w_idx"], k["ki"], k["cl"],
+                              k["pt"], layer=layer, num_new=k["nn"],
+                              interpret=True)
+    dense = sla.dense_index_scores(
+        k["q_idx"], k["w_idx"], _paged_gather(k["ki"][layer], k["pt"]))
+    qpos = k["cl"][:, None] + jnp.arange(S)[None]
+    real = (jnp.arange(S)[None] < k["nn"][:, None])[..., None]
+    seen = (jnp.arange(dense.shape[-1])[None, None] <= qpos[..., None]) & real
+    np.testing.assert_allclose(
+        jnp.where(seen, sla.unblocked(scores), 0),
+        jnp.where(seen, dense, 0), atol=1e-4)
+    if ties:  # many equal scores, signed zeros among them
+        scores = jnp.round(scores * 2) / 2
+    thr, tie = sla.select_topk(scores, k["cl"], k["nn"], topk, interpret=True)
+    got = chosen_by_kernel(scores, thr, tie, qpos)
+    want = sla.dense_selection(
+        jnp.where(seen, sla.unblocked(scores), 0.0), qpos, topk)
+    assert bool(jnp.all(jnp.where(real, got == want, True)))
+    counts = np.asarray(got.sum(-1))
+    assert list(counts[2, :9]) == [24] * 9 and list(counts[0]) == list(
+        range(1, 17))
+
+
+def test_the_attention_kernel_attends_the_chosen_rows_alone():
+    k = kernel_inputs(seed=2)
+    layer, topk, S, V = 0, 24, 16, 64
+    out, why = sla.latent_sparse_attention(
+        k["q_abs"], k["q_idx"], k["w_idx"], k["kv"], k["ki"], k["cl"],
+        k["pt"], layer=layer, topk=topk, scale=0.1, v_width=V,
+        num_new=k["nn"], interpret=True)
+    assert why == []
+    qpos = k["cl"][:, None] + jnp.arange(S)[None]
+    chosen = sla.dense_selection(sla.dense_index_scores(
+        k["q_idx"], k["w_idx"], _paged_gather(k["ki"][layer], k["pt"])),
+        qpos, topk)
+    want = sla.dense_sparse_attention(
+        k["q_abs"], _paged_gather(k["kv"][layer], k["pt"]), chosen, 0.1, V)
+    real = (jnp.arange(S)[None] < k["nn"][:, None])[..., None, None]
+    np.testing.assert_allclose(jnp.where(real, out, 0),
+                               jnp.where(real, want, 0), atol=1e-5)
+
+
+def test_the_absorbed_form_is_the_plain_form(model, params, shape):
+    """One attention block over a fresh chunk: the program scores absorbed
+    queries against cached latents and up-projects after the sum; the
+    reference builds every head's keys and values. Same numbers."""
+    from deepspeed_tpu.models.decoding import _latent_cached_attention
+
+    cfg = model.config
+    S, ps = 48, 16
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, S, cfg.hidden_size))
+    a = jax.tree.map(lambda w: w[1], params["layers"]["attn"])
+    pools = init_paged_cache(cfg, 4, ps, F32)
+    table = jnp.asarray([[2, 0, 3, 1]], jnp.int32)
+    normed = reference.rmsnorm(x, {"scale": jnp.ones(cfg.hidden_size)},
+                               cfg.norm_eps)
+    got, pools = _latent_cached_attention(
+        cfg, a, normed, jnp.arange(S)[None], 2, pools, jnp.zeros(1, jnp.int32),
+        table)
+    ones = {"scale": jnp.ones(cfg.hidden_size)}
+    with reference.HIGHEST():
+        want = fam._attn(x[0], ones, a, shape,
+                         jnp.asarray(fam.rope_table(shape))) - x[0]
+    np.testing.assert_allclose(got[0], want, atol=2e-5, rtol=2e-4)
+    # the cache holds the latent and the rotated key, one row a token
+    assert pools[LATENT].shape[-1] == 128 and pools[INDEX].shape[-1] == 16
+    rows = _paged_gather(pools[LATENT][2], table)[0, :S]
+    assert float(jnp.abs(rows[:, cfg.latent_width:]).max()) == 0.0
+    assert float(jnp.abs(pools[LATENT][1]).max()) == 0.0  # another layer
+
+
+def test_the_gate_against_a_case_worked_by_hand():
+    """8 experts in 4 groups of 2, 2 groups kept, top-2, scaling 2.5. The
+    bias changes the choice and not the weight."""
+    s = np.array([[0.9, 0.1, 0.6, 0.55, 0.5, 0.45, 0.2, 0.8]], np.float32)
+    logits = jnp.asarray(np.log(s / (1 - s)))
+    zero = jnp.zeros(8)
+    # groups score 1.0, 1.15, 0.95, 1.0: groups 1 and 0 kept (a tie at 1.0
+    # goes to the lower group); inside them the two best are 0.9 and 0.6
+    idx, w = sm.sigmoid_group_gate(logits, zero, 2, 4, 2, 2.5)
+    assert idx.tolist() == [[0, 2]]
+    np.testing.assert_allclose(w, [[2.5 * 0.9 / 1.5, 2.5 * 0.6 / 1.5]],
+                               rtol=1e-5)
+    # a bias on expert 5: group 2 scores 1.35 and stays with group 1; the
+    # choice is 5 (biased 0.85) then 2 (0.6), weights from the UNBIASED
+    # scores 0.45 and 0.6
+    bias = zero.at[5].set(0.4)
+    idx, w = sm.sigmoid_group_gate(logits, bias, 2, 4, 2, 2.5)
+    assert idx.tolist() == [[5, 2]]
+    np.testing.assert_allclose(w, [[2.5 * 0.45 / 1.05, 2.5 * 0.6 / 1.05]],
+                               rtol=1e-5)
+    # all groups kept: the plain top-2 of the biased scores
+    idx, _ = sm.sigmoid_group_gate(logits, zero, 2, 4, 4, 1.0)
+    assert idx.tolist() == [[0, 7]]
+    # the reference routes alike
+    x = jnp.asarray([[1.0, 0.0, 0.0, 0.0]])  # normed: [2, 0, 0, 0]
+    router = jnp.zeros((4, 8)).at[0].set(logits[0] / 2.0)
+    _, wr, margin = fam._route(
+        x, {"scale": jnp.ones(4)}, router, bias, top_k=2, groups=4,
+        groups_kept=2, scale=2.5, first=0, held=8, eps=0.0)
+    np.testing.assert_allclose(
+        wr[0, [5, 2]], [2.5 * 0.45 / 1.05, 2.5 * 0.6 / 1.05], rtol=1e-4)
+    assert float(wr.sum()) == pytest.approx(2.5, rel=1e-5)
+    assert float(margin[0]) == pytest.approx(0.6 - 0.55, abs=1e-5)
+
+
+def test_the_sixteen_shares_add_up(model, params):
+    """The partial outputs of every member of the expert-parallel layer, the
+    shared expert counted once, are the uncut layer's output; and no member
+    drops a token."""
+    cfg = model.config  # 4 of 16 experts a member: 4 members
+    E, held = cfg.routed_experts, cfg.num_experts
+    rng = jax.random.PRNGKey(11)
+    p = jax.tree.map(lambda w: w[0], params["layers"]["mlp"])
+    d, f = cfg.hidden_size, cfg.ffn
+    ks = jax.random.split(rng, 4)
+    bank = {n: jax.random.normal(k, (E, *p[n].shape[1:])) * 0.1
+            for n, k in zip(("wi", "wg", "wo"), ks)}
+    x = jax.random.normal(ks[3], (2, 16, d))
+    valid = jnp.arange(16)[None, :] < jnp.asarray([16, 5])[:, None]
+    whole_cfg = dataclasses.replace(cfg, num_experts=E, moe_routed_experts=E)
+    whole, _ = sm.moe_serving_mlp(whole_cfg, {**p, **bank}, x,
+                                  token_valid=valid)
+    from deepspeed_tpu.models.transformer import _mlp
+
+    shared = _mlp(cfg, p["shared"], x, None, False, dense=True)[0]
+    parts, seen = [], 0
+    for m in range(E // held):
+        share = dataclasses.replace(cfg, moe_first_expert=m * held)
+        mine = {n: w[m * held:(m + 1) * held] for n, w in bank.items()}
+        out, stats = sm.moe_serving_mlp(share, {**p, **mine}, x,
+                                        token_valid=valid)
+        assert float(stats["drop_fraction"]) == 0.0
+        seen += int(stats["tokens_per_expert"].sum())
+        parts.append(out)
+    assert seen == 21 * cfg.moe_top_k  # every choice of every real token
+    total = sum(parts) - (E // held - 1) * shared
+    real = valid[..., None]
+    np.testing.assert_allclose(jnp.where(real, total, 0),
+                               jnp.where(real, whole, 0), atol=1e-5)
+    # a member differs from the whole (the test would pass vacuously else)
+    assert float(jnp.abs(jnp.where(real, parts[0] - whole, 0)).max()) > 1e-3
+
+
+def serving(**over):
+    return dict(dict(max_slots=4, token_budget=16, max_tokens=384, paged=True,
+                     page_size=16, num_pages=0, prefix_cache=False), **over)
+
+
+def test_the_engine_serves_the_references_argmax_and_counts_its_work(
+        model, params, shape):
+    srv = deepspeed_tpu.init_serving(model, serving=serving(), params=params,
+                                     dtype=F32)
+    rng = np.random.default_rng(0)
+    states = [srv.submit(Request(
+        request_id=f"r{i}", prompt=rng.integers(0, 512, n, np.int32),
+        max_new_tokens=6, temperature=0.0, eos_token_id=-1))
+        for i, n in enumerate((20, 75, 130))]
+    srv.run_until_idle()
+    assert srv.step_traces == 1
+    assert srv.attention_path == "dense" and srv.attention_fallback
+    for st in states:
+        ids = np.concatenate([st.request.prompt, np.asarray(st.tokens)])
+        want = fam.logits(params, ids[:-1], shape, last=6)
+        assert reference.served_token_gaps(want, st.tokens).max() == 0.0
+    snap = srv.metrics.snapshot()
+    # the plan's arithmetic: a token at position p scores p + 1 keys and
+    # attends min(p + 1, 24)
+    ctx = sum(p + 1 for n in (20, 75, 130) for p in range(n + 5))
+    att = sum(min(p + 1, 24) for n in (20, 75, 130) for p in range(n + 5))
+    assert snap["context_keys"] == ctx and snap["attended_keys_sparse"] == att
+    assert snap["moe_steps"] == srv.metrics.steps
+    assert snap["moe_dropped_fraction"] == 0.0
+    rows = 3 * sum(n + 5 for n in (20, 75, 130))  # real tokens x 3 layers
+    assert 0 < snap["moe_unrouted_tokens"] < rows
+    assert snap["moe_routed_tokens"] + snap["moe_unrouted_tokens"] >= rows
+
+
+def test_the_engine_with_the_kernels_serves_the_dense_tokens(model, params):
+    def served():
+        srv = deepspeed_tpu.init_serving(model, serving=serving(),
+                                         params=params, dtype=F32)
+        rng = np.random.default_rng(4)
+        states = [srv.submit(Request(
+            request_id=f"r{i}", prompt=rng.integers(0, 512, n, np.int32),
+            max_new_tokens=4, temperature=0.0, eos_token_id=-1))
+            for i, n in enumerate((33, 61))]
+        srv.run_until_idle()
+        return srv, [st.tokens for st in states]
+
+    dense, want = served()
+    with attention_impl("flash"):  # the kernels, in interpret mode here
+        kern, got = served()
+    assert got == want
+    assert dense.attention_path == "dense"
+    assert kern.attention_path == "latent_sparse_kernel"
+    assert kern.attention_fallback == ()
+    assert kern.metrics.snapshot()["attention_paged_kernel"] == 1.0
+    kern.lower_step()
+
+
+@pytest.mark.parametrize("what,kw,match", [
+    ("int8-kv", dict(kv_cache_dtype="int8"), "int8 KV cache is refused"),
+    ("contiguous-arena", dict(serving=serving(paged=False)),
+     "contiguous KV arena is refused"),
+    ("host-pages", dict(serving=serving(host_pages=8)),
+     "host_pages is refused"),
+])
+def test_what_the_latent_path_cannot_take_is_refused_by_mechanism(
+        model, params, what, kw, match):
+    kw = dict(dict(serving=serving()), **kw)
+    with pytest.raises(DeepSpeedConfigError, match=match):
+        deepspeed_tpu.init_serving(model, params=params, dtype=F32, **kw)
+
+
+def test_training_a_latent_model_is_refused_by_mechanism(model, params):
+    batch = {"input_ids": jnp.zeros((2, 8), jnp.int32),
+             "labels": jnp.zeros((2, 8), jnp.int32)}
+    with pytest.raises(DeepSpeedConfigError, match="kv_latent_dim") as e:
+        model.loss(params, batch)
+    for mechanism in ("lead_dense_layers", "sigmoid_groups",
+                      "moe_shared_width", "moe_routed_experts"):
+        assert mechanism in str(e.value)
+    assert "deepseek" not in str(e.value).lower()
+    # one mechanism alone is refused by its own name
+    from deepspeed_tpu.models import mixtral
+
+    cfg = dataclasses.replace(mixtral("mixtral-tiny").config,
+                              moe_shared_width=16)
+    from deepspeed_tpu.models.transformer import _refuse_uncached
+
+    with pytest.raises(DeepSpeedConfigError, match="shared expert"):
+        _refuse_uncached(cfg)

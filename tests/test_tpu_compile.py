@@ -319,6 +319,46 @@ def test_mellum_slot_step_compiles_beside_its_arena(one_chip, monkeypatch,
     assert "paged_attention_window" in text and "paged_attention_full" in text
 
 
+def test_deepseek_slot_step_keeps_both_pools_in_place(one_chip, monkeypatch,
+                                                      capsys):
+    """The one [4, 128] serving step of DeepSeek-V3.2 at its published
+    widths and the benchmark's cut (1 dense + 4 routed layers, 16 of 256
+    experts, an eighth of the vocabulary) over its arena of 16,640 pages:
+    the latent pool and the indexer-key pool ride BOTH layer scans (the
+    dense layer's and the routed layers') as one carry, so the compiled step
+    holds no copy, slice or write-back of either pool's or a layer's size;
+    the three named kernels are in it; and it fits the chip with 0.75 GiB
+    to spare."""
+    from deepspeed_tpu.models import deepseek
+    from deepspeed_tpu.models.decoding import init_paged_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = deepseek("deepseek-v3.2", num_layers=4, lead_dense_layers=1,
+                     num_experts=16, moe_routed_experts=256, vocab_size=16160)
+    N, W, ps, cap = 4, 128, 16, 66560
+    caches = jax.eval_shape(
+        lambda: init_paged_cache(model.config, 16640, ps, BF16))
+    assert {k: v.shape[-1] for k, v in caches.items()} == {"kv": 640,
+                                                           "ki": 128}
+    compiled = _compile_slot_step(model, caches, one_chip, N, W,
+                                  -(-(cap + W) // ps))
+    m = compiled.memory_analysis()
+    pools = sum(a.size * a.dtype.itemsize for a in caches.values())
+    with capsys.disabled():
+        print(f"\ndeepseek slot step, described v5e: arguments "
+              f"{m.argument_size_in_bytes / GIB:.2f} GiB, temporaries "
+              f"{m.temp_size_in_bytes / GIB:.2f} GiB, aliased "
+              f"{m.alias_size_in_bytes / GIB:.2f} (the pools "
+              f"{pools / GIB:.2f})")
+    text = compiled.as_text()
+    assert _pool_copies(text, caches) == []
+    assert m.alias_size_in_bytes >= pools
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.0 * GIB
+    for name in ("indexer_scores", "selection_topk",
+                 "sparse_latent_attention"):
+        assert name in text
+
+
 # ----------------------------------------------------------------- norms
 @pytest.mark.parametrize("D", [1024, 4096])
 @pytest.mark.parametrize("kind", ["rmsnorm", "layernorm"])
