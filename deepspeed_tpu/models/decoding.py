@@ -104,24 +104,22 @@ def _update_scale_at(scale: jax.Array, new: jax.Array, layer,
         scale, new[None], (layer, 0, 0, cache_len, 0))
 
 
-def gather_verify_window(logits: jax.Array, num_new, spec_len,
-                         max_draft: int) -> jax.Array:
-    """Per-row verify-window gather for speculative decoding: of a ragged
-    chunk's logits [B, W, V], pick each row's last ``spec_len + 1`` REAL
-    positions (the committed-token feed plus its drafts), left-aligned
-    into a fixed [B, max_draft + 1, V] window. Rows with ``spec_len = 0``
-    reduce to the single last-real-position gather the plain serving
-    step always did (bitwise — same clip, same take_along_axis); window
-    slots past a row's ``spec_len`` hold clipped garbage the caller
-    masks. ``max_draft`` is static (the ONE step's fixed shape),
-    ``spec_len`` is traced — per-slot draft counts never recompile."""
-    W = logits.shape[1]
+def verify_window_rows(num_new, spec_len, max_draft: int, width: int):
+    """The rows of a ragged chunk whose logits the sampler reads: each
+    slot's last ``spec_len + 1`` REAL positions (the committed-token feed
+    plus its drafts), left-aligned into a fixed [B, max_draft + 1] index
+    into the chunk's ``width`` rows. Rows with ``spec_len = 0`` reduce to
+    the single last-real-position the plain serving step always read;
+    window slots past a row's ``spec_len`` and an idle slot
+    (``num_new`` 0) hold clipped rows the caller masks. Column ``j + 1``
+    is also where draft ``j`` rides in the chunk's tokens. ``max_draft``
+    is static (the ONE step's fixed shape), ``spec_len`` is traced —
+    per-slot draft counts never recompile."""
     base = num_new - 1 - spec_len
-    idx = jnp.clip(
+    return jnp.clip(
         base[:, None] + jnp.arange(max_draft + 1, dtype=jnp.int32)[None, :],
-        0, W - 1,
+        0, width - 1,
     )
-    return jnp.take_along_axis(logits, idx[:, :, None], axis=1)
 
 
 WIN = "_win"  # suffix of the window layers' pool leaves and page table
@@ -697,6 +695,7 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
                        page_table_win=None,
                        token_valid=None,
                        num_new=None,
+                       logit_rows=None,
                        return_moe_stats: bool = False):
     """Run new tokens through all layers against the cache.
 
@@ -705,6 +704,11 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
     engine's ragged slot batch. Returns (fp32 logits [B, S, V], updated
     cache) — plus per-step MoE load-balance stats as a third element when
     ``return_moe_stats`` is set on a routed-expert model.
+
+    ``logit_rows`` [B, K] int32 names the rows of each chunk whose logits
+    the caller reads (the serving step: :func:`verify_window_rows`): the
+    hidden state is gathered to them after the layers, so the final norm
+    and the head run over K rows a slot and the logits are [B, K, V].
 
     ``page_table`` [B, max_pages] switches ``cache`` to the block-paged
     pool form (init_paged_cache): every layer scatters its chunk through
@@ -837,6 +841,8 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
          layers if period == 1 else None))
     if collect_moe:  # [trips, period, ...] -> one row a layer
         lstats = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), lstats)
+    if logit_rows is not None:
+        x = jnp.take_along_axis(x, logit_rows[:, :, None], axis=1)
     x = _norm(cfg, cast(params["final_norm"]), x)
     logits = lm_head_logits(cfg, params, x)
     if return_moe_stats:

@@ -60,7 +60,8 @@ from ..inference.engine import (InferenceEngine, _align_cache,
                                 init_inference)
 from ..models.decoding import (SCALE_LANES, WIN, forward_with_cache,
                                init_cache, init_paged_cache, paged_cow_copy,
-                               record_attention_path, staged_promote)
+                               record_attention_path, staged_promote,
+                               verify_window_rows)
 from ..models.sharding import use_topology
 from ..utils.logging import log_dist
 from .metrics import ServingMetrics
@@ -127,16 +128,21 @@ def serving_kv_stream(cfg, max_slots: int, capacity: int,
     }
 
 
-def _make_sample_one(vocab: int):
-    """Per-slot sampler reproducing InferenceEngine._build_decode.sample
-    on a [1, V] row — same masking composition, same categorical key
-    shape — so a slot's tokens match the single-request engine bitwise.
-    The static top_k/top_p gates become traced ``where`` gates (identity
-    branches are bitwise identity), which is what keeps the serving step
-    at one compile for every sampling mix."""
+def _make_sample_window(vocab: int):
+    """The step's sampler over every slot's verify window, reproducing
+    InferenceEngine._build_decode.sample on each [1, V] row — same
+    masking composition, same categorical key shape — so a slot's tokens
+    match the single-request engine bitwise. The static top_k/top_p gates
+    become traced ``where`` gates (identity branches are bitwise
+    identity), and the filters they gate (two full-vocabulary sorts, a
+    softmax and a cumulative sum a row) run under ONE ``lax.cond`` a
+    step, taken when a live slot asks for top-k or top-p: a step of
+    greedy and temperature-only slots skips what every gate would have
+    discarded, and a step with one filtering slot runs the filters for
+    all, as before. Either way it is one compile for every sampling
+    mix."""
 
-    def sample_one(row, key, temp, tk, tp_):
-        l = row[None, :] / jnp.maximum(temp, 1e-6)
+    def filter_row(l, tk, tp_):
         # top-k: the k-th largest as threshold; identity when tk <= 0
         sorted_desc = jnp.sort(l, axis=-1)[:, ::-1]
         kth = jnp.take_along_axis(
@@ -152,12 +158,25 @@ def _make_sample_one(vocab: int):
         keep = (cum - probs) < tp_
         keep = keep.at[:, 0].set(True)
         pth = jnp.min(jnp.where(keep, nuc, jnp.inf), axis=-1, keepdims=True)
-        l = jnp.where((tp_ < 1.0) & (l < pth), -1e30, l)
+        return jnp.where((tp_ < 1.0) & (l < pth), -1e30, l)
+
+    def draw(l, key, temp):
         greedy = jnp.argmax(l, axis=-1)
         sampled = jax.random.categorical(key, l, axis=-1)
         return jnp.where(temp == 0.0, greedy, sampled)[0]
 
-    return sample_one
+    def sample_window(win, keys, live, temp, tk, tp_):
+        """win [N, Kw, V], keys [N, Kw, 2], the rest [N] -> [N, Kw]."""
+        l = win[:, :, None, :] / jnp.maximum(temp, 1e-6)[:, None, None, None]
+        l = jax.lax.cond(
+            jnp.any(live & ((tk > 0) | (tp_ < 1.0))),
+            jax.vmap(jax.vmap(filter_row, in_axes=(0, None, None))),
+            lambda l, tk, tp_: l,
+            l, tk, tp_,
+        )
+        return jax.vmap(jax.vmap(draw, in_axes=(0, 0, None)))(l, keys, temp)
+
+    return sample_window
 
 
 def paged_kv_stream(cfg, num_pages: int, page_size: int, max_slots: int,
@@ -376,7 +395,13 @@ def make_step_fn(cfg, dtype, vocab: int, cache_shardings=None,
 
     ``max_draft`` is STATIC (the step's fixed output shape
     [N, max_draft + 1]); 0 disables speculation and reduces the verify
-    window to the pre-spec single-token sampling tail, bitwise.
+    window to the pre-spec single-token sampling tail, bitwise. The final
+    norm and the head run over that window's rows alone
+    (``verify_window_rows``), not the [N, W] rows the layers computed:
+    nothing else of the chunk has logits.
+
+    ``page_table`` / ``page_table_win`` (keywords) are the paged step's:
+    :func:`make_paged_step_fn` is this step over its pools.
 
     Returns (caches, seen, out_tokens [N, max_draft + 1] i32,
     n_emit [N] i32, new_rng [N, 2]) — MoE models append a sixth
@@ -390,38 +415,36 @@ def make_step_fn(cfg, dtype, vocab: int, cache_shardings=None,
     more than W real tokens per step) — occupancy changes recompile
     nothing.
     """
-    sample_one = _make_sample_one(vocab)
+    sample_window = _make_sample_window(vocab)
     moe = bool(getattr(cfg, "is_moe", False))
 
     def step(params, caches, seen, tokens, num_new, start_pos, fresh,
              sample_flag, spec_len, eos_id, rng, temperature, top_k, top_p,
-             rep_penalty):
+             rep_penalty, page_table=None, page_table_win=None):
         live = sample_flag & (num_new > 0)
         seen = _book_seen(seen, tokens, num_new, spec_len, fresh, vocab)
         token_valid = (
             jnp.arange(tokens.shape[1])[None, :] < num_new[:, None]
             if moe else None
         )
-        fw = forward_with_cache(
+        rows = verify_window_rows(num_new, spec_len, max_draft,
+                                  tokens.shape[1])
+        logits, caches, *moe_stats = forward_with_cache(
             cfg, params, tokens, caches, start_pos, dtype=dtype,
-            token_valid=token_valid, return_moe_stats=moe,
+            page_table=page_table, page_table_win=page_table_win,
+            num_new=num_new,
+            token_valid=token_valid, logit_rows=rows, return_moe_stats=moe,
         )
-        if moe:
-            logits, caches, moe_stats = fw
-        else:
-            logits, caches = fw
         if cache_shardings is not None:
             # keep the donated arena carry sharding-closed across steps
             caches = jax.lax.with_sharding_constraint(
                 caches, cache_shardings
             )
         out_tok, n_emit, new_rng = verify_window(
-            sample_one, logits, tokens, seen, num_new, spec_len, live, rng,
-            temperature, top_k, top_p, rep_penalty, eos_id, max_draft,
+            sample_window, logits, tokens, rows, seen, spec_len, live, rng,
+            temperature, top_k, top_p, rep_penalty, eos_id,
         )
-        if moe:
-            return caches, seen, out_tok, n_emit, new_rng, moe_stats
-        return caches, seen, out_tok, n_emit, new_rng
+        return (caches, seen, out_tok, n_emit, new_rng, *moe_stats)
 
     return step
 
@@ -481,43 +504,16 @@ def make_paged_step_fn(cfg, dtype, vocab: int, cache_shardings=None,
     GATHERS (per-slot views) through the tables, so every arrival/
     sharing/divergence mix runs the same compiled program — zero
     recompiles after warmup."""
-    sample_one = _make_sample_one(vocab)
-    moe = bool(getattr(cfg, "is_moe", False))
+    slot_step = make_step_fn(cfg, dtype, vocab, cache_shardings, max_draft)
 
     def step(params, caches, seen, tokens, num_new, start_pos, page_table,
-             cow_src, fresh, sample_flag, spec_len, eos_id, rng, temperature,
-             top_k, top_p, rep_penalty, page_table_win=None):
-        live = sample_flag & (num_new > 0)
-        seen = _book_seen(seen, tokens, num_new, spec_len, fresh, vocab)
+             cow_src, *rest, page_table_win=None):
         if page_table_win is None:
             # (a model with window layers shares no page: nothing to copy)
             caches = paged_cow_copy(caches, page_table, start_pos, cow_src)
-        token_valid = (
-            jnp.arange(tokens.shape[1])[None, :] < num_new[:, None]
-            if moe else None
-        )
-        fw = forward_with_cache(
-            cfg, params, tokens, caches, start_pos, dtype=dtype,
-            page_table=page_table, page_table_win=page_table_win,
-            num_new=num_new,
-            token_valid=token_valid, return_moe_stats=moe,
-        )
-        if moe:
-            logits, caches, moe_stats = fw
-        else:
-            logits, caches = fw
-        if cache_shardings is not None:
-            # keep the donated pool carry sharding-closed across steps
-            caches = jax.lax.with_sharding_constraint(
-                caches, cache_shardings
-            )
-        out_tok, n_emit, new_rng = verify_window(
-            sample_one, logits, tokens, seen, num_new, spec_len, live, rng,
-            temperature, top_k, top_p, rep_penalty, eos_id, max_draft,
-        )
-        if moe:
-            return caches, seen, out_tok, n_emit, new_rng, moe_stats
-        return caches, seen, out_tok, n_emit, new_rng
+        return slot_step(params, caches, seen, tokens, num_new, start_pos,
+                         *rest, page_table=page_table,
+                         page_table_win=page_table_win)
 
     if cfg.has_window:
         # pages by layer kind: the window layers' table rides beside the
@@ -730,6 +726,9 @@ class ServingEngine:
         self.metrics = metrics or ServingMetrics(clock=clock)
         self.metrics.configure(N, num_pages=self.num_pages or 0,
                                host_pages=self.host_pages)
+        # rows a step norms and projects to the vocabulary: each slot's
+        # verify window, not its chunk
+        self.metrics.head_rows_per_step = N * (self.max_draft + 1)
         if self.tiered:
             from .paging import HostPageStore, PageSpiller, export_pages
 
@@ -1120,7 +1119,11 @@ class ServingEngine:
         finished = self.scheduler.complete(
             plan, out_tok, new_rng, n_emit=n_emit,
         )
-        self.metrics.on_step()
+        # did the step pay for the sampler's sorts: the step's own predicate,
+        # from the vectors the host filled for it
+        self.metrics.on_step(filtered=bool(np.any(
+            plan.sample & (plan.num_new > 0)
+            & ((top_k > 0) | (top_p < 1.0)))))
         if moe_stats is not None:
             # expert load-balance counters (ISSUE 14 satellite): the step
             # already computed them on device

@@ -129,7 +129,11 @@ class ServingMetrics:
         #   (valid token-expert assignments that overflowed capacity)
         self.moe_a2a_bytes = 0        # cumulative expert-exchange wire
         #   bytes (the analytic moe_decode_a2a stream; 0 without ep)
+        self.filter_steps = 0         # steps in which a live slot asked for
+        #   top-k or top-p, so the sampler's sorts ran (of ``steps``)
         # gauges (last observed)
+        self.head_rows_per_step = 0   # rows a step projects to the
+        #   vocabulary: max_slots x (max_draft + 1), set once an engine
         self.attention_paged_kernel = 0.0  # 1 when the compiled step's
         #   attention is the paged Pallas kernel (ServingEngine
         #   .attention_path; 0 = the dense XLA lines or not compiled yet)
@@ -363,8 +367,9 @@ class ServingMetrics:
         self._num_pages = max(int(num_pages), 0)
         self._host_pages = max(int(host_pages), 0)
 
-    def on_step(self) -> None:
+    def on_step(self, filtered: bool = False) -> None:
         self.steps += 1
+        self.filter_steps += bool(filtered)
 
     def on_keys(self, kind: str, attended: int, fetched: int) -> None:
         """One step's attention work in one layer of ``kind``."""
@@ -413,6 +418,8 @@ class ServingMetrics:
             "mean_accepted_tokens_per_step":
                 self.mean_accepted_tokens_per_step,
             "attention_paged_kernel": self.attention_paged_kernel,
+            "head_rows_per_step": self.head_rows_per_step,
+            "filter_steps": self.filter_steps,
         }
         for kind in self.attended_keys:
             snap[f"attended_keys_{kind}"] = self.attended_keys[kind]

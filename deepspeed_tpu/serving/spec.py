@@ -159,9 +159,8 @@ def advance_rng(key, flag):
             jnp.where(use, pair[1], key))
 
 
-def verify_window(sample_one, logits, tokens, seen, num_new, spec_len, live,
-                  rng, temperature, top_k, top_p, rep_penalty, eos_id,
-                  max_draft: int):
+def verify_window(sample_window, logits, tokens, rows, seen, spec_len, live,
+                  rng, temperature, top_k, top_p, rep_penalty, eos_id):
     """Batched-ragged verification inside the ONE jitted serving step.
 
     Every live slot's row ends with a verify window: its committed token
@@ -175,22 +174,24 @@ def verify_window(sample_one, logits, tokens, seen, num_new, spec_len, live,
     chain to the state after exactly ``n_emit`` advances.
 
     Shapes (N = max_slots, W = token_budget, Kw = max_draft + 1):
-      logits [N, W, V], tokens [N, W], seen [N, V],
-      num_new/spec_len/eos_id [N] i32, live [N] bool, rng [N, 2] u32,
+      logits [N, Kw, V] — the window's rows alone, as the forward computed
+      them for ``rows`` [N, Kw] (models/decoding.verify_window_rows),
+      tokens [N, W], seen [N, V],
+      spec_len/eos_id [N] i32, live [N] bool, rng [N, 2] u32,
       temperature/top_p/rep_penalty [N] f32, top_k [N] i32.
+    ``sample_window(win [N, Kw, V], keys [N, Kw, 2], live, temperature,
+    top_k, top_p) -> [N, Kw]`` is the engine's sampler.
 
     Returns ``(out_tokens [N, Kw] i32, n_emit [N] i32, new_rng [N, 2])``
     — ``out_tokens[:, :n_emit]`` are the slot's emitted tokens this
-    step; ``n_emit`` is 0 for non-sampling rows. ``max_draft`` is STATIC
-    (the step's fixed output shape); ``spec_len`` is traced, so any
+    step; ``n_emit`` is 0 for non-sampling rows. Kw is STATIC (the
+    step's fixed output shape); ``spec_len`` is traced, so any
     per-slot/per-step draft count runs the same compiled program.
     """
     from ..inference.engine import apply_repetition_penalty
-    from ..models.decoding import gather_verify_window
 
-    N, W = tokens.shape
-    kw = max_draft + 1
-    win = gather_verify_window(logits, num_new, spec_len, max_draft)
+    kw = rows.shape[1]
+    max_draft = kw - 1
     # repetition penalty over the whole window with the pre-forward seen
     # matrix. Spec rows are penalty == 1.0 by the scheduler gate (the
     # seen matrix is built from FED tokens and spec-accepted tokens are
@@ -198,7 +199,7 @@ def verify_window(sample_one, logits, tokens, seen, num_new, spec_len, live,
     # penalty math is bitwise identity there; spec_len == 0 rows take
     # exactly the pre-spec single-position path.
     win = apply_repetition_penalty(
-        win, seen, rep_penalty[:, None, None], active=live
+        logits, seen, rep_penalty[:, None, None], active=live
     )
     # the RNG chain, advanced kw times (live rows only): chains[j] is the
     # state after j advances, keys[j] the sample key position j uses.
@@ -206,22 +207,17 @@ def verify_window(sample_one, logits, tokens, seen, num_new, spec_len, live,
     # keys past the emitted run are never consumed — the next step's
     # first sample reuses exactly the key spec-off would.
     chains = [rng]
-    targets = []
-    for j in range(kw):
+    keys = []
+    for _ in range(kw):
         key_j, nxt = jax.vmap(advance_rng)(chains[-1], live)
         chains.append(nxt)
-        targets.append(jax.vmap(sample_one)(
-            win[:, j], key_j, temperature, top_k, top_p
-        ))
-    out_tokens = jnp.stack(targets, axis=1).astype(jnp.int32)  # [N, kw]
+        keys.append(key_j)
+    out_tokens = sample_window(
+        win, jnp.stack(keys, axis=1), live, temperature, top_k, top_p
+    ).astype(jnp.int32)  # [N, kw]
     # drafts ride in the row right after the committed token: window
-    # position j's draft is tokens[base + 1 + j]
-    base = num_new - 1 - spec_len
-    draft_idx = jnp.clip(
-        base[:, None] + 1 + jnp.arange(max_draft, dtype=jnp.int32)[None, :],
-        0, W - 1,
-    )
-    drafts = jnp.take_along_axis(tokens, draft_idx, axis=1)  # [N, max_draft]
+    # position j's draft is the token at the window's row j + 1
+    drafts = jnp.take_along_axis(tokens, rows[:, 1:], axis=1)
     in_window = jnp.arange(max_draft)[None, :] < spec_len[:, None]
     match = (drafts == out_tokens[:, :max_draft]) & in_window
     n_acc = longest_accepted_prefix(match)

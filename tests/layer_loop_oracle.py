@@ -1,7 +1,8 @@
 """The oracle of ``forward_with_cache``'s scan: the same layers as a plain
 Python loop, every layer on a cache of its own (a stack of one, written at
 index 0), so neither a carry nor an index inside a pool exists to get
-wrong. Shared by tests/test_inference.py and tests/test_mellum.py."""
+wrong. Shared by tests/test_inference.py, tests/test_mellum.py and
+tests/test_serving_tail.py."""
 
 import functools
 
@@ -92,6 +93,19 @@ def random_cache(cache, seed=0):
         else:
             out[n] = jnp.asarray(r.normal(size=a.shape), a.dtype)
     return out
+
+
+def paged_setup(cfg, B, ps, mp, quantized, seed):
+    """A shuffled table over B x mp pages (unmapped tails on the NULL
+    page) and a pool stack filled with noise."""
+    pages = B * mp
+    table = np.random.default_rng(seed).permutation(pages).reshape(B, mp)
+    table[:, -1] = pages  # the NULL page
+    cache = random_cache(
+        decoding.init_paged_cache(cfg, pages, ps, jnp.float32,
+                                  quantized=quantized),
+        seed)
+    return cache, jnp.asarray(table, jnp.int32)
 
 
 def assert_bitwise(got, want, atol=0.0):

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from layer_loop_oracle import (assert_bitwise, layer_loop_forward,
-                               random_cache)
+                               paged_setup, random_cache)
 
 from deepspeed_tpu import init_inference
 from deepspeed_tpu.comm.topology import MeshTopology, ParallelDims
@@ -899,18 +899,6 @@ def test_speculative_verify_window_streams_with_configured_threshold(
 
 
 # ---------------------------------------------- the cache rides the scan
-def _paged_setup(cfg, B, ps, mp, quantized, seed):
-    """A shuffled table over B x mp pages (unmapped tails on the NULL
-    page) and a pool stack filled with noise."""
-    pages = B * mp
-    table = np.random.default_rng(seed).permutation(pages).reshape(B, mp)
-    table[:, -1] = pages  # the NULL page
-    cache = random_cache(
-        init_paged_cache(cfg, pages, ps, jnp.float32, quantized=quantized),
-        seed)
-    return cache, jnp.asarray(table, jnp.int32)
-
-
 @pytest.mark.parametrize(
     "family,layout,quantized,impl",
     [
@@ -952,7 +940,7 @@ def test_carried_cache_is_bitwise_the_layer_loop(family, layout, quantized,
     ids = np.random.RandomState(2).randint(0, 128, size=(2, B, S))
     kw = {}
     if layout == "paged":
-        cache, table = _paged_setup(cfg, B, ps, mp, quantized, seed=3)
+        cache, table = paged_setup(cfg, B, ps, mp, quantized, seed=3)
         kw = dict(page_table=table)
     else:
         cache = random_cache(
@@ -982,7 +970,7 @@ def test_donated_caches_are_consumed_by_the_step():
     model = tiny_llama(num_layers=3)
     cfg = model.config
     params = model.init(jax.random.PRNGKey(1), dtype=jnp.float32)
-    cache, table = _paged_setup(cfg, 2, 4, 8, False, seed=0)
+    cache, table = paged_setup(cfg, 2, 4, 8, False, seed=0)
     step = jax.jit(
         lambda c, ids, cl: forward_with_cache(
             cfg, params, ids, c, cl, dtype=jnp.float32, page_table=table),

@@ -187,6 +187,10 @@ GIB = 2.0 ** 30
 # on PR 30's tree): each held a sliced layer and a rebuilt stack of every
 # pool
 PARENT_TEMP_GIB = {"mixtral": 1.32, "mellum": 1.89}
+# and while the head still ran over every row of the chunk (the same
+# tests on PR 34's tree): each held the float32 [N, W, V] logits
+WHOLE_CHUNK_TEMP_BYTES = {"mixtral": 298311680, "mellum": 818951680,
+                          "deepseek": 340044288}
 
 
 def _pool_copies(text, caches):
@@ -221,6 +225,26 @@ def _pool_copies(text, caches):
         if op in moves:
             found.append(f"{name}: {op} -> [{dims}]")
     return found
+
+
+def _check_head_runs_over_the_window(compiled, N, W, V, family, capsys):
+    """The tail computes what the sampler reads: no float32 array of the
+    chunk's [N, W, V] logits in any shape, and the temporaries fell (by
+    that buffer where it stood at their peak: Mixtral's and Mellum's; the
+    peak of DeepSeek's is inside its attention)."""
+    chunk_logits = N * W * V * 4
+    m = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"{family} slot step, head over {N} rows of {N * W}: "
+              f"temporaries {m.temp_size_in_bytes / 1e6:.1f} MB (with the "
+              f"whole chunk's logits "
+              f"{WHOLE_CHUNK_TEMP_BYTES[family] / 1e6:.1f}, of which they "
+              f"were {chunk_logits / 1e6:.1f})")
+    held = [dims for dims in set(
+        re.findall(r"\bf32\[([\d,]+)\]", compiled.as_text()))
+        if np.prod([int(d) for d in dims.split(",")]) == N * W * V]
+    assert held == []
+    assert m.temp_size_in_bytes < WHOLE_CHUNK_TEMP_BYTES[family]
 
 
 def _compile_slot_step(model, caches, one_chip, N, W, mp):
@@ -291,6 +315,8 @@ def test_mixtral_slot_step_keeps_its_pools_in_place(one_chip, monkeypatch,
     compiled = _compile_slot_step(model, caches, one_chip, N, W,
                                   -(-(cap + W) // ps))
     _check_caches_stay_in_place(compiled, caches, "mixtral", capsys)
+    _check_head_runs_over_the_window(compiled, N, W, model.config.vocab_size,
+                                     "mixtral", capsys)
     assert "paged_attention" in compiled.as_text()
 
 
@@ -314,6 +340,8 @@ def test_mellum_slot_step_compiles_beside_its_arena(one_chip, monkeypatch,
         model.config, N * mp, ps, BF16, window_pages=592))
     compiled = _compile_slot_step(model, caches, one_chip, N, W, mp)
     m = _check_caches_stay_in_place(compiled, caches, "mellum", capsys)
+    _check_head_runs_over_the_window(compiled, N, W, model.config.vocab_size,
+                                     "mellum", capsys)
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * GIB
     text = compiled.as_text()
     assert "paged_attention_window" in text and "paged_attention_full" in text
@@ -350,6 +378,8 @@ def test_deepseek_slot_step_keeps_both_pools_in_place(one_chip, monkeypatch,
               f"{m.temp_size_in_bytes / GIB:.2f} GiB, aliased "
               f"{m.alias_size_in_bytes / GIB:.2f} (the pools "
               f"{pools / GIB:.2f})")
+    _check_head_runs_over_the_window(compiled, N, W, model.config.vocab_size,
+                                     "deepseek", capsys)
     text = compiled.as_text()
     assert _pool_copies(text, caches) == []
     assert m.alias_size_in_bytes >= pools
