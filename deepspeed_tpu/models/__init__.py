@@ -10,6 +10,7 @@ from .bloom import bloom, bloom_config  # noqa: F401
 from .mixtral import mixtral, mixtral_config  # noqa: F401
 from .mellum import mellum, mellum_config  # noqa: F401
 from .deepseek import deepseek, deepseek_config  # noqa: F401
+from .glm import glm, glm_config  # noqa: F401
 
 MODEL_REGISTRY = {
     "gpt2": gpt2,
@@ -18,6 +19,7 @@ MODEL_REGISTRY = {
     "mixtral": mixtral,
     "mellum": mellum,
     "deepseek": deepseek,
+    "glm": glm,
 }
 
 

@@ -26,8 +26,8 @@ from .transformer import (
     TransformerConfig,
     _mlp,
     _norm,
+    _latent_projections,
     _qk_norm,
-    _rms_last,
     _rope,
     alibi_slopes,
     lm_head_logits,
@@ -565,11 +565,7 @@ def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
     H, kl, rd = cfg.num_heads, cfg.kv_latent_dim, cfg.qk_rope_dim
     nope, vd, eps = cfg.qk_nope_dim, cfg.v_head_dim, cfg.norm_eps
     table = cfg.rope_of("full")
-    c_q = _rms_last(x @ p["wq_a"], p["q_norm"]["scale"], eps)
-    q = (c_q @ p["wq_b"]).reshape(B, S, H, nope + rd)
-    kv_a = x @ p["wkv_a"]
-    c_kv = _rms_last(kv_a[..., :kl], p["kv_norm"]["scale"], eps)
-    q_pe, k_pe = _rope(q[..., nope:], kv_a[:, :, None, kl:], positions, table)
+    c_q, q_nope, q_pe, c_kv, k_pe = _latent_projections(cfg, p, x, positions)
     pad = latent_row_width(cfg) - cfg.latent_width
 
     def row(latent, pe):  # [..., kl] + [..., rd] -> a row of the pool's width
@@ -584,7 +580,7 @@ def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
     # the key half of wkv_b absorbed into the query, the value half applied
     # to the attended latents: [kl, H, nope | vd]
     wkv_b = p["wkv_b"].reshape(kl, H, nope + vd)
-    q_abs = row(jnp.einsum("bshn,chn->bshc", q[..., :nope], wkv_b[..., :nope]),
+    q_abs = row(jnp.einsum("bshn,chn->bshc", q_nope, wkv_b[..., :nope]),
                 q_pe)
     scale = cfg.hd ** -0.5 * cfg.attn_scale_mult
 

@@ -147,6 +147,18 @@ class TransformerConfig:
     # ``lead_dense_ffn``, in a stack of their own (``lead_layers``).
     lead_dense_layers: int = 0
     lead_dense_ffn: int = 0
+    # The selection bias of the sigmoid_groups router is no parameter: it
+    # takes no gradient, and after each step the engine moves every entry by
+    # ``moe_bias_update_rate`` towards balance, by the sign of the mean
+    # count less the expert's own count over the step (``noaux_tc``).
+    moe_bias_update_rate: float = 0.0
+    # Multi-token prediction: ``mtp_layers`` (0 or 1) module after the main
+    # stack, one more block of the main stack's kind over
+    # ``[norm(embed(t[i+1])) ; norm(h[i])] W_eh``, its own final norm, the
+    # SHARED embedding and head; it predicts t[i+2] and its cross-entropy
+    # joins the loss times ``mtp_loss_weight``.
+    mtp_layers: int = 0
+    mtp_loss_weight: float = 0.3
     name: str = "transformer"
 
     def __post_init__(self):
@@ -174,6 +186,16 @@ class TransformerConfig:
             raise ValueError(
                 "latent attention: head_dim is qk_nope_dim + qk_rope_dim, "
                 "num_kv_heads 1 (one latent a token), one layer kind")
+        if self.mtp_layers not in (0, 1):
+            raise ValueError(
+                f"mtp_layers {self.mtp_layers}: one module (it predicts the "
+                "token after next) or none")
+        if self.moe_gate == "softmax" and self.moe_routed_experts not in (
+                0, self.num_experts):
+            raise ValueError(
+                "one member's share of an expert-parallel layer "
+                "(moe_routed_experts) is computed under the sigmoid_groups "
+                "router alone")
         if self.routed_experts % self.moe_groups or not (
                 0 <= self.moe_first_expert
                 <= self.routed_experts - self.num_experts):
@@ -273,7 +295,10 @@ class TransformerConfig:
         if self.embed_norm:
             embed += ln_width
         head = 0 if self.tie_embeddings else v * d
-        return L * per_layer + lead + embed + head + ln_width
+        # an MTP module: a block of the main stack's kind, eh_proj, the two
+        # norms before it and its own final norm
+        mtp = self.mtp_layers * (per_layer + 2 * d * d + 3 * ln_width)
+        return L * per_layer + lead + embed + head + ln_width + mtp
 
 
 def _latent_attn_params(cfg: "TransformerConfig", nrm, lk, L: int,
@@ -361,39 +386,41 @@ def init(cfg: TransformerConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
             mlp["bo"] = jnp.zeros((L, d), dtype)
         return mlp
 
-    lk = jax.random.split(keys[3], 12)
-    L = cfg.num_layers
-    if cfg.is_moe:
-        E = cfg.num_experts
-        mlp = {
-            "router": nrm(lk[4], L, d, cfg.routed_experts),
-            "wi": nrm(lk[5], L, E, d, f),
-            "wo": nrm(lk[6], L, E, f, d, scale=out_scale),
-        }
-        if cfg.activation == "swiglu":
-            mlp["wg"] = nrm(lk[7], L, E, d, f)
-        if cfg.moe_use_residual:
-            mlp["res_wi"] = nrm(lk[8], L, d, f)
-            mlp["res_wo"] = nrm(lk[9], L, f, d, scale=out_scale)
+    def main_stack(lk, L):
+        """``L`` layers of the main stack's kind, stacked."""
+        if cfg.is_moe:
+            E = cfg.num_experts
+            mlp = {
+                "router": nrm(lk[4], L, d, cfg.routed_experts),
+                "wi": nrm(lk[5], L, E, d, f),
+                "wo": nrm(lk[6], L, E, f, d, scale=out_scale),
+            }
             if cfg.activation == "swiglu":
-                mlp["res_wg"] = nrm(lk[10], L, d, f)
-            mlp["coef"] = nrm(lk[11], L, d, 2)
-        if cfg.moe_gate == "sigmoid_groups":
-            # the selection bias: added to the scores to choose, never to
-            # weigh ("sel_": a leaf named b... is a bias drawn as zero)
-            mlp["sel_bias"] = nrm(lk[8], L, cfg.routed_experts)
-        if cfg.moe_shared_width:
-            sk = jax.random.split(lk[9], 8)
-            mlp["shared"] = dense_mlp(sk, L, cfg.moe_shared_width)
-    else:
-        mlp = dense_mlp(lk, L, f)
+                mlp["wg"] = nrm(lk[7], L, E, d, f)
+            if cfg.moe_use_residual:
+                mlp["res_wi"] = nrm(lk[8], L, d, f)
+                mlp["res_wo"] = nrm(lk[9], L, f, d, scale=out_scale)
+                if cfg.activation == "swiglu":
+                    mlp["res_wg"] = nrm(lk[10], L, d, f)
+                mlp["coef"] = nrm(lk[11], L, d, 2)
+            if cfg.moe_gate == "sigmoid_groups":
+                # the selection bias: added to the scores to choose, never to
+                # weigh ("sel_": a leaf named b... is a bias drawn as zero)
+                mlp["sel_bias"] = nrm(lk[8], L, cfg.routed_experts)
+            if cfg.moe_shared_width:
+                sk = jax.random.split(lk[9], 8)
+                mlp["shared"] = dense_mlp(sk, L, cfg.moe_shared_width)
+        else:
+            mlp = dense_mlp(lk, L, f)
+        return {
+            "ln1": norm_params(ln_bias, (L,)),
+            "ln2": norm_params(ln_bias, (L,)),
+            "attn": attn_params(lk, L),
+            "mlp": mlp,
+        }
 
-    params["layers"] = {
-        "ln1": norm_params(ln_bias, (L,)),
-        "ln2": norm_params(ln_bias, (L,)),
-        "attn": attn_params(lk, L),
-        "mlp": mlp,
-    }
+    params["layers"] = main_stack(jax.random.split(keys[3], 12),
+                                  cfg.num_layers)
     if cfg.lead_dense_layers:
         dk = jax.random.split(keys[4], 12)
         Ld = cfg.lead_dense_layers
@@ -402,6 +429,16 @@ def init(cfg: TransformerConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
             "ln2": norm_params(ln_bias, (Ld,)),
             "attn": attn_params(dk, Ld),
             "mlp": dense_mlp(dk, Ld, cfg.lead_dense_ffn),
+        }
+    if cfg.mtp_layers:
+        # the embedding and the head are the main model's: no leaf here
+        params["mtp"] = {
+            "enorm": norm_params(ln_bias),
+            "hnorm": norm_params(ln_bias),
+            "eh_proj": nrm(keys[5], 2 * d, d),
+            "layers": main_stack(jax.random.split(keys[6], 12),
+                                 cfg.mtp_layers),
+            "final_norm": norm_params(ln_bias),
         }
     return params
 
@@ -477,9 +514,66 @@ def alibi_slopes(num_heads: int) -> np.ndarray:
     return np.asarray(slopes, dtype=np.float32)
 
 
+def _latent_projections(cfg: TransformerConfig, p: Params, x: jax.Array,
+                        positions: jax.Array):
+    """What both forms of latent attention (this file's training form, the
+    cached one of models/decoding.py) start from: x [B,S,d] -> (the query
+    latent c_q [B,S,ql], q_nope [B,S,H,nope], q_pe [B,S,H,rd] rotated, the
+    normed kv latent c_kv [B,S,kl], ONE rotated key k_pe [B,S,1,rd])."""
+    B, S, _ = x.shape
+    kl, nope, eps = cfg.kv_latent_dim, cfg.qk_nope_dim, cfg.norm_eps
+    c_q = _rms_last(x @ p["wq_a"], p["q_norm"]["scale"], eps)
+    q = (c_q @ p["wq_b"]).reshape(B, S, cfg.num_heads, nope + cfg.qk_rope_dim)
+    kv_a = x @ p["wkv_a"]
+    c_kv = _rms_last(kv_a[..., :kl], p["kv_norm"]["scale"], eps)
+    q_pe, k_pe = _rope(q[..., nope:], kv_a[:, :, None, kl:], positions,
+                       cfg.rope_of("full"))
+    return c_q, q[..., :nope], q_pe, c_kv, k_pe
+
+
+def _latent_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
+                      positions: jax.Array,
+                      segment_ids: Optional[jax.Array]) -> jax.Array:
+    """Latent attention in its training form: every head's keys and values
+    are up-projected from the token's latent and attended as plain heads (no
+    cache, nothing absorbed), so the registered attention op (the flash
+    kernels on the chip) takes them. Queries, keys and values are padded
+    with zeros to the widest of the qk and the value widths, which changes
+    neither a score nor an output; the op's own ``width ** -0.5`` is set
+    right on the queries."""
+    from ..ops.attention import attention as attn_op
+
+    B, S, _ = x.shape
+    H, rd = cfg.num_heads, cfg.qk_rope_dim
+    nope, vd = cfg.qk_nope_dim, cfg.v_head_dim
+    _, q_nope, q_pe, c_kv, k_pe = _latent_projections(cfg, p, x, positions)
+    kv = (c_kv @ p["wkv_b"]).reshape(B, S, H, nope + vd)
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_pe, (B, S, H, rd))], axis=-1)
+    v = kv[..., nope:]
+    width = max(cfg.hd, vd)
+    mult = cfg.attn_scale_mult * (width / cfg.hd) ** 0.5
+    if mult != 1.0:
+        q = q * jnp.asarray(mult, q.dtype)
+
+    def widen(t):
+        gap = width - t.shape[-1]
+        return jnp.pad(t, ((0, 0),) * 3 + ((0, gap),)) if gap else t
+
+    q, k, v = (constrain(widen(t), ("dp", "fsdp"), "sp", None, None)
+               for t in (q, k, v))
+    out = attn_op(q, k, v, causal=True, bias=None, segment_ids=segment_ids,
+                  alibi_slopes=None)[..., :vd]
+    return out.reshape(B, S, H * vd) @ p["wo"]
+
+
 def _attention(cfg: TransformerConfig, p: Params, x: jax.Array, positions: jax.Array,
                segment_ids: Optional[jax.Array],
                pos_default: bool = True, kind: str = "full") -> jax.Array:
+    if cfg.is_latent:
+        with jax.named_scope("latent_attention"):
+            return _latent_attention(cfg, p, x, positions, segment_ids)
     from ..ops.attention import attention as attn_op
     from ..parallel.tensor_overlap import tp_in_proj, tp_out_proj
 
@@ -581,7 +675,10 @@ def _mlp(cfg: TransformerConfig, p: Params, x: jax.Array, rng: Optional[jax.Arra
 
 def _block(cfg: TransformerConfig, layer: Params, x: jax.Array, positions: jax.Array,
            segment_ids: Optional[jax.Array], rng: Optional[jax.Array], train: bool,
-           pos_default: bool = True, kind: str = "full"):
+           pos_default: bool = True, kind: str = "full", dense: bool = False):
+    """One block -> (x, aux loss, routing stats). ``dense``: a leading dense
+    layer of a routed model. The stats are those of the sigmoid_groups
+    router (moe/sharded_moe.moe_held_layer), None for any other MLP."""
     from jax.ad_checkpoint import checkpoint_name
 
     from ..parallel.tensor_overlap import seq_shard_axes
@@ -596,11 +693,22 @@ def _block(cfg: TransformerConfig, layer: Params, x: jax.Array, positions: jax.A
     h = checkpoint_name(h, "attn_out")  # selective remat anchor (attn_only)
     x = x + h
     x = constrain(x, ("dp", "fsdp"), seq_ax, None)
-    m, aux = _mlp(cfg, layer["mlp"], _norm(cfg, layer["ln2"], x), rng, train)
+    normed = _norm(cfg, layer["ln2"], x)
+    stats = None
+    if cfg.is_moe and not dense and cfg.moe_gate == "sigmoid_groups":
+        from ..moe.sharded_moe import moe_held_layer
+
+        m, stats = moe_held_layer(cfg, layer["mlp"], normed)
+        aux = jnp.zeros((), jnp.float32)
+    else:
+        m, aux = _mlp(cfg, layer["mlp"], normed, rng, train, dense)
+    if cfg.moe_shared_width and not dense:
+        # the shared expert: a dense MLP every token takes, beside the routed
+        m = m + _mlp(cfg, layer["mlp"]["shared"], normed, None, train, True)[0]
     m = checkpoint_name(m, "mlp_out")
     x = x + m
     x = constrain(x, ("dp", "fsdp"), seq_ax, None)
-    return x, aux
+    return x, aux, stats
 
 
 def apply_layer_stack(cfg: TransformerConfig, layers: Params, x: jax.Array,
@@ -608,8 +716,14 @@ def apply_layer_stack(cfg: TransformerConfig, layers: Params, x: jax.Array,
                       remat_policy: Optional[str] = None, pld_keep=None,
                       ltd_keep: Optional[int] = None,
                       ltd_layers: Optional[Tuple[int, int]] = None,
-                      pos_default: bool = True):
+                      pos_default: bool = True, dense: bool = False,
+                      with_stats: bool = False):
     """Scan the stacked layer params over the sequence of blocks.
+
+    ``dense``: the stack is a routed model's leading dense layers
+    (``lead_layers``: a stack of its own before the main one). With
+    ``with_stats`` the result gains the routing stats of the sigmoid_groups
+    router, one row a layer (None for any other model).
 
     pld_keep: optional [L] per-layer keep probabilities (progressive layer
     dropping) — a dropped layer passes its input through unchanged.
@@ -655,6 +769,7 @@ def apply_layer_stack(cfg: TransformerConfig, layers: Params, x: jax.Array,
 
     def body(carry, inp, *, ltd: bool = False):
         x, aux = carry
+        stats = []
         for j, kind in enumerate(pattern):
             layer = jax.tree.map(lambda t: member(t, j), inp[0])
             key = member(inp[1], j)
@@ -679,21 +794,25 @@ def apply_layer_stack(cfg: TransformerConfig, layers: Params, x: jax.Array,
                 # gathered positions are no longer sequence indices:
                 # pos_default False routes ALiBi through the exact
                 # positions-derived bias
-                out_kept, a = _block(
+                out_kept, a, st = _block(
                     cfg, layer, x_kept, pos_kept, seg_kept, key, train,
-                    pos_default=False, kind=kind,
+                    pos_default=False, kind=kind, dense=dense,
                 )
                 out = scatter_tokens(x, out_kept, idx)
             else:
-                out, a = _block(cfg, layer, x, positions, segment_ids, key,
-                                train, pos_default=pos_default, kind=kind)
+                out, a, st = _block(cfg, layer, x, positions, segment_ids,
+                                    key, train, pos_default=pos_default,
+                                    kind=kind, dense=dense)
+            stats.append(st)
             if use_pld:
                 keep = jax.random.bernoulli(
                     jax.random.fold_in(key, 7), member(inp[2], j))
                 out = jnp.where(keep, out, x)
                 a = jnp.where(keep, a, 0.0)
             x, aux = out, aux + a
-        return (x, aux), None
+        # the routing stats leave the scan as its ys: [trips, period, ...]
+        return (x, aux), None if stats[0] is None else jax.tree.map(
+            lambda *t: jnp.stack(t), *stats)
 
     import functools
 
@@ -755,16 +874,25 @@ def apply_layer_stack(cfg: TransformerConfig, layers: Params, x: jax.Array,
             raise ValueError(
                 f"random_ltd layer range {ltd_layers} outside [0, {num_layers})"
             )
+        parts = []
         if lo > 0:
-            carry, _ = seg_scan(full_body, carry, 0, lo)
-        carry, _ = seg_scan(ltd_body, carry, lo, hi)
+            carry, st = seg_scan(full_body, carry, 0, lo)
+            parts.append(st)
+        carry, st = seg_scan(ltd_body, carry, lo, hi)
+        parts.append(st)
         if hi < num_layers:
-            carry, _ = seg_scan(full_body, carry, hi, num_layers)
-        x, aux = carry
+            carry, st = seg_scan(full_body, carry, hi, num_layers)
+            parts.append(st)
+        stats = None if parts[0] is None else jax.tree.map(
+            lambda *a: jnp.concatenate(a), *parts)
+    else:
+        carry, stats = seg_scan(full_body, carry, 0, num_layers)
+    x, aux = carry
+    if not with_stats:
         return x, aux
-
-    (x, aux), _ = seg_scan(full_body, carry, 0, num_layers)
-    return x, aux
+    if stats is not None:  # one row a layer
+        stats = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), stats)
+    return x, aux, stats
 
 
 # -----------------------------------------------------------------------------
@@ -834,22 +962,55 @@ def masked_ce(logits: jax.Array, labels: jax.Array, num_mb_dims: int = 0):
 def _refuse_uncached(cfg: TransformerConfig) -> None:
     """What only the paged serving step computes (models/decoding.py) is
     refused by the uncached forward, which training and ``forward`` use."""
-    why = [what for cond, what in (
-        (cfg.is_latent, "latent attention (kv_latent_dim): its keys exist "
-         "as cached latents alone"),
-        (cfg.lead_dense_layers, "leading dense layers (lead_dense_layers)"),
-        (cfg.moe_gate != "softmax", f"the {cfg.moe_gate} router"),
-        (cfg.moe_shared_width, "a shared expert (moe_shared_width)"),
-        (cfg.routed_experts != cfg.num_experts, "one member's share of an "
-         "expert-parallel layer (moe_routed_experts)"),
-    ) if cond]
-    if why:
+    if cfg.index_topk:
         from ..config import DeepSpeedConfigError
 
         raise DeepSpeedConfigError(
-            "the uncached forward (training, evaluation, generate) does not "
-            "compute " + "; ".join(why) + ": serve this configuration "
-            "through init_serving with serving.paged")
+            "the uncached forward (training, evaluation, forward) does not "
+            "compute the indexer's selection (index_topk): it is made from "
+            "cached index keys alone; serve this configuration through "
+            "init_serving with serving.paged")
+
+
+def routing_stats_summary(stats) -> Dict[str, jax.Array]:
+    """The step's model metrics from the sigmoid_groups router's stats
+    (``counts`` [layers, routed experts], ``held`` [layers, experts held]):
+    the rows routed to held experts a layer, and the fullest held expert
+    over their mean."""
+    held = stats["held"]
+    return {
+        "moe_rows_held": jnp.mean(jnp.sum(held, axis=-1)),
+        "moe_rows_max_over_mean": jnp.mean(
+            jnp.max(held, axis=-1) / jnp.maximum(jnp.mean(held, axis=-1),
+                                                 1e-9)),
+    }
+
+
+def _hidden(cfg: TransformerConfig, params: Params, input_ids: jax.Array, *,
+            dtype, train: bool, rng, positions, segment_ids, remat_policy,
+            pld_keep, ltd_keep, ltd_layers):
+    """Embedding and both stacks -> (hidden before the final norm [B,S,d],
+    aux loss, routing stats one row a routed layer or None, positions)."""
+    B, S = input_ids.shape
+    _refuse_uncached(cfg)
+    pos_default = positions is None
+    if positions is None:
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    from ..ops.quantizer import cast_floating
+
+    x = embed_tokens(cfg, params, input_ids, positions, dtype)
+    if cfg.lead_dense_layers:
+        lead_rng = None if rng is None else jax.random.fold_in(rng, 1)
+        x, _ = apply_layer_stack(
+            cfg, cast_floating(params["lead_layers"], dtype), x, positions,
+            segment_ids, lead_rng, train, remat_policy,
+            pos_default=pos_default, dense=True)
+    x, aux, stats = apply_layer_stack(
+        cfg, cast_floating(params["layers"], dtype), x, positions,
+        segment_ids, rng, train, remat_policy, pld_keep, ltd_keep,
+        ltd_layers, pos_default, with_stats=True,
+    )
+    return x, aux, stats, positions
 
 
 def apply(cfg: TransformerConfig, params: Params, input_ids: jax.Array, *,
@@ -861,24 +1022,49 @@ def apply(cfg: TransformerConfig, params: Params, input_ids: jax.Array, *,
           return_hidden: bool = False) -> Tuple[jax.Array, jax.Array]:
     """Forward pass → (logits fp32 [B,S,V], moe_aux_loss); with
     ``return_hidden`` the final normed hidden [B,S,d] instead of logits
-    (the fused-CE path projects chunk-wise itself)."""
-    B, S = input_ids.shape
-    _refuse_uncached(cfg)
-    pos_default = positions is None
-    if positions is None:
-        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    (the fused-CE path projects chunk-wise itself). The next-token logits
+    do not depend on an MTP module, which only ``loss_fn`` runs."""
     from ..ops.quantizer import cast_floating
 
-    cast = lambda t: cast_floating(t, dtype)
-    x = embed_tokens(cfg, params, input_ids, positions, dtype)
-    x, aux = apply_layer_stack(
-        cfg, cast(params["layers"]), x, positions, segment_ids, rng, train,
-        remat_policy, pld_keep, ltd_keep, ltd_layers, pos_default,
-    )
-    x = _norm(cfg, cast(params["final_norm"]), x)
+    x, aux, _, _ = _hidden(
+        cfg, params, input_ids, dtype=dtype, train=train, rng=rng,
+        positions=positions, segment_ids=segment_ids,
+        remat_policy=remat_policy, pld_keep=pld_keep, ltd_keep=ltd_keep,
+        ltd_layers=ltd_layers)
+    x = _norm(cfg, cast_floating(params["final_norm"], dtype), x)
     if return_hidden:
         return x, aux
     return lm_head_logits(cfg, params, x), aux
+
+
+def mtp_labels(labels: jax.Array) -> jax.Array:
+    """The MTP module's targets: position ``i`` of next-token labels holds
+    t[i+1], the module predicts t[i+2], and a position whose own next token
+    is ignored is ignored."""
+    nxt = jnp.concatenate(
+        [labels[:, 1:], jnp.full_like(labels[:, :1], -1)], axis=1)
+    return jnp.where(labels >= 0, nxt, -1)
+
+
+def _mtp_hidden(cfg: TransformerConfig, params: Params, h: jax.Array,
+                labels: jax.Array, positions: jax.Array, segment_ids, *,
+                dtype, train: bool, rng, remat_policy):
+    """The MTP module over the main stack's output ``h`` [B,S,d] (before
+    the final norm) -> (its own normed hidden [B,S,d], routing stats).
+    ``labels`` hold t[i+1] at position i (ignored ones embed token 0; their
+    targets are ignored too)."""
+    from ..ops.quantizer import cast_floating
+
+    m = cast_floating(params["mtp"], dtype)
+    emb = params["embed"]["tok"].astype(dtype)[jnp.maximum(labels, 0)]
+    x = jnp.concatenate(
+        [_norm(cfg, m["enorm"], emb), _norm(cfg, m["hnorm"], h)], axis=-1)
+    x = x @ m["eh_proj"]
+    mtp_rng = None if rng is None else jax.random.fold_in(rng, 2)
+    x, _, stats = apply_layer_stack(
+        cfg, m["layers"], x, positions, segment_ids, mtp_rng, train,
+        remat_policy, with_stats=True)
+    return _norm(cfg, m["final_norm"], x), stats
 
 
 def loss_fn(cfg: TransformerConfig, params: Params, batch: Dict[str, jax.Array], *,
@@ -886,39 +1072,54 @@ def loss_fn(cfg: TransformerConfig, params: Params, batch: Dict[str, jax.Array],
             remat_policy: Optional[str] = None, pld_keep=None,
             ltd_keep: Optional[int] = None,
             ltd_layers: Optional[Tuple[int, int]] = None) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Next-token cross-entropy (fp32), labels < 0 are ignored (HF -100 style)."""
+    """Next-token cross-entropy (fp32), labels < 0 are ignored (HF -100
+    style); with an MTP module plus ``mtp_loss_weight`` times its own."""
     from ..ops.cross_entropy import (
         chunked_masked_ce,
         fused_ce_applicable,
         fused_ce_config,
     )
+    from ..ops.quantizer import cast_floating
     from .sharding import current_topology
 
     fused_on, ce_chunk = fused_ce_config()
-    if fused_on and fused_ce_applicable(cfg.vocab_size, ce_chunk,
-                                        current_topology()):
-        # memory path: final hidden → chunked CE, [B,S,V] never materializes
-        x, aux = apply(
-            cfg, params, batch["input_ids"], dtype=dtype, train=train, rng=rng,
-            segment_ids=batch.get("segment_ids"),
-            positions=batch.get("positions"), remat_policy=remat_policy,
-            pld_keep=pld_keep, ltd_keep=ltd_keep, ltd_layers=ltd_layers,
-            return_hidden=True,
-        )
-        ce, denom = chunked_masked_ce(
-            x, lm_head_weight(cfg, params), batch["labels"], ce_chunk
-        )
-        total = ce + cfg.moe_aux_loss_coef * aux if cfg.is_moe else ce
-        return total, {"lm_loss": ce, "moe_aux_loss": aux, "tokens": denom}
-    logits, aux = apply(
+    fused = fused_on and fused_ce_applicable(cfg.vocab_size, ce_chunk,
+                                             current_topology())
+
+    def ce_of(x, labels):
+        if fused:
+            # memory path: final hidden → chunked CE, [B,S,V] never
+            # materializes
+            return chunked_masked_ce(x, lm_head_weight(cfg, params), labels,
+                                     ce_chunk)
+        return masked_ce(lm_head_logits(cfg, params, x), labels)
+
+    h, aux, stats, positions = _hidden(
         cfg, params, batch["input_ids"], dtype=dtype, train=train, rng=rng,
-        segment_ids=batch.get("segment_ids"), positions=batch.get("positions"),
-        remat_policy=remat_policy, pld_keep=pld_keep,
-        ltd_keep=ltd_keep, ltd_layers=ltd_layers,
-    )
-    ce, denom = masked_ce(logits, batch["labels"])
+        positions=batch.get("positions"),
+        segment_ids=batch.get("segment_ids"), remat_policy=remat_policy,
+        pld_keep=pld_keep, ltd_keep=ltd_keep, ltd_layers=ltd_layers)
+    ce, denom = ce_of(
+        _norm(cfg, cast_floating(params["final_norm"], dtype), h),
+        batch["labels"])
     total = ce + cfg.moe_aux_loss_coef * aux if cfg.is_moe else ce
-    return total, {"lm_loss": ce, "moe_aux_loss": aux, "tokens": denom}
+    metrics = {"lm_loss": ce, "moe_aux_loss": aux, "tokens": denom}
+    if cfg.mtp_layers:
+        with jax.named_scope("mtp"):
+            xm, mtp_stats = _mtp_hidden(
+                cfg, params, h, batch["labels"], positions,
+                batch.get("segment_ids"), dtype=dtype, train=train, rng=rng,
+                remat_policy=remat_policy)
+            mtp_ce, _ = ce_of(xm, mtp_labels(batch["labels"]))
+        total = total + cfg.mtp_loss_weight * mtp_ce
+        metrics["mtp_loss"] = mtp_ce
+        if stats is not None:  # the module's router: the rows after the main
+            stats = jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
+                                 stats, mtp_stats)
+    if stats is not None:
+        metrics.update(routing_stats_summary(stats),
+                       moe_counts=stats["counts"])
+    return total, metrics
 
 
 def make_lm_batch(input_ids: jax.Array, pad_id: int = -1) -> Dict[str, jax.Array]:
@@ -961,6 +1162,16 @@ def tp_partition_specs(cfg: TransformerConfig, tp_divides_kv: bool = True) -> Pa
     if cfg.qk_norm:
         attn["q_norm"] = {"scale": P(None, None)}
         attn["k_norm"] = {"scale": P(None, None)}
+
+    def dense_mlp():
+        mlp = {"wi": P(None, None, "tp"), "wo": P(None, "tp", None)}
+        if cfg.activation == "swiglu":
+            mlp["wg"] = P(None, None, "tp")
+        if cfg.use_bias:
+            mlp["bi"] = P(None, "tp")
+            mlp["bo"] = P(None, None)
+        return mlp
+
     if cfg.is_moe:
         mlp = {
             "router": P(None, None, None),
@@ -975,13 +1186,12 @@ def tp_partition_specs(cfg: TransformerConfig, tp_divides_kv: bool = True) -> Pa
             if cfg.activation == "swiglu":
                 mlp["res_wg"] = P(None, None, "tp")
             mlp["coef"] = P(None, None, None)
+        if cfg.moe_gate == "sigmoid_groups":
+            mlp["sel_bias"] = P(None, None)
+        if cfg.moe_shared_width:
+            mlp["shared"] = dense_mlp()
     else:
-        mlp = {"wi": P(None, None, "tp"), "wo": P(None, "tp", None)}
-        if cfg.activation == "swiglu":
-            mlp["wg"] = P(None, None, "tp")
-        if cfg.use_bias:
-            mlp["bi"] = P(None, "tp")
-            mlp["bo"] = P(None, None)
+        mlp = dense_mlp()
     specs: Params = {
         "embed": {"tok": P("tp", None)},
         "final_norm": dict(scale=P(None), **({"bias": P(None)} if cfg.norm == "layernorm" else {})),
@@ -993,6 +1203,14 @@ def tp_partition_specs(cfg: TransformerConfig, tp_divides_kv: bool = True) -> Pa
         specs["embed_norm"] = specs["final_norm"]
     if not cfg.tie_embeddings:
         specs["lm_head"] = P(None, "tp")
+    if cfg.lead_dense_layers:
+        specs["lead_layers"] = dict(specs["layers"], mlp=dense_mlp())
+    if cfg.mtp_layers:
+        specs["mtp"] = {
+            "enorm": specs["final_norm"], "hnorm": specs["final_norm"],
+            "eh_proj": P(None, None), "layers": specs["layers"],
+            "final_norm": specs["final_norm"],
+        }
     return specs
 
 
@@ -1018,3 +1236,40 @@ class TransformerModel:
 
     def num_params(self) -> int:
         return self.config.num_params()
+
+    def buffer_mask(self, params):
+        """True at the leaves of ``params`` that are state and no parameter
+        (the engine keeps its optimizer off them and calls
+        :meth:`update_buffers` after each step): the selection bias of every
+        sigmoid_groups router. None if the model has none."""
+        cfg = self.config
+        if not (cfg.is_moe and cfg.moe_gate == "sigmoid_groups"):
+            return None
+        return jax.tree_util.tree_map_with_path(
+            lambda path, _: getattr(path[-1], "key", None) == "sel_bias",
+            params)
+
+    def update_buffers(self, params, metrics):
+        """``params`` with every selection bias moved by the ``noaux_tc``
+        rule: ``b_e += u * sign(mean count - count_e)`` over a layer's
+        router outputs, from the step's own ``metrics["moe_counts"]`` (one
+        row a routed layer: the main stack's, then the MTP module's)."""
+        cfg = self.config
+        counts = metrics.get("moe_counts")
+        if counts is None:  # a step that reports the loss alone
+            return params
+        step = cfg.moe_bias_update_rate * jnp.sign(
+            jnp.mean(counts, axis=-1, keepdims=True) - counts)
+
+        def moved(stack, rows):
+            mlp = dict(stack["mlp"])
+            mlp["sel_bias"] = mlp["sel_bias"] + rows.astype(
+                mlp["sel_bias"].dtype)
+            return dict(stack, mlp=mlp)
+
+        L = cfg.num_layers
+        out = dict(params, layers=moved(params["layers"], step[:L]))
+        if cfg.mtp_layers:
+            out["mtp"] = dict(params["mtp"], layers=moved(
+                params["mtp"]["layers"], step[L:]))
+        return out

@@ -275,6 +275,100 @@ def held_expert_tables(idx, w, valid, first: int, held: int, capacity: int):
             jnp.stack(slots, axis=1), w * here, fill, unrouted)
 
 
+@jax.custom_vjp
+def _rows_of_pairs(tokens, order, inv, here):
+    """``tokens`` [N, D] -> the row of every (token, chosen expert) pair in
+    sorted order [N x K, D]: a gather forward, and a gather backward too
+    (``inv`` is the inverse of the permutation ``order``; the cotangent of a
+    pair not held here, ``here`` false, is left out)."""
+    return jnp.take(tokens, order // (order.shape[0] // tokens.shape[0]),
+                    axis=0)
+
+
+def _rows_of_pairs_fwd(tokens, order, inv, here):
+    return _rows_of_pairs(tokens, order, inv, here), (inv, here,
+                                                      tokens.shape[0])
+
+
+def _rows_of_pairs_bwd(res, g):
+    inv, here, n = res
+    back = jnp.take(g, inv, axis=0).reshape(n, -1, g.shape[-1])
+    back = jnp.where(here[..., None], back, 0)
+    return jnp.sum(back, axis=1).astype(g.dtype), None, None, None
+
+
+_rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
+
+
+@jax.custom_vjp
+def _pairs_of_rows(rows, order, inv):
+    """Sorted rows [N x K, D] back in pair order: the inverse gather of
+    :func:`_rows_of_pairs`, and its cotangent is the forward one."""
+    return jnp.take(rows, inv, axis=0)
+
+
+_pairs_of_rows.defvjp(
+    lambda rows, order, inv: (jnp.take(rows, inv, axis=0), order),
+    lambda order, g: (jnp.take(g, order, axis=0), None, None))
+
+
+def moe_held_layer(cfg, p: Dict, x: jax.Array):
+    """The sigmoid_groups layer in its training form: x [B, S, D] -> (the
+    partial sum of the experts held here [B, S, D], routing stats).
+
+    The router scores all ``cfg.routed_experts`` and chooses ``moe_top_k``
+    of them a token (:func:`sigmoid_group_gate`; the selection bias chooses
+    and carries no gradient). The (token, chosen expert) pairs are sorted by
+    expert, those of experts held elsewhere last; the rows are gathered
+    once, the three products run over the ragged groups
+    (``jax.lax.ragged_dot``: on the chip a grouped matrix product whose work
+    follows the rows in the groups), and every pair reads its row back with
+    its weight. The buffer holds every pair, so no token is dropped whatever
+    the imbalance, and nothing but the products depends on how many rows
+    are held. Backward reaches the router through the weights; the choice
+    carries no gradient. The shared expert is the caller's.
+
+    Stats: ``counts`` [routed experts] float32, the tokens that chose each
+    expert of the layer (what moves the selection bias); ``held`` [experts
+    held], those of the experts computed here."""
+    B, S, D = x.shape
+    N, K, E = B * S, cfg.moe_top_k, cfg.num_experts
+    R, first = cfg.routed_experts, cfg.moe_first_expert
+    tokens = x.reshape(N, D)
+    with jax.named_scope("moe_route"):
+        logits = jnp.einsum("nd,de->ne", tokens.astype(jnp.float32),
+                            p["router"].astype(jnp.float32))
+        idx, w = sigmoid_group_gate(
+            logits, jax.lax.stop_gradient(p["sel_bias"]), K, cfg.moe_groups,
+            cfg.moe_groups_kept, cfg.moe_routed_scale)
+        counts = jnp.sum(idx[..., None] == jnp.arange(R), axis=(0, 1),
+                         dtype=jnp.float32)
+        local = idx - first
+        here = (local >= 0) & (local < E)
+        # pairs in token order, sorted by expert; E: held elsewhere, last
+        order = jnp.argsort(jnp.where(here, local, E).reshape(-1),
+                            stable=True).astype(jnp.int32)
+        inv = jnp.argsort(order).astype(jnp.int32)
+        held = counts[first:first + E]
+        sizes = held.astype(jnp.int32)
+        in_group = jnp.arange(N * K) < jnp.sum(sizes)
+    with jax.named_scope("moe_experts"):
+        rows = _rows_of_pairs(tokens, order, inv, here)
+        h = jax.lax.ragged_dot(rows, p["wi"], sizes)
+        if cfg.activation == "swiglu":
+            h = jax.nn.silu(jax.lax.ragged_dot(rows, p["wg"], sizes)) * h
+        else:
+            h = jax.nn.gelu(h)
+        # rows past the last group belong to no product: what a grouped
+        # product leaves there is not read
+        h = jnp.where(in_group[:, None], h, 0)
+        out = jnp.where(in_group[:, None],
+                        jax.lax.ragged_dot(h, p["wo"], sizes), 0)
+        picked = _pairs_of_rows(out, order, inv).reshape(N, K, D)
+        out = jnp.sum(picked * (w * here)[..., None].astype(x.dtype), axis=1)
+    return out.reshape(B, S, D), {"counts": counts, "held": held}
+
+
 def eval_capacity(cfg, n_tokens: int) -> int:
     """Per-expert capacity at inference for a program that feeds at most
     ``n_tokens`` real tokens: ``max(4, ceil(max(capacity_factor, 2.0) ·
@@ -526,10 +620,6 @@ def moe_serving_mlp(cfg, p: Dict, x: jax.Array,
                    "drop_fraction": jnp.zeros((), jnp.float32),
                    "unrouted_tokens": unrouted}
     else:
-        if cfg.routed_experts != E:
-            raise NotImplementedError(
-                "a share of an expert-parallel layer (moe_routed_experts) is "
-                "served under the sigmoid_groups router alone")
         capacity = eval_capacity(cfg, int(budget_tokens))
         tok_of_slot, slot_valid, slot_of_tok, w_of_tok, metrics = (
             top_k_gating_indices(router_logits, K, capacity, rng=None,

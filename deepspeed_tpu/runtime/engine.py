@@ -46,6 +46,12 @@ from .precision import (
 from .zero.partition import make_shardings, opt_state_sharding, zero_specs
 
 
+# model metrics of a step that go to the monitor (``train/<name>``) and onto
+# the ``train/device`` span beside the loss: the MTP module's own loss and
+# the sigmoid_groups router's load (models/transformer.loss_fn)
+_SPAN_METRICS = ("mtp_loss", "moe_rows_held", "moe_rows_max_over_mean")
+
+
 class TrainState:
     """Params (fp32 master), optax state, loss-scale state, step counter."""
 
@@ -502,6 +508,18 @@ class TpuEngine:
         )
         if tp_specs is None:
             tp_specs = jax.tree.map(lambda x: P(), params_shape)
+        # ---- state that is no parameter (a model's ``buffer_mask``: True at
+        # a leaf of the tree that takes no gradient, e.g. a router's
+        # selection bias). The optimizer never sees such a leaf (no moments,
+        # no weight decay); after each update the model's ``update_buffers``
+        # moves it from the step's own metrics. It stays a leaf of
+        # ``state.params``, so a checkpoint saves and restores it.
+        mask_of = getattr(model, "buffer_mask", None)
+        self._buffer_mask = mask_of(params_shape) if mask_of else None
+        if self._buffer_mask is not None:
+            self.optimizer_tx = optax.masked(
+                self.optimizer_tx,
+                jax.tree.map(lambda b: not b, self._buffer_mask))
         self.param_specs, self.grad_specs, self.opt_leaf_specs = zero_specs(
             params_shape, tp_specs, topology, config.zero_config
         )
@@ -1948,6 +1966,16 @@ class TpuEngine:
                 grads, opt_state, params
             )
             new_params = optax.apply_updates(params, updates)
+        mmetrics = mmetrics or {}
+        if self._buffer_mask is not None:
+            # buffers move by the model's own rule, from this step's
+            # metrics; whatever the masked optimizer passed through is void
+            moved = self.model.update_buffers(params, mmetrics)
+            new_params = jax.tree.map(
+                lambda b, new, buf: buf if b else new,
+                self._buffer_mask, new_params, moved)
+        # a model metric that is no scalar fed the update and is not reported
+        mmetrics = {k: v for k, v in mmetrics.items() if jnp.ndim(v) == 0}
 
         if self.fp16_enabled:
             # overflow → keep old state (skip step); bf16/fp32 never overflow
@@ -2005,7 +2033,7 @@ class TpuEngine:
             "overflow": overflow,
             "loss_scale": new_scale.scale,
             "lr": self.lr_schedule(step),
-            **(mmetrics or {}),  # lm_loss / moe_aux_loss / tokens
+            **mmetrics,  # lm_loss / moe_aux_loss / tokens
         }
         return new_params, new_opt, new_scale, new_step, metrics
 
@@ -2264,7 +2292,14 @@ class TpuEngine:
             # replacing the old (donated) state while the step is still
             # in flight blocks inside the assignment, which would
             # silently attribute the whole device time to host work.
-            tr.begin("train/device", "train").end(fence=metrics["loss"])
+            sp = tr.begin("train/device", "train")
+            jax.block_until_ready(metrics["loss"])
+            # the step's model metrics ride on the span that timed it
+            extra = {k: float(metrics[k]) for k in _SPAN_METRICS
+                     if k in metrics}
+            if extra:
+                sp.annotate(**extra)
+            sp.end()
         self.state = TrainState(p, o, s, st)
         if breakdown:
             # dispatch returns immediately; a second timer blocks on the
@@ -2341,6 +2376,8 @@ class TpuEngine:
                     "train/moe_aux_loss", float(metrics["moe_aux_loss"]),
                     step_no,
                 ))
+            events += [("train/" + k, float(metrics[k]), step_no)
+                       for k in _SPAN_METRICS if k in metrics]
             if self.tput.avg_samples_per_sec > 0:
                 events.append((
                     "train/samples_per_sec", self.tput.avg_samples_per_sec,

@@ -127,12 +127,19 @@ def opt_state_sharding(tx, opt_state, opt_leaf_specs, topo: MeshTopology,
     kwargs = {"memory_kind": memory_kind} if memory_kind else {}
     replicated = NamedSharding(topo.mesh, P())
 
+    # a leaf the optimizer is masked from (engine state that is no
+    # parameter: optax.masked keeps a MaskedNode in its place) has no state
+    # to shard
+    masked = lambda x: isinstance(x, optax.MaskedNode)  # noqa: E731
+
     return optax.tree_map_params(
         tx,
-        lambda leaf, spec: NamedSharding(topo.mesh, spec, **kwargs),
+        lambda leaf, spec: leaf if masked(leaf) else NamedSharding(
+            topo.mesh, spec, **kwargs),
         opt_state,
         opt_leaf_specs,
         transform_non_params=lambda leaf: replicated,
+        is_leaf=masked,
     )
 
 

@@ -387,21 +387,45 @@ def test_what_the_latent_path_cannot_take_is_refused_by_mechanism(
         deepspeed_tpu.init_serving(model, params=params, dtype=F32, **kw)
 
 
-def test_training_a_latent_model_is_refused_by_mechanism(model, params):
+def test_training_refuses_the_indexer_by_name_and_runs_the_rest(model, params):
     batch = {"input_ids": jnp.zeros((2, 8), jnp.int32),
              "labels": jnp.zeros((2, 8), jnp.int32)}
-    with pytest.raises(DeepSpeedConfigError, match="kv_latent_dim") as e:
+    with pytest.raises(DeepSpeedConfigError, match="index_topk") as e:
         model.loss(params, batch)
-    for mechanism in ("lead_dense_layers", "sigmoid_groups",
+    for mechanism in ("kv_latent_dim", "lead_dense_layers", "sigmoid_groups",
                       "moe_shared_width", "moe_routed_experts"):
-        assert mechanism in str(e.value)
+        assert mechanism not in str(e.value)
     assert "deepseek" not in str(e.value).lower()
-    # one mechanism alone is refused by its own name
+    # without its indexer the same model trains: latent attention, the
+    # leading dense layer, the sigmoid router over one member's share and
+    # the shared expert all run in the uncached forward
+    from deepspeed_tpu.models.transformer import TransformerModel
+
+    plain = TransformerModel(dataclasses.replace(
+        model.config, index_topk=0, index_heads=0, index_dim=0))
+    p = plain.init(jax.random.PRNGKey(7), dtype=F32)
+    ids = jax.random.randint(jax.random.PRNGKey(2), (2, 16), 0, 512)
+    batch = {"input_ids": ids, "labels": jnp.roll(ids, -1, axis=1)}
+    (loss, m), g = jax.value_and_grad(
+        lambda p: plain.loss(p, batch, dtype=F32), has_aux=True)(p)
+    assert np.isfinite(float(loss)) and float(m["moe_rows_held"]) > 0
+    for leaf in (g["lead_layers"]["mlp"]["wi"], g["layers"]["attn"]["wkv_b"],
+                 g["layers"]["mlp"]["router"], g["layers"]["mlp"]["wo"],
+                 g["layers"]["mlp"]["shared"]["wi"]):
+        assert float(jnp.abs(leaf).max()) > 0
+    assert not np.any(np.asarray(g["layers"]["mlp"]["sel_bias"]))
+    # one mechanism alone runs too: a shared expert beside a softmax router
     from deepspeed_tpu.models import mixtral
 
-    cfg = dataclasses.replace(mixtral("mixtral-tiny").config,
-                              moe_shared_width=16)
-    from deepspeed_tpu.models.transformer import _refuse_uncached
-
-    with pytest.raises(DeepSpeedConfigError, match="shared expert"):
-        _refuse_uncached(cfg)
+    base = mixtral("mixtral-tiny").config
+    shared = TransformerModel(dataclasses.replace(base, moe_shared_width=16))
+    ps = shared.init(jax.random.PRNGKey(3), dtype=F32)
+    ids = ids % base.vocab_size
+    batch = {"input_ids": ids, "labels": jnp.roll(ids, -1, axis=1)}
+    with_it = shared.loss(ps, batch, dtype=F32)[0]
+    ps["layers"]["mlp"]["shared"]["wo"] *= 0.0
+    assert float(jnp.abs(with_it - shared.loss(ps, batch, dtype=F32)[0])) > 0
+    # ... and one member's share of a softmax-routed layer is refused where
+    # the configuration is made, by its own name
+    with pytest.raises(ValueError, match="moe_routed_experts"):
+        dataclasses.replace(base, moe_routed_experts=2 * base.num_experts)

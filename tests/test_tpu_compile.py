@@ -389,6 +389,52 @@ def test_deepseek_slot_step_keeps_both_pools_in_place(one_chip, monkeypatch,
         assert name in text
 
 
+def test_glm_train_step_fits_the_described_chip(topo, monkeypatch, capsys):
+    """The train step of the cell ``glm47flash-pretrain-4k`` (its
+    configuration file's model and ds_config, micro-batch 2 x 4,096,
+    706.5 M parameters with float32 AdamW state) lowered through
+    ``initialize(abstract_init=True)`` for the described v5e: the chip's
+    compiler takes it beside the 16 GB, the latent attention runs in the
+    flash kernels and the experts in grouped matrix products."""
+    import json
+    import os
+
+    import deepspeed_tpu
+    from deepspeed_tpu.analysis import shardlint
+    from deepspeed_tpu.comm import MeshTopology, ParallelDims
+    from deepspeed_tpu.models import glm
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "glm-4.7-flash.json")) as f:
+        eng = json.load(f)["engine"]
+    model = glm(eng["model"]["size"], **eng["model"]["overrides"])
+    batch = int(eng["micro_batch_per_chip"])
+    engine, *_ = deepspeed_tpu.initialize(
+        model=model, abstract_init=True,
+        topology=MeshTopology(dims=ParallelDims(), devices=[topo.devices[0]]),
+        config=dict(eng["ds_config"], train_batch_size=batch))
+    # the kernels pick interpret mode from the backend, the CPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(rn, "_interpret", lambda: False)
+    compiled = shardlint.lower_train_step(engine).compile()
+    m = compiled.memory_analysis()
+    peak = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+    with capsys.disabled():
+        print(f"\nglm-4.7-flash train step [{batch}, "
+              f"{model.config.max_seq_len}], described v5e: arguments "
+              f"{m.argument_size_in_bytes / GIB:.2f} GiB, temporaries "
+              f"{m.temp_size_in_bytes / GIB:.2f} GiB, peak {peak / GIB:.2f} "
+              f"GiB of 15.75")
+    assert peak < 15.75 * GIB
+    text = compiled.as_text()
+    calls = set(re.findall(
+        r'%([a-z_\-]+)[.\d]* = [^\n]*custom_call_target="tpu_custom_call"',
+        text))
+    assert {"latent_attention", "ragged-dot-none"} <= calls
+
+
 # ----------------------------------------------------------------- norms
 @pytest.mark.parametrize("D", [1024, 4096])
 @pytest.mark.parametrize("kind", ["rmsnorm", "layernorm"])
