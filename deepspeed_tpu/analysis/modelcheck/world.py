@@ -134,6 +134,9 @@ class _Recorder:
     def on_token(self, state, now):
         pass
 
+    def on_rows(self, tokens, discarded=0):
+        pass
+
     def on_spec(self, state, proposed, accepted, emitted):
         pass
 

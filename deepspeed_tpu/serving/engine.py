@@ -48,6 +48,7 @@ see docs/serving.md "Speculative decoding" and tests/test_serving_spec.py.
 from __future__ import annotations
 
 import time
+from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -392,6 +393,16 @@ def make_step_fn(cfg, dtype, vocab: int, cache_shardings=None,
       rng [N, 2] uint32     per-slot PRNG keys (split ONLY when a token
                             is emitted, mirroring the lockstep chain)
       temperature/top_p/rep_penalty [N] f32, top_k [N] i32
+      from_prev [N] bool    the row's first token and the slot's key are
+                            the outputs of the step before, which the host
+                            has not fetched yet (a decode row planned while
+                            that step was in flight): taken from
+      prev_tok [N, max_draft + 1] i32, prev_rng [N, 2] u32
+                            that step's ``out_tokens`` and ``new_rng``,
+                            handed from call to call on the device (never
+                            donated: the host still fetches them). With
+                            the flag all false the program computes bit
+                            for bit what it computes without the operands
 
     ``max_draft`` is STATIC (the step's fixed output shape
     [N, max_draft + 1]); 0 disables speculation and reduces the verify
@@ -420,7 +431,12 @@ def make_step_fn(cfg, dtype, vocab: int, cache_shardings=None,
 
     def step(params, caches, seen, tokens, num_new, start_pos, fresh,
              sample_flag, spec_len, eos_id, rng, temperature, top_k, top_p,
-             rep_penalty, page_table=None, page_table_win=None):
+             rep_penalty, from_prev, prev_tok, prev_rng, page_table=None,
+             page_table_win=None):
+        # (before the seen bookkeeping: a token is booked where it is fed)
+        tokens = tokens.at[:, 0].set(
+            jnp.where(from_prev, prev_tok[:, 0], tokens[:, 0]))
+        rng = jnp.where(from_prev[:, None], prev_rng, rng)
         live = sample_flag & (num_new > 0)
         seen = _book_seen(seen, tokens, num_new, spec_len, fresh, vocab)
         token_valid = (
@@ -528,26 +544,36 @@ def make_paged_step_fn(cfg, dtype, vocab: int, cache_shardings=None,
         return step
 
     def tiered_step(params, caches, seen, tokens, num_new, start_pos,
-                    page_table, cow_src, stage_kv, stage_dst, fresh,
-                    sample_flag, spec_len, eos_id, rng, temperature,
-                    top_k, top_p, rep_penalty):
+                    page_table, cow_src, stage_kv, stage_dst, *rest):
         # scatter-before-gather: promoted pages land in the pool before
         # the COW lane and the per-slot view gathers, so a slot whose
         # last host page promotes THIS step also schedules this step
         caches = staged_promote(caches, stage_kv, stage_dst)
         return step(params, caches, seen, tokens, num_new, start_pos,
-                    page_table, cow_src, fresh, sample_flag, spec_len,
-                    eos_id, rng, temperature, top_k, top_p, rep_penalty)
+                    page_table, cow_src, *rest)
 
     return tiered_step
+
+
+@dataclass
+class _Flying:
+    """A step the device has been handed and the host has not folded."""
+
+    plan: StepPlan
+    reads: tuple      # (out_tokens, new_rng, n_emit, moe counters | []):
+    #   device arrays, their copies to the host begun at dispatch
+    overlapped: bool  # dispatched while the step before was unfetched
+    filtered: bool    # a live slot asked for top-k or top-p
+    t0: Optional[float]  # when its dispatch began (under the tracer)
 
 
 class ServingEngine:
     """Request-level front end over one slot-ragged jitted step.
 
-    Drive it with :meth:`submit` + :meth:`step` (one scheduler plan + one
-    device step per call), or :meth:`run_until_idle` to drain everything
-    in flight. ``clock`` is injectable for tests/replay."""
+    Drive it with :meth:`submit` + :meth:`step` (one scheduler plan, one
+    device step dispatched and one folded per call: ``step_order`` says in
+    which order), or :meth:`run_until_idle` to drain everything in
+    flight. ``clock`` is injectable for tests/replay."""
 
     def __init__(
         self,
@@ -910,6 +936,35 @@ class ServingEngine:
             return out
 
         self._step = jax.jit(counting_step, donate_argnums=(1, 2))
+        # ---- the order of a turn. "overlapped": step n+1 is planned and
+        # dispatched before step n is fetched, so the device goes from one
+        # step into the next while the host folds the first; the plan is a
+        # projection (Scheduler.plan(ahead_of=...)). "serial" (plan,
+        # dispatch, fetch and fold of one step in one call) where the
+        # projection is impossible, with the reason: a property of the
+        # engine, not a key
+        self.step_order, self.step_order_reason = "overlapped", None
+        if self.max_draft > 0:
+            self.step_order_reason = (
+                "a verify window advances a slot by n_emit, known only "
+                "after the fetch, and drafts are proposed from the tokens "
+                "it emitted")
+        elif self.tiered:
+            self.step_order_reason = (
+                "the fold hands back the staging buffer and the host keys "
+                "that the next plan's promotions and demotions use")
+        if self.step_order_reason:
+            self.step_order = "serial"
+        self._flying: Optional[_Flying] = None  # dispatched, not folded
+        # the newest step's out_tokens and new_rng, the next call's
+        # operands whether or not a row of it reads them (zeros before the
+        # first step, placed as the step's outputs are)
+        rep = (NamedSharding(self.topology.mesh, P())
+               if self.topology.world_size > 1 else self.topology.devices[0])
+        self._prev = (
+            jax.device_put(np.zeros((N, self.max_draft + 1), np.int32), rep),
+            jax.device_put(np.zeros((N, 2), np.uint32), rep),
+        )
         # lazily-jitted fleet-handoff page scatter (pool donated; one
         # compile per distinct transferred-page count, bounded by
         # pages_per_slot)
@@ -945,6 +1000,7 @@ class ServingEngine:
             f"{'int8' if engine.kv_cache_quantized else jnp.dtype(engine.kv_cache_storage_dtype).name}, "
             f"tp={self.topology.tp_size}, spec="
             f"{f'ngram(k<={self.max_draft})' if self.max_draft else 'off'}"
+            f", order={self.step_order}"
             + (
                 f", moe=ep{self.moe_ep}/{self.moe_a2a_form}"
                 if self.moe_serving else ""
@@ -964,8 +1020,13 @@ class ServingEngine:
 
     # ------------------------------------------------------------- stepping
     def step(self) -> List[RequestState]:
-        """One scheduler plan + one jitted device step. Returns requests
-        that FINISHED this step (their slots already recycled)."""
+        """One turn: plan and dispatch the next device step, then fold the
+        one in flight (fetch its tokens, deliver them, retire what
+        finished). Returns the requests the FOLDED step finished. In the
+        overlapped order (``step_order``) that is the step dispatched by
+        the call before: the first call after idle dispatches and returns
+        ``[]``, and a call with nothing to plan folds what is in flight. In
+        the serial order it is the step this call dispatched."""
         hw = self.healthwatch
         if hw is None:
             return self._step_inner()
@@ -983,38 +1044,61 @@ class ServingEngine:
 
     def _step_inner(self) -> List[RequestState]:
         tr = self.tracer
+        # the plan in flight, which the next is projected over (None in
+        # the serial order: every step is folded by the call it began in)
+        ahead = self._flying.plan if self._flying is not None else None
         if tr is None:
-            plan = self.scheduler.plan()
-            if plan is None:
+            plan = self.scheduler.plan(ahead_of=ahead)
+            if plan is None and ahead is None:
                 return []
             return self._run_plan(plan)
         # traced step: serve/step parent; serve/plan, serve/dispatch,
         # serve/device, serve/complete children cover the whole of it
-        # (tools/trace_report.py --validate checks the coverage)
+        # (tools/trace_report.py --validate checks the coverage). In the
+        # overlapped order plan and dispatch are the next step's, device
+        # (what is left of the wait) and complete the step's in flight
         step_args = {"step": self.metrics.steps + 1}
         if self.name is not None:
             step_args["replica"] = self.name
         step_sp = tr.begin("serve/step", "serve", step_args)
         plan_sp = tr.begin("serve/plan", "serve")
-        plan = self.scheduler.plan()
-        if plan is None:
+        plan = self.scheduler.plan(ahead_of=ahead)
+        if plan is None and ahead is None:
             # idle tick: no device step ran — drop BOTH spans (an orphan
             # serve/plan with no parent step would skew the phase table)
             plan_sp.cancel()
             step_sp.cancel()
             return []
         plan_sp.end()
-        step_sp.annotate(scheduled_tokens=int(plan.total_tokens))
-        if plan.spec_len is not None and plan.spec_len.any():
-            # spec observability: how many of this step's budget rows are
-            # draft (verify-window) rows — trace_report shows it per step
-            step_sp.annotate(spec_draft_tokens=int(plan.spec_len.sum()))
+        if plan is not None:
+            step_sp.annotate(scheduled_tokens=int(plan.total_tokens))
+            if plan.spec_len is not None and plan.spec_len.any():
+                # spec observability: how many of this step's budget rows
+                # are draft (verify-window) rows — trace_report shows it
+                # per step
+                step_sp.annotate(spec_draft_tokens=int(plan.spec_len.sum()))
         try:
             return self._run_plan(plan)
         finally:
             step_sp.end()
 
-    def _run_plan(self, plan: StepPlan) -> List[RequestState]:
+    def _run_plan(self, plan: Optional[StepPlan]) -> List[RequestState]:
+        """Dispatch ``plan`` (None: nothing to plan) and fold the step
+        that is due: in the overlapped order the one dispatched a call
+        ago, which the device ran while the host planned this one; in the
+        serial order the one just dispatched."""
+        due, self._flying = self._flying, None
+        if plan is not None:
+            self._flying = self._dispatch(plan, overlapped=due is not None)
+        if self.step_order == "serial":
+            due, self._flying = self._flying, None
+        return self._fold(due) if due is not None else []
+
+    def _dispatch(self, plan: StepPlan, overlapped: bool) -> "_Flying":
+        """Hand one plan to the device. The jitted call returns at once
+        (it queues behind the step in flight); the three small results the
+        host reads start their way back here, so the fold finds them
+        landed."""
         tr = self.tracer
         # dispatch span covers host-side array staging (the per-slot
         # numpy fills below, including jnp uploads) + the jit call; the
@@ -1040,6 +1124,10 @@ class ServingEngine:
         spec_len = (
             plan.spec_len if plan.spec_len is not None
             else np.zeros(N, np.int32)
+        )
+        from_prev = (
+            plan.from_prev if plan.from_prev is not None
+            else np.zeros(N, np.bool_)
         )
         if self.paged:
             # idle rows need no dead-tail repoint: the scheduler hands
@@ -1074,7 +1162,6 @@ class ServingEngine:
         traces_before = self.step_traces
         from ..parallel.a2a_overlap import a2a_scope
 
-        moe_stats = None
         # the step's attention work rides the profiler's host trace with the
         # call it describes (free while no trace is being taken)
         with use_topology(self.topology), self.engine._impl_ctx(), \
@@ -1086,45 +1173,55 @@ class ServingEngine:
                 self.engine.params, self._caches, self._seen,
                 plan.tokens, plan.num_new, start_pos, *paged_args,
                 plan.fresh, plan.sample, spec_len, eos, rng, temp, top_k,
-                top_p, penalty,
+                top_p, penalty, from_prev, *self._prev,
             )
-        if self.moe_serving:
-            caches, seen, out_tok, n_emit, new_rng, moe_stats = outs
-        else:
-            caches, seen, out_tok, n_emit, new_rng = outs
+        self._caches, self._seen, out_tok, n_emit, new_rng, *moe = outs
+        self._prev = (out_tok, new_rng)
+        # one fetch for everything the host reads, begun now
+        reads = (out_tok, new_rng, n_emit, moe and (
+            moe[0]["tokens_per_expert"], moe[0]["drop_fraction"],
+            moe[0].get("unrouted_tokens")))
+        for a in jax.tree_util.tree_leaves(reads):
+            a.copy_to_host_async()
         if dispatch_sp is not None:
             dispatch_sp.annotate(traced=self.step_traces - traces_before)
             dispatch_sp.end()
+        return _Flying(
+            plan, reads, overlapped,
+            # did the step pay for the sampler's sorts: the step's own
+            # predicate, from the vectors the host filled for it
+            filtered=bool(np.any(
+                plan.sample & (plan.num_new > 0)
+                & ((top_k > 0) | (top_p < 1.0)))),
+            t0=dispatch_sp.t0 if dispatch_sp is not None else None,
+        )
+
+    def _fold(self, fl: "_Flying") -> List[RequestState]:
+        """Fetch a dispatched step's results and fold them into the
+        requests: the one place a token reaches the host."""
+        tr = self.tracer
+        plan = fl.plan
+        if tr is not None:
             device_sp = tr.begin("serve/device", "serve")
-            device_sp.end(fence=out_tok)
+            device_sp.end(fence=fl.reads[0])
             # prompt chunks fed this step become request-scoped spans
             # covering the dispatch+device window (statuses read BEFORE
-            # complete() advances them)
+            # complete() advances them; a row whose request went while
+            # the step flew has no tree to hang on)
             for w in plan.work:
-                if w.n_tokens > 0 and \
-                        w.state.status is RequestStatus.PREFILL:
+                if w.n_tokens > 0 \
+                        and self.scheduler.slots[w.slot] is w.state \
+                        and w.state.status is RequestStatus.PREFILL:
                     self._serve_tracer.on_chunk(
-                        w.state, w.n_tokens, dispatch_sp.t0, device_sp.t1
+                        w.state, w.n_tokens, fl.t0, device_sp.t1
                     )
             complete_sp = tr.begin("serve/complete", "serve")
-        self._caches, self._seen = caches, seen
-        # one fetch for everything the host reads (the copies start
-        # together; a fetch apiece waited a round trip each)
-        out_tok, new_rng, n_emit, moe_stats = jax.device_get((
-            out_tok, new_rng, n_emit,
-            moe_stats and (moe_stats["tokens_per_expert"],
-                           moe_stats["drop_fraction"],
-                           moe_stats.get("unrouted_tokens")),
-        ))
+        out_tok, new_rng, n_emit, moe_stats = jax.device_get(fl.reads)
         finished = self.scheduler.complete(
             plan, out_tok, new_rng, n_emit=n_emit,
         )
-        # did the step pay for the sampler's sorts: the step's own predicate,
-        # from the vectors the host filled for it
-        self.metrics.on_step(filtered=bool(np.any(
-            plan.sample & (plan.num_new > 0)
-            & ((top_k > 0) | (top_p < 1.0)))))
-        if moe_stats is not None:
+        self.metrics.on_step(filtered=fl.filtered, overlapped=fl.overlapped)
+        if moe_stats:
             # expert load-balance counters (ISSUE 14 satellite): the step
             # already computed them on device
             self.metrics.on_moe(
@@ -1284,11 +1381,12 @@ class ServingEngine:
 
     def run_until_idle(self, max_steps: int = 100_000
                        ) -> List[RequestState]:
-        """Drain queue + slots; returns every request finished on the way
-        (DONE order). Timed-out requests surface through their states."""
+        """Drain queue + slots and fold the last step in flight: nothing
+        is left unfetched. Returns every request finished on the way (DONE
+        order). Timed-out requests surface through their states."""
         finished: List[RequestState] = []
         steps = 0
-        while self.scheduler.has_work:
+        while self.scheduler.has_work or self._flying is not None:
             if steps >= max_steps:
                 raise RuntimeError(
                     f"serving did not drain within {max_steps} steps"
@@ -1336,6 +1434,7 @@ class ServingEngine:
                     vec(jnp.bool_), vec(jnp.bool_), vec(jnp.int32),
                     vec(jnp.int32), vec(jnp.uint32, 2), vec(jnp.float32),
                     vec(jnp.int32), vec(jnp.float32), vec(jnp.float32),
+                    vec(jnp.bool_), *map(sds, self._prev),
                 )
         finally:
             # lowering may re-trace; that is not a recompile of the step
@@ -1581,6 +1680,9 @@ def trace_serving_step(model, ds_config, topology: Optional[MeshTopology]
         ("top_k", sds((N,), jnp.int32, P())),
         ("top_p", sds((N,), jnp.float32, P())),
         ("rep_penalty", sds((N,), jnp.float32, P())),
+        ("from_prev", sds((N,), jnp.bool_, P())),
+        ("prev_tok", sds((N, max_draft + 1), jnp.int32, P())),
+        ("prev_rng", sds((N, 2), jnp.uint32, P())),
     )
     args = tuple(v for _, v in named_args)
     if paged:
@@ -1653,7 +1755,7 @@ def trace_serving_step(model, ds_config, topology: Optional[MeshTopology]
         lo += n
     required = [
         "tokens", "num_new", "start_pos", "fresh", "sample_flag",
-        "spec_len", "eos_id", "rng",
+        "spec_len", "eos_id", "rng", "from_prev", "prev_tok", "prev_rng",
     ]
     if paged:
         required += ["page_table", "cow_src"]

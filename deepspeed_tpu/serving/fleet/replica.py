@@ -70,8 +70,8 @@ class ReplicaHandle:
         correctness over placement, the same rule as the prefix-cache and
         spec bypasses."""
         out = []
-        for st in self.engine.scheduler.slots:
-            if st is None or st.status is not RequestStatus.DECODE:
+        for st in self.engine.scheduler.live:
+            if st.status is not RequestStatus.DECODE:
                 continue
             if st.request.repetition_penalty != 1.0:
                 continue

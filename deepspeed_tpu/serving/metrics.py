@@ -93,6 +93,11 @@ class ServingMetrics:
         self.steps = 0
         self.tokens_out = 0
         self.scheduled_tokens = 0     # real tokens fed (prefill + decode)
+        #   whose step was folded; a discarded row's are not among them
+        self.overlapped_steps = 0     # steps dispatched while the one
+        #   before was not yet fetched (of ``steps``)
+        self.discarded_rows = 0       # rows computed for a request that
+        #   had ended or gone by the time its step was folded
         # paged arena / prefix cache
         self.prefix_lookups = 0       # slot admissions that consulted it
         self.prefix_hits = 0          # admissions with >= 1 cached token
@@ -200,7 +205,15 @@ class ServingMetrics:
                 occupancy: int = 0) -> None:
         self.queue_depth = queue_depth
         self.slot_occupancy = occupancy / max(self._max_slots, 1)
-        self.scheduled_tokens += plan.total_tokens
+
+    def on_rows(self, tokens: int, discarded: int = 0) -> None:
+        """One folded step: the real tokens of the rows whose results
+        reached their requests, and the rows that reached nobody (their
+        request ended or was evicted while the step was in flight). Booked
+        at the fold, with ``steps``, so the two always describe the same
+        steps."""
+        self.scheduled_tokens += int(tokens)
+        self.discarded_rows += int(discarded)
 
     def on_token(self, state, now: float) -> None:
         """One EMITTED token (fires once per token, not per step — a
@@ -367,9 +380,11 @@ class ServingMetrics:
         self._num_pages = max(int(num_pages), 0)
         self._host_pages = max(int(host_pages), 0)
 
-    def on_step(self, filtered: bool = False) -> None:
+    def on_step(self, filtered: bool = False,
+                overlapped: bool = False) -> None:
         self.steps += 1
         self.filter_steps += bool(filtered)
+        self.overlapped_steps += bool(overlapped)
 
     def on_keys(self, kind: str, attended: int, fetched: int) -> None:
         """One step's attention work in one layer of ``kind``."""
@@ -395,6 +410,8 @@ class ServingMetrics:
             "steps": self.steps,
             "tokens_out": self.tokens_out,
             "scheduled_tokens": self.scheduled_tokens,
+            "overlapped_steps": self.overlapped_steps,
+            "discarded_rows": self.discarded_rows,
             "queue_depth": self.queue_depth,
             "slot_occupancy": self.slot_occupancy,
             "tokens_per_s": self.tokens_per_s(),
@@ -567,10 +584,11 @@ class FleetMetrics:
     # replica counters that sum into the fleet snapshot
     _SUM_KEYS = (
         "submitted", "admitted", "rejected", "evicted", "finished",
-        "steps", "tokens_out", "scheduled_tokens", "prefix_hits",
-        "cached_prompt_tokens", "cow_copies", "prefill_chunks",
-        "cached_tail_feeds", "spec_steps", "draft_tokens_proposed",
-        "draft_tokens_accepted", "pages_in_use", "pages_spilled",
+        "steps", "tokens_out", "scheduled_tokens", "overlapped_steps",
+        "discarded_rows", "prefix_hits", "cached_prompt_tokens",
+        "cow_copies", "prefill_chunks", "cached_tail_feeds", "spec_steps",
+        "draft_tokens_proposed", "draft_tokens_accepted", "pages_in_use",
+        "pages_spilled",
         "pages_promoted", "spill_bytes", "promote_bytes",
         "host_prefix_hits", "host_cached_prompt_tokens",
         "host_pages_resident",

@@ -28,6 +28,7 @@ testable with a fake clock.
 
 from __future__ import annotations
 
+import copy
 import time
 from dataclasses import dataclass, field
 from itertools import chain
@@ -91,6 +92,9 @@ class StepPlan:
     #   page to copy-on-write onto the slot's frontier page (-1 = none)
     spec_len: Optional[np.ndarray] = None    # [max_slots] int32 draft
     #   tokens per row (speculative decoding; None/zeros = plain)
+    from_prev: Optional[np.ndarray] = None   # [max_slots] bool: the row's
+    #   token and the slot's key are outputs of the step in flight (the
+    #   device takes them from there; the host has not seen them yet)
     work: List[ScheduledWork] = field(default_factory=list)
     stage: List[StagedPage] = field(default_factory=list)  # tiered KV:
     #   <= STAGE_SLOTS host pages promoting under this step (may be
@@ -137,6 +141,16 @@ class Scheduler:
         self._fresh: set = set()  # slots allocated since their first step
         self._decode_rr = 0  # rotating decode start: fairness when the
                              # token budget cannot cover every decode slot
+        # ---- plans dispatched and not yet folded (complete()), oldest
+        # first, as far as a caller said so: ``plan(ahead_of=p)`` names p
+        # as in flight and the plan it returns as the next; plan /
+        # complete in turn leaves this empty
+        self._in_flight: List[StepPlan] = []
+        self._held: Dict[int, bool] = {}  # slot -> insert_prefix: releases
+        #   put off because a plan in flight still names the slot; its
+        #   request is gone, a husk holds the pages until that plan folds
+        self._blocked = 0  # live slots the last _build_plan skipped for
+        #   want of pages (or waiting on the host tier)
         # ---- speculative decoding (serving.spec): each decode slot may
         # claim up to spec_max_draft draft rows on top of its committed
         # feed — a spec slot costs k+1 budget rows; under pressure k
@@ -272,12 +286,26 @@ class Scheduler:
         requests (``insert_prefix``), publish its pages to the prefix
         cache first so identical prompts skip their prefill entirely."""
         state = self.slots[slot]
-        if state is not None:
-            self.slots[slot] = None
-            self._free.append(slot)
-            self._fresh.discard(slot)
-            if self.paged:
-                self._release_pages(state, insert=insert_prefix)
+        if state is None:
+            return
+        if slot in self._named():
+            # a step in flight still reads and writes this slot's pages:
+            # the slot and the pages stay held, by a husk of the request
+            # (its pages, its tokens for the prefix cache), until that
+            # step is folded; the request itself is free to go
+            self._held[slot] = insert_prefix
+            self.slots[slot] = copy.copy(state)
+            state.pages, state.win_pages, state.host_pages = [], [], {}
+            return
+        self.slots[slot] = None
+        self._free.append(slot)
+        self._fresh.discard(slot)
+        if self.paged:
+            self._release_pages(state, insert=insert_prefix)
+
+    def _named(self) -> set:
+        """The slots that a plan in flight names."""
+        return {w.slot for p in self._in_flight for w in p.work}
 
     # ------------------------------------------------------------- pages
     def _release_pages(self, state: RequestState, insert: bool) -> None:
@@ -625,8 +653,17 @@ class Scheduler:
         return sum(1 for s in self.slots if s is not None)
 
     @property
+    def live(self) -> List[RequestState]:
+        """The slotted requests (a husk that holds a slot for a step in
+        flight is no request any more)."""
+        return [st for slot, st in enumerate(self.slots)
+                if st is not None and slot not in self._held]
+
+    @property
     def has_work(self) -> bool:
-        return bool(self.queue) or self.active_count > 0
+        """Something queued, slotted, or dispatched and not yet folded."""
+        return (bool(self.queue) or self.active_count > 0
+                or bool(self._in_flight))
 
     def _plan_promotions(self) -> List[StagedPage]:
         """Drain waiting host pages into this step's staging slots
@@ -704,8 +741,25 @@ class Scheduler:
                     self._promote_focus = None
         return stage
 
-    def plan(self) -> Optional[StepPlan]:
-        """Build the next step's fixed-shape work, or None when idle."""
+    def plan(self, ahead_of: Optional[StepPlan] = None
+             ) -> Optional[StepPlan]:
+        """Build the next step's fixed-shape work, or None when idle.
+
+        ``ahead_of`` is a plan whose step is in flight: dispatched, not
+        yet folded by :meth:`complete`. The work is then planned from the
+        state AS THAT STEP WILL LEAVE IT, as far as the host can know it
+        without the step's results: a prompt's frontier past its chunk, a
+        final chunk's slot in decode, each sampling slot one token longer
+        (so a slot that ``max_new_tokens`` ends there is not planned
+        again). The token itself and the slot's advanced key stay on the
+        device: the row is flagged ``from_prev``. A request with an eos is
+        assumed to live; :meth:`complete` drops the row if it did not."""
+        if ahead_of is not None and (self.spec_max_draft or self.spiller):
+            raise ValueError(
+                "plan(ahead_of=...): a verify window's advance and a "
+                "staging buffer's hand-back are known only at the fold"
+            )
+        self._in_flight = [] if ahead_of is None else [ahead_of]
         now = self.clock()
         self._ticks += 1
         self._plan_protect = set()
@@ -719,7 +773,11 @@ class Scheduler:
         # backoff — and retry, so the oldest requests always finish. The
         # config floor num_pages >= pages_per_slot makes this terminate
         # with at least one schedulable request.
-        while plan is None and self.paged and self.active_count > 0:
+        # With a step in flight nobody is evicted on a projection: the
+        # empty plan makes the caller fold that step (which may free pages,
+        # and lets every release it put off happen), and the next plan
+        # judges starvation on what is really there.
+        while plan is None and self._blocked and not self._in_flight:
             victim = max(
                 (s for s in self.slots if s is not None),
                 key=lambda s: (s.prefill_start_t or 0.0, s.slot),
@@ -755,6 +813,8 @@ class Scheduler:
                 if self.window:
                     self.metrics.on_window_pages(
                         self.window_pool, self.window_pages_released)
+        if plan is not None and ahead_of is not None:
+            self._in_flight.append(plan)
         if plan is not None and self.metrics is not None:
             self.metrics.on_plan(plan, now, queue_depth=len(self.queue),
                                  occupancy=self.active_count)
@@ -780,34 +840,54 @@ class Scheduler:
             ),
             cow_src=np.full(N, -1, np.int32) if self.paged else None,
             spec_len=np.zeros(N, np.int32),
+            from_prev=np.zeros(N, np.bool_),
             stage=list(stage) if stage else [],
         )
         budget = W
+        self._blocked = 0
+        # what the step in flight adds to each slot it names (a slot whose
+        # request has gone since is a husk: nothing is planned for it)
+        ahead = {
+            w.slot: w for p in self._in_flight for w in p.work
+            if self.slots[w.slot] is w.state
+        }
         # decodes first: latency-critical, one committed feed each. The
         # scan starts at a ROTATING index so a budget smaller than the
         # decode count round-robins across steps instead of
         # deterministically starving the high-index slots.
-        decodes: List[list] = []  # [slot, state, pos, cow, k]
+        decodes: List[list] = []  # [slot, state, pos, cow, k, pending]
         for off in range(N):
             slot = (self._decode_rr + off) % N
             state = self.slots[slot]
-            if state is None or state.status is not RequestStatus.DECODE:
+            if state is None or slot in self._held:
                 continue
+            w = ahead.get(slot)
+            # tokens the step in flight samples for the slot: its value is
+            # still on the device, its place in the sequence is known
+            pending = w is not None and w.sample
+            if state.status is not RequestStatus.DECODE and not (
+                    state.status is RequestStatus.PREFILL and pending):
+                continue  # (a final chunk in flight leaves a decode slot)
+            n_tok = len(state.tokens) + pending
+            if pending and n_tok >= state.request.max_new_tokens:
+                continue  # the step in flight ends it
             if state.host_pages:
+                self._blocked += 1
                 continue  # tiered: waiting on promotion — attention
                 #   gathers the whole sequence, so a slot with ANY page
                 #   still on host cannot schedule this step
             if budget < 1:
                 break
-            pos = state.prompt_len + len(state.tokens) - 1
+            pos = state.prompt_len + n_tok - 1
             cow = -1
             if self.paged:
                 ok, cow = self._prepare_pages(state, pos, 1)
                 if ok < 1:
+                    self._blocked += 1
                     continue  # page pressure: this decode waits a step
             self._plan_protect.add(id(state))
             state.last_planned = self._ticks
-            decodes.append([slot, state, pos, cow, 0])
+            decodes.append([slot, state, pos, cow, 0, pending])
             budget -= 1
         self._decode_rr = (self._decode_rr + 1) % N
         # speculative drafts ride WITH the decode pass: a spec slot's row
@@ -817,8 +897,10 @@ class Scheduler:
         # step shape never changes
         if self.spec_max_draft > 0 and budget > 0 and decodes:
             budget = self._assign_drafts(decodes, budget)
-        for slot, state, pos, cow, k in decodes:
-            row = [state.tokens[-1]]
+        for slot, state, pos, cow, k, pending in decodes:
+            # (fed by the step in flight: the device fills column 0)
+            plan.from_prev[slot] = pending
+            row = [0 if pending else state.tokens[-1]]
             if k > 0:
                 drafts = propose_drafts(
                     state.request.prompt, state.tokens, state.draft_tail,
@@ -848,8 +930,10 @@ class Scheduler:
         prefills = sorted(
             (
                 (slot, state) for slot, state in enumerate(self.slots)
-                if state is not None
+                if state is not None and slot not in self._held
                 and state.status is RequestStatus.PREFILL
+                # (its final chunk in flight: a decode slot, above)
+                and not (slot in ahead and ahead[slot].sample)
             ),
             key=lambda it: (it[1].prefill_start_t, it[0]),
         )
@@ -857,14 +941,18 @@ class Scheduler:
             if budget < 1:
                 break
             if state.host_pages:
+                self._blocked += 1
                 continue  # tiered: prefix tail still on host — the write
                 #   frontier sits past pages that must promote first
-            chunk = min(budget, state.prompt_remaining, W)
-            lo = state.prompt_pos
+            # the frontier, past the chunk the step in flight feeds
+            lo = state.prompt_pos + (
+                ahead[slot].n_tokens if slot in ahead else 0)
+            chunk = min(budget, state.prompt_len - lo, W)
             cow = -1
             if self.paged:
                 chunk, cow = self._prepare_pages(state, lo, chunk)
                 if chunk < 1:
+                    self._blocked += 1
                     continue  # page pressure: the prompt waits a step
             self._plan_protect.add(id(state))
             state.last_planned = self._ticks
@@ -923,7 +1011,7 @@ class Scheduler:
             for item in decodes:
                 if budget < 1:
                     break
-                slot, state, pos, cow, k = item
+                slot, state, pos, cow, k, _ = item
                 req = state.request
                 if req.repetition_penalty != 1.0:
                     continue
@@ -949,7 +1037,8 @@ class Scheduler:
                  n_emit: Optional[np.ndarray] = None
                  ) -> List[RequestState]:
         """Fold one executed step back into request state. Returns the
-        requests that finished this step (slots already recycled).
+        requests that finished this step (slots already recycled, unless
+        a later plan in flight still names them: then at its fold).
 
         ``next_tokens`` is the engine's verify-window output
         ``[max_slots, max_draft + 1]`` with ``n_emit`` tokens emitted
@@ -962,8 +1051,17 @@ class Scheduler:
             next_tokens = next_tokens[:, None]
         now = self.clock()
         finished: List[RequestState] = []
+        self._in_flight = [p for p in self._in_flight if p is not plan]
+        rows = discarded = 0
         for w in plan.work:
             st = w.state
+            if self.slots[w.slot] is not st:
+                # the request went while this step was in flight (an eos
+                # the plan could not know of, an eviction): what the step
+                # computed for it reaches nobody
+                discarded += 1
+                continue
+            rows += w.n_tokens
             if w.n_tokens and st.status is RequestStatus.PREFILL:
                 st.prompt_pos += w.n_tokens
             if not w.sample:
@@ -1023,9 +1121,15 @@ class Scheduler:
                 self.spiller.drop(s.key)
             elif self.prefix_cache is not None:
                 self.prefix_cache.unpin_host(s.key)
+        # releases put off for this step happen now (a later plan in
+        # flight never names a husk)
+        named = self._named()
+        for slot in [s for s in self._held if s not in named]:
+            self.release(slot, insert_prefix=self._held.pop(slot))
         if self.paged:
             self.assert_page_invariants()
         if self.metrics is not None:
+            self.metrics.on_rows(rows, discarded)
             for st in finished:
                 self.metrics.on_finish(st, now)
         return finished

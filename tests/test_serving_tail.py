@@ -81,7 +81,13 @@ def parent_step_fn(cfg, dtype, vocab, cache_shardings=None, max_draft=0):
 
     def step(params, caches, seen, tokens, num_new, start_pos, fresh,
              sample_flag, spec_len, eos_id, rng, temperature, top_k, top_p,
-             rep_penalty, page_table=None, page_table_win=None):
+             rep_penalty, from_prev, prev_tok, prev_rng, page_table=None,
+             page_table_win=None):
+        # (ISSUE 37's operands, as the engine now hands them to any step:
+        # a row fed by the step in flight takes its token and key there)
+        tokens = tokens.at[:, 0].set(
+            jnp.where(from_prev, prev_tok[:, 0], tokens[:, 0]))
+        rng = jnp.where(from_prev[:, None], prev_rng, rng)
         live = sample_flag & (num_new > 0)
         seen = _book_seen(seen, tokens, num_new, spec_len, fresh, vocab)
         logits, caches = forward_with_cache(

@@ -191,6 +191,13 @@ PARENT_TEMP_GIB = {"mixtral": 1.32, "mellum": 1.89}
 # tests on PR 34's tree): each held the float32 [N, W, V] logits
 WHOLE_CHUNK_TEMP_BYTES = {"mixtral": 298311680, "mellum": 818951680,
                           "deepseek": 340044288}
+# and before the step took the token and the key of a row from the step in
+# flight (the same tests on PR 36's tree, as they print it): three small
+# operands more may not cost a buffer of any size that counts. (The chunk's
+# tokens are a computed [N, W] array now and no longer the argument's
+# buffer; the compiler's layout of the rest moves by 0.16 MB for Mixtral,
+# 2.7 MB for Mellum, 0.14 MB for DeepSeek: under a hundredth.)
+PR36_TEMP_MB = {"mixtral": 52.3, "mellum": 374.7, "deepseek": 339.9}
 
 
 def _pool_copies(text, caches):
@@ -245,6 +252,7 @@ def _check_head_runs_over_the_window(compiled, N, W, V, family, capsys):
         if np.prod([int(d) for d in dims.split(",")]) == N * W * V]
     assert held == []
     assert m.temp_size_in_bytes < WHOLE_CHUNK_TEMP_BYTES[family]
+    assert m.temp_size_in_bytes / 1e6 < PR36_TEMP_MB[family] * 1.01
 
 
 def _compile_slot_step(model, caches, one_chip, N, W, mp):
@@ -274,6 +282,8 @@ def _compile_slot_step(model, caches, one_chip, N, W, mp):
             vec(I32), vec(I32), *tables, vec(I32),
             vec(jnp.bool_), vec(jnp.bool_), vec(I32), vec(I32),
             vec(jnp.uint32, 2), vec(F32), vec(I32), vec(F32), vec(F32),
+            # the row flag, and the step in flight's tokens and keys
+            vec(jnp.bool_), vec(I32, 1), vec(jnp.uint32, 2),
         ).compile()
 
 
