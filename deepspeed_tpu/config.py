@@ -702,10 +702,13 @@ class ServingConfig:
 class SteptraceConfig:
     """"steptrace" section — structured span tracing + the process-global
     metrics registry (profiling/steptrace.py, docs/observability.md).
-    Host-side only: spans bracket dispatches and fence with
-    ``jax.block_until_ready`` at close; nothing is traced inside jitted
-    programs. MUST be zero-overhead when disabled — engines keep
-    ``tracer = None`` and allocate no spans."""
+    Host-side only: spans bracket dispatches; nothing is traced inside
+    jitted programs. The section gates the REGISTRY (and the one span
+    that fences, ``train/device``): disabled, engines keep
+    ``tracer = None``, no registry exists and nothing is stored. The span
+    sites themselves always feed the profiler's trace
+    (``steptrace.Phase``), which costs about a microsecond a span while
+    no profile is being taken."""
 
     enabled: bool = False
     max_spans: int = 100_000   # registry bound (spans / async events /

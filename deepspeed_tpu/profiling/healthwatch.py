@@ -55,11 +55,12 @@ goodput buckets are classified straight off the engine's own spans):
   ``train/* serve/* comm/* plan/* health/*`` namespaces, so one scrape
   answers "is it healthy".
 
-Zero overhead when disabled (the steptrace NULL-object discipline):
+Zero overhead when disabled:
 engines keep ``healthwatch = None``, no ring deque is allocated, no
-span is added, no device scalar is read (``DEVICE_TAPS`` stays put),
-and the compiled step program is untouched — the loss trajectory is
-bitwise identical to an engine with no healthwatch section at all
+registry comes into being for its sake, no device scalar is read
+(``DEVICE_TAPS`` stays put), and the compiled step program is
+untouched — the loss trajectory is bitwise identical to an engine with
+no healthwatch section at all
 (tests/test_healthwatch.py). Config gate::
 
     {"healthwatch": {"enabled": true, "ring_steps": 64,

@@ -7,12 +7,22 @@ wall-clock timers (utils/timer.py) and the shardplan drift ledger
 step's time go, and does it match what shardplan predicted?". This
 module is the substrate they all feed into:
 
-- **Spans** are host-side wall-clock intervals (``time.perf_counter``
-  monotonic clocks) bracketing *dispatches*. Nothing traces inside a
-  jitted program: a span that should be charged with device work fences
-  via ``jax.block_until_ready`` at close (``Span.end(fence=out)``), so
-  async-dispatched work is attributed to the span that launched it —
-  the same discipline utils/timer.py's ``block_on`` uses.
+- **Spans** are host-side intervals bracketing *dispatches*; nothing
+  traces inside a jitted program. A span site is written ONCE, as a
+  :class:`Phase`, and feeds two sinks: always a
+  ``jax.profiler.TraceAnnotation`` of the span's name and arguments (so
+  whenever a profile is being taken the span lies on the profiler's
+  clock, on the host plane beside the device's operations; free while
+  none is), and the :class:`MetricsRegistry` on ``time.perf_counter``
+  when the engine has configured one. The profiler being on is the one
+  switch of the first sink, the ``"steptrace"`` section of the second.
+- A span that should be charged with device work fences:
+  ``Span.end(fence=out)`` blocks on ``out`` first (the discipline
+  utils/timer.py's ``block_on`` uses). A fence changes the run, so the one
+  site that blocks where the program otherwise would not,
+  ``train/device``, exists when, and only when, the registry is on (it is
+  a :class:`Phase` like the others, so it is then on the profile too);
+  ``serve/device`` wraps a wait the serving turn makes anyway.
 - The **MetricsRegistry** is process-global (one trace per process, the
   way ``jax.profiler`` works): engines call :func:`configure` and share
   it, so a serving replay and the comms logger land on one timeline.
@@ -36,12 +46,16 @@ module is the substrate they all feed into:
   a per-component one: rule R8's "this overlap is real" claim becomes
   inspectable per stream.
 
-Zero overhead when disabled: engines keep ``tracer = None`` and every
-instrumentation site is a ``if tracer is not None`` guard — no span
-objects, no per-token allocation, nothing inside jitted code. The
-config gate is the ``"steptrace"`` section (config.py):
-``{"steptrace": {"enabled": true, "max_spans": 100000,
-"export_path": "trace.json"}}``.
+What a run pays. With no profile being taken and no ``"steptrace"``
+section, a span site costs one inactive ``TraceAnnotation`` (about 1 us:
+a serving turn opens five, a training step four), no registry exists
+(``get_registry() is None``), nothing is stored and nothing is fenced.
+The config gate of the registry is the ``"steptrace"`` section
+(config.py): ``{"steptrace": {"enabled": true, "max_spans": 100000,
+"export_path": "trace.json"}}``. Registry-only, because they are no
+intervals of the host's thread: the request trees of
+:class:`ServeTracer` (async events keyed by request id), ``plan/*``
+predictions, metric samples.
 
 See docs/observability.md for the span model and the Perfetto
 walkthrough.
@@ -55,35 +69,13 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
-    "MetricsRegistry", "Span", "ServeTracer", "NULL_SPAN",
-    "configure", "get_registry", "reset", "tracer_from_config",
+    "MetricsRegistry", "Span", "Phase", "ServeTracer",
+    "configure", "get_registry", "reset",
     "write_events", "stream_span_args",
 ]
-
-
-class _NullSpan:
-    """Shared no-op span: the disabled path allocates nothing per call."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def end(self, fence=None):
-        pass
-
-    def cancel(self):
-        pass
-
-    def annotate(self, **kw):
-        pass
-
-
-NULL_SPAN = _NullSpan()
 
 
 class Span:
@@ -94,13 +86,14 @@ class Span:
     __slots__ = ("_reg", "name", "cat", "args", "tid", "t0", "t1", "_open")
 
     def __init__(self, reg: "MetricsRegistry", name: str, cat: str,
-                 args: Optional[Dict[str, Any]], tid):
+                 args: Optional[Dict[str, Any]], tid,
+                 t0: Optional[float] = None):
         self._reg = reg
         self.name = name
         self.cat = cat
         self.args = args
         self.tid = tid
-        self.t0 = reg.clock()
+        self.t0 = reg.clock() if t0 is None else t0
         self.t1 = None
         self._open = True
 
@@ -132,6 +125,76 @@ class Span:
         return False
 
 
+class Phase:
+    """One span site, two sinks: THE way an engine opens a span.
+
+    Opening enters a ``jax.profiler.TraceAnnotation`` named ``name`` with
+    ``args`` (on the profiler's host plane whenever a profile is being
+    taken, about a microsecond when none is) and, when ``registry`` is a
+    :class:`MetricsRegistry`, begins the same interval there; ``registry``
+    is None for an engine that configured none. :meth:`annotate` adds
+    arguments known only later (``traced=``, ``scheduled_tokens=``) to
+    both. Nothing here fences: a site that must block on the device says
+    so in its own code (``train/device`` blocks inside its ``with``, and
+    is opened only when the registry is on). Sites use it as a ``with``
+    block, so a body that raises still closes both sinks and the thread's
+    annotations stay nested."""
+
+    __slots__ = ("_ann", "_sp")
+
+    def __init__(self, registry: Optional["MetricsRegistry"], name: str,
+                 cat: str = "train", **args):
+        # the registry's clock is read before anything is allocated: what
+        # opening costs (a collection of garbage, once in a while) belongs
+        # inside the span, or a step's children would not cover it
+        t0 = registry.clock() if registry is not None else None
+        self._ann = TraceAnnotation(name, **args)
+        self._ann.__enter__()
+        self._sp = (registry.begin(name, cat, args or None, t0)
+                    if registry is not None else None)
+
+    @property
+    def t0(self) -> Optional[float]:
+        """Opening time on the registry's clock (None without one)."""
+        return self._sp.t0 if self._sp is not None else None
+
+    @property
+    def t1(self) -> Optional[float]:
+        return self._sp.t1 if self._sp is not None else None
+
+    def annotate(self, **kw) -> None:
+        if self._ann is not None:
+            self._ann.set_metadata(**kw)
+        if self._sp is not None:
+            self._sp.annotate(**kw)
+
+    def _close(self) -> None:
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+
+    def end(self) -> None:
+        if self._sp is not None:
+            self._sp.end()
+        self._close()
+
+    def cancel(self) -> None:
+        """Drop the registry's span unrecorded. An annotation cannot be
+        taken back once entered: it closes under its name, so a site that
+        may cancel is opened only when that is rare and harmless (see
+        ``ServingEngine._step_inner``)."""
+        if self._sp is not None:
+            self._sp.cancel()
+        self._close()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.end()
+        return False
+
+
 class MetricsRegistry:
     """Process-global span + metric-event store with Chrome export.
 
@@ -151,27 +214,14 @@ class MetricsRegistry:
 
     # ------------------------------------------------------------- spans
     def begin(self, name: str, cat: str = "train",
-              args: Optional[Dict[str, Any]] = None) -> Span:
-        return Span(self, name, cat, args, threading.get_ident())
+              args: Optional[Dict[str, Any]] = None,
+              t0: Optional[float] = None) -> Span:
+        return Span(self, name, cat, args, threading.get_ident(), t0)
 
     def span(self, name: str, cat: str = "train",
              args: Optional[Dict[str, Any]] = None) -> Span:
         """Context-manager form: ``with reg.span("train/step"): ...``"""
         return self.begin(name, cat, args)
-
-    def trace(self, name: str, cat: str = "train"):
-        """Decorator form: the wrapped call body becomes one span."""
-
-        def deco(fn):
-            def wrapped(*a, **kw):
-                with self.span(name, cat):
-                    return fn(*a, **kw)
-
-            wrapped.__name__ = getattr(fn, "__name__", "traced")
-            wrapped.__doc__ = fn.__doc__
-            return wrapped
-
-        return deco
 
     def _record(self, span: Span) -> None:
         with self._lock:
@@ -235,13 +285,6 @@ class MetricsRegistry:
                 return
             self.samples.append((tag, float(value), step, self.clock()))
 
-    def samples_since(self, cursor: int):
-        """(new_cursor, samples[cursor:]) — the healthwatch exporter's
-        incremental intake: each flush picks up only the metric samples
-        recorded since its last one."""
-        with self._lock:
-            return len(self.samples), list(self.samples[cursor:])
-
     def write_events(self, monitor, events) -> None:
         """THE monitor bridge: record the (tag, value, step) triples as
         registry samples, then forward to the monitor backends (no-op
@@ -282,38 +325,6 @@ class MetricsRegistry:
             t0 + max(args["predicted_s_per_step"], 1e-6), args=args,
             tid="plan",
         )
-
-    def phase_table(self, prefix: Optional[str] = None, topk: int = 16
-                    ) -> str:
-        """Per-phase aggregate over recorded spans: count, total, mean,
-        and share of the trace window — the host-side answer to "where
-        did the time go"."""
-        agg: Dict[str, List[float]] = {}
-        for s in self.spans:
-            if prefix and not s["name"].startswith(prefix):
-                continue
-            agg.setdefault(s["name"], []).append(s["t1"] - s["t0"])
-        if not agg:
-            return "steptrace: no spans recorded"
-        window = max(
-            (s["t1"] for s in self.spans), default=self.clock()
-        ) - min((s["t0"] for s in self.spans), default=self.t_origin)
-        lines = [
-            f"{'phase':<28}{'count':>7}{'total ms':>12}{'mean ms':>10}"
-            f"{'% window':>10}"
-        ]
-        rows = sorted(agg.items(), key=lambda kv: -sum(kv[1]))[:topk]
-        for name, durs in rows:
-            total = sum(durs)
-            lines.append(
-                f"{name:<28}{len(durs):>7}{total * 1e3:>12.2f}"
-                f"{total / len(durs) * 1e3:>10.2f}"
-                f"{100.0 * total / window if window > 0 else 0.0:>10.1f}"
-            )
-        if self.dropped:
-            lines.append(f"(dropped {self.dropped} entries past "
-                         f"max_spans={self.max_spans})")
-        return "\n".join(lines)
 
     # ------------------------------------------------------------ export
     def to_chrome(self) -> Dict[str, Any]:
@@ -402,25 +413,6 @@ def reset() -> None:
     """Drop the global registry (tests; a fresh trace per scenario)."""
     global _GLOBAL
     _GLOBAL = None
-
-
-def tracer_from_config(section) -> Optional[MetricsRegistry]:
-    """The config gate: ``None`` (tracing disabled — the zero-overhead
-    path; instrumentation sites guard on it) or the configured global
-    registry. ``section`` is a SteptraceConfig, a dict, or None."""
-    if section is None:
-        return None
-    enabled = bool(
-        section.get("enabled", False) if isinstance(section, dict)
-        else getattr(section, "enabled", False)
-    )
-    if not enabled:
-        return None
-    max_spans = int(
-        section.get("max_spans", 100_000) if isinstance(section, dict)
-        else getattr(section, "max_spans", 100_000)
-    )
-    return configure(max_spans=max_spans)
 
 
 def write_events(monitor, events) -> None:

@@ -31,6 +31,7 @@ from .. import comm
 from ..comm.topology import MeshTopology, ParallelDims
 from ..config import DeepSpeedConfig
 from ..models.sharding import use_topology
+from ..profiling.steptrace import Phase
 from ..utils.logging import log_dist
 from ..utils.timer import SynchronizedWallClockTimer, ThroughputTimer
 from ..utils.tree import global_norm, tree_cast
@@ -270,9 +271,10 @@ class TpuEngine:
 
             self.monitor = MonitorMaster(config.monitor)
         self.comm_logger = None
-        # steptrace (config-gated; docs/observability.md). None is the
-        # zero-overhead path: every instrumentation site guards on it,
-        # so no span ever allocates. Abstract (lint) shells never trace.
+        # steptrace's registry (config-gated; docs/observability.md).
+        # None: no registry exists and nothing is stored; the span sites
+        # still feed the profiler's trace (steptrace.Phase). Abstract
+        # (lint) shells never trace.
         self.tracer = None
         self._steptrace_export_path = None
         if config.steptrace.enabled and not self.abstract:
@@ -2183,10 +2185,8 @@ class TpuEngine:
                 raise ValueError("train_batch needs data_iter or batch")
             # input-wait instrumentation (ISSUE 11): the iterator pull is
             # the data stall — healthwatch's stall_on_data goodput bucket
-            in_sp = tr.begin("train/input_wait", "train") if tr else None
-            batch = self._next_batch(data_iter)
-            if in_sp is not None:
-                in_sp.end()
+            with Phase(tr, "train/input_wait"):
+                batch = self._next_batch(data_iter)
         if "labels" not in batch:
             from ..models.transformer import make_lm_batch
 
@@ -2214,18 +2214,33 @@ class TpuEngine:
                 k: (np.asarray(v)[:, :difficulty] if np.asarray(v).ndim >= 2 else v)
                 for k, v in batch.items()
             }
+        traces_before = self.step_traces
+        with Phase(tr, "train/step", step=self.global_steps + 1):
+            metrics = self._host_step(batch)
+        if hw is not None:
+            # healthwatch tick AFTER the step span closed: the device
+            # fence already ran, so the loss/grad taps read finished
+            # values (exactly 2 host scalar transfers per step)
+            hw.on_train_step(
+                step=self.global_steps,
+                loss=metrics["loss"],
+                grad_norm=metrics["grad_norm"],
+                compiled=self.step_traces - traces_before,
+            )
+        return metrics["loss"]
+
+    def _host_step(self, batch):
+        """The host's side of one step, inside ``train/step``: lay the
+        batch out, hand the jitted step over, commit what it returned.
+        Every phase is a ``with`` block, so a body that raises (a failed
+        compile, an OOM) still closes its span in both sinks."""
+        tr = self.tracer
         breakdown = self.config.wall_clock_breakdown
-        step_sp = (
-            tr.begin("train/step", "train", {"step": self.global_steps + 1})
-            if tr else None
-        )
         if breakdown:
             self.timers("batch_prep").start()
-        prep_sp = tr.begin("train/batch_prep", "train") if tr else None
-        prepared = self._prepare_batch(batch)
-        self._last_seq = int(prepared["input_ids"].shape[-1])
-        if prep_sp is not None:
-            prep_sp.end()
+        with Phase(tr, "train/batch_prep"):
+            prepared = self._prepare_batch(batch)
+            self._last_seq = int(prepared["input_ids"].shape[-1])
         if breakdown:
             self.timers("batch_prep").stop()
         ltd_keep = None
@@ -2249,42 +2264,31 @@ class TpuEngine:
                 # fence (a fence here would serialize the swap-in against
                 # the device work — the very overlap being traced); the
                 # train/device span at the bottom owns the blocking wait.
-                sp = tr.begin("train/fwd_bwd_dispatch", "train") if tr \
-                    else None
-                grads, loss, mmetrics = self._jit_grads(
-                    self.state.params, self.state.loss_scale, self.state.step,
-                    prepared, self.next_rng(), ltd_keep,
-                )
-                if sp is not None:
-                    if self.step_traces != traces_before:
-                        # a retrace happened inside this dispatch —
-                        # healthwatch books the span as compile time
-                        sp.annotate(traced=self.step_traces - traces_before)
-                    sp.end()
-                    sp = tr.begin("train/offload_swap_in", "train")
-                self._swap_in_opt()
-                if sp is not None:
-                    sp.end()
-                    sp = tr.begin("train/optimizer_dispatch", "train")
-                traces_mid = self.step_traces
-                p, o, s, st, metrics = self._jit_update(
-                    *self.state.astuple(), grads, loss, mmetrics
-                )
-                if sp is not None:
-                    if self.step_traces != traces_mid:
-                        sp.annotate(traced=self.step_traces - traces_mid)
-                    sp.end()
+                # A retrace inside a dispatch: healthwatch books the span
+                # as compile time.
+                with Phase(tr, "train/fwd_bwd_dispatch") as sp:
+                    grads, loss, mmetrics = self._jit_grads(
+                        self.state.params, self.state.loss_scale,
+                        self.state.step, prepared, self.next_rng(), ltd_keep,
+                    )
+                    sp.annotate(traced=self.step_traces - traces_before)
+                with Phase(tr, "train/offload_swap_in"):
+                    self._swap_in_opt()
+                with Phase(tr, "train/optimizer_dispatch") as sp:
+                    traces_mid = self.step_traces
+                    p, o, s, st, metrics = self._jit_update(
+                        *self.state.astuple(), grads, loss, mmetrics
+                    )
+                    sp.annotate(traced=self.step_traces - traces_mid)
             else:
-                sp = tr.begin("train/dispatch", "train") if tr else None
-                p, o, s, st, metrics = self._jit_train(
-                    *self.state.astuple(), prepared, self.next_rng(), ltd_keep
-                )
-                if sp is not None:
-                    if self.step_traces != traces_before:
-                        # a retrace happened inside this dispatch —
-                        # healthwatch books the span as compile time
-                        sp.annotate(traced=self.step_traces - traces_before)
-                    sp.end()
+                with Phase(tr, "train/dispatch") as sp:
+                    p, o, s, st, metrics = self._jit_train(
+                        *self.state.astuple(), prepared, self.next_rng(),
+                        ltd_keep,
+                    )
+                    # a retrace inside the dispatch: healthwatch books the
+                    # span as compile time
+                    sp.annotate(traced=self.step_traces - traces_before)
         if tr is not None:
             # fence at close: the async-dispatched fwd/bwd/optimizer work
             # is charged to this span (utils/timer.py block_on
@@ -2292,62 +2296,56 @@ class TpuEngine:
             # replacing the old (donated) state while the step is still
             # in flight blocks inside the assignment, which would
             # silently attribute the whole device time to host work.
-            sp = tr.begin("train/device", "train")
-            jax.block_until_ready(metrics["loss"])
-            # the step's model metrics ride on the span that timed it
-            extra = {k: float(metrics[k]) for k in _SPAN_METRICS
-                     if k in metrics}
-            if extra:
-                sp.annotate(**extra)
-            sp.end()
-        self.state = TrainState(p, o, s, st)
-        if breakdown:
-            # dispatch returns immediately; a second timer blocks on the
-            # device so the pair splits host time from device time
-            self.timers("step_dispatch").stop()
-            self.timers("step_device").start()
-            self.timers("step_device").stop(block_on=metrics["loss"])
-            if (self.global_steps + 1) % self.config.steps_per_print == 0:
-                self.timers.log(["batch_prep", "step_dispatch", "step_device"])
-        if self._nvme_swapper is not None:
-            sp = tr.begin("train/offload_swap_out", "train") if tr else None
-            self._swap_out_opt(blocking=False)  # writes overlap next step
-            if sp is not None:
-                sp.end()
-        self.global_steps += 1
-        self.micro_steps += self.config.gradient_accumulation_steps
-        self._record_offload_stream(batch=prepared)
-        self._metrics = {k: v for k, v in metrics.items()}
-        # only the fp16 path reads overflow on host — a host read here forces
-        # a device sync every step and kills async dispatch overlap
-        if self.fp16_enabled and bool(metrics["overflow"]):
-            self.skipped_steps += 1
-            log_dist(
-                f"step {self.global_steps}: fp16 overflow, skipping update "
-                f"(new scale {float(metrics['loss_scale'])})"
-            )
-        if (
-            self.config.memory_breakdown
-            and self.global_steps % self.config.steps_per_print == 0
-        ):
-            from ..utils.memory import see_memory_usage
+            # A fence changes the run, so this span exists only where
+            # the registry was asked for (and then in both sinks, like any
+            # other): no fence on a path without one.
+            with Phase(tr, "train/device") as sp:
+                jax.block_until_ready(metrics["loss"])
+                # the step's model metrics ride on the span that timed it
+                extra = {k: float(metrics[k]) for k in _SPAN_METRICS
+                         if k in metrics}
+                if extra:
+                    sp.annotate(**extra)
+        # what follows the dispatch's return: the donated state's swap,
+        # the counters, the step log (0.2-0.4 ms on the chip: the swap
+        # does not wait for the step in flight there, PERF.md PR 39)
+        with Phase(tr, "train/commit"):
+            self.state = TrainState(p, o, s, st)
+            if breakdown:
+                # dispatch returns immediately; a second timer blocks on the
+                # device so the pair splits host time from device time
+                self.timers("step_dispatch").stop()
+                self.timers("step_device").start()
+                self.timers("step_device").stop(block_on=metrics["loss"])
+                if (self.global_steps + 1) % self.config.steps_per_print == 0:
+                    self.timers.log(
+                        ["batch_prep", "step_dispatch", "step_device"])
+            if self._nvme_swapper is not None:
+                with Phase(tr, "train/offload_swap_out"):
+                    # the writes overlap the next step
+                    self._swap_out_opt(blocking=False)
+            self.global_steps += 1
+            self.micro_steps += self.config.gradient_accumulation_steps
+            self._record_offload_stream(batch=prepared)
+            self._metrics = {k: v for k, v in metrics.items()}
+            # only the fp16 path reads overflow on host — a host read here
+            # forces a device sync every step and kills async dispatch overlap
+            if self.fp16_enabled and bool(metrics["overflow"]):
+                self.skipped_steps += 1
+                log_dist(
+                    f"step {self.global_steps}: fp16 overflow, skipping update "
+                    f"(new scale {float(metrics['loss_scale'])})"
+                )
+            if (
+                self.config.memory_breakdown
+                and self.global_steps % self.config.steps_per_print == 0
+            ):
+                from ..utils.memory import see_memory_usage
 
-            see_memory_usage(f"step {self.global_steps}")
-        self._emit_step_log(metrics, self.global_steps)
-        self.tput.stop()
-        if step_sp is not None:
-            step_sp.end()
-        if hw is not None:
-            # healthwatch tick AFTER the step span closed: the device
-            # fence already ran, so the loss/grad taps read finished
-            # values (exactly 2 host scalar transfers per step)
-            hw.on_train_step(
-                step=self.global_steps,
-                loss=metrics["loss"],
-                grad_norm=metrics["grad_norm"],
-                compiled=self.step_traces - traces_before,
-            )
-        return metrics["loss"]
+                see_memory_usage(f"step {self.global_steps}")
+            self._emit_step_log(metrics, self.global_steps)
+            self.tput.stop()
+        return metrics
 
     def _emit_step_log(self, metrics, step_no: int):
         """Monitor events + steps_per_print log line for one step's metrics
@@ -2942,20 +2940,18 @@ class TpuEngine:
         # (device→pinned-host copy), and the swap-out. An async save's
         # shard write lands in the background and is reported separately
         # as ckpt_write_s — charging it here would bill overlap as stall.
-        sp = (self.tracer.begin("train/checkpoint", "train")
-              if self.tracer is not None else None)
-        if self._nvme_swapper is not None:
-            self._swap_in_opt()
-        try:
-            return _save(
-                self, save_dir, tag=tag, client_state=client_state or {},
-                async_save=async_save, guard=self._ckpt_guard(),
-            )
-        finally:
+        with Phase(self.tracer, "train/checkpoint"):
             if self._nvme_swapper is not None:
-                self._swap_out_opt()  # keep "on disk between steps" invariant
-            if sp is not None:
-                sp.end()
+                self._swap_in_opt()
+            try:
+                return _save(
+                    self, save_dir, tag=tag, client_state=client_state or {},
+                    async_save=async_save, guard=self._ckpt_guard(),
+                )
+            finally:
+                if self._nvme_swapper is not None:
+                    # keep the "on disk between steps" invariant
+                    self._swap_out_opt()
 
     def load_checkpoint(self, load_dir, tag=None, strict=True):
         from .ckpt import load_checkpoint as _load

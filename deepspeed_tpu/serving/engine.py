@@ -64,6 +64,8 @@ from ..models.decoding import (SCALE_LANES, WIN, forward_with_cache,
                                record_attention_path, staged_promote,
                                verify_window_rows)
 from ..models.sharding import use_topology
+from ..profiling import steptrace as _steptrace
+from ..profiling.steptrace import Phase
 from ..utils.logging import log_dist
 from .metrics import ServingMetrics
 from .paging import STAGE_SLOTS
@@ -564,7 +566,8 @@ class _Flying:
     #   device arrays, their copies to the host begun at dispatch
     overlapped: bool  # dispatched while the step before was unfetched
     filtered: bool    # a live slot asked for top-k or top-p
-    t0: Optional[float]  # when its dispatch began (under the tracer)
+    n: int            # the step's number: dispatches are counted from 1
+    t0: Optional[float]  # when its dispatch began (the registry's clock)
 
 
 class ServingEngine:
@@ -769,8 +772,9 @@ class ServingEngine:
                 lambda ids: export_pages(self._caches, ids),
                 metrics=self.metrics,
             )
-        # ---- steptrace (config-gated; None = the zero-overhead path:
-        # no span objects exist and every site below guards on it) ------
+        # ---- steptrace: the registry is config-gated (None = no
+        # registry exists and nothing is stored; the span sites below
+        # still feed the profiler's trace, through steptrace.Phase) -----
         self.tracer = None
         self._serve_tracer = None
         self._steptrace_export_path = None
@@ -783,8 +787,6 @@ class ServingEngine:
             )
             stc.validate()
             if stc.enabled:
-                from ..profiling import steptrace as _steptrace
-
                 self.tracer = _steptrace.configure(max_spans=stc.max_spans)
                 self._serve_tracer = _steptrace.ServeTracer(self.tracer)
                 self.metrics.tracer = self._serve_tracer
@@ -804,7 +806,6 @@ class ServingEngine:
             hwc.validate()
             if hwc.enabled:
                 from ..profiling import healthwatch as _healthwatch
-                from ..profiling import steptrace as _steptrace
 
                 if self.tracer is None:
                     self.tracer = _steptrace.configure()
@@ -956,6 +957,7 @@ class ServingEngine:
         if self.step_order_reason:
             self.step_order = "serial"
         self._flying: Optional[_Flying] = None  # dispatched, not folded
+        self._dispatched = 0  # steps handed to the device, ever
         # the newest step's out_tokens and new_rng, the next call's
         # operands whether or not a row of it reads them (zeros before the
         # first step, placed as the step's outputs are)
@@ -1043,44 +1045,68 @@ class ServingEngine:
         return finished
 
     def _step_inner(self) -> List[RequestState]:
-        tr = self.tracer
         # the plan in flight, which the next is projected over (None in
         # the serial order: every step is folded by the call it began in)
-        ahead = self._flying.plan if self._flying is not None else None
-        if tr is None:
-            plan = self.scheduler.plan(ahead_of=ahead)
-            if plan is None and ahead is None:
-                return []
-            return self._run_plan(plan)
-        # traced step: serve/step parent; serve/plan, serve/dispatch,
-        # serve/device, serve/complete children cover the whole of it
+        due = self._flying
+        ahead = due.plan if due is not None else None
+        if ahead is None and not self.scheduler.has_work:
+            # an idle tick (nothing queued, slotted or in flight) is told
+            # BEFORE serve/step opens and writes no span: an annotation
+            # cannot be taken back. The scheduler still ticks (its page
+            # gauges follow the last fold)
+            idle = self.scheduler.plan()
+            assert idle is None, "a plan from a scheduler without work"
+            return []
+        # serve/step parent; serve/plan, serve/dispatch, serve/device,
+        # serve/complete children cover the whole of it
         # (tools/trace_report.py --validate checks the coverage). In the
-        # overlapped order plan and dispatch are the next step's, device
-        # (what is left of the wait) and complete the step's in flight
+        # overlapped order plan and dispatch are the NEXT step's, device
+        # (what is left of the wait) and complete the step's in flight:
+        # each span carries the number of the step that caused it
+        tr = self.tracer
         step_args = {"step": self.metrics.steps + 1}
         if self.name is not None:
             step_args["replica"] = self.name
-        step_sp = tr.begin("serve/step", "serve", step_args)
-        plan_sp = tr.begin("serve/plan", "serve")
-        plan = self.scheduler.plan(ahead_of=ahead)
-        if plan is None and ahead is None:
-            # idle tick: no device step ran — drop BOTH spans (an orphan
-            # serve/plan with no parent step would skew the phase table)
-            plan_sp.cancel()
-            step_sp.cancel()
-            return []
-        plan_sp.end()
+        with Phase(tr, "serve/step", "serve", **step_args) as step_sp:
+            with Phase(tr, "serve/plan", "serve") as plan_sp:
+                plan = self.scheduler.plan(ahead_of=ahead)
+                if plan is None and ahead is None:
+                    # the scheduler held requests and planned none of
+                    # them: no device step ran. (No way into this is known:
+                    # admission is eager, a live slot always schedules and
+                    # a starved pool evicts until one does; the scheduler's
+                    # answer is taken all the same.) Whether a turn is a
+                    # step is known only here, after the annotation was
+                    # entered under its name: the registry drops both
+                    # spans, the profile keeps a serve/step that says
+                    # dispatched=0, folded=0
+                    step_sp.annotate(dispatched=0, folded=0, overlapped=0)
+                    plan_sp.cancel()
+                    step_sp.cancel()
+                    return []
+                step_sp.annotate(**self._turn_args(plan, due))
+            return self._run_plan(plan)
+
+    def _turn_args(self, plan: Optional[StepPlan],
+                   due: Optional["_Flying"]) -> Dict[str, int]:
+        """What ``serve/step`` says of its turn: the number of the step it
+        dispatches and of the one it folds (0: none), so a reader pairs a
+        step's dispatch with its fold."""
+        dispatched = self._dispatched + 1 if plan is not None else 0
+        turn = dict(
+            dispatched=dispatched,
+            folded=(dispatched if self.step_order == "serial"
+                    else due.n if due is not None else 0),
+            overlapped=int(plan is not None and due is not None),
+        )
         if plan is not None:
-            step_sp.annotate(scheduled_tokens=int(plan.total_tokens))
+            turn["scheduled_tokens"] = int(plan.total_tokens)
             if plan.spec_len is not None and plan.spec_len.any():
                 # spec observability: how many of this step's budget rows
                 # are draft (verify-window) rows — trace_report shows it
                 # per step
-                step_sp.annotate(spec_draft_tokens=int(plan.spec_len.sum()))
-        try:
-            return self._run_plan(plan)
-        finally:
-            step_sp.end()
+                turn["spec_draft_tokens"] = int(plan.spec_len.sum())
+        return turn
 
     def _run_plan(self, plan: Optional[StepPlan]) -> List[RequestState]:
         """Dispatch ``plan`` (None: nothing to plan) and fold the step
@@ -1099,111 +1125,116 @@ class ServingEngine:
         (it queues behind the step in flight); the three small results the
         host reads start their way back here, so the fold finds them
         landed."""
-        tr = self.tracer
         # dispatch span covers host-side array staging (the per-slot
         # numpy fills below, including jnp uploads) + the jit call; the
-        # device span then FENCES on the outputs, so compile time lands
+        # device span then waits for the outputs, so compile time lands
         # in dispatch (the first-step TTFT spike is visible as such) and
         # device wait time in device
-        dispatch_sp = tr.begin("serve/dispatch", "serve") if tr else None
-        N = self.max_slots
-        temp = np.zeros(N, np.float32)
-        top_k = np.zeros(N, np.int32)
-        top_p = np.ones(N, np.float32)
-        penalty = np.ones(N, np.float32)
-        eos = np.full(N, -1, np.int32)
-        rng = np.zeros((N, 2), np.uint32)
-        for w in plan.work:
-            req = w.state.request
-            temp[w.slot] = req.temperature
-            top_k[w.slot] = req.top_k
-            top_p[w.slot] = req.top_p
-            penalty[w.slot] = req.repetition_penalty
-            eos[w.slot] = req.eos_token_id
-            rng[w.slot] = np.asarray(w.state.rng, np.uint32)
-        spec_len = (
-            plan.spec_len if plan.spec_len is not None
-            else np.zeros(N, np.int32)
-        )
-        from_prev = (
-            plan.from_prev if plan.from_prev is not None
-            else np.zeros(N, np.bool_)
-        )
-        if self.paged:
-            # idle rows need no dead-tail repoint: the scheduler hands
-            # them an all-NULL page-table row, so their padded W-wide
-            # writes land in the NULL sink page by construction
-            start_pos = plan.start_pos
-            tables = (plan.page_table, plan.page_table_win)[
-                :1 + self.kinds_paged]
-            paged_args = (*tables, plan.cow_src)
-            if self.tiered:
-                paged_args += self._stage_args(plan)
-            # a one-kind model pays for the count only under the tracer
-            keys = (self._count_keys(plan)
-                    if self.kinds_paged or self.latent
-                    or dispatch_sp is not None else {})
-            if dispatch_sp is not None:
-                dispatch_sp.annotate(**keys)
-        else:
-            keys = {}
-            # rows the plan left idle (num_new == 0) still get a W-wide
-            # padded cache write — repoint it at the DEAD TAIL margin
-            # [capacity - W, capacity), which by construction never holds
-            # live tokens (frontiers stop at max_tokens <= capacity - W).
-            # Without this, an idle ACTIVE slot's row would write garbage
-            # at its plan-default start_pos of 0, clobbering cached prompt
-            # K/V the moment a scheduling policy ever skips a live slot.
-            start_pos = np.where(
-                plan.num_new > 0, plan.start_pos,
-                self.capacity - self.token_budget,
-            ).astype(np.int32)
-            paged_args = ()
-        traces_before = self.step_traces
-        from ..parallel.a2a_overlap import a2a_scope
-
-        # the step's attention work rides the profiler's host trace with the
-        # call it describes (free while no trace is being taken)
-        with use_topology(self.topology), self.engine._impl_ctx(), \
-                a2a_scope(self._a2a_cfg), \
-                jax.profiler.TraceAnnotation("serve/device_step", **keys):
-            # the plan's numpy vectors go to the jitted call as they are:
-            # it uploads them itself, without a device_put apiece
-            outs = self._step(
-                self.engine.params, self._caches, self._seen,
-                plan.tokens, plan.num_new, start_pos, *paged_args,
-                plan.fresh, plan.sample, spec_len, eos, rng, temp, top_k,
-                top_p, penalty, from_prev, *self._prev,
+        n = self._dispatched + 1
+        with Phase(self.tracer, "serve/dispatch", "serve",
+                   step=n) as dispatch_sp:
+            N = self.max_slots
+            temp = np.zeros(N, np.float32)
+            top_k = np.zeros(N, np.int32)
+            top_p = np.ones(N, np.float32)
+            penalty = np.ones(N, np.float32)
+            eos = np.full(N, -1, np.int32)
+            rng = np.zeros((N, 2), np.uint32)
+            for w in plan.work:
+                req = w.state.request
+                temp[w.slot] = req.temperature
+                top_k[w.slot] = req.top_k
+                top_p[w.slot] = req.top_p
+                penalty[w.slot] = req.repetition_penalty
+                eos[w.slot] = req.eos_token_id
+                rng[w.slot] = np.asarray(w.state.rng, np.uint32)
+            spec_len = (
+                plan.spec_len if plan.spec_len is not None
+                else np.zeros(N, np.int32)
             )
-        self._caches, self._seen, out_tok, n_emit, new_rng, *moe = outs
-        self._prev = (out_tok, new_rng)
-        # one fetch for everything the host reads, begun now
-        reads = (out_tok, new_rng, n_emit, moe and (
-            moe[0]["tokens_per_expert"], moe[0]["drop_fraction"],
-            moe[0].get("unrouted_tokens")))
-        for a in jax.tree_util.tree_leaves(reads):
-            a.copy_to_host_async()
-        if dispatch_sp is not None:
+            from_prev = (
+                plan.from_prev if plan.from_prev is not None
+                else np.zeros(N, np.bool_)
+            )
+            if self.paged:
+                # idle rows need no dead-tail repoint: the scheduler hands
+                # them an all-NULL page-table row, so their padded W-wide
+                # writes land in the NULL sink page by construction
+                start_pos = plan.start_pos
+                tables = (plan.page_table, plan.page_table_win)[
+                    :1 + self.kinds_paged]
+                paged_args = (*tables, plan.cow_src)
+                if self.tiered:
+                    paged_args += self._stage_args(plan)
+                # a one-kind model pays for the count only under the tracer
+                keys = (self._count_keys(plan)
+                        if self.kinds_paged or self.latent
+                        or self.tracer is not None else {})
+                if keys:
+                    dispatch_sp.annotate(**keys)
+            else:
+                keys = {}
+                # rows the plan left idle (num_new == 0) still get a W-wide
+                # padded cache write — repoint it at the DEAD TAIL margin
+                # [capacity - W, capacity), which by construction never holds
+                # live tokens (frontiers stop at max_tokens <= capacity - W).
+                # Without this, an idle ACTIVE slot's row would write garbage
+                # at its plan-default start_pos of 0, clobbering cached prompt
+                # K/V the moment a scheduling policy ever skips a live slot.
+                start_pos = np.where(
+                    plan.num_new > 0, plan.start_pos,
+                    self.capacity - self.token_budget,
+                ).astype(np.int32)
+                paged_args = ()
+            traces_before = self.step_traces
+            from ..parallel.a2a_overlap import a2a_scope
+
+            # the step's attention work rides the profiler's host trace with
+            # the call it describes (free while no trace is being taken)
+            with use_topology(self.topology), self.engine._impl_ctx(), \
+                    a2a_scope(self._a2a_cfg), \
+                    jax.profiler.TraceAnnotation("serve/device_step", **keys):
+                # the plan's numpy vectors go to the jitted call as they are:
+                # it uploads them itself, without a device_put apiece
+                outs = self._step(
+                    self.engine.params, self._caches, self._seen,
+                    plan.tokens, plan.num_new, start_pos, *paged_args,
+                    plan.fresh, plan.sample, spec_len, eos, rng, temp, top_k,
+                    top_p, penalty, from_prev, *self._prev,
+                )
+            # the step is the device's now: only then does it take its number
+            self._dispatched = n
+            self._caches, self._seen, out_tok, n_emit, new_rng, *moe = outs
+            self._prev = (out_tok, new_rng)
+            # one fetch for everything the host reads, begun now
+            reads = (out_tok, new_rng, n_emit, moe and (
+                moe[0]["tokens_per_expert"], moe[0]["drop_fraction"],
+                moe[0].get("unrouted_tokens")))
+            for a in jax.tree_util.tree_leaves(reads):
+                a.copy_to_host_async()
+            fl = _Flying(
+                plan, reads, overlapped,
+                # did the step pay for the sampler's sorts: the step's own
+                # predicate, from the vectors the host filled for it
+                filtered=bool(np.any(
+                    plan.sample & (plan.num_new > 0)
+                    & ((top_k > 0) | (top_p < 1.0)))),
+                n=n, t0=dispatch_sp.t0,
+            )
             dispatch_sp.annotate(traced=self.step_traces - traces_before)
-            dispatch_sp.end()
-        return _Flying(
-            plan, reads, overlapped,
-            # did the step pay for the sampler's sorts: the step's own
-            # predicate, from the vectors the host filled for it
-            filtered=bool(np.any(
-                plan.sample & (plan.num_new > 0)
-                & ((top_k > 0) | (top_p < 1.0)))),
-            t0=dispatch_sp.t0 if dispatch_sp is not None else None,
-        )
+        return fl
 
     def _fold(self, fl: "_Flying") -> List[RequestState]:
         """Fetch a dispatched step's results and fold them into the
         requests: the one place a token reaches the host."""
-        tr = self.tracer
         plan = fl.plan
-        if tr is not None:
-            device_sp = tr.begin("serve/device", "serve")
-            device_sp.end(fence=fl.reads[0])
+        # the host blocked on the results of the step in flight: the wait
+        # itself, whether or not anything is being traced (the copies to
+        # the host began at dispatch; device_get below finds them landed)
+        with Phase(self.tracer, "serve/device", "serve",
+                   step=fl.n) as device_sp:
+            jax.block_until_ready(fl.reads)
+        if self._serve_tracer is not None:
             # prompt chunks fed this step become request-scoped spans
             # covering the dispatch+device window (statuses read BEFORE
             # complete() advances them; a row whose request went while
@@ -1215,23 +1246,22 @@ class ServingEngine:
                     self._serve_tracer.on_chunk(
                         w.state, w.n_tokens, fl.t0, device_sp.t1
                     )
-            complete_sp = tr.begin("serve/complete", "serve")
-        out_tok, new_rng, n_emit, moe_stats = jax.device_get(fl.reads)
-        finished = self.scheduler.complete(
-            plan, out_tok, new_rng, n_emit=n_emit,
-        )
-        self.metrics.on_step(filtered=fl.filtered, overlapped=fl.overlapped)
-        if moe_stats:
-            # expert load-balance counters (ISSUE 14 satellite): the step
-            # already computed them on device
-            self.metrics.on_moe(
-                moe_stats[0], float(moe_stats[1]),
-                a2a_bytes=self._moe_a2a_step_bytes, unrouted=moe_stats[2],
+        with Phase(self.tracer, "serve/complete", "serve", step=fl.n):
+            out_tok, new_rng, n_emit, moe_stats = jax.device_get(fl.reads)
+            finished = self.scheduler.complete(
+                plan, out_tok, new_rng, n_emit=n_emit,
             )
-        if self.comm_logger is not None:
-            self.comm_logger.record_streams(self.analytic_streams())
-        if tr is not None:
-            complete_sp.end()
+            self.metrics.on_step(
+                filtered=fl.filtered, overlapped=fl.overlapped)
+            if moe_stats:
+                # expert load-balance counters (ISSUE 14 satellite): the step
+                # already computed them on device
+                self.metrics.on_moe(
+                    moe_stats[0], float(moe_stats[1]),
+                    a2a_bytes=self._moe_a2a_step_bytes, unrouted=moe_stats[2],
+                )
+            if self.comm_logger is not None:
+                self.comm_logger.record_streams(self.analytic_streams())
         return finished
 
     def _count_keys(self, plan: StepPlan) -> Dict[str, int]:
@@ -1294,30 +1324,29 @@ class ServingEngine:
         ride under the step."""
         if not plan.stage:
             return (self._stage_zero_np, self._stage_dst_null)
-        tr = self.tracer
-        page_in_sp = tr.begin("serve/page_in", "serve") if tr else None
-        t0 = self.clock()
-        # rotate: the buffer filled LAST step may still be feeding an
-        # in-flight H2D copy — fill the other one (the PR-1 two-
-        # generation discipline, host side)
-        bufs = self._stage_np[self._stage_idx]
-        self._stage_idx ^= 1
-        stage_dst = np.full(STAGE_SLOTS, self.null_page, np.int32)
-        at_rest = 0
-        for i, s in enumerate(plan.stage):
-            leaves, nbytes = self._spiller.load(s.key)
-            at_rest += nbytes
-            stage_dst[i] = s.dst_page
-            for name, arr in leaves.items():
-                bufs[name][:, i] = arr[:, 0]
-        stall = self.clock() - t0
-        self.metrics.on_page_in(
-            pages=len(plan.stage), nbytes=at_rest, stall_s=stall,
-        )
-        if page_in_sp is not None:
-            page_in_sp.annotate(pages=len(plan.stage),
-                                at_rest_bytes=int(at_rest))
-            page_in_sp.end()
+        # inside serve/dispatch: the step that is being dispatched
+        with Phase(self.tracer, "serve/page_in", "serve",
+                   step=self._dispatched + 1) as page_in_sp:
+            t0 = self.clock()
+            # rotate: the buffer filled LAST step may still be feeding an
+            # in-flight H2D copy — fill the other one (the PR-1 two-
+            # generation discipline, host side)
+            bufs = self._stage_np[self._stage_idx]
+            self._stage_idx ^= 1
+            stage_dst = np.full(STAGE_SLOTS, self.null_page, np.int32)
+            at_rest = 0
+            for i, s in enumerate(plan.stage):
+                leaves, nbytes = self._spiller.load(s.key)
+                at_rest += nbytes
+                stage_dst[i] = s.dst_page
+                for name, arr in leaves.items():
+                    bufs[name][:, i] = arr[:, 0]
+            stall = self.clock() - t0
+            self.metrics.on_page_in(
+                pages=len(plan.stage), nbytes=at_rest, stall_s=stall,
+            )
+            page_in_sp.annotate(
+                pages=len(plan.stage), at_rest_bytes=int(at_rest))
         return (bufs, stage_dst)
 
     # ------------------------------------------------- fleet KV handoff
