@@ -10,7 +10,19 @@ same all-to-all the reference hand-codes, but fused and overlapped.
 Top-1/top-k gating with capacity factor, token dropping, load-balance aux
 loss and router z-loss match the reference's TopKGate semantics.
 
-Two dispatch formulations share one gating loop (``moe_dispatch``):
+Placement is ONE pass a layer (:func:`_place_pairs`): the K argmax rounds
+only choose (cheap elementwise work that fixes the tie rule); where every
+(token, chosen expert) pair sits inside its expert comes from one
+triangular product over the rounds' one-hots laid side by side plus a
+[K, E] recurrence of fills that counts kept pairs only, in round-major
+order (all of round 0 in token order, then round 1, ...); the gather
+tables take one scatter over the N x K pairs (:func:`_slot_tables`). No
+cumulative sum over rows, which the chip runs as a slow ``reduce-window``,
+and no scatter a round. The softmax gate and the sigmoid gate's serving
+placement (:func:`held_expert_tables`) share it; the round-by-round loop
+it replaced is the tests' oracle (tests/moe_round_oracle.py).
+
+Two dispatch formulations share one gating function (``moe_dispatch``):
 - "einsum" (default): one-hot dispatch/combine dots — GShard-style, rides
   the MXU, sharding-friendly.
 - "gather": index tables drive plain gathers — the one-hot dots are
@@ -46,11 +58,103 @@ def _a2a_overlap_active(B: int, S: int, E: int, F: int):
     return cfg, topo
 
 
+# rows one triangular product spans: an [N, N] operand is never built for
+# the training callers' 16 k+ tokens (64 MB in bf16 at 4,096 rows already)
+_RANK_BLOCK = 1024
+
+
+def _rows_before(marks: jax.Array) -> jax.Array:
+    """``marks`` [N, C] bool -> [N, C] int32: for row n and column c, how
+    many rows BEFORE n are marked in c (an exclusive count down the rows).
+
+    One product of a strictly-lower-triangular 0/1 matrix with the marks
+    (bf16 operands, float32 accumulation: exact, the counts are whole
+    numbers up to the block) — MXU work, where a cumulative sum over rows
+    is a ``reduce-window`` the chip runs slowly. Rows go in blocks of at
+    most ``_RANK_BLOCK`` (the block follows N), the product inside a
+    block, and a block starts from the totals of the blocks before it."""
+    N, C = marks.shape
+    blocks = -(-N // _RANK_BLOCK)
+    block = -(-N // blocks)
+    x = jnp.pad(marks, ((0, blocks * block - N), (0, 0)))
+    x = x.reshape(blocks, block, C)
+    row = jnp.arange(block, dtype=jnp.int32)
+    below = (row[:, None] > row[None, :]).astype(jnp.bfloat16)
+    ranks = jnp.einsum("ij,bjc->bic", below, x.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32).astype(jnp.int32)
+    if blocks > 1:
+        totals = jnp.sum(x, axis=1, dtype=jnp.int32)  # [blocks, C]
+        b = jnp.arange(blocks, dtype=jnp.int32)
+        before = jnp.sum(jnp.where((b[:, None] > b[None, :])[:, :, None],
+                                   totals[None], 0), axis=1)
+        ranks = ranks + before[:, None, :]
+    return ranks.reshape(blocks * block, C)[:N]
+
+
+def _place_pairs(choice: jax.Array, live: jax.Array, n_experts: int,
+                 capacity: int):
+    """The placement both gates share, in one pass over the layer's
+    (token, chosen expert) pairs: ``choice`` [N, K] int32 is the expert of
+    pair (n, k) (in range wherever ``live``), ``live`` [N, K] bool which
+    pairs take part at all (the softmax gate passes its ``valid`` rows, the
+    sigmoid gate the pairs whose expert is held here).
+
+    Pairs fill an expert in ROUND-MAJOR order: all of round 0 in token
+    order, then round 1, ... So pair (n, k) sits at ``start[k, e]`` + the
+    live tokens before n that chose e in round k. The second term, for all
+    K rounds at once, is one triangular product over the rounds' one-hots
+    side by side (:func:`_rows_before`); the first is [K, E] arithmetic: a
+    round's positions in an expert are contiguous from ``start[k]``, so it
+    KEEPS ``clip(capacity - start[k], 0, count[k])`` of them and the next
+    round starts after the kept ones only.
+
+    Returns ``pos`` [N, K] int32 (0 for a pair that is not live), ``keep``
+    [N, K] bool (live and under the capacity), ``counts`` [K, E] int32 (live
+    pairs a round an expert, kept or not) and ``fill`` [E] int32 (pairs kept
+    an expert)."""
+    N, K = choice.shape
+    onehot = live[:, :, None] & (
+        choice[:, :, None] == jnp.arange(n_experts, dtype=choice.dtype))
+    ranks = _rows_before(onehot.reshape(N, K * n_experts))
+    counts = jnp.sum(onehot, axis=0, dtype=jnp.int32)  # [K, E]
+    fill = jnp.zeros((n_experts,), jnp.int32)
+    start = []
+    for k in range(K):
+        start.append(fill)
+        fill = fill + jnp.clip(capacity - fill, 0, counts[k])
+    at = ranks.reshape(N, K, n_experts) + jnp.stack(start)[None]
+    pos = jnp.sum(jnp.where(onehot, at, 0), axis=-1)  # [N, K]
+    return pos, live & (pos < capacity), counts, fill
+
+
+def _slot_tables(choice: jax.Array, pos: jax.Array, keep: jax.Array,
+                 n_experts: int, capacity: int):
+    """The index tables of a placement (:func:`_place_pairs`):
+    ``tok_of_slot`` [E, C] int32, ``slot_valid`` [E, C] bool and
+    ``slot_of_tok`` [N, K] int32 (flat ``e * C + c``; 0 for a pair not
+    kept). ONE scatter over the N x K pairs writes ``token + 1`` into
+    zeros, so an empty slot reads token 0, not valid; kept pairs have
+    distinct slots, and every other pair writes the dummy slot that is
+    sliced off."""
+    N, K = choice.shape
+    slots = n_experts * capacity
+    flat = choice * capacity + jnp.minimum(pos, capacity - 1)
+    target = jnp.where(keep, flat, slots)
+    token = jnp.repeat(jnp.arange(1, N + 1, dtype=jnp.int32), K)
+    held = jnp.zeros((slots + 1,), jnp.int32).at[target.reshape(-1)].set(
+        token)[:-1].reshape(n_experts, capacity)
+    return jnp.maximum(held - 1, 0), held > 0, jnp.where(keep, flat, 0)
+
+
 def _gating_rounds(logits, top_k, capacity, rng, train, noise_std,
                    valid=None):
-    """The shared top-k selection loop: per-round (expert idx, slot pos,
-    keep mask, raw gate value) plus the aux metrics. ONE implementation so
-    the einsum and gather dispatch paths cannot diverge.
+    """The shared top-k selection: per-round (expert idx, slot pos, keep
+    mask, raw gate value) plus the aux metrics. ONE implementation so the
+    einsum and gather dispatch paths cannot diverge. The rounds choose the
+    experts (argmax, ties to the lower index, the chosen expert masked out
+    of the next round); the positions of all K rounds come from one pass
+    (:func:`_place_pairs`): round-major order inside an expert, a round
+    starting where the KEPT pairs of the rounds before it end.
 
     The inference path accepts ``rng=None`` without consuming a key:
     router noise is only ever sampled when TRAINING with
@@ -62,7 +166,7 @@ def _gating_rounds(logits, top_k, capacity, rng, train, noise_std,
     ``valid`` ([N] bool, optional) is the serving engine's null-expert
     contract: rows marked invalid (padded chunk tails, idle slots, done
     requests) never enter the selection — they occupy no capacity slot,
-    shift no other token's cumsum position, and carry zero combine
+    shift no other token's position, and carry zero combine
     weight — so routing of the REAL tokens is independent of batch
     occupancy and the one fixed-shape step never recompiles (or drops
     differently) as occupancy changes."""
@@ -76,31 +180,26 @@ def _gating_rounds(logits, top_k, capacity, rng, train, noise_std,
         logits = jnp.where(valid[:, None], logits, 0.0)
     gates = jax.nn.softmax(logits, axis=-1)  # [N, E]
 
-    fill = jnp.zeros((E,), jnp.int32)
     masked_gates = gates
     me = jnp.mean(gates, axis=0)  # gate fraction per expert
-    ce_acc = jnp.zeros((E,), jnp.float32)
-    rounds = []
-    kept_total = jnp.zeros((), jnp.float32)
-
+    choice, gate_vals = [], []
     for _ in range(top_k):
-        idx = jnp.argmax(masked_gates, axis=-1)  # [N]
+        idx = jnp.argmax(masked_gates, axis=-1)  # [N], ties to the lower
         onehot = jax.nn.one_hot(idx, E, dtype=jnp.float32)  # [N, E]
         if valid is not None:
             onehot = onehot * valid[:, None].astype(onehot.dtype)
-        # position of each token within its chosen expert (this round)
-        pos_in_round = (jnp.cumsum(onehot, axis=0) - 1.0) * onehot  # [N, E]
-        pos = pos_in_round + fill[None, :] * onehot
-        pos_tok = jnp.sum(pos * onehot, axis=-1).astype(jnp.int32)  # [N]
-        keep = pos_tok < capacity
-        if valid is not None:
-            keep = keep & valid
-        gate_val = jnp.sum(gates * onehot, axis=-1)  # [N]
-        rounds.append((idx, pos_tok, keep, gate_val))
-        fill = fill + jnp.sum(onehot * keep[:, None], axis=0).astype(jnp.int32)
-        ce_acc = ce_acc + jnp.mean(onehot, axis=0)
-        kept_total = kept_total + jnp.sum(keep.astype(jnp.float32))
+        choice.append(idx)
+        gate_vals.append(jnp.sum(gates * onehot, axis=-1))  # [N]
         masked_gates = masked_gates * (1.0 - onehot)  # exclude chosen expert
+    choice = jnp.stack(choice, axis=1)  # [N, K]
+    live = jnp.ones((N, top_k), bool) if valid is None else jnp.broadcast_to(
+        valid[:, None], (N, top_k))
+    pos, keep, counts, fill = _place_pairs(choice, live, E, capacity)
+    rounds = [(choice[:, k], pos[:, k], keep[:, k], gate_vals[k])
+              for k in range(top_k)]
+    # token fraction per expert, summed over the rounds
+    ce_acc = jnp.sum(counts, axis=0).astype(jnp.float32) / N
+    routed = jnp.sum(fill)
 
     aux_loss = E * jnp.sum(me * (ce_acc / top_k))
     z_loss = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
@@ -109,7 +208,8 @@ def _gating_rounds(logits, top_k, capacity, rng, train, noise_std,
         else jnp.asarray(float(N))
     )
     dropped = jnp.where(
-        n_routed > 0, 1.0 - kept_total / jnp.maximum(n_routed * top_k, 1.0),
+        n_routed > 0,
+        1.0 - routed.astype(jnp.float32) / jnp.maximum(n_routed * top_k, 1.0),
         0.0,
     )
     metrics = {
@@ -119,7 +219,7 @@ def _gating_rounds(logits, top_k, capacity, rng, train, noise_std,
         # serving load-balance observability: tokens that actually landed
         # a capacity slot, per expert (the fill counters)
         "tokens_per_expert": fill,
-        "routed_tokens": kept_total.astype(jnp.int32),
+        "routed_tokens": routed,
     }
     return rounds, metrics
 
@@ -136,7 +236,8 @@ def top_k_gating(
     """Returns (dispatch [N,E,C] bool-ish, combine [N,E,C], aux metrics).
 
     Parity: TopKGate.forward (deepspeed/moe/sharded_moe.py top1gating/top2gating):
-    softmax gates, top-k experts per token, positions via cumsum, overflow
+    softmax gates, top-k experts per token, positions in arrival order
+    round by round (computed in one pass, :func:`_place_pairs`), overflow
     tokens dropped, load-balance loss = E * mean(gate_frac * token_frac).
     ``valid`` is the serving null-expert mask (see :func:`_gating_rounds`).
     """
@@ -169,7 +270,9 @@ def top_k_gating_indices(
     noise_std: float = 0.0,
     valid: Optional[jax.Array] = None,
 ):
-    """Index-table form of :func:`top_k_gating` (same selection loop).
+    """Index-table form of :func:`top_k_gating` (same selection, same
+    one-pass placement; the tables are written by one scatter over the
+    N x K pairs, :func:`_slot_tables`).
 
     Returns (tok_of_slot [E,C] int32, slot_valid [E,C] bool,
     slot_of_tok [N,K] int32 flat e*C+c, w_of_tok [N,K] fp32, metrics).
@@ -182,31 +285,17 @@ def top_k_gating_indices(
     N, E = logits.shape
     rounds, metrics = _gating_rounds(logits, top_k, capacity, rng, train,
                                      noise_std, valid=valid)
-    # one extra dummy slot soaks up dropped tokens' scatter writes
-    tok_flat = jnp.zeros((E * capacity + 1,), jnp.int32)
-    valid_flat = jnp.zeros((E * capacity + 1,), jnp.bool_)
-    slot_of_tok = []
-    w_raw = []
-    arange_n = jnp.arange(N, dtype=jnp.int32)
-    for idx, pos_tok, keep, gate_val in rounds:
-        flat = idx * capacity + jnp.minimum(pos_tok, capacity - 1)
-        target = jnp.where(keep, flat, E * capacity)
-        tok_flat = tok_flat.at[target].set(arange_n)
-        valid_flat = valid_flat.at[target].set(True)
-        slot_of_tok.append(jnp.where(keep, flat, 0))
-        w_raw.append(gate_val * keep)
-    # (the dummy slot E*capacity is sliced off below — its contents never
-    # reach the gather path)
-    w = jnp.stack(w_raw, axis=1)  # [N, K]
+    idx, pos, keep, gate_val = zip(*rounds)
+    tok_of_slot, slot_valid, slot_of_tok = _slot_tables(
+        *(jnp.stack(column, axis=1) for column in (idx, pos, keep)),
+        E, capacity)
+    # a round's weights multiplied before they are laid side by side: the
+    # sum below then adds the K columns in order on the chip too (a
+    # reduction over the stacked product adds them in another)
+    w = jnp.stack([g * k for g, k in zip(gate_val, keep)], axis=1)  # [N, K]
     denom = jnp.sum(w, axis=1, keepdims=True)
     w = jnp.where(denom > 0, w / jnp.maximum(denom, 1e-9), w)
-    return (
-        tok_flat[:-1].reshape(E, capacity),
-        valid_flat[:-1].reshape(E, capacity),
-        jnp.stack(slot_of_tok, axis=1),
-        w,
-        metrics,
-    )
+    return tok_of_slot, slot_valid, slot_of_tok, w, metrics
 
 
 def sigmoid_group_gate(logits: jax.Array, sel_bias: jax.Array, top_k: int,
@@ -242,37 +331,23 @@ def held_expert_tables(idx, w, valid, first: int, held: int, capacity: int):
     held`` get a row of their expert's ``capacity`` here; the others are
     computed elsewhere (slot 0, weight zero). No token is dropped while
     ``capacity`` is at least the real tokens (an expert is chosen once a
-    token). Also returns the tokens per held expert [held] and the real
-    tokens that chose no held expert."""
+    token). Also returns the tokens per held expert [held] (every pair
+    sent to it, kept or not) and the real tokens with no pair kept here.
+    The placement is the softmax gate's (:func:`_place_pairs` with the
+    pairs held here as its live ones, :func:`_slot_tables`)."""
     N, K = idx.shape
     local = idx - first
     here = (local >= 0) & (local < held)
     if valid is not None:
         here = here & valid[:, None]
-    tok_flat = jnp.zeros((held * capacity + 1,), jnp.int32)
-    valid_flat = jnp.zeros((held * capacity + 1,), jnp.bool_)
-    fill = jnp.zeros((held,), jnp.int32)
-    slots = []
-    arange_n = jnp.arange(N, dtype=jnp.int32)
-    for k in range(K):
-        onehot = jax.nn.one_hot(local[:, k], held, dtype=jnp.int32) * (
-            here[:, k, None].astype(jnp.int32))
-        pos = jnp.sum((jnp.cumsum(onehot, axis=0) - 1 + fill[None, :])
-                      * onehot, axis=-1)
-        keep = here[:, k] & (pos < capacity)
-        flat = jnp.clip(local[:, k], 0, held - 1) * capacity + jnp.minimum(
-            pos, capacity - 1)
-        target = jnp.where(keep, flat, held * capacity)
-        tok_flat = tok_flat.at[target].set(arange_n)
-        valid_flat = valid_flat.at[target].set(True)
-        slots.append(jnp.where(keep, flat, 0))
-        fill = fill + jnp.sum(onehot, axis=0)
-        here = here.at[:, k].set(keep)
+    choice = jnp.clip(local, 0, held - 1)
+    pos, keep, counts, _ = _place_pairs(choice, here, held, capacity)
+    tok_of_slot, slot_valid, slot_of_tok = _slot_tables(
+        choice, pos, keep, held, capacity)
     real = jnp.ones((N,), bool) if valid is None else valid
-    unrouted = jnp.sum((real & ~jnp.any(here, axis=1)).astype(jnp.int32))
-    return (tok_flat[:-1].reshape(held, capacity),
-            valid_flat[:-1].reshape(held, capacity),
-            jnp.stack(slots, axis=1), w * here, fill, unrouted)
+    unrouted = jnp.sum((real & ~jnp.any(keep, axis=1)).astype(jnp.int32))
+    return (tok_of_slot, slot_valid, slot_of_tok, w * keep,
+            jnp.sum(counts, axis=0), unrouted)
 
 
 @jax.custom_vjp
@@ -572,8 +647,8 @@ def moe_serving_mlp(cfg, p: Dict, x: jax.Array,
       changes;
     - **null-expert padding** — ``token_valid`` [B, S] marks the real
       positions; padded chunk tails, idle slots and done rows route to
-      no expert at all (zero capacity, zero combine weight, zero cumsum
-      shift — :func:`_gating_rounds`);
+      no expert at all (zero capacity, zero combine weight, no shift of
+      another token's position — :func:`_gating_rounds`);
     - **slot-ragged gather dispatch** — :func:`top_k_gating_indices`
       index tables drive plain gathers (O(N·D·K) bytes), not the one-hot
       dots (O(N·E·C·D) flops of data movement — decode steps are

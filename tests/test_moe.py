@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from deepspeed_tpu.moe.sharded_moe import top_k_gating
 from deepspeed_tpu.models import make_lm_batch, mixtral
@@ -230,3 +231,139 @@ def test_gather_dispatch_trains_under_ep_mesh(devices8):
     losses = [float(engine.train_batch(batch=batch)) for _ in range(4)]
     assert all(np.isfinite(l) for l in losses), losses
     assert losses[-1] < losses[0], losses
+
+
+# ---------------------------------------------------------------------------
+# the one-pass placement against the round loop it replaced (PR 40)
+# ---------------------------------------------------------------------------
+def _mask(n, kind, seed):
+    """A ``valid`` mask: None, or [n] bool with the named share real."""
+    if kind is None:
+        return None
+    if kind == "all":
+        return jnp.ones((n,), bool)
+    if kind == "none":
+        return jnp.zeros((n,), bool)
+    if kind == "one":
+        return jnp.zeros((n,), bool).at[n // 3].set(True)
+    return jax.random.uniform(jax.random.PRNGKey(seed), (n,)) < kind
+
+
+def _logits(n, e, kind, seed):
+    x = jax.random.normal(jax.random.PRNGKey(seed), (n, e))
+    if kind == "ties":  # a handful of distinct values: ties in every row
+        return jnp.round(x * 1.5) / 1.5
+    if kind == "flat":  # every expert ties in every row
+        return jnp.zeros((n, e))
+    return x
+
+
+def _same(got, want, what):
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for i, (g, w) in enumerate(zip(got, want)):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape, (what, i)
+        np.testing.assert_array_equal(g, w, err_msg=f"{what}[{i}]")
+
+
+# (N, E, K, capacity, mask, logits): the serving cells' shapes first
+# (Mixtral's two cells [16 x 128] rows top-2 of 8, Mellum's [8 x 128] top-8
+# of 64; capacity 128 is their no-drop ``eval_capacity``), then capacities
+# that drop, every kind of mask, ties, and row counts that take several
+# row blocks (3,000 = 3 x 1,000; 2,050 pads to 3 x 684; 16,384 = 16 x 1,024)
+SOFTMAX_CASES = {
+    "mixtral-cells": (2048, 8, 2, 128, 0.06, "normal"),
+    "mellum-cell": (1024, 64, 8, 128, 0.12, "normal"),
+    "mellum-drops": (1024, 64, 8, 6, 0.12, "normal"),
+    "mixtral-drops-all-real": (2048, 8, 2, 6, None, "normal"),
+    "mask-all-true": (256, 8, 2, 40, "all", "normal"),
+    "mask-one-row": (256, 8, 2, 4, "one", "normal"),
+    "mask-no-row": (256, 8, 2, 4, "none", "normal"),
+    "ties": (512, 16, 4, 24, 0.5, "ties"),
+    "every-row-ties-drops": (300, 8, 3, 7, 0.7, "flat"),
+    "round-fills-the-rest": (64, 2, 2, 40, None, "flat"),
+    "blocks-3": (3000, 16, 4, 20, 0.7, "normal"),
+    "blocks-padded": (2050, 8, 2, 9, 0.9, "ties"),
+    "n-16384": (16384, 8, 2, 16, 0.8, "normal"),
+}
+# (N, held, K, capacity, routed experts, first, mask): DeepSeek's cell
+# ([4 x 128] rows, top-8 of 256, 16 held, room for every real token), a
+# member that is not the first, capacities that drop, masks, row blocks
+HELD_CASES = {
+    "deepseek-cell": (512, 16, 8, 128, 256, 0, 0.25),
+    "deepseek-member-3": (512, 16, 8, 128, 256, 48, 0.25),
+    "deepseek-drops": (512, 16, 8, 3, 256, 0, None),
+    "held-all-true": (128, 4, 2, 5, 8, 4, "all"),
+    "held-one-row": (128, 4, 2, 5, 8, 0, "one"),
+    "held-no-row": (128, 4, 2, 5, 8, 0, "none"),
+    "held-blocks-padded": (2050, 8, 4, 11, 64, 8, 0.6),
+    "held-n-16384": (16384, 8, 4, 16384, 64, 0, 0.9),
+}
+EINSUM_ROOM = 4_000_000  # [N, E, C] elements the einsum form is run up to
+
+
+@pytest.mark.parametrize("gate,case", [
+    *(("indices", c) for c in SOFTMAX_CASES),
+    *(("einsum", c) for c, v in SOFTMAX_CASES.items()
+      if v[0] * v[1] * v[3] <= EINSUM_ROOM),
+    *(("held", c) for c in HELD_CASES),
+])
+def test_one_pass_placement_is_the_round_loop(gate, case):
+    """``top_k_gating_indices``, ``top_k_gating`` and ``held_expert_tables``
+    return, bit for bit, what the round-by-round loop they replaced returns
+    (tests/moe_round_oracle.py): tables, weights, ``tokens_per_expert``,
+    ``drop_fraction``, ``unrouted_tokens``; the two losses to the file's
+    tolerance."""
+    import moe_round_oracle as oracle
+    from deepspeed_tpu.moe import sharded_moe
+
+    seed = sum(map(ord, case))
+    if gate == "held":
+        N, held, K, cap, R, first, mask = HELD_CASES[case]
+        idx = jnp.argsort(jax.random.uniform(jax.random.PRNGKey(seed), (N, R)),
+                          axis=1)[:, :K].astype(jnp.int32)
+        w = jax.random.uniform(jax.random.PRNGKey(seed + 1), (N, K))
+        valid = _mask(N, mask, seed + 2)
+        got = jax.jit(lambda i, w, v: sharded_moe.held_expert_tables(
+            i, w, v, first, held, cap))(idx, w, valid)
+        want = jax.jit(lambda i, w, v: oracle.held_expert_tables(
+            i, w, v, first, held, cap))(idx, w, valid)
+        _same(got, want, "held_expert_tables")
+        if mask == "none":
+            assert int(got[4].sum()) == 0 and int(got[5]) == 0
+        return
+    N, E, K, cap, mask, kind = SOFTMAX_CASES[case]
+    logits, valid = _logits(N, E, kind, seed), _mask(N, mask, seed + 1)
+    name = "top_k_gating_indices" if gate == "indices" else "top_k_gating"
+    got = jax.jit(lambda l, v: getattr(sharded_moe, name)(
+        l, K, cap, None, False, valid=v))(logits, valid)
+    want = jax.jit(lambda l, v: getattr(oracle, name)(l, K, cap, v))(
+        logits, valid)
+    _same(got[:-1], want[:-1], name)
+    gm, wm = got[-1], want[-1]
+    assert set(gm) == set(wm)
+    for key in ("tokens_per_expert", "routed_tokens", "drop_fraction"):
+        _same(gm[key], wm[key], key)
+    for key in ("aux_loss", "z_loss"):
+        np.testing.assert_allclose(float(gm[key]), float(wm[key]), rtol=1e-6)
+    if "drops" in case:
+        assert float(gm["drop_fraction"]) > 0.0
+
+
+def test_placement_of_many_rows_builds_no_square():
+    """At a training caller's 16,384 tokens the ranks come block by block:
+    the compiled placement holds no [N, N] array in any type, its
+    temporaries are far under one's bytes, and the row block follows N."""
+    from deepspeed_tpu.moe import sharded_moe
+
+    N, E, K, cap = 16384, 8, 2, 5120
+    compiled = jax.jit(lambda l: sharded_moe.top_k_gating_indices(
+        l, K, cap, None, False)).lower(
+            jax.ShapeDtypeStruct((N, E), jnp.float32)).compile()
+    assert f"[{N},{N}]" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < N * N // 8
+    for n, want in ((512, 512), (1024, 1024), (1025, 513), (2048, 1024),
+                    (2050, 684), (16384, 1024)):
+        blocks = -(-n // sharded_moe._RANK_BLOCK)
+        assert -(-n // blocks) == want

@@ -255,6 +255,32 @@ def _check_head_runs_over_the_window(compiled, N, W, V, family, capsys):
     assert m.temp_size_in_bytes / 1e6 < PR36_TEMP_MB[family] * 1.01
 
 
+def _check_placement_is_one_pass(compiled, rows, cfg, family, capsys):
+    """A routed layer ranks its (token, chosen expert) pairs with one
+    triangular product (PR 40): the compiled step holds no ``reduce-window``
+    (what a cumulative sum over rows is on the chip) over a routing one-hot,
+    i.e. over ``rows x experts`` elements (a round's) or ``top_k`` times
+    that (a layer's), in any layout. Prints the step's counts of the three operations the round
+    loop paid a round."""
+    text = compiled.as_text()
+    counts = {op: len(re.findall(rf"= (?:\([^)]*\)|\S+) {op}\(", text))
+              for op in ("reduce-window", "scatter", "sort")}
+    with capsys.disabled():
+        print(f"{family} slot step: " + ", ".join(
+            f"{n} {op}" for op, n in counts.items()) + " instructions")
+    shapes = {m.group(1): m.group(2) for m in re.finditer(
+        r"%([\w.\-]+) = \w+\[([\d,]*)\]", text)}
+    over_onehots = []
+    for m in re.finditer(
+            r"%([\w.\-]+) = \S+ reduce-window\(%([\w.\-]+)", text):
+        dims = shapes.get(m.group(2), "")
+        size = int(np.prod([int(d) for d in dims.split(",") if d] or [0]))
+        if size in (rows * cfg.num_experts,
+                    rows * cfg.num_experts * cfg.moe_top_k):
+            over_onehots.append(f"{m.group(1)} over [{dims}]")
+    assert over_onehots == []
+
+
 def _compile_slot_step(model, caches, one_chip, N, W, mp):
     """``make_paged_step_fn`` of ``model`` jitted as the serving engine
     jits it (caches and ``seen`` donated), compiled for the described
@@ -327,6 +353,8 @@ def test_mixtral_slot_step_keeps_its_pools_in_place(one_chip, monkeypatch,
     _check_caches_stay_in_place(compiled, caches, "mixtral", capsys)
     _check_head_runs_over_the_window(compiled, N, W, model.config.vocab_size,
                                      "mixtral", capsys)
+    _check_placement_is_one_pass(compiled, N * W, model.config, "mixtral",
+                                 capsys)
     assert "paged_attention" in compiled.as_text()
 
 
@@ -352,6 +380,8 @@ def test_mellum_slot_step_compiles_beside_its_arena(one_chip, monkeypatch,
     m = _check_caches_stay_in_place(compiled, caches, "mellum", capsys)
     _check_head_runs_over_the_window(compiled, N, W, model.config.vocab_size,
                                      "mellum", capsys)
+    _check_placement_is_one_pass(compiled, N * W, model.config, "mellum",
+                                 capsys)
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * GIB
     text = compiled.as_text()
     assert "paged_attention_window" in text and "paged_attention_full" in text
@@ -390,6 +420,8 @@ def test_deepseek_slot_step_keeps_both_pools_in_place(one_chip, monkeypatch,
               f"{pools / GIB:.2f})")
     _check_head_runs_over_the_window(compiled, N, W, model.config.vocab_size,
                                      "deepseek", capsys)
+    _check_placement_is_one_pass(compiled, N * W, model.config, "deepseek",
+                                 capsys)
     text = compiled.as_text()
     assert _pool_copies(text, caches) == []
     assert m.alias_size_in_bytes >= pools
