@@ -1,0 +1,13 @@
+"""Kernels on the serve path, the sparse layers' attention over the kept blocks: device time of the Pallas
+call(s) the program names ``block_sparse_attention`` (once a layer of the kind) per traced step.
+A program without the call yields nothing. Source: device trace."""
+
+from benchmarks import kinds_trace
+
+CALLS = r"^block_sparse_attention"
+
+
+def read(ctx):
+    steps = kinds_trace.traced_steps(ctx)
+    s = ctx.reduced.op_seconds(CALLS) if steps else 0
+    return 1e3 * s / steps if s > 0 else None

@@ -1,0 +1,25 @@
+"""Kernels on the serve path, lightning attention: the kernel's share of its
+roofline. The least time the chip could take for what the traced steps
+needed (the family's ``lightning_cost``: for every real row the state's
+update and read-out; every live state read and written once a slot a step,
+float32; the real rows' q, k, v in and o out; the greater of the compute and
+the memory time) over the measured device time of the calls named
+``lightning_attention``. The counts are the program's own, carried by the
+trace with the steps it timed (``kinds_trace.step_counts``), for one layer;
+the time is divided by the number of lightning layers. Source: device trace
++ program counters + ``peaks.json``."""
+
+from benchmarks import kinds_trace
+
+CALLS = r"^lightning_attention"
+
+
+def read(ctx):
+    counts = kinds_trace.step_counts(ctx)
+    cost = getattr(ctx.family, "lightning_cost", None)
+    if not counts or "state_slots" not in counts or cost is None:
+        return None
+    measured = ctx.reduced.op_seconds(CALLS) / ctx.shape.count("lightning")
+    need, _bound = ctx.flops.roofline_seconds(
+        *cost(ctx.shape, counts["rows"], counts["state_slots"]), ctx.peak)
+    return 100.0 * need / measured if measured > 0 else None

@@ -11,6 +11,7 @@ from .mixtral import mixtral, mixtral_config  # noqa: F401
 from .mellum import mellum, mellum_config  # noqa: F401
 from .deepseek import deepseek, deepseek_config  # noqa: F401
 from .glm import glm, glm_config  # noqa: F401
+from .minicpm import minicpm, minicpm_config  # noqa: F401
 
 MODEL_REGISTRY = {
     "gpt2": gpt2,
@@ -20,6 +21,7 @@ MODEL_REGISTRY = {
     "mellum": mellum,
     "deepseek": deepseek,
     "glm": glm,
+    "minicpm": minicpm,
 }
 
 

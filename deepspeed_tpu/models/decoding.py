@@ -134,7 +134,8 @@ def latent_row_width(cfg: TransformerConfig) -> int:
 
 def init_paged_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
                      dtype=jnp.bfloat16, quantized: bool = False,
-                     window_pages: Optional[int] = None) -> Cache:
+                     window_pages: Optional[int] = None,
+                     max_slots: Optional[int] = None) -> Cache:
     """Block-paged KV pool for all layers (the serving engine's paged
     arena): ``k``/``v`` are [L, num_pages + 1, page_size, KV, hd] — one
     extra physical page at index ``num_pages`` is the NULL page, where
@@ -147,7 +148,25 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
     A model with window layers (``cfg.has_window``) keeps pages by layer
     kind: ``k``/``v`` hold the full layers alone, and ``k_win``/``v_win``
     [L_window, window_pages + 1, ...] the window layers, whose pages a
-    slot gives back once every query still to come is past them."""
+    slot gives back once every query still to come is past them.
+
+    A model with mixers (``cfg.mixer_types``, models/minicpm.py) keeps
+    ``k``/``v`` pages and a compressed key a page (``kc``) for its sparse
+    layers alone, and for its lightning layers a third kind of leaf that is
+    indexed by SLOT, not through the page table: ``state``
+    [L_lightning, max_slots, heads, hd, hd] float32."""
+    if cfg.mixer_types:
+        from ..config import DeepSpeedConfigError
+        from .minicpm import init_pools
+
+        if quantized:
+            raise DeepSpeedConfigError(
+                "an int8 KV cache is refused: a sparse layer's compressed "
+                "keys are means of its cached keys, and a lightning layer's "
+                "state is float32")
+        if max_slots is None:
+            raise ValueError("a model with state layers needs max_slots")
+        return init_pools(cfg, num_pages, page_size, max_slots, dtype)
     if cfg.is_latent:
         if quantized:
             from ..config import DeepSpeedConfigError
@@ -293,6 +312,13 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
     halves KV HBM for long-context serving (reference: kv-cache quant in
     the inference engine family). Dequant happens at read (in-kernel on the
     Pallas decode path)."""
+    if cfg.mixer_types:
+        from ..config import DeepSpeedConfigError
+
+        raise DeepSpeedConfigError(
+            "a contiguous KV arena is refused: a sparse layer selects blocks "
+            "of pages and a lightning layer keeps a state a slot "
+            "(mixer_types); both live in the paged arena (serving.paged)")
     if cfg.is_latent:
         from ..config import DeepSpeedConfigError
 
@@ -743,6 +769,23 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
     if cfg.embed_norm:
         x = _norm(cfg, cast(params["embed_norm"]), x)
     x = constrain(x, ("dp", "fsdp"), None, None)
+    if cfg.mixer_types:
+        # layers by published order, two parameter stacks, runs of a kind a
+        # scan each over the same carry (models/minicpm.py); muP scales
+        from .minicpm import STACK, cached_layers
+
+        stacks = {k: cast(params[k]) for k in STACK.values() if k in params}
+        x, new_cache = cached_layers(
+            cfg, stacks, x * jnp.asarray(cfg.scale_emb, x.dtype), positions,
+            dict(cache), cache_len, page_table, num_new)
+        if logit_rows is not None:
+            x = jnp.take_along_axis(x, logit_rows[:, :, None], axis=1)
+        x = _norm(cfg, cast(params["final_norm"]), x)
+        if cfg.dim_model_base:
+            x = x / jnp.asarray(cfg.hidden_size / cfg.dim_model_base, x.dtype)
+        logits = lm_head_logits(cfg, params, x)
+        return (logits, new_cache, None) if return_moe_stats else (
+            logits, new_cache)
 
     moe = cfg.is_moe
     collect_moe = bool(return_moe_stats) and moe

@@ -159,6 +159,24 @@ class TransformerConfig:
     # joins the loss times ``mtp_loss_weight``.
     mtp_layers: int = 0
     mtp_loss_weight: float = 0.3
+    # Mixers by layer (models/minicpm.py): ``mixer_types`` names every layer,
+    # in an order that need be no period, "sparse" (grouped-query attention
+    # over a learned selection of blocks of the paged cache: ``block_sparse``,
+    # an ops/pallas/block_sparse_attention.BlockSparse) or "lightning"
+    # (linear attention whose cache is a state a slot, no page); each kind
+    # has a parameter stack of its own. ``mixer_layer_ids`` gives each layer
+    # its index in the published model of ``mixer_depth`` layers (a cut
+    # keeps both: the decay of a lightning layer and the residual scale
+    # follow them). muP: the embedding times ``scale_emb``, a residual
+    # branch times ``scale_depth / sqrt(mixer_depth)``, the hidden state
+    # over ``hidden_size / dim_model_base`` before the head.
+    mixer_types: Tuple[str, ...] = ()
+    mixer_layer_ids: Tuple[int, ...] = ()
+    mixer_depth: int = 0
+    block_sparse: Optional[Any] = None
+    scale_emb: float = 1.0
+    scale_depth: float = 1.0
+    dim_model_base: int = 0
     name: str = "transformer"
 
     def __post_init__(self):
@@ -196,6 +214,17 @@ class TransformerConfig:
                 "one member's share of an expert-parallel layer "
                 "(moe_routed_experts) is computed under the sigmoid_groups "
                 "router alone")
+        if self.mixer_types and (
+                set(self.mixer_types) - {"sparse", "lightning"}
+                or len(self.mixer_types) != self.num_layers
+                or len(self.mixer_layer_ids) != self.num_layers
+                or self.mixer_depth <= max(self.mixer_layer_ids)
+                or self.block_sparse is None or self.layer_pattern
+                or self.is_latent or self.is_moe):
+            raise ValueError(
+                "mixer_types names every layer 'sparse' or 'lightning', each "
+                "with its published index under mixer_depth, beside a "
+                "block_sparse geometry and no other layer kind")
         if self.routed_experts % self.moe_groups or not (
                 0 <= self.moe_first_expert
                 <= self.routed_experts - self.num_experts):
@@ -224,6 +253,11 @@ class TransformerConfig:
         return "window" in self.layer_pattern
 
     @property
+    def has_state(self) -> bool:
+        """A layer keeps a recurrent state a slot, which is no page."""
+        return "lightning" in self.mixer_types
+
+    @property
     def is_latent(self) -> bool:
         return self.kv_latent_dim > 0
 
@@ -243,6 +277,8 @@ class TransformerConfig:
 
     def kind_count(self, kind: str) -> int:
         """Layers of ``kind`` in the whole stack."""
+        if self.mixer_types:
+            return self.mixer_types.count(kind)
         if not self.layer_pattern:
             return self.num_layers if kind == "full" else 0
         return self.layer_pattern.count(kind) * (
@@ -256,6 +292,10 @@ class TransformerConfig:
 
     def num_params(self) -> int:
         """Analytic parameter count (for flops profiler / partition planner)."""
+        if self.mixer_types:
+            from .minicpm import num_params
+
+            return num_params(self)
         d, v, L = self.hidden_size, self.vocab_size, self.num_layers
         ln_width = 2 * d if self.norm == "layernorm" else d  # scale (+bias)
         qkvo = d * self.num_heads * self.hd * 2 + d * self.kv_heads * self.hd * 2
@@ -332,6 +372,10 @@ def _latent_attn_params(cfg: "TransformerConfig", nrm, lk, L: int,
 # init
 # -----------------------------------------------------------------------------
 def init(cfg: TransformerConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
+    if cfg.mixer_types:
+        from .minicpm import init as init_mixers
+
+        return init_mixers(cfg, rng, dtype)
     std = cfg.initializer_range
     keys = jax.random.split(rng, 16)
     d, hd, nh, nkv, f = cfg.hidden_size, cfg.hd, cfg.num_heads, cfg.kv_heads, cfg.ffn
@@ -962,14 +1006,22 @@ def masked_ce(logits: jax.Array, labels: jax.Array, num_mb_dims: int = 0):
 def _refuse_uncached(cfg: TransformerConfig) -> None:
     """What only the paged serving step computes (models/decoding.py) is
     refused by the uncached forward, which training and ``forward`` use."""
-    if cfg.index_topk:
-        from ..config import DeepSpeedConfigError
+    from ..config import DeepSpeedConfigError
 
+    if cfg.index_topk:
         raise DeepSpeedConfigError(
             "the uncached forward (training, evaluation, forward) does not "
             "compute the indexer's selection (index_topk): it is made from "
             "cached index keys alone; serve this configuration through "
             "init_serving with serving.paged")
+    if cfg.mixer_types:
+        raise DeepSpeedConfigError(
+            "the uncached forward (training, evaluation, forward) runs "
+            "neither mixer of mixer_types: a lightning layer's recurrence "
+            "lives in a slot's state and a sparse layer's block selection "
+            "is made from cached compressed keys, both in the paged arena "
+            "alone; serve this configuration through init_serving with "
+            "serving.paged")
 
 
 def routing_stats_summary(stats) -> Dict[str, jax.Array]:
@@ -1141,8 +1193,9 @@ def tp_partition_specs(cfg: TransformerConfig, tp_divides_kv: bool = True) -> Pa
     Row-parallel: attn-out + mlp-out shard input dim over tp.
     Embeddings/lm_head shard vocab over tp (loss is vocab-parallel).
     """
-    if cfg.is_latent:
-        # one latent a token serves every head: nothing splits by head, and
+    if cfg.is_latent or cfg.mixer_types:
+        # one latent a token serves every head, a block selection is a kv
+        # group's and a state a slot's: nothing splits by head, and
         # every leaf is whole on every device
         shapes = jax.eval_shape(partial(init, cfg), jax.random.PRNGKey(0))
         return jax.tree.map(lambda a: P(*([None] * a.ndim)), shapes)

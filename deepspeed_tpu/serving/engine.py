@@ -94,6 +94,12 @@ def cache_token_bytes(cfg, storage_itemsize: int, quantized: bool) -> int:
     """Bytes one token keeps in one layer of the cache, scales left out:
     keys and values of every KV head, or for latent attention its one
     latent row (as the pool pads it) and its indexer key."""
+    if cfg.mixer_types:
+        # a sparse layer's keys and values, and a page's one compressed key
+        # spread over its tokens (the lightning layers keep no token)
+        geom = cfg.block_sparse
+        return (2 * geom.kernel_stride + 1) * cfg.kv_heads * cfg.hd * (
+            storage_itemsize) // geom.kernel_stride
     if cfg.is_latent:
         from ..models.decoding import latent_row_width
 
@@ -196,13 +202,16 @@ def paged_kv_stream(cfg, num_pages: int, page_size: int, max_slots: int,
     # k + v of a token, with their scales
     per_tok = cache_token_bytes(cfg, storage_itemsize, quantized) + (
         2 * SCALE_LANES * 4 if quantized else 0)
-    L = cfg.total_layers
-    gather = L * max_slots * pages_per_slot * page_size * per_tok
+    # (a model with mixers keeps pages for its sparse layers alone; its
+    # lightning layers' states are read and written once a step)
+    L = cfg.kind_count("sparse") if cfg.mixer_types else cfg.total_layers
+    state = state_bytes(cfg, max_slots)
+    gather = 2 * state + L * max_slots * pages_per_slot * page_size * per_tok
     scatter = L * max_slots * token_budget * per_tok
     cow = L * max_slots * page_size * per_tok
     total = gather + scatter + cow
     pool_tokens = L * (num_pages + 1) * page_size
-    return {
+    stream = {
         "kind": "hbm",
         "bytes_per_step": total,
         "per_device_bytes_per_step": total // max(tp, 1),
@@ -211,10 +220,22 @@ def paged_kv_stream(cfg, num_pages: int, page_size: int, max_slots: int,
         "page_size": page_size,
         "num_pages": num_pages,
         "pages_per_slot": pages_per_slot,
-        "pool_bytes": pool_tokens * per_tok,
+        "pool_bytes": pool_tokens * per_tok + state,
         "slots": max_slots,
         "quantized": quantized,
     }
+    if state:  # the arena's bytes that are no page
+        stream["state_bytes"] = state
+    return stream
+
+
+def state_bytes(cfg, max_slots: int) -> int:
+    """Bytes of the arena that are no page: a float32 state
+    ``[heads, hd, hd]`` a slot a lightning layer (0 for any other model)."""
+    if not getattr(cfg, "has_state", False):
+        return 0
+    return (cfg.kind_count("lightning") * max_slots * cfg.num_heads
+            * cfg.hd * cfg.hd * 4)
 
 
 def kv_spill_page_bytes(cfg, page_size: int, codec_name: str,
@@ -526,8 +547,9 @@ def make_paged_step_fn(cfg, dtype, vocab: int, cache_shardings=None,
 
     def step(params, caches, seen, tokens, num_new, start_pos, page_table,
              cow_src, *rest, page_table_win=None):
-        if page_table_win is None:
-            # (a model with window layers shares no page: nothing to copy)
+        if page_table_win is None and not cfg.mixer_types:
+            # (a model with window layers or with slot state shares no
+            # page: nothing to copy)
             caches = paged_cow_copy(caches, page_table, start_pos, cow_src)
         return slot_step(params, caches, seen, tokens, num_new, start_pos,
                          *rest, page_table=page_table,
@@ -743,6 +765,38 @@ class ServingEngine:
             if int(serving.fleet.prefill_replicas) > 0:
                 raise DeepSpeedConfigError(
                     f"serving.fleet.prefill_replicas is refused: {why}")
+        # ---- state layers: a lightning layer's cache is a state a slot,
+        # begun at zero with its request and never shared; a page holds the
+        # sparse layers' keys alone (docs/serving.md "Layer kinds") ---------
+        self.slot_state = bool(getattr(mcfg, "has_state", False))
+        if mcfg.mixer_types:
+            from ..config import DeepSpeedConfigError
+
+            if not self.paged:
+                raise DeepSpeedConfigError(
+                    "serving.paged false is refused: a sparse layer selects "
+                    "blocks of pages and a lightning layer keeps a state a "
+                    "slot (mixer_types); both live in the paged arena")
+            why = (
+                "the model has lightning layers, whose cache is a recurrent "
+                "state a slot and no page: a page that is kept, spilled or "
+                "handed over holds the sparse layers' keys alone, and the "
+                "state that summed the same tokens would be missing")
+            if int(getattr(serving, "host_pages", 0) or 0) > 0:
+                raise DeepSpeedConfigError(
+                    f"serving.host_pages is refused: {why}")
+            if int(serving.fleet.prefill_replicas) > 0:
+                raise DeepSpeedConfigError(
+                    f"serving.fleet.prefill_replicas is refused: {why}")
+            if self.spec_enabled:
+                raise DeepSpeedConfigError(
+                    "serving.spec is refused: the model has lightning "
+                    "layers, whose state sums every row it was fed; a "
+                    "rejected draft would need the state rolled back to the "
+                    "last accepted token, and the step keeps no such copy")
+            if prefix_cache:
+                log_dist(f"serving: prefix cache off: {why}")
+                prefix_cache = False
         # ---- tiered KV (serving.host_pages > 0, ISSUE 18): a pinned-
         # host second tier behind the HBM pool. The ENGINE owns the
         # store + spiller (movement needs device access: export/encode on
@@ -840,6 +894,7 @@ class ServingEngine:
             spiller=self._spiller,
             window=mcfg.attn_window if self.kinds_paged else 0,
             window_num_pages=self.window_num_pages,
+            slot_state=self.slot_state,
         )
 
         # ---- the KV arena (contiguous slots, or a paged pool) ----------
@@ -848,7 +903,7 @@ class ServingEngine:
                 self.config, self.num_pages, self.page_size,
                 engine.kv_cache_storage_dtype,
                 quantized=engine.kv_cache_quantized,
-                window_pages=self.window_num_pages,
+                window_pages=self.window_num_pages, max_slots=N,
             )
         else:
             caches = init_cache(
@@ -859,12 +914,12 @@ class ServingEngine:
         self._cache_shardings = None
         if self.topology.world_size > 1:
             mesh = self.topology.mesh
+            specs = cache_partition_specs(
+                engine.kv_cache_quantized, self.kinds_paged)
+            if mcfg.mixer_types:  # a selection is a kv group's: whole leaves
+                specs = {k: P() for k in caches}
             self._cache_shardings = {
-                k: NamedSharding(mesh, spec)
-                for k, spec in cache_partition_specs(
-                    engine.kv_cache_quantized, self.kinds_paged
-                ).items()
-            }
+                k: NamedSharding(mesh, spec) for k, spec in specs.items()}
             caches = jax.device_put(caches, self._cache_shardings)
             seen = jax.device_put(seen, NamedSharding(mesh, P()))
         else:
@@ -919,7 +974,9 @@ class ServingEngine:
         # trace time from what the step can observe, so recorded by the same
         # side effect
         self.attention_path: Optional[str] = None
+        self.attention_paths: Dict[str, str] = {}
         self.attention_fallback: Tuple[str, ...] = ()
+        self.metrics.state_bytes = state_bytes(mcfg, N)
 
         def counting_step(*args):
             self.step_traces += 1
@@ -927,11 +984,19 @@ class ServingEngine:
                 out = step_fn(*args)
             self.attention_path = rec["path"]
             self.attention_fallback = rec["reasons"]
+            if mcfg.mixer_types:
+                # a path a mixer kind: "block_sparse_kernel" / "dense" for
+                # the sparse layers, "lightning_kernel" / "dense" for the
+                # lightning ones; attention_path is the sparse layers'
+                self.attention_paths = dict(rec["kinds"])
+                self.attention_path = rec["kinds"].get("sparse", rec["path"])
             self.metrics.attention_paged_kernel = float(
-                rec["path"] in ("paged_kernel", "latent_sparse_kernel")
+                self.attention_path in ("paged_kernel", "latent_sparse_kernel",
+                                        "block_sparse_kernel")
             )
             self.metrics.attention_paged_kernel_kinds = {
-                kind: float(path == "paged_kernel")
+                kind: float(path == "paged_kernel" or bool(mcfg.mixer_types)
+                            and path.endswith("_kernel"))
                 for kind, path in rec["kinds"].items()
             }
             return out
@@ -1169,6 +1234,7 @@ class ServingEngine:
                 # a one-kind model pays for the count only under the tracer
                 keys = (self._count_keys(plan)
                         if self.kinds_paged or self.latent
+                        or self.config.mixer_types
                         or self.tracer is not None else {})
                 if keys:
                     dispatch_sp.annotate(**keys)
@@ -1275,6 +1341,8 @@ class ServingEngine:
         out = {"rows": int(plan.num_new.sum())}
         if self.latent:
             return {**out, **self._count_selected(plan)}
+        if self.config.mixer_types:
+            return {**out, **self._count_mixers(plan)}
         for kind in ("full", "window")[:1 + self.kinds_paged]:
             attended, fetched = key_counts(
                 plan.start_pos, plan.num_new, self.page_size,
@@ -1314,6 +1382,52 @@ class ServingEngine:
         self.metrics.context_keys += counts["context_keys"]
         return counts
 
+    def _count_mixers(self, plan: StepPlan) -> Dict[str, int]:
+        """The work of one sparse and one lightning layer of a model with
+        mixers, from the plan (host arithmetic): ``context_keys`` the cached
+        tokens at or before every real query token; ``attended_sparse``
+        those a query attends, all of them at a position inside
+        ``dense_len`` and ``topk`` blocks' worth past it; ``compressed_keys``
+        the compressed keys at or before every real query, which its
+        selection scores, and ``compressed_rows`` those at or before a
+        slot's last real query, which it reads once a slot; ``chosen_min``
+        the fewest K / V rows a slot's queries can have chosen between
+        them (its last query's);
+        ``state_slots`` the live states the step reads and writes,
+        ``state_resets`` those of them that begin at zero. Booked on the
+        metrics."""
+        geom = self.config.block_sparse
+        cl = plan.start_pos.astype(np.int64)
+        nn = plan.num_new.astype(np.int64)
+        busy = nn > 0
+        kept = geom.topk * geom.block_size
+        context = nn * cl + nn * (nn + 1) // 2
+        # rows at a position past dense_len whose context passes the kept
+        # blocks attend the blocks' worth
+        edge = max(geom.dense_len, kept)
+        over = np.clip(cl + nn - edge, 0, nn)
+        attended = context - (over * (cl + nn - kept) - over * (over - 1) // 2)
+        compressed = sum(
+            int(np.maximum((np.arange(c, c + n) + 1 - geom.kernel_size)
+                           // geom.kernel_stride + 1, 0).sum())
+            for c, n in zip(cl[busy], nn[busy]))
+        last = (cl + nn)[busy]
+        counts = {
+            "context_keys": int(context.sum()),
+            "attended_sparse": int(attended.sum()),
+            "compressed_keys": compressed,
+            "compressed_rows": int(np.maximum(
+                (last - geom.kernel_size) // geom.kernel_stride + 1, 0).sum()),
+            "chosen_min": int(np.where(last > edge, kept, last).sum()),
+            "state_slots": int(busy.sum()),
+            "state_resets": int((busy & (cl == 0)).sum()),
+        }
+        self.metrics.on_keys("sparse", counts["attended_sparse"],
+                             counts["chosen_min"])
+        self.metrics.context_keys += counts["context_keys"]
+        self.metrics.state_resets += counts["state_resets"]
+        return counts
+
     def _stage_args(self, plan: StepPlan) -> tuple:
         """Decode this step's promotions into the rotating staging buffer
         (host side) and return the ``(stage_kv, stage_dst)`` step args.
@@ -1351,6 +1465,13 @@ class ServingEngine:
 
     # ------------------------------------------------- fleet KV handoff
     def _refuse_page_moves(self, what: str) -> None:
+        if self.slot_state:
+            raise RuntimeError(
+                f"{what}: a page holds the sparse layers' keys alone; the "
+                "lightning layers' state that summed the same tokens is a "
+                "slot's and no page, so a hand-off would serve a model with "
+                "state layers a context its state never saw"
+            )
         if self.kinds_paged:
             raise RuntimeError(
                 f"{what}: a page id names the full layers' pool alone; the "
@@ -1657,13 +1778,16 @@ def trace_serving_step(model, ds_config, topology: Optional[MeshTopology]
                 "max_tokens; one request could never finish"
             )
         cache_shape = init_paged_cache(
-            mcfg, num_pages, page_size, storage, quantized=quantized
+            mcfg, num_pages, page_size, storage, quantized=quantized,
+            max_slots=N,
         )
     else:
         cache_shape = init_cache(
             mcfg, N, capacity, storage, quantized=quantized
         )
     cache_specs = cache_partition_specs(quantized)
+    if mcfg.mixer_types:  # a selection is a kv group's: whole leaves
+        cache_specs = {k: P() for k in cache_shape}
     caches = {
         k: sds(v.shape, v.dtype, cache_specs[k])
         for k, v in cache_shape.items()

@@ -168,6 +168,12 @@ class ServingMetrics:
         self.attended_keys: Dict[str, int] = defaultdict(int)
         self.fetched_keys: Dict[str, int] = defaultdict(int)
         self.attention_paged_kernel_kinds: Dict[str, float] = {}
+        # a model with state layers: the arena's bytes that are no page
+        # (gauge, set once an engine) and the states that began at zero (a
+        # request's first chunk); the compiled step's path a mixer kind is
+        # under attention_paged_kernel_kinds (1 = the kind's Pallas kernel)
+        self.state_bytes = 0
+        self.state_resets = 0
         self._max_slots = 1
         self._num_pages = 0
         self._host_pages = 0
@@ -445,6 +451,9 @@ class ServingMetrics:
             snap[f"attention_paged_kernel_{kind}"] = on
         if self.context_keys:
             snap["context_keys"] = self.context_keys
+        if self.state_bytes:
+            snap["state_bytes"] = self.state_bytes
+            snap["state_resets"] = self.state_resets
         if "window" in self.attended_keys:
             snap.update({
                 "pages_free": self.pages_free,

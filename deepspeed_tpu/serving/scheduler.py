@@ -126,6 +126,7 @@ class Scheduler:
         spiller=None,
         window: int = 0,
         window_num_pages: Optional[int] = None,
+        slot_state: bool = False,
     ):
         self.max_slots = int(max_slots)
         self.token_budget = int(token_budget)
@@ -203,6 +204,18 @@ class Scheduler:
                 )
             self.window_pool = PagePool(int(window_num_pages))
             self.null_page_win = self.window_pool.num_pages
+        # a model with state layers: beside its pages a slot holds a
+        # recurrent state, which is the slot's own (no id, no refcount) and
+        # sums exactly the tokens its request was fed since position 0
+        self.slot_state = bool(slot_state)
+        if self.slot_state and (
+                self.prefix_cache is not None or self.spiller is not None
+                or self.spec_max_draft):
+            raise ValueError(
+                "a prefix hit, a host page or a rejected draft leaves a "
+                "slot's state out of step with its pages: none serves a "
+                "model with state layers"
+            )
 
     # -------------------------------------------------------------- intake
     def submit(self, request: Request) -> RequestState:
@@ -496,6 +509,12 @@ class Scheduler:
                 "adopt: a handed-off request brings full-layer pages alone; "
                 "a model with window layers needs its window pages too"
             )
+        if self.slot_state:
+            raise RuntimeError(
+                "adopt: a handed-off request brings the sparse layers' pages "
+                "alone; a model with state layers needs the state that "
+                "summed the same tokens too, and a state is no page"
+            )
         if not self._free:
             raise RuntimeError("adopt: no free slot")
         if state.status is not RequestStatus.DECODE:
@@ -588,9 +607,19 @@ class Scheduler:
         cache's host chains — and HBM free + HBM live + host-resident
         must equal the total logical page count. A mid-demotion failure
         (full host store) mutates nothing, so this holds on every tick
-        including the rollback path."""
+        including the rollback path.
+
+        A model with state layers (``slot_state``): the pages audited are
+        its sparse layers' alone, and what the lightning layers hold is no
+        page, so the ledger says nothing of it; what it can hold them to is
+        that no page is shared (a second holder's state never saw the
+        page's tokens): every live page has one reference."""
         if not self.paged:
             return
+        if self.slot_state:
+            assert self.prefix_cache is None and int(
+                self.pool.refcount.max(initial=0)) <= 1, (
+                "a page of a model with state layers has a second holder")
         live = [st for st in self.slots if st is not None]
         held = chain.from_iterable(st.pages for st in live)
         if self.spiller is not None:

@@ -431,6 +431,51 @@ def test_deepseek_slot_step_keeps_both_pools_in_place(one_chip, monkeypatch,
         assert name in text
 
 
+def test_minicpm_sala_slot_step_keeps_pools_and_states_in_place(
+        one_chip, monkeypatch, capsys):
+    """The one [4, 128] serving step of MiniCPM-SALA at its published widths
+    and the benchmark's cut (published layers 16-27: 3 sparse, 9 lightning)
+    over its arena of 33,056 pages: the sparse layers' K / V pages and
+    compressed keys and the lightning layers' slot states ride every run's
+    scan as one carry, so the compiled step holds no copy, slice or
+    write-back the size of a pool or of the state stack; the three named
+    kernels (and the dense GQA models' paged kernel, for steps inside
+    dense_len) are in it; and it fits the chip."""
+    from deepspeed_tpu.models import minicpm
+    from deepspeed_tpu.models.decoding import init_paged_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = minicpm("minicpm-sala", layer_ids=list(range(16, 28)))
+    cfg = model.config
+    assert cfg.mixer_types.count("sparse") == 3
+    N, W, ps, cap = 4, 128, 16, 132096
+    caches = jax.eval_shape(
+        lambda: init_paged_cache(cfg, 33056, ps, BF16, max_slots=N))
+    assert caches["state"].shape == (9, N, 32, 128, 128)
+    assert caches["state"].dtype == F32 and caches["kc"].shape[2:] == (2, 128)
+    compiled = _compile_slot_step(model, caches, one_chip, N, W,
+                                  -(-(cap + W) // ps))
+    m = compiled.memory_analysis()
+    pools = sum(a.size * a.dtype.itemsize for a in caches.values())
+    with capsys.disabled():
+        print(f"\nminicpm-sala slot step, described v5e: arguments "
+              f"{m.argument_size_in_bytes / GIB:.2f} GiB, temporaries "
+              f"{m.temp_size_in_bytes / GIB:.2f} GiB, aliased "
+              f"{m.alias_size_in_bytes / GIB:.2f} (pools and states "
+              f"{pools / GIB:.2f})")
+    text = compiled.as_text()
+    # the head runs over each slot's window: no chunk-wide logits
+    assert [dims for dims in set(re.findall(r"\bf32\[([\d,]+)\]", text))
+            if np.prod([int(d) for d in dims.split(",")])
+            == N * W * cfg.vocab_size] == []
+    assert _pool_copies(text, caches) == []
+    assert m.alias_size_in_bytes >= pools
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * GIB
+    for name in ("lightning_attention", "block_select",
+                 "block_sparse_attention", "paged_attention_full"):
+        assert name in text
+
+
 def test_glm_train_step_fits_the_described_chip(topo, monkeypatch, capsys):
     """The train step of the cell ``glm47flash-pretrain-4k`` (its
     configuration file's model and ds_config, micro-batch 2 x 4,096,
