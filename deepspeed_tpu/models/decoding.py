@@ -122,6 +122,98 @@ def verify_window_rows(num_new, spec_len, max_draft: int, width: int):
     )
 
 
+def row_layout(topology) -> Tuple[str, Optional[str]]:
+    """How a ragged step traced under ``topology`` lays out the rows of its
+    row-by-row layers: ``("packed", None)``, or ``("slots", reason)`` where
+    the mesh shards the step's slot axis (``constrain(h, ("dp", "fsdp"),
+    ...)``): a ``[1, token_budget]`` block has no slot axis to shard."""
+    ways = 1 if topology is None else (
+        topology.sizes.get("dp", 1) * topology.sizes.get("fsdp", 1))
+    if ways > 1:
+        return "slots", (
+            f"the mesh shards the step's slot axis {ways} ways (dp x fsdp), "
+            "and a [1, token_budget] block of packed rows has no slot axis")
+    return "packed", None
+
+
+class ChunkRows:
+    """The rows a cached forward computes for a ``[B, S]`` chunk, and the
+    way between them and the slot layout.
+
+    Everything that works row by row (embedding, norms, projections, rotary,
+    MLP, experts, residual adds) runs on ``rows.positions``' layout; the
+    cache writes and the attention calls, whose operands are indexed by
+    slot, take ``unpack``-ed ``[B, S, ...]`` blocks and hand their output
+    back through ``pack``.
+
+    Identity (``budget`` None, or one slot): the rows ARE the slots'
+    ``[B, S]`` and ``pack`` / ``unpack`` return their argument: the lockstep
+    engine and ``generate``, where every row is real.
+
+    Packed (``num_new`` [B] with the caller's promise that it sums to at
+    most ``budget``: the scheduler's invariant 1): the rows are ``[1,
+    budget]``, the real tokens slot after slot in chunk order and idle rows
+    after them. Slot ``b`` owns the contiguous run ``start[b] : start[b] +
+    num_new[b]``, so ``unpack`` is ``B`` slices of ``S`` rows out of the
+    block padded by ``S`` (rows past a slot's ``num_new`` hold its
+    neighbours' rows: garbage past its frontier, as padding always was),
+    and ``pack`` is a gather of ``budget`` rows."""
+
+    def __init__(self, B: int, S: int, cache_len, num_new=None,
+                 budget: Optional[int] = None):
+        self.B, self.S = B, S
+        steps = jnp.arange(S, dtype=jnp.int32)
+        if _is_ragged(cache_len):
+            self.slot_positions = (
+                cache_len[:, None].astype(jnp.int32) + steps[None, :])
+        else:
+            self.slot_positions = cache_len + jnp.broadcast_to(steps, (B, S))
+        self.packed = budget is not None and num_new is not None and B > 1
+        if not self.packed:
+            self.count, self.valid = B * S, None
+            self.positions = self.slot_positions
+            return
+        self.count = T = int(budget)
+        ends = jnp.cumsum(num_new.astype(jnp.int32))
+        self.start = jnp.minimum(ends - num_new, T)
+        row = jnp.arange(T, dtype=jnp.int32)
+        slot = jnp.minimum(
+            jnp.sum(row[:, None] >= ends[None, :], axis=1), B - 1)
+        # each packed row's row of the flattened slot layout (an idle row
+        # re-reads a row of the last slot)
+        self._src = slot * S + jnp.clip(row - self.start[slot], 0, S - 1)
+        self.valid = (row < ends[-1])[None, :]
+        self.positions = self.pack(self.slot_positions)
+
+    def pack(self, x: jax.Array) -> jax.Array:
+        """``[B, S, ...]`` by slot -> the computed rows ``[1, T, ...]``."""
+        if not self.packed:
+            return x
+        return x.reshape(self.B * self.S, *x.shape[2:])[self._src][None]
+
+    def unpack(self, x: jax.Array) -> jax.Array:
+        """The computed rows ``[1, T, ...]`` -> ``[B, S, ...]`` by slot."""
+        if not self.packed:
+            return x
+        block = jnp.concatenate(
+            [x[0], jnp.zeros((self.S, *x.shape[2:]), x.dtype)], axis=0)
+        # (a slice a slot, stacked: on the chip one fusion at the bandwidth
+        # of its output; the vmapped form is a gather that takes twice as
+        # long once a slot's slice is tens of MB: my chip run, PR 42)
+        return jnp.stack([
+            lax.dynamic_slice_in_dim(block, self.start[b], self.S)
+            for b in range(self.B)])
+
+    def take(self, x: jax.Array, chunk_rows: jax.Array) -> jax.Array:
+        """Rows ``chunk_rows`` [B, K] (indices into each slot's chunk, as
+        :func:`verify_window_rows` gives them) of the computed rows ``x``
+        (the hidden state): ``[B, K, D]``."""
+        if not self.packed:
+            return jnp.take_along_axis(x, chunk_rows[:, :, None], axis=1)
+        return x[0][jnp.minimum(self.start[:, None] + chunk_rows,
+                                self.count - 1)]
+
+
 WIN = "_win"  # suffix of the window layers' pool leaves and page table
 LATENT, INDEX = "kv", "ki"  # a latent model's pools: latent rows, indexer keys
 
@@ -388,11 +480,17 @@ def _qkv(cfg: TransformerConfig, p: Params, x: jax.Array, positions: jax.Array,
 
 
 def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
-                      positions: jax.Array, layer, k_cache: jax.Array,
+                      rows: ChunkRows, layer, k_cache: jax.Array,
                       v_cache: jax.Array, cache_len,
                       k_scale=None, v_scale=None, page_table=None,
                       num_new=None, kind: str = "full"):
-    """Attend new tokens (x, [B,S,D]) against cache[:cache_len] + themselves.
+    """Attend new tokens against cache[:cache_len] + themselves.
+
+    ``x`` holds the rows ``rows`` computes (``[B, S, D]``, or ``[1, T, D]``
+    packed): the projections, their biases, QK-norm, rotary and the KV
+    quantisation run on those rows; q, k and v are unpacked to the slot
+    layout ``[B, S, ...]`` for the cache write and the attention call, and
+    the attention's output is packed again before ``wo``.
 
     ``k_cache``/``v_cache`` (and the scales) are the whole stacks of the
     layer's pool, ``[L, ...]`` as init_cache / init_paged_cache give them,
@@ -433,10 +531,10 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
     other kernels (which know no window) leave such a layer to the XLA
     lines.
     """
-    B, S, _ = x.shape
+    B, S = rows.B, rows.S
     nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.hd
     window = cfg.window_of(kind)
-    q, k, v = _qkv(cfg, p, x, positions, kind)
+    q, k, v = _qkv(cfg, p, x, rows.positions, kind)
 
     quantized = k_scale is not None
     paged = page_table is not None
@@ -446,15 +544,20 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
     else:
         put, put_scale = _update_at, _update_scale_at
         where = (layer, cache_len)
+
+    def write(stack, new):
+        return put(stack, rows.unpack(new), *where)
+
     if quantized:
         kq, ks = _quantize_kv(k)
         vq, vs = _quantize_kv(v)
-        k_cache, v_cache = put(k_cache, kq, *where), put(v_cache, vq, *where)
-        k_scale = put_scale(k_scale, ks, *where)
-        v_scale = put_scale(v_scale, vs, *where)
+        k_cache, v_cache = write(k_cache, kq), write(v_cache, vq)
+        k_scale = put_scale(k_scale, rows.unpack(ks), *where)
+        v_scale = put_scale(v_scale, rows.unpack(vs), *where)
     else:
-        k_cache = put(k_cache, k.astype(k_cache.dtype), *where)
-        v_cache = put(v_cache, v.astype(v_cache.dtype), *where)
+        k_cache = write(k_cache, k.astype(k_cache.dtype))
+        v_cache = write(v_cache, v.astype(v_cache.dtype))
+    q = rows.unpack(q)
 
     def at_layer(stack):
         # what cannot take a stack reads its layer (post-write) as a slice
@@ -467,7 +570,7 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
         return out, k_cache, v_cache
 
     def project(out):
-        out = out.astype(x.dtype).reshape(B, S, nh * hd)
+        out = rows.pack(out.astype(x.dtype).reshape(B, S, nh * hd))
         out = _out_proj(out, p["wo"])
         if cfg.use_bias:
             out = out + p["bo"]
@@ -545,7 +648,8 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
             if cfg.pos_embedding == "alibi"
             else None
         )
-        return project(attn_op(q, k, v, causal=True, alibi_slopes=slopes))
+        return project(attn_op(q, rows.unpack(k), rows.unpack(v), causal=True,
+                               alibi_slopes=slopes))
     if S == 1 and kernel_ok:
         # fused decode path: Pallas cached-KV attention over the contiguous
         # (or gathered) view when shapes fit
@@ -566,11 +670,14 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
 
 
 def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
-                             positions: jax.Array, layer, pools: Cache,
+                             rows: ChunkRows, layer, pools: Cache,
                              cache_len, page_table, num_new=None):
-    """Latent attention of new tokens ``x`` [B,S,D] over the paged latent
-    cache, in the absorbed form: returns (out [B,S,D], the pools with this
-    layer's rows written in place).
+    """Latent attention of new tokens ``x`` (the rows ``rows`` computes:
+    [B,S,D], or [1,T,D] packed) over the paged latent cache, in the absorbed
+    form: returns (out, in x's layout, and the pools with this layer's rows
+    written in place). The projections, the indexer's and the two ``wkv_b``
+    products run on the computed rows; the cache writes and the attention
+    calls take the slot layout.
 
     A token caches its normed ``kv_latent_dim``-wide latent and ONE rotated
     ``qk_rope_dim``-wide key for all heads (``pools[LATENT]``); a head's
@@ -587,10 +694,11 @@ def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
     otherwise the XLA lines gather a per-slot view."""
     from ..ops.pallas import sparse_latent_attention as sla
 
-    B, S, _ = x.shape
+    B, S, _ = x.shape  # of the computed rows
     H, kl, rd = cfg.num_heads, cfg.kv_latent_dim, cfg.qk_rope_dim
     nope, vd, eps = cfg.qk_nope_dim, cfg.v_head_dim, cfg.norm_eps
     table = cfg.rope_of("full")
+    positions = rows.positions
     c_q, q_nope, q_pe, c_kv, k_pe = _latent_projections(cfg, p, x, positions)
     pad = latent_row_width(cfg) - cfg.latent_width
 
@@ -601,13 +709,14 @@ def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
 
     pools = dict(pools)
     pools[LATENT] = _paged_write(
-        pools[LATENT], row(c_kv, k_pe[:, :, 0]).astype(pools[LATENT].dtype),
+        pools[LATENT],
+        rows.unpack(row(c_kv, k_pe[:, :, 0]).astype(pools[LATENT].dtype)),
         layer, cache_len, page_table)
     # the key half of wkv_b absorbed into the query, the value half applied
     # to the attended latents: [kl, H, nope | vd]
     wkv_b = p["wkv_b"].reshape(kl, H, nope + vd)
-    q_abs = row(jnp.einsum("bshn,chn->bshc", q_nope, wkv_b[..., :nope]),
-                q_pe)
+    q_abs = rows.unpack(row(
+        jnp.einsum("bshn,chn->bshc", q_nope, wkv_b[..., :nope]), q_pe))
     scale = cfg.hd ** -0.5 * cfg.attn_scale_mult
 
     q_idx = w_idx = None
@@ -629,8 +738,9 @@ def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
             "bsd,dh->bsh", x.astype(jnp.float32),
             ix["w_proj"].astype(jnp.float32)) * (Hi ** -0.5 * Di ** -0.5)
         pools[INDEX] = _paged_write(
-            pools[INDEX], k_idx.astype(pools[INDEX].dtype), layer, cache_len,
-            page_table)
+            pools[INDEX], rows.unpack(k_idx.astype(pools[INDEX].dtype)),
+            layer, cache_len, page_table)
+        q_idx, w_idx = rows.unpack(q_idx), rows.unpack(w_idx)
 
     from ..ops.attention import _resolve
 
@@ -655,13 +765,14 @@ def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
 
         kv_view = view(LATENT)
         chosen = jnp.arange(kv_view.shape[1])[None, None, :] <= (
-            positions[..., None])
+            rows.slot_positions[..., None])
         if cfg.index_topk:
             chosen = sla.dense_selection(
-                sla.dense_index_scores(q_idx, w_idx, view(INDEX)), positions,
-                cfg.index_topk)
+                sla.dense_index_scores(q_idx, w_idx, view(INDEX)),
+                rows.slot_positions, cfg.index_topk)
         out = sla.dense_sparse_attention(q_abs, kv_view, chosen, scale, kl)
-    out = jnp.einsum("bshc,chv->bshv", out.astype(x.dtype), wkv_b[..., nope:])
+    out = jnp.einsum("bshc,chv->bshv", rows.pack(out.astype(x.dtype)),
+                     wkv_b[..., nope:])
     return _out_proj(out.reshape(B, S, H * vd), p["wo"]), pools
 
 
@@ -717,9 +828,21 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
                        page_table_win=None,
                        token_valid=None,
                        num_new=None,
+                       token_budget: Optional[int] = None,
                        logit_rows=None,
                        return_moe_stats: bool = False):
     """Run new tokens through all layers against the cache.
+
+    Which rows are computed. ``token_budget`` (with ``num_new``) is the
+    caller's promise that the chunk holds at most that many real tokens
+    (the slot engine: the scheduler's invariant 1). The residual stream is
+    then ``[1, token_budget, D]``, the real tokens slot after slot
+    (:class:`ChunkRows`), and the embedding, every norm, projection, rotary,
+    router, MLP, expert dispatch and residual add run on those rows, not on
+    ``B x S``; the cache writes and the attention calls alone see ``[B, S,
+    ...]``. Without it (the lockstep engine, ``generate``: every row real),
+    or on a mesh that shards the slot axis (:func:`row_layout`), the same
+    lines run on the slots' ``[B, S]`` rows.
 
     input_ids: [B, S] (prefill) or [B, 1] (decode). cache_len: tokens already
     cached — a shared scalar, or a per-row [B] vector for the serving
@@ -753,17 +876,17 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
     """
     B, S = input_ids.shape
     from ..ops.quantizer import cast_floating
+    from .sharding import current_topology
 
     cast = lambda t: cast_floating(t, dtype)
-    if _is_ragged(cache_len):
-        positions = cache_len[:, None].astype(jnp.int32) + jnp.arange(
-            S, dtype=jnp.int32
-        )[None, :]
-    else:
-        positions = cache_len + jnp.broadcast_to(
-            jnp.arange(S, dtype=jnp.int32), (B, S)
-        )
-    x = cast(params["embed"]["tok"])[input_ids]
+    if token_budget is not None and row_layout(
+            current_topology())[0] != "packed":
+        token_budget = None
+    rows = ChunkRows(B, S, cache_len, num_new, token_budget)
+    positions = rows.positions
+    if rows.packed:
+        token_valid = rows.valid
+    x = cast(params["embed"]["tok"])[rows.pack(input_ids)]
     if cfg.pos_embedding == "learned":
         x = x + cast(params["embed"]["pos"])[positions]
     if cfg.embed_norm:
@@ -776,10 +899,9 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
 
         stacks = {k: cast(params[k]) for k in STACK.values() if k in params}
         x, new_cache = cached_layers(
-            cfg, stacks, x * jnp.asarray(cfg.scale_emb, x.dtype), positions,
+            cfg, stacks, x * jnp.asarray(cfg.scale_emb, x.dtype), rows,
             dict(cache), cache_len, page_table, num_new)
-        if logit_rows is not None:
-            x = jnp.take_along_axis(x, logit_rows[:, :, None], axis=1)
+        x = rows.unpack(x) if logit_rows is None else rows.take(x, logit_rows)
         x = _norm(cfg, cast(params["final_norm"]), x)
         if cfg.dim_model_base:
             x = x / jnp.asarray(cfg.hidden_size / cfg.dim_model_base, x.dtype)
@@ -789,6 +911,9 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
 
     moe = cfg.is_moe
     collect_moe = bool(return_moe_stats) and moe
+    # the most real tokens a step holds: what an expert's capacity is of
+    budget_tokens = rows.count if (
+        rows.packed or token_valid is None) else S
     # Layers of several kinds scan whole periods of the pattern, a period's
     # layers unrolled with their kinds static; one kind scans single layers
     # with the weights as xs. The cache rides the scan as its CARRY, every
@@ -831,13 +956,13 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
                 normed = _norm(cfg, layer["ln1"], h)
                 if cfg.is_latent:
                     a, pools = _latent_cached_attention(
-                        cfg, layer["attn"], normed, positions, index, pools,
+                        cfg, layer["attn"], normed, rows, index, pools,
                         cache_len, page_table, num_new=num_new)
                 else:
                     names = [n + sfx for n in ("k", "v", "k_scale", "v_scale")
                              if n + sfx in pools]
                     a, *updated = _cached_attention(
-                        cfg, layer["attn"], normed, positions, index,
+                        cfg, layer["attn"], normed, rows, index,
                         *(pools[n] for n in names[:2]),
                         cache_len, *(pools[n] for n in names[2:]),
                         page_table=tables[sfx], num_new=num_new, kind=kind,
@@ -850,10 +975,10 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
 
                     # the routed decode path: capacity from the STATIC budget
                     # (token_budget for the slot engine, B·S for lockstep),
-                    # padded rows to the null expert
+                    # padded and idle rows to the null expert
                     m, lstats = moe_serving_mlp(
                         cfg, layer["mlp"], normed, token_valid=token_valid,
-                        budget_tokens=S if token_valid is not None else B * S,
+                        budget_tokens=budget_tokens,
                     )
                     stats.append(lstats)
                 else:
@@ -880,8 +1005,7 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
          layers if period == 1 else None))
     if collect_moe:  # [trips, period, ...] -> one row a layer
         lstats = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), lstats)
-    if logit_rows is not None:
-        x = jnp.take_along_axis(x, logit_rows[:, :, None], axis=1)
+    x = rows.unpack(x) if logit_rows is None else rows.take(x, logit_rows)
     x = _norm(cfg, cast(params["final_norm"]), x)
     logits = lm_head_logits(cfg, params, x)
     if return_moe_stats:

@@ -201,8 +201,9 @@ def init_pools(cfg: TransformerConfig, num_pages: int, page_size: int,
 
 # ---------------------------------------------------------------- mixers
 def _heads(cfg, p, x, kv_heads: int):
-    """Normed hidden states -> (q [B,S,H,hd], k, v [B,S,kv_heads,hd]),
-    RMSNorm over every head of q and k."""
+    """Normed hidden states (the computed rows: [B,S,d], or [1,T,d] packed)
+    -> (q [B,S,H,hd], k, v [B,S,kv_heads,hd]) in the same layout, RMSNorm
+    over every head of q and k."""
     B, S, _ = x.shape
     q = (x @ p["wq"]).reshape(B, S, cfg.num_heads, cfg.hd)
     k = (x @ p["wk"]).reshape(B, S, kv_heads, cfg.hd)
@@ -223,15 +224,17 @@ def _kernels_registered() -> bool:
     return _resolve() == "flash"
 
 
-def lightning_mixer(cfg, p, x, positions, state, index, layer_id, cache_len,
+def lightning_mixer(cfg, p, x, rows, state, index, layer_id, cache_len,
                     num_new, note):
-    """A lightning layer's mixer over the chunk ``x`` [B,S,d] (normed):
-    (out [B,S,d], the state stack with ``[index]`` advanced in place)."""
+    """A lightning layer's mixer over the normed rows ``x`` that ``rows``
+    computes: (out, in x's layout, and the state stack with ``[index]``
+    advanced in place). Projections, norms, rotary and the gate run on the
+    computed rows; the attention and its state take the slot layout."""
     from ..ops.pallas import lightning_attention as la
 
-    B, S, _ = x.shape
     q, k, v = _heads(cfg, p, x, cfg.num_heads)
-    q, k = _rope(q, k, positions, cfg.rope_of(LIGHTNING))
+    q, k = _rope(q, k, rows.positions, cfg.rope_of(LIGHTNING))
+    q, k, v = map(rows.unpack, (q, k, v))
     ll, scale = log_decay(cfg, layer_id), cfg.hd ** -0.5
     if _kernels_registered():
         note("lightning_kernel", (), LIGHTNING)
@@ -244,21 +247,23 @@ def lightning_mixer(cfg, p, x, positions, state, index, layer_id, cache_len,
             q, k, v, ll, lax.dynamic_index_in_dim(state, index, 0, False),
             cache_len, num_new, scale=scale)
         state = lax.dynamic_update_index_in_dim(state, after, index, 0)
-    o = _rms_last(o.reshape(B, S, -1), p["o_norm"]["scale"], cfg.norm_eps)
+    o = _rms_last(rows.pack(o.reshape(rows.B, rows.S, -1)),
+                  p["o_norm"]["scale"], cfg.norm_eps)
     return _gated_out(p, x, o), state
 
 
 def sparse_mixer(cfg, p, x, pools, index, cache_len, num_new, page_table,
-                 positions, note):
-    """A sparse layer's mixer: (out [B,S,d], the pools with this layer's
-    keys, values and compressed keys written in place)."""
+                 rows, note):
+    """A sparse layer's mixer over the normed rows ``x`` that ``rows``
+    computes: (out, in x's layout, and the pools with this layer's keys,
+    values and compressed keys written in place)."""
     from ..ops.pallas import block_sparse_attention as bsa
     from ..ops.pallas.paged_attention import paged_attention
     from .decoding import _paged_gather, _paged_write
 
-    B, S, _ = x.shape
-    geom = cfg.block_sparse
-    q, k, v = _heads(cfg, p, x, cfg.kv_heads)
+    B, S, geom = rows.B, rows.S, cfg.block_sparse
+    positions = rows.slot_positions
+    q, k, v = map(rows.unpack, _heads(cfg, p, x, cfg.kv_heads))
     pools = dict(pools)
     for name, new in (("k", k), ("v", v)):
         pools[name] = _paged_write(pools[name], new.astype(pools[name].dtype),
@@ -301,17 +306,19 @@ def sparse_mixer(cfg, p, x, pools, index, cache_len, num_new, page_table,
             page_table)
         out = bsa.dense_block_attention(q, view("k"), view("v"), kept,
                                         positions, geom)
-    return _gated_out(p, x, out.reshape(B, S, -1).astype(x.dtype)), pools
+    out = rows.pack(out.reshape(B, S, -1).astype(x.dtype))
+    return _gated_out(p, x, out), pools
 
 
-def cached_layers(cfg: TransformerConfig, params: Params, x, positions,
+def cached_layers(cfg: TransformerConfig, params: Params, x, rows,
                   pools, cache_len, page_table, num_new):
-    """Every layer in published order over the chunk ``x`` [B,S,d]:
-    (hidden [B,S,d], the pools). ``params``: already in the compute type."""
+    """Every layer in published order over the rows ``x`` that ``rows``
+    (``decoding.ChunkRows``) computes, [B,S,d] or [1,T,d] packed: (hidden in
+    the same layout, the pools). ``params``: already in the compute type."""
     from .decoding import _note_attention_path as note
 
     if num_new is None:
-        num_new = jnp.full(x.shape[:1], x.shape[1], jnp.int32)
+        num_new = jnp.full((rows.B,), rows.S, jnp.int32)
     branch = cfg.scale_depth / math.sqrt(cfg.mixer_depth)
     ids = jnp.asarray(cfg.mixer_layer_ids, jnp.int32)
     done = 0
@@ -330,13 +337,13 @@ def cached_layers(cfg: TransformerConfig, params: Params, x, positions,
             normed = _norm(cfg, layer["ln1"], h)
             if kind == LIGHTNING:
                 a, state = lightning_mixer(
-                    cfg, layer["attn"], normed, positions, pools[STATE],
+                    cfg, layer["attn"], normed, rows, pools[STATE],
                     index, layer_id, cache_len, num_new, note)
                 pools = {**pools, STATE: state}
             else:
                 a, pools = sparse_mixer(
                     cfg, layer["attn"], normed, pools, index, cache_len,
-                    num_new, page_table, positions, note)
+                    num_new, page_table, rows, note)
             h = h + branch * a
             m, _ = _mlp(cfg, layer["mlp"], _norm(cfg, layer["ln2"], h),
                         rng=None, train=False, dense=True)

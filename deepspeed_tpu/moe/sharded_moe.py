@@ -637,6 +637,15 @@ def moe_serving_mlp(cfg, p: Dict, x: jax.Array,
     """Routed expert MLP for the decode/serving path (ISSUE 14):
     x [B, S, D] → (out [B, S, D], load-balance stats).
 
+    Rows computed: the router, the placement and the combine run over the
+    ``B·S`` rows of ``x`` as they come. The slot engine hands the plan's
+    tokens packed to ``[1, token_budget, D]`` (``models/decoding.ChunkRows``:
+    real rows first, slot after slot in chunk order, which is the order the
+    valid rows of ``[max_slots, token_budget]`` had; idle rows after them,
+    marked by ``token_valid``), so none of them runs over a slot's padding;
+    the expert matmuls run over capacity rows from ``budget_tokens`` either
+    way.
+
     The serving engine's contract, end to end:
 
     - **capacity from the static token budget** — ``budget_tokens`` is

@@ -61,8 +61,8 @@ from ..inference.engine import (InferenceEngine, _align_cache,
                                 init_inference)
 from ..models.decoding import (SCALE_LANES, WIN, forward_with_cache,
                                init_cache, init_paged_cache, paged_cow_copy,
-                               record_attention_path, staged_promote,
-                               verify_window_rows)
+                               record_attention_path, row_layout,
+                               staged_promote, verify_window_rows)
 from ..models.sharding import use_topology
 from ..profiling import steptrace as _steptrace
 from ..profiling.steptrace import Phase
@@ -429,10 +429,18 @@ def make_step_fn(cfg, dtype, vocab: int, cache_shardings=None,
 
     ``max_draft`` is STATIC (the step's fixed output shape
     [N, max_draft + 1]); 0 disables speculation and reduces the verify
-    window to the pre-spec single-token sampling tail, bitwise. The final
-    norm and the head run over that window's rows alone
-    (``verify_window_rows``), not the [N, W] rows the layers computed:
-    nothing else of the chunk has logits.
+    window to the pre-spec single-token sampling tail, bitwise.
+
+    Rows computed: the scheduler never plans more than W real tokens a step
+    (its invariant 1), and the step hands that promise on
+    (``token_budget=W``), so the layers' row-by-row work (embedding, norms,
+    projections, rotary, routers, MLPs, expert dispatch, residual adds) runs
+    over W packed rows, not N x W; the cache writes and the attention calls
+    alone take the [N, W] slot layout (``models/decoding.ChunkRows``), and a
+    mesh that shards the slot axis keeps it throughout
+    (``ServingEngine.row_layout``). The final norm and the head run over the
+    verify window's rows alone (``verify_window_rows``): nothing else of the
+    chunk has logits.
 
     ``page_table`` / ``page_table_win`` (keywords) are the paged step's:
     :func:`make_paged_step_fn` is this step over its pools.
@@ -471,7 +479,7 @@ def make_step_fn(cfg, dtype, vocab: int, cache_shardings=None,
         logits, caches, *moe_stats = forward_with_cache(
             cfg, params, tokens, caches, start_pos, dtype=dtype,
             page_table=page_table, page_table_win=page_table_win,
-            num_new=num_new,
+            num_new=num_new, token_budget=tokens.shape[1],
             token_valid=token_valid, logit_rows=rows, return_moe_stats=moe,
         )
         if cache_shardings is not None:
@@ -812,6 +820,13 @@ class ServingEngine:
         # rows a step norms and projects to the vocabulary: each slot's
         # verify window, not its chunk
         self.metrics.head_rows_per_step = N * (self.max_draft + 1)
+        # rows the layers' row-by-row work runs over: the plan's tokens
+        # packed to the budget, or every slot's chunk where the mesh shards
+        # the slot axis (models/decoding.row_layout, which the step's trace
+        # asks too)
+        self.row_layout, self.row_layout_reason = row_layout(self.topology)
+        self.metrics.dense_rows_per_step = (
+            W if self.row_layout == "packed" else N * W)
         if self.tiered:
             from .paging import HostPageStore, PageSpiller, export_pages
 
@@ -1067,7 +1082,7 @@ class ServingEngine:
             f"{'int8' if engine.kv_cache_quantized else jnp.dtype(engine.kv_cache_storage_dtype).name}, "
             f"tp={self.topology.tp_size}, spec="
             f"{f'ngram(k<={self.max_draft})' if self.max_draft else 'off'}"
-            f", order={self.step_order}"
+            f", order={self.step_order}, rows={self.row_layout}"
             + (
                 f", moe=ep{self.moe_ep}/{self.moe_a2a_form}"
                 if self.moe_serving else ""
@@ -1259,7 +1274,9 @@ class ServingEngine:
             # the call it describes (free while no trace is being taken)
             with use_topology(self.topology), self.engine._impl_ctx(), \
                     a2a_scope(self._a2a_cfg), \
-                    jax.profiler.TraceAnnotation("serve/device_step", **keys):
+                    jax.profiler.TraceAnnotation(
+                        "serve/device_step", **keys,
+                        dense_rows=self.metrics.dense_rows_per_step):
                 # the plan's numpy vectors go to the jitted call as they are:
                 # it uploads them itself, without a device_put apiece
                 outs = self._step(

@@ -139,6 +139,10 @@ class ServingMetrics:
         # gauges (last observed)
         self.head_rows_per_step = 0   # rows a step projects to the
         #   vocabulary: max_slots x (max_draft + 1), set once an engine
+        self.dense_rows_per_step = 0  # rows a step's row-by-row layers
+        #   (norms, projections, MLPs, routers) run over: token_budget where
+        #   the step packs the plan's tokens (ServingEngine.row_layout),
+        #   max_slots x token_budget where it keeps the slot layout
         self.attention_paged_kernel = 0.0  # 1 when the compiled step's
         #   attention is the paged Pallas kernel (ServingEngine
         #   .attention_path; 0 = the dense XLA lines or not compiled yet)
@@ -442,6 +446,7 @@ class ServingMetrics:
                 self.mean_accepted_tokens_per_step,
             "attention_paged_kernel": self.attention_paged_kernel,
             "head_rows_per_step": self.head_rows_per_step,
+            "dense_rows_per_step": self.dense_rows_per_step,
             "filter_steps": self.filter_steps,
         }
         for kind in self.attended_keys:

@@ -39,13 +39,15 @@ def layer_loop_forward(cfg, params, input_ids, cache, cache_len, *,
             x = x + params["embed"]["pos"][positions]
         if cfg.embed_norm:
             x = _norm(cfg, params["embed_norm"], x)
-        return x, positions
+        return x
 
     @functools.partial(jax.jit, static_argnames=("kind",))
-    def one_layer(x, layer, own_leaves, positions, cache_len, table, num_new,
+    def one_layer(x, layer, own_leaves, cache_len, table, num_new,
                   token_valid, kind):
+        # (the slots' own rows: the oracle packs nothing)
+        rows = decoding.ChunkRows(B, S, cache_len)
         a, *written = decoding._cached_attention(
-            cfg, layer["attn"], _norm(cfg, layer["ln1"], x), positions, 0,
+            cfg, layer["attn"], _norm(cfg, layer["ln1"], x), rows, 0,
             *own_leaves[:2], cache_len, *own_leaves[2:],
             page_table=table, num_new=num_new, kind=kind)
         x = x + a
@@ -59,7 +61,7 @@ def layer_loop_forward(cfg, params, input_ids, cache, cache_len, *,
         return x + m, written
 
     cache_len = jnp.asarray(cache_len, jnp.int32)
-    x, positions = embed(input_ids, cache_len)
+    x = embed(input_ids, cache_len)
     own = {n: [a[i:i + 1] for i in range(a.shape[0])]
            for n, a in cache.items()}
     used = {sfx: 0 for sfx in tables}
@@ -70,7 +72,7 @@ def layer_loop_forward(cfg, params, input_ids, cache, cache_len, *,
         names = [n + sfx for n in LEAVES if n + sfx in own]
         x, written = one_layer(
             x, jax.tree.map(lambda a: a[i], params["layers"]),
-            [own[n][at] for n in names], positions, cache_len, tables[sfx],
+            [own[n][at] for n in names], cache_len, tables[sfx],
             num_new, token_valid, kind=kind)
         for n, leaf in zip(names, written):
             own[n][at] = leaf

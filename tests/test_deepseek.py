@@ -218,7 +218,8 @@ def test_the_absorbed_form_is_the_plain_form(model, params, shape):
     """One attention block over a fresh chunk: the program scores absorbed
     queries against cached latents and up-projects after the sum; the
     reference builds every head's keys and values. Same numbers."""
-    from deepspeed_tpu.models.decoding import _latent_cached_attention
+    from deepspeed_tpu.models.decoding import (ChunkRows,
+                                               _latent_cached_attention)
 
     cfg = model.config
     S, ps = 48, 16
@@ -229,8 +230,8 @@ def test_the_absorbed_form_is_the_plain_form(model, params, shape):
     normed = reference.rmsnorm(x, {"scale": jnp.ones(cfg.hidden_size)},
                                cfg.norm_eps)
     got, pools = _latent_cached_attention(
-        cfg, a, normed, jnp.arange(S)[None], 2, pools, jnp.zeros(1, jnp.int32),
-        table)
+        cfg, a, normed, ChunkRows(1, S, jnp.zeros(1, jnp.int32)), 2, pools,
+        jnp.zeros(1, jnp.int32), table)
     ones = {"scale": jnp.ones(cfg.hidden_size)}
     with reference.HIGHEST():
         want = fam._attn(x[0], ones, a, shape,

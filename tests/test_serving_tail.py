@@ -297,6 +297,9 @@ def test_filter_steps_counts_and_one_compile_serves_every_mix():
             max_new_tokens=new, rng=jax.random.PRNGKey(7), **kw))
 
     assert srv.metrics.snapshot()["head_rows_per_step"] == 3
+    # the layers' rows: the budget's, packed, not every slot's chunk
+    assert srv.metrics.snapshot()["dense_rows_per_step"] == 8
+    assert (srv.row_layout, srv.row_layout_reason) == ("packed", None)
     submit("greedy", 5, 6)
     submit("warm", 9, 4, temperature=0.9)
     srv.run_until_idle()

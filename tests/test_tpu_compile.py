@@ -198,6 +198,11 @@ WHOLE_CHUNK_TEMP_BYTES = {"mixtral": 298311680, "mellum": 818951680,
 # buffer; the compiler's layout of the rest moves by 0.16 MB for Mixtral,
 # 2.7 MB for Mellum, 0.14 MB for DeepSeek: under a hundredth.)
 PR36_TEMP_MB = {"mixtral": 52.3, "mellum": 374.7, "deepseek": 339.9}
+# and while the layers' row-by-row work still ran over every slot's chunk
+# (the same tests on PR 41's tree, as they print it): (arguments GiB,
+# temporaries GiB). Packed to the budget's rows the step may hold no more
+PR41_ARGS_TEMP_GIB = {"mixtral": (9.35, 0.034), "mellum": (11.11, 0.347),
+                      "deepseek": (10.54, 0.316), "minicpm": (8.95, 0.86)}
 
 
 def _pool_copies(text, caches):
@@ -253,6 +258,34 @@ def _check_head_runs_over_the_window(compiled, N, W, V, family, capsys):
     assert held == []
     assert m.temp_size_in_bytes < WHOLE_CHUNK_TEMP_BYTES[family]
     assert m.temp_size_in_bytes / 1e6 < PR36_TEMP_MB[family] * 1.01
+
+
+def _check_dense_rows_are_the_budgets(compiled, N, W, family, capsys):
+    """The layers' matmuls run over the plan's tokens (PR 42): outside the
+    attention calls (Pallas kernels: no ``convolution``) no matmul of the
+    compiled step has a result of ``N x W`` rows, as ``[N, W, ...]`` or as
+    ``[N * W, ...]``; and the step's arguments and temporaries are no higher
+    than they were with every slot's chunk computed."""
+    text = compiled.as_text()
+    wide = []
+    for m in re.finditer(
+            r"%([\w.\-]+) = \w+\[([\d,]+)\]\S* (?:convolution|dot)\(", text):
+        dims = [int(d) for d in m.group(2).split(",")]
+        # ([N, W] alone is a table a slot a row, not rows of features)
+        if dims[0] == N * W or (dims[:2] == [N, W] and len(dims) > 2):
+            wide.append(f"{m.group(1)} -> [{m.group(2)}]")
+    assert wide == []
+    assert re.search(rf"\[1,{W},[\d,]+\]\S* (?:convolution|dot)\(", text) or (
+        re.search(rf"\[{W},[\d,]+\]\S* (?:convolution|dot)\(", text))
+    m = compiled.memory_analysis()
+    args, temp = PR41_ARGS_TEMP_GIB[family]
+    with capsys.disabled():
+        print(f"{family} slot step, rows packed to {W} of {N * W}: arguments "
+              f"{m.argument_size_in_bytes / GIB:.2f} GiB (were {args}), "
+              f"temporaries {m.temp_size_in_bytes / GIB:.3f} GiB (were "
+              f"{temp})")
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes) / GIB < (
+        args + temp + 0.1)
 
 
 def _check_placement_is_one_pass(compiled, rows, cfg, family, capsys):
@@ -353,8 +386,9 @@ def test_mixtral_slot_step_keeps_its_pools_in_place(one_chip, monkeypatch,
     _check_caches_stay_in_place(compiled, caches, "mixtral", capsys)
     _check_head_runs_over_the_window(compiled, N, W, model.config.vocab_size,
                                      "mixtral", capsys)
-    _check_placement_is_one_pass(compiled, N * W, model.config, "mixtral",
+    _check_placement_is_one_pass(compiled, W, model.config, "mixtral",
                                  capsys)
+    _check_dense_rows_are_the_budgets(compiled, N, W, "mixtral", capsys)
     assert "paged_attention" in compiled.as_text()
 
 
@@ -380,8 +414,9 @@ def test_mellum_slot_step_compiles_beside_its_arena(one_chip, monkeypatch,
     m = _check_caches_stay_in_place(compiled, caches, "mellum", capsys)
     _check_head_runs_over_the_window(compiled, N, W, model.config.vocab_size,
                                      "mellum", capsys)
-    _check_placement_is_one_pass(compiled, N * W, model.config, "mellum",
+    _check_placement_is_one_pass(compiled, W, model.config, "mellum",
                                  capsys)
+    _check_dense_rows_are_the_budgets(compiled, N, W, "mellum", capsys)
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * GIB
     text = compiled.as_text()
     assert "paged_attention_window" in text and "paged_attention_full" in text
@@ -420,8 +455,9 @@ def test_deepseek_slot_step_keeps_both_pools_in_place(one_chip, monkeypatch,
               f"{pools / GIB:.2f})")
     _check_head_runs_over_the_window(compiled, N, W, model.config.vocab_size,
                                      "deepseek", capsys)
-    _check_placement_is_one_pass(compiled, N * W, model.config, "deepseek",
+    _check_placement_is_one_pass(compiled, W, model.config, "deepseek",
                                  capsys)
+    _check_dense_rows_are_the_budgets(compiled, N, W, "deepseek", capsys)
     text = compiled.as_text()
     assert _pool_copies(text, caches) == []
     assert m.alias_size_in_bytes >= pools
@@ -469,6 +505,7 @@ def test_minicpm_sala_slot_step_keeps_pools_and_states_in_place(
             if np.prod([int(d) for d in dims.split(",")])
             == N * W * cfg.vocab_size] == []
     assert _pool_copies(text, caches) == []
+    _check_dense_rows_are_the_budgets(compiled, N, W, "minicpm", capsys)
     assert m.alias_size_in_bytes >= pools
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * GIB
     for name in ("lightning_attention", "block_select",
