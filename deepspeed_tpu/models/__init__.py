@@ -12,6 +12,7 @@ from .mellum import mellum, mellum_config  # noqa: F401
 from .deepseek import deepseek, deepseek_config  # noqa: F401
 from .glm import glm, glm_config  # noqa: F401
 from .minicpm import minicpm, minicpm_config  # noqa: F401
+from .ling import ling, ling_config  # noqa: F401
 
 MODEL_REGISTRY = {
     "gpt2": gpt2,
@@ -22,6 +23,7 @@ MODEL_REGISTRY = {
     "deepseek": deepseek,
     "glm": glm,
     "minicpm": minicpm,
+    "ling": ling,
 }
 
 

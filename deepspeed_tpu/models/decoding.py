@@ -47,10 +47,10 @@ def record_attention_path():
     it took: yields a dict whose ``path`` reads ``paged_kernel`` (a Pallas
     kernel reads the page pool through the table), ``decode_kernel`` (the
     contiguous single-token Pallas kernel) or ``dense`` (the XLA lines),
-    with the ``reasons`` for a dense path, and under ``kinds`` the path of
-    each layer kind. The choice is made at trace time, so the serving
+    with the ``reasons`` for a dense path, and under ``kinds`` /
+    ``kind_reasons`` the path of each layer kind and why it is a dense one. The choice is made at trace time, so the serving
     engine opens this around its step's trace."""
-    rec = {"path": None, "reasons": (), "kinds": {}}
+    rec = {"path": None, "reasons": (), "kinds": {}, "kind_reasons": {}}
     _path_recorders.append(rec)
     try:
         yield rec
@@ -62,6 +62,7 @@ def _note_attention_path(path: str, reasons=(), kind: str = "full") -> None:
     for rec in _path_recorders:
         rec["path"], rec["reasons"] = path, tuple(reasons)
         rec["kinds"][kind] = path
+        rec["kind_reasons"][kind] = tuple(reasons)
 
 
 def _is_ragged(cache_len) -> bool:
@@ -204,6 +205,19 @@ class ChunkRows:
             lax.dynamic_slice_in_dim(block, self.start[b], self.S)
             for b in range(self.B)])
 
+    def origin(self) -> Tuple[jax.Array, jax.Array]:
+        """(slot, row inside its slot's chunk) of every computed row, both
+        in the computed rows' leading layout (``[B, S]``, or ``[1, T]``
+        packed): what a layer that looks back along a slot's rows (a short
+        convolution) needs to know where a slot's run begins."""
+        if not self.packed:
+            shape = (self.B, self.S)
+            return (jnp.broadcast_to(
+                jnp.arange(self.B, dtype=jnp.int32)[:, None], shape),
+                jnp.broadcast_to(
+                    jnp.arange(self.S, dtype=jnp.int32)[None, :], shape))
+        return (self._src // self.S)[None], (self._src % self.S)[None]
+
     def take(self, x: jax.Array, chunk_rows: jax.Array) -> jax.Array:
         """Rows ``chunk_rows`` [B, K] (indices into each slot's chunk, as
         :func:`verify_window_rows` gives them) of the computed rows ``x``
@@ -242,23 +256,27 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
     [L_window, window_pages + 1, ...] the window layers, whose pages a
     slot gives back once every query still to come is past them.
 
-    A model with mixers (``cfg.mixer_types``, models/minicpm.py) keeps
-    ``k``/``v`` pages and a compressed key a page (``kc``) for its sparse
-    layers alone, and for its lightning layers a third kind of leaf that is
-    indexed by SLOT, not through the page table: ``state``
-    [L_lightning, max_slots, heads, hd, hd] float32."""
+    A model with mixers (``cfg.mixer_types``, models/mixers.py) keeps pages
+    for the layers whose kind keeps any (``MIXER_KINDS``: ``k``/``v`` and a
+    compressed key a page, ``kc``, for sparse layers; latent rows ``kv`` for
+    latent layers), and for its state layers leaves that are indexed by
+    SLOT, not through the page table: ``state`` [L_state, max_slots, heads,
+    hd, hd] float32 and, for kda layers, ``conv`` [L_kda, max_slots,
+    conv_kernel - 1, 3 x heads x hd], the short convolution's last rows."""
     if cfg.mixer_types:
         from ..config import DeepSpeedConfigError
-        from .minicpm import init_pools
+        from .mixers import family
 
         if quantized:
             raise DeepSpeedConfigError(
                 "an int8 KV cache is refused: a sparse layer's compressed "
-                "keys are means of its cached keys, and a lightning layer's "
-                "state is float32")
+                "keys are means of its cached keys, a latent layer's row "
+                "takes no per-head scale, and a state layer's state is "
+                "float32")
         if max_slots is None:
             raise ValueError("a model with state layers needs max_slots")
-        return init_pools(cfg, num_pages, page_size, max_slots, dtype)
+        return family(cfg).init_pools(cfg, num_pages, page_size, max_slots,
+                                      dtype)
     if cfg.is_latent:
         if quantized:
             from ..config import DeepSpeedConfigError
@@ -408,9 +426,10 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
         from ..config import DeepSpeedConfigError
 
         raise DeepSpeedConfigError(
-            "a contiguous KV arena is refused: a sparse layer selects blocks "
-            "of pages and a lightning layer keeps a state a slot "
-            "(mixer_types); both live in the paged arena (serving.paged)")
+            "a contiguous KV arena is refused: a sparse or latent layer "
+            "reads its keys through the page table and a state layer keeps "
+            "its state a slot (mixer_types); all live in the paged arena "
+            "(serving.paged)")
     if cfg.is_latent:
         from ..config import DeepSpeedConfigError
 
@@ -671,7 +690,8 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
 
 def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
                              rows: ChunkRows, layer, pools: Cache,
-                             cache_len, page_table, num_new=None):
+                             cache_len, page_table, num_new=None,
+                             kind: str = "full"):
     """Latent attention of new tokens ``x`` (the rows ``rows`` computes:
     [B,S,D], or [1,T,D] packed) over the paged latent cache, in the absorbed
     form: returns (out, in x's layout, and the pools with this layer's rows
@@ -687,11 +707,15 @@ def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
     weighted sum of latents. With an indexer (``cfg.index_topk``) each
     token also caches an index key (``pools[INDEX]``), every cached token at
     or before a query is scored from it, and the query attends its
-    ``index_topk`` best alone.
+    ``index_topk`` best alone. With a head-wise output gate (``p["wgate"]``,
+    ``d -> heads``: a latent layer of models/ling.py) ``y = W_o (sigmoid(x
+    W_g)_h * o)``.
 
-    With the kernel attention registered the three Pallas calls of
-    ops/pallas/sparse_latent_attention.py read the pools through the table;
-    otherwise the XLA lines gather a per-slot view."""
+    With the kernel attention registered the Pallas calls of
+    ops/pallas/sparse_latent_attention.py read the pools through the table
+    (scores, selection and the walk over the chosen with an indexer; the
+    same walk over every key without one); otherwise the XLA lines gather a
+    per-slot view."""
     from ..ops.pallas import sparse_latent_attention as sla
 
     B, S, _ = x.shape  # of the computed rows
@@ -717,7 +741,7 @@ def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
     wkv_b = p["wkv_b"].reshape(kl, H, nope + vd)
     q_abs = rows.unpack(row(
         jnp.einsum("bshn,chn->bshc", q_nope, wkv_b[..., :nope]), q_pe))
-    scale = cfg.hd ** -0.5 * cfg.attn_scale_mult
+    scale = (nope + rd) ** -0.5 * cfg.attn_scale_mult
 
     q_idx = w_idx = None
     if cfg.index_topk:
@@ -748,16 +772,20 @@ def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
     if _resolve() != "flash":
         why_dense.append("the registered attention is not the kernel one")
     elif not cfg.index_topk:
-        why_dense.append("no indexer (the kernels attend a selection)")
+        out, why_dense = sla.latent_attention(
+            q_abs, pools[LATENT], cache_len, page_table, layer=layer,
+            scale=scale, v_width=kl, num_new=num_new)
     else:
         out, why_dense = sla.latent_sparse_attention(
             q_abs, q_idx, w_idx, pools[LATENT], pools[INDEX], cache_len,
             page_table, layer=layer, topk=cfg.index_topk, scale=scale,
             v_width=kl, num_new=num_new)
     if out is not None:
-        _note_attention_path("latent_sparse_kernel")
+        _note_attention_path(
+            "latent_sparse_kernel" if cfg.index_topk else "latent_kernel",
+            kind=kind)
     else:
-        _note_attention_path("dense", why_dense)
+        _note_attention_path("dense", why_dense, kind)
 
         def view(name):
             return _paged_gather(lax.dynamic_index_in_dim(
@@ -773,6 +801,9 @@ def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
         out = sla.dense_sparse_attention(q_abs, kv_view, chosen, scale, kl)
     out = jnp.einsum("bshc,chv->bshv", rows.pack(out.astype(x.dtype)),
                      wkv_b[..., nope:])
+    if "wgate" in p:  # one gate a head
+        gate = jax.nn.sigmoid((x @ p["wgate"]).astype(jnp.float32))
+        out = (gate[..., None] * out.astype(jnp.float32)).astype(x.dtype)
     return _out_proj(out.reshape(B, S, H * vd), p["wo"]), pools
 
 
@@ -893,20 +924,23 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
         x = _norm(cfg, cast(params["embed_norm"]), x)
     x = constrain(x, ("dp", "fsdp"), None, None)
     if cfg.mixer_types:
-        # layers by published order, two parameter stacks, runs of a kind a
-        # scan each over the same carry (models/minicpm.py); muP scales
-        from .minicpm import STACK, cached_layers
+        # layers by published order, a parameter stack a mixer kind and an
+        # MLP kind, runs of equal (mixer, MLP) a scan each over the same
+        # carry (models/mixers.py); muP scales
+        from .mixers import cached_layers, stacks_of
 
-        stacks = {k: cast(params[k]) for k in STACK.values() if k in params}
-        x, new_cache = cached_layers(
-            cfg, stacks, x * jnp.asarray(cfg.scale_emb, x.dtype), rows,
-            dict(cache), cache_len, page_table, num_new)
+        stacks = {k: cast(params[k]) for k in stacks_of(cfg) if k in params}
+        if cfg.scale_emb != 1.0:
+            x = x * jnp.asarray(cfg.scale_emb, x.dtype)
+        x, new_cache, moe_stats = cached_layers(
+            cfg, stacks, x, rows, dict(cache), cache_len, page_table,
+            num_new, token_valid=token_valid)
         x = rows.unpack(x) if logit_rows is None else rows.take(x, logit_rows)
         x = _norm(cfg, cast(params["final_norm"]), x)
         if cfg.dim_model_base:
             x = x / jnp.asarray(cfg.hidden_size / cfg.dim_model_base, x.dtype)
         logits = lm_head_logits(cfg, params, x)
-        return (logits, new_cache, None) if return_moe_stats else (
+        return (logits, new_cache, moe_stats) if return_moe_stats else (
             logits, new_cache)
 
     moe = cfg.is_moe

@@ -10,7 +10,8 @@ QK-norm, an output norm and an output gate, whose cache is a float32 state
 ``[heads, hd, hd]`` a SLOT and no page). The two kinds differ in parameter
 shapes (a lightning layer's ``wk`` / ``wv`` are full width), so each has a
 stack of its own (``sparse_layers``, ``lightning_layers``), read in published
-order: runs of one kind are one ``lax.scan`` each over the cache carry.
+order by the one layer walk (models/mixers.py): runs of one kind are one
+``lax.scan`` each over the cache carry.
 
 muP: the embedding times ``scale_emb``, every residual branch times
 ``scale_depth / sqrt(published depth)``, the hidden state over
@@ -23,19 +24,21 @@ layer's compressed keys exist in the paged arena alone
 from __future__ import annotations
 
 import math
-from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .transformer import (Params, TransformerConfig, TransformerModel, _mlp,
-                          _norm, _rms_last, _rope)
+from .mixers import runs  # noqa: F401  (the walk's; the tests read it here)
+from .transformer import (Params, TransformerConfig, TransformerModel,
+                          _rms_last, _rope)
 
 SPARSE, LIGHTNING = "sparse", "lightning"
 MIXER_KINDS = (SPARSE, LIGHTNING)
 _PUBLISHED_KINDS = {"minicpm4": SPARSE, "lightning-attn": LIGHTNING}
 STACK = {SPARSE: "sparse_layers", LIGHTNING: "lightning_layers"}
+# a layer's dense MLP lies in its mixer's stack, at the mixer's index
+MLP_STACK = {"dense": None, "routed": None}
 # the pools' leaves: compressed keys (a row a page a kv head) and the state
 COMPRESSED, STATE = "kc", "state"
 
@@ -152,19 +155,6 @@ def init(cfg: TransformerConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
     return params
 
 
-def runs(cfg: TransformerConfig) -> List[Tuple[str, int, int]]:
-    """The layers in published order as runs of one kind: (kind, the run's
-    first index inside its kind's stack, its length)."""
-    out, seen = [], {kind: 0 for kind in MIXER_KINDS}
-    for kind in cfg.mixer_types:
-        if out and out[-1][0] == kind:
-            out[-1] = (kind, out[-1][1], out[-1][2] + 1)
-        else:
-            out.append((kind, seen[kind], 1))
-        seen[kind] += 1
-    return out
-
-
 def log_decay(cfg: TransformerConfig, layer_id) -> jax.Array:
     """``log lambda`` [heads] of the lightning layer at published index
     ``layer_id`` (traced or not): ``-s_h (1 - l / (L - 1) + 1e-5)``, ``s_h =
@@ -174,6 +164,14 @@ def log_decay(cfg: TransformerConfig, layer_id) -> jax.Array:
     slope = 2.0 ** (-8.0 * (jnp.arange(H, dtype=jnp.float32) + 1.0) / H)
     depth = max(cfg.mixer_depth - 1, 1)
     return -slope * (1.0 - jnp.asarray(layer_id, jnp.float32) / depth + 1e-5)
+
+
+def slot_leaves(cfg: TransformerConfig, max_slots: int, dtype) -> dict:
+    """What a slot keeps that is no page: the lightning layers' float32
+    state ``[L_lightning, max_slots, heads, hd, hd]``."""
+    return {STATE: jax.ShapeDtypeStruct(
+        (cfg.kind_count(LIGHTNING), max_slots, cfg.num_heads, cfg.hd,
+         cfg.hd), jnp.float32)}
 
 
 def init_pools(cfg: TransformerConfig, num_pages: int, page_size: int,
@@ -193,9 +191,8 @@ def init_pools(cfg: TransformerConfig, num_pages: int, page_size: int,
     pools = {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype),
              COMPRESSED: jnp.zeros((Ls, P1, cfg.kv_heads, cfg.hd), dtype)}
     if cfg.has_state:
-        pools[STATE] = jnp.zeros(
-            (cfg.kind_count(LIGHTNING), max_slots, cfg.num_heads, cfg.hd,
-             cfg.hd), jnp.float32)
+        pools.update({k: jnp.zeros(v.shape, v.dtype) for k, v in
+                      slot_leaves(cfg, max_slots, dtype).items()})
     return pools
 
 
@@ -308,47 +305,3 @@ def sparse_mixer(cfg, p, x, pools, index, cache_len, num_new, page_table,
                                         positions, geom)
     out = rows.pack(out.reshape(B, S, -1).astype(x.dtype))
     return _gated_out(p, x, out), pools
-
-
-def cached_layers(cfg: TransformerConfig, params: Params, x, rows,
-                  pools, cache_len, page_table, num_new):
-    """Every layer in published order over the rows ``x`` that ``rows``
-    (``decoding.ChunkRows``) computes, [B,S,d] or [1,T,d] packed: (hidden in
-    the same layout, the pools). ``params``: already in the compute type."""
-    from .decoding import _note_attention_path as note
-
-    if num_new is None:
-        num_new = jnp.full((rows.B,), rows.S, jnp.int32)
-    branch = cfg.scale_depth / math.sqrt(cfg.mixer_depth)
-    ids = jnp.asarray(cfg.mixer_layer_ids, jnp.int32)
-    done = 0
-    for kind, first, count in runs(cfg):
-        stack = params[STACK[kind]]
-        run_ids = lax.dynamic_slice_in_dim(ids, done, count)
-        done += count
-
-        def body(carry, scanned, kind=kind, stack=stack):
-            h, pools = carry
-            index, layer_id = scanned
-            # one layer at a time out of the whole stack: a run-sized slice
-            # of the weights would be a copy
-            layer = jax.tree.map(
-                lambda a: lax.dynamic_index_in_dim(a, index, 0, False), stack)
-            normed = _norm(cfg, layer["ln1"], h)
-            if kind == LIGHTNING:
-                a, state = lightning_mixer(
-                    cfg, layer["attn"], normed, rows, pools[STATE],
-                    index, layer_id, cache_len, num_new, note)
-                pools = {**pools, STATE: state}
-            else:
-                a, pools = sparse_mixer(
-                    cfg, layer["attn"], normed, pools, index, cache_len,
-                    num_new, page_table, rows, note)
-            h = h + branch * a
-            m, _ = _mlp(cfg, layer["mlp"], _norm(cfg, layer["ln2"], h),
-                        rng=None, train=False, dense=True)
-            return (h + branch * m, pools), None
-
-        (x, pools), _ = lax.scan(
-            body, (x, pools), (first + jnp.arange(count), run_ids))
-    return x, pools

@@ -38,6 +38,35 @@ LAYER_KINDS = ("full", "window")
 
 
 @dataclass(frozen=True)
+class MixerKind:
+    """One kind of ``mixer_types``: the pool leaves it keeps a SLOT (indexed
+    by slot, no page: begun at zero with a request, never shared), those it
+    keeps a PAGE (through the page table), the module of ``models/`` that
+    owns its parameters and pools, and what it needs of the configuration
+    beside its name (a field that must be set, and why)."""
+
+    slot: Tuple[str, ...]
+    page: Tuple[str, ...]
+    family: str
+    needs: Tuple[str, str] = ("", "")
+
+
+MIXER_KINDS: Dict[str, MixerKind] = {
+    # grouped-query attention over a learned selection of blocks of pages
+    "sparse": MixerKind((), ("k", "v", "kc"), "minicpm",
+                        ("block_sparse", "the geometry of its selection")),
+    # linear attention with a decay a head: a float32 state a slot
+    "lightning": MixerKind(("state",), (), "minicpm"),
+    # the gated delta rule with a decay a channel: a float32 state and the
+    # short convolution's last rows a slot
+    "kda": MixerKind(("state", "conv"), (), "ling"),
+    # latent attention over every key of the latent pool
+    "latent": MixerKind((), ("kv",), "ling",
+                        ("kv_latent_dim", "the width of its cached latent")),
+}
+
+
+@dataclass(frozen=True)
 class RopeTable:
     """One rotary table: plain (``theta`` alone) or YaRN-scaled (Peng et
     al. 2023, as HF's ``_compute_yarn_parameters``): frequencies above the
@@ -159,21 +188,28 @@ class TransformerConfig:
     # joins the loss times ``mtp_loss_weight``.
     mtp_layers: int = 0
     mtp_loss_weight: float = 0.3
-    # Mixers by layer (models/minicpm.py): ``mixer_types`` names every layer,
-    # in an order that need be no period, "sparse" (grouped-query attention
-    # over a learned selection of blocks of the paged cache: ``block_sparse``,
-    # an ops/pallas/block_sparse_attention.BlockSparse) or "lightning"
-    # (linear attention whose cache is a state a slot, no page); each kind
-    # has a parameter stack of its own. ``mixer_layer_ids`` gives each layer
-    # its index in the published model of ``mixer_depth`` layers (a cut
-    # keeps both: the decay of a lightning layer and the residual scale
-    # follow them). muP: the embedding times ``scale_emb``, a residual
+    # Mixers by layer (models/mixers.py): ``mixer_types`` names every layer
+    # (the leading dense ones first), in an order that need be no period, by
+    # a kind of ``MIXER_KINDS``: "sparse" (grouped-query attention over a
+    # learned selection of blocks of the paged cache: ``block_sparse``, an
+    # ops/pallas/block_sparse_attention.BlockSparse) and "lightning" (linear
+    # attention whose cache is a state a slot, no page) of models/minicpm.py;
+    # "kda" (the gated delta rule: a state and the last ``conv_kernel - 1``
+    # pre-convolution rows a slot, log-decays bounded by ``kda_lower_bound``)
+    # and "latent" (latent attention over every key of the latent pool) of
+    # models/ling.py. Each kind has a parameter stack of its own; the MLP of
+    # a layer (dense lead | routed) is independent of its mixer.
+    # ``mixer_layer_ids`` gives each layer its index in the published model
+    # of ``mixer_depth`` layers (a cut keeps both: the decay of a lightning
+    # layer and the residual scale follow them). muP: the embedding times ``scale_emb``, a residual
     # branch times ``scale_depth / sqrt(mixer_depth)``, the hidden state
     # over ``hidden_size / dim_model_base`` before the head.
     mixer_types: Tuple[str, ...] = ()
     mixer_layer_ids: Tuple[int, ...] = ()
     mixer_depth: int = 0
     block_sparse: Optional[Any] = None
+    conv_kernel: int = 4
+    kda_lower_bound: float = -5.0
     scale_emb: float = 1.0
     scale_depth: float = 1.0
     dim_model_base: int = 0
@@ -195,9 +231,10 @@ class TransformerConfig:
         if self.moe_gate not in ("softmax", "sigmoid_groups"):
             raise ValueError(
                 f"moe_gate {self.moe_gate!r} (softmax or sigmoid_groups)")
-        if self.index_topk and not self.is_latent:
-            raise ValueError("the indexer scores a latent cache's tokens: "
-                             "index_topk needs kv_latent_dim")
+        if self.index_topk and not (self.is_latent and self.q_latent_dim):
+            raise ValueError("the indexer scores a latent cache's tokens "
+                             "from the query latent: index_topk needs "
+                             "kv_latent_dim and q_latent_dim")
         if self.is_latent and (
                 self.layer_pattern or self.qk_nope_dim + self.qk_rope_dim
                 != self.hd or self.kv_heads != 1):
@@ -214,23 +251,46 @@ class TransformerConfig:
                 "one member's share of an expert-parallel layer "
                 "(moe_routed_experts) is computed under the sigmoid_groups "
                 "router alone")
-        if self.mixer_types and (
-                set(self.mixer_types) - {"sparse", "lightning"}
-                or len(self.mixer_types) != self.num_layers
-                or len(self.mixer_layer_ids) != self.num_layers
-                or self.mixer_depth <= max(self.mixer_layer_ids)
-                or self.block_sparse is None or self.layer_pattern
-                or self.is_latent or self.is_moe):
-            raise ValueError(
-                "mixer_types names every layer 'sparse' or 'lightning', each "
-                "with its published index under mixer_depth, beside a "
-                "block_sparse geometry and no other layer kind")
+        if self.mixer_types:
+            self._check_mixers()
         if self.routed_experts % self.moe_groups or not (
                 0 <= self.moe_first_expert
                 <= self.routed_experts - self.num_experts):
             raise ValueError(
                 f"experts {self.moe_first_expert}..+{self.num_experts} of "
                 f"{self.routed_experts} in {self.moe_groups} groups")
+
+    def _check_mixers(self) -> None:
+        """``mixer_types`` against the table of kinds: what each kind keeps
+        and needs decides what the configuration must bring."""
+        unknown = sorted(set(self.mixer_types) - set(MIXER_KINDS))
+        if unknown:
+            raise ValueError(
+                f"mixer_types names {unknown}: no such mixer kind (have "
+                f"{sorted(MIXER_KINDS)}, see MIXER_KINDS)")
+        names = list(dict.fromkeys(self.mixer_types))
+        families = sorted({MIXER_KINDS[n].family for n in names})
+        if len(families) > 1:
+            raise ValueError(
+                f"mixer_types mixes kinds of models/{families}: the kinds of "
+                "one model share the module that owns their parameter "
+                "stacks and pools")
+        for name in names:
+            field, why = MIXER_KINDS[name].needs
+            if field and not getattr(self, field):
+                raise ValueError(f"a {name!r} mixer needs {field}: {why}")
+        if (len(self.mixer_types) != self.total_layers
+                or len(self.mixer_layer_ids) != self.total_layers
+                or self.mixer_depth <= max(self.mixer_layer_ids)
+                or self.layer_pattern):
+            raise ValueError(
+                "mixer_types names every layer (leading dense ones first), "
+                "each with its published index under mixer_depth, and is "
+                "the only list of layer kinds (no layer_pattern)")
+        if self.kda_lower_bound > 0 or self.conv_kernel < 2:
+            raise ValueError(
+                "kda_lower_bound bounds a log-decay (at most 0) and a short "
+                "convolution has at least 2 taps")
 
     @property
     def kv_heads(self) -> int:
@@ -254,12 +314,27 @@ class TransformerConfig:
 
     @property
     def has_state(self) -> bool:
-        """A layer keeps a recurrent state a slot, which is no page."""
-        return "lightning" in self.mixer_types
+        """A layer keeps leaves a slot (a recurrent state), which are no
+        page."""
+        return any(MIXER_KINDS[k].slot for k in set(self.mixer_types))
+
+    @property
+    def mixer_family(self) -> str:
+        """The module of models/ that owns the mixers' stacks and pools."""
+        return MIXER_KINDS[self.mixer_types[0]].family
+
+    @property
+    def paged_layers(self) -> int:
+        """Layers that keep pages (a state layer keeps none)."""
+        if not self.mixer_types:
+            return self.total_layers
+        return sum(bool(MIXER_KINDS[k].page) for k in self.mixer_types)
 
     @property
     def is_latent(self) -> bool:
-        return self.kv_latent_dim > 0
+        """EVERY layer is latent attention (a model with mixers names its
+        latent layers in ``mixer_types``)."""
+        return self.kv_latent_dim > 0 and not self.mixer_types
 
     @property
     def latent_width(self) -> int:
@@ -293,15 +368,17 @@ class TransformerConfig:
     def num_params(self) -> int:
         """Analytic parameter count (for flops profiler / partition planner)."""
         if self.mixer_types:
-            from .minicpm import num_params
+            from .mixers import family
 
-            return num_params(self)
+            return family(self).num_params(self)
         d, v, L = self.hidden_size, self.vocab_size, self.num_layers
         ln_width = 2 * d if self.norm == "layernorm" else d  # scale (+bias)
         qkvo = d * self.num_heads * self.hd * 2 + d * self.kv_heads * self.hd * 2
         if self.is_latent:
             nh, ql, kl = self.num_heads, self.q_latent_dim, self.kv_latent_dim
-            qkvo = (d * ql + ql + ql * nh * self.hd + d * self.latent_width
+            # (no query latent: one W_q, no norm)
+            wq = d * ql + ql + ql * nh * self.hd if ql else d * nh * self.hd
+            qkvo = (wq + d * self.latent_width
                     + kl + kl * nh * (self.qk_nope_dim + self.v_head_dim)
                     + nh * self.v_head_dim * d)
             if self.index_topk:
@@ -347,15 +424,19 @@ def _latent_attn_params(cfg: "TransformerConfig", nrm, lk, L: int,
     indexer's, under ``idx``), stacked."""
     d, nh = cfg.hidden_size, cfg.num_heads
     ql, kl = cfg.q_latent_dim, cfg.kv_latent_dim
-    attn = {
-        "wq_a": nrm(lk[0], L, d, ql),
-        "q_norm": {"scale": jnp.ones((L, ql), dtype)},
-        "wq_b": nrm(lk[1], L, ql, nh * cfg.hd),
+    qk = cfg.qk_nope_dim + cfg.qk_rope_dim
+    if ql:
+        attn = {"wq_a": nrm(lk[0], L, d, ql),
+                "q_norm": {"scale": jnp.ones((L, ql), dtype)},
+                "wq_b": nrm(lk[1], L, ql, nh * qk)}
+    else:  # no query latent: one W_q
+        attn = {"wq": nrm(lk[0], L, d, nh * qk)}
+    attn.update({
         "wkv_a": nrm(lk[2], L, d, cfg.latent_width),
         "kv_norm": {"scale": jnp.ones((L, kl), dtype)},
         "wkv_b": nrm(lk[10], L, kl, nh * (cfg.qk_nope_dim + cfg.v_head_dim)),
         "wo": nrm(lk[3], L, nh * cfg.v_head_dim, d, scale=out_scale),
-    }
+    })
     if cfg.index_topk:
         ik = jax.random.split(lk[11], 3)
         attn["idx"] = {
@@ -373,9 +454,9 @@ def _latent_attn_params(cfg: "TransformerConfig", nrm, lk, L: int,
 # -----------------------------------------------------------------------------
 def init(cfg: TransformerConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
     if cfg.mixer_types:
-        from .minicpm import init as init_mixers
+        from .mixers import family
 
-        return init_mixers(cfg, rng, dtype)
+        return family(cfg).init(cfg, rng, dtype)
     std = cfg.initializer_range
     keys = jax.random.split(rng, 16)
     d, hd, nh, nkv, f = cfg.hidden_size, cfg.hd, cfg.num_heads, cfg.kv_heads, cfg.ffn
@@ -562,12 +643,16 @@ def _latent_projections(cfg: TransformerConfig, p: Params, x: jax.Array,
                         positions: jax.Array):
     """What both forms of latent attention (this file's training form, the
     cached one of models/decoding.py) start from: x [B,S,d] -> (the query
-    latent c_q [B,S,ql], q_nope [B,S,H,nope], q_pe [B,S,H,rd] rotated, the
-    normed kv latent c_kv [B,S,kl], ONE rotated key k_pe [B,S,1,rd])."""
+    latent c_q [B,S,ql], None without one (``q_latent_dim`` 0: one ``wq``),
+    q_nope [B,S,H,nope], q_pe [B,S,H,rd] rotated, the normed kv latent c_kv
+    [B,S,kl], ONE rotated key k_pe [B,S,1,rd])."""
     B, S, _ = x.shape
     kl, nope, eps = cfg.kv_latent_dim, cfg.qk_nope_dim, cfg.norm_eps
-    c_q = _rms_last(x @ p["wq_a"], p["q_norm"]["scale"], eps)
-    q = (c_q @ p["wq_b"]).reshape(B, S, cfg.num_heads, nope + cfg.qk_rope_dim)
+    c_q = None
+    if cfg.q_latent_dim:
+        c_q = _rms_last(x @ p["wq_a"], p["q_norm"]["scale"], eps)
+    q = (x @ p["wq"] if c_q is None else c_q @ p["wq_b"]).reshape(
+        B, S, cfg.num_heads, nope + cfg.qk_rope_dim)
     kv_a = x @ p["wkv_a"]
     c_kv = _rms_last(kv_a[..., :kl], p["kv_norm"]["scale"], eps)
     q_pe, k_pe = _rope(q[..., nope:], kv_a[:, :, None, kl:], positions,
@@ -1016,12 +1101,12 @@ def _refuse_uncached(cfg: TransformerConfig) -> None:
             "init_serving with serving.paged")
     if cfg.mixer_types:
         raise DeepSpeedConfigError(
-            "the uncached forward (training, evaluation, forward) runs "
-            "neither mixer of mixer_types: a lightning layer's recurrence "
-            "lives in a slot's state and a sparse layer's block selection "
-            "is made from cached compressed keys, both in the paged arena "
-            "alone; serve this configuration through init_serving with "
-            "serving.paged")
+            "the uncached forward (training, evaluation, forward) runs no "
+            "mixer of mixer_types: a state layer's recurrence (lightning, "
+            "kda) lives in a slot's state, a sparse layer's block selection "
+            "is made from cached compressed keys and a latent layer attends "
+            "the latent pool, all in the paged arena alone; serve this "
+            "configuration through init_serving with serving.paged")
 
 
 def routing_stats_summary(stats) -> Dict[str, jax.Array]:

@@ -23,6 +23,9 @@ engine compiles. Three calls a layer, each named in a device trace:
     the slot's context and masks what the selection left out: its work
     follows the context, not ``topk`` (PERF.md says what that costs; a walk
     over a per-row list of chosen rows is the open follow-up).
+``latent_attention``  The same walk with nothing masked, for a latent layer
+    without an indexer (:func:`latent_attention`): every key at or before a
+    query is attended; no scores, no threshold.
 
 Layouts: pools ``[L, P+1, page_size, width]`` as stored, the layer's index a
 scalar in SMEM beside ``page_table [B, max_pages]`` and the per-slot
@@ -315,11 +318,16 @@ def select_topk(scores, cache_len, num_new, topk: int,
 
 
 # ------------------------------------------------------------ attention
-def _sparse_attention_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, s_ref,
-                             thr_ref, tie_ref, kv_hbm, o_ref, kv_buf, sems,
-                             m_scr, l_scr, acc_scr,
-                             *, scale, page_size, pages_per_block, heads,
-                             rows, v_width):
+def _sparse_attention_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, *refs,
+                             scale, page_size, pages_per_block, heads,
+                             rows, v_width, selected: bool = True):
+    """``selected``: the operands hold a selection (scores, threshold, tie)
+    between the queries and the pool; without one every key at or before a
+    query is attended."""
+    s_ref = thr_ref = tie_ref = None
+    if selected:
+        s_ref, thr_ref, tie_ref, *refs = refs
+    kv_hbm, o_ref, kv_buf, sems, m_scr, l_scr, acc_scr = refs
     ps, ppb = page_size, pages_per_block
     bk = ps * ppb
     mp = pt_ref.shape[1]
@@ -340,7 +348,8 @@ def _sparse_attention_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, s_ref,
             pl.cdiv(cl + jnp.minimum(nn, r0 + rows), bk),
             pl.cdiv(mp * ps, bk))
         qpos = cl + r0 + lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-        thr, tie = thr_ref[0, :, :1], tie_ref[0, :, :1]
+        if selected:
+            thr, tie = thr_ref[0, :, :1], tie_ref[0, :, :1]
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
@@ -356,10 +365,11 @@ def _sparse_attention_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, s_ref,
             wait_fetch(slot)
             q = q_ref[0]
             kv = kv_buf[slot].astype(q.dtype)
-            key = _sort_key(s_ref[0, i])
             pos = i * bk + lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
-            chosen = (pos <= qpos) & (
-                (key > thr) | ((key == thr) & (pos <= tie)))
+            chosen = pos <= qpos
+            if selected:
+                key = _sort_key(s_ref[0, i])
+                chosen &= (key > thr) | ((key == thr) & (pos <= tie))
             # the heads of a query share its row of the selection
             _tile_update(
                 q, kv, kv[:, :v_width], None, None, i * bk, None, scale,
@@ -381,12 +391,15 @@ def sparse_attention(q_abs, kv_pool, scores, thr, tie, cache_len, page_table,
     """Absorbed queries ``q_abs`` [B,S,H,W] against the chosen latent rows of
     ``kv_pool`` [L,P+1,ps,W] (key: the whole row; value: its first
     ``v_width`` lanes). ``scores``/``thr``/``tie`` as :func:`select_topk`
-    gives them. Returns [B,S,H,v_width]; a tile of rows wholly past
-    ``num_new`` is zeros, padded rows beside real ones are finite."""
+    gives them; all three None (:func:`latent_attention`): no selection,
+    every key at or before a query. Returns [B,S,H,v_width]; a tile of rows
+    wholly past ``num_new`` is zeros, padded rows beside real ones are
+    finite."""
     B, S, H, W = q_abs.shape
     ps, mp = kv_pool.shape[2], page_table.shape[1]
     ppb = _block_pages(BLOCK_K, ps, mp)
-    NB, bk = scores.shape[1], scores.shape[3]
+    selected = scores is not None
+    bk = scores.shape[3] if selected else ps * ppb
     rows = min(ATTN_ROWS, S)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -395,13 +408,20 @@ def sparse_attention(q_abs, kv_pool, scores, thr, tie, cache_len, page_table,
     def lanes(a):
         return jnp.broadcast_to(a[:, :, None], (B, S, LANES))
 
+    selection, selection_specs = (), []
+    if selected:
+        selection = (scores, lanes(thr), lanes(tie))
+        selection_specs = [
+            pl.BlockSpec((1, scores.shape[1], rows, bk),
+                         lambda b, t, *_: (b, 0, t, 0)),
+            pl.BlockSpec((1, rows, LANES), lambda b, t, *_: (b, t, 0)),
+            pl.BlockSpec((1, rows, LANES), lambda b, t, *_: (b, t, 0)),
+        ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4, grid=(B, S // rows),
         in_specs=[
             pl.BlockSpec((1, rows * H, W), lambda b, t, *_: (b, t, 0)),
-            pl.BlockSpec((1, NB, rows, bk), lambda b, t, *_: (b, 0, t, 0)),
-            pl.BlockSpec((1, rows, LANES), lambda b, t, *_: (b, t, 0)),
-            pl.BlockSpec((1, rows, LANES), lambda b, t, *_: (b, t, 0)),
+            *selection_specs,
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, rows * H, v_width),
@@ -417,22 +437,49 @@ def sparse_attention(q_abs, kv_pool, scores, thr, tie, cache_len, page_table,
     out = pl.pallas_call(
         functools.partial(
             _sparse_attention_kernel, scale=float(scale), page_size=ps,
-            pages_per_block=ppb, heads=H, rows=rows, v_width=v_width),
+            pages_per_block=ppb, heads=H, rows=rows, v_width=v_width,
+            selected=selected),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, S * H, v_width), q_abs.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        interpret=interpret, name="sparse_latent_attention",
+        interpret=interpret,
+        name="sparse_latent_attention" if selected else "latent_attention",
     )(jnp.asarray(page_table, jnp.int32), cl, nn,
       jnp.asarray(layer, jnp.int32).reshape(1),
-      q_abs.reshape(B, S * H, W), scores, lanes(thr), lanes(tie), kv_pool)
+      q_abs.reshape(B, S * H, W), *selection, kv_pool)
     return out.reshape(B, S, H, v_width)
+
+
+def latent_attention(q_abs, kv_pool, cache_len, page_table, *, layer,
+                     scale: float, v_width: int, num_new=None,
+                     interpret: Optional[bool] = None
+                     ) -> Tuple[Optional[jax.Array], List[str]]:
+    """Latent attention WITHOUT a selection: the walk of
+    :func:`sparse_attention` over every key of each slot's context, prompt
+    chunks and decode rows alike (the device call is named
+    ``latent_attention``). Returns ``(out [B,S,H,v_width], [])``, or
+    ``(None, reasons)`` when the operands are not the kernel's (the caller
+    takes the dense lines)."""
+    interp = interpret if interpret is not None else (
+        jax.default_backend() != "tpu")
+    reasons = kernel_reasons(q_abs, None, kv_pool, None, page_table, interp)
+    if reasons:
+        from ...utils.logging import log_fallback_once
+
+        log_fallback_once("latent_attention", reasons)
+        return None, reasons
+    return sparse_attention(
+        q_abs, kv_pool, None, None, None, cache_len, page_table, layer=layer,
+        scale=scale, v_width=v_width, num_new=num_new,
+        interpret=interp), reasons
 
 
 def kernel_reasons(q_abs, q_idx, kv_pool, ki_pool, page_table,
                    interpret: bool) -> List[str]:
-    """Why the three kernels cannot take these operands ([] = they can)."""
+    """Why the kernels cannot take these operands ([] = they can);
+    ``q_idx`` / ``ki_pool`` None: no indexer."""
     from ...models.sharding import current_topology
 
     B, S = q_abs.shape[:2]
@@ -446,8 +493,8 @@ def kernel_reasons(q_abs, q_idx, kv_pool, ki_pool, page_table,
     if S % SELECT_ROWS or S % ATTN_ROWS:
         reasons.append(f"a chunk of {S} rows is not whole 8-row tiles")
     if not interpret:
-        for what, width in (("latent row", kv_pool.shape[-1]),
-                            ("indexer key", ki_pool.shape[-1])):
+        for what, pool in (("latent row", kv_pool), ("indexer key", ki_pool)):
+            width = 0 if pool is None else pool.shape[-1]
             if width % LANES:
                 reasons.append(f"{what} of {width} is not {LANES}-aligned")
         if B * page_table.shape[1] * 4 > SMEM_TABLE_BYTES:

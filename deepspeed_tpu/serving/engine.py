@@ -94,13 +94,13 @@ def cache_token_bytes(cfg, storage_itemsize: int, quantized: bool) -> int:
     """Bytes one token keeps in one layer of the cache, scales left out:
     keys and values of every KV head, or for latent attention its one
     latent row (as the pool pads it) and its indexer key."""
-    if cfg.mixer_types:
+    if cfg.mixer_types and cfg.block_sparse is not None:
         # a sparse layer's keys and values, and a page's one compressed key
-        # spread over its tokens (the lightning layers keep no token)
+        # spread over its tokens (the state layers keep no token)
         geom = cfg.block_sparse
         return (2 * geom.kernel_stride + 1) * cfg.kv_heads * cfg.hd * (
             storage_itemsize) // geom.kernel_stride
-    if cfg.is_latent:
+    if cfg.kv_latent_dim:  # every layer latent, or a model's latent layers
         from ..models.decoding import latent_row_width
 
         width = latent_row_width(cfg) + (
@@ -202,10 +202,10 @@ def paged_kv_stream(cfg, num_pages: int, page_size: int, max_slots: int,
     # k + v of a token, with their scales
     per_tok = cache_token_bytes(cfg, storage_itemsize, quantized) + (
         2 * SCALE_LANES * 4 if quantized else 0)
-    # (a model with mixers keeps pages for its sparse layers alone; its
-    # lightning layers' states are read and written once a step)
-    L = cfg.kind_count("sparse") if cfg.mixer_types else cfg.total_layers
-    state = state_bytes(cfg, max_slots)
+    # (a model with mixers keeps pages for the layers whose kind keeps any;
+    # its state layers' leaves are read and written once a step)
+    L = cfg.paged_layers
+    state = state_bytes(cfg, max_slots, storage_itemsize)
     gather = 2 * state + L * max_slots * pages_per_slot * page_size * per_tok
     scatter = L * max_slots * token_budget * per_tok
     cow = L * max_slots * page_size * per_tok
@@ -229,13 +229,18 @@ def paged_kv_stream(cfg, num_pages: int, page_size: int, max_slots: int,
     return stream
 
 
-def state_bytes(cfg, max_slots: int) -> int:
-    """Bytes of the arena that are no page: a float32 state
-    ``[heads, hd, hd]`` a slot a lightning layer (0 for any other model)."""
+def state_bytes(cfg, max_slots: int, storage_itemsize: int = 2) -> int:
+    """Bytes of the arena that are no page: every leaf the model's state
+    layers keep a slot (``models/mixers.slot_leaves``: a float32 state a
+    lightning or kda layer, and a kda layer's convolution rows in the
+    storage type); 0 for a model without state layers."""
     if not getattr(cfg, "has_state", False):
         return 0
-    return (cfg.kind_count("lightning") * max_slots * cfg.num_heads
-            * cfg.hd * cfg.hd * 4)
+    from ..models.mixers import slot_leaves
+
+    dtype = jnp.float32 if int(storage_itemsize) == 4 else jnp.bfloat16
+    return sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+               for leaf in slot_leaves(cfg, max_slots, dtype).values())
 
 
 def kv_spill_page_bytes(cfg, page_size: int, codec_name: str,
@@ -773,23 +778,32 @@ class ServingEngine:
             if int(serving.fleet.prefill_replicas) > 0:
                 raise DeepSpeedConfigError(
                     f"serving.fleet.prefill_replicas is refused: {why}")
-        # ---- state layers: a lightning layer's cache is a state a slot,
-        # begun at zero with its request and never shared; a page holds the
-        # sparse layers' keys alone (docs/serving.md "Layer kinds") ---------
+        # ---- state layers: a lightning or kda layer's cache is leaves a
+        # slot, begun at zero with its request and never shared; a page holds
+        # the paged layers' keys alone (docs/serving.md "Layer kinds") ------
         self.slot_state = bool(getattr(mcfg, "has_state", False))
         if mcfg.mixer_types:
             from ..config import DeepSpeedConfigError
 
             if not self.paged:
                 raise DeepSpeedConfigError(
-                    "serving.paged false is refused: a sparse layer selects "
-                    "blocks of pages and a lightning layer keeps a state a "
-                    "slot (mixer_types); both live in the paged arena")
+                    "serving.paged false is refused: a sparse or latent "
+                    "layer reads its keys through the page table and a state "
+                    "layer keeps its state a slot (mixer_types); all live in "
+                    "the paged arena")
+            from ..models.transformer import MIXER_KINDS
+
+            kinds = dict.fromkeys(mcfg.mixer_types)
+            self._state_kinds = ", ".join(
+                k for k in kinds if MIXER_KINDS[k].slot)
+            self._paged_kinds = ", ".join(
+                k for k in kinds if MIXER_KINDS[k].page)
             why = (
-                "the model has lightning layers, whose cache is a recurrent "
-                "state a slot and no page: a page that is kept, spilled or "
-                "handed over holds the sparse layers' keys alone, and the "
-                "state that summed the same tokens would be missing")
+                f"the model has state layers ({self._state_kinds}), whose "
+                "cache is a recurrent state a slot and no page: a page that "
+                "is kept, spilled or handed over holds the paged layers' "
+                f"({self._paged_kinds}) keys alone, and the state that "
+                "summed the same tokens would be missing")
             if int(getattr(serving, "host_pages", 0) or 0) > 0:
                 raise DeepSpeedConfigError(
                     f"serving.host_pages is refused: {why}")
@@ -798,8 +812,9 @@ class ServingEngine:
                     f"serving.fleet.prefill_replicas is refused: {why}")
             if self.spec_enabled:
                 raise DeepSpeedConfigError(
-                    "serving.spec is refused: the model has lightning "
-                    "layers, whose state sums every row it was fed; a "
+                    "serving.spec is refused: the model has state "
+                    f"layers ({self._state_kinds}), whose state sums every "
+                    "row it was fed; a "
                     "rejected draft would need the state rolled back to the "
                     "last accepted token, and the step keeps no such copy")
             if prefix_cache:
@@ -991,7 +1006,12 @@ class ServingEngine:
         self.attention_path: Optional[str] = None
         self.attention_paths: Dict[str, str] = {}
         self.attention_fallback: Tuple[str, ...] = ()
-        self.metrics.state_bytes = state_bytes(mcfg, N)
+        self._kind_reasons: Dict[str, Tuple[str, ...]] = {}
+        self.metrics.state_bytes = state_bytes(
+            mcfg, N, jnp.dtype(engine.kv_cache_storage_dtype).itemsize)
+        # a routed model with mixers: the held experts that got a row in the
+        # step folded last (the device's own count, read with its tokens)
+        self._experts_touched: Optional[int] = None
 
         def counting_step(*args):
             self.step_traces += 1
@@ -999,15 +1019,18 @@ class ServingEngine:
                 out = step_fn(*args)
             self.attention_path = rec["path"]
             self.attention_fallback = rec["reasons"]
+            self._kind_reasons = dict(rec["kind_reasons"])
             if mcfg.mixer_types:
                 # a path a mixer kind: "block_sparse_kernel" / "dense" for
-                # the sparse layers, "lightning_kernel" / "dense" for the
-                # lightning ones; attention_path is the sparse layers'
+                # sparse layers, "lightning_kernel", "kda_kernel" and
+                # "latent_kernel" likewise; attention_path is the sparse
+                # layers' (the last layer kind's where there is none)
                 self.attention_paths = dict(rec["kinds"])
                 self.attention_path = rec["kinds"].get("sparse", rec["path"])
             self.metrics.attention_paged_kernel = float(
                 self.attention_path in ("paged_kernel", "latent_sparse_kernel",
-                                        "block_sparse_kernel")
+                                        "block_sparse_kernel", "kda_kernel",
+                                        "latent_kernel")
             )
             self.metrics.attention_paged_kernel_kinds = {
                 kind: float(path == "paged_kernel" or bool(mcfg.mixer_types)
@@ -1292,7 +1315,8 @@ class ServingEngine:
             # one fetch for everything the host reads, begun now
             reads = (out_tok, new_rng, n_emit, moe and (
                 moe[0]["tokens_per_expert"], moe[0]["drop_fraction"],
-                moe[0].get("unrouted_tokens")))
+                moe[0].get("unrouted_tokens"),
+                moe[0].get("experts_touched")))
             for a in jax.tree_util.tree_leaves(reads):
                 a.copy_to_host_async()
             fl = _Flying(
@@ -1342,7 +1366,10 @@ class ServingEngine:
                 self.metrics.on_moe(
                     moe_stats[0], float(moe_stats[1]),
                     a2a_bytes=self._moe_a2a_step_bytes, unrouted=moe_stats[2],
+                    touched=moe_stats[3],
                 )
+                if moe_stats[3] is not None:
+                    self._experts_touched = int(moe_stats[3])
             if self.comm_logger is not None:
                 self.comm_logger.record_streams(self.analytic_streams())
         return finished
@@ -1414,6 +1441,8 @@ class ServingEngine:
         ``state_resets`` those of them that begin at zero. Booked on the
         metrics."""
         geom = self.config.block_sparse
+        if geom is None:
+            return self._count_state_and_latent(plan)
         cl = plan.start_pos.astype(np.int64)
         nn = plan.num_new.astype(np.int64)
         busy = nn > 0
@@ -1441,6 +1470,68 @@ class ServingEngine:
         }
         self.metrics.on_keys("sparse", counts["attended_sparse"],
                              counts["chosen_min"])
+        self.metrics.context_keys += counts["context_keys"]
+        self.metrics.state_resets += counts["state_resets"]
+        return counts
+
+    def describe(self) -> Dict[str, Any]:
+        """What the built engine is, as it observed it (the attention paths
+        once its step has been traced): the order of a turn and the layout
+        of the rows with their reasons, the attention path of every layer
+        kind (``*_kernel`` or ``dense``) with the reasons for a dense one,
+        and the bytes of every leaf a slot keeps that is no page."""
+        from ..models.mixers import slot_leaves
+
+        mcfg = self.config
+        leaves = slot_leaves(mcfg, self.max_slots,
+                             self.engine.kv_cache_storage_dtype
+                             ) if mcfg.mixer_types else {}
+        kinds = self.attention_paths or (
+            {"full": self.attention_path} if self.attention_path else {})
+        return {
+            "model": mcfg.name,
+            "step_order": self.step_order,
+            "step_order_reason": self.step_order_reason,
+            "row_layout": self.row_layout,
+            "row_layout_reason": self.row_layout_reason,
+            "attention": {kind: {"path": path, "reasons": list(
+                self._kind_reasons.get(kind, ()))}
+                for kind, path in kinds.items()},
+            "paged_layers": mcfg.paged_layers,
+            "state_leaves": {
+                name: int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+                for name, leaf in leaves.items()},
+        }
+
+    def _count_state_and_latent(self, plan: StepPlan) -> Dict[str, int]:
+        """The work of one kda and one latent layer of a model with those
+        mixers, from the plan (host arithmetic): ``kda_rows`` the real rows
+        the delta rule runs and ``kda_state_slots`` the live states it reads
+        and writes (``state_resets`` those that begin at zero);
+        ``latent_rows`` the real query rows and ``latent_keys_walked`` the
+        cached latents at or before each slot's last real row, which the
+        walk reads once a slot; ``context_keys`` those at or before every
+        real query. A routed model adds the device's own count of the step
+        folded last (two calls behind the one it rides on: the sums over a
+        window are of the same steps but its edges): ``experts_touched`` the
+        held experts that got at least one row, over the routed layers, of
+        ``experts_held`` a step. Booked on the metrics."""
+        cl = plan.start_pos.astype(np.int64)
+        nn = plan.num_new.astype(np.int64)
+        busy = nn > 0
+        rows = int(nn.sum())
+        counts = {
+            "kda_rows": rows,
+            "kda_state_slots": int(busy.sum()),
+            "state_resets": int((busy & (cl == 0)).sum()),
+            "latent_rows": rows,
+            "latent_keys_walked": int((cl + nn)[busy].sum()),
+            "context_keys": int((nn * cl + nn * (nn + 1) // 2).sum()),
+        }
+        if self._experts_touched is not None:
+            counts["experts_touched"] = self._experts_touched
+            counts["experts_held"] = (
+                self.config.num_experts * self.config.num_layers)
         self.metrics.context_keys += counts["context_keys"]
         self.metrics.state_resets += counts["state_resets"]
         return counts
@@ -1484,8 +1575,9 @@ class ServingEngine:
     def _refuse_page_moves(self, what: str) -> None:
         if self.slot_state:
             raise RuntimeError(
-                f"{what}: a page holds the sparse layers' keys alone; the "
-                "lightning layers' state that summed the same tokens is a "
+                f"{what}: a page holds the paged layers' "
+                f"({self._paged_kinds}) keys alone; the state layers' "
+                f"({self._state_kinds}) state that summed the same tokens is a "
                 "slot's and no page, so a hand-off would serve a model with "
                 "state layers a context its state never saw"
             )

@@ -162,6 +162,7 @@ class ServingMetrics:
         # one member's share of an expert-parallel layer: real tokens (summed
         # over layers) none of whose experts is held here
         self.moe_unrouted_tokens = 0
+        self.moe_experts_touched = 0
         # a latent model with an indexer: cached tokens at or before every
         # real query token (what the indexer scores), beside
         # attended_keys["sparse"], what its selection lets attention see
@@ -298,7 +299,7 @@ class ServingMetrics:
             self.prefill_chunks += 1
 
     def on_moe(self, tokens_per_expert, dropped_fraction,
-               a2a_bytes: int = 0, unrouted=None) -> None:
+               a2a_bytes: int = 0, unrouted=None, touched=None) -> None:
         """One MoE serving step's expert load-balance counters (ISSUE 14
         satellite): ``tokens_per_expert`` is the step's [E] capacity-slot
         histogram (summed over layers), ``dropped_fraction`` the valid
@@ -318,6 +319,8 @@ class ServingMetrics:
         self.moe_a2a_bytes += int(_finite(a2a_bytes))
         if unrouted is not None:
             self.moe_unrouted_tokens += int(_finite(unrouted))
+        if touched is not None:  # held experts with a row, over the layers
+            self.moe_experts_touched += int(_finite(touched))
 
     @property
     def moe_load_imbalance(self) -> float:
@@ -487,6 +490,7 @@ class ServingMetrics:
                 "moe_load_imbalance": self.moe_load_imbalance,
                 "moe_a2a_bytes": self.moe_a2a_bytes,
                 "moe_unrouted_tokens": self.moe_unrouted_tokens,
+                "moe_experts_touched": self.moe_experts_touched,
             })
             # the per-expert histogram rides the snapshot (and the
             # serve/* bridge) as bounded scalar keys — E is small

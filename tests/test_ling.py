@@ -1,0 +1,360 @@
+"""Ling-3.0-flash on the serving path, float32 on the CPU at a tiny size: KDA
+layers (a state and the convolution's last rows a slot, no page) five to one
+beside latent attention over the latent pool, under one member's share of a
+sigmoid-routed layer, against the benchmark's plain reference
+(benchmarks/families/bailing_hybrid.py)."""
+
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from deepspeed_tpu.config import DeepSpeedConfigError
+from deepspeed_tpu.models import ling
+from deepspeed_tpu.models.decoding import (_paged_gather, forward_with_cache,
+                                           init_paged_cache)
+from deepspeed_tpu.models.mixers import layer_plan
+from deepspeed_tpu.models.transformer import TransformerConfig
+from deepspeed_tpu.ops.attention import attention_impl
+from deepspeed_tpu.ops.pallas import kda_attention as ka
+from deepspeed_tpu.ops.pallas import sparse_latent_attention as sla
+from deepspeed_tpu.serving import Request
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+from benchmarks import reference as ref  # noqa: E402
+from benchmarks.families import bailing_hybrid as fam  # noqa: E402
+
+F32 = jnp.float32
+# float32 against float32 on logits whose spread is about 1: what is left is
+# the order of the sums (the chunk form's cumulative log-decays reach 80 a
+# sub-block, so a decay carries 1e-5 of relative rounding)
+TOL = 2e-4
+PS, W, SLOTS = 16, 16, 3
+SERVING = dict(max_slots=SLOTS, token_budget=W, max_tokens=240, paged=True,
+               page_size=PS, prefix_cache=False)
+IDS = list(range(12))  # two whole periods K K K K K M of the tiny preset
+HELD = dict(num_experts=4, moe_routed_experts=16)
+CONFIG = dict(
+    family="bailing_hybrid", hidden_size=64, num_hidden_layers=12,
+    num_attention_heads=4, num_key_value_heads=4, head_dim=16,
+    intermediate_size=128, moe_intermediate_size=32,
+    moe_shared_expert_intermediate_size=32, num_shared_experts=1,
+    vocab_size=512, num_experts=4, num_experts_per_tok=4, n_group=4,
+    topk_group=2, routed_scaling_factor=2.5, rms_norm_eps=1e-6,
+    rope_theta=6000000, layer_group_size=6, first_k_dense_replace=2,
+    kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    short_conv_kernel_size=4, kda_lower_bound=-5, layer_ids=IDS,
+    published=dict(num_hidden_layers=13, num_experts=16,
+                   first_k_dense_replace=2))
+
+
+def tiny(**over):
+    # weights five times the preset's spread, so that the mixers weigh as
+    # much as the residual stream and a fault in one shows in the logits
+    return ling("ling-tiny", layer_ids=IDS, initializer_range=0.1,
+                **{**HELD, **over})
+
+
+@pytest.fixture(scope="module")
+def model():
+    return tiny()
+
+
+def init_params(model, seed=0):
+    tree = model.init(jax.random.PRNGKey(seed), dtype=F32)
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    out = []
+    for i, (path, a) in enumerate(leaves):  # norm scales that are not one
+        if getattr(path[-1], "key", "") == "scale":
+            a = a * (1 + 0.1 * jax.random.normal(jax.random.PRNGKey(i),
+                                                 a.shape))
+        out.append(a)
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+@pytest.fixture(scope="module")
+def params(model):
+    return init_params(model)
+
+
+@pytest.fixture(scope="module")
+def shape():
+    return fam.shape_of(CONFIG)
+
+
+def ids_of(n, seed):
+    return np.random.default_rng(seed).integers(0, 512, n, dtype=np.int32)
+
+
+_STEPS = {}
+
+
+def cached_step(cfg, kernels: bool):
+    """``forward_with_cache`` of the slot step (packed rows), jitted once a
+    path."""
+    if kernels not in _STEPS:
+        def step(params, tokens, caches, start, table, num_new):
+            with attention_impl("flash" if kernels else "xla"):
+                return forward_with_cache(
+                    cfg, params, tokens, caches, start, dtype=F32,
+                    page_table=table, num_new=num_new, token_budget=W)
+
+        _STEPS[kernels] = jax.jit(step)
+    return _STEPS[kernels]
+
+
+def drive(model, params, feeds, kernels=False):
+    """Run steps of the ``[SLOTS, W]`` slot program: ``feeds`` is a list of
+    steps, each {slot: (ids of the rows fed, the slot's position before
+    them)}; at most W rows a step in all (the scheduler's promise). Returns
+    {slot: [logits of every row fed, in order]} and the caches."""
+    cfg = model.config
+    mp = 16
+    caches = init_paged_cache(cfg, SLOTS * mp, PS, F32, max_slots=SLOTS)
+    table = (np.arange(SLOTS * mp, dtype=np.int32).reshape(SLOTS, mp))
+    out = {s: [] for s in range(SLOTS)}
+    for feed in feeds:
+        tokens = np.zeros((SLOTS, W), np.int32)
+        num_new = np.zeros(SLOTS, np.int32)
+        start = np.zeros(SLOTS, np.int32)
+        for slot, (part, at) in feed.items():
+            tokens[slot, :len(part)] = part
+            num_new[slot], start[slot] = len(part), at
+        assert num_new.sum() <= W
+        # an idle slot's row of the table is all NULL pages, as the
+        # scheduler hands it: its padded writes land in the sink
+        live = np.where((num_new > 0)[:, None], table, SLOTS * mp)
+        logits, caches = cached_step(cfg, kernels)(
+            params, jnp.asarray(tokens), caches, jnp.asarray(start),
+            jnp.asarray(live), jnp.asarray(num_new))
+        for slot, (part, _) in feed.items():
+            out[slot].append(np.asarray(logits[slot, :len(part)]))
+    return out, caches
+
+
+def schedule(seqs, sizes):
+    """Feeds that prefill ``seqs`` {slot: ids} side by side, slot ``s`` in
+    chunks of ``sizes[s]`` rows."""
+    at = {s: 0 for s in seqs}
+    feeds = []
+    while any(at[s] < len(seqs[s]) for s in seqs):
+        feed = {}
+        for s, ids in seqs.items():
+            if at[s] < len(ids):
+                feed[s] = (ids[at[s]:at[s] + sizes[s]], at[s])
+                at[s] += sizes[s]
+        feeds.append(feed)
+    return feeds
+
+
+def test_the_plan_names_every_layers_two_halves(model):
+    """Mixer kind and MLP kind are independent: the cut's first two layers
+    are KDA over a dense MLP, every sixth is latent, and each half is read
+    at its own index inside its kind's stack."""
+    cfg = model.config
+    plan = layer_plan(cfg)
+    assert [l.mixer for l in plan] == ["kda"] * 5 + ["latent"] + ["kda"] * 5 + [
+        "latent"]
+    assert [l.mlp for l in plan] == ["dense"] * 2 + ["routed"] * 10
+    assert [l.mixer_at for l in plan] == [0, 1, 2, 3, 4, 0, 5, 6, 7, 8, 9, 1]
+    assert [l.mlp_at for l in plan] == [0, 1, *range(10)]
+    assert cfg.has_state and cfg.paged_layers == 2 and not cfg.is_latent
+    got = sum(a.size for a in jax.tree.leaves(
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))))
+    assert model.num_params() == got
+
+
+@pytest.mark.parametrize("kernels", [False, True], ids=["dense", "kernels"])
+def test_slots_at_different_frontiers_match_the_reference(model, params,
+                                                          shape, kernels):
+    """Prefill in chunks through the one slot step with packed rows: three
+    slots at different frontiers and chunk sizes (7, 5 and 3 rows: every
+    chunk boundary lies inside the convolution's 3-row reach of the next
+    chunk's first rows), then one-row steps (decode), then slot 1 taken by a
+    SECOND request from position 0 (its state and convolution rows start
+    from zero, whatever the first left). Logits of every row against the
+    reference's full forward, with the kernels (interpret mode) and
+    without."""
+    seqs = {0: ids_of(37, 1), 1: ids_of(21, 2), 2: ids_of(11, 3)}
+    feeds = schedule(seqs, {0: 7, 1: 5, 2: 3})
+    more = {s: ids_of(4, 10 + s) for s in seqs}
+    for j in range(4):  # decode rows, all three slots in a step
+        feeds.append({s: (more[s][j:j + 1], len(seqs[s]) + j) for s in seqs})
+    again = ids_of(19, 7)
+    feeds += schedule({1: again}, {1: 6})
+    got, _ = drive(model, params, feeds, kernels)
+    for s in seqs:
+        ids = np.concatenate([seqs[s], more[s]])
+        want = np.asarray(fam.logits(params, ids, shape))
+        have = np.concatenate(got[s])[:len(ids)]
+        assert np.abs(have - want).max() < TOL, (s, np.abs(have - want).max())
+    want = np.asarray(fam.logits(params, again, shape))
+    have = np.concatenate(got[1])[len(seqs[1]) + 4:]
+    assert np.abs(have - want).max() < TOL
+
+
+def test_engine_serves_what_the_reference_predicts(model, params, shape):
+    """Through init_serving (scheduler, paged arena, packed rows, overlapped
+    step order): four requests over three slots, so one slot is reused; every
+    served token is the reference's argmax for its context, or within TOL of
+    it."""
+    srv = deepspeed_tpu.init_serving(model, serving=SERVING, params=params,
+                                     dtype=F32)
+    assert srv.row_layout == "packed" and srv.step_order == "overlapped"
+    prompts = [ids_of(n, 20 + i) for i, n in enumerate((37, 5, 50, 21))]
+    states = [srv.submit(Request(
+        request_id=f"r{i}", prompt=p, max_new_tokens=6, temperature=0.0,
+        eos_token_id=-1)) for i, p in enumerate(prompts)]
+    srv.run_until_idle()
+    assert srv.attention_paths == {"kda": "dense", "latent": "dense"}
+    for p, st in zip(prompts, states):
+        assert len(st.tokens) == 6
+        ids = np.concatenate([p, np.asarray(st.tokens, np.int32)])
+        logits = fam.logits(params, ids[:-1], shape, last=6)
+        assert ref.served_token_gaps(logits, st.tokens).max() < TOL
+    snap = srv.metrics.snapshot()
+    assert snap["state_resets"] == 4
+    leaves = srv.describe()["state_leaves"]
+    assert set(leaves) == {"state", "conv"}
+    assert snap["state_bytes"] == sum(leaves.values()) == (
+        10 * SLOTS * 4 * 16 * 16 * 4 + 10 * SLOTS * 3 * 3 * 64 * 4)
+    assert snap["moe_experts_touched"] > 0
+
+
+@pytest.mark.parametrize("decay", [-5.0, 0.0, None],
+                         ids=["pinned-to-the-bound", "no-decay", "drawn"])
+def test_kda_kernel_is_its_twin_is_the_recurrence(decay):
+    """``kda_attention`` (interpret mode) against ``dense_kda`` against the
+    reference's row-by-row recurrence, with the log-decays pinned to -5 over
+    the whole chunk (G reaches -320 over 64 rows: a quotient of powers would
+    overflow), pinned to 0, and drawn; slots with a whole chunk, one row, no
+    row and a ragged count; a slot at position 0 starts from zeros."""
+    B, S, H, hd = 4, 64, 2, 16
+    k_ = jax.random.split(jax.random.PRNGKey(3), 6)
+    nrm = lambda key, *s: jax.random.normal(key, s, F32)
+    unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)
+    q, k, v = unit(nrm(k_[0], B, S, H, hd)), unit(nrm(k_[1], B, S, H, hd)), \
+        nrm(k_[2], B, S, H, hd)
+    g = -5 * jax.nn.sigmoid(nrm(k_[3], B, S, H, hd)) if decay is None else (
+        jnp.full((B, S, H, hd), decay, F32))
+    beta = jax.nn.sigmoid(nrm(k_[4], B, S, H))
+    stack = nrm(k_[5], 2, B, H, hd, hd)
+    cl, nn = jnp.array([0, 9, 4, 30]), jnp.array([S, 1, 0, 37])
+    scale = hd ** -0.5
+    o, after = ka.kda_attention(q, k, v, g, beta, stack, cl, nn, layer=1,
+                                scale=scale, interpret=True)
+    o2, after2 = ka.dense_kda(q, k, v, g, beta, stack[1], cl, nn, scale=scale)
+    assert bool((after[0] == stack[0]).all())  # the other layer untouched
+    assert bool((after[1, 2] == stack[1, 2]).all())  # no real row: bit for bit
+    for b in range(B):
+        n = int(nn[b])
+        s0 = jnp.zeros((H, hd, hd)) if int(cl[b]) == 0 else stack[1, b]
+        with jax.default_matmul_precision("highest"):
+            o3, after3 = fam._delta_rule(q[b, :n] * scale, k[b, :n], v[b, :n],
+                                         g[b, :n], beta[b, :n], s0)
+        for have in (o[b, :n], o2[b, :n]):
+            assert float(jnp.abs(have - o3).max()) < TOL if n else True
+        for have in (after[1, b], after2[b]):
+            assert float(jnp.abs(have - after3).max()) < TOL
+
+
+def test_the_members_shares_add_up_to_the_uncut_layer(params):
+    """The share test: one routed layer of 16 experts in 4 groups of 4, cut
+    over 4 members of one group each. The program's partial sums (first
+    expert 0, 4, 8, 12; each member also computes the shared expert), the
+    shared expert counted once, add up to the reference's uncut layer."""
+    from deepspeed_tpu.moe.sharded_moe import moe_serving_mlp
+
+    full_model = tiny(num_experts=16, moe_routed_experts=16)
+    full = init_params(full_model, seed=5)["layers"]
+    uncut = fam.shape_of({**CONFIG, "num_experts": 16})
+    x = jax.random.normal(jax.random.PRNGKey(9), (24, 64), F32)
+    load = lambda tree: jax.tree.map(lambda a: a.astype(F32), tree)
+    j = 3
+    with ref.HIGHEST():
+        want, _ = fam.routed_block(x, load(ref.layer(full["ln2"], j)),
+                                   full["mlp"], j, uncut, load, first=0)
+        normed = ref.rmsnorm(x, load(ref.layer(full["ln2"], j)), 1e-6)
+        shared = fam._gated(normed, load(ref.layer(full["mlp"]["shared"], j)))
+        total = 0.0
+        for member in range(4):
+            cfg = tiny(moe_first_expert=4 * member).config
+            bank = {k: (a[j, 4 * member:4 * member + 4]
+                        if k in ("wi", "wg", "wo") else a[j])
+                    for k, a in full["mlp"].items() if k != "shared"}
+            bank["shared"] = ref.layer(full["mlp"]["shared"], j)
+            out, _ = moe_serving_mlp(cfg, bank, normed[None])
+            total = total + out[0]
+    assert float(jnp.abs(total - 3 * shared - (want - x)).max()) < 1e-5
+
+
+def test_the_latent_walk_without_a_selection(model):
+    """``latent_attention`` (every key of each slot's context, no scores, no
+    threshold) against plain attention over the gathered view, and against
+    the selection walk handed ``index_topk`` >= the context: prompt chunks
+    and a decode row alike."""
+    B, S, H, Wd, vw, mp = 3, 16, 4, 128, 64, 8
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    pool = jax.random.normal(ks[0], (2, B * mp + 1, PS, Wd), F32)
+    q = jax.random.normal(ks[1], (B, S, H, Wd), F32)
+    table = jnp.arange(B * mp, dtype=jnp.int32).reshape(B, mp)
+    cl, nn = jnp.array([40, 99, 0]), jnp.array([16, 1, 9])
+    kw = dict(layer=1, scale=0.2, v_width=vw, num_new=nn, interpret=True)
+    out, why = sla.latent_attention(q, pool, cl, table, **kw)
+    assert why == []
+    view = _paged_gather(pool[1], table)
+    pos = cl[:, None] + jnp.arange(S)[None, :]
+    causal = jnp.arange(view.shape[1])[None, None, :] <= pos[..., None]
+    want = sla.dense_sparse_attention(q, view, causal, 0.2, vw)
+    nb, bk = sla.score_blocks(mp, PS)
+    scores = jax.random.normal(ks[2], (B, nb, S, bk), F32)
+    thr, tie = sla.select_topk(scores, cl, nn, 4096, interpret=True)
+    chosen = sla.sparse_attention(q, pool, scores, thr, tie, cl, table, **kw)
+    for b in range(B):
+        n = int(nn[b])
+        assert float(jnp.abs(out[b, :n] - want[b, :n]).max()) < 1e-5
+        assert float(jnp.abs(out[b, :n] - chosen[b, :n]).max()) < 1e-5
+
+
+@pytest.mark.parametrize("fault", fam.FAULTS)
+def test_every_fault_moves_the_reference(params, shape, fault):
+    """Each name in FAULTS changes the reference's logits by far more than
+    the tolerance the engine is held to (150 tokens: the chunk faults bite
+    at row 128)."""
+    ids = ids_of(150, 31)
+    sound = np.asarray(fam.logits(params, ids, shape))
+    broken = np.asarray(fam.logits(
+        ids=ids, shape=shape, **fam.faulted(params, fault, shape)))
+    assert np.abs(broken - sound).max() > 20 * TOL, fault
+
+
+def test_what_cannot_be_built_is_refused_in_words(model, params):
+    with pytest.raises(ValueError, match="no such mixer kind"):
+        TransformerConfig(num_layers=1, mixer_types=("mamba",),
+                          mixer_layer_ids=(0,), mixer_depth=1)
+    with pytest.raises(ValueError, match="kv_latent_dim"):
+        TransformerConfig(num_layers=1, mixer_types=("latent",),
+                          mixer_layer_ids=(0,), mixer_depth=1)
+    with pytest.raises(ValueError, match="share the module"):
+        TransformerConfig(num_layers=2, mixer_types=("kda", "lightning"),
+                          mixer_layer_ids=(0, 1), mixer_depth=2)
+    with pytest.raises(ValueError, match="clamp is not built"):
+        ling("ling-tiny")  # published layer 12 clamps its shared expert
+    with pytest.raises(ValueError, match="clamp is not built"):
+        ling("ling-3.0-flash", layer_ids=[0, 34])
+    with pytest.raises(DeepSpeedConfigError, match="paged arena"):
+        model.apply(params, jnp.zeros((1, 8), jnp.int32))
+    serve = lambda **over: deepspeed_tpu.init_serving(
+        model, serving=dict(SERVING, **over), params=params, dtype=F32)
+    with pytest.raises(DeepSpeedConfigError, match="state layers"):
+        serve(spec=dict(enabled=True, max_draft=2))
+    with pytest.raises(DeepSpeedConfigError, match="state layers"):
+        serve(host_pages=8)
+    with pytest.raises(DeepSpeedConfigError, match="paged"):
+        serve(paged=False)
+    srv = serve(prefix_cache=True)  # off, with the reason logged: a prefix
+    assert srv.scheduler.prefix_cache is None  # hit has no state to resume

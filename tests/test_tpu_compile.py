@@ -513,6 +513,96 @@ def test_minicpm_sala_slot_step_keeps_pools_and_states_in_place(
         assert name in text
 
 
+LING_IDS = [0, *range(6, 18)]  # the benchmark's cut of Ling-3.0-flash
+
+
+def test_ling_kernels_compile_at_published_widths(one_chip):
+    """The two kernels Ling-3.0-flash brings, at the benchmark cell's shapes
+    ([16, 128] rows, 32 heads of 128, a 576-wide latent padded to 640 lanes,
+    18,432 pages): the chip's compiler takes the delta rule's chunk form
+    (sub-block slices, the in-place state stack, the one-row path's column
+    operand) and the latent walk without a selection."""
+    from deepspeed_tpu.ops.pallas import kda_attention as ka
+    from deepspeed_tpu.ops.pallas import sparse_latent_attention as sla
+
+    B, S, H, hd, L = 16, 128, 32, 128, 11
+    row = ((B, S, H, hd), BF16)
+
+    def kda(q, k, v, g, beta, state, cl, nn, layer):
+        return ka.kda_attention(q, k, v, g, beta, state, cl, nn, layer=layer,
+                                scale=hd ** -0.5, interpret=False)
+
+    text = _compile(kda, one_chip, row, row, row, ((B, S, H, hd), F32),
+                    ((B, S, H), F32), ((L, B, H, hd, hd), F32), ((B,), I32),
+                    ((B,), I32), ((), I32))
+    assert "kda_attention" in text and "tpu_custom_call" in text
+    mp = 18432 // B + S // 16
+
+    def walk(q, pool, cl, nn, table, layer):
+        out, why = sla.latent_attention(
+            q, pool, cl, table, layer=layer, scale=192 ** -0.5, v_width=512,
+            num_new=nn, interpret=False)
+        assert why == []
+        return out
+
+    text = _compile(walk, one_chip, ((B, S, H, 640), BF16),
+                    ((2, 18433, 16, 640), BF16), ((B,), I32), ((B,), I32),
+                    ((B, mp), I32), ((), I32))
+    assert "latent_attention" in text and "tpu_custom_call" in text
+
+
+def test_ling_slot_step_keeps_pools_and_both_state_leaves_in_place(
+        one_chip, monkeypatch, capsys):
+    """The one [16, 128] serving step of Ling-3.0-flash at its published
+    widths and the benchmark's cut (published layers 0 and 6-17: 11 KDA + 2
+    latent mixers over 1 dense + 12 routed MLPs, 64 of 512 experts, an
+    eighth of the vocabulary) over its arena of 18,560 pages: the latent
+    pool, the KDA states and the convolution rows ride every run's scan as
+    one carry, so the compiled step holds no copy, slice or write-back the
+    size of a pool or of a state stack; both named kernels are in it; the
+    layers' matmuls run over the budget's rows; and it fits the chip."""
+    from deepspeed_tpu.models import ling
+    from deepspeed_tpu.models.decoding import init_paged_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = ling("ling-3.0-flash", layer_ids=LING_IDS, num_experts=64,
+                 moe_routed_experts=512, vocab_size=19648)
+    cfg = model.config
+    assert (cfg.kind_count("kda"), cfg.kind_count("latent")) == (11, 2)
+    assert (cfg.lead_dense_layers, cfg.num_layers) == (1, 12)
+    N, W, ps, cap = 16, 128, 16, 18432
+    pages = N * (cap + W) // ps
+    caches = jax.eval_shape(
+        lambda: init_paged_cache(cfg, pages, ps, BF16, max_slots=N))
+    assert caches["state"].shape == (11, N, 32, 128, 128)
+    assert caches["state"].dtype == F32
+    assert caches["conv"].shape == (11, N, 3, 3 * 4096)
+    assert caches["kv"].shape == (2, pages + 1, ps, 640)
+    compiled = _compile_slot_step(model, caches, one_chip, N, W,
+                                  -(-(cap + W) // ps))
+    m = compiled.memory_analysis()
+    pools = sum(a.size * a.dtype.itemsize for a in caches.values())
+    with capsys.disabled():
+        print(f"\nling slot step, described v5e: {model.num_params():,} "
+              f"parameters, arguments {m.argument_size_in_bytes / GIB:.2f} "
+              f"GiB, temporaries {m.temp_size_in_bytes / GIB:.2f} GiB, "
+              f"aliased {m.alias_size_in_bytes / GIB:.2f} (pools and states "
+              f"{pools / GIB:.2f})")
+    text = compiled.as_text()
+    assert [dims for dims in set(re.findall(r"\bf32\[([\d,]+)\]", text))
+            if np.prod([int(d) for d in dims.split(",")])
+            == N * W * cfg.vocab_size] == []
+    # (the convolution rows are 1.2 MB a layer, 13 MB the stack: a layer's
+    # block is read and written by plain slices of the scan's carry, which
+    # is the layer's own work; the leaves that matter are the GB-sized ones)
+    assert _pool_copies(
+        text, {k: v for k, v in caches.items() if k != "conv"}) == []
+    assert m.alias_size_in_bytes >= pools
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 12.0 * GIB
+    for name in ("kda_attention", "latent_attention"):
+        assert name in text
+
+
 def test_glm_train_step_fits_the_described_chip(topo, monkeypatch, capsys):
     """The train step of the cell ``glm47flash-pretrain-4k`` (its
     configuration file's model and ds_config, micro-batch 2 x 4,096,
