@@ -48,13 +48,16 @@ see docs/serving.md "Speculative decoding" and tests/test_serving_spec.py.
 from __future__ import annotations
 
 import time
+import weakref
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.experimental.layout import Format, Layout
+from jax.sharding import (NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from ..comm.topology import MeshTopology, ParallelDims
 from ..inference.engine import (InferenceEngine, _align_cache,
@@ -487,15 +490,21 @@ def make_step_fn(cfg, dtype, vocab: int, cache_shardings=None,
             num_new=num_new, token_budget=tokens.shape[1],
             token_valid=token_valid, logit_rows=rows, return_moe_stats=moe,
         )
-        if cache_shardings is not None:
-            # keep the donated arena carry sharding-closed across steps
-            caches = jax.lax.with_sharding_constraint(
-                caches, cache_shardings
-            )
         out_tok, n_emit, new_rng = verify_window(
             sample_window, logits, tokens, rows, seen, spec_len, live, rng,
             temperature, top_k, top_p, rep_penalty, eos_id,
         )
+        if cache_shardings is not None:
+            # keep the donated arena carry sharding-closed across steps, and
+            # what the next call takes back (seen, prev_tok, prev_rng) as it
+            # was handed in: one executable serves every step
+            caches = jax.lax.with_sharding_constraint(
+                caches, cache_shardings
+            )
+            rep = NamedSharding(
+                next(iter(cache_shardings.values())).mesh, P())
+            seen, out_tok, new_rng = jax.lax.with_sharding_constraint(
+                (seen, out_tok, new_rng), rep)
         return (caches, seen, out_tok, n_emit, new_rng, *moe_stats)
 
     return step
@@ -590,6 +599,41 @@ def make_paged_step_fn(cfg, dtype, vocab: int, cache_shardings=None,
                     page_table, cow_src, *rest)
 
     return tiered_step
+
+
+def compiler_param_formats(params):
+    """The step's ``in_shardings`` entry for its parameters when their
+    layouts are the compiler's to choose: ``Layout.AUTO`` a leaf, each
+    leaf's sharding kept (arrays or ``ShapeDtypeStruct``\\ s that carry
+    one)."""
+    return jax.tree.map(lambda a: Format(Layout.AUTO, a.sharding), params)
+
+
+def jit_step(step_fn, num_args: int, param_formats=None):
+    """The serve step jitted as the engine jits it: caches and ``seen``
+    donated and, with ``param_formats`` (:func:`compiler_param_formats`),
+    the layout of every parameter leaf left to the compiler; every other
+    argument as it arrives. Such a jit cannot be called: it is lowered for
+    the engine's shapes and the ONE compiled executable is what runs
+    (``Compiled.input_formats`` says what was chosen)."""
+    if param_formats is None:
+        return jax.jit(step_fn, donate_argnums=(1, 2))
+    return jax.jit(step_fn, donate_argnums=(1, 2),
+                   in_shardings=(param_formats, *[None] * (num_args - 1)))
+
+
+def _device_bytes(leaf) -> int:
+    """Bytes of ``leaf`` on one of its devices."""
+    return leaf.addressable_shards[0].data.nbytes
+
+
+def _free_device_bytes(device) -> float:
+    """What ``device`` has left, where its backend says (the CPU's does
+    not: no limit is assumed there)."""
+    stats = device.memory_stats() or {}
+    if "bytes_limit" in stats and "bytes_in_use" in stats:
+        return stats["bytes_limit"] - stats["bytes_in_use"]
+    return float("inf")
 
 
 @dataclass
@@ -927,36 +971,38 @@ class ServingEngine:
             slot_state=self.slot_state,
         )
 
-        # ---- the KV arena (contiguous slots, or a paged pool) ----------
-        if self.paged:
-            caches = init_paged_cache(
-                self.config, self.num_pages, self.page_size,
-                engine.kv_cache_storage_dtype,
-                quantized=engine.kv_cache_quantized,
-                window_pages=self.window_num_pages, max_slots=N,
-            )
-        else:
-            caches = init_cache(
-                self.config, N, self.capacity, engine.kv_cache_storage_dtype,
-                quantized=engine.kv_cache_quantized,
-            )
-        seen = jnp.zeros((N, self.config.vocab_size), jnp.bool_)
+        # ---- the KV arena (contiguous slots, or a paged pool): its shapes
+        # first. The step is compiled from them and the parameters are
+        # re-laid (below) before a byte of the arena is allocated ---------
+        def make_arena():
+            if self.paged:
+                caches = init_paged_cache(
+                    self.config, self.num_pages, self.page_size,
+                    engine.kv_cache_storage_dtype,
+                    quantized=engine.kv_cache_quantized,
+                    window_pages=self.window_num_pages, max_slots=N,
+                )
+            else:
+                caches = init_cache(
+                    self.config, N, self.capacity,
+                    engine.kv_cache_storage_dtype,
+                    quantized=engine.kv_cache_quantized,
+                )
+            return caches, jnp.zeros((N, self.config.vocab_size), jnp.bool_)
+
+        cache_shapes, seen_shape = jax.eval_shape(make_arena)
         self._cache_shardings = None
         if self.topology.world_size > 1:
             mesh = self.topology.mesh
             specs = cache_partition_specs(
                 engine.kv_cache_quantized, self.kinds_paged)
             if mcfg.mixer_types:  # a selection is a kv group's: whole leaves
-                specs = {k: P() for k in caches}
+                specs = {k: P() for k in cache_shapes}
             self._cache_shardings = {
                 k: NamedSharding(mesh, spec) for k, spec in specs.items()}
-            caches = jax.device_put(caches, self._cache_shardings)
-            seen = jax.device_put(seen, NamedSharding(mesh, P()))
+            rep = NamedSharding(mesh, P())
         else:
-            caches = jax.device_put(caches, self.topology.devices[0])
-            seen = jax.device_put(seen, self.topology.devices[0])
-        self._caches = caches
-        self._seen = seen
+            rep = SingleDeviceSharding(self.topology.devices[0])
         # tiered: the rotating in-step staging buffer (the PR-1 double-
         # buffer carry): TWO numpy fills alternate so the buffer the
         # device may still be copying from is never the one the next
@@ -972,7 +1018,7 @@ class ServingEngine:
                         (v.shape[0], STAGE_SLOTS) + tuple(v.shape[2:]),
                         dtype=v.dtype,
                     )
-                    for k, v in self._caches.items()
+                    for k, v in cache_shapes.items()
                 }
 
             self._stage_np = [stage_like(), stage_like()]
@@ -1039,7 +1085,42 @@ class ServingEngine:
             }
             return out
 
-        self._step = jax.jit(counting_step, donate_argnums=(1, 2))
+        # ---- the step's arguments after the parameters, abstract: what it
+        # is lowered for (lower_step) and what every call hands it
+        def sds(a, sharding=None):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+        def vec(dt, *tail, sharding=None):
+            return jax.ShapeDtypeStruct((N, *tail), dt, sharding=sharding)
+
+        paged_avals = ()
+        if self.paged:
+            paged_avals = (vec(jnp.int32, self.pages_per_slot),
+                           vec(jnp.int32))
+            if self.kinds_paged:
+                paged_avals = (paged_avals[0], *paged_avals)
+            if self.tiered:
+                paged_avals += (jax.tree.map(sds, self._stage_zero_np),
+                                sds(self._stage_dst_null))
+        self._step_avals = (
+            {k: sds(v, (self._cache_shardings or {}).get(k, rep))
+             for k, v in cache_shapes.items()},
+            sds(seen_shape, rep),
+            vec(jnp.int32, W), vec(jnp.int32), vec(jnp.int32), *paged_avals,
+            vec(jnp.bool_), vec(jnp.bool_), vec(jnp.int32), vec(jnp.int32),
+            vec(jnp.uint32, 2), vec(jnp.float32), vec(jnp.int32),
+            vec(jnp.float32), vec(jnp.float32), vec(jnp.bool_),
+            vec(jnp.int32, self.max_draft + 1, sharding=rep),
+            vec(jnp.uint32, 2, sharding=rep),
+        )
+        # ---- the ONE compiled step, and the parameters in the layouts it
+        # reads them in (docs/serving.md "Parameter layouts")
+        self._compile_step(counting_step)
+        self._adopt_params()
+        # ---- the arena itself
+        caches, seen = make_arena()
+        self._caches = jax.device_put(caches, self._cache_shardings or rep)
+        self._seen = jax.device_put(seen, rep)
         # ---- the order of a turn. "overlapped": step n+1 is planned and
         # dispatched before step n is fetched, so the device goes from one
         # step into the next while the host folds the first; the plan is a
@@ -1064,8 +1145,6 @@ class ServingEngine:
         # the newest step's out_tokens and new_rng, the next call's
         # operands whether or not a row of it reads them (zeros before the
         # first step, placed as the step's outputs are)
-        rep = (NamedSharding(self.topology.mesh, P())
-               if self.topology.world_size > 1 else self.topology.devices[0])
         self._prev = (
             jax.device_put(np.zeros((N, self.max_draft + 1), np.int32), rep),
             jax.device_put(np.zeros((N, 2), np.uint32), rep),
@@ -1118,6 +1197,102 @@ class ServingEngine:
             self.healthwatch.set_comm_estimate_from_streams(
                 self.analytic_streams()
             )
+
+    # ---------------------------------------------------- parameter layouts
+    def _compile_step(self, counting_step) -> None:
+        """Lower the step for this engine's shapes and compile it, once:
+        ``self._step`` is the jit (what :meth:`lower_step` lowers and whose
+        traces ``step_traces`` counts), ``self._step_exec`` the executable
+        every dispatch calls. The layouts of the parameter leaves are the
+        compiler's to choose (``param_layout`` ``"compiled"``); where that
+        lowering or its compile is refused the step is compiled for the
+        layouts the leaves are held in (``"held"``, with the refusal)."""
+        num_args = 1 + len(self._step_avals)
+        self.param_layout, self.param_layout_reason = "compiled", None
+        try:
+            self._step = jit_step(counting_step, num_args,
+                                  compiler_param_formats(self.engine.params))
+            self._step_exec = self._lower_step().compile()
+        except Exception as e:  # noqa: BLE001 - whatever refuses the choice
+            self.param_layout = "held"
+            self.param_layout_reason = (
+                "the step could not be compiled with its parameters' "
+                f"layouts left open ({type(e).__name__}: "
+                f"{str(e).splitlines()[0] if str(e) else ''})")
+            log_dist(f"serving: parameter layouts held: "
+                     f"{self.param_layout_reason}")
+            self.step_traces = 0
+            self._step = jit_step(counting_step, num_args)
+            self._step_exec = self._lower_step().compile()
+
+    def _adopt_params(self) -> None:
+        """Put ``engine.params`` into the layouts the compiled step reads
+        them in (``Compiled.input_formats``: what the compiler reports for
+        this model, mesh and shapes). The leaves held in another layout are
+        re-laid in ONE jitted identity whose outputs have the formats asked
+        for, the inputs donated: a re-laid leaf cannot alias its input, so
+        for the length of the call the moved leaves exist twice, and after
+        it the old ones are gone, whoever else held them. Where the device
+        has not the room for all of them at once they go in as few calls as
+        fit, largest first, a leaf that fits beside nothing alone. Leaves
+        that need no move stay the same arrays; the tree, shapes and dtypes
+        do not change, so every other reader of ``engine.params``
+        (``generate``, checkpoints, a reference) goes on as it was."""
+        leaves, tree = jax.tree.flatten(self.engine.params)
+        wanted = tree.flatten_up_to(self._step_exec.input_formats[0][0])
+        size = {i: _device_bytes(a)
+                for i, (a, f) in enumerate(zip(leaves, wanted))
+                if a.format.layout != f.layout}
+        moved = sorted(size, key=lambda i: -size[i])
+        nbytes = sum(size.values())
+        self.metrics.relaid_param_leaves = len(moved)
+        self.metrics.relaid_param_bytes = nbytes
+        self.metrics.param_relayout_s = 0.0
+        if moved:
+            free = _free_device_bytes(self.topology.devices[0])
+            calls, room = [], []  # first fit, largest first
+            for i in moved:
+                call = next(
+                    (c for c, left in enumerate(room) if size[i] <= left),
+                    None)
+                if call is None:
+                    call = len(calls)
+                    calls.append([])
+                    room.append(free)
+                calls[call].append(i)
+                room[call] -= size[i]
+            # whatever still draws or loads the weights is not the re-lay's
+            jax.block_until_ready([leaves[i] for i in moved])
+            t0 = time.monotonic()
+            with Phase(self.tracer, "serve/param_relayout", "serve",
+                       leaves=len(moved), bytes=nbytes, calls=len(calls)):
+                for call in calls:
+                    news = jax.jit(
+                        lambda *ls: ls,
+                        donate_argnums=tuple(range(len(call))),
+                        out_shardings=tuple(wanted[i] for i in call),
+                    )(*[leaves[i] for i in call])
+                    # the next call, or the arena, is allocated once this
+                    # one's donated inputs are free
+                    jax.block_until_ready(news)
+                    for i, new in zip(call, news):
+                        leaves[i] = new
+            self.metrics.param_relayout_s = time.monotonic() - t0
+            self.engine.params = tree.unflatten(leaves)
+            log_dist(
+                f"serving: {len(moved)} parameter leaves "
+                f"({nbytes / 2**20:.1f} MiB a device) re-laid for the "
+                f"compiled step in {self.metrics.param_relayout_s:.2f} s, "
+                f"{len(calls)} call(s)")
+        # weakly: whoever replaces the weights frees the old set first
+        self._adopted = [weakref.ref(a) for a in leaves]
+
+    def _params_adopted(self) -> bool:
+        """Are ``engine.params`` still the arrays :meth:`_adopt_params` left
+        there (about 40 us for 200 leaves, in a turn the device hides)."""
+        leaves = jax.tree.leaves(self.engine.params)
+        return len(leaves) == len(self._adopted) and all(
+            ref() is a for ref, a in zip(self._adopted, leaves))
 
     # ------------------------------------------------------------- intake
     def submit(self, request: Request) -> RequestState:
@@ -1291,18 +1466,18 @@ class ServingEngine:
                 ).astype(np.int32)
                 paged_args = ()
             traces_before = self.step_traces
-            from ..parallel.a2a_overlap import a2a_scope
-
+            if not self._params_adopted():
+                # the weights were replaced since (another seed's, a
+                # checkpoint's): the executable takes them in its layouts
+                self._adopt_params()
             # the step's attention work rides the profiler's host trace with
             # the call it describes (free while no trace is being taken)
-            with use_topology(self.topology), self.engine._impl_ctx(), \
-                    a2a_scope(self._a2a_cfg), \
-                    jax.profiler.TraceAnnotation(
-                        "serve/device_step", **keys,
-                        dense_rows=self.metrics.dense_rows_per_step):
-                # the plan's numpy vectors go to the jitted call as they are:
-                # it uploads them itself, without a device_put apiece
-                outs = self._step(
+            with jax.profiler.TraceAnnotation(
+                    "serve/device_step", **keys,
+                    dense_rows=self.metrics.dense_rows_per_step):
+                # the plan's numpy vectors go to the compiled call as they
+                # are: it uploads them itself, without a device_put apiece
+                outs = self._step_exec(
                     self.engine.params, self._caches, self._seen,
                     plan.tokens, plan.num_new, start_pos, *paged_args,
                     plan.fresh, plan.sample, spec_len, eos, rng, temp, top_k,
@@ -1494,6 +1669,11 @@ class ServingEngine:
             "step_order_reason": self.step_order_reason,
             "row_layout": self.row_layout,
             "row_layout_reason": self.row_layout_reason,
+            "param_layout": self.param_layout,
+            "param_layout_reason": self.param_layout_reason,
+            "relaid_param_leaves": self.metrics.relaid_param_leaves,
+            "relaid_param_bytes": self.metrics.relaid_param_bytes,
+            "param_relayout_s": self.metrics.param_relayout_s,
             "attention": {kind: {"path": path, "reasons": list(
                 self._kind_reasons.get(kind, ()))}
                 for kind, path in kinds.items()},
@@ -1654,47 +1834,28 @@ class ServingEngine:
             steps += 1
         return finished
 
-    def lower_step(self):
-        """The ONE jitted step, lowered for this engine's own argument
-        shapes (abstract: nothing runs, the arena is not donated).
-        ``.compile().as_text()`` answers whether a kernel really is in the
-        served program (``tpu_custom_call``) — a config value cannot."""
-        def sds(a):
-            return jax.ShapeDtypeStruct(
-                a.shape, a.dtype, sharding=getattr(a, "sharding", None)
-            )
-
-        N, W = self.max_slots, self.token_budget
-
-        def vec(dt, *tail):
-            return jax.ShapeDtypeStruct((N, *tail), dt)
-
-        paged_args = ()
-        if self.paged:
-            paged_args = (vec(jnp.int32, self.pages_per_slot), vec(jnp.int32))
-            if self.kinds_paged:
-                paged_args = (paged_args[0], *paged_args)
-            if self.tiered:
-                paged_args += (
-                    jax.tree.map(sds, self._stage_zero_np),
-                    sds(self._stage_dst_null),
-                )
+    def _lower_step(self):
         from ..parallel.a2a_overlap import a2a_scope
 
+        with use_topology(self.topology), self.engine._impl_ctx(), \
+                a2a_scope(self._a2a_cfg):
+            return self._step.lower(
+                jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(
+                        a.shape, a.dtype, sharding=a.sharding),
+                    self.engine.params),
+                *self._step_avals)
+
+    def lower_step(self):
+        """The ONE jitted step, lowered for this engine's own argument
+        shapes (abstract: nothing runs, the arena is not donated), the
+        parameters' layouts left to the compiler where ``param_layout``
+        says ``"compiled"``: ``.compile()`` is the program the engine
+        calls. Its ``.as_text()`` answers whether a kernel really is in the
+        served program (``tpu_custom_call``) — a config value cannot."""
         traces = self.step_traces
         try:
-            with use_topology(self.topology), self.engine._impl_ctx(), \
-                    a2a_scope(self._a2a_cfg):
-                return self._step.lower(
-                    jax.tree.map(sds, self.engine.params),
-                    jax.tree.map(sds, self._caches), sds(self._seen),
-                    vec(jnp.int32, W), vec(jnp.int32), vec(jnp.int32),
-                    *paged_args,
-                    vec(jnp.bool_), vec(jnp.bool_), vec(jnp.int32),
-                    vec(jnp.int32), vec(jnp.uint32, 2), vec(jnp.float32),
-                    vec(jnp.int32), vec(jnp.float32), vec(jnp.float32),
-                    vec(jnp.bool_), *map(sds, self._prev),
-                )
+            return self._lower_step()
         finally:
             # lowering may re-trace; that is not a recompile of the step
             self.step_traces = traces

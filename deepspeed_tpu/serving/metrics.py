@@ -143,6 +143,13 @@ class ServingMetrics:
         #   (norms, projections, MLPs, routers) run over: token_budget where
         #   the step packs the plan's tokens (ServingEngine.row_layout),
         #   max_slots x token_budget where it keeps the slot layout
+        self.relaid_param_leaves = 0  # parameter leaves the engine re-laid
+        #   into the layout its compiled step reads them in
+        #   (ServingEngine.param_layout), their bytes a device and the
+        #   seconds the re-lay took: the last one's (construction, or the
+        #   first step after engine.params was replaced)
+        self.relaid_param_bytes = 0
+        self.param_relayout_s = 0.0
         self.attention_paged_kernel = 0.0  # 1 when the compiled step's
         #   attention is the paged Pallas kernel (ServingEngine
         #   .attention_path; 0 = the dense XLA lines or not compiled yet)
@@ -450,6 +457,9 @@ class ServingMetrics:
             "attention_paged_kernel": self.attention_paged_kernel,
             "head_rows_per_step": self.head_rows_per_step,
             "dense_rows_per_step": self.dense_rows_per_step,
+            "relaid_param_leaves": self.relaid_param_leaves,
+            "relaid_param_bytes": self.relaid_param_bytes,
+            "param_relayout_s": self.param_relayout_s,
             "filter_steps": self.filter_steps,
         }
         for kind in self.attended_keys:

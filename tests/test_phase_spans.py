@@ -261,7 +261,7 @@ def test_a_dispatch_that_raises_closes_its_spans_and_takes_no_number(
     def refuses(*args):
         raise RuntimeError("out of memory")
 
-    srv._step = refuses
+    srv._step_exec = refuses
     srv.submit(Request(request_id="x", prompt=np.arange(9),
                        max_new_tokens=3))
     with profiled(tmp_path) as prof:

@@ -465,10 +465,10 @@ def test_paged_attention_kernel_through_engine(fallback):
     assert "not the kernel one" in dense_srv.attention_fallback[0]
     assert dense_srv.metrics.snapshot()["attention_paged_kernel"] == 0.0
 
-    srv = ServingEngine(engine=_engine(**kw), serving=serving)
-    assert srv.attention_path is None  # nothing compiled yet
-    with attention_impl("flash"):
-        got = _mixed_replay(srv)
+    with attention_impl("flash"):  # the step is compiled when it is built
+        srv = ServingEngine(engine=_engine(**kw), serving=serving)
+    assert srv.step_traces == 1
+    got = _mixed_replay(srv)
     assert got == want
     if fallback is None:
         assert srv.attention_path == "paged_kernel"
@@ -490,14 +490,14 @@ def test_paged_attention_alibi_falls_back_to_dense():
     eng = deepspeed_tpu.init_inference(
         model, dtype=jnp.float32, max_tokens=48, rng=jax.random.PRNGKey(2)
     )
-    srv = ServingEngine(engine=eng, serving={
-        "max_slots": 2, "token_budget": 8, "max_tokens": 48,
-        "paged": True, "page_size": 8,
-    })
     with attention_impl("flash"):
-        st = srv.submit(Request(request_id="b0", prompt=np.arange(11) % 64,
-                                max_new_tokens=3))
-        srv.run_until_idle()
+        srv = ServingEngine(engine=eng, serving={
+            "max_slots": 2, "token_budget": 8, "max_tokens": 48,
+            "paged": True, "page_size": 8,
+        })
+    st = srv.submit(Request(request_id="b0", prompt=np.arange(11) % 64,
+                            max_new_tokens=3))
+    srv.run_until_idle()
     assert len(st.tokens) == 3
     assert srv.attention_path == "dense"
     assert srv.attention_fallback == ("ALiBi positions",)

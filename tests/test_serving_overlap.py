@@ -272,7 +272,7 @@ def test_two_pools_and_latent_pools_replay_the_oracle(routed):
 def test_the_next_step_is_called_before_the_last_is_fetched(eng, monkeypatch):
     srv = ServingEngine(engine=eng, serving=dict(SLOTS))
     log = []
-    step, get = srv._step, jax.device_get
+    step, get = srv._step_exec, jax.device_get
 
     def called(*a):
         log.append("call")
@@ -282,7 +282,7 @@ def test_the_next_step_is_called_before_the_last_is_fetched(eng, monkeypatch):
         log.append("fetch")
         return get(x)
 
-    srv._step = called
+    srv._step_exec = called
     monkeypatch.setattr(jax, "device_get", fetched)
     states = [srv.submit(Request(request_id=f"r{i}", prompt=p,
                                  max_new_tokens=4))

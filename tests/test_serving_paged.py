@@ -135,9 +135,9 @@ def test_paged_attention_kernel_tp2_serves_the_dense_tokens():
     dense_srv = _serving(eng, paged=True)
     dense = _drive(dense_srv, prompts, news)
     assert dense_srv.attention_path == "dense"
-    srv = _serving(eng, paged=True)
-    with attention_impl("flash"):
-        kernel = _drive(srv, prompts, news)
+    with attention_impl("flash"):  # the step is compiled when it is built
+        srv = _serving(eng, paged=True)
+    kernel = _drive(srv, prompts, news)
     for i, (d, k) in enumerate(zip(dense, kernel)):
         np.testing.assert_array_equal(d.output(), k.output(),
                                       err_msg=f"r{i}")

@@ -213,14 +213,7 @@ def _pool_copies(text, caches):
     in-place scatter and the Pallas call are what may touch a stack."""
     tails = {",".join(map(str, a.shape[1:])) for a in caches.values()}
     moves = ("copy", "dynamic-slice", "dynamic-update-slice")
-    roots, comp = {}, None
-    for line in text.splitlines():
-        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(", line)
-        if head:
-            comp = head.group(1)
-        root = re.match(r"^\s+ROOT %[\w.\-]+ = [^ ]+ ([\w\-]+)\(", line)
-        if root and comp:
-            roots[comp] = root.group(1)
+    roots = _compiled_roots(text)
     found = []
     for line in text.splitlines():
         m = re.match(
@@ -237,6 +230,76 @@ def _pool_copies(text, caches):
         if op in moves:
             found.append(f"{name}: {op} -> [{dims}]")
     return found
+
+
+def _compiled_roots(text):
+    """The root operation of every computation of a compiled module."""
+    roots, comp = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            comp = head.group(1)
+        root = re.match(r"^\s+ROOT %[\w.\-]+ = [^ ]+ ([\w\-]+)\(", line)
+        if root and comp:
+            roots[comp] = root.group(1)
+    return roots
+
+
+def _param_copies(text, params):
+    """The ``copy`` instructions of a compiled step (a fusion that ends in
+    one too: what a ``dynamic-slice`` + ``copy`` pair fuses to) whose result
+    is a parameter in another layout: a whole leaf, one layer of a stack
+    (``[1, ...]`` or the bare matrix), for every leaf of at least one
+    layer's smallest attention projection (a matrix under ``attn`` with
+    both sides 128 or more). With the parameters' layouts left to the
+    compiler (PR 45) the step reads each weight as it is held."""
+    leaves = jax.tree_util.tree_flatten_with_path(params)[0]
+    floor = min(int(np.prod(a.shape[1:])) for path, a in leaves
+                if a.ndim == 3 and min(a.shape[1:]) >= 128
+                and any(getattr(k, "key", None) == "attn" for k in path))
+    shapes = set()
+    for _, a in leaves:
+        layer = list(a.shape[1:])
+        if a.ndim >= 3 and int(np.prod(layer)) >= floor:
+            shapes |= {",".join(map(str, d))
+                       for d in (a.shape, [1, *layer], layer)}
+        elif a.ndim == 2 and a.size >= floor:
+            shapes.add(",".join(map(str, a.shape)))
+    roots = _compiled_roots(text)
+    found = []
+    for line in text.splitlines():
+        m = re.match(
+            r"^\s+(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]+)\]\S* ([\w\-]+)\(",
+            line)
+        if not m or m.group(2) not in shapes:
+            continue
+        name, dims, op = m.groups()
+        if op == "fusion":
+            called = re.search(r"calls=%([\w.\-]+)", line)
+            op = roots.get(called.group(1), op) if called else op
+        if op == "copy":
+            found.append(f"{name}: copy -> [{dims}]")
+    return found
+
+
+def _check_weights_are_read_as_held(compiled, model, family, capsys):
+    """The step compiled with the chosen formats copies no parameter, and
+    says which leaves the compiler wanted in another layout than the
+    row-major one (the chip's own default for a narrow or unaligned last
+    dimension among them: the engine compares with what a leaf is held in,
+    ``ServingEngine._adopt_params``)."""
+    params = jax.eval_shape(
+        lambda k: model.init(k, dtype=BF16), jax.random.PRNGKey(0))
+    chosen = jax.tree_util.tree_flatten_with_path(
+        compiled.input_formats[0][0])[0]
+    moved = [
+        f"{jax.tree_util.keystr(path)} {list(a.shape)}"
+        for (path, f), a in zip(chosen, jax.tree.leaves(params))
+        if f.layout.major_to_minor != tuple(range(a.ndim))]
+    with capsys.disabled():
+        print(f"{family} slot step, layouts left to the compiler: "
+              f"{len(moved)} leaves not row-major: " + "; ".join(moved))
+    assert _param_copies(compiled.as_text(), params) == []
 
 
 def _check_head_runs_over_the_window(compiled, N, W, V, family, capsys):
@@ -316,10 +379,12 @@ def _check_placement_is_one_pass(compiled, rows, cfg, family, capsys):
 
 def _compile_slot_step(model, caches, one_chip, N, W, mp):
     """``make_paged_step_fn`` of ``model`` jitted as the serving engine
-    jits it (caches and ``seen`` donated), compiled for the described
-    chip with the kernel attention registered."""
+    jits it (caches and ``seen`` donated, the parameter leaves' layouts
+    left to the compiler), compiled for the described chip with the kernel
+    attention registered."""
     from deepspeed_tpu.ops.attention import attention_impl
-    from deepspeed_tpu.serving.engine import make_paged_step_fn
+    from deepspeed_tpu.serving.engine import (compiler_param_formats,
+                                              jit_step, make_paged_step_fn)
 
     cfg = model.config
 
@@ -334,16 +399,18 @@ def _compile_slot_step(model, caches, one_chip, N, W, mp):
     step = make_paged_step_fn(cfg, BF16, cfg.vocab_size)
     # a model with window layers brings their table beside the full ones'
     tables = [vec(I32, mp)] * (2 if cfg.has_window else 1)
+    args = (
+        params, jax.tree.map(sds, caches),
+        vec(jnp.bool_, cfg.vocab_size), vec(I32, W),
+        vec(I32), vec(I32), *tables, vec(I32),
+        vec(jnp.bool_), vec(jnp.bool_), vec(I32), vec(I32),
+        vec(jnp.uint32, 2), vec(F32), vec(I32), vec(F32), vec(F32),
+        # the row flag, and the step in flight's tokens and keys
+        vec(jnp.bool_), vec(I32, 1), vec(jnp.uint32, 2),
+    )
     with attention_impl("flash"):
-        return jax.jit(step, donate_argnums=(1, 2)).lower(
-            params, jax.tree.map(sds, caches),
-            vec(jnp.bool_, cfg.vocab_size), vec(I32, W),
-            vec(I32), vec(I32), *tables, vec(I32),
-            vec(jnp.bool_), vec(jnp.bool_), vec(I32), vec(I32),
-            vec(jnp.uint32, 2), vec(F32), vec(I32), vec(F32), vec(F32),
-            # the row flag, and the step in flight's tokens and keys
-            vec(jnp.bool_), vec(I32, 1), vec(jnp.uint32, 2),
-        ).compile()
+        return jit_step(step, len(args), compiler_param_formats(params)
+                        ).lower(*args).compile()
 
 
 def _check_caches_stay_in_place(compiled, caches, family, capsys):
@@ -389,6 +456,9 @@ def test_mixtral_slot_step_keeps_its_pools_in_place(one_chip, monkeypatch,
     _check_placement_is_one_pass(compiled, W, model.config, "mixtral",
                                  capsys)
     _check_dense_rows_are_the_budgets(compiled, N, W, "mixtral", capsys)
+    _check_weights_are_read_as_held(compiled, model, "mixtral", capsys)
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * GIB
     assert "paged_attention" in compiled.as_text()
 
 
@@ -417,6 +487,7 @@ def test_mellum_slot_step_compiles_beside_its_arena(one_chip, monkeypatch,
     _check_placement_is_one_pass(compiled, W, model.config, "mellum",
                                  capsys)
     _check_dense_rows_are_the_budgets(compiled, N, W, "mellum", capsys)
+    _check_weights_are_read_as_held(compiled, model, "mellum", capsys)
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * GIB
     text = compiled.as_text()
     assert "paged_attention_window" in text and "paged_attention_full" in text
@@ -458,6 +529,7 @@ def test_deepseek_slot_step_keeps_both_pools_in_place(one_chip, monkeypatch,
     _check_placement_is_one_pass(compiled, W, model.config, "deepseek",
                                  capsys)
     _check_dense_rows_are_the_budgets(compiled, N, W, "deepseek", capsys)
+    _check_weights_are_read_as_held(compiled, model, "deepseek", capsys)
     text = compiled.as_text()
     assert _pool_copies(text, caches) == []
     assert m.alias_size_in_bytes >= pools
@@ -506,6 +578,7 @@ def test_minicpm_sala_slot_step_keeps_pools_and_states_in_place(
             == N * W * cfg.vocab_size] == []
     assert _pool_copies(text, caches) == []
     _check_dense_rows_are_the_budgets(compiled, N, W, "minicpm", capsys)
+    _check_weights_are_read_as_held(compiled, model, "minicpm", capsys)
     assert m.alias_size_in_bytes >= pools
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * GIB
     for name in ("lightning_attention", "block_select",
@@ -599,6 +672,7 @@ def test_ling_slot_step_keeps_pools_and_both_state_leaves_in_place(
         text, {k: v for k, v in caches.items() if k != "conv"}) == []
     assert m.alias_size_in_bytes >= pools
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 12.0 * GIB
+    _check_weights_are_read_as_held(compiled, model, "ling", capsys)
     for name in ("kda_attention", "latent_attention"):
         assert name in text
 
