@@ -48,9 +48,13 @@ def record_attention_path():
     kernel reads the page pool through the table), ``decode_kernel`` (the
     contiguous single-token Pallas kernel) or ``dense`` (the XLA lines),
     with the ``reasons`` for a dense path, and under ``kinds`` /
-    ``kind_reasons`` the path of each layer kind and why it is a dense one. The choice is made at trace time, so the serving
-    engine opens this around its step's trace."""
-    rec = {"path": None, "reasons": (), "kinds": {}, "kind_reasons": {}}
+    ``kind_reasons`` the path of each layer kind and why it is a dense one;
+    a routed model's bank products are under ``expert_path`` /
+    ``expert_path_reason`` (:func:`_note_expert_path`). The choice is made
+    at trace time, so the serving engine opens this around its step's
+    trace."""
+    rec = {"path": None, "reasons": (), "kinds": {}, "kind_reasons": {},
+           "expert_path": None, "expert_path_reason": None}
     _path_recorders.append(rec)
     try:
         yield rec
@@ -63,6 +67,14 @@ def _note_attention_path(path: str, reasons=(), kind: str = "full") -> None:
         rec["path"], rec["reasons"] = path, tuple(reasons)
         rec["kinds"][kind] = path
         rec["kind_reasons"][kind] = tuple(reasons)
+
+
+def _note_expert_path(path: str, reason: Optional[str]) -> None:
+    """A routed layer's bank products (``moe/sharded_moe.expert_bank_path``):
+    ``touched_kernel`` or ``einsum`` with why, under ``expert_path`` /
+    ``expert_path_reason`` of the scopes open."""
+    for rec in _path_recorders:
+        rec["expert_path"], rec["expert_path_reason"] = path, reason
 
 
 def _is_ragged(cache_len) -> bool:

@@ -173,9 +173,11 @@ def cached_layers(cfg: TransformerConfig, params: Params, x, rows, pools,
             if mlp == ROUTED:
                 from ..moe.sharded_moe import moe_serving_mlp
 
+                # the banks go whole, the layer's index beside them: a
+                # kernel over them takes no slice (which would be a copy)
                 m, lstats = moe_serving_mlp(
                     cfg, layer["mlp"], normed, token_valid=token_valid,
-                    budget_tokens=budget)
+                    budget_tokens=budget, stack=(mlp_stack["mlp"], mlp_index))
             else:
                 m, _ = _mlp(cfg, layer["mlp"], normed, rng=None, train=False,
                             dense=True)

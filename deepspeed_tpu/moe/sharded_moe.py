@@ -534,6 +534,53 @@ def _experts_packed(p: Dict) -> bool:
     )
 
 
+# Expected rows an expert at a full step, r = budget x top_k / routed
+# experts, under which the serving layer's bank products take the kernel that
+# reads the touched experts' banks alone (ops/pallas/expert_bank.py). A share
+# exp(-r) of the held experts gets no row at a full step (more at a lighter
+# one), and that share of the read is all the kernel can save; what it costs
+# is its fixed part (the zero rows it writes, a grid step an expert). On the
+# chip at Ling's bank (64 x [2560, 768] bf16, 128 capacity rows; my run,
+# PR 46, PERF.md section 6) a layer's three products take 1.047 ms by the
+# einsum and 0.097 + 0.01555 ms x touched experts by the kernel: 1.092 ms
+# with all 64 touched, even at 61 (4.5 % untouched, r = 3.1), 0.72 ms at 40.
+# Ling-3.0-flash's member reads r = 2 (13 % untouched at a full step, 78 % at
+# a decode-only one) and takes the kernel; DeepSeek-V3.2's reads 4 (2 %),
+# Mellum's 16 and Mixtral's 32 (none): they could only lose, and keep the
+# einsum.
+TOUCHED_KERNEL_MAX_ROWS = 3.0
+
+
+def expert_bank_path(cfg, p: Dict, budget_tokens: int,
+                     stacked: bool) -> Tuple[str, Optional[str]]:
+    """Which way the serving layer's three bank products go, from what the
+    trace can see: ``("touched_kernel", None)`` (``expert_bank``: the banks
+    of the experts the step reached are read, no other) or ``("einsum",
+    why)`` (:func:`_expert_ffn`: every held expert's)."""
+    r = budget_tokens * cfg.moe_top_k / cfg.routed_experts
+    if r >= TOUCHED_KERNEL_MAX_ROWS:
+        return "einsum", (
+            f"{r:g} rows an expert expected at a full step (the kernel is "
+            f"taken under {TOUCHED_KERNEL_MAX_ROWS:g}): about "
+            f"{100 * math.exp(-r):.2g} % of the held experts would go "
+            "untouched")
+    if _experts_packed(p):
+        return "einsum", "the bank is packed (weight-only quantized)"
+    topo = current_topology()
+    if topo is not None and (topo.sizes.get("ep", 1) > 1
+                             or topo.tp_size > 1):
+        return "einsum", (
+            f"the bank is sharded over the mesh (ep {topo.sizes.get('ep', 1)}"
+            f" x tp {topo.tp_size}): a kernel's operand is whole")
+    if not stacked:
+        return "einsum", ("the caller hands one layer's bank, not the "
+                          "stack: a kernel's slice of it would be a copy")
+    if p["wi"].shape[-1] % 128 or p["wi"].shape[-2] % 128:
+        return "einsum", (f"bank widths {p['wi'].shape[-2:]} are no "
+                          "multiples of the 128 lanes")
+    return "touched_kernel", None
+
+
 def moe_layer(cfg, p: Dict, x: jax.Array, rng: Optional[jax.Array], train: bool):
     """Routed expert MLP. x: [B, S, D] → ([B, S, D], aux_loss scalar).
 
@@ -633,7 +680,8 @@ def moe_layer(cfg, p: Dict, x: jax.Array, rng: Optional[jax.Array], train: bool)
 
 def moe_serving_mlp(cfg, p: Dict, x: jax.Array,
                     token_valid: Optional[jax.Array] = None,
-                    budget_tokens: Optional[int] = None):
+                    budget_tokens: Optional[int] = None,
+                    stack: Optional[Tuple[Dict, jax.Array]] = None):
     """Routed expert MLP for the decode/serving path (ISSUE 14):
     x [B, S, D] → (out [B, S, D], load-balance stats).
 
@@ -672,6 +720,18 @@ def moe_serving_mlp(cfg, p: Dict, x: jax.Array,
     - **packed int8/int4 expert weights** stream through the Pallas
       matvec (:func:`_expert_proj`); packed banks always take the stock
       exchange (the tp-ring fallback rule).
+    - **the bank's path** — ``stack`` is (the layer stack's ``mlp`` tree,
+      this layer's index in it) where the caller walks a stack
+      (``models/mixers.cached_layers``). Where a full step is expected to
+      leave held experts without a row (:func:`expert_bank_path`: under
+      ``TOUCHED_KERNEL_MAX_ROWS`` rows an expert, a dense unsharded bank
+      of lane-wide sides, outside the ring) the three products are two
+      calls of ``ops/pallas/expert_bank`` over ``stack``'s ``wi`` / ``wg``
+      / ``wo`` at that index, which read the banks of the experts that
+      hold a valid capacity row (``tokens_per_expert`` > 0 where nothing
+      is dropped) and write zeros for the others; everywhere else :func:`_expert_ffn` over ``p``'s. Placement,
+      the capacity rows and the combine are the same lines either way. The
+      choice is noted for the engine (``ServingEngine.expert_path``).
 
     Returns ``(out, stats)`` with stats = {"tokens_per_expert" [E] i32,
     "routed_tokens" i32, "drop_fraction" f32} — the serving metrics
@@ -721,6 +781,14 @@ def moe_serving_mlp(cfg, p: Dict, x: jax.Array,
             topo, E=E, F=p["wi"].shape[-1], n_tokens=N
         ):
             ring_cfg = ov
+    from ..models.decoding import _note_expert_path
+
+    if ring_cfg is not None:
+        bank_path, why = "einsum", "the decode a2a ring runs the experts"
+    else:
+        bank_path, why = expert_bank_path(cfg, p, int(budget_tokens),
+                                          stack is not None)
+    _note_expert_path(bank_path, why)
     if ring_cfg is not None:
         # the chunked-ppermute decode ring runs dispatch + FFN + combine
         # per ep member (each member emits its own token block, the
@@ -740,8 +808,19 @@ def moe_serving_mlp(cfg, p: Dict, x: jax.Array,
             .reshape(E, capacity, D)
             * slot_valid[..., None].astype(x.dtype)
         )
-        expert_in = constrain(expert_in, "ep", None, None)
-        expert_out = _expert_ffn(cfg, p, expert_in)
+        if bank_path == "touched_kernel":
+            from ..ops.pallas.expert_bank import expert_bank
+
+            banks, at = stack
+            # an expert's rows here: tokens_per_expert where none is dropped
+            fill = jnp.sum(slot_valid, axis=1, dtype=jnp.int32)
+            swiglu = cfg.activation == "swiglu"  # or GELU: _expert_ffn's
+            h = expert_bank(expert_in, banks["wi"], at, fill, gelu=not swiglu,
+                            gate=banks["wg"] if swiglu else None)
+            expert_out = expert_bank(h, banks["wo"], at, fill)
+        else:
+            expert_in = constrain(expert_in, "ep", None, None)
+            expert_out = _expert_ffn(cfg, p, expert_in)
         # combine: dropped/invalid tokens carry w == 0, so their slot-0
         # fallback gather contributes exact zeros
         picked = jnp.take(

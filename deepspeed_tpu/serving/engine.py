@@ -1053,6 +1053,12 @@ class ServingEngine:
         self.attention_paths: Dict[str, str] = {}
         self.attention_fallback: Tuple[str, ...] = ()
         self._kind_reasons: Dict[str, Tuple[str, ...]] = {}
+        # a routed model's bank products, chosen at trace time likewise
+        # (moe/sharded_moe.expert_bank_path): "touched_kernel" (the banks of
+        # the experts a step reached are read) or "einsum" (every held
+        # expert's) with why; None for a model without routed layers
+        self.expert_path: Optional[str] = None
+        self.expert_path_reason: Optional[str] = None
         self.metrics.state_bytes = state_bytes(
             mcfg, N, jnp.dtype(engine.kv_cache_storage_dtype).itemsize)
         # a routed model with mixers: the held experts that got a row in the
@@ -1066,6 +1072,10 @@ class ServingEngine:
             self.attention_path = rec["path"]
             self.attention_fallback = rec["reasons"]
             self._kind_reasons = dict(rec["kind_reasons"])
+            self.expert_path = rec["expert_path"]
+            self.expert_path_reason = rec["expert_path_reason"]
+            self.metrics.expert_touched_kernel = float(
+                self.expert_path == "touched_kernel")
             if mcfg.mixer_types:
                 # a path a mixer kind: "block_sparse_kernel" / "dense" for
                 # sparse layers, "lightning_kernel", "kda_kernel" and
@@ -1187,6 +1197,7 @@ class ServingEngine:
             f", order={self.step_order}, rows={self.row_layout}"
             + (
                 f", moe=ep{self.moe_ep}/{self.moe_a2a_form}"
+                f"/{self.expert_path}"
                 if self.moe_serving else ""
             )
         )
@@ -1654,7 +1665,8 @@ class ServingEngine:
         once its step has been traced): the order of a turn and the layout
         of the rows with their reasons, the attention path of every layer
         kind (``*_kernel`` or ``dense``) with the reasons for a dense one,
-        and the bytes of every leaf a slot keeps that is no page."""
+        a routed model's bank products (``touched_kernel`` or ``einsum`` and
+        why), and the bytes of every leaf a slot keeps that is no page."""
         from ..models.mixers import slot_leaves
 
         mcfg = self.config
@@ -1677,6 +1689,8 @@ class ServingEngine:
             "attention": {kind: {"path": path, "reasons": list(
                 self._kind_reasons.get(kind, ()))}
                 for kind, path in kinds.items()},
+            "expert_path": self.expert_path,
+            "expert_path_reason": self.expert_path_reason,
             "paged_layers": mcfg.paged_layers,
             "state_leaves": {
                 name: int(np.prod(leaf.shape)) * leaf.dtype.itemsize

@@ -153,6 +153,10 @@ class ServingMetrics:
         self.attention_paged_kernel = 0.0  # 1 when the compiled step's
         #   attention is the paged Pallas kernel (ServingEngine
         #   .attention_path; 0 = the dense XLA lines or not compiled yet)
+        self.expert_touched_kernel = 0.0  # 1 when the compiled step's
+        #   expert banks go through the kernel that reads the touched
+        #   experts' alone (ServingEngine.expert_path; 0 = the einsum over
+        #   every held expert, no routed layer, or not compiled yet)
         self.queue_depth = 0
         self.slot_occupancy = 0.0
         self.pages_in_use = 0
@@ -455,6 +459,7 @@ class ServingMetrics:
             "mean_accepted_tokens_per_step":
                 self.mean_accepted_tokens_per_step,
             "attention_paged_kernel": self.attention_paged_kernel,
+            "expert_touched_kernel": self.expert_touched_kernel,
             "head_rows_per_step": self.head_rows_per_step,
             "dense_rows_per_step": self.dense_rows_per_step,
             "relaid_param_leaves": self.relaid_param_leaves,

@@ -502,3 +502,116 @@ def test_paged_attention_alibi_falls_back_to_dense():
     assert srv.attention_path == "dense"
     assert srv.attention_fallback == ("ALiBi positions",)
     assert srv.step_traces == 1
+
+
+# ------------------------------------------------- the bank products' path
+# One case a configuration the benchmark serves, at its ratio of expected
+# rows an expert at a full step (budget x top-k / routed experts), every
+# bank lane-wide so that nothing but the ratio, the mesh and the caller's
+# stack decide (moe/sharded_moe.expert_bank_path).
+def _shaped(name):
+    from deepspeed_tpu.models import deepseek, ling, mellum
+
+    wide = dict(hidden_size=128, intermediate_size=128)
+    if name == "mixtral":   # 8 experts, top-2: 32 rows
+        return tiny_mixtral(num_experts=8, moe_top_k=2, max_seq_len=512,
+                            **wide)
+    if name == "mellum":    # 64 of 64, top-8: 16 rows
+        return mellum("mellum-tiny", num_experts=64, moe_top_k=8, **wide)
+    if name == "deepseek":  # 16 held of 256, top-8: 4 rows
+        return deepseek("deepseek-tiny", num_experts=16,
+                        moe_routed_experts=256, moe_top_k=8, moe_groups=8,
+                        moe_groups_kept=4, **wide)
+    # 64 held of 512, top-8: 2 rows
+    return ling("ling-tiny", layer_ids=[0, 1, 2, 5], num_experts=64,
+                moe_routed_experts=512, moe_top_k=8, moe_groups=8,
+                moe_groups_kept=4, num_heads=8, **wide)
+
+
+def _served(name, **kw):
+    eng = deepspeed_tpu.init_inference(
+        _shaped(name), dtype=jnp.float32, max_tokens=256,
+        rng=jax.random.PRNGKey(1), **kw)
+    return ServingEngine(engine=eng, serving={
+        "max_slots": 2, "token_budget": 128, "max_tokens": 256,
+        "paged": True, "page_size": 16})
+
+
+@pytest.mark.parametrize("name, rows", [
+    ("mixtral", 32), ("mellum", 16), ("deepseek", 4)])
+def test_banks_of_a_layer_whose_experts_are_all_touched_take_the_einsum(
+        name, rows):
+    srv = _served(name)
+    assert srv.expert_path == "einsum"
+    assert srv.expert_path_reason.startswith(f"{rows} rows an expert")
+    assert srv.describe()["expert_path"] == "einsum"
+    assert srv.describe()["expert_path_reason"] == srv.expert_path_reason
+    assert srv.metrics.snapshot()["expert_touched_kernel"] == 0.0
+    assert "expert_bank" not in srv.lower_step().as_text(debug_info=True)
+
+
+def test_banks_of_a_ling_shaped_layer_take_the_touched_kernel():
+    srv = _served("ling")
+    assert (srv.expert_path, srv.expert_path_reason) == (
+        "touched_kernel", None)
+    assert srv.describe()["expert_path"] == "touched_kernel"
+    assert srv.metrics.snapshot()["expert_touched_kernel"] == 1.0
+    assert "expert_bank" in srv.lower_step().as_text(debug_info=True)
+    st = srv.submit(Request(request_id="a", prompt=np.arange(150) % 64,
+                            max_new_tokens=3))
+    srv.run_until_idle()
+    assert srv.step_traces == 1 and len(st.tokens) == 3
+    # the experts that got a row, as the device counted them: not all
+    assert 0 < srv.metrics.moe_experts_touched
+
+
+def test_banks_sharded_over_ep_take_the_einsum_and_serve_the_same(devices8):
+    topo = MeshTopology(dims=ParallelDims(ep=2), devices=jax.devices()[:2])
+    srv = _served("ling", topology=topo)
+    assert srv.expert_path == "einsum"
+    assert "sharded over the mesh (ep 2" in srv.expert_path_reason
+    assert "expert_bank" not in srv.lower_step().as_text(debug_info=True)
+    whole = _served("ling")
+    tokens = []
+    for s in (srv, whole):
+        st = s.submit(Request(request_id="a", prompt=np.arange(40) % 64,
+                              max_new_tokens=4))
+        s.run_until_idle()
+        tokens.append(list(st.tokens))
+    assert tokens[0] == tokens[1]
+
+
+@pytest.mark.parametrize("case", ["packed", "no_stack", "narrow", "ling",
+                                  "ling_full_width"])
+def test_expert_bank_path_reads_the_static_shapes(case):
+    """The rule alone, at the published shapes where it matters."""
+    from deepspeed_tpu.models import ling
+    from deepspeed_tpu.moe.sharded_moe import (TOUCHED_KERNEL_MAX_ROWS,
+                                               expert_bank_path)
+    from deepspeed_tpu.ops.quantizer import pack_quantize_blockwise
+
+    assert 2 < TOUCHED_KERNEL_MAX_ROWS <= 4
+    cfg = _shaped("ling").config
+    bank = jnp.zeros((64, 128, 128), jnp.float32)
+    if case == "packed":
+        path, why = expert_bank_path(
+            cfg, {"wi": pack_quantize_blockwise(bank)}, 128, True)
+        assert path == "einsum" and "packed" in why
+    elif case == "no_stack":
+        path, why = expert_bank_path(cfg, {"wi": bank}, 128, False)
+        assert path == "einsum" and "not the stack" in why
+    elif case == "narrow":
+        path, why = expert_bank_path(cfg, {"wi": bank[..., :64]}, 128, True)
+        assert path == "einsum" and "128 lanes" in why
+    elif case == "ling":
+        assert expert_bank_path(cfg, {"wi": bank}, 128, True) == (
+            "touched_kernel", None)
+        # a budget under which a step would touch every expert: the einsum
+        path, why = expert_bank_path(cfg, {"wi": bank}, 256, True)
+        assert path == "einsum" and why.startswith("4 rows")
+    else:
+        full = ling("ling-3.0-flash", layer_ids=[0, *range(6, 18)],
+                    num_experts=64, moe_routed_experts=512).config
+        wi = jax.ShapeDtypeStruct((64, 2560, 768), jnp.bfloat16)
+        assert expert_bank_path(full, {"wi": wi}, 128, True) == (
+            "touched_kernel", None)

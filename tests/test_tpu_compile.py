@@ -624,6 +624,29 @@ def test_ling_kernels_compile_at_published_widths(one_chip):
     assert "latent_attention" in text and "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("L, E, C, K, N, gated", [
+    (12, 64, 128, 2560, 768, True),    # Ling's wi / wg: one block a bank
+    (12, 64, 128, 768, 2560, False),   # Ling's wo
+    (3, 8, 128, 4096, 14336, True),    # Mixtral's: 28 tiles of 512 lanes
+], ids=["ling_up", "ling_down", "tiled_up"])
+def test_expert_bank_compiles_over_the_stack(one_chip, L, E, C, K, N, gated):
+    """The touched-experts bank matmul at the widths of the cell that takes
+    it (and at a bank wide enough to be tiled): the stack whole with the
+    layer's index and the walk's tables in SMEM, the double-buffered 4 MB
+    weight blocks inside the VMEM limit the call sets."""
+    from deepspeed_tpu.ops.pallas import expert_bank as eb
+
+    def bank(x, w, g, layer, fill):
+        return eb.expert_bank(x, w, layer, fill, gate=g if gated else None,
+                              interpret=False)
+
+    text = _compile(bank, one_chip, ((E, C, K), BF16), ((L, E, K, N), BF16),
+                    ((L, E, K, N), BF16), ((), I32), ((E,), I32))
+    assert "expert_bank" in text and "tpu_custom_call" in text
+    # no slice or copy of the stack feeds the call
+    assert not re.search(rf"= bf16\[(?:1,)?{E},{K},{N}\]", text)
+
+
 def test_ling_slot_step_keeps_pools_and_both_state_leaves_in_place(
         one_chip, monkeypatch, capsys):
     """The one [16, 128] serving step of Ling-3.0-flash at its published
@@ -632,8 +655,10 @@ def test_ling_slot_step_keeps_pools_and_both_state_leaves_in_place(
     eighth of the vocabulary) over its arena of 18,560 pages: the latent
     pool, the KDA states and the convolution rows ride every run's scan as
     one carry, so the compiled step holds no copy, slice or write-back the
-    size of a pool or of a state stack; both named kernels are in it; the
-    layers' matmuls run over the budget's rows; and it fits the chip."""
+    size of a pool or of a state stack; both named kernels are in it, and
+    the bank matmul that reads the touched experts (``expert_bank``, over
+    the bank stacks whole); the layers' matmuls run over the budget's rows;
+    and it fits the chip."""
     from deepspeed_tpu.models import ling
     from deepspeed_tpu.models.decoding import init_paged_cache
 
@@ -673,8 +698,14 @@ def test_ling_slot_step_keeps_pools_and_both_state_leaves_in_place(
     assert m.alias_size_in_bytes >= pools
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 12.0 * GIB
     _check_weights_are_read_as_held(compiled, model, "ling", capsys)
-    for name in ("kda_attention", "latent_attention"):
+    for name in ("kda_attention", "latent_attention", "expert_bank"):
         assert name in text
+    # the routed layers' banks go to the kernel as the stack they are held
+    # in: no instruction makes one layer's bank ([64, 2560, 768] or its
+    # transpose, 252 MB), by slice, copy or fusion
+    assert re.findall(
+        r"= bf16\[(?:1,)?64,(?:2560,768|768,2560)\]\S* [\w\-]+\(", text) == []
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * GIB
 
 
 def test_glm_train_step_fits_the_described_chip(topo, monkeypatch, capsys):
