@@ -24,7 +24,6 @@ from .sharding import constrain
 from .transformer import (
     Params,
     TransformerConfig,
-    _mlp,
     _norm,
     _latent_projections,
     _qk_norm,
@@ -268,7 +267,7 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
     [L_window, window_pages + 1, ...] the window layers, whose pages a
     slot gives back once every query still to come is past them.
 
-    A model with mixers (``cfg.mixer_types``, models/mixers.py) keeps pages
+    A model that names its layers (``cfg.mixer_types``) keeps pages
     for the layers whose kind keeps any (``MIXER_KINDS``: ``k``/``v`` and a
     compressed key a page, ``kc``, for sparse layers; latent rows ``kv`` for
     latent layers), and for its state layers leaves that are indexed by
@@ -874,7 +873,10 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
                        token_budget: Optional[int] = None,
                        logit_rows=None,
                        return_moe_stats: bool = False):
-    """Run new tokens through all layers against the cache.
+    """Run new tokens through all layers against the cache: embed the rows,
+    walk the layers (models/mixers.py ``cached_layers``, the one walk of
+    every model: runs of a period of (mixer, MLP) kinds, a scan each, the
+    cache their carry), then the final norm and the head.
 
     Which rows are computed. ``token_budget`` (with ``num_new``) is the
     caller's promise that the chunk holds at most that many real tokens
@@ -934,138 +936,22 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
         x = x + cast(params["embed"]["pos"])[positions]
     if cfg.embed_norm:
         x = _norm(cfg, cast(params["embed_norm"]), x)
+    if cfg.scale_emb != 1.0:  # muP
+        x = x * jnp.asarray(cfg.scale_emb, x.dtype)
     x = constrain(x, ("dp", "fsdp"), None, None)
-    if cfg.mixer_types:
-        # layers by published order, a parameter stack a mixer kind and an
-        # MLP kind, runs of equal (mixer, MLP) a scan each over the same
-        # carry (models/mixers.py); muP scales
-        from .mixers import cached_layers, stacks_of
+    # the layers: runs of a period of (mixer, MLP) kinds, a scan each over
+    # the same carry, the hidden rows and the cache, every leaf whole and in
+    # the layout it came in (models/mixers.py)
+    from .mixers import cached_layers, stacks_of
 
-        stacks = {k: cast(params[k]) for k in stacks_of(cfg) if k in params}
-        if cfg.scale_emb != 1.0:
-            x = x * jnp.asarray(cfg.scale_emb, x.dtype)
-        x, new_cache, moe_stats = cached_layers(
-            cfg, stacks, x, rows, dict(cache), cache_len, page_table,
-            num_new, token_valid=token_valid)
-        x = rows.unpack(x) if logit_rows is None else rows.take(x, logit_rows)
-        x = _norm(cfg, cast(params["final_norm"]), x)
-        if cfg.dim_model_base:
-            x = x / jnp.asarray(cfg.hidden_size / cfg.dim_model_base, x.dtype)
-        logits = lm_head_logits(cfg, params, x)
-        return (logits, new_cache, moe_stats) if return_moe_stats else (
-            logits, new_cache)
-
-    moe = cfg.is_moe
-    collect_moe = bool(return_moe_stats) and moe
-    # the most real tokens a step holds: what an expert's capacity is of
-    budget_tokens = rows.count if (
-        rows.packed or token_valid is None) else S
-    # Layers of several kinds scan whole periods of the pattern, a period's
-    # layers unrolled with their kinds static; one kind scans single layers
-    # with the weights as xs. The cache rides the scan as its CARRY, every
-    # leaf whole and in the layout it came in: a layer writes its keys in
-    # place at its index inside its pool and reads from the same buffer, so
-    # no trip copies a pool. Under a page table a model with window layers
-    # keeps two pools (init_paged_cache): a layer reads the leaves and the
-    # table of its kind. Leading dense layers (``lead_layers``, a stack of
-    # another parameter shape) are a scan of their own before the main one,
-    # over the same carry: their rows are the pools' first.
-    kinds = cfg.layer_pattern or ("full",)
-    period = len(kinds)
-    split = page_table is not None and cfg.has_window
-    tables = {"": page_table, WIN: page_table_win}
-    place = []  # (leaf suffix, index inside the period's share of that pool)
-    for j, kind in enumerate(kinds):
-        sfx = WIN if split and kind == "window" else ""
-        place.append((sfx, sum(1 for s, _ in place if s == sfx)))
-    # layers of a period in each pool: trip * share + place is a layer's
-    # index inside its pool
-    share = {sfx: sum(1 for s, _ in place if s == sfx) for sfx in tables}
-
-    def make_body(layers, base: int, routed: bool):
-        """The scan body over the stack ``layers``, whose first layer is
-        layer ``base`` of the pools; ``routed``: its MLP is the expert
-        layer."""
-        def body(carry, scanned):
-            h, pools = carry
-            group, layer = scanned  # the trip; one kind: its layer's weights
-            stats = []
-            for j, (kind, (sfx, at)) in enumerate(zip(kinds, place)):
-                # a period's layers are read from the whole stack one at a
-                # time: a period-sized slice of the weights would be copied
-                # every trip
-                if period > 1:
-                    layer = jax.tree.map(
-                        lambda a: lax.dynamic_index_in_dim(
-                            a, group * period + j, 0, keepdims=False), layers)
-                index = base + group * share[sfx] + at
-                normed = _norm(cfg, layer["ln1"], h)
-                if cfg.is_latent:
-                    a, pools = _latent_cached_attention(
-                        cfg, layer["attn"], normed, rows, index, pools,
-                        cache_len, page_table, num_new=num_new)
-                else:
-                    names = [n + sfx for n in ("k", "v", "k_scale", "v_scale")
-                             if n + sfx in pools]
-                    a, *updated = _cached_attention(
-                        cfg, layer["attn"], normed, rows, index,
-                        *(pools[n] for n in names[:2]),
-                        cache_len, *(pools[n] for n in names[2:]),
-                        page_table=tables[sfx], num_new=num_new, kind=kind,
-                    )
-                    pools = {**pools, **dict(zip(names, updated))}
-                h = h + a
-                normed = _norm(cfg, layer["ln2"], h)
-                if routed:
-                    from ..moe.sharded_moe import moe_serving_mlp
-
-                    # the routed decode path: capacity from the STATIC budget
-                    # (token_budget for the slot engine, B·S for lockstep),
-                    # padded and idle rows to the null expert
-                    m, lstats = moe_serving_mlp(
-                        cfg, layer["mlp"], normed, token_valid=token_valid,
-                        budget_tokens=budget_tokens,
-                    )
-                    stats.append(lstats)
-                else:
-                    m, _aux = _mlp(cfg, layer["mlp"], normed, rng=None,
-                                   train=False, dense=True)
-                h = h + m
-                h = constrain(h, ("dp", "fsdp"), None, None)
-            if not (collect_moe and routed):
-                return (h, pools), None
-            return (h, pools), jax.tree.map(lambda *t: jnp.stack(t), *stats)
-
-        return body
-
-    carry = (x, dict(cache))
-    if cfg.lead_dense_layers:
-        lead = cast(params["lead_layers"])
-        carry, _ = lax.scan(
-            make_body(lead, 0, False), carry,
-            (jnp.arange(cfg.lead_dense_layers), lead))
-    layers = cast(params["layers"])
-    (x, new_cache), lstats = lax.scan(
-        make_body(layers, cfg.lead_dense_layers, moe), carry,
-        (jnp.arange(cfg.num_layers // period),
-         layers if period == 1 else None))
-    if collect_moe:  # [trips, period, ...] -> one row a layer
-        lstats = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), lstats)
+    x, new_cache, moe_stats = cached_layers(
+        cfg, {k: cast(params[k]) for k in stacks_of(cfg)}, x, rows,
+        dict(cache), cache_len, page_table, num_new, token_valid=token_valid,
+        page_table_win=page_table_win)
     x = rows.unpack(x) if logit_rows is None else rows.take(x, logit_rows)
     x = _norm(cfg, cast(params["final_norm"]), x)
+    if cfg.dim_model_base:  # muP
+        x = x / jnp.asarray(cfg.hidden_size / cfg.dim_model_base, x.dtype)
     logits = lm_head_logits(cfg, params, x)
-    if return_moe_stats:
-        moe_stats = None
-        if collect_moe:
-            # per-layer stacks → one per-step view (the metrics counters)
-            moe_stats = {
-                "tokens_per_expert": jnp.sum(
-                    lstats["tokens_per_expert"], axis=0
-                ),
-                "drop_fraction": jnp.mean(lstats["drop_fraction"]),
-            }
-            if "unrouted_tokens" in lstats:  # one member's share of a layer
-                moe_stats["unrouted_tokens"] = jnp.sum(
-                    lstats["unrouted_tokens"])
-        return logits, new_cache, moe_stats
-    return logits, new_cache
+    return (logits, new_cache, moe_stats) if return_moe_stats else (
+        logits, new_cache)

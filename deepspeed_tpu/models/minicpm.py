@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .mixers import runs  # noqa: F401  (the walk's; the tests read it here)
 from .transformer import (Params, TransformerConfig, TransformerModel,
                           _rms_last, _rope)
 

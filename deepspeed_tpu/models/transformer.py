@@ -34,12 +34,11 @@ from .sharding import constrain, current_topology
 Params = Dict[str, Any]
 
 
-LAYER_KINDS = ("full", "window")
-
-
 @dataclass(frozen=True)
 class MixerKind:
-    """One kind of ``mixer_types``: the pool leaves it keeps a SLOT (indexed
+    """One kind of mixer a layer can have (named by ``mixer_types``, or
+    declared by ``layer_pattern`` / ``kv_latent_dim`` for the kinds of
+    models/decoding.py): the pool leaves it keeps a SLOT (indexed
     by slot, no page: begun at zero with a request, never shared), those it
     keeps a PAGE (through the page table), the module of ``models/`` that
     owns its parameters and pools, and what it needs of the configuration
@@ -52,6 +51,15 @@ class MixerKind:
 
 
 MIXER_KINDS: Dict[str, MixerKind] = {
+    # attention over every key of the K / V pool (int8 with its scales)
+    "full": MixerKind((), ("k", "v", "k_scale", "v_scale"), "decoding"),
+    # attention over the last ``attn_window`` keys; a paged cache keeps the
+    # window layers a pool and a page table of their own (``k_win`` ...)
+    "window": MixerKind((), ("k", "v", "k_scale", "v_scale"), "decoding"),
+    # latent attention as every layer of a ``kv_latent_dim`` model has it:
+    # over every key of the latent pool or, with an indexer
+    # (``index_topk``), over the best by its keys
+    "mla": MixerKind((), ("kv", "ki"), "decoding"),
     # grouped-query attention over a learned selection of blocks of pages
     "sparse": MixerKind((), ("k", "v", "kc"), "minicpm",
                         ("block_sparse", "the geometry of its selection")),
@@ -64,6 +72,11 @@ MIXER_KINDS: Dict[str, MixerKind] = {
     "latent": MixerKind((), ("kv",), "ling",
                         ("kv_latent_dim", "the width of its cached latent")),
 }
+
+# the kinds ``layer_pattern`` may name: those of models/decoding.py that
+# keep K / V pages (a contiguous cache holds a pattern's layers in one pool)
+LAYER_KINDS = tuple(k for k, kind in MIXER_KINDS.items()
+                    if kind.family == "decoding" and "k" in kind.page)
 
 
 @dataclass(frozen=True)
@@ -263,11 +276,15 @@ class TransformerConfig:
     def _check_mixers(self) -> None:
         """``mixer_types`` against the table of kinds: what each kind keeps
         and needs decides what the configuration must bring."""
-        unknown = sorted(set(self.mixer_types) - set(MIXER_KINDS))
+        # (models/decoding.py's kinds are declared by layer_pattern and
+        # kv_latent_dim: they have no stack a kind)
+        named = sorted(k for k, kind in MIXER_KINDS.items()
+                       if kind.family != "decoding")
+        unknown = sorted(set(self.mixer_types) - set(named))
         if unknown:
             raise ValueError(
                 f"mixer_types names {unknown}: no such mixer kind (have "
-                f"{sorted(MIXER_KINDS)}, see MIXER_KINDS)")
+                f"{named}, see MIXER_KINDS)")
         names = list(dict.fromkeys(self.mixer_types))
         families = sorted({MIXER_KINDS[n].family for n in names})
         if len(families) > 1:

@@ -722,7 +722,7 @@ def moe_serving_mlp(cfg, p: Dict, x: jax.Array,
       exchange (the tp-ring fallback rule).
     - **the bank's path** — ``stack`` is (the layer stack's ``mlp`` tree,
       this layer's index in it) where the caller walks a stack
-      (``models/mixers.cached_layers``). Where a full step is expected to
+      (``models/mixers.cached_layers``, every served model's walk). Where a full step is expected to
       leave held experts without a row (:func:`expert_bank_path`: under
       ``TOUCHED_KERNEL_MAX_ROWS`` rows an expert, a dense unsharded bank
       of lane-wide sides, outside the ring) the three products are two

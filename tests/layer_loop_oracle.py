@@ -1,5 +1,6 @@
-"""The oracle of ``forward_with_cache``'s scan: the same layers as a plain
-Python loop, every layer on a cache of its own (a stack of one, written at
+"""The oracle of the layer walk under ``forward_with_cache``
+(models/mixers.py ``cached_layers``: runs of a period, a scan each) for the
+models of ``layer_pattern``: the same layers as a plain Python loop, every layer on a cache of its own (a stack of one, written at
 index 0), so neither a carry nor an index inside a pool exists to get
 wrong. Shared by tests/test_inference.py, tests/test_mellum.py and
 tests/test_serving_tail.py."""

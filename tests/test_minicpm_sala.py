@@ -15,7 +15,7 @@ import deepspeed_tpu
 from deepspeed_tpu.config import DeepSpeedConfigError
 from deepspeed_tpu.models import minicpm
 from deepspeed_tpu.models.decoding import forward_with_cache, init_paged_cache
-from deepspeed_tpu.models.minicpm import runs
+from deepspeed_tpu.models.mixers import walk_runs
 from deepspeed_tpu.ops.attention import attention_impl
 from deepspeed_tpu.ops.pallas import block_sparse_attention as bsa
 from deepspeed_tpu.ops.pallas import lightning_attention as la
@@ -329,14 +329,16 @@ def test_the_selection_is_the_brute_force_one(kernel):
 # ---------------------------------------------------------------- the shapes
 def test_published_order_and_the_32_layer_preset():
     tiny = minicpm("minicpm-sala-tiny").config
-    assert [r[0] for r in runs(tiny)] == ["sparse", "lightning", "sparse",
-                                          "lightning"]
+    assert [r.period for r in walk_runs(tiny)] == [
+        ((kind, "dense"),) for kind in ("sparse", "lightning", "sparse",
+                                        "lightning")]
     model = minicpm("minicpm-sala")
     cfg = model.config
     assert cfg.num_layers == 32 and cfg.kind_count("sparse") == 8
     assert [i for i, k in enumerate(cfg.mixer_types) if k == "sparse"] == [
         0, 9, 16, 17, 22, 29, 30, 31]
-    assert len(runs(cfg)) == 9 and sum(r[2] for r in runs(cfg)) == 32
+    runs = walk_runs(cfg)
+    assert len(runs) == 9 and sum(r.trips for r in runs) == 32
     shapes = jax.eval_shape(lambda k: model.init(k, dtype=jnp.bfloat16),
                             jax.random.PRNGKey(0))
     assert shapes["lightning_layers"]["attn"]["wk"].shape == (24, 4096, 4096)

@@ -219,10 +219,13 @@ def test_weights_replaced_after_construction_are_relaid_at_the_next_step(
 # ---------------------------------------------------------------------------
 def live_bytes() -> int:
     """Bytes of the live device buffers, each once however many arrays
-    view it (a tree committed to the device it is on shares its buffers)."""
+    view it (a tree committed to the device it is on shares its buffers),
+    shard by shard: an earlier test file of the same worker may have left
+    an array alive that is sharded over a mesh, which has no one pointer."""
     gc.collect()
-    return sum({a.unsafe_buffer_pointer(): a.nbytes
-                for a in jax.live_arrays() if not a.is_deleted()}.values())
+    return sum({s.data.unsafe_buffer_pointer(): s.data.nbytes
+                for a in jax.live_arrays() if not a.is_deleted()
+                for s in a.addressable_shards}.values())
 
 
 @pytest.mark.parametrize("family", ["dense", "minicpm_sala_shaped"])
