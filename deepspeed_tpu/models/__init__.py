@@ -13,6 +13,7 @@ from .deepseek import deepseek, deepseek_config  # noqa: F401
 from .glm import glm, glm_config  # noqa: F401
 from .minicpm import minicpm, minicpm_config  # noqa: F401
 from .ling import ling, ling_config  # noqa: F401
+from .brumby import brumby, brumby_config  # noqa: F401
 
 MODEL_REGISTRY = {
     "gpt2": gpt2,
@@ -24,6 +25,7 @@ MODEL_REGISTRY = {
     "glm": glm,
     "minicpm": minicpm,
     "ling": ling,
+    "brumby": brumby,
 }
 
 

@@ -2,10 +2,11 @@
 
 Each layer has a MIXER kind (``transformer.MIXER_KINDS``: "full" | "window" |
 "mla" of models/decoding.py, "sparse" | "lightning" of models/minicpm.py,
-"kda" | "latent" of models/ling.py) and, independently, an MLP kind ("dense":
-a SwiGLU or GELU MLP of the layer's own width, the leading dense layers of a
-routed model among them; "routed": an expert layer or one member's share of
-one, ``moe/sharded_moe.moe_serving_mlp``). A layer reads its mixer and its MLP
+"kda" | "latent" of models/ling.py, "retention" of models/brumby.py) and,
+independently, an MLP kind ("dense": a SwiGLU or GELU MLP of the layer's own
+width, the leading dense layers of a routed model among them; "routed": an
+expert layer or one member's share of one,
+``moe/sharded_moe.moe_serving_mlp``). A layer reads its mixer and its MLP
 each at its index inside a parameter stack, and its pool leaves at its index
 inside its pool (:func:`layer_plan`).
 
@@ -177,6 +178,11 @@ def _mix(kind: str, cfg, p, x, rows, pools, index, layer_id, cache_len,
 
         return kda_mixer(cfg, p, x, rows, pools, index, cache_len, num_new,
                          note)
+    if kind == "retention":
+        from .brumby import retention_mixer
+
+        return retention_mixer(cfg, p, x, rows, pools, index, cache_len,
+                               num_new, note)
     from . import decoding
 
     if kind in ("full", "window"):

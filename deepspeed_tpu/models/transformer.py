@@ -71,6 +71,9 @@ MIXER_KINDS: Dict[str, MixerKind] = {
     # latent attention over every key of the latent pool
     "latent": MixerKind((), ("kv",), "ling",
                         ("kv_latent_dim", "the width of its cached latent")),
+    # power retention (degree 2) with a gate a kv head: a float32 state of
+    # the symmetric square of the keys and its normaliser a slot
+    "retention": MixerKind(("state", "norm"), (), "brumby"),
 }
 
 # the kinds ``layer_pattern`` may name: those of models/decoding.py that
@@ -210,8 +213,10 @@ class TransformerConfig:
     # "kda" (the gated delta rule: a state and the last ``conv_kernel - 1``
     # pre-convolution rows a slot, log-decays bounded by ``kda_lower_bound``)
     # and "latent" (latent attention over every key of the latent pool) of
-    # models/ling.py. Each kind has a parameter stack of its own; the MLP of
-    # a layer (dense lead | routed) is independent of its mixer.
+    # models/ling.py; "retention" (power retention of degree 2: a state a kv
+    # head and its normaliser a slot, the denominator plus ``retention_eps``)
+    # of models/brumby.py. Each kind has a parameter stack of its own; the
+    # MLP of a layer (dense lead | routed) is independent of its mixer.
     # ``mixer_layer_ids`` gives each layer its index in the published model
     # of ``mixer_depth`` layers (a cut keeps both: the decay of a lightning
     # layer and the residual scale follow them). muP: the embedding times ``scale_emb``, a residual
@@ -223,6 +228,7 @@ class TransformerConfig:
     block_sparse: Optional[Any] = None
     conv_kernel: int = 4
     kda_lower_bound: float = -5.0
+    retention_eps: float = 1e-6
     scale_emb: float = 1.0
     scale_depth: float = 1.0
     dim_model_base: int = 0
@@ -1120,9 +1126,10 @@ def _refuse_uncached(cfg: TransformerConfig) -> None:
         raise DeepSpeedConfigError(
             "the uncached forward (training, evaluation, forward) runs no "
             "mixer of mixer_types: a state layer's recurrence (lightning, "
-            "kda) lives in a slot's state, a sparse layer's block selection "
-            "is made from cached compressed keys and a latent layer attends "
-            "the latent pool, all in the paged arena alone; serve this "
+            "kda, retention) lives in a slot's state, a sparse layer's block "
+            "selection is made from cached compressed keys and a latent "
+            "layer attends the latent pool, all in the paged arena alone; "
+            "serve this "
             "configuration through init_serving with serving.paged")
 
 

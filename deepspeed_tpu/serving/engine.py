@@ -232,6 +232,33 @@ def paged_kv_stream(cfg, num_pages: int, page_size: int, max_slots: int,
     return stream
 
 
+def paged_geometry(cfg, serving, max_tokens: int,
+                   max_slots: int) -> Tuple[int, int, int]:
+    """(page_size, pages_per_slot, num_pages) of the paged arena: logical
+    pages a slot cover ``max_tokens`` + the ``token_budget`` write margin.
+    A model NONE of whose layers keeps a page (``paged_layers`` 0: every
+    mixer's cache is leaves a slot) has no pool for a table to index: its
+    page is a slot's whole length, one a slot, so the scheduler's page plan
+    IS its slot plan (admission by slot and ``max_tokens``, nothing to run
+    dry, a table of one column), whatever ``serving.page_size`` and
+    ``serving.num_pages`` ask."""
+    if cfg.mixer_types and not cfg.paged_layers:
+        return int(max_tokens) + int(serving.token_budget), 1, int(max_slots)
+    pages_per_slot = serving.pages_per_slot(max_tokens)
+    num_pages = int(serving.num_pages) or max_slots * pages_per_slot
+    if num_pages < pages_per_slot:
+        from ..config import DeepSpeedConfigError
+
+        # liveness floor: after evicting everything else, ONE request must
+        # still be able to run to max_tokens, or forced eviction can never
+        # make progress
+        raise DeepSpeedConfigError(
+            f"serving.num_pages {num_pages} is below the liveness floor "
+            f"ceil((max_tokens + token_budget) / page_size) = "
+            f"{pages_per_slot}; one request could never finish")
+    return int(serving.page_size), pages_per_slot, num_pages
+
+
 def state_bytes(cfg, max_slots: int, storage_itemsize: int = 2) -> int:
     """Bytes of the arena that are no page: every leaf the model's state
     layers keep a slot (``models/mixers.slot_leaves``: a float32 state a
@@ -649,6 +676,79 @@ class _Flying:
     t0: Optional[float]  # when its dispatch began (the registry's clock)
 
 
+def _state_counts(prefix: str):
+    """The counter of a kind whose cache is a state a slot: ``<prefix>rows``
+    the real rows its recurrence runs, ``<prefix>state_slots`` the live
+    states the step reads and writes, ``state_resets`` those of them that
+    begin at zero (the step's ``rows`` stands for a prefix of none)."""
+    def count(engine, cl, nn) -> Dict[str, int]:
+        busy = nn > 0
+        out = {prefix + "state_slots": int(busy.sum()),
+               "state_resets": int((busy & (cl == 0)).sum())}
+        if prefix:
+            out[prefix + "rows"] = int(nn.sum())
+        return out
+    return count
+
+
+def _sparse_counts(engine, cl, nn) -> Dict[str, int]:
+    """A sparse layer: ``context_keys`` the cached tokens at or before every
+    real query token; ``attended_sparse`` those a query attends, all of them
+    at a position inside ``dense_len`` and ``topk`` blocks' worth past it;
+    ``compressed_keys`` the compressed keys at or before every real query,
+    which its selection scores, and ``compressed_rows`` those at or before a
+    slot's last real query, which it reads once a slot; ``chosen_min`` the
+    fewest K / V rows a slot's queries can have chosen between them (its
+    last query's). Books the attended keys on the metrics."""
+    geom = engine.config.block_sparse
+    busy = nn > 0
+    kept = geom.topk * geom.block_size
+    context = nn * cl + nn * (nn + 1) // 2
+    # rows at a position past dense_len whose context passes the kept
+    # blocks attend the blocks' worth
+    edge = max(geom.dense_len, kept)
+    over = np.clip(cl + nn - edge, 0, nn)
+    attended = context - (over * (cl + nn - kept) - over * (over - 1) // 2)
+    compressed = sum(
+        int(np.maximum((np.arange(c, c + n) + 1 - geom.kernel_size)
+                       // geom.kernel_stride + 1, 0).sum())
+        for c, n in zip(cl[busy], nn[busy]))
+    last = (cl + nn)[busy]
+    counts = {
+        "context_keys": int(context.sum()),
+        "attended_sparse": int(attended.sum()),
+        "compressed_keys": compressed,
+        "compressed_rows": int(np.maximum(
+            (last - geom.kernel_size) // geom.kernel_stride + 1, 0).sum()),
+        "chosen_min": int(np.where(last > edge, kept, last).sum()),
+    }
+    engine.metrics.on_keys("sparse", counts["attended_sparse"],
+                           counts["chosen_min"])
+    return counts
+
+
+def _latent_counts(engine, cl, nn) -> Dict[str, int]:
+    """A latent layer without an indexer: ``latent_rows`` the real query
+    rows, ``latent_keys_walked`` the cached latents at or before each slot's
+    last real row, which the walk reads once a slot, ``context_keys`` those
+    at or before every real query."""
+    return {"latent_rows": int(nn.sum()),
+            "latent_keys_walked": int((cl + nn)[nn > 0].sum()),
+            "context_keys": int((nn * cl + nn * (nn + 1) // 2).sum())}
+
+
+# what a step's plan says of one layer of each mixer kind ``mixer_types`` may
+# name (the kinds of ``models/transformer.MIXER_KINDS`` outside
+# models/decoding.py): the keys ride the ``serve/device_step`` annotation
+_KIND_COUNTS = {
+    "sparse": _sparse_counts,
+    "lightning": _state_counts(""),
+    "kda": _state_counts("kda_"),
+    "latent": _latent_counts,
+    "retention": _state_counts("retention_"),
+}
+
+
 class ServingEngine:
     """Request-level front end over one slot-ragged jitted step.
 
@@ -752,27 +852,11 @@ class ServingEngine:
             self._a2a_cfg = moe_a2a_scope_cfg(self.moe_a2a_form)
         self.paged = bool(serving.paged)
         if self.paged:
-            from ..config import DeepSpeedConfigError
-
-            self.page_size = int(serving.page_size)
-            # logical pages per slot cover max_tokens + the W write margin
-            # (ONE definition of the page math: ServingConfig, fed the
-            # engine-clamped max_tokens)
-            self.pages_per_slot = serving.pages_per_slot(self.max_tokens)
+            # ONE definition of the page math, fed the engine-clamped
+            # max_tokens
+            self.page_size, self.pages_per_slot, self.num_pages = (
+                paged_geometry(mcfg, serving, self.max_tokens, N))
             self.capacity = self.pages_per_slot * self.page_size
-            self.num_pages = (
-                int(serving.num_pages) or N * self.pages_per_slot
-            )
-            if self.num_pages < self.pages_per_slot:
-                # liveness floor: after evicting everything else, ONE
-                # request must still be able to run to max_tokens —
-                # otherwise forced eviction can never make progress
-                raise DeepSpeedConfigError(
-                    f"serving.num_pages {self.num_pages} is below the "
-                    f"liveness floor ceil((max_tokens + token_budget) / "
-                    f"page_size) = {self.pages_per_slot}; one request "
-                    "could never finish"
-                )
             self.null_page = self.num_pages  # physical id of the sink page
         else:
             self.page_size = self.num_pages = self.pages_per_slot = None
@@ -842,12 +926,17 @@ class ServingEngine:
                 k for k in kinds if MIXER_KINDS[k].slot)
             self._paged_kinds = ", ".join(
                 k for k in kinds if MIXER_KINDS[k].page)
+            # what a page of this model holds (nothing, where no kind of
+            # its layers keeps a page)
+            self._page_holds = (
+                f"holds the paged layers' ({self._paged_kinds}) keys alone"
+                if self._paged_kinds else
+                "holds nothing (no layer of this model keeps a page)")
             why = (
                 f"the model has state layers ({self._state_kinds}), whose "
                 "cache is a recurrent state a slot and no page: a page that "
-                "is kept, spilled or handed over holds the paged layers' "
-                f"({self._paged_kinds}) keys alone, and the state that "
-                "summed the same tokens would be missing")
+                f"is kept, spilled or handed over {self._page_holds}, and "
+                "the state that summed the same tokens would be missing")
             if int(getattr(serving, "host_pages", 0) or 0) > 0:
                 raise DeepSpeedConfigError(
                     f"serving.host_pages is refused: {why}")
@@ -1078,15 +1167,16 @@ class ServingEngine:
                 self.expert_path == "touched_kernel")
             if mcfg.mixer_types:
                 # a path a mixer kind: "block_sparse_kernel" / "dense" for
-                # sparse layers, "lightning_kernel", "kda_kernel" and
-                # "latent_kernel" likewise; attention_path is the sparse
+                # sparse layers, "lightning_kernel", "kda_kernel",
+                # "latent_kernel" and "retention_kernel" likewise;
+                # attention_path is the sparse
                 # layers' (the last layer kind's where there is none)
                 self.attention_paths = dict(rec["kinds"])
                 self.attention_path = rec["kinds"].get("sparse", rec["path"])
             self.metrics.attention_paged_kernel = float(
                 self.attention_path in ("paged_kernel", "latent_sparse_kernel",
                                         "block_sparse_kernel", "kda_kernel",
-                                        "latent_kernel")
+                                        "latent_kernel", "retention_kernel")
             )
             self.metrics.attention_paged_kernel_kinds = {
                 kind: float(path == "paged_kernel" or bool(mcfg.mixer_types)
@@ -1613,51 +1703,33 @@ class ServingEngine:
         return counts
 
     def _count_mixers(self, plan: StepPlan) -> Dict[str, int]:
-        """The work of one sparse and one lightning layer of a model with
-        mixers, from the plan (host arithmetic): ``context_keys`` the cached
-        tokens at or before every real query token; ``attended_sparse``
-        those a query attends, all of them at a position inside
-        ``dense_len`` and ``topk`` blocks' worth past it; ``compressed_keys``
-        the compressed keys at or before every real query, which its
-        selection scores, and ``compressed_rows`` those at or before a
-        slot's last real query, which it reads once a slot; ``chosen_min``
-        the fewest K / V rows a slot's queries can have chosen between
-        them (its last query's);
-        ``state_slots`` the live states the step reads and writes,
-        ``state_resets`` those of them that begin at zero. Booked on the
-        metrics."""
-        geom = self.config.block_sparse
-        if geom is None:
-            return self._count_state_and_latent(plan)
+        """The work of one layer of every mixer kind the model names, from
+        the plan (host arithmetic, nothing read back): what each kind
+        counts is ``_KIND_COUNTS``' entry for it, beside the leaves the kind
+        keeps (``MIXER_KINDS``), so a model is the union of its kinds. A
+        routed model adds the device's own count of the step folded last
+        (two calls behind the one it rides on: the sums over a window are of
+        the same steps but its edges): ``experts_touched`` the held experts
+        that got at least one row, over the routed layers, of
+        ``experts_held`` a step. Booked on the metrics."""
+        cfg = self.config
         cl = plan.start_pos.astype(np.int64)
         nn = plan.num_new.astype(np.int64)
-        busy = nn > 0
-        kept = geom.topk * geom.block_size
-        context = nn * cl + nn * (nn + 1) // 2
-        # rows at a position past dense_len whose context passes the kept
-        # blocks attend the blocks' worth
-        edge = max(geom.dense_len, kept)
-        over = np.clip(cl + nn - edge, 0, nn)
-        attended = context - (over * (cl + nn - kept) - over * (over - 1) // 2)
-        compressed = sum(
-            int(np.maximum((np.arange(c, c + n) + 1 - geom.kernel_size)
-                           // geom.kernel_stride + 1, 0).sum())
-            for c, n in zip(cl[busy], nn[busy]))
-        last = (cl + nn)[busy]
-        counts = {
-            "context_keys": int(context.sum()),
-            "attended_sparse": int(attended.sum()),
-            "compressed_keys": compressed,
-            "compressed_rows": int(np.maximum(
-                (last - geom.kernel_size) // geom.kernel_stride + 1, 0).sum()),
-            "chosen_min": int(np.where(last > edge, kept, last).sum()),
-            "state_slots": int(busy.sum()),
-            "state_resets": int((busy & (cl == 0)).sum()),
-        }
-        self.metrics.on_keys("sparse", counts["attended_sparse"],
-                             counts["chosen_min"])
-        self.metrics.context_keys += counts["context_keys"]
-        self.metrics.state_resets += counts["state_resets"]
+        # (a configuration that names no layer is told by what it carries:
+        # the hand counts of tests/benchmark build such)
+        kinds = getattr(cfg, "mixer_types", None) or (
+            ("sparse", "lightning") if cfg.block_sparse is not None
+            else ("kda", "latent"))
+        counts: Dict[str, int] = {}
+        for kind in dict.fromkeys(kinds):
+            counts.update(_KIND_COUNTS[kind](self, cl, nn))
+        touched = getattr(self, "_experts_touched", None)
+        if touched is not None:
+            counts["experts_touched"] = touched
+            counts["experts_held"] = cfg.num_experts * cfg.num_layers
+        if "context_keys" in counts:
+            self.metrics.context_keys += counts["context_keys"]
+        self.metrics.state_resets += counts.get("state_resets", 0)
         return counts
 
     def describe(self) -> Dict[str, Any]:
@@ -1696,39 +1768,6 @@ class ServingEngine:
                 name: int(np.prod(leaf.shape)) * leaf.dtype.itemsize
                 for name, leaf in leaves.items()},
         }
-
-    def _count_state_and_latent(self, plan: StepPlan) -> Dict[str, int]:
-        """The work of one kda and one latent layer of a model with those
-        mixers, from the plan (host arithmetic): ``kda_rows`` the real rows
-        the delta rule runs and ``kda_state_slots`` the live states it reads
-        and writes (``state_resets`` those that begin at zero);
-        ``latent_rows`` the real query rows and ``latent_keys_walked`` the
-        cached latents at or before each slot's last real row, which the
-        walk reads once a slot; ``context_keys`` those at or before every
-        real query. A routed model adds the device's own count of the step
-        folded last (two calls behind the one it rides on: the sums over a
-        window are of the same steps but its edges): ``experts_touched`` the
-        held experts that got at least one row, over the routed layers, of
-        ``experts_held`` a step. Booked on the metrics."""
-        cl = plan.start_pos.astype(np.int64)
-        nn = plan.num_new.astype(np.int64)
-        busy = nn > 0
-        rows = int(nn.sum())
-        counts = {
-            "kda_rows": rows,
-            "kda_state_slots": int(busy.sum()),
-            "state_resets": int((busy & (cl == 0)).sum()),
-            "latent_rows": rows,
-            "latent_keys_walked": int((cl + nn)[busy].sum()),
-            "context_keys": int((nn * cl + nn * (nn + 1) // 2).sum()),
-        }
-        if self._experts_touched is not None:
-            counts["experts_touched"] = self._experts_touched
-            counts["experts_held"] = (
-                self.config.num_experts * self.config.num_layers)
-        self.metrics.context_keys += counts["context_keys"]
-        self.metrics.state_resets += counts["state_resets"]
-        return counts
 
     def _stage_args(self, plan: StepPlan) -> tuple:
         """Decode this step's promotions into the rotating staging buffer
@@ -1769,8 +1808,7 @@ class ServingEngine:
     def _refuse_page_moves(self, what: str) -> None:
         if self.slot_state:
             raise RuntimeError(
-                f"{what}: a page holds the paged layers' "
-                f"({self._paged_kinds}) keys alone; the state layers' "
+                f"{what}: a page {self._page_holds}; the state layers' "
                 f"({self._state_kinds}) state that summed the same tokens is a "
                 "slot's and no page, so a hand-off would serve a model with "
                 "state layers a context its state never saw"
@@ -2050,17 +2088,8 @@ def trace_serving_step(model, ds_config, topology: Optional[MeshTopology]
         )
     paged = bool(srv.paged)
     if paged:
-        from ..config import DeepSpeedConfigError
-
-        page_size = int(srv.page_size)
-        pages_per_slot = srv.pages_per_slot(max_tokens)
-        num_pages = int(srv.num_pages) or N * pages_per_slot
-        if num_pages < pages_per_slot:
-            raise DeepSpeedConfigError(
-                f"serving.num_pages {num_pages} is below the liveness "
-                f"floor {pages_per_slot} for this model's clamped "
-                "max_tokens; one request could never finish"
-            )
+        page_size, pages_per_slot, num_pages = paged_geometry(
+            mcfg, srv, max_tokens, N)
         cache_shape = init_paged_cache(
             mcfg, num_pages, page_size, storage, quantized=quantized,
             max_slots=N,

@@ -708,6 +708,51 @@ def test_ling_slot_step_keeps_pools_and_both_state_leaves_in_place(
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * GIB
 
 
+def test_brumby_slot_step_keeps_both_state_leaves_in_place(
+        one_chip, monkeypatch, capsys):
+    """The one [16, 128] serving step of Brumby-14B-Base at its published
+    widths and the benchmark's cut (published layers 0-7, the vocabulary
+    whole) over its PAGELESS arena: the two slot leaves alone, 4.36 GB of
+    expanded float32 state and its normaliser, no pool a page table indexes
+    (the table is one column wide). They ride the run's scan as its carry
+    and are aliased into the ``power_retention`` call, so the compiled step
+    holds no copy, slice or write-back the size of a leaf or of a layer of
+    one, and it fits the chip beside 8.4 GB of weights."""
+    from deepspeed_tpu.models import brumby
+    from deepspeed_tpu.models.decoding import init_paged_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = brumby("brumby-14b", layer_ids=list(range(8)))
+    cfg = model.config
+    assert cfg.paged_layers == 0 and cfg.kind_count("retention") == 8
+    N, W = 16, 128
+    caches = jax.eval_shape(
+        lambda: init_paged_cache(cfg, N, 18432 + W, BF16, max_slots=N))
+    # 65 packed rows of 128 lanes: the 8,256 products of a key's symmetric
+    # square and 64 lanes of zeros
+    assert {k: (v.shape, v.dtype) for k, v in caches.items()} == {
+        "state": ((8, N, 8, 65, 128, 128), F32),
+        "norm": ((8, N, 8, 65, 1, 128), F32)}
+    compiled = _compile_slot_step(model, caches, one_chip, N, W, 1)
+    m = compiled.memory_analysis()
+    pools = sum(a.size * a.dtype.itemsize for a in caches.values())
+    with capsys.disabled():
+        print(f"\nbrumby slot step, described v5e: {model.num_params():,} "
+              f"parameters, arguments {m.argument_size_in_bytes / GIB:.2f} "
+              f"GiB, temporaries {m.temp_size_in_bytes / GIB:.2f} GiB, "
+              f"aliased {m.alias_size_in_bytes / GIB:.2f} (the two leaves "
+              f"{pools / GIB:.2f})")
+    text = compiled.as_text()
+    assert "power_retention" in text and "tpu_custom_call" in text
+    assert _pool_copies(text, caches) == []
+    assert m.alias_size_in_bytes >= pools
+    _check_weights_are_read_as_held(compiled, model, "brumby", capsys)
+    # the head runs over the 16 sampling rows, not the chunk's 2,048
+    assert [dims for dims in set(re.findall(r"\bf32\[([\d,]+)\]", text))
+            if np.prod([int(d) for d in dims.split(",")])
+            == N * W * cfg.vocab_size] == []
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * GIB
+
+
 def test_glm_train_step_fits_the_described_chip(topo, monkeypatch, capsys):
     """The train step of the cell ``glm47flash-pretrain-4k`` (its
     configuration file's model and ds_config, micro-batch 2 x 4,096,
