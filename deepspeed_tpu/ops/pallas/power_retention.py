@@ -29,21 +29,31 @@ Over a chunk of ``n`` real rows from ``(S, z)``, ``c_i`` the running sum of
 
 Every exponent is a difference that is at most 0, taken of the difference.
 
-One program a (slot, kv head, tile of ``D``): a kv head's state is 4.3 MB,
-so it goes through VMEM ``tile_rows`` packed rows at a time, and ``phi`` of
-the rows is formed a packed row at a time in VMEM and never written out (a
-loop over the tile's rows whose index is a scalar: two lane rotations by
-it, so the program is one packed row long, not sixty-five). The leaves keep
-the packed rows as an axis: the state stack ``[L, slots, KV, R, hd, hd]``
-(packed row, value channel, lane of the packed row) and the normaliser ``[L,
-slots, KV, R, 1, hd]``, ``R = hd / 2 + 1``, float32, read and written in
-place at the layer's index, a scalar in SMEM beside the frontiers. The query
-heads of a group are stacked along the rows, so they read the state ONCE.
-Three ways through a program: a slot with no real row gets its leaves back
-bit for bit; ONE real row (decode) runs the recurrence itself on the vector
-units, the state never going through a matrix product; more rows take the
-chunk form. A slot that begins at position 0 starts from zeros; padded rows
-add nothing.
+One program a (slot, kv head, tile of ``D``): a kv head's state is 4.3 MB
+at 128 and goes through VMEM WHOLE, two buffers each way, where that fits
+``STATE_VMEM_BYTES`` (:func:`tile_rows`: else the most packed rows that divide
+``R`` and fit), and ``phi`` of the rows is formed a packed row at a time in
+VMEM and never written out (loops over the tile's rows whose index is a
+scalar: two lane rotations by it, so the program is a few packed rows long,
+not sixty-five). The leaves keep the packed rows as an axis: the state stack
+``[L, slots, KV, R, hd, hd]`` (packed row, value channel, lane of the packed
+row) and the normaliser ``[L, slots, KV, R, 1, hd]``, ``R = hd / 2 + 1``,
+float32, read and written in place at the layer's index, a scalar in SMEM
+beside the frontiers. The query heads of a group are stacked along the rows,
+so they read the state ONCE. Three ways through a program: a slot with no
+real row gets its leaves back bit for bit; ONE real row (decode) runs the
+recurrence itself on the vector units, the state never going through a
+matrix product; more rows take the chunk form. A slot that begins at
+position 0 starts from zeros; padded rows add nothing.
+
+The call is as fast as the chip copies: a stream read and written back
+through VMEM runs at 658 GB/s on a v5e whatever the tile and however many
+copies are in flight (PERF.md, PR 49), and the decode path keeps its vector
+work under that. Its one long chain, a packed row of ``phi`` (a rotation, a
+lane broadcast, a product, a select, each waiting for the last), is formed
+``PHI_BLOCK`` packed rows side by side before the state is touched; the
+state is then walked ``WALK_CHANNELS`` value channels at a time over all the
+tile's packed rows, the heads' read-outs carried in registers.
 
 :func:`dense_power_retention` is the same chunk in plain ``jax.numpy``: the
 path of an engine without kernel injection and the kernel's oracle.
@@ -52,6 +62,7 @@ path of an engine without kernel injection and the kernel's oracle.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -61,10 +72,16 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .expert_bank import VMEM_CAP
+
 F32 = jnp.float32
 _HI = lax.Precision.HIGHEST
 FIRST_ROWS = 8        # rows of the one-row operand: a group's q heads, k, v
-TILE_BYTES = 1 << 20  # the most of a kv head's state a program holds
+SUBLANES = 8          # rows of a float32 register
+PHI_BLOCK = 8         # packed rows of the one row's phi formed side by side
+WALK_CHANNELS = 32    # value channels whose read-outs a walk keeps in registers
+# the most of its siblings' VMEM cap a tile's buffers take, two in and two out
+STATE_VMEM_BYTES = VMEM_CAP // 3
 
 
 def expanded_dim(hd: int) -> int:
@@ -96,11 +113,25 @@ def phi(a, key: bool = False):
 
 
 def tile_rows(hd: int) -> int:
-    """Packed rows a program takes: the most that divide ``hd / 2 + 1`` with
-    the tile under ``TILE_BYTES``."""
+    """Packed rows a program takes: the whole ``hd / 2 + 1`` where a tile's
+    four buffers fit ``STATE_VMEM_BYTES``, else the most that divide it and
+    fit."""
     rows = hd // 2 + 1
-    return max(p for p in range(1, rows + 1)
-               if rows % p == 0 and (p == 1 or p * hd * hd * 4 <= TILE_BYTES))
+    return max(p for p in range(1, rows + 1) if rows % p == 0 and (
+        p == 1 or 4 * p * hd * hd * 4 <= STATE_VMEM_BYTES))
+
+
+def vmem_limit(per_tile: int, hd: int, group: int, S: int, itemsize: int):
+    """The VMEM the call asks for: twice what its buffers take (two of each
+    operand's block, the scratches, the chunk form's temporaries the size of
+    the query block), at least the 32 MiB its siblings start from and at most
+    ``VMEM_CAP``."""
+    state = 4 * per_tile * hd * hd * 4
+    norm = 4 * per_tile * SUBLANES * hd * 4  # a [1, hd] row pads to a register
+    rows = 2 * (2 * group * S + 2 * S + FIRST_ROWS) * hd * itemsize
+    scratch = (4 * group * S + group * hd + FIRST_ROWS
+               + per_tile * (group + 1) * SUBLANES) * hd * 4
+    return min(VMEM_CAP, max(32 << 20, 2 * (state + norm + rows + scratch)))
 
 
 def _phi_row(a, r, roll):
@@ -128,7 +159,8 @@ def _dot(a, b, dims, precision=None):
 def _retention_kernel(cl_ref, nn_ref, layer_ref, q_ref, k_ref, v_ref,
                       ccol_ref, crow_ref, first_ref, vcol_ref, s_ref, z_ref,
                       o_ref, s_out, z_out, num_ref, den_ref, acc_ref,
-                      dacc_ref, *, scale, eps, group, per_tile, tiles, roll):
+                      dacc_ref, phi_ref, *, scale, eps, group, per_tile,
+                      tiles, roll):
     b, t = pl.program_id(0), pl.program_id(2)
     cl, nn = cl_ref[b], nn_ref[b]
     S, hd = k_ref.shape[2], k_ref.shape[3]
@@ -145,7 +177,11 @@ def _retention_kernel(cl_ref, nn_ref, layer_ref, q_ref, k_ref, v_ref,
 
     @pl.when(nn == 0)
     def _idle():
-        s_out[0, 0, 0] = s_ref[0, 0, 0]
+        def packed_row(rr, carry):
+            s_out[0, 0, 0, rr] = s_ref[0, 0, 0, rr]
+            return carry
+
+        lax.fori_loop(0, per_tile, packed_row, 0)
         z_out[0, 0, 0] = z_ref[0, 0, 0]
         o_ref[0, 0] = jnp.zeros((G * S, hd), o_ref.dtype)
 
@@ -155,7 +191,6 @@ def _retention_kernel(cl_ref, nn_ref, layer_ref, q_ref, k_ref, v_ref,
         # of the one real token in one tile of FIRST_ROWS rows; v as a COLUMN
         # too (the value channel indexes the state's rows)
         rows = first_ref[0, 0]
-        vcol = vcol_ref[0, 0] * scale2
         # c_0 = g_0, along the lanes first: a [1, 1] does not broadcast both
         # ways at once
         decay = jnp.exp(jnp.broadcast_to(ccol_ref[0, 0, 0:1], (1, hd)))
@@ -165,21 +200,69 @@ def _retention_kernel(cl_ref, nn_ref, layer_ref, q_ref, k_ref, v_ref,
             acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
             dacc_ref[...] = jnp.zeros(dacc_ref.shape, F32)
 
-        def packed_row(rr, dacc):
+        def phi_of(rr, dacc):
+            # packed row ``rr`` of the tile: every head's phi and the key's,
+            # each broadcast down a register for the walks below, and the
+            # normaliser's row, which is one register, while it is here
             r = base + rr
             ph = _phi_row(rows, r, roll)                      # [8, hd]
             pk = ph[G:G + 1] * _key_weight(hd, r)             # [1, hd]
-            s0, z0 = s_at(rr), z_at(rr)
-            s_out[0, 0, 0, rr] = decay * s0 + vcol * pk
+            z0 = z_at(rr)
             z_out[0, 0, 0, rr] = decay * z0 + pk * scale2
-            # what the state BEFORE the row gives each head (the row's own
-            # share is taken below from q . k itself, as the chunk form
-            # takes it)
             for h in range(G):
-                acc_ref[h * hd:(h + 1) * hd] += s0 * ph[h:h + 1]
+                phi_ref[rr, h] = jnp.broadcast_to(ph[h:h + 1], (SUBLANES, hd))
+            phi_ref[rr, G] = jnp.broadcast_to(pk, (SUBLANES, hd))
             return dacc + ph * z0
 
-        dacc_ref[...] = lax.fori_loop(0, per_tile, packed_row, dacc_ref[...])
+        def phi_block(i, dacc):
+            # PHI_BLOCK rows a trip: a row's chain is some 180 cycles long
+            # and a trip of one row has nothing to put between its links
+            # (traced once; laid out side by side where the kernel is lowered)
+            return lax.fori_loop(
+                0, PHI_BLOCK, lambda u, d: phi_of(i * PHI_BLOCK + u, d), dacc,
+                unroll=True)
+
+        dacc = lax.fori_loop(0, per_tile // PHI_BLOCK, phi_block,
+                             dacc_ref[...])
+        for rr in range(per_tile - per_tile % PHI_BLOCK, per_tile):
+            dacc = phi_of(rr, dacc)
+        dacc_ref[...] = dacc
+
+        # the state, ``walk`` value channels at a time over the tile's packed
+        # rows: a register of it is read once, gives each head its products
+        # (what the state BEFORE the row gives: the row's own share is taken
+        # below from q . k itself, as the chunk form takes it) and is written
+        # once
+        walk = math.gcd(WALK_CHANNELS, hd)
+        blocks = walk // SUBLANES
+        decay8 = jnp.broadcast_to(decay, (SUBLANES, hd))
+
+        def walk_from(w, carry):
+            at = [pl.ds(pl.multiple_of(w * walk + SUBLANES * i, SUBLANES),
+                        SUBLANES) for i in range(blocks)]
+            vlanes = [jnp.broadcast_to(vcol_ref[0, 0, a] * scale2,
+                                       (SUBLANES, hd)) for a in at]
+
+            def packed_row(rr, accs):
+                ph = [phi_ref[rr, h] for h in range(G + 1)]
+                out = []
+                for i, (a, vl) in enumerate(zip(at, vlanes)):
+                    s0 = jnp.where(fresh, 0.0, s_ref[0, 0, 0, rr, a, :])
+                    s_out[0, 0, 0, rr, a, :] = decay8 * s0 + vl * ph[G]
+                    out += [accs[i * G + h] + s0 * ph[h] for h in range(G)]
+                return tuple(out)
+
+            accs = lax.fori_loop(0, per_tile, packed_row, tuple(
+                jnp.zeros((SUBLANES, hd), F32) for _ in range(G * blocks)))
+            for i in range(blocks):
+                for h in range(G):
+                    acc_ref[pl.ds(pl.multiple_of(
+                        h * hd + w * walk + SUBLANES * i, SUBLANES),
+                        SUBLANES)] += accs[i * G + h]
+            return carry
+
+        # traced once, a walk after the other where the kernel is lowered
+        lax.fori_loop(0, hd // walk, walk_from, 0, unroll=True)
 
         @pl.when(t == tiles - 1)
         def _():
@@ -253,8 +336,9 @@ def kernel_reasons(q, k, interpret: bool) -> Tuple[str, ...]:
     if H % KV or H // KV + 2 > FIRST_ROWS:
         why.append(f"{H} query heads over {KV} kv heads: a group, its key and "
                    f"its value fill the one-row tile of {FIRST_ROWS} rows")
-    if hd % 2:
-        why.append(f"head_dim {hd} is odd")
+    if hd % SUBLANES:
+        why.append(f"head_dim {hd} is no multiple of {SUBLANES}: the state "
+                   "is walked a register of value channels at a time")
     if not interpret and (hd % 128 or S % 16):
         why.append(f"head_dim {hd} is no lane multiple or the chunk of {S} "
                    "rows no sublane multiple")
@@ -317,6 +401,7 @@ def power_retention(q, k, v, g, state, norm, cache_len, num_new, *, layer,
         scratch_shapes=[
             pltpu.VMEM((G * S, hd), F32), pltpu.VMEM((G * S, 1), F32),
             pltpu.VMEM((G * hd, hd), F32), pltpu.VMEM((FIRST_ROWS, hd), F32),
+            pltpu.VMEM((per_tile, G + 1, SUBLANES, hd), F32),
         ],
     )
     roll = (lambda a, r: jnp.roll(a, r, axis=1)) if interpret else (
@@ -333,7 +418,9 @@ def power_retention(q, k, v, g, state, norm, cache_len, num_new, *, layer,
         # the 11th and the 12th
         input_output_aliases={10: 1, 11: 2},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem_limit(per_tile, hd, G, S,
+                                        q.dtype.itemsize)),
         interpret=interpret, name="power_retention",
     )(jnp.asarray(cache_len, jnp.int32), nn,
       jnp.asarray(layer, jnp.int32).reshape(1),

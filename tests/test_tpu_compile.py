@@ -743,6 +743,25 @@ def test_brumby_slot_step_keeps_both_state_leaves_in_place(
               f"{pools / GIB:.2f})")
     text = compiled.as_text()
     assert "power_retention" in text and "tpu_custom_call" in text
+    # one program a (slot, kv head): a kv head's 65 packed rows go through
+    # VMEM whole, two buffers each way, under a limit the call raises itself
+    # and keeps under its sibling kernels' cap
+    from deepspeed_tpu.ops.pallas import power_retention as pr
+    tile = pr.tile_rows(cfg.hd)
+    asked = pr.vmem_limit(tile, cfg.hd, cfg.num_heads // cfg.kv_heads, W, 2)
+    call = next(line for line in text.split("\n")
+                if "power_retention" in line and "tpu_custom_call" in line)
+    scoped = [int(b) for b in re.findall(
+        r'"scoped_memory_configs":\[\{"memory_space":"1",[^}]*"size":"(\d+)"',
+        call)]
+    with capsys.disabled():
+        print(f"power_retention: grid {(N, cfg.kv_heads, 65 // tile)}, "
+              f"{tile} packed rows a program "
+              f"({4 * tile * cfg.hd ** 2 * 4 / 2 ** 20:.1f} MiB of state in "
+              f"four buffers), VMEM limit asked {asked / 2 ** 20:.1f} MiB, "
+              f"in the compiled call {scoped}")
+    assert tile == 65 and scoped == [asked]
+    assert 4 * tile * cfg.hd ** 2 * 4 < asked < 96 * 2 ** 20
     assert _pool_copies(text, caches) == []
     assert m.alias_size_in_bytes >= pools
     _check_weights_are_read_as_held(compiled, model, "brumby", capsys)
