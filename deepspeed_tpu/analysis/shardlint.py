@@ -186,6 +186,24 @@ def trace_train_step(engine):
     return closed, arg_shardings, master_pairs, out_shape, meta
 
 
+def pallas_grids(jaxpr):
+    """``[(kernel function, grid)]`` of every ``pallas_call`` under a jaxpr,
+    in program order, the bodies of scans, remats and custom derivatives
+    included: what a kernel's grid IS in the program that was traced (the
+    flash kernels' walk of a causal call's live tiles, say)."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append((eqn.params["jaxpr"].debug_info.func_name,
+                        tuple(eqn.params["grid_mapping"].grid)))
+        for v in eqn.params.values():
+            for x in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(x, "jaxpr", x)  # closed or open
+                if hasattr(sub, "eqns"):
+                    out += pallas_grids(sub)
+    return out
+
+
 def lower_train_step(engine):
     """The engine's jitted train step, lowered for its own state and batch
     shapes (abstract: nothing materializes, nothing is donated). Works on

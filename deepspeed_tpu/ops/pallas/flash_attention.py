@@ -18,10 +18,17 @@ In-kernel masking (r3):
   1.5e-5 at 512 for a slope that is no power of two), not of its own small
   product: see :func:`_mask_and_bias`.
 - **Causal**: positions are a [bq,1] column and a [1,bk] row compared by
-  broadcast on every visible tile; tiles wholly above the diagonal are
-  skipped (:func:`_block_visible`). One body a kernel: a bare body for the
+  broadcast on every visible tile; tiles wholly above the diagonal
+  (:func:`_block_visible`) are no grid step at all: the grid is
+  (B, H, live tiles), one flat list of the (q-block, k-block) pairs a call
+  can see (:func:`flat_walk`). One body a kernel: a bare body for the
   tiles wholly below the diagonal was built and measured (PR 33) and earned
   under 1 ms of a 437 ms step, so it is not here.
+- **Block-sparse layouts** (``block_mask``, ops/sparse_attention.py) ride the
+  same walk: the causal triangle is a static layout like any other. What
+  has no static layout or needs every tile keeps the dense grid
+  (B, H, nq, nk) with the in-kernel predicate: ring hops with traced offsets
+  (ring_flash.py), a dense ``bias`` (its dbias paths), non-causal calls.
 - **sp composition**: under a DS-Ulysses mesh the kernel shard_maps heads
   over ("tp","sp") — the all-to-alls happen outside (parallel/sequence.py),
   the kernel itself always sees full sequence.
@@ -40,10 +47,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-# Measured on one v5e chip, the kernels alone (PR 33's chip runs, PERF.md
+# Measured on one v5e chip, the kernels alone (PR 51's chip runs, PERF.md
 # section 6): [4, 16, 2048, D] bf16, causal, ALiBi, 512x512 tiles, device ms
-# a call at D 64 | 128: fwd 0.90 | 0.96, dq 1.21 | 1.25, dk/dv 1.50 | 1.50.
-# 1024-wide tiles gain 0-8 % and Mosaic compiles them three times as long;
+# a call at D 64 | 128: fwd 0.79 | 0.82, dq 0.99 | 1.00, dk/dv 1.27 | 1.25
+# (0.90 | 0.96, 1.21 | 1.25, 1.50 | 1.50 while a (batch, head) walked 16
+# grid steps for its 10 visible tiles: PR 33). On those 16-step grids
+# 1024-wide tiles gained 0-8 % and Mosaic compiled them three times as long;
 # 256-wide tiles lose a fifth to a half. So 512x512 stays, for every kernel.
 # The time does not follow D: a 64-deep contraction occupies the 128 x 128
 # MXU as long as a 128-deep one. What a tile costs beside its matmuls is
@@ -76,29 +85,77 @@ def _block_visible(qi, ki, block_q, block_k, qoff=0, koff=0):
     return qi * block_q + block_q - 1 + qoff >= ki * block_k + koff
 
 
-def _compact_rows(layout):
-    """[n, m] 0/1 layout → (idx [n, jmax] int32, counts [n] int32).
+def causal_layout(S, block_q, block_k):
+    """The lower block triangle a causal call can see, [nq, nk] bool: the
+    in-kernel predicate over the block grid (one source of truth)."""
+    import numpy as np
 
-    Row r's active column indices, ascending, in idx[r, :counts[r]]; padding
-    REPEATS the last active index so consecutive grid steps see the same
-    block index and Mosaic's pipeline skips the re-fetch — a padded step
-    costs neither DMA nor (predicated-off) compute. This is the block-sparse
-    DMA-skip table: the kernel grid iterates j over jmax instead of every
-    k-block, so masked tiles are never fetched at all (the reference's
-    triton sdd/dsd kernels get this from their explicit lut; VERDICT r3
-    missing #5)."""
+    qi = np.arange(S // block_q)[:, None]
+    ki = np.arange(S // block_k)[None, :]
+    return _block_visible(qi, ki, block_q, block_k)
+
+
+# One grid step of a static layout's walk, packed in an int32:
+# qi << 17 | ki << 3 | live << 2 | last << 1 | first.
+_WALK_FIRST, _WALK_LAST, _WALK_LIVE = 1, 2, 4
+_WALK_KI_SHIFT, _WALK_QI_SHIFT = 3, 17
+_WALK_MAX_BLOCKS = 1 << (_WALK_QI_SHIFT - _WALK_KI_SHIFT)
+# The list rides scalar prefetch, so it has to fit the scalar memory beside
+# whatever else a kernel keeps there: the chip's compiler (a forward for the
+# described v5e, as tests/test_tpu_compile.py compiles) took a list of
+# 128 Ki entries and refused 256 Ki ("Used 1.00M of 1.00M smem"), so the
+# bound is an eighth of that memory. The causal triangle at 512-wide tiles
+# reaches it past S 128 Ki; a block-sparse layout at 128-wide blocks at
+# S 64 Ki if an eighth of its tiles are live.
+WALK_MAX_STEPS = 32 * 1024
+
+
+def flat_walk(layout, *, by_col=False):
+    """[nq, nk] 0/1 layout -> int32 [steps]: the live tiles in the order a
+    kernel visits them, one packed word a grid step.
+
+    Forward and dq walk row-major (a q-block's k-blocks ascending); dk/dv
+    walks ``by_col`` (a k-block's q-blocks ascending). ``first`` / ``last``
+    mark the ends of a run over one output block: the accumulators are
+    zeroed at the first and written out at the last. An output block with no
+    live tile keeps one entry, first and last and not live, so it is still
+    written (zeros; lse NEG_INF). The causal triangle has no such block and
+    its kernels carry no run predicate at all. This is the block-sparse
+    lookup table of the reference's triton sdd/dsd kernels: a masked tile is
+    neither fetched nor a grid step."""
+    import numpy as np
+
+    live = np.asarray(layout) != 0
+    if max(live.shape) > _WALK_MAX_BLOCKS:
+        raise ValueError(f"layout {live.shape} has more than "
+                         f"{_WALK_MAX_BLOCKS} blocks a side")
+    if by_col:
+        live = live.T
+    placed = live.copy()
+    placed[~live.any(axis=1), 0] = True  # the empty runs' one dead entry
+    run, other = np.nonzero(placed)  # row-major: runs in order, ascending
+    edge = run[1:] != run[:-1]
+    first, last = np.r_[True, edge], np.r_[edge, True]
+    qi, ki = (other, run) if by_col else (run, other)
+    return (qi << _WALK_QI_SHIFT | ki << _WALK_KI_SHIFT
+            | live[run, other] * _WALK_LIVE | last * _WALK_LAST
+            | first * _WALK_FIRST).astype(np.int32)
+
+
+def _walk_bound(layout):
+    """No walk of the layout, in either order, has more grid steps."""
     import numpy as np
 
     layout = np.asarray(layout)
-    counts = (layout != 0).sum(axis=1).astype(np.int32)
-    jmax = max(int(counts.max(initial=0)), 1)
-    idx = np.zeros((layout.shape[0], jmax), np.int32)
-    for r in range(layout.shape[0]):
-        cols = np.nonzero(layout[r])[0]
-        if len(cols):
-            idx[r, : len(cols)] = cols
-            idx[r, len(cols):] = cols[-1]
-    return idx, counts
+    return int(np.count_nonzero(layout)) + max(layout.shape)
+
+
+def walk_steps(layout, *, by_col=False):
+    """(grid steps, live tiles) a (batch, head) of a static layout's walk:
+    equal unless an output block has no live tile. What the grids of the
+    lowered program are checked against (tests/test_tpu_compile.py)."""
+    word = flat_walk(layout, by_col=by_col)
+    return len(word), int(((word & _WALK_LIVE) != 0).sum())
 
 
 def _mask_and_bias(s, rel, block_q, block_k, *, causal, seg_q, seg_k, slope,
@@ -149,8 +206,13 @@ def _lanes_to(x, width):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
 
 
-def _parse_refs(refs, *, has_seg, has_alibi, has_bias=False, has_offsets=False):
-    """Split a kernel's (in_refs..., out_refs..., scratch...) positional refs."""
+def _parse_refs(refs, *, has_seg, has_alibi, has_bias=False, has_offsets=False,
+                flat=False):
+    """Split a kernel's ([walk_ref], in_refs..., out_refs..., scratch...)
+    positional refs; the scalar-prefetch walk leads on the flat grid."""
+    walk_ref = None
+    if flat:
+        walk_ref, refs = refs[0], refs[1:]
     q_ref, k_ref, v_ref = refs[0], refs[1], refs[2]
     i = 3
     seg_q_ref = seg_k_ref = slopes_ref = bias_ref = offsets_ref = None
@@ -167,35 +229,64 @@ def _parse_refs(refs, *, has_seg, has_alibi, has_bias=False, has_offsets=False):
         offsets_ref = refs[i]  # SMEM (1,2): [qoff, koff]
         i += 1
     extra = refs[i:]
-    return (q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slopes_ref,
+    return (walk_ref, q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slopes_ref,
             bias_ref, offsets_ref, extra)
 
 
-def _tile_step(tables, row, step, *, causal, block_q, block_k, swap, qoff=0,
-               koff=0):
-    """Decode one grid step: (other-axis block index, run predicate, rel).
+def _walk_blocks(word):
+    """(qi, ki) of a packed walk word (see :func:`flat_walk`)."""
+    return (word >> _WALK_QI_SHIFT,
+            (word >> _WALK_KI_SHIFT) & (_WALK_MAX_BLOCKS - 1))
 
-    row is the dense grid axis (qi for fwd/dq, ki for dkv: ``swap``). On
-    the dense grid ``tables`` is None and step IS the other block index; on
-    the compacted grid step indexes the (cols, counts) table: padded steps
-    repeat the previous index (no DMA) and predicate off via the count.
-    Causal visibility comes from the block indices and the ring-hop offsets
-    (dynamic when those are traced; sparse never combines with offsets —
-    enforced at entry); ``rel`` is the tile's first query position minus its
-    first key position, what :func:`_mask_and_bias` places its column and
-    row by."""
-    if tables is None:
-        other, ok = step, True
+
+def _tile_step(walk_ref, dead, *, causal, block_q, block_k, swap, qoff=0,
+               koff=0):
+    """Decode this grid step: (first, last, run predicate, rel).
+
+    On the flat grid (B, H, steps) ``walk_ref[step]`` names the tile and
+    says whether it opens and closes its output block's run; every step is a
+    tile the call can see, so the predicate is the Python ``True`` unless
+    the layout has ``dead`` entries (an output block with no live tile). On
+    the dense grid (B, H, row, other) the run is the last axis (``swap``:
+    the row is the k-block, dk/dv) and causal visibility is tested a step,
+    from the block indices and the ring-hop offsets (dynamic when those are
+    traced; a walk never combines with offsets — enforced at entry).
+    ``rel`` is the tile's first query position minus its first key position,
+    what :func:`_mask_and_bias` places its column and row by."""
+    if walk_ref is not None:
+        word = walk_ref[pl.program_id(2)]
+        qi, ki = _walk_blocks(word)
+        first = (word & _WALK_FIRST) != 0
+        last = (word & _WALK_LAST) != 0
+        ok = (word & _WALK_LIVE) != 0 if dead else True
     else:
-        cols_ref, counts_ref = tables
-        other = cols_ref[row, step]
-        ok = step < counts_ref[row]
-    qi, ki = (other, row) if swap else (row, other)
-    if causal:
-        ok = jnp.logical_and(
-            ok, _block_visible(qi, ki, block_q, block_k, qoff, koff))
+        row, step = pl.program_id(2), pl.program_id(3)
+        qi, ki = (step, row) if swap else (row, step)
+        first, last = step == 0, step == pl.num_programs(3) - 1
+        ok = (_block_visible(qi, ki, block_q, block_k, qoff, koff)
+              if causal else True)
     rel = qi * block_q + qoff - (ki * block_k + koff)
-    return other, ok, rel
+    return first, last, ok, rel
+
+
+def _when(pred, body):
+    """Run ``body`` under ``pred``; a static ``True`` is no test at all."""
+    if pred is True:
+        body()
+    else:
+        pl.when(pred)(body)
+
+
+def _tile_index_maps(flat, swap=False):
+    """(qi_of, ki_of): a grid step's q- and k-block index from what an index
+    map receives after (b, h) — (step, walk_ref) on the flat grid, the two
+    block axes on the dense one (``swap``: k-block first, dk/dv)."""
+    if flat:
+        return (lambda t, walk: _walk_blocks(walk[t])[0],
+                lambda t, walk: _walk_blocks(walk[t])[1])
+    if swap:
+        return (lambda x, y: y), (lambda x, y: x)
+    return (lambda x, y: x), (lambda x, y: y)
 
 
 def _offs(offsets_ref):
@@ -227,35 +318,29 @@ def _tile_mask_args(seg_q_ref, seg_k_ref, bias_ref=None):
 # forward
 # -----------------------------------------------------------------------------
 def _fwd_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
-                sparse=False, has_bias=False, has_offsets=False):
-    if sparse:
-        kcols_ref, kcounts_ref, refs = refs[0], refs[1], refs[2:]
-    (q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slopes_ref,
+                flat=False, dead=False, has_bias=False, has_offsets=False):
+    (walk_ref, q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slopes_ref,
      bias_ref, offsets_ref, extra) = (
         _parse_refs(refs, has_seg=has_seg, has_alibi=has_alibi,
-                    has_bias=has_bias, has_offsets=has_offsets)
+                    has_bias=has_bias, has_offsets=has_offsets, flat=flat)
     )
     o_ref, lse_ref, m_scr, l_scr, acc_scr = extra
     qoff, koff = _offs(offsets_ref)
     slope = _head_slope(slopes_ref, pl.program_id(1))
-    qi, step = pl.program_id(2), pl.program_id(3)
-    nstep = pl.num_programs(3)
-    # compacted grid: step walks this q-row's active k-blocks only; dense
-    # grid: causal skips blocks fully above the diagonal (dynamic when the
-    # blocks carry ring-hop position offsets)
-    ki, should_run, rel = _tile_step(
-        (kcols_ref, kcounts_ref) if sparse else None, qi, step,
-        causal=causal, block_q=block_q, block_k=block_k, swap=False,
-        qoff=qoff, koff=koff,
+    # flat grid: a step is one tile this q-row sees, its k-blocks in order;
+    # dense grid: causal skips blocks fully above the diagonal (dynamic when
+    # the blocks carry ring-hop position offsets)
+    first, last, should_run, rel = _tile_step(
+        walk_ref, dead, causal=causal, block_q=block_q, block_k=block_k,
+        swap=False, qoff=qoff, koff=koff,
     )
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(should_run)
     def _body():
         # keep operands in input dtype (bf16 → full MXU rate), accumulate fp32
         q = q_ref[0, 0]  # [bq, d]
@@ -289,7 +374,9 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
         )
         m_scr[...] = m_new
 
-    @pl.when(step == nstep - 1)
+    _when(should_run, _body)
+
+    @pl.when(last)
     def _finalize():
         l = l_scr[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -300,62 +387,41 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
         lse_ref[0, 0] = lse[:, :AUX_LANES]
 
 
-def _mask_specs(has_seg, has_alibi, block_q, block_k, *, swap_grid=False,
-                bias_bh=None, sparse=False, has_offsets=False):
+def _mask_specs(has_seg, has_alibi, block_q, block_k, qi_of, ki_of, *,
+                bias_bh=None, has_offsets=False):
     """BlockSpecs for the optional mask/bias operands.
 
-    swap_grid: the dk/dv kernel's grid is (b, h, ki, qi).
+    qi_of / ki_of: the grid's :func:`_tile_index_maps`.
     bias_bh: (Bb, Hb) of the dense-bias operand (each 1 → broadcast), or
     None when there is no dense bias.
-    sparse: the grid's last dim is a compaction step; index maps receive
-    the scalar-prefetch (cols, counts) tables and decode the real block
-    index from them.
     has_offsets: a (1,2) SMEM [qoff, koff] position-offset operand rides
     along (ring attention hops)."""
-    if sparse:
-        if swap_grid:  # grid (b, h, ki, step): qi comes from the table
-            qi_of = lambda b, h, x, y, cols, counts: cols[x, y]
-            ki_of = lambda b, h, x, y, cols, counts: x
-        else:  # grid (b, h, qi, step): ki comes from the table
-            qi_of = lambda b, h, x, y, cols, counts: x
-            ki_of = lambda b, h, x, y, cols, counts: cols[x, y]
-    else:
-        qi_of = (lambda b, h, x, y: y) if swap_grid else (lambda b, h, x, y: x)
-        ki_of = (lambda b, h, x, y: x) if swap_grid else (lambda b, h, x, y: y)
     specs = []
     if bias_bh is not None:
         Bb, Hb = bias_bh
         specs.append(
             pl.BlockSpec(
                 (1, 1, block_q, block_k),
-                lambda b, h, x, y, *pf: (b if Bb > 1 else 0,
-                                         h if Hb > 1 else 0,
-                                         qi_of(b, h, x, y, *pf),
-                                         ki_of(b, h, x, y, *pf)),
+                lambda b, h, *g: (b if Bb > 1 else 0, h if Hb > 1 else 0,
+                                  qi_of(*g), ki_of(*g)),
             )
         )
     if has_seg:
         specs.append(
-            pl.BlockSpec(
-                (1, block_q, LANES),
-                lambda b, h, x, y, *pf: (b, qi_of(b, h, x, y, *pf), 0),
-            )
+            pl.BlockSpec((1, block_q, LANES),
+                         lambda b, h, *g: (b, qi_of(*g), 0))
         )
         specs.append(
-            pl.BlockSpec(
-                (1, SUBLANES, block_k),
-                lambda b, h, x, y, *pf: (b, 0, ki_of(b, h, x, y, *pf)),
-            )
+            pl.BlockSpec((1, SUBLANES, block_k),
+                         lambda b, h, *g: (b, 0, ki_of(*g)))
         )
     if has_alibi:
         # the whole [H] slopes vector, indexed by head inside the kernel
         specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     if has_offsets:
         specs.append(
-            pl.BlockSpec(
-                (1, 2), lambda b, h, x, y, *pf: (0, 0),
-                memory_space=pltpu.SMEM
-            )
+            pl.BlockSpec((1, 2), lambda b, h, *g: (0, 0),
+                         memory_space=pltpu.SMEM)
         )
     return specs
 
@@ -379,36 +445,76 @@ def _broadcast_segment_ids(segment_ids, S):
     return seg_q, seg_k
 
 
+def _grid_call(kernel, rows, in_specs, out_specs, out_shape, scratch_shapes,
+               operands, walk, interpret):
+    """One pallas_call over (B, H, len(walk)) with the walk as its scalar
+    prefetch, or over the dense (B, H, *rows) when there is no walk."""
+    B, H = operands[0].shape[:2]
+    if walk is None:
+        return pl.pallas_call(
+            kernel,
+            grid=(B, H, *rows),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=scratch_shapes,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary"),
+            ),
+            interpret=interpret,
+        )(*operands)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, H, len(walk)), in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch_shapes,
+        ),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+    )(jnp.asarray(walk), *operands)
+
+
+def _as_walk(walk):
+    """(int32 vector | None, has it a dead entry) of a static walk."""
+    import numpy as np
+
+    if walk is None:
+        return None, False
+    walk = np.asarray(walk, np.int32)
+    return walk, bool(((walk & _WALK_LIVE) == 0).any())
+
+
 def _flash_fwd(q, k, v, bias, seg, slopes, tables, offsets=None, *, causal,
                scale, block_q, block_k, interpret):
+    """``tables``: the layout's row-major :func:`flat_walk` (static), or
+    None for the dense grid."""
     B, H, S, D = q.shape
     KV = k.shape[1]
     group = H // KV
     nq, nk = pl.cdiv(S, block_q), pl.cdiv(S, block_k)
     has_seg, has_alibi = seg is not None, slopes is not None
-    has_bias, sparse = bias is not None, tables is not None
-    has_offsets = offsets is not None
-    # block-sparse: the grid's last dim walks each q-row's compaction table
-    # (length jmax = densest row) instead of every k-block — masked tiles
-    # are never DMA'd
-    nstep = tables[0].shape[1] if sparse else nk
-    grid = (B, H, nq, nstep)
+    has_bias, has_offsets = bias is not None, offsets is not None
+    walk, dead = _as_walk(tables)
+    qi_of, ki_of = _tile_index_maps(walk is not None)
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, has_seg=has_seg, has_alibi=has_alibi,
-        sparse=sparse, has_bias=has_bias, has_offsets=has_offsets,
+        flat=walk is not None, dead=dead, has_bias=has_bias,
+        has_offsets=has_offsets,
     )
     operands = [q, k, v]
     in_specs = [
         pl.BlockSpec((1, 1, block_q, D),
-                     lambda b, h, qi, y, *pf: (b, h, qi, 0)),
+                     lambda b, h, *g: (b, h, qi_of(*g), 0)),
         pl.BlockSpec((1, 1, block_k, D),
-                     lambda b, h, qi, y, *pf: (
-                         b, h // group, pf[0][qi, y] if pf else y, 0)),
+                     lambda b, h, *g: (b, h // group, ki_of(*g), 0)),
         pl.BlockSpec((1, 1, block_k, D),
-                     lambda b, h, qi, y, *pf: (
-                         b, h // group, pf[0][qi, y] if pf else y, 0)),
+                     lambda b, h, *g: (b, h // group, ki_of(*g), 0)),
     ]
     if has_bias:
         operands.append(bias)
@@ -419,50 +525,32 @@ def _flash_fwd(q, k, v, bias, seg, slopes, tables, offsets=None, *, causal,
         operands.append(slopes.astype(jnp.float32))
     if has_offsets:
         operands.append(offsets)
-    in_specs += _mask_specs(has_seg, has_alibi, block_q, block_k,
-                            sparse=sparse, has_offsets=has_offsets,
+    in_specs += _mask_specs(has_seg, has_alibi, block_q, block_k, qi_of,
+                            ki_of, has_offsets=has_offsets,
                             bias_bh=bias.shape[:2] if has_bias else None)
-
-    out_specs = [
-        pl.BlockSpec((1, 1, block_q, D),
-                     lambda b, h, qi, y, *pf: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, block_q, AUX_LANES),
-                     lambda b, h, qi, y, *pf: (b, h, qi, 0)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-        jax.ShapeDtypeStruct((B, H, S, AUX_LANES), jnp.float32),
-    ]
-    scratch_shapes = [
-        pltpu.VMEM((block_q, LANES), jnp.float32),
-        pltpu.VMEM((block_q, LANES), jnp.float32),
-        pltpu.VMEM((block_q, D), jnp.float32),
-    ]
-    compiler_params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+    out, lse = _grid_call(
+        kernel,
+        (nq, nk),
+        in_specs,
+        [
+            pl.BlockSpec((1, 1, block_q, D),
+                         lambda b, h, *g: (b, h, qi_of(*g), 0)),
+            pl.BlockSpec((1, 1, block_q, AUX_LANES),
+                         lambda b, h, *g: (b, h, qi_of(*g), 0)),
+        ],
+        [
+            jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, S, AUX_LANES), jnp.float32),
+        ],
+        [
+            pltpu.VMEM((block_q, LANES), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
+            pltpu.VMEM((block_q, D), jnp.float32),
+        ],
+        operands,
+        walk,
+        interpret,
     )
-    if sparse:
-        out, lse = pl.pallas_call(
-            kernel,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
-                out_specs=out_specs, scratch_shapes=scratch_shapes,
-            ),
-            out_shape=out_shape,
-            compiler_params=compiler_params,
-            interpret=interpret,
-        )(tables[0], tables[1], *operands)
-    else:
-        out, lse = pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=out_shape,
-            scratch_shapes=scratch_shapes,
-            compiler_params=compiler_params,
-            interpret=interpret,
-        )(*operands)
     return out, lse
 
 
@@ -499,14 +587,12 @@ def _recompute_p_dp(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slope,
 
 
 def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
-                   sparse=False, has_bias=False, emit_dbias=False,
+                   flat=False, dead=False, has_bias=False, emit_dbias=False,
                    has_offsets=False):
-    if sparse:
-        kcols_ref, kcounts_ref, refs = refs[0], refs[1], refs[2:]
-    (q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slopes_ref,
+    (walk_ref, q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slopes_ref,
      bias_ref, offsets_ref, extra) = (
         _parse_refs(refs, has_seg=has_seg, has_alibi=has_alibi,
-                    has_bias=has_bias, has_offsets=has_offsets)
+                    has_bias=has_bias, has_offsets=has_offsets, flat=flat)
     )
     if emit_dbias:
         do_ref, lse_ref, delta_ref, dq_ref, dbias_ref, dq_scr = extra
@@ -515,19 +601,15 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
         dbias_ref = None
     qoff, koff = _offs(offsets_ref)
     slope = _head_slope(slopes_ref, pl.program_id(1))
-    qi, step = pl.program_id(2), pl.program_id(3)
-    nstep = pl.num_programs(3)
-    ki, should_run, rel = _tile_step(
-        (kcols_ref, kcounts_ref) if sparse else None, qi, step,
-        causal=causal, block_q=block_q, block_k=block_k, swap=False,
-        qoff=qoff, koff=koff,
+    first, last, should_run, rel = _tile_step(
+        walk_ref, dead, causal=causal, block_q=block_q, block_k=block_k,
+        swap=False, qoff=qoff, koff=koff,
     )
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    @pl.when(should_run)
     def _body():
         p, dp, delta, do, q, k, v = _recompute_p_dp(
             q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slope, bias_ref,
@@ -543,44 +625,40 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
             preferred_element_type=jnp.float32,
         )
 
-    if dbias_ref is not None:
+    _when(should_run, _body)
+
+    if dbias_ref is not None and should_run is not True:
         # every tile of the dbias output must be written, including the
-        # causally-skipped ones
+        # causally-skipped ones (a dense bias rides the dense grid)
         @pl.when(jnp.logical_not(should_run))
         def _zero_dbias():
             dbias_ref[0, 0] = jnp.zeros_like(dbias_ref[0, 0])
 
-    @pl.when(step == nstep - 1)
+    @pl.when(last)
     def _finalize():
         dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
-                    sparse=False, has_bias=False, has_offsets=False):
-    if sparse:
-        qrows_ref, qcounts_ref, refs = refs[0], refs[1], refs[2:]
-    (q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slopes_ref,
+                    flat=False, dead=False, has_bias=False, has_offsets=False):
+    (walk_ref, q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slopes_ref,
      bias_ref, offsets_ref, extra) = (
         _parse_refs(refs, has_seg=has_seg, has_alibi=has_alibi,
-                    has_bias=has_bias, has_offsets=has_offsets)
+                    has_bias=has_bias, has_offsets=has_offsets, flat=flat)
     )
     do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr = extra
     qoff, koff = _offs(offsets_ref)
     slope = _head_slope(slopes_ref, pl.program_id(1))
-    ki, step = pl.program_id(2), pl.program_id(3)
-    nstep = pl.num_programs(3)
-    qi, should_run, rel = _tile_step(
-        (qrows_ref, qcounts_ref) if sparse else None, ki, step,
-        causal=causal, block_q=block_q, block_k=block_k, swap=True,
-        qoff=qoff, koff=koff,
+    first, last, should_run, rel = _tile_step(
+        walk_ref, dead, causal=causal, block_q=block_q, block_k=block_k,
+        swap=True, qoff=qoff, koff=koff,
     )
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(should_run)
     def _body():
         p, dp, delta, do, q, k, v = _recompute_p_dp(
             q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slope, bias_ref,
@@ -597,7 +675,9 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, has_seg, has_alibi,
             preferred_element_type=jnp.float32,
         )  # [bk, d]
 
-    @pl.when(step == nstep - 1)
+    _when(should_run, _body)
+
+    @pl.when(last)
     def _finalize():
         dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
@@ -613,7 +693,7 @@ def _bias_grad_kernel(*refs, scale, causal, block_q, block_k, has_seg,
     shared rel-pos bias would otherwise pay a B× fp32 blow-up in backward).
     Recomputes the two logit matmuls; that trade (2 extra tile matmuls vs
     a [B,H,S,S] HBM tensor) is the bandwidth-bound-friendly direction."""
-    (q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slopes_ref,
+    (_, q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slopes_ref,
      bias_ref, _offsets_unused, extra) = (
         _parse_refs(refs, has_seg=has_seg, has_alibi=has_alibi, has_bias=True)
     )
@@ -634,11 +714,9 @@ def _bias_grad_kernel(*refs, scale, causal, block_q, block_k, has_seg,
     def _init():
         scr[:] = jnp.zeros_like(scr)
 
-    _, should_run, rel = _tile_step(
-        None, qi, ki, causal=causal, block_q=block_q, block_k=block_k,
-        swap=False)
+    should_run = _block_visible(qi, ki, block_q, block_k) if causal else True
+    rel = qi * block_q - ki * block_k
 
-    @pl.when(should_run)
     def _body():
         p, dp, delta, _, _, _, _ = _recompute_p_dp(
             q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, slope, bias_ref,
@@ -646,6 +724,8 @@ def _bias_grad_kernel(*refs, scale, causal, block_q, block_k, has_seg,
             block_q=block_q, block_k=block_k,
         )
         scr[:] += p * (dp - delta)
+
+    _when(should_run, _body)
 
     @pl.when(inner == inner_n - 1)
     def _write():
@@ -725,45 +805,16 @@ def _bias_grad_call(q, k, v, bias, seg, slopes, do, lse, delta, *,
     return dbias
 
 
-def _bwd_call(kernel, grid, in_specs, out_specs, out_shape, scratch_shapes,
-              operands, sparse_tables, interpret):
-    """Dispatch one backward pallas_call, with the scalar-prefetch grid
-    spec when a compaction table drives the last grid dim."""
-    compiler_params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-    )
-    if sparse_tables is not None:
-        return pl.pallas_call(
-            kernel,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
-                out_specs=out_specs, scratch_shapes=scratch_shapes,
-            ),
-            out_shape=out_shape,
-            compiler_params=compiler_params,
-            interpret=interpret,
-        )(*sparse_tables, *operands)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch_shapes,
-        compiler_params=compiler_params,
-        interpret=interpret,
-    )(*operands)
-
-
 def _flash_bwd(q, k, v, out, lse, do, bias, seg, slopes, tables, offsets=None,
                *, causal, scale, block_q, block_k, interpret, delta=None):
+    """``tables``: the layout's (row-major, by-column) :func:`flat_walk`
+    pair for dq and dk/dv (static), or None for the dense grids."""
     B, H, S, D = q.shape
     KV = k.shape[1]
     group = H // KV
     nq, nk = pl.cdiv(S, block_q), pl.cdiv(S, block_k)
     has_seg, has_alibi = seg is not None, slopes is not None
-    has_bias, sparse = bias is not None, tables is not None
-    has_offsets = offsets is not None
+    has_bias, has_offsets = bias is not None, offsets is not None
     if delta is None:
         delta = jnp.sum(
             do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
@@ -780,73 +831,55 @@ def _flash_bwd(q, k, v, out, lse, do, bias, seg, slopes, tables, offsets=None,
         mask_operands.append(slopes.astype(jnp.float32))
     if has_offsets:
         mask_operands.append(offsets)
+    operands = [q, k, v, *mask_operands, do, lse, delta]
     bias_bh = bias.shape[:2] if has_bias else None
     # full-shape bias: its gradient IS [B,H,S,S], so the dq kernel emits the
     # tiles inline for free. Broadcast bias: a dedicated accumulation kernel
     # keeps peak dbias memory at the bias's own shape (see _bias_grad_kernel).
     emit_dbias = has_bias and bias_bh == (B, H)
 
-    def qspec(qi_of):
-        return pl.BlockSpec((1, 1, block_q, D),
-                            lambda b, h, x, y, *pf: (b, h, qi_of(x, y, *pf), 0))
-
-    def kvspec(ki_of):
-        return pl.BlockSpec(
+    def in_specs(qi_of, ki_of):
+        q_like = pl.BlockSpec((1, 1, block_q, D),
+                              lambda b, h, *g: (b, h, qi_of(*g), 0))
+        kv_like = pl.BlockSpec(
             (1, 1, block_k, D),
-            lambda b, h, x, y, *pf: (b, h // group, ki_of(x, y, *pf), 0))
-
-    def auxspecs(qi_of):
+            lambda b, h, *g: (b, h // group, ki_of(*g), 0))
+        aux = pl.BlockSpec((1, 1, block_q, AUX_LANES),
+                           lambda b, h, *g: (b, h, qi_of(*g), 0))
         # do / lse / delta all follow the q-block index
-        return [
-            pl.BlockSpec((1, 1, block_q, D),
-                         lambda b, h, x, y, *pf: (b, h, qi_of(x, y, *pf), 0)),
-            pl.BlockSpec((1, 1, block_q, AUX_LANES),
-                         lambda b, h, x, y, *pf: (b, h, qi_of(x, y, *pf), 0)),
-            pl.BlockSpec((1, 1, block_q, AUX_LANES),
-                         lambda b, h, x, y, *pf: (b, h, qi_of(x, y, *pf), 0)),
-        ]
+        return ([q_like, kv_like, kv_like]
+                + _mask_specs(has_seg, has_alibi, block_q, block_k, qi_of,
+                              ki_of, bias_bh=bias_bh, has_offsets=has_offsets)
+                + [q_like, aux, aux])
 
-    # --- dq (grid: b, h, qi, k-step) ---------------------------------------
-    if sparse:
-        kcols, kcounts, qrows, qcounts = tables
-        dq_tables = (kcols, kcounts)
-        dq_steps = kcols.shape[1]
-        qi_of = lambda x, y, *pf: x
-        ki_of = lambda x, y, *pf: pf[0][x, y]
-    else:
-        dq_tables = None
-        dq_steps = nk
-        qi_of = lambda x, y, *pf: x
-        ki_of = lambda x, y, *pf: y
+    flags = dict(scale=scale, causal=causal, block_q=block_q,
+                 block_k=block_k, has_seg=has_seg, has_alibi=has_alibi,
+                 has_bias=has_bias, has_offsets=has_offsets)
 
+    # --- dq (a q-block's k-blocks in order) --------------------------------
+    walk, dead = _as_walk(tables[0] if tables else None)
+    qi_of, ki_of = _tile_index_maps(walk is not None)
     dq_out_specs = pl.BlockSpec((1, 1, block_q, D),
-                                lambda b, h, x, y, *pf: (b, h, x, 0))
+                                lambda b, h, *g: (b, h, qi_of(*g), 0))
     dq_out_shape = jax.ShapeDtypeStruct((B, H, S, D), q.dtype)
     if emit_dbias:
         # each tile written exactly once → emit in the bias dtype directly
-        # (emit_dbias never combines with sparse: enforced at the entry)
+        # (a dense bias never combines with a walk: enforced at the entry)
         dq_out_specs = [dq_out_specs, pl.BlockSpec(
             (1, 1, block_q, block_k), lambda b, h, x, y: (b, h, x, y))]
         dq_out_shape = [dq_out_shape,
                         jax.ShapeDtypeStruct((B, H, S, S), bias.dtype)]
 
-    dq = _bwd_call(
-        functools.partial(
-            _bwd_dq_kernel, scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k, has_seg=has_seg, has_alibi=has_alibi,
-            sparse=sparse, has_bias=has_bias, emit_dbias=emit_dbias,
-            has_offsets=has_offsets,
-        ),
-        (B, H, nq, dq_steps),
-        [qspec(qi_of), kvspec(ki_of), kvspec(ki_of)]
-        + _mask_specs(has_seg, has_alibi, block_q, block_k, sparse=sparse,
-                      bias_bh=bias_bh, has_offsets=has_offsets)
-        + auxspecs(qi_of),
+    dq = _grid_call(
+        functools.partial(_bwd_dq_kernel, flat=walk is not None, dead=dead,
+                          emit_dbias=emit_dbias, **flags),
+        (nq, nk),
+        in_specs(qi_of, ki_of),
         dq_out_specs,
         dq_out_shape,
         [pltpu.VMEM((block_q, D), jnp.float32)],
-        [q, k, v, *mask_operands, do, lse, delta],
-        dq_tables,
+        operands,
+        walk,
         interpret,
     )
     dbias = None
@@ -859,35 +892,17 @@ def _flash_bwd(q, k, v, out, lse, do, bias, seg, slopes, tables, offsets=None,
             interpret=interpret, group=group,
         )
 
-    # --- dk/dv (grid: b, h, ki, q-step); GQA-sum over the group after ------
-    if sparse:
-        dkv_tables = (qrows, qcounts)
-        dkv_steps = qrows.shape[1]
-        qi_of = lambda x, y, *pf: pf[0][x, y]
-        ki_of = lambda x, y, *pf: x
-    else:
-        dkv_tables = None
-        dkv_steps = nq
-        qi_of = lambda x, y, *pf: y
-        ki_of = lambda x, y, *pf: x
-
-    dk, dv = _bwd_call(
-        functools.partial(
-            _bwd_dkv_kernel, scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k, has_seg=has_seg, has_alibi=has_alibi,
-            sparse=sparse, has_bias=has_bias, has_offsets=has_offsets,
-        ),
-        (B, H, nk, dkv_steps),
-        [qspec(qi_of), kvspec(ki_of), kvspec(ki_of)]
-        + _mask_specs(has_seg, has_alibi, block_q, block_k, swap_grid=True,
-                      sparse=sparse, bias_bh=bias_bh, has_offsets=has_offsets)
-        + auxspecs(qi_of),
-        [
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, x, y, *pf: (b, h, x, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, x, y, *pf: (b, h, x, 0)),
-        ],
+    # --- dk/dv (a k-block's q-blocks in order); GQA-sum over the group after
+    walk, dead = _as_walk(tables[1] if tables else None)
+    qi_of, ki_of = _tile_index_maps(walk is not None, swap=True)
+    dkv_spec = pl.BlockSpec((1, 1, block_k, D),
+                            lambda b, h, *g: (b, h, ki_of(*g), 0))
+    dk, dv = _grid_call(
+        functools.partial(_bwd_dkv_kernel, flat=walk is not None, dead=dead,
+                          **flags),
+        (nk, nq),
+        in_specs(qi_of, ki_of),
+        [dkv_spec, dkv_spec],
         [
             jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
@@ -896,8 +911,8 @@ def _flash_bwd(q, k, v, out, lse, do, bias, seg, slopes, tables, offsets=None,
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        [q, k, v, *mask_operands, do, lse, delta],
-        dkv_tables,
+        operands,
+        walk,
         interpret,
     )
     if group > 1:
@@ -909,24 +924,29 @@ def _flash_bwd(q, k, v, out, lse, do, bias, seg, slopes, tables, offsets=None,
 # -----------------------------------------------------------------------------
 # public op ([B, S, H, D] layout, custom vjp)
 # -----------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12, 13))
-def _flash_attention_bhsd(q, k, v, bias, seg, slopes, tables, causal, scale,
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(6, 14)))
+def _flash_attention_bhsd(q, k, v, bias, seg, slopes, walks, causal, scale,
                           block_q, block_k, block_q_bwd, block_k_bwd,
                           interpret):
+    """``walks``: None (dense grids) or the static layout's three
+    :func:`flat_walk` lists as tuples of ints (hashable: they are no
+    operand, each call builds its scalar-prefetch vector from them) —
+    forward at (block_q, block_k) granularity, dq and dk/dv at
+    (block_q_bwd, block_k_bwd)."""
     out, _ = _flash_fwd(
-        q, k, v, bias, seg, slopes, tables[:2] if tables else None,
+        q, k, v, bias, seg, slopes, walks[0] if walks else None,
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
         interpret=interpret,
     )
     return out
 
 
-def _fa_fwd(q, k, v, bias, seg, slopes, tables, causal, scale, block_q,
+def _fa_fwd(q, k, v, bias, seg, slopes, walks, causal, scale, block_q,
             block_k, block_q_bwd, block_k_bwd, interpret):
     from jax.ad_checkpoint import checkpoint_name
 
     out, lse = _flash_fwd(
-        q, k, v, bias, seg, slopes, tables[:2] if tables else None,
+        q, k, v, bias, seg, slopes, walks[0] if walks else None,
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
         interpret=interpret,
     )
@@ -939,33 +959,24 @@ def _fa_fwd(q, k, v, bias, seg, slopes, tables, causal, scale, block_q,
     # tag the residual lse AFTER dropping the redundant lane copies so the
     # policy saves [B,H,S], not the kernel's [B,H,S,AUX_LANES] layout
     lse_s = checkpoint_name(lse[..., 0], "flash_lse")
-    return out, (q, k, v, bias, seg, slopes, tables, out, lse_s)
+    return out, (q, k, v, bias, seg, slopes, out, lse_s)
 
 
-def _fa_bwd(causal, scale, block_q, block_k, block_q_bwd, block_k_bwd,
+def _fa_bwd(walks, causal, scale, block_q, block_k, block_q_bwd, block_k_bwd,
             interpret, res, do):
-    q, k, v, bias, seg, slopes, tables, out, lse_s = res
+    q, k, v, bias, seg, slopes, out, lse_s = res
     lse = jnp.broadcast_to(lse_s[..., None], (*lse_s.shape, AUX_LANES))
-    # tables is (kcols_f, kcounts_f, kcols_b, kcounts_b, qrows_b, qcounts_b):
-    # the fwd pair is at (block_q, block_k) granularity, the bwd tuple at
-    # (block_q_bwd, block_k_bwd) — the entry builds both (identical when the
-    # bwd tiles inherit the fwd's)
     dq, dk, dv, dbias = _flash_bwd(
         q, k, v, out, lse, do, bias, seg, slopes,
-        tables[2:] if tables else None, causal=causal, scale=scale,
+        walks[1:] if walks else None, causal=causal, scale=scale,
         block_q=block_q_bwd, block_k=block_k_bwd, interpret=interpret,
     )
-    # segment ids / compaction tables are integer primals: cotangents float0
+    # segment ids are integer primals: cotangents float0
     import numpy as np
 
     dseg = None if seg is None else np.zeros(seg.shape, jax.dtypes.float0)
     dslopes = None if slopes is None else jnp.zeros_like(slopes)
-    dtables = (
-        None
-        if tables is None
-        else tuple(np.zeros(t.shape, jax.dtypes.float0) for t in tables)
-    )
-    return dq, dk, dv, dbias, dseg, dslopes, dtables
+    return dq, dk, dv, dbias, dseg, dslopes
 
 
 _flash_attention_bhsd.defvjp(_fa_fwd, _fa_bwd)
@@ -999,14 +1010,6 @@ def set_default_block_sizes(block_q: int = 0, block_k: int = 0,
 
 
 _block_scope_stack: list = []
-
-# Causal runs ride the block-sparse compaction path by default (skips the
-# above-diagonal k/v DMA — ~2x less HBM traffic on the attention stream).
-# DSTPU_FLASH_CAUSAL_SKIP=0 restores the dense grid (A/B kill-switch).
-import os as _os  # noqa: E402
-
-_CAUSAL_DMA_SKIP = _os.environ.get("DSTPU_FLASH_CAUSAL_SKIP", "1") != "0"
-
 
 def current_block_sizes() -> tuple:
     """The (block_q, block_k) preference in effect right now: innermost
@@ -1093,7 +1096,7 @@ def flash_attention(
     bq, bk = _pick_block(S, block_q), _pick_block(S, block_k)
     # bwd tiles: 0 = inherit the (resolved) fwd tile; a user-supplied
     # block_mask pins them to the fwd sizes because its granularity is
-    # fixed by the mask shape (the causal-synth layout below is rebuilt at
+    # fixed by the mask shape (the causal triangle below is built again at
     # bwd granularity instead)
     bqb = (_pick_block(S, block_q_bwd) if block_q_bwd else None) or bq
     bkb = (_pick_block(S, block_k_bwd) if block_k_bwd else None) or bk
@@ -1131,7 +1134,13 @@ def flash_attention(
     if block_mask is not None and layout_np is None:
         reasons.append(
             "block_mask must be trace-time static (numpy) for the "
-            "DMA-skip compaction tables"
+            "kernels' walk of its live tiles"
+        )
+    if layout_np is not None and _walk_bound(layout_np) > WALK_MAX_STEPS:
+        reasons.append(
+            f"block_mask {layout_np.shape} has up to "
+            f"{_walk_bound(layout_np)} grid steps, over the "
+            f"{WALK_MAX_STEPS} the scalar memory holds"
         )
     if k.shape[1] != S:
         reasons.append(f"cross-length attention (q seq {S}, kv seq {k.shape[1]})")
@@ -1187,55 +1196,43 @@ def flash_attention(
         if alibi_slopes is not None
         else None
     )
-    tables = None
+    # A static layout's kernels walk its live tiles and no others (see
+    # flat_walk): a masked tile is neither fetched nor a grid step. Plain
+    # causal attention IS such a layout, the lower block triangle, so it
+    # rides the same walk: (B, H, 10) at S 2048 with 512-wide tiles where the
+    # dense grid has 16 steps a (batch, head). What has no static layout or
+    # needs every tile keeps the dense grid with its in-kernel predicate: a
+    # dense bias (its dbias paths write every tile), a non-causal call, ring
+    # hops (ring_flash.py), and a triangle whose list would pass
+    # WALK_MAX_STEPS.
+    walks = None
     if layout_np is not None:
         if layout_np.shape != (S // bq, S // bk):
             raise ValueError(
                 f"block_mask shape {layout_np.shape} != (nq={S // bq}, "
                 f"nk={S // bk}) for seq {S} with blocks ({bq}, {bk})"
             )
-    elif causal and bias is None and _CAUSAL_DMA_SKIP:
-        # (bias excluded: its dbias paths use the dense grid)
-        # Plain causal attention IS a static block-sparse layout (lower
-        # block-triangle): without tables, above-diagonal tiles are
-        # predicated off but still DMA'd — nearly half the k/v HBM stream
-        # fetched and discarded. Synthesize the triangle and ride the same
-        # compaction path (grid length is still nk — the densest row —
-        # but padded steps repeat an index, so Mosaic skips their fetch).
-        import numpy as _np
-
-        qi_idx = _np.arange(S // bq)[:, None]
-        ki_idx = _np.arange(S // bk)[None, :]
-        # _block_visible works on numpy arrays: one source of truth with
-        # the in-kernel predicate
-        layout_np = _block_visible(qi_idx, ki_idx, bq, bk).astype(_np.int32)
+        layout_np = layout_bwd = layout_np != 0
+        if causal:
+            # the dense grid's per-step visibility test, folded in here
+            layout_np = layout_bwd = layout_np & causal_layout(S, bq, bk)
+    elif causal and bias is None:
+        # the bwd kernels walk the triangle at their own tiles' granularity
+        layout_np, layout_bwd = causal_layout(S, bq, bk), causal_layout(
+            S, bqb, bkb)
+        if max(map(_walk_bound, (layout_np, layout_bwd))) > WALK_MAX_STEPS:
+            layout_np = None
     if layout_np is not None:
-        # compaction tables (see _compact_rows): the kernels walk only the
-        # active blocks, so masked tiles are never fetched from HBM. The
-        # fwd pair is at (bq, bk) granularity; the bwd kernels get their
-        # own tables at (bqb, bkb) — identical unless the causal-synth
-        # layout was rebuilt for distinct bwd tiles (block_mask pins
-        # bqb/bkb to bq/bk above, so rebuilding only happens for causal).
-        import numpy as _np
-
-        kcols, kcounts = _compact_rows(layout_np)
-        if (bqb, bkb) != (bq, bk):
-            qi_b = _np.arange(S // bqb)[:, None]
-            ki_b = _np.arange(S // bkb)[None, :]
-            layout_bwd = _block_visible(qi_b, ki_b, bqb, bkb).astype(_np.int32)
-        else:
-            layout_bwd = layout_np
-        kcols_b, kcounts_b = _compact_rows(layout_bwd)
-        qrows_b, qcounts_b = _compact_rows(layout_bwd.T)
-        tables = tuple(
-            jnp.asarray(t)
-            for t in (kcols, kcounts, kcols_b, kcounts_b, qrows_b, qcounts_b)
+        walks = tuple(
+            tuple(w.tolist())
+            for w in (flat_walk(layout_np), flat_walk(layout_bwd),
+                      flat_walk(layout_bwd, by_col=True))
         )
     bias_f = bias  # storage dtype rides to the kernel; tiles upcast in VMEM
 
-    def kernel(qt, kt, vt, bias_, seg_, slopes_, tables_):
+    def kernel(qt, kt, vt, bias_, seg_, slopes_):
         return _flash_attention_bhsd(
-            qt, kt, vt, bias_, seg_, slopes_, tables_, causal, scale, bq, bk,
+            qt, kt, vt, bias_, seg_, slopes_, walks, causal, scale, bq, bk,
             bqb, bkb, interpret
         )
 
@@ -1268,20 +1265,13 @@ def flash_attention(
         if not mapped:
             # everything relevant is already Manual/local: run the kernel
             # directly on the local shards
-            out = kernel(qt, kt, vt, bias_f, seg, slopes, tables)
+            out = kernel(qt, kt, vt, bias_f, seg, slopes)
             return jnp.swapaxes(out, 1, 2)
 
         spec_q = P(b_ax, h_ax, None, None)
         # shard_map can't take None operands: pass dummies, re-None inside
         s_in = seg if seg is not None else jnp.zeros((B, S), jnp.int32)
         sl_in = slopes if slopes is not None else jnp.zeros((H,), jnp.float32)
-        t_in = (
-            tables
-            if tables is not None
-            else tuple(
-                jnp.zeros((1,) * n, jnp.int32) for n in (2, 1, 2, 1, 2, 1)
-            )
-        )
         bias_in = (
             bias_f if bias_f is not None else jnp.zeros((1, 1, 1, 1), jnp.float32)
         )
@@ -1292,13 +1282,12 @@ def flash_attention(
             None, None,
         )
 
-        def body(qt, kt, vt, bias_, s_, sl_, t_):
+        def body(qt, kt, vt, bias_, s_, sl_):
             return kernel(
                 qt, kt, vt,
                 bias_ if bias_f is not None else None,
                 s_ if seg is not None else None,
                 sl_ if slopes is not None else None,
-                t_ if tables is not None else None,
             )
 
         kw = {}
@@ -1312,17 +1301,13 @@ def flash_attention(
                 bias_spec,
                 P(b_ax, None),  # segment ids: full sequence per shard
                 P(h_ax),  # per-head slopes follow the head sharding
-                # compaction tables replicated (layout is global/static):
-                # fwd (kcols, kcounts) + bwd (kcols, kcounts, qrows, qcounts)
-                (P(None, None), P(None), P(None, None), P(None),
-                 P(None, None), P(None)),
             ),
             out_specs=spec_q,
             check_vma=False,
             **kw,
-        )(qt, kt, vt, bias_in, s_in, sl_in, t_in)
+        )(qt, kt, vt, bias_in, s_in, sl_in)
     else:
-        out = kernel(qt, kt, vt, bias_f, seg, slopes, tables)
+        out = kernel(qt, kt, vt, bias_f, seg, slopes)
     return jnp.swapaxes(out, 1, 2)
 
 

@@ -3,11 +3,12 @@
 Parity: csrc/sparse_attention/ + deepspeed/ops/sparse_attention/ (SparseSelfAttention,
 sparsity_config.py). The reference builds triton/CUDA block-sparse matmuls
 from a layout tensor; here the same block layout feeds the Pallas flash
-kernel's compacted grid (ops/pallas/flash_attention.py `block_mask`): the
-layout becomes scalar-prefetch compaction tables, the kernel grid walks
-only each row's active blocks, and masked tiles are neither computed NOR
-fetched from HBM — both the MXU work and the DMA bandwidth scale with the
-layout's density, like the reference's triton lut-driven sdd/dsd kernels.
+kernel's flat grid (ops/pallas/flash_attention.py `block_mask`): the
+layout becomes one scalar-prefetch list of its live (q-block, k-block)
+tiles, the kernel grid walks that list and nothing else, and masked tiles
+are neither computed NOR fetched from HBM NOR a grid step — the MXU work,
+the DMA bandwidth and the grid's length all scale with the layout's
+density, like the reference's triton lut-driven sdd/dsd kernels.
 No separate sdd/dsd/dds matmul trio needed; XLA/Mosaic fuse the rest.
 
 Patterns mirror the reference's sparsity_config classes: Fixed (local +

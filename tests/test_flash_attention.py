@@ -313,34 +313,11 @@ def test_flash_under_onebit_stacked_grads(devices8):
     comm.destroy_process_group()
 
 
-def _count_pallas_calls(closed_jaxpr):
-    """Recursively count pallas_call eqns (remat-recompute detector)."""
-    n = 0
-    seen = set()
-
-    def walk(j):
-        nonlocal n
-        if id(j) in seen:
-            return
-        seen.add(id(j))
-        for eqn in j.eqns:
-            if "pallas" in str(eqn.primitive):
-                n += 1
-            for v in eqn.params.values():
-                for x in v if isinstance(v, (tuple, list)) else [v]:
-                    if hasattr(x, "jaxpr"):
-                        walk(x.jaxpr)
-                    elif hasattr(x, "eqns"):
-                        walk(x)
-
-    walk(closed_jaxpr.jaxpr)
-    return n
-
-
 def test_dots_flash_policy_skips_fwd_recompute():
     """The dots_flash remat policy saves the kernel outputs (checkpoint_name
     tags in _fa_fwd), so backward must NOT re-run the forward kernel:
     3 pallas calls (fwd, dq, dkv) vs dots_saveable's 4 (+fwd recompute)."""
+    from deepspeed_tpu.analysis.shardlint import pallas_grids
     from deepspeed_tpu.runtime.activation_checkpointing import policy_by_name
 
     q, k, v = _qkv(jax.random.PRNGKey(3), B=1, S=256, H=2, D=64)
@@ -351,7 +328,7 @@ def test_dots_flash_policy_skips_fwd_recompute():
             policy=policy_by_name(policy_name),
             prevent_cse=False,
         )
-        return _count_pallas_calls(jax.make_jaxpr(jax.grad(f))(q, k, v))
+        return len(pallas_grids(jax.make_jaxpr(jax.grad(f))(q, k, v).jaxpr))
 
     assert counts("dots_saveable") == 4
     assert counts("dots_flash") == 3
@@ -492,32 +469,149 @@ def test_unaligned_seq_fallback_names_reason():
     assert len(hits) == 1 and "128-aligned" in hits[0]
 
 
-def test_causal_dma_skip_bitmatches_dense_grid(monkeypatch):
-    """Causal runs ride the compaction (DMA-skip) path by default; the
-    k-blocks process in the same ascending order as the dense grid, so the
-    two paths are bit-identical — and the kill-switch restores the dense
-    grid."""
-    from deepspeed_tpu.ops.pallas import flash_attention as fa_mod
+_EMPTY_ROW = np.array([  # q-block 1 sees nothing; k-block 3 is seen by none
+    [1, 0, 0, 0],
+    [0, 0, 0, 0],
+    [1, 1, 1, 0],
+    [0, 1, 0, 0],
+])
 
-    assert fa_mod._CAUSAL_DMA_SKIP  # default on
-    q, k, v = _qkv(jax.random.PRNGKey(21), B=1, S=256, H=2, D=64)
-    out_skip = fa_mod.flash_attention(q, k, v, causal=True,
-                                      block_q=128, block_k=128)
-    g_skip = jax.grad(lambda a: jnp.sum(fa_mod.flash_attention(
-        a, k, v, causal=True, block_q=128, block_k=128) ** 2))(q)
-    monkeypatch.setattr(fa_mod, "_CAUSAL_DMA_SKIP", False)
-    out_dense = fa_mod.flash_attention(q, k, v, causal=True,
-                                       block_q=128, block_k=128)
-    g_dense = jax.grad(lambda a: jnp.sum(fa_mod.flash_attention(
-        a, k, v, causal=True, block_q=128, block_k=128) ** 2))(q)
-    np.testing.assert_array_equal(np.asarray(out_skip), np.asarray(out_dense))
-    np.testing.assert_array_equal(np.asarray(g_skip), np.asarray(g_dense))
+
+@pytest.mark.parametrize("case", [
+    dict(),
+    dict(alibi=True, seg=True),
+    dict(H=4, KV=2),
+    dict(S=512, bwd=(256, 128)),
+    dict(S=512, bwd=(128, 256), alibi=True),
+    dict(H=1, D=256),
+    dict(S=512, layout=_EMPTY_ROW),
+    dict(S=512, layout=_EMPTY_ROW, causal=False, alibi=True),
+], ids=["plain", "alibi-segments", "gqa", "bwd-tiles-256x128",
+        "bwd-tiles-128x256-alibi", "hd256", "empty-row-causal",
+        "empty-row-noncausal-alibi"])
+def test_flat_walk_bitmatches_dense_grid(case):
+    """A static layout's kernels walk its live tiles, a row's in the dense
+    grid's ascending order, so out, lse, dq, dk, dv are the dense grid's bit
+    for bit: the causal triangle against ``tables=None`` (the in-kernel
+    predicate), a block-sparse layout with an empty row against the dense
+    grid under a 0 / NEG_INF block bias (a masked tile adds exact zeros)."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    S, H, D = case.get("S", 256), case.get("H", 2), case.get("D", 64)
+    causal, layout = case.get("causal", True), case.get("layout")
+    bq = bk = 128
+    bqb, bkb = case.get("bwd", (bq, bk))
+    q, k, v = _qkv(jax.random.PRNGKey(21), B=1, S=S, H=H,
+                   KV=case.get("KV"), D=D)
+    do = jax.random.normal(jax.random.PRNGKey(22), q.shape, q.dtype)
+    slopes = (jnp.asarray([2.0 ** -(i + 1) for i in range(H)], jnp.float32)
+              if case.get("alibi") else None)
+    seg = _segments(1, S) if case.get("seg") else None
+    qt, kt, vt, dot = (jnp.swapaxes(x, 1, 2) for x in (q, k, v, do))
+
+    bias = None
+    if layout is None:
+        fwd_layout = fa.causal_layout(S, bq, bk)
+        bwd_layout = fa.causal_layout(S, bqb, bkb)
+    else:
+        tok = np.kron(layout, np.ones((bq, bk)))
+        bias = jnp.where(jnp.asarray(tok) > 0, 0.0, fa.NEG_INF)[None, None]
+        fwd_layout = bwd_layout = (
+            layout & fa.causal_layout(S, bq, bk) if causal else layout)
+
+    def run(fwd_walk, bwd_walks, bias):
+        kw = dict(causal=causal, scale=D ** -0.5, interpret=True)
+        out, lse = fa._flash_fwd(qt, kt, vt, bias, seg, slopes, fwd_walk,
+                                 block_q=bq, block_k=bk, **kw)
+        dq, dk, dv, _ = fa._flash_bwd(qt, kt, vt, out, lse, dot, bias, seg,
+                                      slopes, bwd_walks, block_q=bqb,
+                                      block_k=bkb, **kw)
+        return out, lse, dq, dk, dv
+
+    got = run(fa.flat_walk(fwd_layout),
+              (fa.flat_walk(bwd_layout), fa.flat_walk(bwd_layout, by_col=True)),
+              None)
+    want = run(None, None, bias)
+    for g, w, name in zip(got, want, ("out", "lse", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=name)
+    if layout is not None:  # the empty row's block is written, as zeros
+        assert not np.asarray(got[0][:, :, bq:2 * bq]).any()
+        assert (np.asarray(got[1][:, :, bq:2 * bq]) == fa.NEG_INF).all()
+        assert not np.asarray(got[3][:, :, 3 * bk:]).any()
+
+    # and the public entry takes that walk: the same out and grads
+    out, vjp = jax.vjp(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, alibi_slopes=slopes, segment_ids=seg,
+            block_mask=layout, block_q=bq, block_k=bk, block_q_bwd=bqb,
+            block_k_bwd=bkb), q, k, v)
+    for g, w, name in zip((out, *vjp(do)), (got[0], *got[2:]),
+                          ("out", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(
+            np.asarray(g), np.asarray(jnp.swapaxes(w, 1, 2)), err_msg=name)
+
+
+def test_flash_calls_walk_the_live_tiles():
+    """What engages is read off the traced program: a causal call's three
+    kernels run over (B, H, live tiles), a dense bias or a non-causal call
+    keeps the dense (B, H, nq, nk)."""
+    from deepspeed_tpu.analysis.shardlint import pallas_grids
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    q = jax.ShapeDtypeStruct((2, 2048, 4, 64), jnp.bfloat16)
+    bias = jax.ShapeDtypeStruct((2, 4, 2048, 2048), jnp.bfloat16)
+
+    def grids(**kw):
+        has_bias = kw.pop("has_bias", False)
+
+        def loss(q, b):
+            return flash_attention(q, q, q, bias=b if has_bias else None,
+                                   **kw).astype(jnp.float32).sum()
+
+        return pallas_grids(jax.make_jaxpr(jax.grad(loss))(q, bias).jaxpr)
+
+    assert fa.walk_steps(fa.causal_layout(2048, 512, 512)) == (10, 10)
+    assert grids(causal=True) == [
+        ("_fwd_kernel", (2, 4, 10)), ("_bwd_dq_kernel", (2, 4, 10)),
+        ("_bwd_dkv_kernel", (2, 4, 10))]
+    # GLM's 4,096 rows: 36 of 64 tiles; distinct backward tiles: their own
+    assert fa.walk_steps(fa.causal_layout(4096, 512, 512)) == (36, 36)
+    assert [g for _, g in grids(causal=True, block_q_bwd=1024)] == [
+        (2, 4, 10), (2, 4, 6), (2, 4, 6)]
+    assert {g for _, g in grids(causal=False)} == {(2, 4, 4, 4)}
+    assert {g for _, g in grids(causal=True, has_bias=True)} == {(2, 4, 4, 4)}
+
+
+def test_walk_past_the_scalar_memory_takes_the_dense_grid(monkeypatch):
+    """The list rides scalar prefetch: a causal triangle whose list would
+    pass ``WALK_MAX_STEPS`` keeps the dense grid and its in-kernel predicate
+    (same numbers), a block-sparse layout that long falls back to XLA and
+    says why."""
+    from deepspeed_tpu.analysis.shardlint import pallas_grids
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+    from deepspeed_tpu.utils import logging as logging_mod
+
+    q, k, v = _qkv(jax.random.PRNGKey(23), B=1, S=512, H=2, D=64)
+    call = lambda **kw: flash_attention(q, k, v, causal=True, block_q=128,
+                                        block_k=128, **kw)
+    want = call()
+    monkeypatch.setattr(fa, "WALK_MAX_STEPS", 13)  # the triangle: 10 + 4
+    grids = pallas_grids(jax.make_jaxpr(call)().jaxpr)
+    assert grids == [("_fwd_kernel", (1, 2, 4, 4))]
+    np.testing.assert_array_equal(np.asarray(call()), np.asarray(want))
+    logging_mod.fallback_log_seen.clear()
+    layout = np.tril(np.ones((4, 4), np.int32))
+    np.testing.assert_allclose(np.asarray(call(block_mask=layout)),
+                               np.asarray(want), atol=2e-5)
+    reasons = [r for key in logging_mod.fallback_log_seen for r in key[1]]
+    assert any("scalar memory" in r for r in reasons), reasons
 
 
 @pytest.mark.parametrize("causal", [True, False])
 def test_bwd_tiles_independent_of_fwd_tiles(causal):
-    """dq/dkv kernels accept their own tile sizes (the causal DMA-skip
-    tables are rebuilt at bwd granularity): grads must be identical to the
+    """dq/dkv kernels accept their own tile sizes (the causal triangle's
+    tile lists are built at bwd granularity too): grads must be identical to the
     symmetric-tile run."""
     q, k, v = _qkv(jax.random.PRNGKey(22), B=1, S=256, H=2, D=64)
 
@@ -666,35 +760,37 @@ def test_alibi_over_block_sparse_layout(causal):
 
 
 @pytest.mark.parametrize("S,bq,bk,want", [
-    (2048, 512, 512, [1, 2, 3, 4]),   # both training cells: 10 of 16 tiles
+    (2048, 512, 512, [1, 2, 3, 4]),   # both BLOOM cells: 10 of 16 tiles
     (2048, 512, 1024, [1, 1, 2, 2]),
     (2048, 1024, 512, [2, 4]),
     (512, 128, 128, [1, 2, 3, 4]),
     (512, 512, 512, [1]),
 ], ids=lambda x: "x".join(map(str, x)) if isinstance(x, list) else str(x))
-def test_causal_tables_by_hand(S, bq, bk, want):
-    """The causal layout the wrapper compacts: ``_block_visible`` over the
-    block grid is the lower block triangle, row r of the table lists its
-    ``want[r]`` visible k-blocks in order and pads by repeating the last."""
-    from deepspeed_tpu.ops.pallas.flash_attention import (
-        _block_visible,
-        _compact_rows,
-    )
+def test_causal_walk_by_hand(S, bq, bk, want):
+    """The causal layout the wrapper walks: ``_block_visible`` over the
+    block grid is the lower block triangle; the forward and dq list row r's
+    ``want[r]`` visible k-blocks in order, flagged first and last, and no
+    other step; dk/dv lists a k-block's q-blocks from the first that reaches
+    it."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
 
-    qi, ki = np.arange(S // bq)[:, None], np.arange(S // bk)[None, :]
-    layout = _block_visible(qi, ki, bq, bk).astype(np.int32)
-    cols, counts = _compact_rows(layout)
-    assert counts.tolist() == want
-    assert cols.shape == (S // bq, max(want))
-    for r, n in enumerate(want):
-        assert cols[r].tolist() == list(range(n)) + [n - 1] * (max(want) - n)
-    # the dk/dv kernel walks the transpose: k-block c is seen by every
-    # q-block from the first that reaches it
-    rows, rcounts = _compact_rows(layout.T)
-    assert rcounts.tolist() == [int(layout[:, c].sum())
-                                for c in range(S // bk)]
-    assert all(rows[c, 0] == np.argmax(layout[:, c])
-               for c in range(S // bk))
+    layout = fa.causal_layout(S, bq, bk)
+    assert layout.sum(axis=1).tolist() == want
+    word = lambda qi, ki, first, last: (
+        qi << fa._WALK_QI_SHIFT | ki << fa._WALK_KI_SHIFT | fa._WALK_LIVE
+        | last * fa._WALK_LAST | first * fa._WALK_FIRST)
+    assert fa.flat_walk(layout).tolist() == [
+        word(r, c, c == 0, c == n - 1)
+        for r, n in enumerate(want) for c in range(n)]
+    assert fa.walk_steps(layout) == (sum(want), sum(want))
+    # the dk/dv kernel walks by column: k-block c is seen by every q-block
+    # from the first that reaches it to the last
+    nq = S // bq
+    firsts = [int(np.argmax(layout[:, c])) for c in range(S // bk)]
+    assert fa.flat_walk(layout, by_col=True).tolist() == [
+        word(r, c, r == f, r == nq - 1)
+        for c, f in enumerate(firsts) for r in range(f, nq)]
+    assert fa.walk_steps(layout, by_col=True) == (sum(want), sum(want))
 
 
 @pytest.mark.parametrize("bq,bk", [(4, 4), (4, 8), (8, 4)])

@@ -528,6 +528,15 @@ def _shaped(name):
                 moe_groups_kept=4, num_heads=8, **wide)
 
 
+def _lowered_text(srv):
+    """The step's lowered text with its locations. A helper traced earlier in
+    this process is cached with ITS caller's locations (tests/
+    test_expert_bank.py's, where a worker ran that file first), so the
+    caches go before the step is lowered."""
+    jax.clear_caches()
+    return srv.lower_step().as_text(debug_info=True)
+
+
 def _served(name, **kw):
     eng = deepspeed_tpu.init_inference(
         _shaped(name), dtype=jnp.float32, max_tokens=256,
@@ -547,7 +556,7 @@ def test_banks_of_a_layer_whose_experts_are_all_touched_take_the_einsum(
     assert srv.describe()["expert_path"] == "einsum"
     assert srv.describe()["expert_path_reason"] == srv.expert_path_reason
     assert srv.metrics.snapshot()["expert_touched_kernel"] == 0.0
-    assert "expert_bank" not in srv.lower_step().as_text(debug_info=True)
+    assert "expert_bank" not in _lowered_text(srv)
 
 
 def test_banks_of_a_ling_shaped_layer_take_the_touched_kernel():
@@ -556,7 +565,7 @@ def test_banks_of_a_ling_shaped_layer_take_the_touched_kernel():
         "touched_kernel", None)
     assert srv.describe()["expert_path"] == "touched_kernel"
     assert srv.metrics.snapshot()["expert_touched_kernel"] == 1.0
-    assert "expert_bank" in srv.lower_step().as_text(debug_info=True)
+    assert "expert_bank" in _lowered_text(srv)
     st = srv.submit(Request(request_id="a", prompt=np.arange(150) % 64,
                             max_new_tokens=3))
     srv.run_until_idle()
@@ -570,7 +579,7 @@ def test_banks_sharded_over_ep_take_the_einsum_and_serve_the_same(devices8):
     srv = _served("ling", topology=topo)
     assert srv.expert_path == "einsum"
     assert "sharded over the mesh (ep 2" in srv.expert_path_reason
-    assert "expert_bank" not in srv.lower_step().as_text(debug_info=True)
+    assert "expert_bank" not in _lowered_text(srv)
     whole = _served("ling")
     tokens = []
     for s in (srv, whole):

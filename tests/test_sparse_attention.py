@@ -155,12 +155,24 @@ def test_sparse_attention_config_validation():
                              "enabled": True}}}})
 
 
-def test_compaction_tables_pad_repeat_and_counts():
-    """The DMA-skip tables: active columns ascending, padding repeats the
-    last index (consecutive equal indices → Mosaic skips the re-fetch)."""
+def _unpack(walk):
+    """A flat walk's words as (qi, ki, first, last, live) tuples."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    return [(w >> fa._WALK_QI_SHIFT,
+             (w >> fa._WALK_KI_SHIFT) & (fa._WALK_MAX_BLOCKS - 1),
+             bool(w & fa._WALK_FIRST), bool(w & fa._WALK_LAST),
+             bool(w & fa._WALK_LIVE)) for w in walk.tolist()]
+
+
+def test_flat_walk_lists_live_tiles_and_one_dead_entry_an_empty_row():
+    """The kernels' walk of a static layout: the live tiles in row-major
+    order (by column for dk/dv), each saying whether it opens and closes its
+    output block's run; a row with no live tile keeps one dead entry so its
+    output block is still written."""
     import numpy as np
 
-    from deepspeed_tpu.ops.pallas.flash_attention import _compact_rows
+    from deepspeed_tpu.ops.pallas.flash_attention import flat_walk, walk_steps
 
     layout = np.array([
         [1, 0, 1, 0],
@@ -168,30 +180,53 @@ def test_compaction_tables_pad_repeat_and_counts():
         [1, 1, 1, 1],
         [0, 1, 0, 0],
     ])
-    idx, counts = _compact_rows(layout)
-    assert counts.tolist() == [2, 0, 4, 1]
-    assert idx.shape == (4, 4)  # jmax = densest row
-    assert idx[0].tolist() == [0, 2, 2, 2]  # pad repeats last active
-    assert idx[1].tolist() == [0, 0, 0, 0]  # empty row: predicated off
-    assert idx[2].tolist() == [0, 1, 2, 3]
-    assert idx[3].tolist() == [1, 1, 1, 1]
+    F, T = False, True
+    assert _unpack(flat_walk(layout)) == [
+        (0, 0, T, F, T), (0, 2, F, T, T),
+        (1, 0, T, T, F),  # empty row: first, last, computes nothing
+        (2, 0, T, F, T), (2, 1, F, F, T), (2, 2, F, F, T), (2, 3, F, T, T),
+        (3, 1, T, T, T),
+    ]
+    assert walk_steps(layout) == (8, 7)  # 16 steps on the padded table
+    # dk/dv: a k-block's q-blocks ascending, (qi, ki) still in that order
+    assert _unpack(flat_walk(layout, by_col=True)) == [
+        (0, 0, T, F, T), (2, 0, F, T, T),
+        (2, 1, T, F, T), (3, 1, F, T, T),
+        (0, 2, T, F, T), (2, 2, F, T, T),
+        (2, 3, T, T, T),
+    ]
+    assert walk_steps(layout, by_col=True) == (7, 7)
 
 
-def test_sparse_grid_is_compacted_not_dense():
-    """The kernel grid's last dim is jmax (densest row), not nk — the
+def test_sparse_grid_is_the_live_tiles():
+    """The kernel grid's last dim IS the layout's live tiles: fewer than the
+    padded table's rows x densest row, far fewer than the dense grid — the
     structural evidence that masked tiles are skipped, not just predicated."""
-    import numpy as np
+    import jax
+    import jax.numpy as jnp
 
-    from deepspeed_tpu.ops.pallas.flash_attention import _compact_rows
+    from deepspeed_tpu.analysis.shardlint import pallas_grids
+    from deepspeed_tpu.ops.pallas.flash_attention import (
+        flash_attention,
+        walk_steps,
+    )
 
     cfg = BSLongformerSparsityConfig(block=128, num_sliding_window_blocks=3)
     S = 128 * 16
     layout = causal_trim(cfg.make_layout(S))
-    kcols, _ = _compact_rows(layout)
-    nk = S // 128
-    assert kcols.shape[1] < nk, (kcols.shape, nk)  # strictly fewer steps
-    # and the window+global pattern bounds the row density independent of S
-    assert kcols.shape[1] <= 2 + 1 + 1  # window(2 causal) + global col + row
+    nq = nk = S // 128
+    steps, live = walk_steps(layout)
+    assert steps == live == int(layout.sum())
+    jmax = int(layout.sum(axis=1).max())
+    assert jmax <= 2 + 1 + 1  # window(2 causal) + global col + row
+    assert live < nq * jmax < nq * nk, (live, jmax)
+    # and the lowered call walks exactly that list, forward and backward
+    q = jax.ShapeDtypeStruct((1, S, 2, 64), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q: flash_attention(
+        q, q, q, causal=True, block_mask=layout, block_q=128,
+        block_k=128).sum()))(q)
+    grids = [g for _, g in pallas_grids(jaxpr.jaxpr)]
+    assert grids == [(1, 2, live)] * 3, grids
 
 
 def test_traced_block_mask_falls_back_with_reason():

@@ -87,11 +87,16 @@ BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
         (1, 4096, 32, 8, 128, False, False),  # Mixtral/Llama-8B GQA widths
         (2, 2048, 8, 4, 128, False, True),    # packed sequences (segment ids)
         (4, 2048, 16, 16, 128, True, False),  # BLOOM-1b7: a chip of the dp=4 cell
+        (4, 2048, 16, 16, 64, True, False),   # bloom560m-pretrain-2k's call
+        (2, 4096, 20, 20, 256, False, False),  # glm47flash-pretrain-4k's call
     ],
     ids=["bloom560m-alibi-hd64", "gqa-h32kv8-hd128-s4096", "segment-ids",
-         "bloom1b7-alibi-hd128-b4"],
+         "bloom1b7-alibi-hd128-b4", "bloom560m-alibi-hd64-b4",
+         "glm-latent-hd256-s4096"],
 )
 def test_flash_fwd_bwd_compiles(one_chip, B, S, H, KV, hd, alibi, seg):
+    from deepspeed_tpu.analysis.shardlint import pallas_grids
+
     slopes = jnp.asarray(alibi_slopes(H), F32) if alibi else None
 
     def loss(q, k, v, seg_ids):
@@ -101,16 +106,73 @@ def test_flash_fwd_bwd_compiles(one_chip, B, S, H, KV, hd, alibi, seg):
         )
         return out.astype(F32).sum()
 
-    text = _compile(
-        jax.grad(loss, argnums=(0, 1, 2)), one_chip,
-        ((B, S, H, hd), BF16), ((B, S, KV, hd), BF16),
-        ((B, S, KV, hd), BF16), ((B, S), I32),
-    )
+    shapes = (((B, S, H, hd), BF16), ((B, S, KV, hd), BF16),
+              ((B, S, KV, hd), BF16), ((B, S), I32))
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    text = _compile(grad, one_chip, *shapes)
     # forward + dq + dk/dv kernels
     assert text.count("tpu_custom_call") >= 3, text[:2000]
+    # each over the tiles the causal call can see and no others: 10 of 16
+    # at 2,048 rows, 36 of 64 at 4,096
+    steps, live = fa.walk_steps(fa.causal_layout(S, 512, 512))
+    assert steps == live == {2048: 10, 4096: 36}[S]
+    grids = pallas_grids(jax.make_jaxpr(grad)(
+        *(jax.ShapeDtypeStruct(*sd) for sd in shapes)).jaxpr)
+    assert grids == [(k, (B, H, live)) for k in FLASH_KERNELS], grids
 
 
-# ---------------------------------------------------------------- decode
+FLASH_KERNELS = ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel")
+
+
+def test_flash_block_sparse_walk_compiles(one_chip):
+    """A block-sparse layout's walk for the described chip: a Longformer
+    window with a q-block and a k-block that nothing sees, so the kernels
+    carry the dead entries' run predicate, which the causal triangle's do
+    not."""
+    from deepspeed_tpu.analysis.shardlint import pallas_grids
+    from deepspeed_tpu.ops.sparse_attention import (
+        BSLongformerSparsityConfig,
+        causal_trim,
+    )
+
+    B, S, H, hd, blk = 2, 2048, 8, 128, 128
+    layout = causal_trim(BSLongformerSparsityConfig(
+        block=blk, num_sliding_window_blocks=3).make_layout(S))
+    layout[5, :] = 0
+    layout[:, 9] = 0
+
+    def loss(q, k, v):
+        return fa.flash_attention(
+            q, k, v, causal=True, block_mask=layout, block_q=blk,
+            block_k=blk, interpret=False).astype(F32).sum()
+
+    shapes = (((B, S, H, hd), BF16),) * 3
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    text = _compile(grad, one_chip, *shapes)
+    assert text.count("tpu_custom_call") >= 3, text[:2000]
+    rows, cols = fa.walk_steps(layout), fa.walk_steps(layout, by_col=True)
+    assert rows[0] == rows[1] + 1 and cols[0] == cols[1] + 1  # one dead each
+    grids = pallas_grids(jax.make_jaxpr(grad)(
+        *(jax.ShapeDtypeStruct(*sd) for sd in shapes)).jaxpr)
+    assert grids == [(k, (B, H, n)) for k, n in zip(
+        FLASH_KERNELS, (rows[0], rows[0], cols[0]))], grids
+
+
+def _check_flash_grids(engine, B, H, live, family, capsys):
+    """Every flash call of the engine's train step (forward, the remat's
+    second forward, dq, dk/dv) runs over (B, H, live tiles): the grid walks
+    the tiles a causal call can see and no others."""
+    from deepspeed_tpu.analysis import shardlint
+
+    grids = [kg for kg in shardlint.pallas_grids(
+        shardlint.trace_train_step(engine)[0].jaxpr)
+        if kg[0] in FLASH_KERNELS]
+    with capsys.disabled():
+        print(f"\n{family} train step: flash grids {grids}")
+    assert {k for k, _ in grids} == set(FLASH_KERNELS), grids
+    assert {g for _, g in grids} == {(B, H, live)}, grids
+
+
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
 def test_dense_decode_compiles(one_chip, int8):
     B, Smax, H, KV, hd = 8, 2048, 32, 8, 128
@@ -800,6 +862,8 @@ def test_glm_train_step_fits_the_described_chip(topo, monkeypatch, capsys):
     # the kernels pick interpret mode from the backend, the CPU here
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(rn, "_interpret", lambda: False)
+    _check_flash_grids(engine, batch, model.config.num_heads, 36,
+                       "glm-4.7-flash", capsys)
     compiled = shardlint.lower_train_step(engine).compile()
     m = compiled.memory_analysis()
     peak = (m.argument_size_in_bytes + m.temp_size_in_bytes
@@ -816,6 +880,38 @@ def test_glm_train_step_fits_the_described_chip(topo, monkeypatch, capsys):
         r'%([a-z_\-]+)[.\d]* = [^\n]*custom_call_target="tpu_custom_call"',
         text))
     assert {"latent_attention", "ragged-dot-none"} <= calls
+
+
+def test_bloom_train_step_walks_the_causal_triangle(topo, monkeypatch,
+                                                    capsys):
+    """The train step of the cell ``bloom560m-pretrain-2k`` (its
+    configuration file's model and ds_config, micro-batch 4 x 2,048) for the
+    described v5e: every flash call of a layer runs over (4, 16, 10), the
+    tiles a causal call sees of its 16, and the chip's compiler takes it."""
+    import json
+    import os
+
+    import deepspeed_tpu
+    from deepspeed_tpu.analysis import shardlint
+    from deepspeed_tpu.comm import MeshTopology, ParallelDims
+    from deepspeed_tpu.models import bloom
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "bloom-560m.json")) as f:
+        eng = json.load(f)["engine"]
+    model = bloom(eng["model"]["size"], **eng["model"]["overrides"])
+    batch = int(eng["micro_batch_per_chip"])
+    engine, *_ = deepspeed_tpu.initialize(
+        model=model, abstract_init=True,
+        topology=MeshTopology(dims=ParallelDims(), devices=[topo.devices[0]]),
+        config=dict(eng["ds_config"], train_batch_size=batch))
+    # the kernels pick interpret mode from the backend, the CPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _check_flash_grids(engine, batch, model.config.num_heads, 10,
+                       "bloom-560m", capsys)
+    text = shardlint.lower_train_step(engine).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 4
 
 
 # ----------------------------------------------------------------- norms
