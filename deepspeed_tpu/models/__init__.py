@@ -11,6 +11,7 @@ from .mixtral import mixtral, mixtral_config  # noqa: F401
 from .mellum import mellum, mellum_config  # noqa: F401
 from .deepseek import deepseek, deepseek_config  # noqa: F401
 from .glm import glm, glm_config  # noqa: F401
+from .glm5 import glm5, glm5_config  # noqa: F401
 from .minicpm import minicpm, minicpm_config  # noqa: F401
 from .ling import ling, ling_config  # noqa: F401
 from .brumby import brumby, brumby_config  # noqa: F401
@@ -23,6 +24,7 @@ MODEL_REGISTRY = {
     "mellum": mellum,
     "deepseek": deepseek,
     "glm": glm,
+    "glm5": glm5,
     "minicpm": minicpm,
     "ling": ling,
     "brumby": brumby,

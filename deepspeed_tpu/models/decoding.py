@@ -241,12 +241,58 @@ class ChunkRows:
 
 WIN = "_win"  # suffix of the window layers' pool leaves and page table
 LATENT, INDEX = "kv", "ki"  # a latent model's pools: latent rows, indexer keys
+INDEX_TAIL = "ki_tail"  # a slot's index keys of the block it has not finished
 
 
 def latent_row_width(cfg: TransformerConfig) -> int:
     """Values a row of the latent pool holds: ``cfg.latent_width`` in whole
     128-lane tiles (the chip tiles a narrower row up to that anyway)."""
     return -(-cfg.latent_width // 128) * 128
+
+
+def pool_index_keys(block: jax.Array) -> jax.Array:
+    """ONE index key of a block of tokens, from their rotated keys ``[...,
+    index_kpool, index_dim]``: their mean, float32. (The operator behind a
+    published ``index_kpool`` is this function, and no other line.)"""
+    return jnp.mean(block.astype(jnp.float32), axis=-2)
+
+
+def _pooled_index_write(cfg: TransformerConfig, pools: Cache, k_new, layer,
+                        cache_len, num_new, page_table) -> Cache:
+    """The rotated index keys ``k_new`` [B, S, Di] of a chunk a slot into the
+    pool of POOLED keys (``pools[INDEX]`` [L, P+1, page_size / kpool, Di], on
+    the latent pool's page table): every block of ``kpool`` tokens the chunk
+    touches is pooled anew from the slot's carried keys (``pools[INDEX_TAIL]``
+    [L, slots, kpool - 1, Di]: the last keys before the chunk, of which the
+    block the slot had not finished takes its ``cache_len % kpool`` last)
+    and the chunk's own, and written at its place. A block that is not
+    whole yet, or lies past the real rows, holds what no query scores (a
+    block is seen once its last token is at or before the query) and is
+    written again when it is. The keys carried on are the last ``kpool - 1``
+    of the carried and the REAL rows, so a slot with no real row keeps what
+    it held."""
+    kp = cfg.index_kpool
+    B, S, _ = k_new.shape
+    tail = pools[INDEX_TAIL]
+    prev = lax.dynamic_index_in_dim(tail, layer, 0, False)
+    # position cache_len - (kp - 1) + e of the slot at row e
+    ext = jnp.concatenate([prev, k_new.astype(prev.dtype)], axis=1)
+    cache_len = cache_len.astype(jnp.int32)
+    nb = (S + kp - 2) // kp + 1  # the blocks a chunk can touch
+    at = ((kp - 1 - cache_len % kp)[:, None, None]
+          + kp * jnp.arange(nb, dtype=jnp.int32)[None, :, None]
+          + jnp.arange(kp, dtype=jnp.int32)[None, None, :])
+    take = lambda rows_at: jnp.take_along_axis(
+        ext, jnp.minimum(rows_at, S + kp - 2).reshape(B, -1, 1), axis=1)
+    pooled = pool_index_keys(take(at).reshape(B, nb, kp, -1))
+    carried = take(num_new.astype(jnp.int32)[:, None]
+                   + jnp.arange(kp - 1, dtype=jnp.int32)[None, :])
+    return {
+        **pools,
+        INDEX: _paged_write(pools[INDEX], pooled.astype(pools[INDEX].dtype),
+                            layer, cache_len // kp, page_table),
+        INDEX_TAIL: lax.dynamic_update_index_in_dim(tail, carried, layer, 0),
+    }
 
 
 def init_paged_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
@@ -270,10 +316,13 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
     A model that names its layers (``cfg.mixer_types``) keeps pages
     for the layers whose kind keeps any (``MIXER_KINDS``: ``k``/``v`` and a
     compressed key a page, ``kc``, for sparse layers; latent rows ``kv`` for
-    latent layers), and for its state layers leaves that are indexed by
-    SLOT, not through the page table: ``state`` [L_state, max_slots, heads,
-    hd, hd] float32 and, for kda layers, ``conv`` [L_kda, max_slots,
-    conv_kernel - 1, 3 x heads x hd], the short convolution's last rows."""
+    latent and mla layers, and an indexer's keys ``ki``, one a block of
+    ``index_kpool`` tokens), and for its state layers leaves that are indexed
+    by SLOT, not through the page table: ``state`` [L_state, max_slots,
+    heads, hd, hd] float32 and, for kda layers, ``conv`` [L_kda, max_slots,
+    conv_kernel - 1, 3 x heads x hd], the short convolution's last rows;
+    for mla layers under pooled index keys ``ki_tail`` [L_mla, max_slots,
+    index_kpool - 1, index_dim], the keys of the block not yet whole."""
     if cfg.mixer_types:
         from ..config import DeepSpeedConfigError
         from .mixers import family
@@ -718,7 +767,11 @@ def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
     weighted sum of latents. With an indexer (``cfg.index_topk``) each
     token also caches an index key (``pools[INDEX]``), every cached token at
     or before a query is scored from it, and the query attends its
-    ``index_topk`` best alone. With a head-wise output gate (``p["wgate"]``,
+    ``index_topk`` best alone; with ``cfg.index_kpool`` > 1 the pool holds
+    one key a BLOCK of that many tokens (:func:`_pooled_index_write`), a
+    query scores the blocks whose last token is at or before it and attends
+    the tokens of its ``index_topk`` best blocks and the tokens after its
+    last whole block. With a head-wise output gate (``p["wgate"]``,
     ``d -> heads``: a latent layer of models/ling.py) ``y = W_o (sigmoid(x
     W_g)_h * o)``.
 
@@ -755,8 +808,10 @@ def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
     scale = (nope + rd) ** -0.5 * cfg.attn_scale_mult
 
     q_idx = w_idx = None
+    kpool = cfg.index_kpool
     if cfg.index_topk:
         ix, Hi, Di = p["idx"], cfg.index_heads, cfg.index_dim
+        rd = cfg.index_rope_dim or rd  # the indexer's own rotated values
         q_idx = (c_q @ ix["wq_b"]).reshape(B, S, Hi, Di)
         from ..ops.normalization import layernorm
 
@@ -772,9 +827,15 @@ def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
         w_idx = jnp.einsum(
             "bsd,dh->bsh", x.astype(jnp.float32),
             ix["w_proj"].astype(jnp.float32)) * (Hi ** -0.5 * Di ** -0.5)
-        pools[INDEX] = _paged_write(
-            pools[INDEX], rows.unpack(k_idx.astype(pools[INDEX].dtype)),
-            layer, cache_len, page_table)
+        k_idx = rows.unpack(k_idx.astype(pools[INDEX].dtype))
+        if kpool > 1:
+            pools = _pooled_index_write(
+                cfg, pools, k_idx, layer, jnp.broadcast_to(
+                    jnp.asarray(cache_len, jnp.int32), (rows.B,)),
+                num_new, page_table)
+        else:
+            pools[INDEX] = _paged_write(pools[INDEX], k_idx, layer,
+                                        cache_len, page_table)
         q_idx, w_idx = rows.unpack(q_idx), rows.unpack(w_idx)
 
     from ..ops.attention import _resolve
@@ -790,7 +851,7 @@ def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
         out, why_dense = sla.latent_sparse_attention(
             q_abs, q_idx, w_idx, pools[LATENT], pools[INDEX], cache_len,
             page_table, layer=layer, topk=cfg.index_topk, scale=scale,
-            v_width=kl, num_new=num_new)
+            v_width=kl, num_new=num_new, kpool=kpool)
     if out is not None:
         _note_attention_path(
             "latent_sparse_kernel" if cfg.index_topk else "latent_kernel",
@@ -805,7 +866,12 @@ def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
         kv_view = view(LATENT)
         chosen = jnp.arange(kv_view.shape[1])[None, None, :] <= (
             rows.slot_positions[..., None])
-        if cfg.index_topk:
+        if cfg.index_topk and kpool > 1:
+            chosen = sla.tokens_of_blocks(sla.dense_selection(
+                sla.dense_index_scores(q_idx, w_idx, view(INDEX)),
+                sla.last_block(rows.slot_positions, kpool), cfg.index_topk),
+                rows.slot_positions, kpool)
+        elif cfg.index_topk:
             chosen = sla.dense_selection(
                 sla.dense_index_scores(q_idx, w_idx, view(INDEX)),
                 rows.slot_positions, cfg.index_topk)
@@ -939,6 +1005,8 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
     if cfg.scale_emb != 1.0:  # muP
         x = x * jnp.asarray(cfg.scale_emb, x.dtype)
     x = constrain(x, ("dp", "fsdp"), None, None)
+    if cfg.hc_mult:  # hyper-connections: every stream begins as the embedding
+        x = jnp.broadcast_to(x[None], (cfg.hc_mult, *x.shape))
     # the layers: runs of a period of (mixer, MLP) kinds, a scan each over
     # the same carry, the hidden rows and the cache, every leaf whole and in
     # the layout it came in (models/mixers.py)
@@ -948,6 +1016,8 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
         cfg, {k: cast(params[k]) for k in stacks_of(cfg)}, x, rows,
         dict(cache), cache_len, page_table, num_new, token_valid=token_valid,
         page_table_win=page_table_win)
+    if cfg.hc_mult:  # and the head reads their sum
+        x = jnp.sum(x.astype(jnp.float32), axis=0).astype(x.dtype)
     x = rows.unpack(x) if logit_rows is None else rows.take(x, logit_rows)
     x = _norm(cfg, cast(params["final_norm"]), x)
     if cfg.dim_model_base:  # muP

@@ -26,6 +26,16 @@ page.
 (one ``W_q``) and without an indexer, over the latent pool on the one page
 table, with the same head-wise gate.
 
+This module also owns the stacks and pools of a model that runs its kda
+layers beside ``mla`` layers (models/glm5.py): latent attention through a
+query latent, without a gate and, with ``index_topk``, under an indexer
+whose keys lie in a pool of their own (``ki``; one a block of
+``index_kpool`` tokens, the unfinished block's keys a slot leaf,
+``ki_tail``). ``kda_gate_rank`` > 0 is Kimi Linear's published form of the
+kda projections: the decay ``x W_a_down W_a_up`` and an output gate a CHANNEL
+``sigmoid(x W_g_down W_g_up)``. ``hc_mult`` > 1 adds each half-layer's
+hyper-connection leaves (``hc``, models/mixers.py) beside its norm.
+
 The serving step is the only forward (``models/transformer._refuse_uncached``).
 """
 
@@ -40,14 +50,15 @@ from jax import lax
 from .transformer import (Params, TransformerConfig, TransformerModel,
                           _latent_attn_params, _rms_last)
 
-KDA, LATENT_KIND = "kda", "latent"
-STACK = {KDA: "kda_layers", LATENT_KIND: "latent_layers"}
+KDA, LATENT_KIND, MLA = "kda", "latent", "mla"
+STACK = {KDA: "kda_layers", LATENT_KIND: "latent_layers", MLA: "mla_layers"}
 MLP_STACK = {"dense": "lead_layers", "routed": "layers"}
 STATE, CONV = "state", "conv"  # the leaves a slot keeps for its kda layers
 L2_EPS = 1e-6
 
 # inclusionAI/Ling-3.0-flash config.json: the clamp of the SwiGLU's inputs a
-# layer (0 = none); this program builds no clamp
+# layer (0 = none). ``swiglu_limit`` is one limit a model, so a cut keeps
+# layers of one limit
 _FLASH_EXPERT_LIMIT = (0,) * 35 + (4,) * 7
 _FLASH_SHARED_LIMIT = (0,) * 34 + (5,) * 6 + (7,) * 2
 
@@ -77,21 +88,23 @@ def ling_config(size: str = "ling-3.0-flash", layer_ids=None,
     """``layer_ids``: the published layers kept, in order (default all): a
     cut keeps each layer's own published index, which decides its mixer
     (every ``group``-th is latent) and its MLP (the first ``first_dense``
-    are dense). A layer whose published SwiGLU clamp is not 0 is refused:
-    the clamp is not built, and ignoring it would be another model."""
+    are dense). The kept layers share one SwiGLU clamp (``swiglu_limit``,
+    one a model): a cut across layers the release clamps differently, or
+    whose experts and shared expert it clamps differently, is refused."""
     base = dict(_LING_SIZES[size])
     depth, group = base.pop("published_depth"), base.pop("group")
     first_dense = base.pop("first_dense")
     limits = (base.pop("expert_limit"), base.pop("shared_limit"))
     ids = tuple(range(depth)) if layer_ids is None else tuple(
         int(i) for i in layer_ids)
-    clamped = [i for i in ids if limits[0][i] or limits[1][i]]
-    if clamped:
+    clamps = sorted({limit[i] for i in ids for limit in limits})
+    if len(clamps) > 1:
         raise ValueError(
-            f"layer_ids keeps published layers {clamped}, whose SwiGLU "
-            "inputs the release clamps (expert_swiglu_limit_list / "
-            "share_expert_swiglu_limit_list not 0): the clamp is not built, "
-            f"so {size} is served in a cut without them")
+            f"layer_ids keeps published layers {list(ids)}, whose SwiGLU "
+            f"inputs the release clamps at {clamps} "
+            "(expert_swiglu_limit_list / share_expert_swiglu_limit_list): "
+            f"swiglu_limit is one limit a model, so {size} is served in a "
+            "cut whose layers share one")
     if list(ids) != sorted(set(ids)):
         raise ValueError(f"layer_ids {ids} is not in published order")
     lead = sum(i < first_dense for i in ids)
@@ -104,7 +117,8 @@ def ling_config(size: str = "ling-3.0-flash", layer_ids=None,
         pos_embedding="rope", rope_theta=6000000.0, norm="rmsnorm",
         norm_eps=1e-6, activation="swiglu", use_bias=False,
         tie_embeddings=False, moe_gate="sigmoid_groups",
-        moe_routed_scale=2.5, name=size,
+        moe_routed_scale=2.5, swiglu_limit=float(clamps[0]) if clamps else 0.0,
+        name=size,
     )
     base.update(overrides)
     return TransformerConfig(**base)
@@ -118,15 +132,31 @@ def ling(size: str = "ling-3.0-flash", **overrides) -> TransformerModel:
 def mixer_params(cfg: TransformerConfig, kind: str) -> int:
     d, H = cfg.hidden_size, cfg.num_heads
     if kind == KDA:
-        wide = H * cfg.hd
-        # wq wk wv walpha wo; wbeta wgate; taps; A_log, dt_bias, o_norm
-        return (5 * d * wide + 2 * d * H + 3 * wide * cfg.conv_kernel
-                + H + wide + cfg.hd)
-    kl = cfg.kv_latent_dim
-    return (d * H * (cfg.qk_nope_dim + cfg.qk_rope_dim)
-            + d * cfg.latent_width + kl
-            + kl * H * (cfg.qk_nope_dim + cfg.v_head_dim)
-            + H * cfg.v_head_dim * d + d * H)
+        wide, r = H * cfg.hd, cfg.kda_gate_rank
+        # wq wk wv wo; wbeta; taps; A_log, dt_bias, o_norm
+        fixed = (4 * d * wide + d * H + 3 * wide * cfg.conv_kernel
+                 + H + wide + cfg.hd)
+        # the decay and the output gate: low-rank both, or walpha and wgate
+        return fixed + (2 * r * (d + wide) if r else d * wide + d * H)
+    kl, ql = cfg.kv_latent_dim, cfg.q_latent_dim
+    qk = cfg.qk_nope_dim + cfg.qk_rope_dim
+    shared = (d * cfg.latent_width + kl
+              + kl * H * (cfg.qk_nope_dim + cfg.v_head_dim)
+              + H * cfg.v_head_dim * d)
+    if kind == LATENT_KIND:  # one W_q, a gate a head
+        return shared + d * H * qk + d * H
+    indexer = (ql * cfg.index_heads * cfg.index_dim + d * cfg.index_dim
+               + 2 * cfg.index_dim + d * cfg.index_heads
+               ) if cfg.index_topk else 0
+    return shared + d * ql + ql + ql * H * qk + indexer
+
+
+def hyper_params(cfg: TransformerConfig) -> int:
+    """Leaves of one half-layer's hyper-connection: the projection of the
+    streams to ``n`` + ``n`` + ``n x n`` mixing values, its three gains and
+    its biases (0 without ``hc_mult``)."""
+    n = cfg.hc_mult
+    return (n * cfg.hidden_size + 1) * (n * n + 2 * n) + 3 if n else 0
 
 
 def num_params(cfg: TransformerConfig) -> int:
@@ -136,7 +166,8 @@ def num_params(cfg: TransformerConfig) -> int:
     routed = cfg.num_layers * (
         d * R + R + 3 * d * cfg.ffn * cfg.num_experts
         + 3 * d * cfg.moe_shared_width + d)
-    return mixers + lead + routed + 2 * cfg.vocab_size * d + d
+    return (mixers + lead + routed + 2 * cfg.total_layers * hyper_params(cfg)
+            + 2 * cfg.vocab_size * d + d)
 
 
 def init(cfg: TransformerConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
@@ -170,10 +201,19 @@ def init(cfg: TransformerConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
     Lk, Ll = cfg.kind_count(KDA), cfg.kind_count(LATENT_KIND)
     if Lk:
         k = jax.random.split(keys[2], 11)
+        r = cfg.kda_gate_rank
+        if r:  # Kimi Linear's: the decay and a gate a channel, low-rank
+            ka, kg = jax.random.split(k[3]), jax.random.split(k[5])
+            decay_gate = {
+                "wa_down": nrm(ka[0], Lk, d, r), "wa_up": nrm(ka[1], Lk, r, wide),
+                "wg_down": nrm(kg[0], Lk, d, r), "wg_up": nrm(kg[1], Lk, r, wide)}
+        else:
+            decay_gate = {"walpha": nrm(k[3], Lk, d, wide),
+                          "wgate": nrm(k[5], Lk, d, H)}
         params[STACK[KDA]] = {"ln1": ones(Lk, d), "attn": {
             "wq": nrm(k[0], Lk, d, wide), "wk": nrm(k[1], Lk, d, wide),
-            "wv": nrm(k[2], Lk, d, wide), "walpha": nrm(k[3], Lk, d, wide),
-            "wbeta": nrm(k[4], Lk, d, H), "wgate": nrm(k[5], Lk, d, H),
+            "wv": nrm(k[2], Lk, d, wide), **decay_gate,
+            "wbeta": nrm(k[4], Lk, d, H),
             "wo": nrm(k[6], Lk, wide, d, scale=out_std),
             "conv": nrm(k[7], Lk, K, 3 * wide, scale=1.0 / K),
             "A_log": jnp.log(jax.random.uniform(
@@ -186,6 +226,11 @@ def init(cfg: TransformerConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
         attn = _latent_attn_params(cfg, nrm, k, Ll, out_std, dtype)
         attn["wgate"] = nrm(k[12], Ll, d, H)
         params[STACK[LATENT_KIND]] = {"ln1": ones(Ll, d), "attn": attn}
+    Lm = cfg.kind_count(MLA)
+    if Lm:
+        k = jax.random.split(jax.random.fold_in(keys[3], 1), 13)
+        params[STACK[MLA]] = {"ln1": ones(Lm, d), "attn": _latent_attn_params(
+            cfg, nrm, k, Lm, out_std, dtype)}
     if cfg.lead_dense_layers:
         Ld = cfg.lead_dense_layers
         params[MLP_STACK["dense"]] = {"ln2": ones(Ld, d), "mlp": swiglu(
@@ -200,6 +245,19 @@ def init(cfg: TransformerConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
             "wo": nrm(k[4], L, E, cfg.ffn, d, scale=out_std),
             "shared": swiglu(k[5:8], L, cfg.moe_shared_width),
         }}
+    if cfg.hc_mult:  # a hyper-connection round every half-layer
+        n = cfg.hc_mult
+        mixes = n * n + 2 * n
+        for i, name in enumerate(dict.fromkeys(
+                (*(STACK[kind] for kind in cfg.mixer_types),
+                 *(s for s in MLP_STACK.values() if s in params)))):
+            L = jax.tree.leaves(params[name])[0].shape[0]
+            k = jax.random.split(jax.random.fold_in(rng, 100 + i), 2)
+            # gains of one and biases N(0, 1/2): the mixes depend on the
+            # rows and differ a stream from the first layer on
+            params[name]["hc"] = {
+                "w": nrm(k[0], L, n * d, mixes), "gain": ones(L, 3),
+                "bias": nrm(k[1], L, mixes, scale=0.5)}
     return params
 
 
@@ -209,22 +267,39 @@ def slot_leaves(cfg: TransformerConfig, max_slots: int, dtype) -> dict:
     ``conv_kernel - 1`` pre-convolution rows of q~, k~, v~ side by side, in
     the type they were computed in (a carried row is the row itself)."""
     Lk, H, hd = cfg.kind_count(KDA), cfg.num_heads, cfg.hd
-    return {
+    leaves = {
         STATE: jax.ShapeDtypeStruct((Lk, max_slots, H, hd, hd), jnp.float32),
         CONV: jax.ShapeDtypeStruct(
             (Lk, max_slots, cfg.conv_kernel - 1, 3 * H * hd), dtype),
     }
+    if cfg.slot_leaves_of(MLA) and cfg.kind_count(MLA):
+        from .decoding import INDEX_TAIL
+
+        # the rotated index keys of the block a slot has not finished
+        leaves[INDEX_TAIL] = jax.ShapeDtypeStruct(
+            (cfg.kind_count(MLA), max_slots, cfg.index_kpool - 1,
+             cfg.index_dim), dtype)
+    return leaves
 
 
 def init_pools(cfg: TransformerConfig, num_pages: int, page_size: int,
                max_slots: int, dtype) -> dict:
-    """The arena: the latent layers' rows on the one page table, and the
-    kda layers' state and convolution rows by SLOT."""
-    from .decoding import LATENT, latent_row_width
+    """The arena: the latent (or mla) layers' rows on the one page table,
+    an indexer's keys beside them (one a block of ``index_kpool`` tokens, so
+    ``page_size / index_kpool`` a page), and the slot leaves by SLOT."""
+    from .decoding import INDEX, LATENT, latent_row_width
 
+    paged = cfg.kind_count(LATENT_KIND) + cfg.kind_count(MLA)
+    P1 = int(num_pages) + 1
     pools = {LATENT: jnp.zeros(
-        (cfg.kind_count(LATENT_KIND), int(num_pages) + 1, page_size,
-         latent_row_width(cfg)), dtype)}
+        (paged, P1, page_size, latent_row_width(cfg)), dtype)}
+    if cfg.index_topk:
+        if page_size % cfg.index_kpool:
+            raise ValueError(
+                f"a page of {page_size} tokens is not whole blocks of "
+                f"index_kpool {cfg.index_kpool}")
+        pools[INDEX] = jnp.zeros(
+            (paged, P1, page_size // cfg.index_kpool, cfg.index_dim), dtype)
     pools.update({k: jnp.zeros(v.shape, v.dtype) for k, v in
                   slot_leaves(cfg, max_slots, dtype).items()})
     return pools
@@ -285,8 +360,10 @@ def kda_mixer(cfg, p, x, rows, pools, index, cache_len, num_new, note):
         jnp.sum(t * t, axis=-1, keepdims=True) + L2_EPS)
     q, k = unit(q), unit(k)
     pace = jnp.exp(p["A_log"].astype(jnp.float32))[:, None]  # [H, 1]
+    low_rank = "wa_down" in p  # Kimi Linear's decay and gate (the tree says)
+    alpha = (x @ p["wa_down"]) @ p["wa_up"] if low_rank else x @ p["walpha"]
     g = cfg.kda_lower_bound * jax.nn.sigmoid(pace * (
-        (x @ p["walpha"]).astype(jnp.float32)
+        alpha.astype(jnp.float32)
         + p["dt_bias"].astype(jnp.float32)).reshape(Bc, Sc, H, hd))
     beta = jax.nn.sigmoid((x @ p["wbeta"]).astype(jnp.float32))
     q, k, v = (rows.unpack(t.astype(x.dtype)) for t in (q, k, v))
@@ -306,6 +383,10 @@ def kda_mixer(cfg, p, x, rows, pools, index, cache_len, num_new, note):
         state = lax.dynamic_update_index_in_dim(pools[STATE], after, index, 0)
     o = _rms_last(rows.pack(o.astype(x.dtype)), p["o_norm"]["scale"],
                   cfg.norm_eps)
-    gate = jax.nn.sigmoid((x @ p["wgate"]).astype(jnp.float32))[..., None]
+    if low_rank:  # a gate a channel
+        gate = jax.nn.sigmoid(((x @ p["wg_down"]) @ p["wg_up"]).astype(
+            jnp.float32)).reshape(Bc, Sc, H, hd)
+    else:
+        gate = jax.nn.sigmoid((x @ p["wgate"]).astype(jnp.float32))[..., None]
     out = (gate * o.astype(jnp.float32)).astype(x.dtype).reshape(Bc, Sc, wide)
     return out @ p["wo"], {**pools, STATE: state, CONV: conv}

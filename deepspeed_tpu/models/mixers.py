@@ -2,7 +2,8 @@
 
 Each layer has a MIXER kind (``transformer.MIXER_KINDS``: "full" | "window" |
 "mla" of models/decoding.py, "sparse" | "lightning" of models/minicpm.py,
-"kda" | "latent" of models/ling.py, "retention" of models/brumby.py) and,
+"kda" | "latent" of models/ling.py, which also gives "mla" a stack beside
+them, "retention" of models/brumby.py) and,
 independently, an MLP kind ("dense": a SwiGLU or GELU MLP of the layer's own
 width, the leading dense layers of a routed model among them; "routed": an
 expert layer or one member's share of one,
@@ -23,6 +24,13 @@ The module that owns the kinds ``mixer_types`` names (``family(cfg)``) gives
 ``STACK`` (mixer kind -> stack), ``MLP_STACK`` (MLP kind -> stack, or None:
 the MLP lies in its mixer's stack, at the mixer's index), ``init``,
 ``num_params``, ``init_pools`` and ``slot_leaves``.
+
+The residual path is ``h = h + f(norm(h))`` round each half-layer, or, with
+``cfg.hc_mult`` streams, a hyper-connection (:func:`hyper_pre`,
+:func:`hyper_post`): the rows are then ``[n, 1, T, d]``, the streams LEADING
+(a tile of the chip is the last two axes: a stream axis inside them would
+pad 4 to 8 or 16; leading, every stream is the ``[T, d]`` block every other
+line of the walk works on).
 """
 
 from __future__ import annotations
@@ -157,6 +165,61 @@ def walk_runs(cfg: TransformerConfig, by_kind: bool = True) -> List[Run]:
     return out
 
 
+def sinkhorn(m, iters: int):
+    """``exp(m)`` [n, n, ...] made doubly stochastic by ``iters`` rounds of
+    (rows to sum 1, then columns to sum 1) over its two LEADING axes,
+    float32. The sums are plain adds of ``n`` slices, so the rounds are one
+    elementwise fusion over whatever lies behind the two axes."""
+    n = m.shape[0]
+    m = jnp.exp(m - jnp.max(m, axis=(0, 1), keepdims=True))
+    for _ in range(iters):
+        m = m / sum(m[:, j] for j in range(n))[:, None]
+        m = m / sum(m[i] for i in range(n))[None]
+    return m
+
+
+def hyper_pre(cfg: TransformerConfig, p: Params, h):
+    """The reading half of a hyper-connection (manifold-constrained,
+    arXiv:2512.24880) over the streams ``h`` [n, B, S, d]: (the sub-layer's
+    input ``u`` [B, S, d], a mix of the streams; ``post`` [n, B, S] and
+    ``res`` [n, n, B, S] float32, what :func:`hyper_post` writes back with).
+    A row's mixing values are a projection ``p["w"]`` of its ``n d`` values
+    RMS-normed as one vector (eps ``hc_eps``, no learned scale: the
+    projection has every weight a scale would have), times a gain a group
+    plus a bias: ``pre = sigmoid(.)`` [n], ``post = 2 sigmoid(.)`` [n],
+    ``res`` = :func:`sinkhorn` of the [n, n] rest (``res[i, j]``: how much
+    of stream ``j`` stream ``i`` keeps). The norm is a scalar a row, so it
+    is applied to the projected values, not the streams."""
+    n, d = cfg.hc_mult, cfg.hidden_size
+    w = p["w"].reshape(n, d, n * n + 2 * n)
+    raw = jnp.einsum("nbsd,ndk->kbs", h, w,
+                     preferred_element_type=jnp.float32)
+    h32 = h.astype(jnp.float32)
+    raw = raw * lax.rsqrt(
+        jnp.mean(h32 * h32, axis=(0, 3)) + cfg.hc_eps)[None]
+    gain = p["gain"]["scale"].astype(jnp.float32)
+    bias = p["bias"].astype(jnp.float32)[:, None, None]
+    raw = raw * jnp.repeat(gain, np.asarray([n, n, n * n]),
+                           total_repeat_length=n * n + 2 * n
+                           )[:, None, None] + bias
+    pre = jax.nn.sigmoid(raw[:n])
+    post = 2.0 * jax.nn.sigmoid(raw[n:2 * n])
+    res = sinkhorn(raw[2 * n:].reshape(n, n, *raw.shape[1:]),
+                   cfg.hc_sinkhorn_iters)
+    u = sum(pre[j][..., None] * h32[j] for j in range(n)).astype(h.dtype)
+    return u, post, res
+
+
+def hyper_post(h, y, post, res):
+    """The writing half: stream ``i`` becomes ``sum_j res[i, j] h[j] +
+    post[i] y`` (``y`` [B, S, d] the sub-layer's output), float32 inside."""
+    n = h.shape[0]
+    h32, y32 = h.astype(jnp.float32), y.astype(jnp.float32)
+    return jnp.stack([
+        sum(res[i, j][..., None] * h32[j] for j in range(n))
+        + post[i][..., None] * y32 for i in range(n)]).astype(h.dtype)
+
+
 def _mix(kind: str, cfg, p, x, rows, pools, index, layer_id, cache_len,
          num_new, tables, note):
     """The mixer of one layer over the normed rows ``x``: (out, in x's
@@ -195,18 +258,20 @@ def _mix(kind: str, cfg, p, x, rows, pools, index, layer_id, cache_len,
             cache_len, *(pools[n] for n in names[2:]),
             page_table=tables[sfx], num_new=num_new, kind=kind)
         return a, {**pools, **dict(zip(names, written))}
-    # "latent" | "mla" (whose path is noted as a "full" layer's: the one
-    # name the engine of a model without mixer_types reads)
+    # "latent" | "mla" (whose path a model without mixer_types notes as a
+    # "full" layer's: the one name its engine reads)
     return decoding._latent_cached_attention(
         cfg, p, x, rows, index, pools, cache_len, tables[""],
-        num_new=num_new, kind="full" if kind == "mla" else kind)
+        num_new=num_new,
+        kind="full" if kind == "mla" and not cfg.mixer_types else kind)
 
 
 def cached_layers(cfg: TransformerConfig, params: Params, x, rows, pools,
                   cache_len, page_table, num_new, token_valid=None,
                   page_table_win=None):
     """Every layer in published order over the rows ``x`` that ``rows``
-    (``decoding.ChunkRows``) computes, [B,S,d] or [1,T,d] packed: (hidden in
+    (``decoding.ChunkRows``) computes, [B,S,d] or [1,T,d] packed (with
+    ``cfg.hc_mult`` the streams of them, [n,B,S,d]): (hidden in
     the same layout, the pools, the routed layers' stats summed over the
     step or None). ``params``: the stacks, already in the compute type.
     ``page_table_win``: the table of the window layers' pool, where a paged
@@ -223,6 +288,19 @@ def cached_layers(cfg: TransformerConfig, params: Params, x, rows, pools,
         cfg.scale_depth != 1.0) else None
     # the most real tokens a step holds: what an expert's capacity is of
     budget = rows.count if (rows.packed or token_valid is None) else rows.S
+    if cfg.hc_mult:
+        def residual(h, hc, ln, f):  # f: normed rows -> (out, what it keeps)
+            u, post, res = hyper_pre(cfg, hc, h)
+            y, kept = f(_norm(cfg, ln, u))
+            return hyper_post(h, y, post, res), kept
+
+        shard = lambda h: constrain(h, None, ("dp", "fsdp"), None, None)
+    else:
+        def residual(h, hc, ln, f):
+            y, kept = f(_norm(cfg, ln, h))
+            return h + (y if branch is None else branch * y), kept
+
+        shard = lambda h: constrain(h, ("dp", "fsdp"), None, None)
     stats = []
     for run in walk_runs(cfg, by_kind=WIN in tables):
         mix_stack, mlp_stack = params[run.stack], params[run.mlp_stack]
@@ -239,31 +317,33 @@ def cached_layers(cfg: TransformerConfig, params: Params, x, rows, pools,
                 index, mlp_index, pool_index, layer_id = (
                     s[j] for s in scanned)
                 layer = at(mix_stack, index)
-                a, pools = _mix(kind, cfg, layer["attn"],
-                                _norm(cfg, layer["ln1"], h), rows, pools,
-                                pool_index, layer_id, cache_len, num_new,
-                                tables, note)
-                h = h + (a if branch is None else branch * a)
+                h, pools = residual(
+                    h, layer.get("hc"), layer["ln1"],
+                    lambda x, layer=layer, pools=pools: _mix(
+                        kind, cfg, layer["attn"], x, rows, pools, pool_index,
+                        layer_id, cache_len, num_new, tables, note))
                 if mlp_stack is not mix_stack:
                     layer = at(mlp_stack, mlp_index)
-                normed = _norm(cfg, layer["ln2"], h)
-                if mlp == ROUTED:
+
+                def mlp_of(normed, layer=layer):
+                    if mlp != ROUTED:
+                        return _mlp(cfg, layer["mlp"], normed, rng=None,
+                                    train=False, dense=True)[0], None
                     from ..moe.sharded_moe import moe_serving_mlp
 
                     # capacity from the STATIC budget, padded and idle rows
                     # to the null expert; the banks go whole, the layer's
                     # index beside them: a kernel over them takes no slice
                     # (which would be a copy)
-                    m, one = moe_serving_mlp(
+                    return moe_serving_mlp(
                         cfg, layer["mlp"], normed, token_valid=token_valid,
                         budget_tokens=budget,
                         stack=(mlp_stack["mlp"], mlp_index))
+
+                h, one = residual(h, layer.get("hc"), layer["ln2"], mlp_of)
+                if one is not None:
                     lstats.append(one)
-                else:
-                    m, _ = _mlp(cfg, layer["mlp"], normed, rng=None,
-                                train=False, dense=True)
-                h = h + (m if branch is None else branch * m)
-                h = constrain(h, ("dp", "fsdp"), None, None)
+                h = shard(h)
             return (h, pools), (jax.tree.map(
                 lambda *t: jnp.stack(t), *lstats) if lstats else None)
 

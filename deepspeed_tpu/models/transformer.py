@@ -58,8 +58,11 @@ MIXER_KINDS: Dict[str, MixerKind] = {
     "window": MixerKind((), ("k", "v", "k_scale", "v_scale"), "decoding"),
     # latent attention as every layer of a ``kv_latent_dim`` model has it:
     # over every key of the latent pool or, with an indexer
-    # (``index_topk``), over the best by its keys
-    "mla": MixerKind((), ("kv", "ki"), "decoding"),
+    # (``index_topk``), over the best by its keys; named by ``mixer_types``
+    # it lies in the stack models/ling.py gives it and, with ``index_kpool``
+    # > 1, keeps the unfinished block's index keys a slot
+    "mla": MixerKind(("ki_tail",), ("kv", "ki"), "decoding",
+                     ("kv_latent_dim", "the width of its cached latent")),
     # grouped-query attention over a learned selection of blocks of pages
     "sparse": MixerKind((), ("k", "v", "kc"), "minicpm",
                         ("block_sparse", "the geometry of its selection")),
@@ -172,6 +175,15 @@ class TransformerConfig:
     index_heads: int = 0
     index_dim: int = 0
     index_topk: int = 0
+    # ``index_rope_dim``: the leading values of an index query and key that
+    # are rotated (0 = ``qk_rope_dim``, the attention's own). ``index_kpool``
+    # > 1: ONE index key a block of that many tokens, the mean of their
+    # rotated keys (``decoding.pool_index_keys``); a query scores the blocks
+    # whose last token is at or before it, attends the tokens of its
+    # ``index_topk`` best BLOCKS and always the tokens after its last whole
+    # block.
+    index_rope_dim: int = 0
+    index_kpool: int = 1
     # Router: "softmax" (top-k of a softmax, with a capacity), or
     # "sigmoid_groups": sigmoid scores plus a learned selection bias, the
     # ``moe_groups_kept`` best of ``moe_groups`` groups by the sum of their
@@ -228,7 +240,22 @@ class TransformerConfig:
     block_sparse: Optional[Any] = None
     conv_kernel: int = 4
     kda_lower_bound: float = -5.0
+    # Kimi Linear's published kda projections (0 = Ling's: a full-rank decay
+    # and one output gate a head): the decay through ``kda_gate_rank``
+    # values, and an output gate a CHANNEL through as many
+    kda_gate_rank: int = 0
     retention_eps: float = 1e-6
+    # Hyper-connections (``hc_mult`` > 1, manifold-constrained: models/
+    # mixers.py ``hyper_pre`` / ``hyper_post``): that many residual streams,
+    # each sub-layer reading a learned mix of them and writing back through
+    # a doubly-stochastic mix (``hc_sinkhorn_iters`` Sinkhorn rounds) of the
+    # streams; 0 = the one stream and ``h = h + f(norm(h))``.
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    # SwiGLU inputs clamped: ``silu(min(gate, limit)) * clip(up, -limit,
+    # limit)`` in dense, shared and routed MLPs alike (0 = no clamp)
+    swiglu_limit: float = 0.0
     scale_emb: float = 1.0
     scale_depth: float = 1.0
     dim_model_base: int = 0
@@ -250,7 +277,7 @@ class TransformerConfig:
         if self.moe_gate not in ("softmax", "sigmoid_groups"):
             raise ValueError(
                 f"moe_gate {self.moe_gate!r} (softmax or sigmoid_groups)")
-        if self.index_topk and not (self.is_latent and self.q_latent_dim):
+        if self.index_topk and not (self.kv_latent_dim and self.q_latent_dim):
             raise ValueError("the indexer scores a latent cache's tokens "
                              "from the query latent: index_topk needs "
                              "kv_latent_dim and q_latent_dim")
@@ -260,6 +287,16 @@ class TransformerConfig:
             raise ValueError(
                 "latent attention: head_dim is qk_nope_dim + qk_rope_dim, "
                 "num_kv_heads 1 (one latent a token), one layer kind")
+        if self.index_kpool > 1 and not (
+                self.index_topk and "mla" in self.mixer_types):
+            raise ValueError(
+                "index_kpool pools the keys of an indexer (index_topk) whose "
+                "layers mixer_types names mla: the unfinished block's keys "
+                "are a slot's leaf")
+        if self.hc_mult == 1 or (self.hc_mult and not self.mixer_types):
+            raise ValueError(
+                f"hc_mult {self.hc_mult}: several residual streams round "
+                "the layers mixer_types names, or 0 for the one stream")
         if self.mtp_layers not in (0, 1):
             raise ValueError(
                 f"mtp_layers {self.mtp_layers}: one module (it predicts the "
@@ -283,21 +320,24 @@ class TransformerConfig:
         """``mixer_types`` against the table of kinds: what each kind keeps
         and needs decides what the configuration must bring."""
         # (models/decoding.py's kinds are declared by layer_pattern and
-        # kv_latent_dim: they have no stack a kind)
+        # kv_latent_dim: they have no stack a kind, but mla, which the
+        # module that owns the model's other kinds gives one)
         named = sorted(k for k, kind in MIXER_KINDS.items()
-                       if kind.family != "decoding")
+                       if kind.family != "decoding" or k == "mla")
         unknown = sorted(set(self.mixer_types) - set(named))
         if unknown:
             raise ValueError(
                 f"mixer_types names {unknown}: no such mixer kind (have "
                 f"{named}, see MIXER_KINDS)")
         names = list(dict.fromkeys(self.mixer_types))
-        families = sorted({MIXER_KINDS[n].family for n in names})
-        if len(families) > 1:
+        families = sorted({MIXER_KINDS[n].family for n in names}
+                          - {"decoding"})
+        if len(families) != 1 or ("mla" in names and families != ["ling"]):
             raise ValueError(
                 f"mixer_types mixes kinds of models/{families}: the kinds of "
                 "one model share the module that owns their parameter "
-                "stacks and pools")
+                "stacks and pools (mla, of models/decoding.py, has a stack "
+                "beside the kinds of models/ling.py alone)")
         for name in names:
             field, why = MIXER_KINDS[name].needs
             if field and not getattr(self, field):
@@ -339,12 +379,21 @@ class TransformerConfig:
     def has_state(self) -> bool:
         """A layer keeps leaves a slot (a recurrent state), which are no
         page."""
-        return any(MIXER_KINDS[k].slot for k in set(self.mixer_types))
+        return any(self.slot_leaves_of(k) for k in set(self.mixer_types))
+
+    def slot_leaves_of(self, kind: str) -> Tuple[str, ...]:
+        """The leaves a layer of ``kind`` keeps a slot (an mla layer its
+        unfinished block's index keys, where they are pooled)."""
+        if kind == "mla" and self.index_kpool <= 1:
+            return ()
+        return MIXER_KINDS[kind].slot
 
     @property
     def mixer_family(self) -> str:
-        """The module of models/ that owns the mixers' stacks and pools."""
-        return MIXER_KINDS[self.mixer_types[0]].family
+        """The module of models/ that owns the mixers' stacks and pools:
+        its kinds' own (mla lies in the stack that module gives it)."""
+        return next(MIXER_KINDS[k].family for k in self.mixer_types
+                    if MIXER_KINDS[k].family != "decoding")
 
     @property
     def paged_layers(self) -> int:
@@ -678,8 +727,9 @@ def _latent_projections(cfg: TransformerConfig, p: Params, x: jax.Array,
         B, S, cfg.num_heads, nope + cfg.qk_rope_dim)
     kv_a = x @ p["wkv_a"]
     c_kv = _rms_last(kv_a[..., :kl], p["kv_norm"]["scale"], eps)
-    q_pe, k_pe = _rope(q[..., nope:], kv_a[:, :, None, kl:], positions,
-                       cfg.rope_of("full"))
+    q_pe, k_pe = q[..., nope:], kv_a[:, :, None, kl:]
+    if cfg.qk_rope_dim:  # (0: rows without a rotary part, both empty)
+        q_pe, k_pe = _rope(q_pe, k_pe, positions, cfg.rope_of("full"))
     return c_q, q[..., :nope], q_pe, c_kv, k_pe
 
 
@@ -799,6 +849,15 @@ def _act(cfg: TransformerConfig, x: jax.Array) -> jax.Array:
     return jax.nn.gelu(x, approximate=False)
 
 
+def _swiglu(cfg: TransformerConfig, gate: jax.Array, up: jax.Array
+            ) -> jax.Array:
+    """``silu(gate) * up``, both clamped first where ``cfg.swiglu_limit``."""
+    if cfg.swiglu_limit:
+        gate = jnp.minimum(gate, cfg.swiglu_limit)
+        up = jnp.clip(up, -cfg.swiglu_limit, cfg.swiglu_limit)
+    return jax.nn.silu(gate) * up
+
+
 def _mlp(cfg: TransformerConfig, p: Params, x: jax.Array, rng: Optional[jax.Array],
          train: bool, dense: bool = False) -> Tuple[jax.Array, jax.Array]:
     """Returns (output, aux_loss). Dense MLP or routed MoE expert layer
@@ -812,7 +871,7 @@ def _mlp(cfg: TransformerConfig, p: Params, x: jax.Array, rng: Optional[jax.Arra
     if cfg.activation == "swiglu":
         # wi and the gate share one decomposed gather ring under overlap
         h, g = tp_in_proj(x, (p["wi"], p["wg"]))
-        h = jax.nn.silu(g) * h
+        h = _swiglu(cfg, g, h)
     else:
         (h,) = tp_in_proj(x, (p["wi"],))
         if cfg.use_bias:

@@ -431,7 +431,9 @@ def moe_held_layer(cfg, p: Dict, x: jax.Array):
         rows = _rows_of_pairs(tokens, order, inv, here)
         h = jax.lax.ragged_dot(rows, p["wi"], sizes)
         if cfg.activation == "swiglu":
-            h = jax.nn.silu(jax.lax.ragged_dot(rows, p["wg"], sizes)) * h
+            from ..models.transformer import _swiglu
+
+            h = _swiglu(cfg, jax.lax.ragged_dot(rows, p["wg"], sizes), h)
         else:
             h = jax.nn.gelu(h)
         # rows past the last group belong to no product: what a grouped
@@ -490,8 +492,9 @@ def _expert_ffn(cfg, p: Dict, expert_in: jax.Array) -> jax.Array:
     diverge. Handles PackedWeight expert banks via :func:`_expert_proj`."""
     h = _expert_proj(expert_in, p["wi"])
     if cfg.activation == "swiglu":
-        g = _expert_proj(expert_in, p["wg"])
-        h = jax.nn.silu(g) * h
+        from ..models.transformer import _swiglu
+
+        h = _swiglu(cfg, _expert_proj(expert_in, p["wg"]), h)
     else:
         h = jax.nn.gelu(h)
     h = constrain(h, "ep", None, "tp")
@@ -566,6 +569,9 @@ def expert_bank_path(cfg, p: Dict, budget_tokens: int,
             "untouched")
     if _experts_packed(p):
         return "einsum", "the bank is packed (weight-only quantized)"
+    if cfg.swiglu_limit:
+        return "einsum", ("the SwiGLU's inputs are clamped (swiglu_limit): "
+                          "the kernel's epilogue is the plain SwiGLU")
     topo = current_topology()
     if topo is not None and (topo.sizes.get("ep", 1) > 1
                              or topo.tp_size > 1):

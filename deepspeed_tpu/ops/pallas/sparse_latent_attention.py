@@ -36,6 +36,17 @@ and one leading index of the two that read it); only blocks a slot's loop
 reaches are written, and only columns at or before a row's own position are
 ever read.
 
+POOLED index keys (``kpool`` > 1: one key a block of ``kpool`` tokens, the
+pool ``[L, P+1, page_size / kpool, Di]`` on the same table). A page's few
+pooled keys are no tile of a DMA, so :func:`pooled_view` gathers each slot's
+into one contiguous run first (a sixteenth of the latent rows' bytes at
+``kpool`` 4) and the scoring kernel reads that through a table that counts;
+scores, threshold and tie are then by BLOCK (``POOLED_BLOCK_K`` a score
+block, the blocks of one ``BLOCK_K`` of tokens), a row sees the blocks whose
+last token is at or before it, and the walk spreads a block's verdict over
+its tokens with one small product and always attends the tokens after the
+row's last whole block.
+
 The ``dense_*`` functions compute the same three steps in plain
 ``jax.numpy`` over a gathered per-slot view: the path of a CPU engine and
 the oracle of the kernels' tests.
@@ -64,10 +75,11 @@ ATTN_ROWS = 8        # query rows a program of the attention kernel
 INT_MIN = -(2 ** 31)
 
 
-def score_blocks(max_pages: int, page_size: int) -> Tuple[int, int]:
+def score_blocks(max_pages: int, page_size: int,
+                 block_k: int = BLOCK_K) -> Tuple[int, int]:
     """(key blocks of the score matrix, keys a block): the slot's mapped
     tokens in whole blocks, the blocks in whole counting steps."""
-    bk = page_size * _block_pages(BLOCK_K, page_size, max_pages)
+    bk = page_size * _block_pages(block_k, page_size, max_pages)
     nb = -(-(max_pages * page_size) // bk)
     if nb > SELECT_BLOCKS:
         nb = -(-nb // SELECT_BLOCKS) * SELECT_BLOCKS
@@ -106,7 +118,8 @@ def _page_fetch(pt_ref, hbm, buf, sems, b, layer, ps, ppb, mp):
 # -------------------------------------------------------------- indexer
 def _index_scores_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, w_ref,
                          k_hbm, o_hbm, k_buf, o_buf, ksems, osems,
-                         *, page_size, pages_per_block, heads, rows):
+                         *, page_size, pages_per_block, heads, rows,
+                         kpool: int = 1):
     ps, ppb = page_size, pages_per_block
     bk = ps * ppb
     mp = pt_ref.shape[1]
@@ -121,7 +134,10 @@ def _index_scores_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, w_ref,
 
     @pl.when(nn > 0)
     def _score():
-        n_blocks = jnp.minimum(pl.cdiv(cl + nn, bk), pl.cdiv(mp * ps, bk))
+        keys = cl + nn  # a key a token, or with kpool a whole block of them
+        if kpool > 1:
+            keys = keys // kpool
+        n_blocks = jnp.minimum(pl.cdiv(keys, bk), pl.cdiv(mp * ps, bk))
         n_tiles = pl.cdiv(nn, rows)
         start_fetch(0, 0)
 
@@ -165,17 +181,21 @@ def _index_scores_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, w_ref,
 
 
 def index_scores(q_idx, w_idx, ki_pool, cache_len, page_table, *, layer,
-                 num_new=None, interpret: Optional[bool] = None):
+                 num_new=None, interpret: Optional[bool] = None,
+                 kpool: int = 1, block_k: int = BLOCK_K):
     """Index scores float32 ``[B, blocks, S, block_k]`` (token ``s`` of row
     ``i`` at ``[b, s // block_k, i, s % block_k]``). ``q_idx`` [B,S,Hi,Di]
     rotated indexer queries, ``w_idx`` [B,S,Hi] float32 head weights (every
     constant factor folded in), ``ki_pool`` [L,P+1,ps,Di] the indexer keys,
     the chunk's own already written. An entry is defined for
-    ``s < cache_len[b] + num_new[b]`` and ``i < num_new[b]``."""
+    ``s < cache_len[b] + num_new[b]`` and ``i < num_new[b]``. ``kpool`` > 1:
+    a key is a block of that many tokens (``ki_pool`` and ``page_table`` in
+    keys, :func:`pooled_view`'s), defined for ``s < (cache_len[b] +
+    num_new[b]) // kpool``."""
     B, S, Hi, Di = q_idx.shape
     ps, mp = ki_pool.shape[2], page_table.shape[1]
-    ppb = _block_pages(BLOCK_K, ps, mp)
-    NB, bk = score_blocks(mp, ps)
+    ppb = _block_pages(block_k, ps, mp)
+    NB, bk = score_blocks(mp, ps, block_k)
     rows = min(SCORE_ROWS, S)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -200,7 +220,8 @@ def index_scores(q_idx, w_idx, ki_pool, cache_len, page_table, *, layer,
     )
     return pl.pallas_call(
         functools.partial(_index_scores_kernel, page_size=ps,
-                          pages_per_block=ppb, heads=Hi, rows=rows),
+                          pages_per_block=ppb, heads=Hi, rows=rows,
+                          kpool=kpool),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, NB, S, bk), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -219,7 +240,7 @@ def _sort_key(x):
 
 
 def _selection_kernel(cl_ref, nn_ref, s_ref, thr_ref, tie_ref, key_scr,
-                      *, topk, rows, group, pos_bits):
+                      *, topk, rows, group, pos_bits, kpool: int = 1):
     b, t = pl.program_id(0), pl.program_id(1)
     cl, nn = cl_ref[b], nn_ref[b]
     bk = s_ref.shape[-1]
@@ -227,11 +248,15 @@ def _selection_kernel(cl_ref, nn_ref, s_ref, thr_ref, tie_ref, key_scr,
     # every key allowed: what a row inside ``topk`` (or a padded row) gets
     thr_ref[0] = jnp.full(thr_ref.shape[1:], INT_MIN, jnp.int32)
     tie_ref[0] = jnp.full(tie_ref.shape[1:], 2 ** 31 - 1, jnp.int32)
+    # tokens -> keys (with kpool: the whole blocks of that many tokens)
+    keys = (lambda n: n // kpool) if kpool > 1 else (lambda n: n)
 
-    @pl.when((r0 < nn) & (cl + jnp.minimum(nn, r0 + rows) > topk))
+    @pl.when((r0 < nn) & (keys(cl + jnp.minimum(nn, r0 + rows)) > topk))
     def _search():
         qpos = cl + r0 + lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1)
-        n_groups = pl.cdiv(cl + jnp.minimum(nn, r0 + rows), group * bk)
+        if kpool > 1:  # the last block whose last token is at or before it
+            qpos = (qpos + 1) // kpool - 1
+        n_groups = pl.cdiv(keys(cl + jnp.minimum(nn, r0 + rows)), group * bk)
         shape = (group, rows, bk)
         in_group = lax.broadcasted_iota(jnp.int32, shape, 0) * bk + (
             lax.broadcasted_iota(jnp.int32, shape, 2))
@@ -283,13 +308,16 @@ def _selection_kernel(cl_ref, nn_ref, s_ref, thr_ref, tie_ref, key_scr,
 
 
 def select_topk(scores, cache_len, num_new, topk: int,
-                interpret: Optional[bool] = None):
+                interpret: Optional[bool] = None, kpool: int = 1):
     """The selection of every row of ``scores`` (as :func:`index_scores`
     lays them out) as (threshold key, tie position), int32 [B,S] each: row
     ``i`` of slot ``b`` (at position ``cache_len[b] + i``) sees token ``s``
     at or before it iff ``key(score) > thr or (key(score) == thr and
     s <= tie)``, which are its ``topk`` best, ties to the lower position (all
-    of them inside ``topk`` tokens). See :func:`_sort_key` for ``key``."""
+    of them inside ``topk`` tokens). See :func:`_sort_key` for ``key``.
+    ``kpool`` > 1: ``scores`` are of blocks of that many tokens, ``s`` and
+    ``tie`` count blocks, and the row sees block ``s`` iff its last token
+    ``kpool s + kpool - 1`` is at or before the row."""
     B, NB, S, bk = scores.shape
     rows = min(SELECT_ROWS, S)
     group = min(SELECT_BLOCKS, NB)
@@ -306,7 +334,8 @@ def select_topk(scores, cache_len, num_new, topk: int,
     )
     thr, tie = pl.pallas_call(
         functools.partial(_selection_kernel, topk=int(topk), rows=rows,
-                          group=group, pos_bits=(NB * bk).bit_length()),
+                          group=group, pos_bits=(NB * bk).bit_length(),
+                          kpool=kpool),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, S, LANES), jnp.int32)] * 2,
         compiler_params=pltpu.CompilerParams(
@@ -320,10 +349,13 @@ def select_topk(scores, cache_len, num_new, topk: int,
 # ------------------------------------------------------------ attention
 def _sparse_attention_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, *refs,
                              scale, page_size, pages_per_block, heads,
-                             rows, v_width, selected: bool = True):
+                             rows, v_width, selected: bool = True,
+                             kpool: int = 1):
     """``selected``: the operands hold a selection (scores, threshold, tie)
     between the queries and the pool; without one every key at or before a
-    query is attended."""
+    query is attended. ``kpool`` > 1: the selection is of blocks of that
+    many tokens (a score block holds the blocks of one block of keys), and
+    the tokens after a row's last whole block are always attended."""
     s_ref = thr_ref = tie_ref = None
     if selected:
         s_ref, thr_ref, tie_ref, *refs = refs
@@ -350,6 +382,13 @@ def _sparse_attention_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, *refs,
         qpos = cl + r0 + lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
         if selected:
             thr, tie = thr_ref[0, :, :1], tie_ref[0, :, :1]
+        if kpool > 1:
+            whole = (qpos + 1) // kpool  # blocks at or before the row
+            # block c of a key block -> its kpool tokens
+            spread = (lax.broadcasted_iota(jnp.int32, (bk // kpool, bk), 1)
+                      // kpool == lax.broadcasted_iota(
+                          jnp.int32, (bk // kpool, bk), 0)
+                      ).astype(jnp.float32)
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
@@ -367,7 +406,16 @@ def _sparse_attention_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, *refs,
             kv = kv_buf[slot].astype(q.dtype)
             pos = i * bk + lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
             chosen = pos <= qpos
-            if selected:
+            if kpool > 1:
+                key = _sort_key(s_ref[0, i])
+                blk = i * (bk // kpool) + lax.broadcasted_iota(
+                    jnp.int32, (rows, bk // kpool), 1)
+                best = (blk < whole) & (
+                    (key > thr) | ((key == thr) & (blk <= tie)))
+                chosen &= (jnp.dot(best.astype(jnp.float32), spread,
+                                   preferred_element_type=jnp.float32) > 0.5
+                           ) | (pos >= whole * kpool)
+            elif selected:
                 key = _sort_key(s_ref[0, i])
                 chosen &= (key > thr) | ((key == thr) & (pos <= tie))
             # the heads of a query share its row of the selection
@@ -387,19 +435,20 @@ def _sparse_attention_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, *refs,
 
 def sparse_attention(q_abs, kv_pool, scores, thr, tie, cache_len, page_table,
                      *, layer, scale: float, v_width: int, num_new=None,
-                     interpret: Optional[bool] = None):
+                     interpret: Optional[bool] = None, kpool: int = 1):
     """Absorbed queries ``q_abs`` [B,S,H,W] against the chosen latent rows of
     ``kv_pool`` [L,P+1,ps,W] (key: the whole row; value: its first
     ``v_width`` lanes). ``scores``/``thr``/``tie`` as :func:`select_topk`
     gives them; all three None (:func:`latent_attention`): no selection,
     every key at or before a query. Returns [B,S,H,v_width]; a tile of rows
     wholly past ``num_new`` is zeros, padded rows beside real ones are
-    finite."""
+    finite. ``kpool`` > 1: the selection is by block of that many tokens
+    (``scores`` [B, blocks, S, block_k / kpool])."""
     B, S, H, W = q_abs.shape
     ps, mp = kv_pool.shape[2], page_table.shape[1]
     ppb = _block_pages(BLOCK_K, ps, mp)
     selected = scores is not None
-    bk = scores.shape[3] if selected else ps * ppb
+    bk = scores.shape[3] * kpool if selected else ps * ppb
     rows = min(ATTN_ROWS, S)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -412,7 +461,7 @@ def sparse_attention(q_abs, kv_pool, scores, thr, tie, cache_len, page_table,
     if selected:
         selection = (scores, lanes(thr), lanes(tie))
         selection_specs = [
-            pl.BlockSpec((1, scores.shape[1], rows, bk),
+            pl.BlockSpec((1, scores.shape[1], rows, scores.shape[3]),
                          lambda b, t, *_: (b, 0, t, 0)),
             pl.BlockSpec((1, rows, LANES), lambda b, t, *_: (b, t, 0)),
             pl.BlockSpec((1, rows, LANES), lambda b, t, *_: (b, t, 0)),
@@ -438,7 +487,7 @@ def sparse_attention(q_abs, kv_pool, scores, thr, tie, cache_len, page_table,
         functools.partial(
             _sparse_attention_kernel, scale=float(scale), page_size=ps,
             pages_per_block=ppb, heads=H, rows=rows, v_width=v_width,
-            selected=selected),
+            selected=selected, kpool=kpool),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, S * H, v_width), q_abs.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -477,7 +526,7 @@ def latent_attention(q_abs, kv_pool, cache_len, page_table, *, layer,
 
 
 def kernel_reasons(q_abs, q_idx, kv_pool, ki_pool, page_table,
-                   interpret: bool) -> List[str]:
+                   interpret: bool, kpool: int = 1) -> List[str]:
     """Why the kernels cannot take these operands ([] = they can);
     ``q_idx`` / ``ki_pool`` None: no indexer."""
     from ...models.sharding import current_topology
@@ -492,6 +541,13 @@ def kernel_reasons(q_abs, q_idx, kv_pool, ki_pool, page_table,
         reasons.append(f"{jnp.dtype(kv_pool.dtype).name} latent pool")
     if S % SELECT_ROWS or S % ATTN_ROWS:
         reasons.append(f"a chunk of {S} rows is not whole 8-row tiles")
+    if kpool > 1:
+        bk = kv_pool.shape[2] * _block_pages(BLOCK_K, kv_pool.shape[2],
+                                             page_table.shape[1])
+        if bk != POOLED_BLOCK_K * kpool or POOLED_BLOCK_K % ki_pool.shape[2]:
+            reasons.append(
+                f"a block of {bk} keys is not {POOLED_BLOCK_K} blocks of "
+                f"{kpool} tokens in whole pages of {ki_pool.shape[2]}")
     if not interpret:
         for what, pool in (("latent row", kv_pool), ("indexer key", ki_pool)):
             width = 0 if pool is None else pool.shape[-1]
@@ -507,27 +563,56 @@ def kernel_reasons(q_abs, q_idx, kv_pool, ki_pool, page_table,
 def latent_sparse_attention(q_abs, q_idx, w_idx, kv_pool, ki_pool, cache_len,
                             page_table, *, layer, topk: int, scale: float,
                             v_width: int, num_new=None,
-                            interpret: Optional[bool] = None
+                            interpret: Optional[bool] = None, kpool: int = 1
                             ) -> Tuple[Optional[jax.Array], List[str]]:
     """Scores, selection and attention of one layer through the kernels.
     Returns ``(out [B,S,H,v_width], [])``, or ``(None, reasons)`` when the
-    operands are not theirs (the caller takes the dense lines)."""
+    operands are not theirs (the caller takes the dense lines). ``kpool`` >
+    1: ``ki_pool`` holds one key a block of that many tokens and ``topk``
+    counts blocks."""
     interp = interpret if interpret is not None else (
         jax.default_backend() != "tpu")
     reasons = kernel_reasons(q_abs, q_idx, kv_pool, ki_pool, page_table,
-                             interp)
+                             interp, kpool)
     if reasons:
         from ...utils.logging import log_fallback_once
 
         log_fallback_once("latent_sparse_attention", reasons)
         return None, reasons
     kw = dict(num_new=num_new, interpret=interp)
-    scores = index_scores(q_idx, w_idx, ki_pool, cache_len, page_table,
-                          layer=layer, **kw)
-    thr, tie = select_topk(scores, cache_len, num_new, topk, interpret=interp)
+    if kpool > 1:
+        view, counting = pooled_view(ki_pool, page_table, layer)
+        scores = index_scores(q_idx, w_idx, view, cache_len, counting,
+                              layer=0, block_k=POOLED_BLOCK_K, kpool=kpool,
+                              **kw)
+    else:
+        scores = index_scores(q_idx, w_idx, ki_pool, cache_len, page_table,
+                              layer=layer, **kw)
+    thr, tie = select_topk(scores, cache_len, num_new, topk, interpret=interp,
+                           kpool=kpool)
     return sparse_attention(
         q_abs, kv_pool, scores, thr, tie, cache_len, page_table, layer=layer,
-        scale=scale, v_width=v_width, **kw), reasons
+        scale=scale, v_width=v_width, kpool=kpool, **kw), reasons
+
+
+POOLED_BLOCK_K = 128  # pooled keys a score block (and a "page" of the view)
+
+
+def pooled_view(ki_pool, page_table, layer):
+    """The pooled index keys of every slot as the scoring kernel reads them:
+    (a pool ``[1, B x blocks, POOLED_BLOCK_K, Di]`` in which slot ``b``'s
+    keys lie one after the other, gathered through ``page_table`` from
+    ``ki_pool[layer]`` ``[P+1, keys a page, Di]``; the table ``[B, blocks]``
+    that counts its pages). The table is padded to whole blocks by its last
+    entry: those keys lie past every row's frontier."""
+    B, mp = page_table.shape
+    per = ki_pool.shape[2]
+    pages = POOLED_BLOCK_K // per  # pages a block of the view
+    table = jnp.pad(page_table, ((0, 0), (0, -mp % pages)), mode="edge")
+    view = lax.dynamic_index_in_dim(ki_pool, layer, 0, False)[table]
+    blocks = table.shape[1] // pages
+    return (view.reshape(1, B * blocks, POOLED_BLOCK_K, ki_pool.shape[-1]),
+            jnp.arange(B * blocks, dtype=jnp.int32).reshape(B, blocks))
 
 
 # ---------------------------------------------------------- dense lines
@@ -559,6 +644,23 @@ def dense_selection(scores, qpos, topk: int):
         jnp.arange(B)[:, None, None], jnp.arange(S)[None, :, None], idx
     ].set(True)
     return chosen & seen
+
+
+def last_block(qpos, kpool: int):
+    """The last block of ``kpool`` tokens a row at ``qpos`` sees whole: the
+    one whose last token is at or before it (-1: none yet)."""
+    return (qpos + 1) // kpool - 1
+
+
+def tokens_of_blocks(blocks, qpos, kpool: int):
+    """bool [B,S,N x kpool]: the tokens a row at ``qpos`` [B,S] attends
+    under the selection ``blocks`` [B,S,N] of blocks of ``kpool`` tokens
+    (:func:`dense_selection` at :func:`last_block`): those of the chosen
+    blocks, and always the tokens after its last whole block."""
+    pos = jnp.arange(blocks.shape[-1] * kpool)[None, None, :]
+    tail = pos >= ((qpos + 1) // kpool * kpool)[..., None]
+    return (jnp.repeat(blocks, kpool, axis=-1) | tail) & (
+        pos <= qpos[..., None])
 
 
 def dense_sparse_attention(q_abs, kv_view, chosen, scale: float,

@@ -106,8 +106,9 @@ def cache_token_bytes(cfg, storage_itemsize: int, quantized: bool) -> int:
     if cfg.kv_latent_dim:  # every layer latent, or a model's latent layers
         from ..models.decoding import latent_row_width
 
+        # (an index key a token, or one a block of index_kpool tokens)
         width = latent_row_width(cfg) + (
-            cfg.index_dim if cfg.index_topk else 0)
+            cfg.index_dim // cfg.index_kpool if cfg.index_topk else 0)
         return width * storage_itemsize
     return 2 * cfg.kv_heads * cfg.hd * (1 if quantized else storage_itemsize)
 
@@ -737,6 +738,39 @@ def _latent_counts(engine, cl, nn) -> Dict[str, int]:
             "context_keys": int((nn * cl + nn * (nn + 1) // 2).sum())}
 
 
+def _mla_counts(engine, cl, nn) -> Dict[str, int]:
+    """An indexed latent layer named by ``mixer_types`` (its index keys
+    pooled by ``index_kpool``, 1 = a key a token): ``context_keys`` the
+    cached tokens at or before every real query token; ``index_keys`` the
+    POOLED keys its indexer scores, the whole blocks at or before every real
+    query, and ``index_rows`` those at or before a slot's last real query,
+    which it reads once a slot; ``attended_sparse`` the tokens a query
+    attends, those of its ``index_topk`` best blocks and its tail;
+    ``tail_keys`` the tokens attended because they lie after a query's last
+    whole block; ``chosen_min`` the fewest latent rows a slot's queries can
+    have chosen between them (its last query's). Books the attended keys on
+    the metrics."""
+    cfg = engine.config
+    kp, topk = int(cfg.index_kpool), int(cfg.index_topk) or (1 << 62)
+    counts = dict.fromkeys(("context_keys", "index_keys", "index_rows",
+                            "attended_sparse", "tail_keys", "chosen_min"), 0)
+    for c, n in zip(cl[nn > 0], nn[nn > 0]):
+        seen = np.arange(c, c + n) + 1  # a query's context, itself included
+        whole = seen // kp
+        tail = seen - whole * kp
+        attended = np.minimum(whole, topk) * kp + tail
+        for key, add in (("context_keys", seen.sum()),
+                         ("index_keys", whole.sum()),
+                         ("index_rows", whole[-1]),
+                         ("attended_sparse", attended.sum()),
+                         ("tail_keys", tail.sum()),
+                         ("chosen_min", attended[-1])):
+            counts[key] += int(add)
+    engine.metrics.on_keys("sparse", counts["attended_sparse"],
+                           counts["chosen_min"])
+    return counts
+
+
 # what a step's plan says of one layer of each mixer kind ``mixer_types`` may
 # name (the kinds of ``models/transformer.MIXER_KINDS`` outside
 # models/decoding.py): the keys ride the ``serve/device_step`` annotation
@@ -745,6 +779,7 @@ _KIND_COUNTS = {
     "lightning": _state_counts(""),
     "kda": _state_counts("kda_"),
     "latent": _latent_counts,
+    "mla": _mla_counts,
     "retention": _state_counts("retention_"),
 }
 
@@ -923,7 +958,7 @@ class ServingEngine:
 
             kinds = dict.fromkeys(mcfg.mixer_types)
             self._state_kinds = ", ".join(
-                k for k in kinds if MIXER_KINDS[k].slot)
+                k for k in kinds if mcfg.slot_leaves_of(k))
             self._paged_kinds = ", ".join(
                 k for k in kinds if MIXER_KINDS[k].page)
             # what a page of this model holds (nothing, where no kind of
@@ -1150,6 +1185,7 @@ class ServingEngine:
         self.expert_path_reason: Optional[str] = None
         self.metrics.state_bytes = state_bytes(
             mcfg, N, jnp.dtype(engine.kv_cache_storage_dtype).itemsize)
+        self.metrics.hyper_streams = int(getattr(mcfg, "hc_mult", 0))
         # a routed model with mixers: the held experts that got a row in the
         # step folded last (the device's own count, read with its tokens)
         self._experts_touched: Optional[int] = None
@@ -1730,6 +1766,10 @@ class ServingEngine:
         if "context_keys" in counts:
             self.metrics.context_keys += counts["context_keys"]
         self.metrics.state_resets += counts.get("state_resets", 0)
+        # (the residual streams every half-layer mixes; a configuration
+        # built by hand, as above, may carry no such field)
+        if getattr(cfg, "hc_mult", 0):
+            counts["residual_streams"] = cfg.hc_mult
         return counts
 
     def describe(self) -> Dict[str, Any]:
@@ -1764,6 +1804,8 @@ class ServingEngine:
             "expert_path": self.expert_path,
             "expert_path_reason": self.expert_path_reason,
             "paged_layers": mcfg.paged_layers,
+            "residual_streams": getattr(mcfg, "hc_mult", 0) or 1,
+            "state_bytes": self.metrics.state_bytes,
             "state_leaves": {
                 name: int(np.prod(leaf.shape)) * leaf.dtype.itemsize
                 for name, leaf in leaves.items()},

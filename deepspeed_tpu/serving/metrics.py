@@ -190,6 +190,8 @@ class ServingMetrics:
         # under attention_paged_kernel_kinds (1 = the kind's Pallas kernel)
         self.state_bytes = 0
         self.state_resets = 0
+        # the residual streams of a hyper-connected model (0 = the one)
+        self.hyper_streams = 0
         self._max_slots = 1
         self._num_pages = 0
         self._host_pages = 0
@@ -477,6 +479,8 @@ class ServingMetrics:
         if self.state_bytes:
             snap["state_bytes"] = self.state_bytes
             snap["state_resets"] = self.state_resets
+        if self.hyper_streams:
+            snap["hyper_streams"] = self.hyper_streams
         if "window" in self.attended_keys:
             snap.update({
                 "pages_free": self.pages_free,

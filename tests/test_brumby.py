@@ -225,7 +225,7 @@ def test_a_pageless_engine_serves_what_the_reference_predicts(model, params,
     assert _KIND_COUNTS["retention"](srv, cl, nn) == {
         "retention_rows": 6, "retention_state_slots": 2, "state_resets": 1}
     assert set(_KIND_COUNTS) == {"sparse", "lightning", "kda", "latent",
-                                 "retention"}
+                                 "mla", "retention"}
 
 
 def test_the_kernel_path_is_taken_and_named(model, params):

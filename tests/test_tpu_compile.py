@@ -770,6 +770,59 @@ def test_ling_slot_step_keeps_pools_and_both_state_leaves_in_place(
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * GIB
 
 
+def test_glm5_slot_step_keeps_pools_and_slot_leaves_in_place(
+        one_chip, monkeypatch, capsys):
+    """The one [8, 128] serving step of GLM-5.3-Flash at its published widths
+    and the benchmark's cut (published layers 0 and 4-7: 4 KDA + 1 indexed
+    latent mixer over 1 dense + 4 routed MLPs, 36 of 288 experts, an eighth
+    of the vocabulary, four residual streams) over its arena of 33,856
+    pages: the latent pool, the pooled index keys (a quarter as long, on the
+    same table), the KDA states, the convolution rows and the unfinished
+    index keys ride every run's scan as one carry, so the compiled step
+    holds no copy of a pool or of the state stack; the indexer's three
+    calls and the KDA call are in it; and it fits the chip."""
+    from deepspeed_tpu.models import glm5
+    from deepspeed_tpu.models.decoding import init_paged_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = glm5("glm-5.3-flash", layer_ids=[0, 4, 5, 6, 7], num_experts=36,
+                 moe_routed_experts=288, vocab_size=19360)
+    cfg = model.config
+    assert (cfg.kind_count("kda"), cfg.kind_count("mla")) == (4, 1)
+    assert (cfg.lead_dense_layers, cfg.num_layers) == (1, 4)
+    N, W, ps, cap = 8, 128, 16, 67584
+    pages = N * (cap + W) // ps
+    assert pages == 33856
+    caches = jax.eval_shape(
+        lambda: init_paged_cache(cfg, pages, ps, BF16, max_slots=N))
+    assert caches["state"].shape == (4, N, 64, 128, 128)
+    assert caches["conv"].shape == (4, N, 3, 3 * 8192)
+    assert caches["kv"].shape == (1, pages + 1, ps, 512)
+    assert caches["ki"].shape == (1, pages + 1, ps // 4, 128)
+    assert caches["ki_tail"].shape == (1, N, 3, 128)
+    compiled = _compile_slot_step(model, caches, one_chip, N, W,
+                                  -(-(cap + W) // ps))
+    m = compiled.memory_analysis()
+    pools = sum(a.size * a.dtype.itemsize for a in caches.values())
+    with capsys.disabled():
+        print(f"\nglm5 slot step, described v5e: {model.num_params():,} "
+              f"parameters, arguments {m.argument_size_in_bytes / GIB:.2f} "
+              f"GiB, temporaries {m.temp_size_in_bytes / GIB:.2f} GiB, "
+              f"aliased {m.alias_size_in_bytes / GIB:.2f} (pools and states "
+              f"{pools / GIB:.2f})")
+    text = compiled.as_text()
+    # (the pooled keys are gathered a slot for the scoring call: 35 MB, the
+    # whole of their pool, and that copy is the design; the convolution rows
+    # and the unfinished keys are a layer's own small blocks)
+    assert _pool_copies(text, {k: v for k, v in caches.items()
+                               if k in ("kv", "state")}) == []
+    assert m.alias_size_in_bytes >= pools
+    for name in ("kda_attention", "indexer_scores", "selection_topk",
+                 "sparse_latent_attention"):
+        assert name in text
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * GIB
+
+
 def test_brumby_slot_step_keeps_both_state_leaves_in_place(
         one_chip, monkeypatch, capsys):
     """The one [16, 128] serving step of Brumby-14B-Base at its published
