@@ -153,10 +153,11 @@ class ChunkRows:
     way between them and the slot layout.
 
     Everything that works row by row (embedding, norms, projections, rotary,
-    MLP, experts, residual adds) runs on ``rows.positions``' layout; the
-    cache writes and the attention calls, whose operands are indexed by
-    slot, take ``unpack``-ed ``[B, S, ...]`` blocks and hand their output
-    back through ``pack``.
+    MLP, experts, residual adds) runs on ``rows.positions``' layout, and a
+    page pool is written from it (:meth:`page_rows`: a computed row's
+    place in the pool); the attention calls and a contiguous arena's write,
+    whose operands are indexed by slot, take ``unpack``-ed ``[B, S, ...]``
+    blocks, and the attention's output comes back through ``pack``.
 
     Identity (``budget`` None, or one slot): the rows ARE the slots'
     ``[B, S]`` and ``pack`` / ``unpack`` return their argument: the lockstep
@@ -229,6 +230,25 @@ class ChunkRows:
                     jnp.arange(self.S, dtype=jnp.int32)[None, :], shape))
         return (self._src // self.S)[None], (self._src % self.S)[None]
 
+    def page_rows(self, page_table: jax.Array,
+                  pool: jax.Array) -> Tuple[jax.Array, jax.Array]:
+        """(physical page, offset inside it) of every computed row in the
+        page pool ``pool`` [L, P+1, page_size, ...] under ``page_table``
+        [B, max_pages], both in the computed rows' leading layout: where
+        :func:`_paged_write` puts the row. A row of slot ``b`` at position
+        ``t`` lies at ``page_table[b, t // page_size]``, ``t % page_size``.
+        A row past its slot's mapped pages (the slot layout's padding) goes
+        where the table points unmapped entries, and a packed row that is
+        idle to the NULL page itself, ``P``: both hold what no query reads.
+        The same for every layer of the pool, so a step forms it once a
+        table (``mixers.cached_layers``), not once a write."""
+        ps, null = pool.shape[2], pool.shape[1] - 1
+        page = jnp.clip(self.positions // ps, 0, page_table.shape[1] - 1)
+        phys = page_table[self.origin()[0], page]
+        if self.packed:
+            phys = jnp.where(self.valid, phys, null)
+        return phys, self.positions % ps
+
     def take(self, x: jax.Array, chunk_rows: jax.Array) -> jax.Array:
         """Rows ``chunk_rows`` [B, K] (indices into each slot's chunk, as
         :func:`verify_window_rows` gives them) of the computed rows ``x``
@@ -289,8 +309,10 @@ def _pooled_index_write(cfg: TransformerConfig, pools: Cache, k_new, layer,
                    + jnp.arange(kp - 1, dtype=jnp.int32)[None, :])
     return {
         **pools,
-        INDEX: _paged_write(pools[INDEX], pooled.astype(pools[INDEX].dtype),
-                            layer, cache_len // kp, page_table),
+        INDEX: _paged_write(
+            pools[INDEX], pooled.astype(pools[INDEX].dtype), layer,
+            _page_indices(cache_len // kp, nb, page_table,
+                          pools[INDEX].shape[2])),
         INDEX_TAIL: lax.dynamic_update_index_in_dim(tail, carried, layer, 0),
     }
 
@@ -302,9 +324,9 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
     """Block-paged KV pool for all layers (the serving engine's paged
     arena): ``k``/``v`` are [L, num_pages + 1, page_size, KV, hd] — one
     extra physical page at index ``num_pages`` is the NULL page, where
-    unmapped logical pages and idle slots' padded chunk writes land
-    (its bytes are garbage by design and never attendable: every query
-    masks at its own frontier). int8 storage carries per-(token, head)
+    unmapped logical pages, a packed step's idle rows and (in the slot
+    layout) idle slots' padded chunk writes land (its bytes are garbage by
+    design and never attendable: every query masks at its own frontier). int8 storage carries per-(token, head)
     scales in the pre-transposed [L, P+1, KV, page_size, SL] layout the
     decode kernel consumes.
 
@@ -379,8 +401,10 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
 
 def _page_indices(cache_len: jax.Array, S: int, page_table: jax.Array,
                   page_size: int):
-    """Per-token physical destination of a [B, S] chunk written at the
-    per-row frontier: (phys_page [B, S], offset [B, S])."""
+    """Physical destination of ``S`` entries a slot written at the per-slot
+    frontier ``cache_len``: (phys_page [B, S], offset [B, S]). The pooled
+    index keys' (:func:`_pooled_index_write`: a few BLOCKS a slot, by
+    slot); a pool of rows is written at :meth:`ChunkRows.page_rows`."""
     mp = page_table.shape[1]
     pos = cache_len[:, None].astype(jnp.int32) + jnp.arange(
         S, dtype=jnp.int32
@@ -390,24 +414,37 @@ def _page_indices(cache_len: jax.Array, S: int, page_table: jax.Array,
     return phys, pos % page_size
 
 
-def _paged_write(pool: jax.Array, new: jax.Array, layer, cache_len,
-                 page_table: jax.Array) -> jax.Array:
-    """Scatter a chunk's new K/V [B, S, KV, hd] into layer ``layer`` of
-    the page pool stack [L, P+1, page_size, KV, hd] through the per-slot
-    page tables, in place: the stack comes back whole. Tokens past a
-    slot's mapped pages (padding) route to the NULL page the tables point
-    unmapped entries at."""
-    phys, off = _page_indices(cache_len, new.shape[1], page_table,
-                              pool.shape[2])
+def page_places(rows: ChunkRows, pools: Cache, tables: Dict[str, jax.Array]
+                ) -> Dict[str, Tuple[jax.Array, jax.Array]]:
+    """:meth:`ChunkRows.page_rows` under each of ``tables`` (leaf-name
+    suffix -> page table, or None: a contiguous arena) whose pool keeps
+    rows by page: what a step's walk forms once, before its layers."""
+    out = {}
+    for sfx, table in tables.items():
+        pool = next((pools[n + sfx] for n in ("k", LATENT)
+                     if n + sfx in pools), None)
+        if table is not None and pool is not None:
+            out[sfx] = rows.page_rows(table, pool)
+    return out
+
+
+def _paged_write(pool: jax.Array, new: jax.Array, layer,
+                 page_rows: Tuple[jax.Array, jax.Array]) -> jax.Array:
+    """Scatter the computed rows' new K/V ``new`` [B, S, KV, hd] (or
+    [1, T, KV, hd] packed) into layer ``layer`` of the page pool stack
+    [L, P+1, page_size, KV, hd], row ``t`` at ``page_rows`` = (physical
+    page, offset) of :meth:`ChunkRows.page_rows`, in place: the stack comes
+    back whole. One update row a computed row: ``token_budget`` of them in a
+    packed step, whatever the slots."""
+    phys, off = page_rows
     return pool.at[layer, phys, off].set(new)
 
 
-def _paged_write_scale(pool: jax.Array, new: jax.Array, layer, cache_len,
-                       page_table: jax.Array) -> jax.Array:
-    """Scale twin of :func:`_paged_write`: pool [L, P+1, KV, ps, SL], new
-    chunk scales [B, S, KV, SL] (the _quantize_kv layout)."""
-    phys, off = _page_indices(cache_len, new.shape[1], page_table,
-                              pool.shape[3])
+def _paged_write_scale(pool: jax.Array, new: jax.Array, layer,
+                       page_rows: Tuple[jax.Array, jax.Array]) -> jax.Array:
+    """Scale twin of :func:`_paged_write`: pool [L, P+1, KV, ps, SL], the
+    computed rows' scales [B, S, KV, SL] (the _quantize_kv layout)."""
+    phys, off = page_rows
     kv = jnp.arange(pool.shape[2])
     return pool.at[
         layer, phys[:, :, None], kv[None, None, :], off[:, :, None]
@@ -562,14 +599,15 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
                       rows: ChunkRows, layer, k_cache: jax.Array,
                       v_cache: jax.Array, cache_len,
                       k_scale=None, v_scale=None, page_table=None,
-                      num_new=None, kind: str = "full"):
+                      num_new=None, kind: str = "full", page_rows=None):
     """Attend new tokens against cache[:cache_len] + themselves.
 
     ``x`` holds the rows ``rows`` computes (``[B, S, D]``, or ``[1, T, D]``
-    packed): the projections, their biases, QK-norm, rotary and the KV
-    quantisation run on those rows; q, k and v are unpacked to the slot
-    layout ``[B, S, ...]`` for the cache write and the attention call, and
-    the attention's output is packed again before ``wo``.
+    packed): the projections, their biases, QK-norm, rotary, the KV
+    quantisation and a page pool's write run on those rows; q (and k and v
+    for a contiguous arena's write) is unpacked to the slot layout ``[B, S,
+    ...]`` for the attention call, and the attention's output is packed
+    again before ``wo``.
 
     ``k_cache``/``v_cache`` (and the scales) are the whole stacks of the
     layer's pool, ``[L, ...]`` as init_cache / init_paged_cache give them,
@@ -593,8 +631,9 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
     ``page_table`` [B, max_pages] switches the cache operands to the
     block-paged form: ``k_cache``/``v_cache`` are page POOLS
     [L, P+1, page_size, KV, hd] (scales [L, P+1, KV, page_size, SL]) shared
-    by every slot. The chunk scatters to per-token (layer, physical page,
-    offset) destinations FIRST, then attention reads the slot's pages:
+    by every slot. The computed rows scatter to their (layer, physical page,
+    offset) destinations ``page_rows`` (:meth:`ChunkRows.page_rows` of this
+    table and pool) FIRST, then attention reads the slot's pages:
     through the table inside a Pallas kernel that takes the stack and the
     layer's index and whose work follows the slot's length when the
     registered attention is the kernel one (rotary or learned positions,
@@ -617,22 +656,20 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
 
     quantized = k_scale is not None
     paged = page_table is not None
-    if paged:
-        put, put_scale = _paged_write, _paged_write_scale
-        where = (layer, cache_len, page_table)
-    else:
-        put, put_scale = _update_at, _update_scale_at
-        where = (layer, cache_len)
+    if paged:  # the rows as they were computed, each to its place
+        put, put_scale, where = _paged_write, _paged_write_scale, page_rows
+    else:  # a slot's chunk is one slice of its arena
+        put, put_scale, where = _update_at, _update_scale_at, cache_len
 
-    def write(stack, new):
-        return put(stack, rows.unpack(new), *where)
+    def write(stack, new, put=put):
+        return put(stack, new if paged else rows.unpack(new), layer, where)
 
     if quantized:
         kq, ks = _quantize_kv(k)
         vq, vs = _quantize_kv(v)
         k_cache, v_cache = write(k_cache, kq), write(v_cache, vq)
-        k_scale = put_scale(k_scale, rows.unpack(ks), *where)
-        v_scale = put_scale(v_scale, rows.unpack(vs), *where)
+        k_scale = write(k_scale, ks, put_scale)
+        v_scale = write(v_scale, vs, put_scale)
     else:
         k_cache = write(k_cache, k.astype(k_cache.dtype))
         v_cache = write(v_cache, v.astype(v_cache.dtype))
@@ -751,13 +788,14 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
 def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
                              rows: ChunkRows, layer, pools: Cache,
                              cache_len, page_table, num_new=None,
-                             kind: str = "full"):
+                             kind: str = "full", page_rows=None):
     """Latent attention of new tokens ``x`` (the rows ``rows`` computes:
     [B,S,D], or [1,T,D] packed) over the paged latent cache, in the absorbed
     form: returns (out, in x's layout, and the pools with this layer's rows
     written in place). The projections, the indexer's and the two ``wkv_b``
-    products run on the computed rows; the cache writes and the attention
-    calls take the slot layout.
+    products run on the computed rows, which are written to their places
+    ``page_rows`` (:meth:`ChunkRows.page_rows`); the attention calls and
+    the pooled index keys' write take the slot layout.
 
     A token caches its normed ``kv_latent_dim``-wide latent and ONE rotated
     ``qk_rope_dim``-wide key for all heads (``pools[LATENT]``); a head's
@@ -797,9 +835,8 @@ def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
 
     pools = dict(pools)
     pools[LATENT] = _paged_write(
-        pools[LATENT],
-        rows.unpack(row(c_kv, k_pe[:, :, 0]).astype(pools[LATENT].dtype)),
-        layer, cache_len, page_table)
+        pools[LATENT], row(c_kv, k_pe[:, :, 0]).astype(pools[LATENT].dtype),
+        layer, page_rows)
     # the key half of wkv_b absorbed into the query, the value half applied
     # to the attended latents: [kl, H, nope | vd]
     wkv_b = p["wkv_b"].reshape(kl, H, nope + vd)
@@ -827,15 +864,14 @@ def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
         w_idx = jnp.einsum(
             "bsd,dh->bsh", x.astype(jnp.float32),
             ix["w_proj"].astype(jnp.float32)) * (Hi ** -0.5 * Di ** -0.5)
-        k_idx = rows.unpack(k_idx.astype(pools[INDEX].dtype))
+        k_idx = k_idx.astype(pools[INDEX].dtype)
         if kpool > 1:
             pools = _pooled_index_write(
-                cfg, pools, k_idx, layer, jnp.broadcast_to(
+                cfg, pools, rows.unpack(k_idx), layer, jnp.broadcast_to(
                     jnp.asarray(cache_len, jnp.int32), (rows.B,)),
                 num_new, page_table)
         else:
-            pools[INDEX] = _paged_write(pools[INDEX], k_idx, layer,
-                                        cache_len, page_table)
+            pools[INDEX] = _paged_write(pools[INDEX], k_idx, layer, page_rows)
         q_idx, w_idx = rows.unpack(q_idx), rows.unpack(w_idx)
 
     from ..ops.attention import _resolve
@@ -950,7 +986,8 @@ def forward_with_cache(cfg: TransformerConfig, params: Params, input_ids: jax.Ar
     then ``[1, token_budget, D]``, the real tokens slot after slot
     (:class:`ChunkRows`), and the embedding, every norm, projection, rotary,
     router, MLP, expert dispatch and residual add run on those rows, not on
-    ``B x S``; the cache writes and the attention calls alone see ``[B, S,
+    ``B x S``, and a page pool is written from them, a row to its place
+    (:meth:`ChunkRows.page_rows`); the attention calls alone see ``[B, S,
     ...]``. Without it (the lockstep engine, ``generate``: every row real),
     or on a mesh that shards the slot axis (:func:`row_layout`), the same
     lines run on the slots' ``[B, S]`` rows.

@@ -249,21 +249,24 @@ def lightning_mixer(cfg, p, x, rows, state, index, layer_id, cache_len,
 
 
 def sparse_mixer(cfg, p, x, pools, index, cache_len, num_new, page_table,
-                 rows, note):
+                 rows, note, page_rows):
     """A sparse layer's mixer over the normed rows ``x`` that ``rows``
     computes: (out, in x's layout, and the pools with this layer's keys,
-    values and compressed keys written in place)."""
+    values and compressed keys written in place). The keys and values go to
+    the pool as they were computed, each row to its place ``page_rows``
+    (``ChunkRows.page_rows``); the attention takes ``q`` by slot."""
     from ..ops.pallas import block_sparse_attention as bsa
     from ..ops.pallas.paged_attention import paged_attention
     from .decoding import _paged_gather, _paged_write
 
     B, S, geom = rows.B, rows.S, cfg.block_sparse
     positions = rows.slot_positions
-    q, k, v = map(rows.unpack, _heads(cfg, p, x, cfg.kv_heads))
+    q, k, v = _heads(cfg, p, x, cfg.kv_heads)
+    q = rows.unpack(q)
     pools = dict(pools)
     for name, new in (("k", k), ("v", v)):
         pools[name] = _paged_write(pools[name], new.astype(pools[name].dtype),
-                                   index, cache_len, page_table)
+                                   index, page_rows)
     pools[COMPRESSED] = bsa.write_compressed_keys(
         pools[COMPRESSED], pools["k"], index, cache_len, num_new, page_table,
         geom, S)

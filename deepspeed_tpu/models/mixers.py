@@ -221,10 +221,12 @@ def hyper_post(h, y, post, res):
 
 
 def _mix(kind: str, cfg, p, x, rows, pools, index, layer_id, cache_len,
-         num_new, tables, note):
+         num_new, tables, places, note):
     """The mixer of one layer over the normed rows ``x``: (out, in x's
     layout, and the pools with the layer's leaves advanced in place).
-    ``tables``: the page table of each pool's leaf-name suffix."""
+    ``tables``: the page table of each pool's leaf-name suffix, and
+    ``places`` the computed rows' places under it
+    (``decoding.page_places``)."""
     if kind == "lightning":
         from .minicpm import STATE, lightning_mixer
 
@@ -235,7 +237,7 @@ def _mix(kind: str, cfg, p, x, rows, pools, index, layer_id, cache_len,
         from .minicpm import sparse_mixer
 
         return sparse_mixer(cfg, p, x, pools, index, cache_len, num_new,
-                            tables[""], rows, note)
+                            tables[""], rows, note, places[""])
     if kind == "kda":
         from .ling import kda_mixer
 
@@ -256,14 +258,16 @@ def _mix(kind: str, cfg, p, x, rows, pools, index, layer_id, cache_len,
         a, *written = decoding._cached_attention(
             cfg, p, x, rows, index, *(pools[n] for n in names[:2]),
             cache_len, *(pools[n] for n in names[2:]),
-            page_table=tables[sfx], num_new=num_new, kind=kind)
+            page_table=tables[sfx], num_new=num_new, kind=kind,
+            page_rows=places.get(sfx))
         return a, {**pools, **dict(zip(names, written))}
     # "latent" | "mla" (whose path a model without mixer_types notes as a
     # "full" layer's: the one name its engine reads)
     return decoding._latent_cached_attention(
         cfg, p, x, rows, index, pools, cache_len, tables[""],
         num_new=num_new,
-        kind="full" if kind == "mla" and not cfg.mixer_types else kind)
+        kind="full" if kind == "mla" and not cfg.mixer_types else kind,
+        page_rows=places[""])
 
 
 def cached_layers(cfg: TransformerConfig, params: Params, x, rows, pools,
@@ -276,13 +280,15 @@ def cached_layers(cfg: TransformerConfig, params: Params, x, rows, pools,
     step or None). ``params``: the stacks, already in the compute type.
     ``page_table_win``: the table of the window layers' pool, where a paged
     cache keeps one."""
-    from .decoding import WIN, _note_attention_path as note
+    from .decoding import WIN, _note_attention_path as note, page_places
 
     if num_new is None:
         num_new = jnp.full((rows.B,), rows.S, jnp.int32)
     tables = {"": page_table}
     if page_table is not None and cfg.has_window:
         tables[WIN] = page_table_win
+    # where a pool's write puts each computed row: the same in every layer
+    places = page_places(rows, pools, tables)
     # muP: a residual branch's weight (1 for a model without it)
     branch = cfg.scale_depth / math.sqrt(cfg.mixer_depth) if (
         cfg.scale_depth != 1.0) else None
@@ -321,7 +327,7 @@ def cached_layers(cfg: TransformerConfig, params: Params, x, rows, pools,
                     h, layer.get("hc"), layer["ln1"],
                     lambda x, layer=layer, pools=pools: _mix(
                         kind, cfg, layer["attn"], x, rows, pools, pool_index,
-                        layer_id, cache_len, num_new, tables, note))
+                        layer_id, cache_len, num_new, tables, places, note))
                 if mlp_stack is not mix_stack:
                     layer = at(mlp_stack, mlp_index)
 

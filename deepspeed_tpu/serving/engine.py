@@ -471,10 +471,11 @@ def make_step_fn(cfg, dtype, vocab: int, cache_shardings=None,
     (its invariant 1), and the step hands that promise on
     (``token_budget=W``), so the layers' row-by-row work (embedding, norms,
     projections, rotary, routers, MLPs, expert dispatch, residual adds) runs
-    over W packed rows, not N x W; the cache writes and the attention calls
-    alone take the [N, W] slot layout (``models/decoding.ChunkRows``), and a
-    mesh that shards the slot axis keeps it throughout
-    (``ServingEngine.row_layout``). The final norm and the head run over the
+    over W packed rows, not N x W, and a page pool is written from those
+    rows, each to its (page, offset) (``ChunkRows.page_rows``); the attention
+    calls (and a contiguous arena's write) alone take the [N, W] slot layout
+    (``models/decoding.ChunkRows``), and a mesh that shards the slot axis
+    keeps it throughout (``ServingEngine.row_layout``). The final norm and the head run over the
     verify window's rows alone (``verify_window_rows``): nothing else of the
     chunk has logits.
 
@@ -1008,8 +1009,12 @@ class ServingEngine:
         # the slot axis (models/decoding.row_layout, which the step's trace
         # asks too)
         self.row_layout, self.row_layout_reason = row_layout(self.topology)
-        self.metrics.dense_rows_per_step = (
-            W if self.row_layout == "packed" else N * W)
+        packed = self.row_layout == "packed"
+        self.metrics.dense_rows_per_step = W if packed else N * W
+        # rows a layer's cache write takes: a page pool is written from the
+        # computed rows, a contiguous arena a slot's chunk at a time
+        self.metrics.cache_rows_per_step = (
+            W if packed and self.paged else N * W)
         if self.tiered:
             from .paging import HostPageStore, PageSpiller, export_pages
 
@@ -1572,9 +1577,10 @@ class ServingEngine:
                 else np.zeros(N, np.bool_)
             )
             if self.paged:
-                # idle rows need no dead-tail repoint: the scheduler hands
-                # them an all-NULL page-table row, so their padded W-wide
-                # writes land in the NULL sink page by construction
+                # idle rows need no dead-tail repoint: a packed step has no
+                # row of theirs to write, and in the slot layout the
+                # scheduler hands them an all-NULL page-table row, so their
+                # padded W-wide writes land in the NULL sink page
                 start_pos = plan.start_pos
                 tables = (plan.page_table, plan.page_table_win)[
                     :1 + self.kinds_paged]

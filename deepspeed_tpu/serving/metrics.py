@@ -143,6 +143,10 @@ class ServingMetrics:
         #   (norms, projections, MLPs, routers) run over: token_budget where
         #   the step packs the plan's tokens (ServingEngine.row_layout),
         #   max_slots x token_budget where it keeps the slot layout
+        self.cache_rows_per_step = 0  # update rows a layer's cache write
+        #   takes a pool: token_budget where a packed step scatters the rows
+        #   it computed into a page pool, max_slots x token_budget in the
+        #   slot layout and in a contiguous arena (a slice a slot)
         self.relaid_param_leaves = 0  # parameter leaves the engine re-laid
         #   into the layout its compiled step reads them in
         #   (ServingEngine.param_layout), their bytes a device and the
@@ -464,6 +468,7 @@ class ServingMetrics:
             "expert_touched_kernel": self.expert_touched_kernel,
             "head_rows_per_step": self.head_rows_per_step,
             "dense_rows_per_step": self.dense_rows_per_step,
+            "cache_rows_per_step": self.cache_rows_per_step,
             "relaid_param_leaves": self.relaid_param_leaves,
             "relaid_param_bytes": self.relaid_param_bytes,
             "param_relayout_s": self.param_relayout_s,

@@ -50,7 +50,9 @@ def layer_loop_forward(cfg, params, input_ids, cache, cache_len, *,
         a, *written = decoding._cached_attention(
             cfg, layer["attn"], _norm(cfg, layer["ln1"], x), rows, 0,
             *own_leaves[:2], cache_len, *own_leaves[2:],
-            page_table=table, num_new=num_new, kind=kind)
+            page_table=table, num_new=num_new, kind=kind,
+            page_rows=(None if table is None
+                       else rows.page_rows(table, own_leaves[0])))
         x = x + a
         normed = _norm(cfg, layer["ln2"], x)
         if cfg.is_moe:

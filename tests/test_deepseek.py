@@ -229,9 +229,10 @@ def test_the_absorbed_form_is_the_plain_form(model, params, shape):
     table = jnp.asarray([[2, 0, 3, 1]], jnp.int32)
     normed = reference.rmsnorm(x, {"scale": jnp.ones(cfg.hidden_size)},
                                cfg.norm_eps)
+    rows = ChunkRows(1, S, jnp.zeros(1, jnp.int32))
     got, pools = _latent_cached_attention(
-        cfg, a, normed, ChunkRows(1, S, jnp.zeros(1, jnp.int32)), 2, pools,
-        jnp.zeros(1, jnp.int32), table)
+        cfg, a, normed, rows, 2, pools, jnp.zeros(1, jnp.int32), table,
+        page_rows=rows.page_rows(table, pools[LATENT]))
     ones = {"scale": jnp.ones(cfg.hidden_size)}
     with reference.HIGHEST():
         want = fam._attn(x[0], ones, a, shape,

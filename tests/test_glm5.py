@@ -16,7 +16,7 @@ import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu.config import DeepSpeedConfigError
-from deepspeed_tpu.models import glm5, ling, mixers
+from deepspeed_tpu.models import brumby, glm5, ling, mixers
 from deepspeed_tpu.models.decoding import (forward_with_cache,
                                            init_paged_cache)
 from deepspeed_tpu.models.transformer import TransformerConfig
@@ -320,12 +320,32 @@ def test_hyper_pre_is_the_references_reading(model, params, shape):
         np.asarray(wres), atol=1e-5)
 
 
-def test_a_model_without_streams_traces_the_parents_walk():
-    """``hc_mult`` 0 is the two residual lines: a Ling step lowers to the
-    text it lowered to before the walk knew of streams (the sha256 of the
-    parent's text, commit 63b0d39)."""
-    model = ling("ling-tiny", layer_ids=list(range(12)), num_experts=4,
-                 moe_routed_experts=16)
+# (model, sha256 of its step's lowered text): Ling's as it lowers since the
+# pools took the computed rows (PR 53: against the text of commit 63b0d39,
+# ``fb7c5cda...``, the places of the packed rows formed before the scans, the
+# latent row scattered from them, and its unpack and the by-slot places
+# gone; nothing else: CHANGES.md); Brumby's, which keeps no page, the text
+# of commit 63b0d39 still
+PARENT_LING_WALK = (
+    "3ea5e2b534d3d0962eb5e81661557fbcccb217bbf78fb5d734839e6cad0491b2")
+PARENT_BRUMBY_WALK = (
+    "3136cf397a8e286617f76477f9e9c1c7ea290ee1c333c3118e8177c9ba74b3fd")
+PARENT_WALKS = {
+    "ling": (lambda: ling("ling-tiny", layer_ids=list(range(12)),
+                          num_experts=4, moe_routed_experts=16),
+             PARENT_LING_WALK),
+    "brumby": (lambda: brumby("brumby-tiny", layer_ids=[0, 1, 2]),
+               PARENT_BRUMBY_WALK),
+}
+
+
+@pytest.mark.parametrize("name", list(PARENT_WALKS))
+def test_a_model_without_streams_traces_the_parents_walk(name):
+    """``hc_mult`` 0 is the two residual lines: a step of a model without
+    streams lowers to the text it is pinned to (the sha256 of it), which a
+    change to the walk, the rows or a pool's write has to own up to."""
+    make, pinned = PARENT_WALKS[name]
+    model = make()
     cfg = model.config
     assert cfg.hc_mult == 0 and cfg.swiglu_limit == 0
     caches = jax.eval_shape(
@@ -338,11 +358,7 @@ def test_a_model_without_streams_traces_the_parents_walk():
         token_budget=W)).lower(
         p, i32(SLOTS, W), caches, i32(SLOTS), i32(SLOTS, 16),
         i32(SLOTS)).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_LING_WALK
-
-
-PARENT_LING_WALK = (
-    "fb7c5cda6b995aa521f2ec29a006a23f94a940ec83f5d815a8bd1e224be8cd8e")
+    assert hashlib.sha256(text.encode()).hexdigest() == pinned
 
 
 def test_the_clamp_bites_and_zero_is_none(model, params):

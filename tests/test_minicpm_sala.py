@@ -295,14 +295,16 @@ def test_the_selection_is_the_brute_force_one(kernel):
     k_pool = jnp.zeros((1, mp + 1, PS, KV, hd), F32)
     kc_pool = jnp.zeros((1, mp + 1, KV, hd), F32)
     table = jnp.asarray(np.arange(mp, dtype=np.int32)[None])
-    from deepspeed_tpu.models.decoding import _paged_write
+    from deepspeed_tpu.models.decoding import ChunkRows, _paged_write
 
     for lo in range(0, S, W):  # the cache as the step fills it
         n = min(W, S - lo)
         chunk = np.zeros((1, W, KV, hd), np.float32)
         chunk[0, :n] = k_all[lo:lo + n]
         at = (jnp.asarray([lo]), )
-        k_pool = _paged_write(k_pool, jnp.asarray(chunk), 0, at[0], table)
+        k_pool = _paged_write(
+            k_pool, jnp.asarray(chunk), 0,
+            ChunkRows(1, W, at[0]).page_rows(table, k_pool))
         kc_pool = bsa.write_compressed_keys(
             kc_pool, k_pool, 0, at[0], jnp.asarray([n]), table, g, W)
     for lo in (320, 368, 380, 600, 684):

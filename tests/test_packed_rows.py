@@ -6,6 +6,8 @@ slot layout. The oracle is the same forward without the promise: the identity
 tables, every slot's ``[B, S]`` rows, which is the step as it was.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -174,6 +176,155 @@ def test_packed_step_is_the_slot_step_on_every_real_token(case):
                                    err_msg=str(key))
 
 
+# the page pools a step writes rows into, by the model that keeps them
+POOLS = {
+    "gqa_pool": (tiny_llama, {}),
+    "window_and_full_pools_two_tables": (
+        lambda: mellum("mellum-tiny", initializer_range=0.05), {}),
+    "latent_and_index_pools": (lambda: deepseek("deepseek-tiny"), {}),
+    "sparse_keys_values_and_compressed_keys": (
+        lambda: minicpm("minicpm-sala-tiny", initializer_range=0.1), {}),
+    "gqa_int8_pool_and_scales": (tiny_llama, dict(quantized=True)),
+}
+# (frontiers, real tokens) a slot of one step
+WRITES = {
+    "an_idle_slot_between": ([5, 20, 9, 33], [3, 7, 0, 6]),
+    "chunks_across_a_page_and_a_full_budget": ([10, 0, 30, 47], [9, 0, 2, 5]),
+    "a_budget_not_full": ([0, 3, 17, 40], [1, 1, 2, 1]),
+}
+
+
+def _write_by_slot(patch):
+    """The cache write as it was before the pools took the computed rows:
+    every slot's ``[B, S]`` block, the packed rows unpacked into it, at the
+    slot layout's places (a slot's rows past ``num_new`` past its frontier).
+    Patched over the write, it is the oracle of what lands where."""
+    from deepspeed_tpu.models import decoding
+
+    seen = {}
+
+    def places(rows, pools, tables):
+        seen["rows"] = rows
+        by_slot = ChunkRows(rows.B, rows.S, rows.slot_positions[:, 0])
+        return {sfx: by_slot.page_rows(table, pools[
+            next(n + sfx for n in ("k", "kv") if n + sfx in pools)])
+            for sfx, table in tables.items()}
+
+    def unpacked(put):
+        return lambda pool, new, layer, page_rows: put(
+            pool, seen["rows"].unpack(new), layer, page_rows)
+
+    for name in ("_paged_write", "_paged_write_scale"):
+        patch.setattr(decoding, name, unpacked(getattr(decoding, name)))
+    patch.setattr(decoding, "page_places", places)
+
+
+@functools.lru_cache(maxsize=None)
+def _one_step(case):
+    """(cfg, the pools filled with noise, the tables, the packed step and
+    the same step writing by slot) of a pool kind, from any frontier."""
+    make, opts = POOLS[case]
+    model = make()
+    cfg = model.config
+    params = model.init(jax.random.PRNGKey(3), dtype=F32)
+    rng = np.random.default_rng(17)
+    kw = {}
+    if cfg.has_window:
+        kw["window_pages"] = B * MP
+    if cfg.mixer_types:
+        kw["max_slots"] = B
+    zeros = init_paged_cache(cfg, B * MP, PS, F32,
+                             quantized=opts.get("quantized", False), **kw)
+    # no byte is special for being zero: what a write leaves alone shows
+    pools = {
+        name: jnp.asarray(
+            rng.integers(-127, 128, leaf.shape).astype(np.int8)
+            if leaf.dtype == jnp.int8
+            else rng.uniform(0.5, 1.5, leaf.shape).astype(np.float32))
+        for name, leaf in zeros.items()}
+    tables = {"page_table": jnp.asarray(
+        rng.permutation(B * MP).reshape(B, MP).astype(np.int32))}
+    if cfg.has_window:  # the window layers' pool under a table of its own
+        tables["page_table_win"] = jnp.asarray(
+            rng.permutation(B * MP).reshape(B, MP).astype(np.int32))
+
+    def forward(pools, tokens, frontier, num_new):
+        kw = dict(num_new=num_new, token_budget=W, **tables)
+        if cfg.is_moe:
+            kw["token_valid"] = jnp.arange(W)[None, :] < num_new[:, None]
+        return forward_with_cache(cfg, params, tokens, pools, frontier,
+                                  dtype=F32, **kw)[1]
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    shapes = (pools, i32(B, W), i32(B), i32(B))
+    # (a function object each: a jit's trace is cached by the function)
+    steps = {"packed": jax.jit(
+        lambda *a: forward(*a)).lower(*shapes).compile()}
+    with pytest.MonkeyPatch.context() as patch:
+        _write_by_slot(patch)
+        steps["by_slot"] = jax.jit(
+            lambda *a: forward(*a)).lower(*shapes).compile()
+    assert steps["packed"].as_text() != steps["by_slot"].as_text()
+    return cfg, pools, tables, steps
+
+
+@pytest.mark.parametrize("plan", list(WRITES))
+@pytest.mark.parametrize("case", list(POOLS))
+def test_packed_write_puts_real_rows_where_the_slot_write_does(case, plan):
+    """One packed step from pools full of noise, its write as it is and as
+    it was (every slot's block, :func:`_write_by_slot`): at every real
+    position of every mapped page the step leaves bit for bit what
+    the slot-layout write puts there (a compressed key: of every block the
+    chunk made whole), every other byte of every page but the NULL page is
+    the noise it was (the slot layout's padding does write past a frontier:
+    the packed rows have no padding), and so the idle packed rows touched
+    the NULL page alone."""
+    cfg, pools, tables, steps = _one_step(case)
+    frontier, num_new = (np.asarray(v, np.int32) for v in WRITES[plan])
+    assert num_new.sum() <= W
+    tokens = np.random.default_rng(5).integers(
+        0, cfg.vocab_size, (B, W)).astype(np.int32)
+    got, want = (
+        {k: np.asarray(v) for k, v in steps[name](
+            pools, tokens, jnp.asarray(frontier), jnp.asarray(num_new)
+        ).items()} for name in ("packed", "by_slot"))
+    before = {k: np.asarray(v) for k, v in pools.items()}
+    checked = 0
+    for name, leaf in got.items():
+        if leaf.ndim < 3 or leaf.shape[1] != B * MP + 1:
+            continue  # a leaf by slot (a state): no page
+        table = np.asarray(tables[
+            "page_table_win" if name.endswith("_win") else "page_table"])
+        real = np.zeros(leaf.shape[1:3] if "scale" not in name
+                        else (leaf.shape[1], leaf.shape[3]), bool)
+        if name == "kc":  # [L, P+1, KV, hd]: a key a page, once it is whole
+            for b in range(B):
+                whole = range(frontier[b] // PS,
+                              (frontier[b] + num_new[b]) // PS)
+                np.testing.assert_array_equal(
+                    leaf[:, table[b, list(whole)]],
+                    want[name][:, table[b, list(whole)]])
+            continue
+        for b in range(B):
+            pos = frontier[b] + np.arange(num_new[b])
+            real[table[b, pos // PS], pos % PS] = True
+        assert real.sum() == num_new.sum() and not real[-1].any()
+        if "scale" in name:  # [L, P+1, KV, ps, SL]
+            leaf, was, slot = (np.swapaxes(a, 2, 3) for a in (
+                leaf, before[name], want[name]))
+        else:
+            was, slot = before[name], want[name]
+        np.testing.assert_array_equal(leaf[:, real], slot[:, real], name)
+        assert not np.array_equal(leaf[:, real], was[:, real])
+        kept = ~real
+        kept[-1] = False  # the NULL page holds whatever came last
+        np.testing.assert_array_equal(leaf[:, kept], was[:, kept], name)
+        # (which the slot layout's write did not: its padding landed there)
+        assert not np.array_equal(slot[:, kept], was[:, kept])
+        checked += 1
+    assert checked >= (4 if cfg.has_window or "scale" in "".join(got) else 2)
+
+
 @pytest.mark.parametrize("seed", range(6))
 def test_pack_of_unpack_is_the_rows_themselves(seed):
     """Random counts that sum to at most the budget: a slot's rows are the
@@ -257,7 +408,11 @@ def test_engine_says_which_rows_it_computes(paged):
     serving = dict(paged=True, page_size=8) if paged else {}
     srv = _engine(**serving)
     assert (srv.row_layout, srv.row_layout_reason) == ("packed", None)
-    assert srv.metrics.snapshot()["dense_rows_per_step"] == 8
+    snap = srv.metrics.snapshot()
+    assert snap["dense_rows_per_step"] == 8
+    # a page pool is written from the packed rows; a contiguous arena takes
+    # a slot's chunk, one slice a slot
+    assert snap["cache_rows_per_step"] == (8 if paged else 4 * 8)
     want = _tokens(srv)
 
     topo = MeshTopology(dims=ParallelDims(dp=2), devices=jax.devices()[:2])
@@ -265,5 +420,6 @@ def test_engine_says_which_rows_it_computes(paged):
     sharded = _engine(topology=topo, **serving)
     assert sharded.row_layout == "slots"
     assert "dp x fsdp" in sharded.row_layout_reason
-    assert sharded.metrics.snapshot()["dense_rows_per_step"] == 4 * 8
+    snap = sharded.metrics.snapshot()
+    assert snap["dense_rows_per_step"] == snap["cache_rows_per_step"] == 4 * 8
     assert _tokens(sharded) == want

@@ -439,6 +439,40 @@ def _check_placement_is_one_pass(compiled, rows, cfg, family, capsys):
     assert over_onehots == []
 
 
+def _check_pool_writes_take_the_budget(compiled, caches, names, N, W,
+                                       family, capsys, by_slot=()):
+    """A page pool is written from the rows the step computed (PR 53): every
+    ROW scatter of the compiled step into a pool of ``names`` (one whose
+    index names a layer, a page and an offset; the page copies of
+    copy-on-write and promotion index a page alone) takes ``W`` update rows,
+    not ``N x W``, and each of those pools has one (pools of one shape
+    share their scatters). ``by_slot``: pools that
+    are written by block a slot on purpose (pooled index keys), whose
+    scatters are printed and not held to the budget."""
+    text = compiled.as_text()
+    shapes = {m.group(1): [int(d) for d in m.group(2).split(",") if d]
+              for m in re.finditer(r"%([\w.\-]+) = \w+\[([\d,]*)\]", text)}
+    found = {n: [] for n in (*names, *by_slot)}
+    for m in re.finditer(
+            r"= \w+\[([\d,]+)\]\S* scatter\(%[\w.\-]+, %[\w.\-]+, "
+            r"%([\w.\-]+)\), [^\n]*scatter_dims_to_operand_dims=\{([\d,]+)\}",
+            text):
+        target = tuple(int(d) for d in m.group(1).split(","))
+        for name, rows in found.items():
+            pool = tuple(caches[name].shape)
+            # (a pool of one layer is written as its one layer)
+            if (target, m.group(3)) in (
+                    (pool, "0,1,2"), (pool[1:], "0,1") * (pool[0] == 1)):
+                rows.append(int(np.prod(shapes[m.group(2)]))
+                            // int(np.prod(pool[3:])))
+    with capsys.disabled():
+        print(f"{family} slot step, update rows a pool scatter (budget {W}, "
+              f"by slot {N * W}): " + ", ".join(
+                  f"{name} {rows}" for name, rows in found.items()))
+    for name in names:
+        assert found[name] and set(found[name]) == {W}, (name, found[name])
+
+
 def _compile_slot_step(model, caches, one_chip, N, W, mp):
     """``make_paged_step_fn`` of ``model`` jitted as the serving engine
     jits it (caches and ``seen`` donated, the parameter leaves' layouts
@@ -513,6 +547,8 @@ def test_mixtral_slot_step_keeps_its_pools_in_place(one_chip, monkeypatch,
     compiled = _compile_slot_step(model, caches, one_chip, N, W,
                                   -(-(cap + W) // ps))
     _check_caches_stay_in_place(compiled, caches, "mixtral", capsys)
+    _check_pool_writes_take_the_budget(compiled, caches, ("k", "v"), N, W,
+                                       "mixtral", capsys)
     _check_head_runs_over_the_window(compiled, N, W, model.config.vocab_size,
                                      "mixtral", capsys)
     _check_placement_is_one_pass(compiled, W, model.config, "mixtral",
@@ -544,6 +580,9 @@ def test_mellum_slot_step_compiles_beside_its_arena(one_chip, monkeypatch,
         model.config, N * mp, ps, BF16, window_pages=592))
     compiled = _compile_slot_step(model, caches, one_chip, N, W, mp)
     m = _check_caches_stay_in_place(compiled, caches, "mellum", capsys)
+    _check_pool_writes_take_the_budget(
+        compiled, caches, ("k", "v", "k_win", "v_win"), N, W, "mellum",
+        capsys)
     _check_head_runs_over_the_window(compiled, N, W, model.config.vocab_size,
                                      "mellum", capsys)
     _check_placement_is_one_pass(compiled, W, model.config, "mellum",
@@ -594,6 +633,8 @@ def test_deepseek_slot_step_keeps_both_pools_in_place(one_chip, monkeypatch,
     _check_weights_are_read_as_held(compiled, model, "deepseek", capsys)
     text = compiled.as_text()
     assert _pool_copies(text, caches) == []
+    _check_pool_writes_take_the_budget(compiled, caches, ("kv", "ki"), N, W,
+                                       "deepseek", capsys)
     assert m.alias_size_in_bytes >= pools
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.0 * GIB
     for name in ("indexer_scores", "selection_topk",
@@ -639,6 +680,8 @@ def test_minicpm_sala_slot_step_keeps_pools_and_states_in_place(
             if np.prod([int(d) for d in dims.split(",")])
             == N * W * cfg.vocab_size] == []
     assert _pool_copies(text, caches) == []
+    _check_pool_writes_take_the_budget(compiled, caches, ("k", "v"), N, W,
+                                       "minicpm-sala", capsys)
     _check_dense_rows_are_the_budgets(compiled, N, W, "minicpm", capsys)
     _check_weights_are_read_as_held(compiled, model, "minicpm", capsys)
     assert m.alias_size_in_bytes >= pools
@@ -757,6 +800,8 @@ def test_ling_slot_step_keeps_pools_and_both_state_leaves_in_place(
     # is the layer's own work; the leaves that matter are the GB-sized ones)
     assert _pool_copies(
         text, {k: v for k, v in caches.items() if k != "conv"}) == []
+    _check_pool_writes_take_the_budget(compiled, caches, ("kv",), N, W,
+                                       "ling", capsys)
     assert m.alias_size_in_bytes >= pools
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 12.0 * GIB
     _check_weights_are_read_as_held(compiled, model, "ling", capsys)
@@ -816,6 +861,9 @@ def test_glm5_slot_step_keeps_pools_and_slot_leaves_in_place(
     # and the unfinished keys are a layer's own small blocks)
     assert _pool_copies(text, {k: v for k, v in caches.items()
                                if k in ("kv", "state")}) == []
+    # (the pooled keys are written by BLOCK a slot, 33 blocks each: no rows)
+    _check_pool_writes_take_the_budget(compiled, caches, ("kv",), N, W,
+                                       "glm5", capsys, by_slot=("ki",))
     assert m.alias_size_in_bytes >= pools
     for name in ("kda_attention", "indexer_scores", "selection_topk",
                  "sparse_latent_attention"):
