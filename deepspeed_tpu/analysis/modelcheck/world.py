@@ -134,7 +134,8 @@ class _Recorder:
     def on_token(self, state, now):
         pass
 
-    def on_rows(self, tokens, discarded=0):
+    def on_rows(self, tokens, discarded=0, prompt_tokens=0,
+                chunk_step=False):
         pass
 
     def on_spec(self, state, proposed, accepted, emitted):

@@ -1551,8 +1551,11 @@ class ServingEngine:
         # in dispatch (the first-step TTFT spike is visible as such) and
         # device wait time in device
         n = self._dispatched + 1
+        # the step's size and mix ride its dispatch AND its fold's wait
+        # (_fold), so a reader needs one event a step, on the clock the
+        # device trace has: microseconds, whether or not a profile is taken
         with Phase(self.tracer, "serve/dispatch", "serve",
-                   step=n) as dispatch_sp:
+                   step=n, **plan.held()) as dispatch_sp:
             N = self.max_slots
             temp = np.zeros(N, np.float32)
             top_k = np.zeros(N, np.int32)
@@ -1657,7 +1660,7 @@ class ServingEngine:
         # itself, whether or not anything is being traced (the copies to
         # the host began at dispatch; device_get below finds them landed)
         with Phase(self.tracer, "serve/device", "serve",
-                   step=fl.n) as device_sp:
+                   step=fl.n, **plan.held()) as device_sp:
             jax.block_until_ready(fl.reads)
         if self._serve_tracer is not None:
             # prompt chunks fed this step become request-scoped spans

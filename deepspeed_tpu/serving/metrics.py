@@ -94,6 +94,12 @@ class ServingMetrics:
         self.tokens_out = 0
         self.scheduled_tokens = 0     # real tokens fed (prefill + decode)
         #   whose step was folded; a discarded row's are not among them
+        self.prompt_tokens = 0        # those of them in prompt chunks
+        self.decode_tokens = 0        # and the rest: decode rows, verify
+        #   windows, a fully cached prompt's final-token feed (their sum
+        #   is ``scheduled_tokens``)
+        self.chunk_steps = 0          # folded steps that held a prompt
+        #   chunk (of ``steps``)
         self.overlapped_steps = 0     # steps dispatched while the one
         #   before was not yet fetched (of ``steps``)
         self.discarded_rows = 0       # rows computed for a request that
@@ -234,13 +240,18 @@ class ServingMetrics:
         self.queue_depth = queue_depth
         self.slot_occupancy = occupancy / max(self._max_slots, 1)
 
-    def on_rows(self, tokens: int, discarded: int = 0) -> None:
+    def on_rows(self, tokens: int, discarded: int = 0,
+                prompt_tokens: int = 0, chunk_step: bool = False) -> None:
         """One folded step: the real tokens of the rows whose results
-        reached their requests, and the rows that reached nobody (their
-        request ended or was evicted while the step was in flight). Booked
-        at the fold, with ``steps``, so the two always describe the same
-        steps."""
+        reached their requests (``prompt_tokens`` of them in prompt chunks,
+        the rest decode rows), whether the step held a prompt chunk, and
+        the rows that reached nobody (their request ended or was evicted
+        while the step was in flight). Booked at the fold, with ``steps``,
+        so they always describe the same steps."""
         self.scheduled_tokens += int(tokens)
+        self.prompt_tokens += int(prompt_tokens)
+        self.decode_tokens += int(tokens) - int(prompt_tokens)
+        self.chunk_steps += bool(chunk_step)
         self.discarded_rows += int(discarded)
 
     def on_token(self, state, now: float) -> None:
@@ -440,6 +451,9 @@ class ServingMetrics:
             "steps": self.steps,
             "tokens_out": self.tokens_out,
             "scheduled_tokens": self.scheduled_tokens,
+            "prompt_tokens": self.prompt_tokens,
+            "decode_tokens": self.decode_tokens,
+            "chunk_steps": self.chunk_steps,
             "overlapped_steps": self.overlapped_steps,
             "discarded_rows": self.discarded_rows,
             "queue_depth": self.queue_depth,
@@ -538,7 +552,9 @@ class ServingMetrics:
             f"rejected={self.rejected} evicted={self.evicted}",
             f"{'throughput':<18}{s['tokens_per_s']:.1f} tok/s over "
             f"{self.elapsed:.2f}s ({self.steps} steps, "
-            f"{self.scheduled_tokens} scheduled tokens)",
+            f"{self.chunk_steps} with a prompt chunk; "
+            f"{self.scheduled_tokens} scheduled tokens = "
+            f"{self.prompt_tokens} prompt + {self.decode_tokens} decode)",
             f"{'ttft':<18}p50={s['ttft_p50_s'] * 1e3:.1f}ms "
             f"p95={s['ttft_p95_s'] * 1e3:.1f}ms",
             f"{'tpot':<18}p50={s['tpot_p50_s'] * 1e3:.1f}ms "
@@ -626,7 +642,8 @@ class FleetMetrics:
     # replica counters that sum into the fleet snapshot
     _SUM_KEYS = (
         "submitted", "admitted", "rejected", "evicted", "finished",
-        "steps", "tokens_out", "scheduled_tokens", "overlapped_steps",
+        "steps", "tokens_out", "scheduled_tokens", "prompt_tokens",
+        "decode_tokens", "chunk_steps", "overlapped_steps",
         "discarded_rows", "prefix_hits", "cached_prompt_tokens",
         "cow_copies", "prefill_chunks", "cached_tail_feeds", "spec_steps",
         "draft_tokens_proposed", "draft_tokens_accepted", "pages_in_use",

@@ -53,6 +53,8 @@ class ScheduledWork:
     sample: bool           # does this step produce tokens for the slot?
     spec_len: int = 0      # draft tokens in the row's verify window: the
     #   slot emits 1..spec_len+1 tokens this step depending on acceptance
+    chunk: bool = False    # the rows are a prompt chunk (a decode row, a
+    #   verify window and a cached prompt's lone final-token feed are not)
 
 
 @dataclass
@@ -100,10 +102,29 @@ class StepPlan:
     #   <= STAGE_SLOTS host pages promoting under this step (may be
     #   non-empty with an otherwise idle work list — a promote-only step
     #   still dispatches so waiting slots become schedulable)
+    # what the step holds, counted where its rows are formed (the spans of
+    # the step carry them: ``held``):
+    prompt_rows: int = 0     # real tokens of prompt chunks (a fully cached
+    #   prompt's lone final-token feed is no chunk: ``on_prefill_chunk``)
+    prompt_slots: int = 0    # slots that feed a prompt chunk
+    decode_slots: int = 0    # slots that feed a decode row, a verify
+    #   window or such a final-token feed: one sampling row, not a chunk
+    context_tokens: int = 0  # sum over the working slots of start_pos +
+    #   num_new: what their attention or state has behind it after the step
 
     @property
     def total_tokens(self) -> int:
         return int(self.num_new.sum())
+
+    def held(self) -> Dict[str, int]:
+        """What ``serve/dispatch`` and ``serve/device`` say of the step:
+        its real rows (``scheduled_tokens``, as ``serve/step`` names them)
+        and their mix; the rows that are no prompt chunk's are decode rows
+        (``scheduled_tokens - prompt_rows``)."""
+        return dict(
+            scheduled_tokens=self.total_tokens, prompt_rows=self.prompt_rows,
+            prompt_slots=self.prompt_slots, decode_slots=self.decode_slots,
+            context_tokens=self.context_tokens)
 
 
 class Scheduler:
@@ -955,6 +976,8 @@ class Scheduler:
                     ] = state.win_pages
             plan.work.append(ScheduledWork(slot, state, n, True,
                                            spec_len=n - 1))
+            plan.decode_slots += 1
+            plan.context_tokens += pos + n
         # leftover budget to prompt chunks, FCFS by prefill start
         prefills = sorted(
             (
@@ -999,16 +1022,20 @@ class Scheduler:
                     plan.page_table_win[
                         slot, state.win_lo:state.win_lo + len(state.win_pages)
                     ] = state.win_pages
+            # a fully-cached prompt's only feed is its final token
+            # (the sampling feed) — that is NOT a prefill chunk
+            cached_tail = (state.cached_tokens >= state.prompt_len - 1
+                           and lo == state.prompt_len - 1)
             if self.metrics is not None:
-                # a fully-cached prompt's only feed is its final token
-                # (the sampling feed) — that is NOT a prefill chunk
-                self.metrics.on_prefill_chunk(
-                    cached_tail=(
-                        state.cached_tokens >= state.prompt_len - 1
-                        and lo == state.prompt_len - 1
-                    ),
-                )
-            plan.work.append(ScheduledWork(slot, state, chunk, final))
+                self.metrics.on_prefill_chunk(cached_tail=cached_tail)
+            plan.work.append(ScheduledWork(slot, state, chunk, final,
+                                           chunk=not cached_tail))
+            if cached_tail:
+                plan.decode_slots += 1
+            else:
+                plan.prompt_slots += 1
+                plan.prompt_rows += chunk
+            plan.context_tokens += lo + chunk
             budget -= chunk
         # inactive slots keep num_new=0 and start_pos=0; the ENGINE
         # repoints their padded W-wide cache write at the dead tail
@@ -1081,7 +1108,7 @@ class Scheduler:
         now = self.clock()
         finished: List[RequestState] = []
         self._in_flight = [p for p in self._in_flight if p is not plan]
-        rows = discarded = 0
+        rows = prompt_rows = discarded = 0
         for w in plan.work:
             st = w.state
             if self.slots[w.slot] is not st:
@@ -1091,6 +1118,8 @@ class Scheduler:
                 discarded += 1
                 continue
             rows += w.n_tokens
+            if w.chunk:
+                prompt_rows += w.n_tokens
             if w.n_tokens and st.status is RequestStatus.PREFILL:
                 st.prompt_pos += w.n_tokens
             if not w.sample:
@@ -1158,7 +1187,8 @@ class Scheduler:
         if self.paged:
             self.assert_page_invariants()
         if self.metrics is not None:
-            self.metrics.on_rows(rows, discarded)
+            self.metrics.on_rows(rows, discarded, prompt_tokens=prompt_rows,
+                                 chunk_step=plan.prompt_slots > 0)
             for st in finished:
                 self.metrics.on_finish(st, now)
         return finished

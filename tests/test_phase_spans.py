@@ -230,6 +230,80 @@ def test_serving_both_sinks_hold_the_same_spans(tmp_path):
             == [e[3] for e in prof.named(name)], name
 
 
+HELD = ("scheduled_tokens", "prompt_rows", "prompt_slots", "decode_slots",
+        "context_tokens")
+
+
+@pytest.mark.parametrize("order", ["overlapped", "serial"])
+def test_a_steps_dispatch_and_fold_say_what_it_held_in_both_sinks(
+        tmp_path, order):
+    """ISSUE 54: prompts longer than the budget, answers longer than one
+    step. ``serve/dispatch(n)`` and ``serve/device(n)`` carry the same
+    counts of step n, in the profile and in the registry, and the counters
+    booked at the fold add up to them."""
+    srv = tiny_server({"enabled": True}, order=order)
+    reg, m = srv.tracer, srv.metrics
+    replay(srv, n=1, tag="warm")
+    mark, before = len(reg.spans), m.snapshot()
+    lengths, news = [13, 20, 9], [4, 3, 5]
+    r = np.random.RandomState(3)
+    with profiled(tmp_path) as prof:
+        for i, (n, new) in enumerate(zip(lengths, news)):
+            srv.submit(Request(request_id=f"h{i}",
+                               prompt=r.randint(0, 128, size=(n,)),
+                               max_new_tokens=new))
+        srv.run_until_idle()
+    after = m.snapshot()
+    ran = after["steps"] - before["steps"]
+    first = before["steps"] + 1
+
+    def held(events):
+        return {e[3]["step"]: {k: e[3][k] for k in HELD} for e in events}
+
+    dispatched = held(prof.named("serve/dispatch"))
+    folded = held(prof.named("serve/device"))
+    assert dispatched == folded
+    assert sorted(folded) == list(range(first, first + ran))
+    for name, mine in (("serve/dispatch", dispatched), ("serve/device", folded)):
+        assert mine == {s["args"]["step"]: {k: s["args"][k] for k in HELD}
+                        for s in reg.spans[mark:] if s["name"] == name}
+    for s in prof.named("serve/step"):
+        if s[3]["dispatched"]:  # serve/step keeps its own name for the size
+            assert s[3]["scheduled_tokens"] == (
+                dispatched[s[3]["dispatched"]]["scheduled_tokens"])
+    for n, h in folded.items():
+        # no spec here: a decode slot feeds one row
+        assert h["prompt_rows"] + h["decode_slots"] == h["scheduled_tokens"]
+        assert 0 < h["scheduled_tokens"] <= srv.token_budget
+        assert (h["prompt_rows"] > 0) == (h["prompt_slots"] > 0)
+        assert 0 < h["prompt_slots"] + h["decode_slots"] <= srv.max_slots
+        assert h["context_tokens"] >= h["scheduled_tokens"]
+    # the first step is h0's first chunk and nothing else: the whole budget
+    assert folded[first] == dict(scheduled_tokens=8, prompt_rows=8,
+                                 prompt_slots=1, decode_slots=0,
+                                 context_tokens=8)
+    # the last is the longest answer's last decode row, alone, with the
+    # prompt and all its tokens but the one it samples behind it
+    assert folded[first + ran - 1] == dict(
+        scheduled_tokens=1, prompt_rows=0, prompt_slots=0, decode_slots=1,
+        context_tokens=9 + 5 - 1)
+
+    def grew(key):
+        return after[key] - before[key]
+
+    assert grew("prompt_tokens") == sum(lengths) == sum(
+        h["prompt_rows"] for h in folded.values())
+    # a request's first token comes out of its final chunk: one fed decode
+    # row for each further one
+    assert grew("decode_tokens") == sum(n - 1 for n in news) == sum(
+        h["decode_slots"] for h in folded.values())
+    assert grew("scheduled_tokens") == (
+        grew("prompt_tokens") + grew("decode_tokens"))
+    assert grew("chunk_steps") == sum(
+        h["prompt_rows"] > 0 for h in folded.values())
+    assert 0 < grew("chunk_steps") < ran and after["discarded_rows"] == 0
+
+
 def test_a_turn_that_could_plan_nothing_is_no_step_in_either_sink(
         tmp_path, monkeypatch):
     """The scheduler holds a request and plans none of it, with nothing in

@@ -342,6 +342,83 @@ def test_scheduler_decode_round_robin_under_tight_budget():
     assert gains == [3, 3, 3], gains  # perfectly fair, nobody starved
 
 
+def _drive(s, overlapped):
+    """Plan and fold until idle, every plan's ``held()`` in order; the
+    overlapped order plans step n+1 ``ahead_of`` step n in flight."""
+    held, flying = [], None
+    for _ in range(20):
+        plan = s.plan(ahead_of=flying if overlapped else None)
+        if plan is not None:
+            held.append(plan.held())
+            assert plan.prompt_rows + sum(
+                w.n_tokens for w in plan.work if not w.chunk
+            ) == plan.total_tokens == held[-1]["scheduled_tokens"]
+        due, flying = (flying, plan) if overlapped else (plan, None)
+        if due is not None:
+            s.complete(due, np.ones(s.max_slots, np.int64))
+        if plan is None and flying is None:
+            return held
+    raise AssertionError("the scheduler never ran dry")
+
+
+@pytest.mark.parametrize("overlapped", [False, True],
+                         ids=["serial", "ahead_of"])
+def test_a_plan_says_what_it_holds(overlapped):
+    """ISSUE 54, by hand: a prompt of 11 and one of 5 under a budget of 8,
+    answers of 3 and 2. A plan made ``ahead_of`` a step in flight counts
+    the rows it dispatches: the same steps as the serial order's."""
+    s = _sched(FakeClock())
+    s.submit(_req("a", plen=11, new=3))
+    s.submit(_req("b", plen=5, new=2))
+    rows = ("scheduled_tokens", "prompt_rows", "prompt_slots",
+            "decode_slots", "context_tokens")
+    assert [tuple(h[k] for k in rows) for h in _drive(s, overlapped)] == [
+        (8, 8, 1, 0, 8),         # a's first chunk takes the budget
+        (8, 8, 2, 0, 11 + 5),    # a's last 3 rows and the whole of b
+        (2, 0, 0, 2, 12 + 6),    # both decode: the prompt and one token
+        (1, 0, 0, 1, 13),        # b is done (2 tokens); a's third
+    ]
+    m = s.metrics
+    assert (m.prompt_tokens, m.decode_tokens, m.chunk_steps) == (16, 3, 2)
+    assert m.scheduled_tokens == 19 and m.discarded_rows == 0
+    snap = m.snapshot()
+    assert (snap["prompt_tokens"], snap["decode_tokens"],
+            snap["chunk_steps"]) == (16, 3, 2)
+    assert "2 with a prompt chunk" in m.summary()
+    assert "16 prompt + 3 decode" in m.summary()
+
+
+def test_a_discarded_row_is_in_neither_counter():
+    """The eos comes out of step 1 while step 2, planned ahead of it, holds
+    the request's decode row: the plan counted the row it dispatched, the
+    fold books it nowhere but ``discarded_rows``."""
+    s = _sched(FakeClock())
+    st = s.submit(_req("a", plen=4, new=5, eos_token_id=1))
+    first = s.plan()
+    second = s.plan(ahead_of=first)
+    assert second.held() == dict(scheduled_tokens=1, prompt_rows=0,
+                                 prompt_slots=0, decode_slots=1,
+                                 context_tokens=5)
+    s.complete(first, np.ones(s.max_slots, np.int64))
+    assert st.status is RequestStatus.DONE
+    s.complete(second, np.ones(s.max_slots, np.int64))
+    m = s.metrics
+    assert (m.scheduled_tokens, m.prompt_tokens, m.decode_tokens,
+            m.chunk_steps, m.discarded_rows) == (4, 4, 0, 1, 1)
+
+
+def test_fleet_metrics_sum_the_split_counters():
+    from deepspeed_tpu.serving.metrics import FleetMetrics
+
+    replicas = [ServingMetrics(), ServingMetrics()]
+    replicas[0].on_rows(8, prompt_tokens=6, chunk_step=True)
+    replicas[1].on_rows(3)
+    replicas[1].on_rows(5, discarded=1, prompt_tokens=5, chunk_step=True)
+    snap = FleetMetrics(replicas).snapshot()
+    assert (snap["scheduled_tokens"], snap["prompt_tokens"],
+            snap["decode_tokens"], snap["chunk_steps"]) == (16, 11, 5, 2)
+
+
 def test_overlap_budget_hbm_stream_window_excludes_hbm_roofline():
     """R8 for kind='hbm': an overlapped HBM stream shares the link that
     produces the HBM roofline term, so it may only hide under the MXU

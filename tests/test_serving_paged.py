@@ -165,9 +165,18 @@ def test_prefix_cache_skips_prefill_and_cow_diverges():
     srv.run_until_idle()
     np.testing.assert_array_equal(a.output(), want[0])
     chunks_before = srv.metrics.prefill_chunks
+    booked = (srv.metrics.prompt_tokens, srv.metrics.chunk_steps)
+    held, plan = [], srv.scheduler.plan
 
+    def planned(**kw):
+        held.append(plan(**kw))
+        return held[-1]
+
+    srv.scheduler.plan = planned
     b = srv.submit(Request(request_id="b", prompt=prompt, max_new_tokens=6))
     srv.run_until_idle()
+    srv.scheduler.plan = plan
+    held = [p.held() for p in held if p is not None]
     assert b.status is RequestStatus.DONE
     np.testing.assert_array_equal(b.output(), want[0])
     # the entire prompt but its final token came from shared pages …
@@ -175,6 +184,14 @@ def test_prefix_cache_skips_prefill_and_cow_diverges():
     # … so NO prefill chunk was scheduled (only the cached-tail feed)
     assert srv.metrics.prefill_chunks == chunks_before
     assert srv.metrics.cached_tail_feeds >= 1
+    # … and that feed is one sampling row with the prompt behind it, a
+    # decode row to the device: no prompt row, no chunk step (ISSUE 54)
+    assert held[0] == dict(scheduled_tokens=1, prompt_rows=0, prompt_slots=0,
+                           decode_slots=1, context_tokens=prompt.size)
+    m = srv.metrics
+    assert (m.prompt_tokens, m.chunk_steps) == booked
+    assert m.prompt_tokens == prompt.size  # a's: b's length less its cache
+    assert m.decode_tokens == 5 + (1 + 5)  # less its cached-tail feed
     assert srv.metrics.prefix_hits >= 1
     # b's first write landed inside a's shared partial page → COW fired
     assert srv.metrics.cow_copies >= 1

@@ -280,6 +280,24 @@ def test_scheduler_spec_decode_claims_k_plus_one_rows():
         assert w.spec_len == 4 and w.n_tokens == 5 and w.sample
 
 
+def test_scheduler_spec_verify_windows_count_under_decode_slots():
+    """ISSUE 54: a verify window's rows (the committed feed and its drafts)
+    are decode rows of a decode slot; none is a prompt row."""
+    s = _sched(FakeClock(), max_slots=2, token_budget=16)
+    _to_decode(s, "a")
+    _to_decode(s, "b")
+    plan = s.plan()
+    # each slot: a prompt of 4 and one token behind the window's 5 rows
+    assert plan.held() == dict(scheduled_tokens=10, prompt_rows=0,
+                               prompt_slots=0, decode_slots=2,
+                               context_tokens=2 * (4 + 5))
+    assert not any(w.chunk for w in plan.work)
+    out = np.zeros((2, 5), np.int64)
+    s.complete(plan, out, n_emit=np.ones(2, np.int64))
+    m = s.metrics
+    assert (m.prompt_tokens, m.decode_tokens, m.chunk_steps) == (0, 10, 0)
+
+
 def test_scheduler_spec_shrinks_k_under_budget_pressure():
     """budget < decodes * (k+1): every decode keeps its committed feed and
     the drafts shrink uniformly — down to plain decode (k=0) when the

@@ -198,6 +198,14 @@ def test_traced_serving_replay_valid_closed_annotated(tmp_path):
     # the report renders (smoke of the CLI's analysis path)
     text = tr.report(events)
     assert "serve/step" in text and "plan/kv_cache" in text
+    # and lists what each step held beside its scheduled_tokens (ISSUE 54):
+    # step 1 is r0's first chunk, the whole budget of 8 prompt rows
+    table = tr.serve_steps(xs := [e for e in events if e["ph"] == "X"])
+    assert table[2].split()[:6] == ["step", *tr.STEP_ARGS]
+    assert table[3].split()[:6] == ["1", "8", "8", "1", "0", "8"]
+    assert len(table) == 3 + srv.metrics.steps
+    assert "\n".join(tr.serve_steps(xs, 2)) in tr.report(events, steps=2)
+    assert "\n".join(tr.serve_steps(xs)) in text  # 9 steps: all listed
 
 
 def test_serving_disabled_tracing_allocates_zero_spans():

@@ -11,6 +11,8 @@
                                                        # trees, or engine-step
                                                        # phase coverage drift
     python tools/trace_report.py --top 20 trace.json
+    python tools/trace_report.py --steps 0 trace.json  # every serving step
+                                                       # with what it held
 
 Reads traces written by ``engine.trace_export(path)`` /
 ``ServingEngine.trace_export(path)`` / ``bench_serve --trace out.json``
@@ -188,7 +190,40 @@ def _self_times(xs: List[Dict[str, Any]]) -> List[tuple]:
     return out
 
 
-def report(events: List[Dict[str, Any]], topk: int = 10) -> str:
+STEP_ARGS = ("scheduled_tokens", "prompt_rows", "prompt_slots",
+             "decode_slots", "context_tokens")
+
+
+def serve_steps(xs: List[Dict[str, Any]], limit: int = 0) -> List[str]:
+    """One line a serving step, by its number: what ``serve/dispatch`` says
+    the step held (its real rows, those of them in prompt chunks, the slots
+    that fed a chunk or a decode row, the context behind them) beside the
+    host's dispatch and its wait for the step's results."""
+    rows: Dict[Any, Dict[str, Any]] = defaultdict(dict)
+    for e in xs:
+        args = e.get("args") or {}
+        if e["name"] == "serve/dispatch" and "step" in args:
+            rows[args["step"]].update(args, dispatch_ms=e["dur"] / 1e3)
+        elif e["name"] == "serve/device" and "step" in args:
+            rows[args["step"]].update(args, device_ms=e["dur"] / 1e3)
+    if not rows:
+        return []
+    lines = ["", "serve steps (what each held; dispatch and the wait for it):",
+             f"{'step':>6}" + "".join(f"{k:>18}" for k in STEP_ARGS)
+             + f"{'dispatch ms':>13}{'device ms':>11}"]
+    for n in sorted(rows)[:limit or None]:
+        r = rows[n]
+        lines.append(
+            f"{n:>6}" + "".join(f"{r.get(k, '-'):>18}" for k in STEP_ARGS)
+            + "".join(f"{r[k]:>{w}.2f}" if k in r else f"{'-':>{w}}"
+                      for k, w in (("dispatch_ms", 13), ("device_ms", 11))))
+    if limit and len(rows) > limit:
+        lines.append(f"  ... {len(rows) - limit} more (--steps 0: all)")
+    return lines
+
+
+def report(events: List[Dict[str, Any]], topk: int = 10,
+           steps: int = 10) -> str:
     xs = _x_events(events)
     if not xs:
         return "trace has no complete (X) spans"
@@ -232,6 +267,7 @@ def report(events: List[Dict[str, Any]], topk: int = 10) -> str:
                 f"{ratio if ratio is not None else float('nan'):>11.4f}"
             )
 
+    lines.extend(serve_steps(xs, steps))
     lines.append("")
     lines.append(f"top {topk} spans by self time:")
     for self_us, e in sorted(selfs, key=lambda t: -t[0])[:topk]:
@@ -254,6 +290,9 @@ def main(argv=None) -> int:
                     help="per-step phase coverage tolerance (default 0.10)")
     ap.add_argument("--top", type=int, default=10,
                     help="top-k spans by self time in the report")
+    ap.add_argument("--steps", type=int, default=10,
+                    help="serving steps listed with what each held "
+                         "(0: all of them)")
     args = ap.parse_args(argv)
 
     try:
@@ -282,7 +321,7 @@ def main(argv=None) -> int:
         )
         return 0
 
-    print(report(events, topk=args.top))
+    print(report(events, topk=args.top, steps=args.steps))
     return 0
 
 
