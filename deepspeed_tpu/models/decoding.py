@@ -204,6 +204,28 @@ class ChunkRows:
             return x
         return x.reshape(self.B * self.S, *x.shape[2:])[self._src][None]
 
+    def pack_split(self, whole: jax.Array, first: jax.Array,
+                   chunk: jax.Array) -> jax.Array:
+        """:meth:`pack` of an output a call returns in two pieces: slot
+        ``b``'s rows in ``whole`` ``[>= B, S, ...]`` where ``chunk[b]``,
+        else its first rows in ``first`` ``[B, R, ...]`` and nothing after
+        them (the delta-rule call: a slot with one real row or none writes
+        a row tile). No row of ``whole`` is read for a slot that did not
+        write it: an idle computed row, which re-reads a row of the last
+        slot past its frontier, reads ``first``'s last row there."""
+        R = first.shape[1]
+        if not self.packed:
+            short = jnp.pad(first, [(0, 0), (0, self.S - R)]
+                            + [(0, 0)] * (first.ndim - 2))
+            return jnp.where(chunk.reshape(-1, *[1] * (first.ndim - 1)),
+                             whole[:self.B], short)
+        slot, off = self._src // self.S, self._src % self.S
+        rows = jnp.where(
+            chunk[slot].reshape(-1, *[1] * (first.ndim - 2)),
+            whole.reshape(-1, *whole.shape[2:])[self._src],
+            first[slot, jnp.minimum(off, R - 1)])
+        return rows[None]
+
     def unpack(self, x: jax.Array) -> jax.Array:
         """The computed rows ``[1, T, ...]`` -> ``[B, S, ...]`` by slot."""
         if not self.packed:

@@ -371,8 +371,10 @@ def kda_mixer(cfg, p, x, rows, pools, index, cache_len, num_new, note):
     scale = hd ** -0.5
     if _kernels_registered():
         note("kda_kernel", (), KDA)
-        o, state = ka.kda_attention(q, k, v, g, beta, pools[STATE], cache_len,
-                                    num_new, layer=index, scale=scale)
+        whole, first, state = ka.kda_attention(
+            q, k, v, g, beta, pools[STATE], cache_len, num_new, layer=index,
+            scale=scale)
+        o = rows.pack_split(whole, first, num_new > 1).reshape(Bc, Sc, H, hd)
     else:
         note("dense", ("the registered attention is not the kernel one",),
              KDA)
@@ -381,8 +383,8 @@ def kda_mixer(cfg, p, x, rows, pools, index, cache_len, num_new, note):
             lax.dynamic_index_in_dim(pools[STATE], index, 0, False),
             cache_len, num_new, scale=scale)
         state = lax.dynamic_update_index_in_dim(pools[STATE], after, index, 0)
-    o = _rms_last(rows.pack(o.astype(x.dtype)), p["o_norm"]["scale"],
-                  cfg.norm_eps)
+        o = rows.pack(o.astype(x.dtype))
+    o = _rms_last(o, p["o_norm"]["scale"], cfg.norm_eps)
     if low_rank:  # a gate a channel
         gate = jax.nn.sigmoid(((x @ p["wg_down"]) @ p["wg_up"]).astype(
             jnp.float32)).reshape(Bc, Sc, H, hd)

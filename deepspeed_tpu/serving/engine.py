@@ -678,17 +678,23 @@ class _Flying:
     t0: Optional[float]  # when its dispatch began (the registry's clock)
 
 
-def _state_counts(prefix: str):
+def _state_counts(prefix: str, by_path: bool = False):
     """The counter of a kind whose cache is a state a slot: ``<prefix>rows``
     the real rows its recurrence runs, ``<prefix>state_slots`` the live
     states the step reads and writes, ``state_resets`` those of them that
-    begin at zero (the step's ``rows`` stands for a prefix of none)."""
+    begin at zero (the step's ``rows`` stands for a prefix of none).
+    ``by_path``: the slots by the way they take through a call that moves
+    what a slot holds, ``<prefix>one_row_slots`` (one real row: a row tile)
+    and ``<prefix>chunk_slots`` (more: the chunk's blocks)."""
     def count(engine, cl, nn) -> Dict[str, int]:
         busy = nn > 0
         out = {prefix + "state_slots": int(busy.sum()),
                "state_resets": int((busy & (cl == 0)).sum())}
         if prefix:
             out[prefix + "rows"] = int(nn.sum())
+        if by_path:
+            out[prefix + "one_row_slots"] = int((nn == 1).sum())
+            out[prefix + "chunk_slots"] = int((nn > 1).sum())
         return out
     return count
 
@@ -778,7 +784,7 @@ def _mla_counts(engine, cl, nn) -> Dict[str, int]:
 _KIND_COUNTS = {
     "sparse": _sparse_counts,
     "lightning": _state_counts(""),
-    "kda": _state_counts("kda_"),
+    "kda": _state_counts("kda_", by_path=True),
     "latent": _latent_counts,
     "mla": _mla_counts,
     "retention": _state_counts("retention_"),
@@ -1796,6 +1802,12 @@ class ServingEngine:
                              ) if mcfg.mixer_types else {}
         kinds = self.attention_paths or (
             {"full": self.attention_path} if self.attention_path else {})
+        kda_heads = 0  # the heads a program of the delta-rule call takes
+        if kinds.get("kda") == "kda_kernel":
+            from ..ops.pallas.kda_attention import heads_per_program
+            kda_heads = heads_per_program(
+                mcfg.num_heads, mcfg.hd, self.token_budget,
+                jnp.dtype(self.engine.dtype).itemsize)
         return {
             "model": mcfg.name,
             "step_order": self.step_order,
@@ -1812,6 +1824,7 @@ class ServingEngine:
                 for kind, path in kinds.items()},
             "expert_path": self.expert_path,
             "expert_path_reason": self.expert_path_reason,
+            "kda_heads_per_program": kda_heads,
             "paged_layers": mcfg.paged_layers,
             "residual_streams": getattr(mcfg, "hc_mult", 0) or 1,
             "state_bytes": self.metrics.state_bytes,

@@ -223,17 +223,31 @@ def test_engine_serves_what_the_reference_predicts(model, params, shape):
     assert snap["state_bytes"] == sum(leaves.values()) == (
         10 * SLOTS * 4 * 16 * 16 * 4 + 10 * SLOTS * 3 * 3 * 64 * 4)
     assert snap["moe_experts_touched"] > 0
+    assert srv.describe()["kda_heads_per_program"] == 0  # no kernel, no program
 
 
-@pytest.mark.parametrize("decay", [-5.0, 0.0, None],
-                         ids=["pinned-to-the-bound", "no-decay", "drawn"])
-def test_kda_kernel_is_its_twin_is_the_recurrence(decay):
-    """``kda_attention`` (interpret mode) against ``dense_kda`` against the
-    reference's row-by-row recurrence, with the log-decays pinned to -5 over
-    the whole chunk (G reaches -320 over 64 rows: a quotient of powers would
-    overflow), pinned to 0, and drawn; slots with a whole chunk, one row, no
-    row and a ragged count; a slot at position 0 starts from zeros."""
-    B, S, H, hd = 4, 64, 2, 16
+def test_the_step_says_which_way_each_slot_takes(model, params):
+    """The counts a step's annotation carries for the kda kind, from the
+    plan by host arithmetic: beside the real rows and the live states, the
+    slots on the one-row path and those with a chunk (the delta-rule call
+    moves a row tile for the first, the chunk's blocks for the second); an
+    engine built on the kernels says how many heads a program of the call
+    takes (all 4 of ling-tiny's: the blocks are small), statically."""
+    from deepspeed_tpu.serving.engine import _KIND_COUNTS
+
+    cl, nn = np.array([0, 40, 7, 0, 3]), np.array([5, 1, 0, 1, 2])
+    assert _KIND_COUNTS["kda"](None, cl, nn) == {
+        "kda_rows": 9, "kda_state_slots": 4, "state_resets": 2,
+        "kda_one_row_slots": 2, "kda_chunk_slots": 2}
+    with attention_impl("flash"):
+        srv = deepspeed_tpu.init_serving(model, serving=SERVING,
+                                         params=params, dtype=F32)
+    d = srv.describe()
+    assert d["attention"]["kda"]["path"] == "kda_kernel"
+    assert d["kda_heads_per_program"] == model.config.num_heads == 4
+
+
+def _kda_operands(B, S, H, hd, decay=None, layers=2):
     k_ = jax.random.split(jax.random.PRNGKey(3), 6)
     nrm = lambda key, *s: jax.random.normal(key, s, F32)
     unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)
@@ -242,24 +256,111 @@ def test_kda_kernel_is_its_twin_is_the_recurrence(decay):
     g = -5 * jax.nn.sigmoid(nrm(k_[3], B, S, H, hd)) if decay is None else (
         jnp.full((B, S, H, hd), decay, F32))
     beta = jax.nn.sigmoid(nrm(k_[4], B, S, H))
-    stack = nrm(k_[5], 2, B, H, hd, hd)
-    cl, nn = jnp.array([0, 9, 4, 30]), jnp.array([S, 1, 0, 37])
+    return q, k, v, g, beta, nrm(k_[5], layers, B, H, hd, hd)
+
+
+def _check_kda_against_the_recurrence(q, k, v, g, beta, stack, cl, nn, layer):
+    """The kernel's call and ``dense_kda`` against the reference's row-by-row
+    recurrence, slot by slot; a slot with no real row keeps its state bit
+    for bit and the stack's other layers are untouched."""
+    from deepspeed_tpu.models.decoding import ChunkRows
+
+    B, S, H, hd = q.shape
     scale = hd ** -0.5
-    o, after = ka.kda_attention(q, k, v, g, beta, stack, cl, nn, layer=1,
-                                scale=scale, interpret=True)
-    o2, after2 = ka.dense_kda(q, k, v, g, beta, stack[1], cl, nn, scale=scale)
-    assert bool((after[0] == stack[0]).all())  # the other layer untouched
-    assert bool((after[1, 2] == stack[1, 2]).all())  # no real row: bit for bit
+    whole, first, after = ka.kda_attention(
+        q, k, v, g, beta, stack, cl, nn, layer=layer, scale=scale,
+        interpret=True)
+    o = ChunkRows(B, S, cl).pack_split(whole, first, nn > 1).reshape(q.shape)
+    o2, after2 = ka.dense_kda(q, k, v, g, beta, stack[layer], cl, nn,
+                              scale=scale)
+    for other in range(stack.shape[0]):
+        if other != layer:
+            assert bool((after[other] == stack[other]).all())
     for b in range(B):
         n = int(nn[b])
-        s0 = jnp.zeros((H, hd, hd)) if int(cl[b]) == 0 else stack[1, b]
+        if n == 0:  # no real row: bit for bit
+            assert bool((after[layer, b] == stack[layer, b]).all())
+        s0 = jnp.zeros((H, hd, hd)) if int(cl[b]) == 0 else stack[layer, b]
         with jax.default_matmul_precision("highest"):
             o3, after3 = fam._delta_rule(q[b, :n] * scale, k[b, :n], v[b, :n],
                                          g[b, :n], beta[b, :n], s0)
         for have in (o[b, :n], o2[b, :n]):
             assert float(jnp.abs(have - o3).max()) < TOL if n else True
-        for have in (after[1, b], after2[b]):
+        for have in (after[layer, b], after2[b]):
             assert float(jnp.abs(have - after3).max()) < TOL
+    return o, whole, first
+
+
+@pytest.mark.parametrize("decay", [-5.0, 0.0, None, "heads"],
+                         ids=["pinned-to-the-bound", "no-decay", "drawn",
+                              "two-programs-a-slot"])
+def test_kda_kernel_is_its_twin_is_the_recurrence(decay, monkeypatch):
+    """``kda_attention`` (interpret mode) against ``dense_kda`` against the
+    reference's row-by-row recurrence, with the log-decays pinned to -5 over
+    the whole chunk (G reaches -320 over 64 rows: a quotient of powers would
+    overflow), pinned to 0, and drawn; slots with a whole chunk, one row, no
+    row and a ragged count; a slot at position 0 starts from zeros. The last
+    case has more than one head a program and more than one program a slot
+    (8 heads of 32, 4 a program: the budget is the test's)."""
+    B, S, H, hd = 4, 64, 2, 16
+    if decay == "heads":
+        H, hd, decay = 8, 32, None
+        monkeypatch.setattr(ka, "BLOCK_VMEM_BYTES", 4 * 2 * (
+            2 * hd * hd * 4 + S * hd * (4 * 4 + 4)))
+        assert ka.heads_per_program(H, hd, S, 4) == 4
+    else:
+        assert ka.heads_per_program(H, hd, S, 4) == H
+    q, k, v, g, beta, stack = _kda_operands(B, S, H, hd, decay)
+    cl, nn = jnp.array([0, 9, 4, 30]), jnp.array([S, 1, 0, 37])
+    _check_kda_against_the_recurrence(q, k, v, g, beta, stack, cl, nn, 1)
+
+
+# (no real row, one row, one row at position 0, a whole chunk, a ragged count)
+_SLOT_KINDS = ((4, 0), (9, 1), (0, 1), (0, 32), (30, 21))
+
+
+@pytest.mark.parametrize("order", [
+    (0, 1, 2, 3, 4), (4, 3, 2, 1, 0), (3, 0, 4, 1, 2), (1, 3, 0, 2, 4),
+    (2, 4, 1, 0, 3), (0, 2, 1, 4, 3), (3, 4, 0, 1, 2), (1, 0, 3, 2, 4)],
+    ids=lambda o: "".join("i1fcr"[i] for i in o))
+def test_kda_call_moves_what_a_slot_holds_and_leaks_nothing(order,
+                                                            monkeypatch):
+    """A batch whose slots are (idle, one row, one row at position 0, a whole
+    chunk, a ragged count) in orders in which every kind follows and
+    precedes every other: a program of a slot without a chunk parks the big
+    blocks on a neighbour's, and a parked block or a skipped copy that
+    leaked one slot's rows into the next would show against the recurrence.
+    Two programs a slot (8 heads, 4 a program). What ``ChunkRows.pack``
+    takes of the call's two pieces is the slot layout's rows for every real
+    row and finite for every idle one, whichever slot is last."""
+    from deepspeed_tpu.models.decoding import ChunkRows
+
+    B, S, H, hd = 5, 32, 8, 32
+    monkeypatch.setattr(ka, "BLOCK_VMEM_BYTES", 4 * 2 * (
+        2 * hd * hd * 4 + S * hd * (4 * 4 + 4)))
+    assert ka.heads_per_program(H, hd, S, 4) == 4
+    q, k, v, g, beta, stack = _kda_operands(B, S, H, hd, layers=3)
+    cl = jnp.array([_SLOT_KINDS[i][0] for i in order])
+    nn = jnp.array([_SLOT_KINDS[i][1] for i in order])
+    o, whole, first = _check_kda_against_the_recurrence(
+        q, k, v, g, beta, stack, cl, nn, 1)
+    assert whole.shape == (B + 1, S, H * hd) and first.shape == (B, 16, H * hd)
+    # the pieces nobody may read are poisoned: whole's rows of a slot
+    # without a chunk (the chip leaves them unwritten) and its spare block
+    chunk = nn > 1
+    whole = jnp.where(jnp.pad(chunk, (0, 1))[:, None, None], whole, jnp.nan)
+    budget = 64
+    rows = ChunkRows(B, S, cl, nn, budget=budget)
+    packed = rows.pack_split(whole, first, chunk)
+    assert packed.shape == (1, budget, H * hd)
+    assert bool(jnp.isfinite(packed).all())
+    real = int(nn.sum())
+    o = o.reshape(B, S, H * hd)
+    assert bool((packed[0, :real] == rows.pack(o)[0, :real]).all())
+    by_slot = ChunkRows(B, S, cl).pack_split(whole, first, chunk)
+    live = (jnp.arange(S)[None, :] < nn[:, None])[..., None]
+    assert bool(jnp.isfinite(by_slot).all())
+    assert bool((jnp.where(live, by_slot, 0) == jnp.where(live, o, 0)).all())
 
 
 def test_the_members_shares_add_up_to_the_uncut_layer(params):

@@ -694,6 +694,42 @@ def test_minicpm_sala_slot_step_keeps_pools_and_states_in_place(
 LING_IDS = [0, *range(6, 18)]  # the benchmark's cut of Ling-3.0-flash
 
 
+@pytest.mark.parametrize("B,H,L", [(16, 32, 11), (8, 64, 4)],
+                         ids=["ling3flash-reason16", "glm53flash-longreason8"])
+def test_kda_call_takes_several_heads_a_program(one_chip, B, H, L):
+    """The delta-rule call at the two cells' shapes (``[16, 128]`` rows of
+    32 heads over an 11-layer stack, ``[8, 128]`` of 64 over 4): the chip's
+    compiler takes a program of ``hb`` heads (lane blocks of ``hb x 128``
+    parked by a scalar, the row tiles, the columns turned in VMEM), the
+    traced call's grid is ``(B, H / hb)``, ``B x H / hb`` programs and not
+    the ``B x H`` of a head each, and its two outputs are the whole rows
+    with a spare block and the row tile."""
+    from deepspeed_tpu.analysis import shardlint
+    from deepspeed_tpu.ops.pallas import kda_attention as ka
+
+    S, hd = 128, 128
+    hb = ka.heads_per_program(H, hd, S, 2)
+    assert 1 < hb <= H and H % hb == 0
+
+    def kda(q, k, v, g, beta, state, cl, nn, layer):
+        return ka.kda_attention(q, k, v, g, beta, state, cl, nn,
+                                layer=layer, scale=hd ** -0.5,
+                                interpret=False)
+
+    row = ((B, S, H, hd), BF16)
+    shapes = (row, row, row, ((B, S, H, hd), F32), ((B, S, H), F32),
+              ((L, B, H, hd, hd), F32), ((B,), I32), ((B,), I32), ((), I32))
+    text = _compile(kda, one_chip, *shapes)
+    assert "kda_attention" in text and "tpu_custom_call" in text
+    assert f"bf16[{B + 1},{S},{H * hd}]" in text
+    assert f"bf16[{B},{ka.ROW_TILE},{H * hd}]" in text
+    assert f"f32[{B},{H},{hd},4]" not in text
+    jaxpr = jax.make_jaxpr(kda)(
+        *(jax.ShapeDtypeStruct(s, d) for s, d in shapes))
+    assert shardlint.pallas_grids(jaxpr.jaxpr) == [
+        ("kda_attention", (B, H // hb))]
+
+
 def test_ling_kernels_compile_at_published_widths(one_chip):
     """The two kernels Ling-3.0-flash brings, at the benchmark cell's shapes
     ([16, 128] rows, 32 heads of 128, a 576-wide latent padded to 640 lanes,
@@ -714,6 +750,8 @@ def test_ling_kernels_compile_at_published_widths(one_chip):
                     ((B, S, H), F32), ((L, B, H, hd, hd), F32), ((B,), I32),
                     ((B,), I32), ((), I32))
     assert "kda_attention" in text and "tpu_custom_call" in text
+    # the one-row path's columns are turned in VMEM: no 4-lane operand
+    assert f"f32[{B},{H},{hd},4]" not in text
     mp = 18432 // B + S // 16
 
     def walk(q, pool, cl, nn, table, layer):
@@ -807,6 +845,8 @@ def test_ling_slot_step_keeps_pools_and_both_state_leaves_in_place(
     _check_weights_are_read_as_held(compiled, model, "ling", capsys)
     for name in ("kda_attention", "latent_attention", "expert_bank"):
         assert name in text
+    # (PR 55) no 4-lane one-row operand is built for the delta-rule call
+    assert "f32[16,32,128,4]" not in text
     # the routed layers' banks go to the kernel as the stack they are held
     # in: no instruction makes one layer's bank ([64, 2560, 768] or its
     # transpose, 252 MB), by slice, copy or fusion
@@ -868,6 +908,7 @@ def test_glm5_slot_step_keeps_pools_and_slot_leaves_in_place(
     for name in ("kda_attention", "indexer_scores", "selection_topk",
                  "sparse_latent_attention"):
         assert name in text
+    assert "f32[8,64,128,4]" not in text  # (PR 55) as in Ling's step
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * GIB
 
 
