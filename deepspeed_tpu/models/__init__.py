@@ -15,6 +15,7 @@ from .glm5 import glm5, glm5_config  # noqa: F401
 from .minicpm import minicpm, minicpm_config  # noqa: F401
 from .ling import ling, ling_config  # noqa: F401
 from .brumby import brumby, brumby_config  # noqa: F401
+from .cohere import cohere, cohere_config  # noqa: F401
 
 MODEL_REGISTRY = {
     "gpt2": gpt2,
@@ -28,6 +29,7 @@ MODEL_REGISTRY = {
     "minicpm": minicpm,
     "ling": ling,
     "brumby": brumby,
+    "cohere": cohere,
 }
 
 

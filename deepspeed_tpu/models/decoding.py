@@ -612,7 +612,7 @@ def _qkv(cfg: TransformerConfig, p: Params, x: jax.Array, positions: jax.Array,
         v = v + p["bv"].reshape(1, 1, nkv, hd)
     if cfg.qk_norm:
         q, k = _qk_norm(cfg, p, q, k)
-    if cfg.pos_embedding == "rope":
+    if cfg.pos_embedding == "rope" and cfg.rope_of(kind) is not None:
         q, k = _rope(q, k, positions, cfg.rope_of(kind))
     return q, k, v
 

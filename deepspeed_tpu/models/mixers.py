@@ -25,8 +25,9 @@ The module that owns the kinds ``mixer_types`` names (``family(cfg)``) gives
 the MLP lies in its mixer's stack, at the mixer's index), ``init``,
 ``num_params``, ``init_pools`` and ``slot_leaves``.
 
-The residual path is ``h = h + f(norm(h))`` round each half-layer, or, with
-``cfg.hc_mult`` streams, a hyper-connection (:func:`hyper_pre`,
+The residual path is ``h = h + f(norm(h))`` round each half-layer (with
+``cfg.parallel_block`` round the layer: ``h = h + mixer(n) + mlp(n)``, ``n``
+the layer's ONE norm), or, with ``cfg.hc_mult`` streams, a hyper-connection (:func:`hyper_pre`,
 :func:`hyper_post`): the rows are then ``[n, 1, T, d]``, the streams LEADING
 (a tile of the chip is the last two axes: a stream axis inside them would
 pad 4 to 8 or 16; leading, every stream is the ``[T, d]`` block every other
@@ -323,11 +324,16 @@ def cached_layers(cfg: TransformerConfig, params: Params, x, rows, pools,
                 index, mlp_index, pool_index, layer_id = (
                     s[j] for s in scanned)
                 layer = at(mix_stack, index)
-                h, pools = residual(
-                    h, layer.get("hc"), layer["ln1"],
-                    lambda x, layer=layer, pools=pools: _mix(
-                        kind, cfg, layer["attn"], x, rows, pools, pool_index,
-                        layer_id, cache_len, num_new, tables, places, note))
+
+                def mix_of(normed, layer=layer, pools=pools):
+                    return _mix(
+                        kind, cfg, layer["attn"], normed, rows, pools,
+                        pool_index, layer_id, cache_len, num_new, tables,
+                        places, note)
+
+                if not cfg.parallel_block:
+                    h, pools = residual(h, layer.get("hc"), layer["ln1"],
+                                        mix_of)
                 if mlp_stack is not mix_stack:
                     layer = at(mlp_stack, mlp_index)
 
@@ -346,7 +352,17 @@ def cached_layers(cfg: TransformerConfig, params: Params, x, rows, pools,
                         budget_tokens=budget,
                         stack=(mlp_stack["mlp"], mlp_index))
 
-                h, one = residual(h, layer.get("hc"), layer["ln2"], mlp_of)
+                if cfg.parallel_block:
+                    # ONE norm: the mixer and the MLP read it, one sum
+                    def both(normed):
+                        a, kept = mix_of(normed)
+                        m, one = mlp_of(normed)
+                        return a + m, (kept, one)
+
+                    h, (pools, one) = residual(h, None, layer["ln1"], both)
+                else:
+                    h, one = residual(h, layer.get("hc"), layer["ln2"],
+                                      mlp_of)
                 if one is not None:
                     lstats.append(one)
                 h = shard(h)

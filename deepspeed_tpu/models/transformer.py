@@ -131,6 +131,7 @@ class TransformerConfig:
     rope_theta: float = 10000.0
     norm: str = "rmsnorm"  # rmsnorm | layernorm
     norm_eps: float = 1e-5
+    norm_bias: bool = True  # a layernorm's bias (False: mean-centred, a scale)
     activation: str = "swiglu"  # swiglu | gelu | gelu_new
     use_bias: bool = False
     tie_embeddings: bool = False
@@ -150,10 +151,15 @@ class TransformerConfig:
     # Layer kinds: a repeating period of "window" | "full" attention layers
     # (() = every layer full). A window layer's query i sees key j iff
     # 0 <= i - j < attn_window. ``rope_tables`` gives a kind its own rotary
-    # table (absent = the plain table of ``rope_theta``).
+    # table (absent = the plain table of ``rope_theta``); a kind named by
+    # ``nope_kinds`` has none: its q and k are not rotated.
     layer_pattern: Tuple[str, ...] = ()
     attn_window: int = 0
     rope_tables: Tuple[Tuple[str, "RopeTable"], ...] = ()
+    nope_kinds: Tuple[str, ...] = ()
+    # The parallel block: ONE norm a layer (``ln1``), the mixer and the MLP
+    # both read it, and ``h = h + mixer(n) + mlp(n)`` in one sum.
+    parallel_block: bool = False
     qk_norm: bool = False  # RMSNorm over each head of q and k, before rotary
     # Latent attention (``kv_latent_dim`` > 0): queries through a
     # ``q_latent_dim``-wide latent, every head's keys and values
@@ -188,7 +194,8 @@ class TransformerConfig:
     # "sigmoid_groups": sigmoid scores plus a learned selection bias, the
     # ``moe_groups_kept`` best of ``moe_groups`` groups by the sum of their
     # two best, the top-k inside them, weights from the unbiased scores
-    # normalised and times ``moe_routed_scale``; no token dropped.
+    # normalised and times ``moe_routed_scale``; no token dropped. "sigmoid":
+    # the top-k of the sigmoid scores, normalised; no bias, group or scale.
     moe_gate: str = "softmax"
     moe_groups: int = 1
     moe_groups_kept: int = 1
@@ -274,9 +281,24 @@ class TransformerConfig:
             )
         if "window" in self.layer_pattern and self.attn_window < 1:
             raise ValueError("a window layer needs attn_window >= 1")
-        if self.moe_gate not in ("softmax", "sigmoid_groups"):
+        if self.moe_gate not in ("softmax", "sigmoid_groups", "sigmoid"):
             raise ValueError(
-                f"moe_gate {self.moe_gate!r} (softmax or sigmoid_groups)")
+                f"moe_gate {self.moe_gate!r} (softmax, sigmoid_groups or "
+                "sigmoid)")
+        if self.moe_gate == "sigmoid" and (
+                self.moe_groups != 1 or self.moe_routed_scale != 1.0):
+            raise ValueError(
+                "the plain sigmoid router has no groups and no routed scale "
+                "(moe_gate sigmoid_groups has both)")
+        if set(self.nope_kinds) - set(self.layer_pattern):
+            raise ValueError(
+                f"nope_kinds {self.nope_kinds} names kinds of layer_pattern "
+                f"{self.layer_pattern}")
+        if self.parallel_block and (self.hc_mult or self.mixer_types):
+            raise ValueError(
+                "parallel_block: one norm and one sum a layer of "
+                "layer_pattern's kinds; the layers mixer_types names and the "
+                "residual streams of hc_mult norm each half-layer")
         if self.index_topk and not (self.kv_latent_dim and self.q_latent_dim):
             raise ValueError("the indexer scores a latent cache's tokens "
                              "from the query latent: index_topk needs "
@@ -305,8 +327,8 @@ class TransformerConfig:
                 0, self.num_experts):
             raise ValueError(
                 "one member's share of an expert-parallel layer "
-                "(moe_routed_experts) is computed under the sigmoid_groups "
-                "router alone")
+                "(moe_routed_experts) is computed under the sigmoid routers "
+                "alone")
         if self.mixer_types:
             self._check_mixers()
         if self.routed_experts % self.moe_groups or not (
@@ -434,8 +456,20 @@ class TransformerConfig:
     def window_of(self, kind: str) -> Optional[int]:
         return self.attn_window if kind == "window" else None
 
-    def rope_of(self, kind: str) -> "RopeTable":
+    def rope_of(self, kind: str) -> Optional["RopeTable"]:
+        """The rotary table of a layer kind, None for one of ``nope_kinds``."""
+        if kind in self.nope_kinds:
+            return None
         return dict(self.rope_tables).get(kind) or RopeTable(self.rope_theta)
+
+    @property
+    def ln_bias(self) -> bool:
+        """The hidden-size norms carry a bias leaf."""
+        return self.norm == "layernorm" and self.norm_bias
+
+    @property
+    def norms_per_layer(self) -> int:
+        return 1 if self.parallel_block else 2
 
     def num_params(self) -> int:
         """Analytic parameter count (for flops profiler / partition planner)."""
@@ -444,7 +478,7 @@ class TransformerConfig:
 
             return family(self).num_params(self)
         d, v, L = self.hidden_size, self.vocab_size, self.num_layers
-        ln_width = 2 * d if self.norm == "layernorm" else d  # scale (+bias)
+        ln_width = 2 * d if self.ln_bias else d  # scale (+bias)
         qkvo = d * self.num_heads * self.hd * 2 + d * self.kv_heads * self.hd * 2
         if self.is_latent:
             nh, ql, kl = self.num_heads, self.q_latent_dim, self.kv_latent_dim
@@ -477,9 +511,10 @@ class TransformerConfig:
                 biases += self.ffn + d
         if self.qk_norm:
             biases += 2 * self.hd
-        per_layer = qkvo + mlp + biases + 2 * ln_width
+        per_layer = qkvo + mlp + biases + self.norms_per_layer * ln_width
         lead = self.lead_dense_layers * (
-            qkvo + 3 * d * self.lead_dense_ffn + 2 * ln_width)
+            qkvo + 3 * d * self.lead_dense_ffn
+            + self.norms_per_layer * ln_width)
         embed = v * d + (self.max_seq_len * d if self.pos_embedding == "learned" else 0)
         if self.embed_norm:
             embed += ln_width
@@ -542,7 +577,7 @@ def init(cfg: TransformerConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
             p["bias"] = jnp.zeros((*lead, d), dtype)
         return p
 
-    ln_bias = cfg.norm == "layernorm"
+    ln_bias = cfg.ln_bias
     params: Params = {
         "embed": {"tok": nrm(keys[0], cfg.vocab_size, d)},
         "final_norm": norm_params(ln_bias),
@@ -583,6 +618,10 @@ def init(cfg: TransformerConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
             mlp["bo"] = jnp.zeros((L, d), dtype)
         return mlp
 
+    def norms(L):  # a parallel block has the one norm
+        return {name: norm_params(ln_bias, (L,))
+                for name in ("ln1", "ln2")[:cfg.norms_per_layer]}
+
     def main_stack(lk, L):
         """``L`` layers of the main stack's kind, stacked."""
         if cfg.is_moe:
@@ -609,12 +648,7 @@ def init(cfg: TransformerConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
                 mlp["shared"] = dense_mlp(sk, L, cfg.moe_shared_width)
         else:
             mlp = dense_mlp(lk, L, f)
-        return {
-            "ln1": norm_params(ln_bias, (L,)),
-            "ln2": norm_params(ln_bias, (L,)),
-            "attn": attn_params(lk, L),
-            "mlp": mlp,
-        }
+        return {**norms(L), "attn": attn_params(lk, L), "mlp": mlp}
 
     params["layers"] = main_stack(jax.random.split(keys[3], 12),
                                   cfg.num_layers)
@@ -622,8 +656,7 @@ def init(cfg: TransformerConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
         dk = jax.random.split(keys[4], 12)
         Ld = cfg.lead_dense_layers
         params["lead_layers"] = {
-            "ln1": norm_params(ln_bias, (Ld,)),
-            "ln2": norm_params(ln_bias, (Ld,)),
+            **norms(Ld),
             "attn": attn_params(dk, Ld),
             "mlp": dense_mlp(dk, Ld, cfg.lead_dense_ffn),
         }
@@ -651,10 +684,10 @@ def _norm(cfg: TransformerConfig, p: Params, x: jax.Array) -> jax.Array:
         return rmsnorm(x32, p["scale"].astype(jnp.float32), cfg.norm_eps).astype(x.dtype)
     from ..ops.normalization import layernorm
 
-    return layernorm(
-        x32, p["scale"].astype(jnp.float32), p["bias"].astype(jnp.float32),
-        cfg.norm_eps,
-    ).astype(x.dtype)
+    scale = p["scale"].astype(jnp.float32)
+    bias = p["bias"].astype(jnp.float32) if "bias" in p else (
+        jnp.zeros_like(scale))  # norm_bias False: centred and scaled alone
+    return layernorm(x32, scale, bias, cfg.norm_eps).astype(x.dtype)
 
 
 def _rope(q: jax.Array, k: jax.Array, positions: jax.Array,
@@ -793,7 +826,7 @@ def _attention(cfg: TransformerConfig, p: Params, x: jax.Array, positions: jax.A
         v = v + p["bv"].reshape(1, 1, nkv, hd)
     if cfg.qk_norm:
         q, k = _qk_norm(cfg, p, q, k)
-    if cfg.pos_embedding == "rope":
+    if cfg.pos_embedding == "rope" and cfg.rope_of(kind) is not None:
         q, k = _rope(q, k, positions, cfg.rope_of(kind))
 
     # ALiBi rides as per-head slopes: the flash kernel and the ring path
@@ -888,8 +921,10 @@ def _block(cfg: TransformerConfig, layer: Params, x: jax.Array, positions: jax.A
            segment_ids: Optional[jax.Array], rng: Optional[jax.Array], train: bool,
            pos_default: bool = True, kind: str = "full", dense: bool = False):
     """One block -> (x, aux loss, routing stats). ``dense``: a leading dense
-    layer of a routed model. The stats are those of the sigmoid_groups
-    router (moe/sharded_moe.moe_held_layer), None for any other MLP."""
+    layer of a routed model. The stats are those of the sigmoid routers
+    (moe/sharded_moe.moe_held_layer), None for any other MLP. With
+    ``cfg.parallel_block`` the attention and the MLP read one norm and the
+    stream takes both in one sum."""
     from jax.ad_checkpoint import checkpoint_name
 
     from ..parallel.tensor_overlap import seq_shard_axes
@@ -899,14 +934,17 @@ def _block(cfg: TransformerConfig, layer: Params, x: jax.Array, positions: jax.A
     # rings consume it, so the residual adds (and the norms) cost zero
     # collectives between projections (Megatron-SP boundaries)
     seq_ax = seq_shard_axes(x)
-    h = _attention(cfg, layer["attn"], _norm(cfg, layer["ln1"], x), positions,
-                   segment_ids, pos_default, kind)
+    normed = _norm(cfg, layer["ln1"], x)
+    h = _attention(cfg, layer["attn"], normed, positions, segment_ids,
+                   pos_default, kind)
     h = checkpoint_name(h, "attn_out")  # selective remat anchor (attn_only)
-    x = x + h
-    x = constrain(x, ("dp", "fsdp"), seq_ax, None)
-    normed = _norm(cfg, layer["ln2"], x)
+    if not cfg.parallel_block:
+        x = x + h
+        x = constrain(x, ("dp", "fsdp"), seq_ax, None)
+        normed = _norm(cfg, layer["ln2"], x)
+    # (a parallel block: the MLP reads the norm the attention read)
     stats = None
-    if cfg.is_moe and not dense and cfg.moe_gate == "sigmoid_groups":
+    if cfg.is_moe and not dense and cfg.moe_gate != "softmax":
         from ..moe.sharded_moe import moe_held_layer
 
         m, stats = moe_held_layer(cfg, layer["mlp"], normed)
@@ -917,7 +955,7 @@ def _block(cfg: TransformerConfig, layer: Params, x: jax.Array, positions: jax.A
         # the shared expert: a dense MLP every token takes, beside the routed
         m = m + _mlp(cfg, layer["mlp"]["shared"], normed, None, train, True)[0]
     m = checkpoint_name(m, "mlp_out")
-    x = x + m
+    x = x + h + m if cfg.parallel_block else x + m
     x = constrain(x, ("dp", "fsdp"), seq_ax, None)
     return x, aux, stats
 
@@ -1369,8 +1407,9 @@ def tp_partition_specs(cfg: TransformerConfig, tp_divides_kv: bool = True) -> Pa
         return jax.tree.map(lambda a: P(*([None] * a.ndim)), shapes)
     kv_tp = "tp" if tp_divides_kv else None
     ln = {"scale": P(None, None)}
-    if cfg.norm == "layernorm":
+    if cfg.ln_bias:
         ln["bias"] = P(None, None)
+    norms = {name: ln for name in ("ln1", "ln2")[:cfg.norms_per_layer]}
     attn = {
         "wq": P(None, None, "tp"),
         "wk": P(None, None, kv_tp),
@@ -1415,8 +1454,8 @@ def tp_partition_specs(cfg: TransformerConfig, tp_divides_kv: bool = True) -> Pa
         mlp = dense_mlp()
     specs: Params = {
         "embed": {"tok": P("tp", None)},
-        "final_norm": dict(scale=P(None), **({"bias": P(None)} if cfg.norm == "layernorm" else {})),
-        "layers": {"ln1": ln, "ln2": ln, "attn": attn, "mlp": mlp},
+        "final_norm": dict(scale=P(None), **({"bias": P(None)} if cfg.ln_bias else {})),
+        "layers": {**norms, "attn": attn, "mlp": mlp},
     }
     if cfg.pos_embedding == "learned":
         specs["embed"]["pos"] = P(None, None)

@@ -324,6 +324,26 @@ def sigmoid_group_gate(logits: jax.Array, sel_bias: jax.Array, top_k: int,
     return idx.astype(jnp.int32), w * routed_scale
 
 
+def sigmoid_gate(logits: jax.Array, top_k: int):
+    """The plain sigmoid router (``moe_gate`` "sigmoid"): ``logits`` [N, E]
+    float32 -> (expert idx [N, K] int32, weight [N, K] float32): the
+    ``top_k`` largest sigmoid scores (ties to the lower index), normalised
+    to one. No selection bias, no groups, no scale."""
+    w, idx = jax.lax.top_k(jax.nn.sigmoid(logits), top_k)
+    w = w / jnp.maximum(jnp.sum(w, axis=1, keepdims=True), 1e-20)
+    return idx.astype(jnp.int32), w
+
+
+def route_sigmoid(cfg, p: Dict, logits: jax.Array, train: bool = False):
+    """(idx, weight) of either sigmoid router, by ``cfg.moe_gate``; with
+    ``train`` the selection bias carries no gradient."""
+    if cfg.moe_gate == "sigmoid":
+        return sigmoid_gate(logits, cfg.moe_top_k)
+    bias = jax.lax.stop_gradient(p["sel_bias"]) if train else p["sel_bias"]
+    return sigmoid_group_gate(logits, bias, cfg.moe_top_k, cfg.moe_groups,
+                              cfg.moe_groups_kept, cfg.moe_routed_scale)
+
+
 def held_expert_tables(idx, w, valid, first: int, held: int, capacity: int):
     """Index tables of ONE member's share of an expert-parallel layer, in
     the layout of :func:`top_k_gating_indices`: of each token's chosen
@@ -388,14 +408,15 @@ _pairs_of_rows.defvjp(
 
 
 def moe_held_layer(cfg, p: Dict, x: jax.Array):
-    """The sigmoid_groups layer in its training form: x [B, S, D] -> (the
+    """The sigmoid-routed layer in its training form: x [B, S, D] -> (the
     partial sum of the experts held here [B, S, D], routing stats).
 
     The router scores all ``cfg.routed_experts`` and chooses ``moe_top_k``
-    of them a token (:func:`sigmoid_group_gate`; the selection bias chooses
-    and carries no gradient). The (token, chosen expert) pairs are sorted by
-    expert, those of experts held elsewhere last; the rows are gathered
-    once, the three products run over the ragged groups
+    of them a token (:func:`sigmoid_group_gate`, whose selection bias
+    chooses and carries no gradient, or the plain :func:`sigmoid_gate`).
+    The (token, chosen expert) pairs are sorted by expert, those of experts
+    held elsewhere last; the rows are gathered once, the three products run
+    over the ragged groups
     (``jax.lax.ragged_dot``: on the chip a grouped matrix product whose work
     follows the rows in the groups), and every pair reads its row back with
     its weight. The buffer holds every pair, so no token is dropped whatever
@@ -413,9 +434,7 @@ def moe_held_layer(cfg, p: Dict, x: jax.Array):
     with jax.named_scope("moe_route"):
         logits = jnp.einsum("nd,de->ne", tokens.astype(jnp.float32),
                             p["router"].astype(jnp.float32))
-        idx, w = sigmoid_group_gate(
-            logits, jax.lax.stop_gradient(p["sel_bias"]), K, cfg.moe_groups,
-            cfg.moe_groups_kept, cfg.moe_routed_scale)
+        idx, w = route_sigmoid(cfg, p, logits, train=True)
         counts = jnp.sum(idx[..., None] == jnp.arange(R), axis=(0, 1),
                          dtype=jnp.float32)
         local = idx - first
@@ -755,14 +774,12 @@ def moe_serving_mlp(cfg, p: Dict, x: jax.Array,
         "nd,de->ne", tokens.astype(jnp.float32),
         p["router"].astype(jnp.float32),
     )
-    if cfg.moe_gate == "sigmoid_groups":
+    if cfg.moe_gate != "softmax":
         # the router sees every expert of the layer; the ``E`` held here
         # compute the tokens sent to them, with room for every real token
         # (no drop), and the layer returns that partial sum
         capacity = int(budget_tokens)
-        idx, w = sigmoid_group_gate(
-            router_logits, p["sel_bias"], K, cfg.moe_groups,
-            cfg.moe_groups_kept, cfg.moe_routed_scale)
+        idx, w = route_sigmoid(cfg, p, router_logits)
         tok_of_slot, slot_valid, slot_of_tok, w_of_tok, fill, unrouted = (
             held_expert_tables(idx, w, valid, cfg.moe_first_expert, E,
                                capacity))

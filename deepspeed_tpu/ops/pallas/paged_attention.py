@@ -8,7 +8,13 @@ float32 ``[B, H, S, capacity]`` score tensor whatever a slot holds; this
 kernel reads each slot's own pages through the table and its work —
 loop trips and DMA included — follows the slot's length.
 
-Shape of the kernel: one program per slot (grid ``(B,)``). The pools stay
+Shape of the kernel: one program per slot (grid ``(B,)``), or, where a
+slot's whole ``[KV, S * G, hd]`` query block with its float32 accumulators
+is over the VMEM budget (16 query heads a KV head at a 256-row chunk), one
+per slot and ROW TILE (grid ``(B, S / rows)``, :func:`row_tile`): a tile is
+a chunk of its own whose frontier is the slot's plus the rows before it, so
+a tile past the slot's real rows is idle and a decoding slot computes one
+tile, not the chunk. The pools stay
 in HBM; a loop whose trip count is read from the slot's frontier fetches
 ``pages_per_block`` pages a trip (whole pages, in the pool's own
 ``[page_size, KV, hd]`` layout: all KV heads of a page are contiguous and
@@ -55,6 +61,14 @@ VMEM_BUDGET_BYTES = 64 * 1024 * 1024
 # the page table rides in SMEM (1 MiB on the v5e; 512 KiB compiles, 1 MiB
 # does not)
 SMEM_TABLE_BYTES = 512 * 1024
+# rows of the [S * G, hd] query stack a program of the row-tiled grid takes:
+# what a slot with one real row (a decoding one) computes a key block, and
+# the inverse of how often a prompt chunk's programs re-read its K and V. At
+# 16 query heads a KV head under [8, 256] (seven slots decoding beside one
+# prompt chunk at contexts of 4-65 k; my chip runs, PR 56, PERF.md section 6)
+# 1,024 / 512 / 256 served 7,679 / 7,597 / 7,811 tokens/s and the two calls
+# took 15.4 / 14.5 / 10.7 ms of a traced step
+ROW_TILE_ROWS = 256
 
 
 def _head_tiles(buf, KV: int):
@@ -85,7 +99,7 @@ def _paged_attention_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, k_hbm,
                             v_hbm, o_ref, k_buf, v_buf, sems, kh_scr,
                             vh_scr, m_scr, l_scr, acc_scr,
                             *, scale, page_size, pages_per_block, group,
-                            window=None):
+                            window=None, tiled=False):
     KV, SG, hd = q_ref.shape[1:]
     ps, ppb = page_size, pages_per_block
     bk = ps * ppb
@@ -93,6 +107,9 @@ def _paged_attention_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, k_hbm,
     b = pl.program_id(0)
     cl = cl_ref[b]
     nn = nn_ref[b]
+    if tiled:  # this program's rows are a chunk that starts ``before`` in
+        before = pl.program_id(1) * (SG // group)
+        cl, nn = cl + before, jnp.clip(nn - before, 0, SG // group)
     layer = layer_ref[0]
 
     def page_copies(slot, j, page):
@@ -228,6 +245,23 @@ def _vmem_bytes(S, G, KV, hd, page_size, pages_per_block, q_bytes, kv_bytes):
     return scratch + kv_bufs + q_out + temps
 
 
+def row_tile(S: int, G: int, KV: int, hd: int, page_size: int,
+             pages_per_block: int, q_bytes: int, kv_bytes: int
+             ) -> Optional[int]:
+    """Query rows of a slot's chunk one program takes: all ``S`` where the
+    whole block fits the VMEM budget (one program a slot, as ever); else the
+    most rows that divide ``S`` into sublane-whole tiles of at most
+    ``ROW_TILE_ROWS`` stacked rows that fit; None if none does."""
+    def fits(rows):
+        return _vmem_bytes(rows, G, KV, hd, page_size, pages_per_block,
+                           q_bytes, kv_bytes) <= VMEM_BUDGET_BYTES
+
+    if fits(S):
+        return S
+    return next((rows for rows in range(min(S, ROW_TILE_ROWS // G), 7, -1)
+                 if S % rows == 0 and rows % 8 == 0 and fits(rows)), None)
+
+
 def key_counts(cache_len, num_new, page_size: int, max_pages: int,
                window: Optional[int] = None,
                block_k: int = DEFAULT_BLOCK_K) -> Tuple[int, int]:
@@ -283,26 +317,33 @@ def paged_attention_kernel(q, k_pool, v_pool, cache_len, page_table, *,
     ps, KV = k_pool.shape[2], k_pool.shape[3]
     mp = page_table.shape[1]
     G = H // KV
-    SG = S * G
     ppb = _block_pages(block_k, ps, mp)
+    rows = row_tile(S, G, KV, hd, ps, ppb, jnp.dtype(q.dtype).itemsize,
+                    jnp.dtype(k_pool.dtype).itemsize) or S
+    tiled = rows < S
+    SG = rows * G
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     pt = jnp.asarray(page_table, jnp.int32)
     cl, nn = _frontiers(B, S, cache_len, num_new)
     # the G query heads of a KV head stack beside each query: [S * G, hd]
-    qg = q.reshape(B, S, KV, G, hd).swapaxes(1, 2).reshape(B, KV, SG, hd)
+    qg = q.reshape(B, S, KV, G, hd).swapaxes(1, 2).reshape(B, KV, S * G, hd)
+    if tiled:  # a program a slot and row tile
+        grid, q_map = (B, S // rows), lambda b, t, *_: (b, 0, t, 0)
+    else:
+        grid, q_map = (B,), lambda b, *_: (b, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,  # page_table, cache_len, num_new, layer
-        grid=(B,),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((1, KV, SG, hd), lambda b, *_: (b, 0, 0, 0)),
+            pl.BlockSpec((1, KV, SG, hd), q_map),
             # the pool stacks stay in HBM; whole pages ([ps, KV, hd], all
             # heads contiguous) come in by async copy
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, KV, SG, hd), lambda b, *_: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, KV, SG, hd), q_map),
         scratch_shapes=[
             pltpu.VMEM((2, ppb * ps, KV, hd), k_pool.dtype),
             pltpu.VMEM((2, ppb * ps, KV, hd), v_pool.dtype),
@@ -317,12 +358,12 @@ def paged_attention_kernel(q, k_pool, v_pool, cache_len, page_table, *,
     out = pl.pallas_call(
         functools.partial(
             _paged_attention_kernel, scale=1.0 / (hd**0.5), page_size=ps,
-            pages_per_block=ppb, group=G, window=window,
+            pages_per_block=ppb, group=G, window=window, tiled=tiled,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, SG, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KV, S * G, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=("arbitrary",) * len(grid),
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
@@ -384,15 +425,15 @@ def paged_attention(q, k_pool, v_pool, cache_len, page_table, *, layer,
             f"{SMEM_TABLE_BYTES >> 10} KiB of SMEM it may take"
         )
     if not reasons:
-        need = _vmem_bytes(
-            S, H // KV, KV // tp, hd, ps, _block_pages(DEFAULT_BLOCK_K, ps, mp),
-            jnp.dtype(q.dtype).itemsize, jnp.dtype(k_pool.dtype).itemsize,
-        )
-        if need > VMEM_BUDGET_BYTES:
+        shape = (H // KV, KV // tp, hd, ps,
+                 _block_pages(DEFAULT_BLOCK_K, ps, mp),
+                 jnp.dtype(q.dtype).itemsize, jnp.dtype(k_pool.dtype).itemsize)
+        if row_tile(S, *shape) is None:
             reasons.append(
                 f"a [{S} x {H // KV}]-row chunk of {KV // tp} KV heads needs "
-                f"{need >> 20} MiB of VMEM (budget "
-                f"{VMEM_BUDGET_BYTES >> 20} MiB)"
+                f"{_vmem_bytes(S, *shape) >> 20} MiB of VMEM (budget "
+                f"{VMEM_BUDGET_BYTES >> 20} MiB) and no tile of 8 or more of "
+                f"its rows (at most {ROW_TILE_ROWS} stacked) fits either"
             )
     if reasons:
         from ...utils.logging import log_fallback_once

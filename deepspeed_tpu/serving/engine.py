@@ -1200,6 +1200,7 @@ class ServingEngine:
         # a routed model with mixers: the held experts that got a row in the
         # step folded last (the device's own count, read with its tokens)
         self._experts_touched: Optional[int] = None
+        self._held_assignments = 0
 
         def counting_step(*args):
             self.step_traces += 1
@@ -1697,6 +1698,8 @@ class ServingEngine:
                 )
                 if moe_stats[3] is not None:
                     self._experts_touched = int(moe_stats[3])
+                    # (row, chosen expert) pairs that landed on a held expert
+                    self._held_assignments = int(np.sum(moe_stats[0]))
             if self.comm_logger is not None:
                 self.comm_logger.record_streams(self.analytic_streams())
         return finished
@@ -1721,6 +1724,14 @@ class ServingEngine:
                 block_k=self.page_size)
             self.metrics.on_keys(kind, attended, fetched)
             out["attended_" + kind], out["fetched_" + kind] = attended, fetched
+        if self.config.moe_routed_experts and (
+                self._experts_touched is not None):
+            # one member's share of an expert layer: the device's own count
+            # of the step folded last (as ``_count_mixers`` carries it)
+            out["experts_touched"] = self._experts_touched
+            out["experts_held"] = (self.config.num_experts
+                                   * self.config.num_layers)
+            out["held_assignments"] = self._held_assignments
         return out
 
     def _count_selected(self, plan: StepPlan) -> Dict[str, int]:
@@ -1826,6 +1837,10 @@ class ServingEngine:
             "expert_path_reason": self.expert_path_reason,
             "kda_heads_per_program": kda_heads,
             "paged_layers": mcfg.paged_layers,
+            "parallel_block": bool(mcfg.parallel_block),
+            "shared_width": mcfg.moe_shared_width,
+            "pool_pages": {"full": self.num_pages or 0,
+                           "window": self.window_num_pages or 0},
             "residual_streams": getattr(mcfg, "hc_mult", 0) or 1,
             "state_bytes": self.metrics.state_bytes,
             "state_leaves": {

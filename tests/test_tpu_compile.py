@@ -242,6 +242,31 @@ def test_paged_attention_compiles(one_chip, B, S, pages, per_slot):
     assert "tpu_custom_call" in text, text[:2000]
 
 
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
+@pytest.mark.parametrize("S", [128, 256, 512])
+def test_paged_attention_compiles_at_16_heads_a_kv_head(one_chip, S, window):
+    """Command A+'s calls (128 query heads on 8 KV heads of 128, 8 slots of
+    66,560 tokens): the whole [8, S x 16, 128] query block with its float32
+    accumulators passes the VMEM budget from 256 rows on, so the grid takes
+    a program a slot AND row tile there; the chip's compiler takes each."""
+    B, H, KV, hd, ps, per_slot = 8, 128, 8, 128, 16, 4192
+    rows = pa.row_tile(S, H // KV, KV, hd, ps, 32, 2, 2)
+    assert rows == (S if S == 128 else 16)
+
+    def step(q, k, v, cl, nn, pt, layer):
+        return pa.paged_attention_kernel(
+            q, k, v, cl, pt, layer=layer, num_new=nn, interpret=False,
+            window=window)
+
+    text = _compile(
+        step, one_chip,
+        ((B, S, H, hd), BF16), ((1, 2049, ps, KV, hd), BF16),
+        ((1, 2049, ps, KV, hd), BF16), ((B,), I32), ((B,), I32),
+        ((B, per_slot), I32), ((), I32),
+    )
+    assert "tpu_custom_call" in text, text[:2000]
+
+
 # --------------------------------- a whole slot step beside its arena
 GIB = 2.0 ** 30
 # the slot steps' temporaries before the caches rode the layer scan as its
@@ -591,6 +616,53 @@ def test_mellum_slot_step_compiles_beside_its_arena(one_chip, monkeypatch,
     _check_weights_are_read_as_held(compiled, model, "mellum", capsys)
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * GIB
     text = compiled.as_text()
+    assert "paged_attention_window" in text and "paged_attention_full" in text
+
+
+@pytest.mark.parametrize("W", [256])
+def test_cohere_slot_step_compiles_beside_its_arena(one_chip, monkeypatch,
+                                                    capsys, W):
+    """The one [8, budget] serving step of Command A+ at its published
+    widths and the benchmark's cut (one period: three window layers and a
+    NoPE full one, 16 of 128 experts, an eighth of the vocabulary) beside
+    its arena: 33,408 pages for the full layer, 8 x 273 for the window
+    layers. The chip's compiler takes it inside the 16 GB with both named
+    attention calls, no pool re-materialised and every pool's scatter
+    taking the budget's rows."""
+    from deepspeed_tpu.models import cohere
+    from deepspeed_tpu.models.decoding import init_paged_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = cohere("command-a-plus-05-2026", num_layers=4, num_experts=16,
+                   moe_routed_experts=128, vocab_size=32768,
+                   max_seq_len=66560)
+    N, ps, cap = 8, 16, 66560
+    mp = -(-(cap + W) // ps)
+    window_pages = N * (-(-(model.config.attn_window + W) // ps) + 1)
+    caches = jax.eval_shape(lambda: init_paged_cache(
+        model.config, N * mp, ps, BF16, window_pages=window_pages))
+    compiled = _compile_slot_step(model, caches, one_chip, N, W, mp)
+    m = compiled.memory_analysis()
+    pools = sum(a.size * a.dtype.itemsize for a in caches.values())
+    with capsys.disabled():
+        print(f"\ncohere slot step [8, {W}], described v5e: arguments "
+              f"{m.argument_size_in_bytes / GIB:.2f} GiB, temporaries "
+              f"{m.temp_size_in_bytes / GIB:.2f} GiB, aliased "
+              f"{m.alias_size_in_bytes / GIB:.2f} (the pools "
+              f"{pools / GIB:.2f})")
+    text = compiled.as_text()
+    assert _pool_copies(text, caches) == []
+    assert m.alias_size_in_bytes >= pools
+    _check_pool_writes_take_the_budget(
+        compiled, caches, ("k", "v", "k_win", "v_win"), N, W, "cohere",
+        capsys)
+    _check_placement_is_one_pass(compiled, W, model.config, "cohere",
+                                 capsys)
+    _check_weights_are_read_as_held(compiled, model, "cohere", capsys)
+    # no array of the chunk's [N, W, V] logits: the head runs over N rows
+    V = model.config.vocab_size
+    assert not re.search(rf"\[({N},{W}|{N * W}),{V}\]", text)
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.0 * GIB
     assert "paged_attention_window" in text and "paged_attention_full" in text
 
 
