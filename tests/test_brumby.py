@@ -14,7 +14,6 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.config import DeepSpeedConfigError
 from deepspeed_tpu.models import brumby
-from deepspeed_tpu.models.decoding import forward_with_cache, init_paged_cache
 from deepspeed_tpu.models.mixers import layer_plan, walk_runs
 from deepspeed_tpu.ops.attention import attention_impl
 from deepspeed_tpu.serving import Request
@@ -22,8 +21,11 @@ from deepspeed_tpu.serving import Request
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from benchmarks import reference as ref  # noqa: E402
 from benchmarks.families import brumby as fam  # noqa: E402
+from slot_program import (drive, ids_of, init_params,  # noqa: E402
+                          reference_logits, schedule)
 
 F32 = jnp.float32
+logits_of = reference_logits(fam)
 # float32 against float32 on logits whose spread is about 1: what is left is
 # the order of the sums, and the state's way to (q . k) ** 2, a sum of 136
 # products of pairs where the reference squares one dot product: a weight
@@ -52,87 +54,19 @@ def model():
 
 @pytest.fixture(scope="module")
 def params(model):
-    tree = model.init(jax.random.PRNGKey(0), dtype=F32)
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(tree)
-    out = []
-    for i, (path, a) in enumerate(leaves):
-        name = getattr(path[-1], "key", "")
-        if name == "scale":  # norm scales that are not one
-            a = a * (1 + 0.1 * jax.random.normal(jax.random.PRNGKey(i),
-                                                 a.shape))
-        if name == "wg" and a.shape[-1] == 2:
-            # gates that remember: log sigmoid(x W_g + 3) is about -0.05 a
-            # row with a spread, so a state carries hundreds of rows and a
-            # fault in what it holds shows (the benchmark's draw forgets in
-            # a few tokens: PERF.md section 7)
-            a = a * 4
-        out.append(a)
-    return jax.tree_util.tree_unflatten(treedef, out)
+    # gates that remember: log sigmoid(x W_g + 3) is about -0.05 a row with a
+    # spread, so a state carries hundreds of rows and a fault in what it
+    # holds shows (the benchmark's draw forgets in a few tokens: PERF.md
+    # section 7)
+    return jax.tree_util.tree_map_with_path(
+        lambda path, a: a * 4 if (getattr(path[-1], "key", "") == "wg"
+                                  and a.shape[-1] == 2) else a,
+        init_params(model))
 
 
 @pytest.fixture(scope="module")
 def shape():
     return fam.shape_of(CONFIG)
-
-
-def ids_of(n, seed):
-    return np.random.default_rng(seed).integers(0, 512, n, dtype=np.int32)
-
-
-_STEPS = {}
-
-
-def cached_step(cfg, kernels: bool):
-    """``forward_with_cache`` of the slot step (packed rows), jitted once a
-    path."""
-    if kernels not in _STEPS:
-        def step(params, tokens, caches, start, table, num_new):
-            with attention_impl("flash" if kernels else "xla"):
-                return forward_with_cache(
-                    cfg, params, tokens, caches, start, dtype=F32,
-                    page_table=table, num_new=num_new, token_budget=W)
-
-        _STEPS[kernels] = jax.jit(step)
-    return _STEPS[kernels]
-
-
-def drive(model, params, feeds, kernels=False):
-    """Run steps of the ``[SLOTS, W]`` slot program over the pageless arena
-    (a page table of one column that no layer reads): ``feeds`` is a list of
-    steps, each {slot: (ids of the rows fed, the slot's position before
-    them)}. Returns {slot: [logits of every row fed, in order]}."""
-    cfg = model.config
-    caches = init_paged_cache(cfg, SLOTS, 256, F32, max_slots=SLOTS)
-    assert set(caches) == {"state", "norm"}
-    table = jnp.arange(SLOTS, dtype=jnp.int32)[:, None]
-    out = {s: [] for s in range(SLOTS)}
-    for feed in feeds:
-        tokens = np.zeros((SLOTS, W), np.int32)
-        num_new = np.zeros(SLOTS, np.int32)
-        start = np.zeros(SLOTS, np.int32)
-        for slot, (part, at) in feed.items():
-            tokens[slot, :len(part)] = part
-            num_new[slot], start[slot] = len(part), at
-        assert num_new.sum() <= W
-        logits, caches = cached_step(cfg, kernels)(
-            params, jnp.asarray(tokens), caches, jnp.asarray(start), table,
-            jnp.asarray(num_new))
-        for slot, (part, _) in feed.items():
-            out[slot].append(np.asarray(logits[slot, :len(part)]))
-    return out
-
-
-def schedule(seqs, sizes):
-    at = {s: 0 for s in seqs}
-    feeds = []
-    while any(at[s] < len(seqs[s]) for s in seqs):
-        feed = {}
-        for s, ids in seqs.items():
-            if at[s] < len(ids):
-                feed[s] = (ids[at[s]:at[s] + sizes[s]], at[s])
-                at[s] += sizes[s]
-        feeds.append(feed)
-    return feeds
 
 
 def test_one_run_of_one_kind_and_no_page_anywhere(model):
@@ -172,13 +106,16 @@ def test_slots_at_different_frontiers_match_the_reference(model, params,
         feeds.append({s: (more[s][j:j + 1], len(seqs[s]) + j) for s in seqs})
     again = ids_of(11, 7)
     feeds += schedule({1: again}, {1: 6})
-    got = drive(model, params, feeds, kernels)
+    # the pageless arena: a page table of one column that no layer reads
+    got, caches = drive(model, params, feeds, slots=SLOTS, width=W,
+                        pages_per_slot=1, page_size=256, kernels=kernels)
+    assert set(caches) == {"state", "norm"}
     for s in seqs:
         ids = np.concatenate([seqs[s], more[s]])
-        want = np.asarray(fam.logits(params, ids, shape))
+        want = np.asarray(logits_of(params, ids, shape))
         have = np.concatenate(got[s])[:len(ids)]
         assert np.abs(have - want).max() < TOL, (s, np.abs(have - want).max())
-    want = np.asarray(fam.logits(params, again, shape))
+    want = np.asarray(logits_of(params, again, shape))
     have = np.concatenate(got[1])[len(seqs[1]) + 3:]
     assert np.abs(have - want).max() < TOL
 
@@ -205,7 +142,7 @@ def test_a_pageless_engine_serves_what_the_reference_predicts(model, params,
     for p, st in zip(prompts, states):
         assert len(st.tokens) == 6
         ids = np.concatenate([p, np.asarray(st.tokens, np.int32)])
-        logits = fam.logits(params, ids[:-1], shape, last=6)
+        logits = logits_of(params, ids[:-1], shape, last=6)
         assert ref.served_token_gaps(logits, st.tokens).max() < TOL
     d = srv.describe()
     assert d["paged_layers"] == 0

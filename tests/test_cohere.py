@@ -7,6 +7,7 @@ seeded random weights, against the benchmark's plain reference
 paged pools, the uncached ``apply``, the serving engine, the share against
 the uncut layer, the rotary pairing rule, and the row-tiled paged kernel."""
 
+import functools
 import json
 import os
 
@@ -21,11 +22,12 @@ from deepspeed_tpu.models import cohere, mellum
 from deepspeed_tpu.models.cohere import (averaged_shared_bank,
                                          half_split_columns)
 from deepspeed_tpu.models.decoding import (_dense_cached_attention,
-                                           _paged_gather, forward_with_cache,
-                                           init_paged_cache)
+                                           _paged_gather)
 from deepspeed_tpu.models.transformer import TransformerConfig
 from deepspeed_tpu.ops.pallas import paged_attention as pa
 from deepspeed_tpu.serving import Request
+from slot_program import (ids_of, init_params, paged_forward,
+                          reference_logits)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 F32 = jnp.float32
@@ -34,6 +36,7 @@ F32 = jnp.float32
 # fault moves them by thirty times the tolerance
 RTOL = 1e-4
 PS = 16
+logits_of = reference_logits(fam)
 HELD = dict(num_experts=4, moe_routed_experts=8)  # member 0 of two
 
 
@@ -63,25 +66,15 @@ def model():
     return tiny()
 
 
-def init_params(model, seed=0):
-    tree = model.init(jax.random.PRNGKey(seed), dtype=F32)
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(tree)
-    out = []
-    for i, (path, a) in enumerate(leaves):  # norm scales not all one
-        if getattr(path[-1], "key", "") == "scale":
-            a = a * (1 + 0.2 * jax.random.normal(jax.random.PRNGKey(i),
-                                                 a.shape))
-        out.append(a)
-    return jax.tree_util.tree_unflatten(treedef, out)
-
-
 @pytest.fixture(scope="module")
 def params(model):
-    return init_params(model)
+    return init_params(model, spread=0.2)
 
 
-def ids_of(n, seed=0):
-    return np.random.default_rng(seed).integers(0, 512, size=n).astype(np.int32)
+@functools.cache
+def apply_of(model):
+    """The uncached forward in float32, under ``jit``."""
+    return jax.jit(functools.partial(model.apply, dtype=F32))
 
 
 def close(got, want, rtol=RTOL):
@@ -152,8 +145,8 @@ def test_what_the_fields_exclude_is_refused_by_name():
 
 def test_apply_computes_the_reference(model, params, shape):
     ids = ids_of(100)  # past the window (24)
-    got, _ = model.apply(params, jnp.asarray(ids[None]), dtype=F32)
-    want = fam.logits(params, ids, shape)
+    got, _ = apply_of(model)(params, jnp.asarray(ids[None]))
+    want = logits_of(params, ids, shape)
     assert close(got[0], want)
 
 
@@ -167,45 +160,6 @@ def test_every_fault_moves_the_reference_beyond_the_tolerance(
     assert not close(broken, want, rtol=30 * RTOL), fault
 
 
-def paged_forward(model, params, prompts, chunk, new_tokens=3, budget=None):
-    """Chunked prefill then decode of ``prompts`` (one a slot) through the
-    two paged pools, as the engine's step feeds them (``budget``: packed to
-    that many rows); returns the logits of every real position, a row a
-    slot."""
-    cfg = model.config
-    B = len(prompts)
-    mp = -(-(max(map(len, prompts)) + new_tokens + chunk) // PS)
-    cache = init_paged_cache(cfg, B * mp, PS, F32, window_pages=B * mp)
-    table = np.arange(B * mp, dtype=np.int32).reshape(B, mp)
-    fwd = jax.jit(lambda c, ids, cl, nn: forward_with_cache(
-        cfg, params, ids, c, cl, dtype=F32,
-        page_table=jnp.asarray(table), page_table_win=jnp.asarray(table),
-        num_new=nn, token_budget=budget,
-        token_valid=jnp.arange(chunk)[None, :] < nn[:, None]))
-    seqs = [list(p) for p in prompts]
-    done = [0] * B
-    rows = [[] for _ in range(B)]
-    for _ in range(400):
-        feed = np.zeros((B, chunk), np.int32)
-        nn = np.zeros(B, np.int32)
-        left = budget or B * chunk
-        for b in range(B):
-            n = min(chunk, len(seqs[b]) - done[b], left)
-            feed[b, :n] = seqs[b][done[b]:done[b] + n]
-            nn[b], left = n, left - n
-        if not nn.any():
-            break
-        logits, cache = fwd(cache, jnp.asarray(feed),
-                            jnp.asarray(done, jnp.int32), jnp.asarray(nn))
-        for b in range(B):
-            rows[b].extend(np.asarray(logits[b, :nn[b]]))
-            done[b] += int(nn[b])
-            if nn[b] and done[b] == len(seqs[b]) and (
-                    len(seqs[b]) < len(prompts[b]) + new_tokens):
-                seqs[b].append(int(np.argmax(rows[b][-1])))
-    return [np.stack(r) for r in rows], seqs
-
-
 @pytest.mark.parametrize("chunk,budget", [(16, None), (10, 16)],
                          ids=["slots", "packed"])
 def test_the_cached_forward_through_both_pools_is_the_reference(
@@ -213,9 +167,10 @@ def test_the_cached_forward_through_both_pools_is_the_reference(
     # a slot shorter than the window beside one far longer; chunks of 16
     # straddle the window's edge (24), chunks of 10 cut pages too
     prompts = [ids_of(13, seed=1), ids_of(150, seed=2)]
-    rows, seqs = paged_forward(model, params, prompts, chunk, budget=budget)
+    rows, seqs = paged_forward(model, params, prompts, chunk, PS,
+                               budget=budget)
     for got, seq in zip(rows, seqs):
-        want = fam.logits(params, np.asarray(seq, np.int32), shape)
+        want = logits_of(params, np.asarray(seq, np.int32), shape)
         assert got.shape == want.shape
         assert close(got, want)
 
@@ -238,7 +193,7 @@ def test_the_engine_serves_the_references_argmax(model, params, shape):
     srv.run_until_idle()
     for p, st in zip(prompts, states):
         ids = np.concatenate([p, np.asarray(st.tokens, np.int32)])
-        want = fam.logits(params, ids[:-1], shape, last=6)
+        want = logits_of(params, ids[:-1], shape, last=6)
         assert list(np.argmax(np.asarray(want), -1)) == list(st.tokens)
     snap = srv.metrics.snapshot()
     assert snap["moe_experts_touched"] > 0
@@ -252,7 +207,7 @@ def test_the_members_parts_add_up_to_the_uncut_layer(shape):
     counted once, are the uncut layer, in the reference and in the
     program."""
     whole = tiny(num_experts=8, moe_routed_experts=0)
-    full = init_params(whole, seed=4)
+    full = init_params(whole, seed=4, spread=0.2)
     uncut = fam.shape_of(tiny_config(num_experts=8))
     assert uncut.experts == uncut.routed == 8
     x = jnp.asarray(np.random.default_rng(1).normal(size=(40, 64)), F32)
@@ -322,7 +277,7 @@ def test_permuted_columns_make_rotate_half_the_published_rotation(
     """Weights in the PUBLISHED layout (interleaved rotary pairs): the
     program on ``half_split_columns`` of W_q and W_k is the reference's
     interleaved rotation on the published columns."""
-    published = init_params(model, seed=9)
+    published = init_params(model, seed=9, spread=0.2)
     attn = published["layers"]["attn"]
     cfg = model.config
     loaded = {**published, "layers": {**published["layers"], "attn": {
@@ -333,7 +288,7 @@ def test_permuted_columns_make_rotate_half_the_published_rotation(
         fam.published_columns(loaded["layers"]["attn"]["wq"], cfg.hd),
         attn["wq"])
     ids = ids_of(90, seed=5)
-    got, _ = model.apply(loaded, jnp.asarray(ids[None]), dtype=F32)
+    got, _ = apply_of(model)(loaded, jnp.asarray(ids[None]))
     # by hand: interleaved pairs on the published columns
     x = jnp.asarray(np.random.default_rng(2).normal(size=(7, 8, 16)), F32)
     r = fam._rotate(x, 3, 50000.0)
@@ -341,9 +296,9 @@ def test_permuted_columns_make_rotate_half_the_published_rotation(
     np.testing.assert_allclose(
         r[..., 0::2], x[..., 0::2] * np.cos(ang)[:, None]
         - x[..., 1::2] * np.sin(ang)[:, None], rtol=1e-5, atol=1e-5)
-    assert close(got[0], fam.logits(loaded, ids, shape))
+    assert close(got[0], logits_of(loaded, ids, shape))
     # and the published weights unpermuted are NOT the program's model
-    wrong, _ = model.apply(published, jnp.asarray(ids[None]), dtype=F32)
+    wrong, _ = apply_of(model)(published, jnp.asarray(ids[None]))
     assert not close(wrong[0], got[0], rtol=30 * RTOL)
 
 

@@ -20,16 +20,17 @@ from benchmarks.run import merged
 from deepspeed_tpu.config import DeepSpeedConfigError
 from deepspeed_tpu.models import deepseek
 from deepspeed_tpu.models.decoding import (INDEX, LATENT, _paged_gather,
-                                           forward_with_cache,
                                            init_paged_cache)
 from deepspeed_tpu.moe import sharded_moe as sm
 from deepspeed_tpu.ops.attention import attention_impl
 from deepspeed_tpu.ops.pallas import sparse_latent_attention as sla
 from deepspeed_tpu.serving import Request
+from slot_program import chunked_logits, jit_init, reference_logits
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 F32 = jnp.float32
 fam = reference.family("deepseek")
+logits_of = reference_logits(fam)
 
 
 @pytest.fixture(scope="module")
@@ -39,7 +40,7 @@ def model():
 
 @pytest.fixture(scope="module")
 def params(model):
-    p = model.init(jax.random.PRNGKey(7), dtype=F32)
+    p = jit_init(model, jax.random.PRNGKey(7))
     # a selection bias large enough to decide choices (init draws 0.02)
     bias = jax.random.normal(jax.random.PRNGKey(8),
                              p["layers"]["mlp"]["sel_bias"].shape) * 0.3
@@ -90,28 +91,11 @@ def paged_logits(model, params, ids, W=16, ps=16, slot=1, slots=2,
     """Prefill ``ids`` in chunks of ``W`` and nothing else: the logits of
     every position through the paged latent cache, slot ``slot`` of
     ``slots`` (the others idle), pages handed out in a scrambled order."""
-    cfg = model.config
     mp = -(-(len(ids) + W) // ps)
-    cache = init_paged_cache(cfg, slots * mp, ps, F32)
     order = np.random.default_rng(3).permutation(slots * mp)
-    table = jnp.asarray(order.reshape(slots, mp), jnp.int32)
-    out = []
-    step = jax.jit(lambda c, t, n, s: forward_with_cache(
-        cfg, params, t, c, s, dtype=F32, page_table=table, num_new=n,
-        token_valid=jnp.arange(W)[None, :] < n[:, None])[:2])
-    for lo in range(0, len(ids), W):
-        chunk = ids[lo:lo + W]
-        tokens = np.zeros((slots, W), np.int32)
-        tokens[slot, :len(chunk)] = chunk
-        n = np.zeros(slots, np.int32)
-        n[slot] = len(chunk)
-        start = np.zeros(slots, np.int32)
-        start[slot] = lo
-        with attention_impl("flash" if kernel else "xla"):
-            logits, cache = step(cache, jnp.asarray(tokens), jnp.asarray(n),
-                                 jnp.asarray(start))
-        out.append(np.asarray(logits[slot, :len(chunk)]))
-    return np.concatenate(out)
+    return chunked_logits(model, params, ids, order.reshape(slots, mp),
+                          slot=slot, chunk=W, page_size=ps, kernels=kernel,
+                          valid=True)
 
 
 @pytest.mark.parametrize("length", [21, 75], ids=["inside-topk", "past-topk"])
@@ -120,7 +104,7 @@ def test_the_paged_latent_cache_computes_the_reference(model, params, shape,
     """Chunks of 16 over pages of 16 (boundaries crossed, the last chunk
     ragged), a context under and one three times over ``index_topk`` 24."""
     ids = np.random.default_rng(length).integers(0, 512, length, np.int32)
-    want = np.asarray(fam.logits(params, ids, shape))
+    want = np.asarray(logits_of(params, ids, shape))
     got = paged_logits(model, params, ids)
     np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
 
@@ -130,7 +114,7 @@ def test_the_kernels_serve_what_the_dense_lines_serve(model, params, shape):
     the same logits as the reference past ``index_topk``, so the same chosen
     sets."""
     ids = np.random.default_rng(5).integers(0, 512, 60, np.int32)
-    want = np.asarray(fam.logits(params, ids, shape))
+    want = np.asarray(logits_of(params, ids, shape))
     got = paged_logits(model, params, ids, kernel=True)
     np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
 
@@ -337,7 +321,7 @@ def test_the_engine_serves_the_references_argmax_and_counts_its_work(
     assert srv.attention_path == "dense" and srv.attention_fallback
     for st in states:
         ids = np.concatenate([st.request.prompt, np.asarray(st.tokens)])
-        want = fam.logits(params, ids[:-1], shape, last=6)
+        want = logits_of(params, ids[:-1], shape, last=6)
         assert reference.served_token_gaps(want, st.tokens).max() == 0.0
     snap = srv.metrics.snapshot()
     # the plan's arithmetic: a token at position p scores p + 1 keys and
@@ -405,11 +389,11 @@ def test_training_refuses_the_indexer_by_name_and_runs_the_rest(model, params):
 
     plain = TransformerModel(dataclasses.replace(
         model.config, index_topk=0, index_heads=0, index_dim=0))
-    p = plain.init(jax.random.PRNGKey(7), dtype=F32)
+    p = jit_init(plain, jax.random.PRNGKey(7))
     ids = jax.random.randint(jax.random.PRNGKey(2), (2, 16), 0, 512)
     batch = {"input_ids": ids, "labels": jnp.roll(ids, -1, axis=1)}
-    (loss, m), g = jax.value_and_grad(
-        lambda p: plain.loss(p, batch, dtype=F32), has_aux=True)(p)
+    (loss, m), g = jax.jit(jax.value_and_grad(
+        lambda p: plain.loss(p, batch, dtype=F32), has_aux=True))(p)
     assert np.isfinite(float(loss)) and float(m["moe_rows_held"]) > 0
     for leaf in (g["lead_layers"]["mlp"]["wi"], g["layers"]["attn"]["wkv_b"],
                  g["layers"]["mlp"]["router"], g["layers"]["mlp"]["wo"],
@@ -421,12 +405,13 @@ def test_training_refuses_the_indexer_by_name_and_runs_the_rest(model, params):
 
     base = mixtral("mixtral-tiny").config
     shared = TransformerModel(dataclasses.replace(base, moe_shared_width=16))
-    ps = shared.init(jax.random.PRNGKey(3), dtype=F32)
+    ps = jit_init(shared, jax.random.PRNGKey(3))
     ids = ids % base.vocab_size
     batch = {"input_ids": ids, "labels": jnp.roll(ids, -1, axis=1)}
-    with_it = shared.loss(ps, batch, dtype=F32)[0]
+    loss_of = jax.jit(lambda p: shared.loss(p, batch, dtype=F32)[0])
+    with_it = loss_of(ps)
     ps["layers"]["mlp"]["shared"]["wo"] *= 0.0
-    assert float(jnp.abs(with_it - shared.loss(ps, batch, dtype=F32)[0])) > 0
+    assert float(jnp.abs(with_it - loss_of(ps))) > 0
     # ... and one member's share of a softmax-routed layer is refused where
     # the configuration is made, by its own name
     with pytest.raises(ValueError, match="moe_routed_experts"):

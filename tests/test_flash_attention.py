@@ -43,8 +43,8 @@ def test_grads_match_reference(causal):
     def loss_ref(q, k, v):
         return jnp.sum(xla_attention(q, k, v, causal=causal) ** 2)
 
-    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    g_flash = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for gf, gr, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(
             np.asarray(gf), np.asarray(gr), atol=5e-4, err_msg=f"d{name}"
@@ -54,13 +54,13 @@ def test_grads_match_reference(causal):
 def test_gqa_grads():
     q, k, v = _qkv(jax.random.PRNGKey(3), B=1, S=128, H=4, KV=2, D=64)
 
-    g_flash = jax.grad(
+    g_flash = jax.jit(jax.grad(
         lambda *a: jnp.sum(flash_attention(*a, causal=True, block_q=128, block_k=128) ** 2),
         argnums=(0, 1, 2),
-    )(q, k, v)
-    g_ref = jax.grad(
+    ))(q, k, v)
+    g_ref = jax.jit(jax.grad(
         lambda *a: jnp.sum(xla_attention(*a, causal=True) ** 2), argnums=(0, 1, 2)
-    )(q, k, v)
+    ))(q, k, v)
     for gf, gr, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(
             np.asarray(gf), np.asarray(gr), atol=5e-4, err_msg=f"d{name}"
@@ -105,7 +105,7 @@ def test_sharded_flash_matches_reference(devices8):
         g = jax.jit(
             jax.grad(lambda q, k, v: jnp.sum(flash_attention(q, k, v) ** 2), argnums=0)
         )(q, k, v)
-    g_ref = jax.grad(lambda q, k, v: jnp.sum(xla_attention(q, k, v, causal=True) ** 2))(q, k, v)
+    g_ref = jax.jit(jax.grad(lambda q, k, v: jnp.sum(xla_attention(q, k, v, causal=True) ** 2)))(q, k, v)
     np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), atol=5e-4)
 
 
@@ -156,17 +156,17 @@ def test_segment_ids_in_kernel(causal):
 def test_segment_ids_grads():
     q, k, v = _qkv(jax.random.PRNGKey(9), B=1, S=256, H=2, D=64)
     seg = _segments(1, 256)
-    g_flash = jax.grad(
+    g_flash = jax.jit(jax.grad(
         lambda *a: jnp.sum(
             flash_attention(*a, causal=True, segment_ids=seg,
                             block_q=128, block_k=128) ** 2
         ),
         argnums=(0, 1, 2),
-    )(q, k, v)
-    g_ref = jax.grad(
+    ))(q, k, v)
+    g_ref = jax.jit(jax.grad(
         lambda *a: jnp.sum(xla_attention(*a, causal=True, segment_ids=seg) ** 2),
         argnums=(0, 1, 2),
-    )(q, k, v)
+    ))(q, k, v)
     for gf, gr, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(
             np.asarray(gf), np.asarray(gr), atol=5e-4, err_msg=f"d{name}"
@@ -186,15 +186,15 @@ def test_alibi_slopes_in_kernel():
     ref = xla_attention(q, k, v, causal=True, alibi_slopes=slopes)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
-    g = jax.grad(
+    g = jax.jit(jax.grad(
         lambda *a: jnp.sum(
             flash_attention(*a, causal=True, alibi_slopes=slopes,
                             block_q=128, block_k=128) ** 2
         )
-    )(q, k, v)
-    g_ref = jax.grad(
+    ))(q, k, v)
+    g_ref = jax.jit(jax.grad(
         lambda *a: jnp.sum(xla_attention(*a, causal=True, alibi_slopes=slopes) ** 2)
-    )(q, k, v)
+    ))(q, k, v)
     np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), atol=5e-4)
 
 
@@ -342,11 +342,11 @@ def test_dots_flash_policy_grads_match():
     def loss(q, k, v):
         return (flash_attention(q, k, v, interpret=True) ** 2).sum()
 
-    ref = jax.grad(loss)(q, k, v)
-    got = jax.grad(
+    ref = jax.jit(jax.grad(loss))(q, k, v)
+    got = jax.jit(jax.grad(
         jax.checkpoint(loss, policy=policy_by_name("dots_flash"),
                        prevent_cse=False)
-    )(q, k, v)
+    ))(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
 
 
@@ -383,12 +383,12 @@ def test_dense_bias_grads_including_dbias(bias_bh, kv_heads):
             fn(q, k, v, causal=True, bias=b) ** 2
         )
 
-    g_flash = jax.grad(
+    g_flash = jax.jit(jax.grad(
         loss(lambda q, k, v, causal, bias: flash_attention(
             q, k, v, causal=causal, bias=bias, block_q=128, block_k=128)),
         argnums=(0, 1, 2, 3),
-    )(q, k, v, bias)
-    g_ref = jax.grad(loss(xla_attention), argnums=(0, 1, 2, 3))(q, k, v, bias)
+    ))(q, k, v, bias)
+    g_ref = jax.jit(jax.grad(loss(xla_attention), argnums=(0, 1, 2, 3)))(q, k, v, bias)
     for gf, gr, name in zip(g_flash, g_ref, ["q", "k", "v", "bias"]):
         np.testing.assert_allclose(
             np.asarray(gf), np.asarray(gr), atol=1e-3, err_msg=f"d{name}"
@@ -620,7 +620,7 @@ def test_bwd_tiles_independent_of_fwd_tiles(causal):
             return jnp.sum(flash_attention(
                 q, k, v, causal=causal, block_q=128, block_k=256,
                 block_q_bwd=bqb, block_k_bwd=bkb) ** 2)
-        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+        return jax.jit(jax.grad(f, argnums=(0, 1, 2)))(q, k, v)
 
     base = loss(0, 0)           # inherit fwd tiles (128, 256)
     asym = loss(256, 128)       # bwd q-tile 2x fwd, bwd k-tile HALF fwd —
@@ -641,9 +641,9 @@ def test_bwd_tiles_scope_and_config():
     def g(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal=True) ** 2)
 
-    base = jax.grad(g)(q, k, v)
+    base = jax.jit(jax.grad(g))(q, k, v)
     with block_sizes_scope(128, 128, 256, 128):
-        scoped = jax.grad(g)(q, k, v)
+        scoped = jax.jit(jax.grad(g))(q, k, v)
     np.testing.assert_allclose(np.asarray(base), np.asarray(scoped),
                                atol=2e-5)
 
@@ -653,7 +653,7 @@ def test_bwd_tiles_scope_and_config():
         return jnp.sum(flash_attention(
             q, k, v, causal=True, block_mask=mask,
             block_q=128, block_k=128, block_q_bwd=64, block_k_bwd=64) ** 2)
-    out = jax.grad(gm)(q, k, v)
+    out = jax.jit(jax.grad(gm))(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(base), atol=2e-5)
 
 

@@ -6,6 +6,7 @@ multi-token-prediction loss, each against the plain reference
 ``benchmarks/families/glm4_moe_lite.py``."""
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -22,6 +23,7 @@ from deepspeed_tpu.config import DeepSpeedConfigError
 from deepspeed_tpu.models import glm
 from deepspeed_tpu.models.transformer import (_mlp, make_lm_batch, mtp_labels)
 from deepspeed_tpu.moe import sharded_moe as sm
+from slot_program import jit_init
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 F32 = jnp.float32
@@ -42,7 +44,7 @@ def model():
 
 @pytest.fixture(scope="module")
 def params(model):
-    p = model.init(jax.random.PRNGKey(7), dtype=F32)
+    p = jit_init(model, jax.random.PRNGKey(7))
     # a selection bias large enough to decide choices (init draws 0.02)
     for stack, key in ((p["layers"], 8), (p["mtp"]["layers"], 9)):
         stack["mlp"]["sel_bias"] = 0.3 * jax.random.normal(
@@ -61,9 +63,16 @@ def ids():
     return np.asarray(jax.random.randint(jax.random.PRNGKey(3), (S,), 0, 512))
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def program_loss(model, params, ids):
     return model.loss(params, make_lm_batch(jnp.asarray(ids)[None]),
                       dtype=F32)
+
+
+# the sound reference's three losses and its gradients, a compile each and
+# not one an operation
+ref_losses = jax.jit(fam.losses, static_argnames=("shape",))
+ref_grads = jax.jit(fam.grads, static_argnames=("shape",))
 
 
 def test_the_tiny_preset_is_the_rehearsals_shape(model, shape):
@@ -106,7 +115,7 @@ def test_num_params_is_the_count_of_leaves_and_the_configs_706_million(model):
 
 def test_the_loss_is_the_references(model, params, shape, ids):
     total, m = program_loss(model, params, ids)
-    want, main, mtp = fam.losses(params, ids, shape)
+    want, main, mtp = ref_losses(params, ids, shape)
     np.testing.assert_allclose(total, want, rtol=2e-6)
     np.testing.assert_allclose(m["lm_loss"], main, rtol=2e-6)
     np.testing.assert_allclose(m["mtp_loss"], mtp, rtol=2e-6)
@@ -117,8 +126,8 @@ def test_the_loss_is_the_references(model, params, shape, ids):
 
 
 def test_the_gradients_are_the_references(model, params, shape, ids):
-    got = jax.grad(lambda p: program_loss(model, p, ids)[0])(params)
-    want = fam.grads(params, ids, shape)
+    got = jax.jit(jax.grad(lambda p: program_loss(model, p, ids)[0]))(params)
+    want = ref_grads(params, ids, shape)
     flat_got = jax.tree_util.tree_leaves_with_path(got)
     flat_want = jax.tree.leaves(want)
     assert len(flat_got) == len(flat_want)
@@ -139,12 +148,14 @@ def test_every_fault_moves_the_reference(params, shape, ids, fault):
     """Each fault moves the reference's loss by far more than float32
     rounding, or (a change of the weight of the MTP loss aside) its
     gradients."""
-    sound = float(fam.losses(params, ids, shape)[0])
+    sound = float(ref_losses(params, ids, shape)[0])
+    # (a broken reference runs as the benchmark runs it, its pieces jitted
+    # and shared: whole, every fault would be a compile of its own)
     broken = float(fam.losses(params, ids, shape, fault=fault)[0])
     moved = abs(broken - sound) / sound
     if moved > 1e-4:
         return
-    g0 = fam.grads(params, ids, shape)
+    g0 = ref_grads(params, ids, shape)
     g1 = fam.grads(params, ids, shape, fault=fault)
     worst = max(float(jnp.abs(a - b).max() / (jnp.abs(a).max() + 1e-12))
                 for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)))
@@ -165,7 +176,7 @@ def test_the_mtp_module_predicts_the_token_after_next_and_shares_the_head(
     # ... so the embedding and the head take gradient from both losses
     only_mtp = dataclasses.replace(model.config, mtp_loss_weight=1e3)
     ids = jnp.arange(40)[None] % 7
-    g = [jax.grad(lambda p: glm_loss(cfg, p, ids))(params)
+    g = [jax.jit(jax.grad(lambda p: glm_loss(cfg, p, ids)))(params)
          for cfg in (model.config, only_mtp)]
     for leaf in (lambda t: t["embed"]["tok"], lambda t: t["lm_head"]):
         assert float(jnp.abs(leaf(g[1])).max()) > 10 * float(
@@ -216,8 +227,10 @@ def test_the_members_shares_add_up_to_the_uncut_layer(model):
     def layer(x):
         return shared(x) + sm.moe_held_layer(uncut, whole, x)[0]
 
-    np.testing.assert_allclose(summed(x), layer(x), rtol=1e-5, atol=1e-6)
-    dx = [jax.grad(lambda x: jnp.sum(fn(x) * ct))(x) for fn in (summed, layer)]
+    np.testing.assert_allclose(jax.jit(summed)(x), jax.jit(layer)(x),
+                               rtol=1e-5, atol=1e-6)
+    dx = [jax.jit(jax.grad(lambda x: jnp.sum(fn(x) * ct)))(x)
+          for fn in (summed, layer)]
     np.testing.assert_allclose(dx[0], dx[1], rtol=1e-5, atol=1e-6)
     # the counts every member sees are the layer's, its held rows its own
     stats = [member(x, m)[1] for m in range(members)]
@@ -364,13 +377,14 @@ def test_the_traced_step_carries_the_new_scopes_and_counters(model, params):
 
 def test_forward_of_a_latent_model_runs_and_the_indexer_is_still_refused(
         model, params):
-    logits, _ = model.apply(params, jnp.zeros((1, 8), jnp.int32), dtype=F32)
+    logits, _ = jax.jit(lambda p, ids: model.apply(p, ids, dtype=F32))(
+        params, jnp.zeros((1, 8), jnp.int32))
     assert logits.shape == (1, 8, 512) and bool(jnp.isfinite(logits).all())
     from deepspeed_tpu.models import deepseek
 
     with pytest.raises(DeepSpeedConfigError, match="index_topk"):
         deepseek("deepseek-tiny").loss(
-            deepseek("deepseek-tiny").init(jax.random.PRNGKey(0)),
+            jax.jit(deepseek("deepseek-tiny").init)(jax.random.PRNGKey(0)),
             make_lm_batch(jnp.zeros((1, 8), jnp.int32)))
 
 

@@ -25,8 +25,11 @@ from deepspeed_tpu.serving import Request
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from benchmarks.families import glm5_next as fam  # noqa: E402
+from slot_program import (drive, ids_of, init_params,  # noqa: E402
+                          reference_logits, schedule)
 
 F32 = jnp.float32
+logits_of = reference_logits(fam)
 # float32 against float32 on logits whose spread is about 1: what is left is
 # the order of the sums (the KDA chunk form's cumulative log-decays reach 80
 # a sub-block, so a decay carries 1e-5 of relative rounding; twenty Sinkhorn
@@ -71,18 +74,6 @@ def model():
     return tiny()
 
 
-def init_params(model, seed=0):
-    tree = model.init(jax.random.PRNGKey(seed), dtype=F32)
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(tree)
-    out = []
-    for i, (path, a) in enumerate(leaves):  # norm scales and gains not one
-        if getattr(path[-1], "key", "") == "scale":
-            a = a * (1 + 0.1 * jax.random.normal(jax.random.PRNGKey(i),
-                                                 a.shape))
-        out.append(a)
-    return jax.tree_util.tree_unflatten(treedef, out)
-
-
 @pytest.fixture(scope="module")
 def params(model):
     return init_params(model)
@@ -91,61 +82,6 @@ def params(model):
 @pytest.fixture(scope="module")
 def shape():
     return fam.shape_of(CONFIG)
-
-
-def ids_of(n, seed):
-    return np.random.default_rng(seed).integers(0, 512, n, dtype=np.int32)
-
-
-def drive(model, params, feeds):
-    """Run steps of the ``[SLOTS, W]`` slot program: ``feeds`` is a list of
-    steps, each {slot: (ids of the rows fed, the slot's position before
-    them)}; at most W rows a step in all. Returns {slot: [logits of every
-    row fed, in order]}."""
-    cfg = model.config
-    mp = 16
-
-    @jax.jit
-    def step(params, tokens, caches, start, table, num_new):
-        return forward_with_cache(
-            cfg, params, tokens, caches, start, dtype=F32, page_table=table,
-            num_new=num_new, token_budget=W)
-
-    caches = init_paged_cache(cfg, SLOTS * mp, PS, F32, max_slots=SLOTS)
-    table = np.arange(SLOTS * mp, dtype=np.int32).reshape(SLOTS, mp)
-    out = {s: [] for s in range(SLOTS)}
-    for feed in feeds:
-        tokens = np.zeros((SLOTS, W), np.int32)
-        num_new = np.zeros(SLOTS, np.int32)
-        start = np.zeros(SLOTS, np.int32)
-        for slot, (part, at) in feed.items():
-            tokens[slot, :len(part)] = part
-            num_new[slot], start[slot] = len(part), at
-        assert num_new.sum() <= W
-        live = np.where((num_new > 0)[:, None], table, SLOTS * mp)
-        logits, caches = step(
-            params, jnp.asarray(tokens), caches, jnp.asarray(start),
-            jnp.asarray(live), jnp.asarray(num_new))
-        for slot, (part, _) in feed.items():
-            out[slot].append(np.asarray(logits[slot, :len(part)]))
-    return out
-
-
-def schedule(seqs, sizes):
-    """Feeds that run ``seqs`` {slot: ids} side by side, slot ``s`` in chunks
-    whose sizes cycle through ``sizes[s]``."""
-    at = {s: 0 for s in seqs}
-    turn = {s: 0 for s in seqs}
-    feeds = []
-    while any(at[s] < len(seqs[s]) for s in seqs):
-        feed = {}
-        for s, ids in seqs.items():
-            if at[s] < len(ids):
-                n = sizes[s][turn[s] % len(sizes[s])]
-                feed[s] = (ids[at[s]:at[s] + n], at[s])
-                at[s], turn[s] = at[s] + n, turn[s] + 1
-        feeds.append(feed)
-    return feeds
 
 
 def test_the_plan_names_both_families_kinds(model):
@@ -201,9 +137,10 @@ def test_chunks_that_cut_pooled_blocks_then_decode_match_the_reference(
     tokens + tail) many times over."""
     seqs = {0: ids_of(150, 1), 1: ids_of(61, 2), 2: ids_of(94, 3)}
     sizes = {0: [5, 7, 3, 1, 6, 2], 1: [3, 1, 1, 4, 2], 2: [6, 1, 5, 3]}
-    got = drive(model, params, schedule(seqs, sizes))
+    got, _ = drive(model, params, schedule(seqs, sizes), slots=SLOTS,
+                   width=W, pages_per_slot=16, page_size=PS)
     for slot, ids in seqs.items():
-        want = np.asarray(fam.logits(params, ids, shape))
+        want = np.asarray(logits_of(params, ids, shape))
         np.testing.assert_allclose(np.concatenate(got[slot]), want,
                                    atol=TOL, rtol=0)
 
@@ -223,7 +160,7 @@ def test_engine_serves_what_the_reference_predicts(model, params, shape):
     srv.run_until_idle()
     for p, st in zip(prompts, states):
         ids = np.concatenate([p, np.asarray(st.tokens, np.int32)])
-        want = np.asarray(fam.logits(params, ids[:-1], shape, last=8))
+        want = np.asarray(logits_of(params, ids[:-1], shape, last=8))
         gaps = want.max(-1) - want[np.arange(8), st.tokens]
         assert gaps.max() <= TOL, gaps
     snap = srv.metrics.snapshot()

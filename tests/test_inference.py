@@ -949,11 +949,12 @@ def test_carried_cache_is_bitwise_the_layer_loop(family, layout, quantized,
     if layout != "scalar":
         kw["num_new"] = jnp.asarray([S, 3, S], jnp.int32)
     got = want = (None, cache)
+    fwd = jax.jit(lambda ids, c, cl: forward_with_cache(
+        cfg, params, ids, c, cl, dtype=jnp.float32, **kw))
     with attention_impl(impl):
         for chunk in ids:
             args = (cfg, params, jnp.asarray(chunk))
-            got = jax.jit(lambda c, cl, a=args: forward_with_cache(
-                *a, c, cl, dtype=jnp.float32, **kw))(got[1], frontier)
+            got = fwd(args[2], got[1], frontier)
             want = layer_loop_forward(*args, want[1], frontier, **kw)
             assert_bitwise(got, want, atol=0.0 if exact else 1e-6)
             frontier = frontier + S

@@ -4,6 +4,7 @@ beside latent attention over the latent pool, under one member's share of a
 sigmoid-routed layer, against the benchmark's plain reference
 (benchmarks/families/bailing_hybrid.py)."""
 
+import functools
 import sys
 from pathlib import Path
 
@@ -15,8 +16,7 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.config import DeepSpeedConfigError
 from deepspeed_tpu.models import ling
-from deepspeed_tpu.models.decoding import (_paged_gather, forward_with_cache,
-                                           init_paged_cache)
+from deepspeed_tpu.models.decoding import _paged_gather
 from deepspeed_tpu.models.mixers import layer_plan
 from deepspeed_tpu.models.transformer import TransformerConfig
 from deepspeed_tpu.ops.attention import attention_impl
@@ -27,8 +27,11 @@ from deepspeed_tpu.serving import Request
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from benchmarks import reference as ref  # noqa: E402
 from benchmarks.families import bailing_hybrid as fam  # noqa: E402
+from slot_program import (drive, ids_of, init_params,  # noqa: E402
+                          reference_logits, schedule)
 
 F32 = jnp.float32
+logits_of = reference_logits(fam)
 # float32 against float32 on logits whose spread is about 1: what is left is
 # the order of the sums (the chunk form's cumulative log-decays reach 80 a
 # sub-block, so a decay carries 1e-5 of relative rounding)
@@ -36,6 +39,7 @@ TOL = 2e-4
 PS, W, SLOTS = 16, 16, 3
 SERVING = dict(max_slots=SLOTS, token_budget=W, max_tokens=240, paged=True,
                page_size=PS, prefix_cache=False)
+ARENA = dict(slots=SLOTS, width=W, pages_per_slot=16, page_size=PS)
 IDS = list(range(12))  # two whole periods K K K K K M of the tiny preset
 HELD = dict(num_experts=4, moe_routed_experts=16)
 CONFIG = dict(
@@ -64,18 +68,6 @@ def model():
     return tiny()
 
 
-def init_params(model, seed=0):
-    tree = model.init(jax.random.PRNGKey(seed), dtype=F32)
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(tree)
-    out = []
-    for i, (path, a) in enumerate(leaves):  # norm scales that are not one
-        if getattr(path[-1], "key", "") == "scale":
-            a = a * (1 + 0.1 * jax.random.normal(jax.random.PRNGKey(i),
-                                                 a.shape))
-        out.append(a)
-    return jax.tree_util.tree_unflatten(treedef, out)
-
-
 @pytest.fixture(scope="module")
 def params(model):
     return init_params(model)
@@ -84,71 +76,6 @@ def params(model):
 @pytest.fixture(scope="module")
 def shape():
     return fam.shape_of(CONFIG)
-
-
-def ids_of(n, seed):
-    return np.random.default_rng(seed).integers(0, 512, n, dtype=np.int32)
-
-
-_STEPS = {}
-
-
-def cached_step(cfg, kernels: bool):
-    """``forward_with_cache`` of the slot step (packed rows), jitted once a
-    path."""
-    if kernels not in _STEPS:
-        def step(params, tokens, caches, start, table, num_new):
-            with attention_impl("flash" if kernels else "xla"):
-                return forward_with_cache(
-                    cfg, params, tokens, caches, start, dtype=F32,
-                    page_table=table, num_new=num_new, token_budget=W)
-
-        _STEPS[kernels] = jax.jit(step)
-    return _STEPS[kernels]
-
-
-def drive(model, params, feeds, kernels=False):
-    """Run steps of the ``[SLOTS, W]`` slot program: ``feeds`` is a list of
-    steps, each {slot: (ids of the rows fed, the slot's position before
-    them)}; at most W rows a step in all (the scheduler's promise). Returns
-    {slot: [logits of every row fed, in order]} and the caches."""
-    cfg = model.config
-    mp = 16
-    caches = init_paged_cache(cfg, SLOTS * mp, PS, F32, max_slots=SLOTS)
-    table = (np.arange(SLOTS * mp, dtype=np.int32).reshape(SLOTS, mp))
-    out = {s: [] for s in range(SLOTS)}
-    for feed in feeds:
-        tokens = np.zeros((SLOTS, W), np.int32)
-        num_new = np.zeros(SLOTS, np.int32)
-        start = np.zeros(SLOTS, np.int32)
-        for slot, (part, at) in feed.items():
-            tokens[slot, :len(part)] = part
-            num_new[slot], start[slot] = len(part), at
-        assert num_new.sum() <= W
-        # an idle slot's row of the table is all NULL pages, as the
-        # scheduler hands it: its padded writes land in the sink
-        live = np.where((num_new > 0)[:, None], table, SLOTS * mp)
-        logits, caches = cached_step(cfg, kernels)(
-            params, jnp.asarray(tokens), caches, jnp.asarray(start),
-            jnp.asarray(live), jnp.asarray(num_new))
-        for slot, (part, _) in feed.items():
-            out[slot].append(np.asarray(logits[slot, :len(part)]))
-    return out, caches
-
-
-def schedule(seqs, sizes):
-    """Feeds that prefill ``seqs`` {slot: ids} side by side, slot ``s`` in
-    chunks of ``sizes[s]`` rows."""
-    at = {s: 0 for s in seqs}
-    feeds = []
-    while any(at[s] < len(seqs[s]) for s in seqs):
-        feed = {}
-        for s, ids in seqs.items():
-            if at[s] < len(ids):
-                feed[s] = (ids[at[s]:at[s] + sizes[s]], at[s])
-                at[s] += sizes[s]
-        feeds.append(feed)
-    return feeds
 
 
 def test_the_plan_names_every_layers_two_halves(model):
@@ -186,13 +113,13 @@ def test_slots_at_different_frontiers_match_the_reference(model, params,
         feeds.append({s: (more[s][j:j + 1], len(seqs[s]) + j) for s in seqs})
     again = ids_of(19, 7)
     feeds += schedule({1: again}, {1: 6})
-    got, _ = drive(model, params, feeds, kernels)
+    got, _ = drive(model, params, feeds, kernels=kernels, **ARENA)
     for s in seqs:
         ids = np.concatenate([seqs[s], more[s]])
-        want = np.asarray(fam.logits(params, ids, shape))
+        want = np.asarray(logits_of(params, ids, shape))
         have = np.concatenate(got[s])[:len(ids)]
         assert np.abs(have - want).max() < TOL, (s, np.abs(have - want).max())
-    want = np.asarray(fam.logits(params, again, shape))
+    want = np.asarray(logits_of(params, again, shape))
     have = np.concatenate(got[1])[len(seqs[1]) + 4:]
     assert np.abs(have - want).max() < TOL
 
@@ -214,7 +141,7 @@ def test_engine_serves_what_the_reference_predicts(model, params, shape):
     for p, st in zip(prompts, states):
         assert len(st.tokens) == 6
         ids = np.concatenate([p, np.asarray(st.tokens, np.int32)])
-        logits = fam.logits(params, ids[:-1], shape, last=6)
+        logits = logits_of(params, ids[:-1], shape, last=6)
         assert ref.served_token_gaps(logits, st.tokens).max() < TOL
     snap = srv.metrics.snapshot()
     assert snap["state_resets"] == 4
@@ -247,6 +174,7 @@ def test_the_step_says_which_way_each_slot_takes(model, params):
     assert d["kda_heads_per_program"] == model.config.num_heads == 4
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))
 def _kda_operands(B, S, H, hd, decay=None, layers=2):
     k_ = jax.random.split(jax.random.PRNGKey(3), 6)
     nrm = lambda key, *s: jax.random.normal(key, s, F32)
@@ -259,6 +187,19 @@ def _kda_operands(B, S, H, hd, decay=None, layers=2):
     return q, k, v, g, beta, nrm(k_[5], layers, B, H, hd, hd)
 
 
+@functools.cache
+def _kda_calls(budget):
+    """The kernel's call (interpret mode), its dense twin and the reference's
+    recurrence, each under ``jit``: once per ``BLOCK_VMEM_BYTES`` a test
+    sets, which a trace of the kernel's reads."""
+    def rule(*rows):
+        with jax.default_matmul_precision("highest"):
+            return fam._delta_rule(*rows)
+
+    return (jax.jit(ka.kda_attention, static_argnames=("scale", "interpret")),
+            jax.jit(ka.dense_kda, static_argnames=("scale",)), jax.jit(rule))
+
+
 def _check_kda_against_the_recurrence(q, k, v, g, beta, stack, cl, nn, layer):
     """The kernel's call and ``dense_kda`` against the reference's row-by-row
     recurrence, slot by slot; a slot with no real row keeps its state bit
@@ -267,12 +208,11 @@ def _check_kda_against_the_recurrence(q, k, v, g, beta, stack, cl, nn, layer):
 
     B, S, H, hd = q.shape
     scale = hd ** -0.5
-    whole, first, after = ka.kda_attention(
-        q, k, v, g, beta, stack, cl, nn, layer=layer, scale=scale,
-        interpret=True)
+    kernel, dense, rule = _kda_calls(ka.BLOCK_VMEM_BYTES)
+    whole, first, after = kernel(q, k, v, g, beta, stack, cl, nn, layer=layer,
+                                 scale=scale, interpret=True)
     o = ChunkRows(B, S, cl).pack_split(whole, first, nn > 1).reshape(q.shape)
-    o2, after2 = ka.dense_kda(q, k, v, g, beta, stack[layer], cl, nn,
-                              scale=scale)
+    o2, after2 = dense(q, k, v, g, beta, stack[layer], cl, nn, scale=scale)
     for other in range(stack.shape[0]):
         if other != layer:
             assert bool((after[other] == stack[other]).all())
@@ -281,9 +221,8 @@ def _check_kda_against_the_recurrence(q, k, v, g, beta, stack, cl, nn, layer):
         if n == 0:  # no real row: bit for bit
             assert bool((after[layer, b] == stack[layer, b]).all())
         s0 = jnp.zeros((H, hd, hd)) if int(cl[b]) == 0 else stack[layer, b]
-        with jax.default_matmul_precision("highest"):
-            o3, after3 = fam._delta_rule(q[b, :n] * scale, k[b, :n], v[b, :n],
-                                         g[b, :n], beta[b, :n], s0)
+        o3, after3 = rule(q[b, :n] * scale, k[b, :n], v[b, :n], g[b, :n],
+                          beta[b, :n], s0)
         for have in (o[b, :n], o2[b, :n]):
             assert float(jnp.abs(have - o3).max()) < TOL if n else True
         for have in (after[layer, b], after2[b]):

@@ -34,6 +34,7 @@ from deepspeed_tpu.ops.pallas.paged_attention import (
 )
 from deepspeed_tpu.serving import Request
 from deepspeed_tpu.serving.request import RequestStatus
+from slot_program import ids_of, jit_init, paged_forward, reference_logits
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # float32 on both sides, the same equations in another order of summation:
@@ -41,6 +42,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # them by a hundred times the tolerance or more
 RTOL = 1e-4
 WINDOW, PS = 24, 16  # the window is not a multiple of the page
+logits_of = reference_logits(fam)
 
 
 def tiny_config():
@@ -69,17 +71,13 @@ def model():
 
 @pytest.fixture(scope="module")
 def params(model):
-    p = model.init(jax.random.PRNGKey(0), dtype=jnp.float32)
+    p = jit_init(model, jax.random.PRNGKey(0))
     attn = p["layers"]["attn"]
     for i, name in enumerate(("q_norm", "k_norm")):  # not all ones
         s = attn[name]["scale"]
         attn[name]["scale"] = 1.0 + 0.2 * jax.random.normal(
             jax.random.PRNGKey(7 + i), s.shape)
     return p
-
-
-def ids_of(n, seed=0):
-    return np.random.default_rng(seed).integers(0, 512, size=n).astype(np.int32)
 
 
 def close(got, want, rtol=RTOL):
@@ -146,8 +144,9 @@ def test_yarn_table_against_numbers_worked_by_hand():
 
 def test_apply_computes_the_reference(model, params, shape):
     ids = ids_of(100)  # past the window (24) and YaRN's original length (32)
-    got, _ = model.apply(params, jnp.asarray(ids[None]), dtype=jnp.float32)
-    want = fam.logits(params, ids, shape)
+    got, _ = jax.jit(lambda p, i: model.apply(p, i, dtype=jnp.float32))(
+        params, jnp.asarray(ids[None]))
+    want = logits_of(params, ids, shape)
     assert close(got[0], want)
 
 
@@ -161,52 +160,14 @@ def test_every_fault_moves_the_reference_beyond_the_tolerance(
     assert not close(broken, want, rtol=100 * RTOL), fault
 
 
-def paged_forward(model, params, prompts, chunk, new_tokens=3):
-    """Chunked prefill then decode of ``prompts`` (one a slot) through the
-    two paged pools, as the engine's step feeds them; returns the logits of
-    every real position, a row a slot."""
-    cfg = model.config
-    B = len(prompts)
-    mp = -(-(max(map(len, prompts)) + new_tokens + chunk) // PS)
-    cache = init_paged_cache(cfg, B * mp, PS, jnp.float32,
-                             window_pages=B * mp)
-    table = np.arange(B * mp, dtype=np.int32).reshape(B, mp)
-    fwd = jax.jit(lambda c, ids, cl, nn: forward_with_cache(
-        cfg, params, ids, c, cl, dtype=jnp.float32,
-        page_table=jnp.asarray(table), page_table_win=jnp.asarray(table),
-        num_new=nn,
-        token_valid=jnp.arange(chunk)[None, :] < nn[:, None]))
-    seqs = [list(p) for p in prompts]
-    done = [0] * B
-    rows = [[] for _ in range(B)]
-    for _ in range(200):
-        feed = np.zeros((B, chunk), np.int32)
-        nn = np.zeros(B, np.int32)
-        for b in range(B):
-            n = min(chunk, len(seqs[b]) - done[b])
-            feed[b, :n] = seqs[b][done[b]:done[b] + n]
-            nn[b] = n
-        if not nn.any():
-            break
-        logits, cache = fwd(cache, jnp.asarray(feed),
-                            jnp.asarray(done, jnp.int32), jnp.asarray(nn))
-        for b in range(B):
-            rows[b].extend(np.asarray(logits[b, :nn[b]]))
-            done[b] += int(nn[b])
-            if done[b] == len(seqs[b]) and (
-                    len(seqs[b]) < len(prompts[b]) + new_tokens):
-                seqs[b].append(int(np.argmax(rows[b][-1])))
-    return [np.stack(r) for r in rows], seqs
-
-
 def test_the_cached_forward_through_both_pools_is_the_reference(
         model, params, shape):
     # a slot shorter than the window beside one far longer; chunks of 16
     # straddle the window's edge (24) in the long one
     prompts = [ids_of(13, seed=1), ids_of(150, seed=2)]
-    rows, seqs = paged_forward(model, params, prompts, chunk=16)
+    rows, seqs = paged_forward(model, params, prompts, 16, PS)
     for got, seq in zip(rows, seqs):
-        want = fam.logits(params, np.asarray(seq, np.int32), shape)
+        want = logits_of(params, np.asarray(seq, np.int32), shape)
         assert got.shape == want.shape
         assert close(got, want)
 
@@ -236,12 +197,13 @@ def test_two_carried_pools_are_bitwise_the_layer_loop(impl):
         page_table_win=jnp.asarray(rng.permutation(pages).reshape(B, mp)),
         num_new=num_new, token_valid=jnp.arange(S)[None, :] < num_new[:, None])
     got = want = (None, cache)
+    fwd = jax.jit(lambda ids, c, cl: forward_with_cache(
+        cfg, params, ids, c, cl, dtype=jnp.float32, **kw))
     with attention_impl(impl):
         for seed in (1, 2):
             args = (cfg, params, jnp.asarray(
                 np.stack([ids_of(S, seed=10 * seed + b) for b in range(B)])))
-            got = jax.jit(lambda c, cl, a=args: forward_with_cache(
-                *a, c, cl, dtype=jnp.float32, **kw))(got[1], frontier)
+            got = fwd(args[2], got[1], frontier)
             want = layer_loop_forward(*args, want[1], frontier, **kw)
             assert_bitwise(got, want)
             frontier = frontier + S
@@ -261,16 +223,17 @@ def test_windowed_paged_kernel_against_the_dense_lines(window, block_k):
     v = jnp.asarray(rng.normal(size=(P + 1, ps, KV, hd)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(B, S, H, hd)), jnp.float32)
     pt = jnp.asarray(rng.permutation(P).reshape(B, mp), jnp.int32)
+    kernel = jax.jit(lambda cl, nn: paged_attention_kernel(
+        q, k[None], v[None], cl, pt, layer=0, num_new=nn, block_k=block_k,
+        interpret=True, window=window))
+    dense = jax.jit(lambda cl: _dense_cached_attention(
+        cfg, q, _paged_gather(k, pt), _paged_gather(v, pt), cl,
+        window=window))
     for cl, nn in (([0, 5, 77, 140], [8, 8, 3, 8]),
                    ([20, 33, 150, 0], [1, 8, 8, 0]),
                    ([23, 24, 25, 100], [8, 1, 2, 8])):
         cl, nn = jnp.asarray(cl, jnp.int32), jnp.asarray(nn, jnp.int32)
-        out = paged_attention_kernel(q, k[None], v[None], cl, pt, layer=0,
-                                     num_new=nn, block_k=block_k,
-                                     interpret=True, window=window)
-        want = _dense_cached_attention(
-            cfg, q, _paged_gather(k, pt), _paged_gather(v, pt), cl,
-            window=window)
+        out, want = kernel(cl, nn), dense(cl)
         for b in range(B):
             n = int(nn[b])
             if n:
@@ -304,8 +267,16 @@ SERVING = dict(max_slots=3, token_budget=16, max_tokens=256, paged=True,
                page_size=4)
 
 
-def serve(model, params, prompts, new_tokens=10, **over):
-    srv = deepspeed_tpu.init_serving(
+@pytest.fixture(scope="module")
+def idle_engine(model, params):
+    """ONE engine on ``SERVING`` (a build compiles the step) for the tests
+    that read no counter of it and leave it idle and whole."""
+    return deepspeed_tpu.init_serving(model, serving=dict(SERVING),
+                                      params=params, dtype=jnp.float32)
+
+
+def serve(model, params, prompts, new_tokens=10, srv=None, **over):
+    srv = srv or deepspeed_tpu.init_serving(
         model, serving=dict(SERVING, **over), params=params,
         dtype=jnp.float32)
     states = [srv.submit(Request(request_id=f"r{i}", prompt=p,
@@ -329,7 +300,7 @@ def test_the_engine_serves_generates_tokens_and_the_references_argmax(
                                       temperature=0.0))[0]
         assert list(out[len(p):len(p) + 10]) == st.tokens
         full = np.concatenate([p, np.asarray(st.tokens, np.int32)])
-        want = fam.logits(params, full[:-1], shape, last=10)
+        want = logits_of(params, full[:-1], shape, last=10)
         assert list(np.asarray(want).argmax(-1)) == st.tokens
     snap = srv.metrics.snapshot()
     assert snap["window_pages_released"] > 0
@@ -338,9 +309,10 @@ def test_the_engine_serves_generates_tokens_and_the_references_argmax(
     assert snap["attention_paged_kernel_window"] == 0.0  # the CPU's lines
 
 
-def test_the_engine_with_the_kernel_serves_the_dense_tokens(model, params):
+def test_the_engine_with_the_kernel_serves_the_dense_tokens(model, params,
+                                                            idle_engine):
     prompts = [ids_of(70, seed=8), ids_of(9, seed=9)]
-    _, dense = serve(model, params, prompts, new_tokens=6)
+    _, dense = serve(model, params, prompts, new_tokens=6, srv=idle_engine)
     with attention_impl("flash"):  # the kernels, in interpret mode here
         srv, kernel = serve(model, params, prompts, new_tokens=6)
     assert srv.attention_path == "paged_kernel"
@@ -358,12 +330,11 @@ def tick_invariants(srv):
 
 
 @pytest.mark.parametrize("which", ["pool", "window_pool"])
-def test_the_ticks_audit_names_a_page_that_drifted(model, params, which):
+def test_the_ticks_audit_names_a_page_that_drifted(idle_engine, which):
     """Each pool is audited every tick against the ids its holders name
     (counted in numpy, PagePool.check_leaks): a reference nobody
     holds is refused, in the window layers' pool as in the full layers'."""
-    srv = deepspeed_tpu.init_serving(
-        model, serving=dict(SERVING), params=params, dtype=jnp.float32)
+    srv = idle_engine
     srv.submit(Request(request_id="r", prompt=ids_of(40, seed=3),
                        max_new_tokens=4, temperature=0.0))
     srv.step()
@@ -421,7 +392,7 @@ def test_every_slot_at_max_tokens_exhausts_neither_pool(model, params):
     assert srv.metrics.evicted == 0 and srv.step_traces == 1
 
 
-def test_a_shared_prefix_changes_no_ones_tokens(model, params):
+def test_a_shared_prefix_changes_no_ones_tokens(model, params, idle_engine):
     # three pages of shared prefix: with window layers the prefix cache is
     # off, so each request computes its own keys, together as alone
     prefix = ids_of(3 * 4, seed=30)
@@ -430,7 +401,7 @@ def test_a_shared_prefix_changes_no_ones_tokens(model, params):
     srv, both = serve(model, params, [a, b], prefix_cache=True)
     assert srv.metrics.prefix_hits == 0
     for st, p in zip(both, (a, b)):
-        _, alone = serve(model, params, [p])
+        _, alone = serve(model, params, [p], srv=idle_engine)
         assert st.tokens == alone[0].tokens
     # served one after the other on one engine, the second still misses
     later = srv.submit(Request(request_id="later", prompt=a,
@@ -439,7 +410,7 @@ def test_a_shared_prefix_changes_no_ones_tokens(model, params):
     assert later.tokens == both[0].tokens and srv.metrics.prefix_hits == 0
 
 
-def test_what_moves_pages_is_refused_by_name(model, params):
+def test_what_moves_pages_is_refused_by_name(model, params, idle_engine):
     for over, word in ((dict(host_pages=8), "host_pages"),
                        (dict(fleet=dict(enabled=True, replicas=2,
                                         prefill_replicas=1)),
@@ -449,7 +420,8 @@ def test_what_moves_pages_is_refused_by_name(model, params):
                 model, serving=dict(SERVING, **over), params=params,
                 dtype=jnp.float32)
         assert "window layers" in str(e.value)
-    srv, states = serve(model, params, [ids_of(20, seed=40)])
+    srv, states = serve(model, params, [ids_of(20, seed=40)],
+                        srv=idle_engine)
     with pytest.raises(RuntimeError, match="window layers"):
         srv.export_kv_pages([0])
     with pytest.raises(RuntimeError, match="window layers"):
@@ -514,14 +486,13 @@ def test_layer_features_work_on_whole_periods_or_refuse_by_name(
         mellum("mellum-tiny", num_layers=6)
 
 
-def test_modelchecks_invariants_hold_for_each_pool(model, params):
+def test_modelchecks_invariants_hold_for_each_pool(idle_engine):
     from deepspeed_tpu.analysis.modelcheck.invariants import (
         CheckFailure,
         _check_pool,
     )
 
-    srv = deepspeed_tpu.init_serving(model, serving=dict(SERVING),
-                                     params=params, dtype=jnp.float32)
+    srv = idle_engine
     srv.submit(Request(request_id="a", prompt=ids_of(100, seed=50),
                        max_new_tokens=4, temperature=0.0))
     for _ in range(4):

@@ -3,6 +3,7 @@ lightning layers whose slot state is no page beside block-sparse attention
 over the paged cache, against the benchmark's plain reference
 (benchmarks/families/minicpm_sala.py)."""
 
+import functools
 import sys
 from pathlib import Path
 
@@ -14,7 +15,7 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.config import DeepSpeedConfigError
 from deepspeed_tpu.models import minicpm
-from deepspeed_tpu.models.decoding import forward_with_cache, init_paged_cache
+from deepspeed_tpu.models.decoding import init_paged_cache
 from deepspeed_tpu.models.mixers import walk_runs
 from deepspeed_tpu.ops.attention import attention_impl
 from deepspeed_tpu.ops.pallas import block_sparse_attention as bsa
@@ -23,8 +24,11 @@ from deepspeed_tpu.serving import Request
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from benchmarks.families import minicpm_sala as fam  # noqa: E402
+from slot_program import (cached_step, chunked_logits, ids_of,  # noqa: E402
+                          init_params, reference_logits)
 
 F32 = jnp.float32
+logits_of = reference_logits(fam)
 TOL = 2e-5          # on logits whose spread is about 0.2
 PS, W, SLOTS = 16, 16, 2
 SERVING = dict(max_slots=SLOTS, token_budget=W, max_tokens=1008, paged=True,
@@ -51,15 +55,7 @@ def model():
 
 @pytest.fixture(scope="module")
 def params(model):
-    tree = model.init(jax.random.PRNGKey(0), dtype=F32)
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(tree)
-    out = []
-    for i, (path, a) in enumerate(leaves):  # norm scales that are not one
-        if getattr(path[-1], "key", "") == "scale":
-            a = a * (1 + 0.1 * jax.random.normal(jax.random.PRNGKey(i),
-                                                 a.shape))
-        out.append(a)
-    return jax.tree_util.tree_unflatten(treedef, out)
+    return init_params(model)
 
 
 @pytest.fixture(scope="module")
@@ -67,52 +63,15 @@ def shape():
     return fam.shape_of(CONFIG)
 
 
-def ids_of(n, seed):
-    return np.random.default_rng(seed).integers(0, 512, n, dtype=np.int32)
-
-
-_STEPS = {}
-
-
-def cached_step(cfg, kernels: bool):
-    """``forward_with_cache`` jitted once a path (the kernels in interpret
-    mode cost seconds a call when dispatched one operation at a time)."""
-    if kernels not in _STEPS:
-        def step(params, tokens, caches, start, table, num_new):
-            with attention_impl("flash" if kernels else "xla"):
-                return forward_with_cache(
-                    cfg, params, tokens, caches, start, dtype=F32,
-                    page_table=table, num_new=num_new)
-
-        _STEPS[kernels] = jax.jit(step)
-    return _STEPS[kernels]
-
-
-def chunked_logits(model, params, ids, chunk=W, slot=0, caches=None,
-                   kernels=False):
-    """Logits of every position of ``ids`` through the cached forward, a
-    chunk a call, in ``slot`` of a two-slot arena (the other slot idle):
-    (logits [S, V], the caches after)."""
-    cfg = model.config
+def prefill_logits(model, params, ids, kernels=False):
+    """Logits of every position of ``ids``, chunks of W rows in slot 0 of a
+    two-slot arena of 64 pages a slot (the other slot idle, its row of the
+    table all NULL pages)."""
     mp = 64
-    if caches is None:
-        caches = init_paged_cache(cfg, SLOTS * mp, PS, F32, max_slots=SLOTS)
     table = np.full((SLOTS, mp), SLOTS * mp, np.int32)
-    table[slot] = np.arange(mp) + slot * mp
-    out = []
-    for lo in range(0, len(ids), chunk):
-        part = ids[lo:lo + chunk]
-        tokens = np.zeros((SLOTS, chunk), np.int32)
-        tokens[slot, :len(part)] = part
-        num_new = np.zeros(SLOTS, np.int32)
-        num_new[slot] = len(part)
-        start = np.zeros(SLOTS, np.int32)
-        start[slot] = lo
-        logits, caches = cached_step(cfg, kernels)(
-            params, jnp.asarray(tokens), caches, jnp.asarray(start),
-            jnp.asarray(table), jnp.asarray(num_new))
-        out.append(np.asarray(logits[slot, :len(part)]))
-    return np.concatenate(out), caches
+    table[0] = np.arange(mp)
+    return chunked_logits(model, params, ids, table, slot=0, chunk=W,
+                          page_size=PS, kernels=kernels)
 
 
 def serve(model, params, prompts, new=5, **over):
@@ -134,9 +93,9 @@ def test_cached_logits_match_the_reference(model, params, shape, path, n):
     just past it and far past it; none a multiple of 16 or 64) against the
     reference's full forward pass, every position."""
     ids = ids_of(n, seed=n)
-    want = np.asarray(fam.logits(params, ids, shape))
+    want = np.asarray(logits_of(params, ids, shape))
     # ("kernels": the Pallas calls, in interpret mode here)
-    got, _ = chunked_logits(model, params, ids, kernels=path == "kernels")
+    got = prefill_logits(model, params, ids, kernels=path == "kernels")
     assert want.std() > 0.1
     assert np.abs(got - want).max() < TOL
 
@@ -148,7 +107,7 @@ def test_the_engine_serves_the_references_argmax(model, params, shape):
     assert srv.attention_paths == {"sparse": "dense", "lightning": "dense"}
     for p, st in zip(prompts, states):
         ids = np.concatenate([p, np.asarray(st.tokens, np.int32)])
-        lg = np.asarray(fam.logits(params, ids[:-1], shape, last=5))
+        lg = np.asarray(logits_of(params, ids[:-1], shape, last=5))
         assert (lg.max(-1) - lg[np.arange(5), st.tokens]).max() < TOL
     snap = srv.metrics.snapshot()
     assert snap["state_bytes"] == 3 * SLOTS * 4 * 16 * 16 * 4
@@ -185,6 +144,11 @@ def test_lightning_chunked_is_token_by_token_is_one_shot(kernel):
         want.append(np.einsum("hd,hde->he", q[t], s) * hd ** -0.5)
     want = np.asarray(want)
 
+    # (a compile a chunk size, not one a call)
+    lightning = jax.jit(functools.partial(la.lightning_attention, layer=0,
+                                          scale=hd ** -0.5))
+    dense = jax.jit(functools.partial(la.dense_lightning, scale=hd ** -0.5))
+
     def run(chunk):
         state = jnp.zeros((1, B, H, hd, hd), F32)
         outs = []
@@ -196,11 +160,9 @@ def test_lightning_chunked_is_token_by_token_is_one_shot(kernel):
             args = (pad(q), pad(k), pad(v), ll)
             at = (jnp.asarray([lo]), jnp.asarray([n]))
             if kernel:
-                o, state = la.lightning_attention(
-                    *args, state, *at, layer=0, scale=hd ** -0.5)
+                o, state = lightning(*args, state, *at)
             else:
-                o, s1 = la.dense_lightning(*args, state[0], *at,
-                                           scale=hd ** -0.5)
+                o, s1 = dense(*args, state[0], *at)
                 state = s1[None]
             outs.append(np.asarray(o[0, :n]))
         return np.concatenate(outs), np.asarray(state[0, 0])
@@ -225,11 +187,10 @@ def test_padded_rows_and_idle_slots_leave_the_state_bitwise(model, params,
     def step(pad_token):
         tokens = np.full((SLOTS, W), pad_token, np.int32)
         tokens[0, :5] = ids_of(5, seed=9)
-        with attention_impl("flash" if path == "kernels" else "xla"):
-            _, after = forward_with_cache(
-                cfg, params, jnp.asarray(tokens), dict(caches, state=held),
-                jnp.asarray([32, 0], jnp.int32), dtype=F32, page_table=table,
-                num_new=jnp.asarray([5, 0], jnp.int32))
+        _, after = cached_step(cfg, path == "kernels")(
+            params, jnp.asarray(tokens), dict(caches, state=held),
+            jnp.asarray([32, 0], jnp.int32), table,
+            jnp.asarray([5, 0], jnp.int32))
         return np.asarray(after["state"])
 
     a, b = step(0), step(411)

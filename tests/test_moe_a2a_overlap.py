@@ -190,8 +190,8 @@ def test_gradients_flow_through_ring(devices8):
             return jnp.sum(out ** 2)
         return f
 
-    gx_r, gw_r = jax.grad(loss(True), argnums=(0, 1))(x, w[0])
-    gx, gw = jax.grad(loss(False), argnums=(0, 1))(x, w[0])
+    gx_r, gw_r = jax.jit(jax.grad(loss(True), argnums=(0, 1)))(x, w[0])
+    gx, gw = jax.jit(jax.grad(loss(False), argnums=(0, 1)))(x, w[0])
     np.testing.assert_allclose(np.asarray(gx), np.asarray(gx_r),
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(gw), np.asarray(gw_r),

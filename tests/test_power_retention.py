@@ -2,6 +2,8 @@
 small head size) against its plain twin and against the attention form, which
 has no ``phi`` and no state."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,6 +28,7 @@ def draw(seed, B, T, gate_mean=-1.0, hd=HD):
     return q, k, v, g
 
 
+@jax.jit
 def attention_form(q, k, v, g):
     """Row i over every row j <= i of the whole sequence: no state."""
     B, T, H, hd = q.shape
@@ -57,20 +60,27 @@ def run_chunks(fn, q, k, v, g, sizes, S):
     at = np.zeros(B, np.int64)
     leaves = None
     outs = [[] for _ in range(B)]
+    # (cut and padded on the host: on the device every chunk size would
+    # compile its own slice and concatenation)
+    q, k, v, g = (np.asarray(a) for a in (q, k, v, g))
     for step in zip(*sizes):
         nn = np.asarray(step)
-        pad = lambda a: jnp.stack([
-            jnp.concatenate([a[b, at[b]:at[b] + nn[b]], jnp.full(
-                (S - nn[b], *a.shape[2:]), 7.0, a.dtype)]) for b in range(B)])
+        pad = lambda a: jnp.asarray(np.stack([
+            np.concatenate([a[b, at[b]:at[b] + nn[b]], np.full(
+                (S - nn[b], *a.shape[2:]), 7.0, a.dtype)]) for b in range(B)]))
         o, leaves = fn(pad(q), pad(k), pad(v), pad(g), leaves,
                        jnp.asarray(at, jnp.int32), jnp.asarray(nn, jnp.int32))
+        o = np.asarray(o)
         for b in range(B):
             outs[b].append(o[b, :nn[b]])
         at += nn
-    return [jnp.concatenate(o) for o in outs], leaves
+    return [np.concatenate(o) for o in outs], leaves
 
 
-def make_kernel():
+@functools.cache
+def make_kernel(budget=pr.STATE_VMEM_BYTES):
+    """The kernel under ``jit``, one for each ``STATE_VMEM_BYTES`` it is
+    traced under (the tile is chosen while tracing)."""
     return jax.jit(lambda q, k, v, g, state, norm, cl, nn, layer:
                    pr.power_retention(q, k, v, g, state, norm, cl, nn,
                                       layer=layer, scale=q.shape[-1] ** -0.5,
@@ -81,11 +91,11 @@ kernel = make_kernel()
 
 
 def tiled_kernel(monkeypatch, rows, hd=128):
-    """The kernel traced afresh under a budget that holds ``rows`` packed
-    rows a program at head size ``hd`` (the tile is chosen while tracing)."""
+    """The kernel traced under a budget that holds ``rows`` packed rows a
+    program at head size ``hd``."""
     monkeypatch.setattr(pr, "STATE_VMEM_BYTES", 4 * rows * hd * hd * 4)
     assert pr.tile_rows(hd) == rows
-    return make_kernel()
+    return make_kernel(pr.STATE_VMEM_BYTES)
 
 
 dense = jax.jit(lambda q, *a: pr.dense_power_retention(

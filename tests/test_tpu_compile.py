@@ -9,8 +9,11 @@ numerics; these pin that the chip would accept the kernel at all — block
 shapes off the (8, 128) tiling, SMEM operands and VMEM budgets are only
 refused here.
 
-One file on purpose: only one process may load libtpu, and the worker that
-is handed this file keeps it. The topology is described inside a fixture,
+One file on purpose: under ``README.md``'s command only one process may load
+libtpu (a second dies on its lock file, and the ``topo`` fixture turns that
+into a skip), and the worker that is handed this file keeps it; under the
+driver's ``ALLOW_MULTIPLE_LIBTPU_LOAD=1`` two processes at once both describe
+``v5e:2x2`` and compile for it. The topology is described inside a fixture,
 never at import (on-chip-measurement guide, section 2).
 """
 
