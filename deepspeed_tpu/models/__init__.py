@@ -16,6 +16,7 @@ from .minicpm import minicpm, minicpm_config  # noqa: F401
 from .ling import ling, ling_config  # noqa: F401
 from .brumby import brumby, brumby_config  # noqa: F401
 from .cohere import cohere, cohere_config  # noqa: F401
+from .keye import keye, keye_config  # noqa: F401
 
 MODEL_REGISTRY = {
     "gpt2": gpt2,
@@ -30,6 +31,7 @@ MODEL_REGISTRY = {
     "ling": ling,
     "brumby": brumby,
     "cohere": cohere,
+    "keye": keye,
 }
 
 

@@ -292,6 +292,14 @@ def latent_row_width(cfg: TransformerConfig) -> int:
     return -(-cfg.latent_width // 128) * 128
 
 
+def index_row_width(cfg: TransformerConfig) -> int:
+    """Values a row of the index pool beside K / V pools holds:
+    ``cfg.index_dim`` in whole 128-lane tiles, zeros past the key (the
+    scoring kernel's DMA takes whole tiles; a dot product over the zeros
+    adds nothing)."""
+    return -(-cfg.index_dim // 128) * 128
+
+
 def pool_index_keys(block: jax.Array) -> jax.Array:
     """ONE index key of a block of tokens, from their rotated keys ``[...,
     index_kpool, index_dim]``: their mean, float32. (The operator behind a
@@ -351,6 +359,10 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
     design and never attendable: every query masks at its own frontier). int8 storage carries per-(token, head)
     scales in the pre-transposed [L, P+1, KV, page_size, SL] layout the
     decode kernel consumes.
+
+    A model whose full layers select by an indexer (``cfg.index_topk``,
+    no latent) keeps ``ki`` [L, num_pages + 1, page_size, index_row_width]
+    beside ``k``/``v``: an index key a token, on the same page table.
 
     A model with window layers (``cfg.has_window``) keeps pages by layer
     kind: ``k``/``v`` hold the full layers alone, and ``k_win``/``v_win``
@@ -413,6 +425,17 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
             "v" + sfx: jnp.zeros(shape, dtype),
         }
 
+    if cfg.index_in_pages:
+        if quantized:
+            from ..config import DeepSpeedConfigError
+
+            raise DeepSpeedConfigError(
+                "an int8 KV cache is refused: the walk over an indexer's "
+                "selection (index_topk) reads K and V as they are computed")
+        # an index key a token beside its K and V, on their page table
+        return {**pool(cfg.total_layers, num_pages), INDEX: jnp.zeros(
+            (cfg.total_layers, int(num_pages) + 1, page_size,
+             index_row_width(cfg)), dtype)}
     if not cfg.has_window:
         return pool(cfg.total_layers, num_pages)
     if window_pages is None:
@@ -556,6 +579,13 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
             "a contiguous KV arena is refused: latent attention "
             "(kv_latent_dim) attends its cached latents through the page "
             "table (serving.paged)")
+    if cfg.index_in_pages:
+        from ..config import DeepSpeedConfigError
+
+        raise DeepSpeedConfigError(
+            "a contiguous KV arena is refused: an indexer (index_topk) "
+            "scores its cached keys and the walk reads the selection's K "
+            "and V through the page table (serving.paged)")
     shape = (cfg.total_layers, batch, max_len, cfg.kv_heads, cfg.hd)
     if quantized:
         # scales live pre-transposed as [B, KV, Smax, SL]: the Pallas decode
@@ -617,11 +647,41 @@ def _qkv(cfg: TransformerConfig, p: Params, x: jax.Array, positions: jax.Array,
     return q, k, v
 
 
+def _indexer(cfg: TransformerConfig, ix: Params, x: jax.Array, q_src,
+             positions: jax.Array, rd: int):
+    """The indexer's three projections of the computed rows ``x`` [B,S,d]:
+    (index queries [B,S,Hi,Di] from ``q_src`` (the query latent through
+    ``wq_b``, or ``x`` itself through ``wq``), ONE key a token [B,S,Di] =
+    LayerNorm(x W_k), both with their ``rd`` leading values rotated by the
+    full layers' table; head weights float32 [B,S,Hi] with every constant
+    factor folded in)."""
+    from ..ops.normalization import layernorm
+
+    B, S, _ = x.shape
+    Hi, Di = cfg.index_heads, cfg.index_dim
+    wq = ix["wq_b"] if "wq_b" in ix else ix["wq"]
+    q_idx = (q_src @ wq).reshape(B, S, Hi, Di)
+    k_idx = layernorm(
+        (x @ ix["wk"]).astype(jnp.float32),
+        ix["k_norm"]["scale"].astype(jnp.float32),
+        ix["k_norm"]["bias"].astype(jnp.float32), cfg.norm_eps,
+    ).astype(x.dtype)[:, :, None, :]
+    q_rot, k_rot = _rope(q_idx[..., :rd], k_idx[..., :rd], positions,
+                         cfg.rope_of("full"))
+    q_idx = jnp.concatenate([q_rot, q_idx[..., rd:]], axis=-1)
+    k_idx = jnp.concatenate([k_rot, k_idx[..., rd:]], axis=-1)[:, :, 0]
+    w_idx = jnp.einsum(
+        "bsd,dh->bsh", x.astype(jnp.float32),
+        ix["w_proj"].astype(jnp.float32)) * (Hi ** -0.5 * Di ** -0.5)
+    return q_idx, k_idx, w_idx
+
+
 def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
                       rows: ChunkRows, layer, k_cache: jax.Array,
                       v_cache: jax.Array, cache_len,
                       k_scale=None, v_scale=None, page_table=None,
-                      num_new=None, kind: str = "full", page_rows=None):
+                      num_new=None, kind: str = "full", page_rows=None,
+                      ki_cache=None):
     """Attend new tokens against cache[:cache_len] + themselves.
 
     ``x`` holds the rows ``rows`` computes (``[B, S, D]``, or ``[1, T, D]``
@@ -670,6 +730,14 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
     starts at the page of its first row's oldest visible key, and the
     other kernels (which know no window) leave such a layer to the XLA
     lines.
+
+    ``ki_cache`` [L, P+1, page_size, index_row_width] (a paged model with
+    ``cfg.index_topk`` and no latent): the layer's indexer scores every
+    cached token for each query from the index key the token wrote there,
+    and every head of the query attends the query's ``index_topk`` best
+    inside its KV group (ops/pallas/sparse_paged_attention.py: scores,
+    selection and a walk that masks; the same by plain lines over gathered
+    views otherwise). The pool comes back last.
     """
     B, S = rows.B, rows.S
     nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.hd
@@ -696,6 +764,18 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
         k_cache = write(k_cache, k.astype(k_cache.dtype))
         v_cache = write(v_cache, v.astype(v_cache.dtype))
     q = rows.unpack(q)
+    index = None
+    if ki_cache is not None:
+        # the indexer: queries from the normed input, a key a token written
+        # beside its K and V; both as wide as the pool's row
+        q_idx, k_idx, w_idx = _indexer(cfg, p["idx"], x, x, rows.positions,
+                                       cfg.index_rope_dim or cfg.index_dim)
+        pad = [(0, ki_cache.shape[-1] - cfg.index_dim)]
+        ki_cache = _paged_write(
+            ki_cache, jnp.pad(k_idx, [(0, 0)] * 2 + pad).astype(
+                ki_cache.dtype), layer, page_rows)
+        index = (rows.unpack(jnp.pad(q_idx, [(0, 0)] * 3 + pad)),
+                 rows.unpack(w_idx))
 
     def at_layer(stack):
         # what cannot take a stack reads its layer (post-write) as a slice
@@ -703,9 +783,10 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
             stack, layer, 0, keepdims=False)
 
     def ret(out):
-        if quantized:
-            return out, k_cache, v_cache, k_scale, v_scale
-        return out, k_cache, v_cache
+        pools = (k_cache, v_cache) + (
+            (k_scale, v_scale) if quantized else ()) + (
+            () if ki_cache is None else (ki_cache,))
+        return (out, *pools)
 
     def project(out):
         out = rows.pack(out.astype(x.dtype).reshape(B, S, nh * hd))
@@ -725,6 +806,25 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
         why_dense.append("ALiBi positions")
     kernel_ok = not why_dense
 
+    if index is not None:
+        from ..ops.pallas import sparse_latent_attention as sla
+        from ..ops.pallas import sparse_paged_attention as spa
+
+        out = None
+        if kernel_ok:
+            out, why_dense = spa.indexed_paged_attention(
+                q, *index, k_cache, v_cache, ki_cache, cache_len, page_table,
+                layer=layer, topk=cfg.index_topk, num_new=num_new)
+        if out is not None:
+            _note_attention_path("paged_sparse_kernel", kind=kind)
+            return project(out)
+        _note_attention_path("dense", why_dense, kind)
+        view = lambda stack: _paged_gather(at_layer(stack), page_table)
+        chosen = sla.dense_selection(
+            sla.dense_index_scores(*index, view(ki_cache)),
+            rows.slot_positions, cfg.index_topk)
+        return project(spa.dense_sparse_paged_attention(
+            q, view(k_cache), view(v_cache), chosen))
     if paged:
         out = None
         if kernel_ok and S == 1 and window is None:
@@ -844,8 +944,7 @@ def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
 
     B, S, _ = x.shape  # of the computed rows
     H, kl, rd = cfg.num_heads, cfg.kv_latent_dim, cfg.qk_rope_dim
-    nope, vd, eps = cfg.qk_nope_dim, cfg.v_head_dim, cfg.norm_eps
-    table = cfg.rope_of("full")
+    nope, vd = cfg.qk_nope_dim, cfg.v_head_dim
     positions = rows.positions
     c_q, q_nope, q_pe, c_kv, k_pe = _latent_projections(cfg, p, x, positions)
     pad = latent_row_width(cfg) - cfg.latent_width
@@ -869,23 +968,9 @@ def _latent_cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
     q_idx = w_idx = None
     kpool = cfg.index_kpool
     if cfg.index_topk:
-        ix, Hi, Di = p["idx"], cfg.index_heads, cfg.index_dim
-        rd = cfg.index_rope_dim or rd  # the indexer's own rotated values
-        q_idx = (c_q @ ix["wq_b"]).reshape(B, S, Hi, Di)
-        from ..ops.normalization import layernorm
-
-        k_idx = layernorm(
-            (x @ ix["wk"]).astype(jnp.float32),
-            ix["k_norm"]["scale"].astype(jnp.float32),
-            ix["k_norm"]["bias"].astype(jnp.float32), eps,
-        ).astype(x.dtype)[:, :, None, :]
-        q_rot, k_rot = _rope(q_idx[..., :rd], k_idx[..., :rd], positions,
-                             table)
-        q_idx = jnp.concatenate([q_rot, q_idx[..., rd:]], axis=-1)
-        k_idx = jnp.concatenate([k_rot, k_idx[..., rd:]], axis=-1)[:, :, 0]
-        w_idx = jnp.einsum(
-            "bsd,dh->bsh", x.astype(jnp.float32),
-            ix["w_proj"].astype(jnp.float32)) * (Hi ** -0.5 * Di ** -0.5)
+        # (the indexer's own rotated values, or the attention's)
+        q_idx, k_idx, w_idx = _indexer(cfg, p["idx"], x, c_q, positions,
+                                       cfg.index_rope_dim or rd)
         k_idx = k_idx.astype(pools[INDEX].dtype)
         if kpool > 1:
             pools = _pooled_index_write(

@@ -256,12 +256,15 @@ def _mix(kind: str, cfg, p, x, rows, pools, index, layer_id, cache_len,
             decoding.WIN in tables) else ""
         names = [n + sfx for n in ("k", "v", "k_scale", "v_scale")
                  if n + sfx in pools]
+        # (an indexer's keys beside the full layers' K and V)
+        indexed = [decoding.INDEX] if cfg.index_in_pages else []
         a, *written = decoding._cached_attention(
             cfg, p, x, rows, index, *(pools[n] for n in names[:2]),
             cache_len, *(pools[n] for n in names[2:]),
             page_table=tables[sfx], num_new=num_new, kind=kind,
-            page_rows=places.get(sfx))
-        return a, {**pools, **dict(zip(names, written))}
+            page_rows=places.get(sfx),
+            ki_cache=pools[decoding.INDEX] if indexed else None)
+        return a, {**pools, **dict(zip(names + indexed, written))}
     # "latent" | "mla" (whose path a model without mixer_types notes as a
     # "full" layer's: the one name its engine reads)
     return decoding._latent_cached_attention(

@@ -51,8 +51,10 @@ class MixerKind:
 
 
 MIXER_KINDS: Dict[str, MixerKind] = {
-    # attention over every key of the K / V pool (int8 with its scales)
-    "full": MixerKind((), ("k", "v", "k_scale", "v_scale"), "decoding"),
+    # attention over every key of the K / V pool (int8 with its scales) or,
+    # with an indexer (``index_topk``), over the best by its keys, which the
+    # page keeps beside K and V (``ki``)
+    "full": MixerKind((), ("k", "v", "k_scale", "v_scale", "ki"), "decoding"),
     # attention over the last ``attn_window`` keys; a paged cache keeps the
     # window layers a pool and a page table of their own (``k_win`` ...)
     "window": MixerKind((), ("k", "v", "k_scale", "v_scale"), "decoding"),
@@ -177,7 +179,10 @@ class TransformerConfig:
     # Learned sparse attention (``index_topk`` > 0): an indexer of
     # ``index_heads`` heads of ``index_dim`` scores every cached token for
     # every query from its own cached key, and attention sees the
-    # ``index_topk`` best alone.
+    # ``index_topk`` best alone. Over a latent cache its queries come from
+    # the query latent; without one (grouped-query attention over paged K
+    # and V) from the layer's normed input, and every head of a query
+    # attends the query's selection inside its own KV group.
     index_heads: int = 0
     index_dim: int = 0
     index_topk: int = 0
@@ -190,7 +195,9 @@ class TransformerConfig:
     # block.
     index_rope_dim: int = 0
     index_kpool: int = 1
-    # Router: "softmax" (top-k of a softmax, with a capacity), or
+    # Router: "softmax" (top-k of a softmax, with a capacity; with
+    # ``moe_capacity_factor`` 0 the top-k of a softmax over every routed
+    # expert renormalised over the chosen, no token dropped, served only), or
     # "sigmoid_groups": sigmoid scores plus a learned selection bias, the
     # ``moe_groups_kept`` best of ``moe_groups`` groups by the sum of their
     # two best, the top-k inside them, weights from the unbiased scores
@@ -299,10 +306,20 @@ class TransformerConfig:
                 "parallel_block: one norm and one sum a layer of "
                 "layer_pattern's kinds; the layers mixer_types names and the "
                 "residual streams of hc_mult norm each half-layer")
-        if self.index_topk and not (self.kv_latent_dim and self.q_latent_dim):
-            raise ValueError("the indexer scores a latent cache's tokens "
-                             "from the query latent: index_topk needs "
-                             "kv_latent_dim and q_latent_dim")
+        if self.index_topk and self.kv_latent_dim and not self.q_latent_dim:
+            raise ValueError("over a latent cache the indexer's queries come "
+                             "from the query latent: index_topk with "
+                             "kv_latent_dim needs q_latent_dim")
+        if self.index_in_pages and (
+                self.layer_pattern or self.mixer_types
+                or self.pos_embedding != "rope"
+                or not (self.index_heads and self.index_dim)):
+            raise ValueError(
+                "an indexer without a latent cache (index_topk, no "
+                "kv_latent_dim) selects inside the paged K and V of full "
+                "rotary layers: it needs index_heads and index_dim, and a "
+                "window layer's selection (layer_pattern) or a named "
+                "mixer's (mixer_types) is not computed")
         if self.is_latent and (
                 self.layer_pattern or self.qk_nope_dim + self.qk_rope_dim
                 != self.hd or self.kv_heads != 1):
@@ -323,12 +340,14 @@ class TransformerConfig:
             raise ValueError(
                 f"mtp_layers {self.mtp_layers}: one module (it predicts the "
                 "token after next) or none")
-        if self.moe_gate == "softmax" and self.moe_routed_experts not in (
-                0, self.num_experts):
+        if (self.moe_gate == "softmax" and self.moe_capacity_factor
+                and self.moe_routed_experts not in (0, self.num_experts)):
             raise ValueError(
                 "one member's share of an expert-parallel layer "
-                "(moe_routed_experts) is computed under the sigmoid routers "
-                "alone")
+                "(moe_routed_experts) has room for every token: it is "
+                "computed under the sigmoid routers, or under a softmax "
+                "router that drops nothing (moe_capacity_factor 0); what a "
+                "capacity drops is decided by every member's fill")
         if self.mixer_types:
             self._check_mixers()
         if self.routed_experts % self.moe_groups or not (
@@ -392,6 +411,20 @@ class TransformerConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def index_in_pages(self) -> bool:
+        """An indexer without a latent cache: it selects inside the paged K
+        and V of the full layers, its keys a pool of their own (``ki``)
+        beside them on their page table."""
+        return bool(self.index_topk) and not self.kv_latent_dim
+
+    @property
+    def moe_dropless(self) -> bool:
+        """The router drops no token (every router but a softmax one under a
+        capacity): the serving layer gives each held expert room for every
+        token, and may hold one member's share of the layer."""
+        return self.moe_gate != "softmax" or not self.moe_capacity_factor
 
     @property
     def has_window(self) -> bool:
@@ -487,10 +520,10 @@ class TransformerConfig:
             qkvo = (wq + d * self.latent_width
                     + kl + kl * nh * (self.qk_nope_dim + self.v_head_dim)
                     + nh * self.v_head_dim * d)
-            if self.index_topk:
-                qkvo += (ql * self.index_heads * self.index_dim
-                         + d * self.index_dim + 2 * self.index_dim
-                         + d * self.index_heads)
+        if self.index_topk:  # queries from the query latent, or from d
+            qkvo += ((self.q_latent_dim or d) * self.index_heads
+                     * self.index_dim + d * self.index_dim
+                     + 2 * self.index_dim + d * self.index_heads)
         if self.activation == "swiglu":
             mlp = 3 * d * self.ffn
         else:
@@ -545,15 +578,26 @@ def _latent_attn_params(cfg: "TransformerConfig", nrm, lk, L: int,
         "wo": nrm(lk[3], L, nh * cfg.v_head_dim, d, scale=out_scale),
     })
     if cfg.index_topk:
-        ik = jax.random.split(lk[11], 3)
-        attn["idx"] = {
-            "wq_b": nrm(ik[0], L, ql, cfg.index_heads * cfg.index_dim),
-            "wk": nrm(ik[1], L, d, cfg.index_dim),
-            "k_norm": {"scale": jnp.ones((L, cfg.index_dim), dtype),
-                       "bias": jnp.zeros((L, cfg.index_dim), dtype)},
-            "w_proj": nrm(ik[2], L, d, cfg.index_heads),
-        }
+        attn["idx"] = _indexer_params(cfg, nrm, lk[11], L, dtype)
     return attn
+
+
+def _indexer_params(cfg: "TransformerConfig", nrm, key, L: int,
+                    dtype) -> Params:
+    """The indexer's leaves of ``L`` layers, stacked: its queries from the
+    query latent (``wq_b``) or, without one, from the layer's normed input
+    (``wq``); one key a token (``wk``, LayerNorm ``k_norm``) and a weight a
+    head (``w_proj``) from the normed input."""
+    d, ql = cfg.hidden_size, cfg.q_latent_dim
+    ik = jax.random.split(key, 3)
+    return {
+        ("wq_b" if ql else "wq"): nrm(ik[0], L, ql or d,
+                                      cfg.index_heads * cfg.index_dim),
+        "wk": nrm(ik[1], L, d, cfg.index_dim),
+        "k_norm": {"scale": jnp.ones((L, cfg.index_dim), dtype),
+                   "bias": jnp.zeros((L, cfg.index_dim), dtype)},
+        "w_proj": nrm(ik[2], L, d, cfg.index_heads),
+    }
 
 
 # -----------------------------------------------------------------------------
@@ -607,6 +651,8 @@ def init(cfg: TransformerConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
         if cfg.qk_norm:
             attn["q_norm"] = {"scale": jnp.ones((L, hd), dtype)}
             attn["k_norm"] = {"scale": jnp.ones((L, hd), dtype)}
+        if cfg.index_topk:
+            attn["idx"] = _indexer_params(cfg, nrm, lk[11], L, dtype)
         return attn
 
     def dense_mlp(lk, L, f):
@@ -1216,9 +1262,18 @@ def _refuse_uncached(cfg: TransformerConfig) -> None:
     if cfg.index_topk:
         raise DeepSpeedConfigError(
             "the uncached forward (training, evaluation, forward) does not "
-            "compute the indexer's selection (index_topk): it is made from "
-            "cached index keys alone; serve this configuration through "
-            "init_serving with serving.paged")
+            "compute the indexer's selection (index_topk), over a latent "
+            "cache or inside paged K and V: it is made from cached index "
+            "keys alone; serve this configuration through init_serving with "
+            "serving.paged")
+    if cfg.is_moe and cfg.moe_gate == "softmax" and cfg.moe_dropless:
+        raise DeepSpeedConfigError(
+            "the uncached forward (training, evaluation, forward) routes a "
+            "softmax gate under a capacity (moe/sharded_moe.moe_layer): a "
+            "softmax router that drops nothing (moe_capacity_factor 0), "
+            "whole or one member's share (moe_routed_experts), is computed "
+            "by the serving layer alone, and the exchange between members "
+            "by neither; serve this configuration through init_serving")
     if cfg.mixer_types:
         raise DeepSpeedConfigError(
             "the uncached forward (training, evaluation, forward) runs no "
@@ -1399,10 +1454,11 @@ def tp_partition_specs(cfg: TransformerConfig, tp_divides_kv: bool = True) -> Pa
     Row-parallel: attn-out + mlp-out shard input dim over tp.
     Embeddings/lm_head shard vocab over tp (loss is vocab-parallel).
     """
-    if cfg.is_latent or cfg.mixer_types:
+    if cfg.is_latent or cfg.mixer_types or cfg.index_topk:
         # one latent a token serves every head, a block selection is a kv
-        # group's and a state a slot's: nothing splits by head, and
-        # every leaf is whole on every device
+        # group's, an indexer's a query's (every head's) and a state a
+        # slot's: nothing splits by head, and every leaf is whole on every
+        # device
         shapes = jax.eval_shape(partial(init, cfg), jax.random.PRNGKey(0))
         return jax.tree.map(lambda a: P(*([None] * a.ndim)), shapes)
     kv_tp = "tp" if tp_divides_kv else None

@@ -334,9 +334,24 @@ def sigmoid_gate(logits: jax.Array, top_k: int):
     return idx.astype(jnp.int32), w
 
 
-def route_sigmoid(cfg, p: Dict, logits: jax.Array, train: bool = False):
-    """(idx, weight) of either sigmoid router, by ``cfg.moe_gate``; with
-    ``train`` the selection bias carries no gradient."""
+def softmax_gate(logits: jax.Array, top_k: int):
+    """The softmax router that drops nothing (``moe_gate`` "softmax" with
+    ``moe_capacity_factor`` 0): ``logits`` [N, E] float32 -> (expert idx
+    [N, K] int32, weight [N, K] float32): the ``top_k`` largest of a softmax
+    over ALL ``E`` outputs (ties to the lower index), renormalised to one
+    over the chosen."""
+    w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    w = w / jnp.maximum(jnp.sum(w, axis=1, keepdims=True), 1e-20)
+    return idx.astype(jnp.int32), w
+
+
+def route_dropless(cfg, p: Dict, logits: jax.Array, train: bool = False):
+    """(idx, weight) of a router that drops no token
+    (``cfg.moe_dropless``), by ``cfg.moe_gate``: either sigmoid router or
+    the softmax one without a capacity. The one place the choice is made;
+    with ``train`` the selection bias carries no gradient."""
+    if cfg.moe_gate == "softmax":
+        return softmax_gate(logits, cfg.moe_top_k)
     if cfg.moe_gate == "sigmoid":
         return sigmoid_gate(logits, cfg.moe_top_k)
     bias = jax.lax.stop_gradient(p["sel_bias"]) if train else p["sel_bias"]
@@ -434,7 +449,7 @@ def moe_held_layer(cfg, p: Dict, x: jax.Array):
     with jax.named_scope("moe_route"):
         logits = jnp.einsum("nd,de->ne", tokens.astype(jnp.float32),
                             p["router"].astype(jnp.float32))
-        idx, w = route_sigmoid(cfg, p, logits, train=True)
+        idx, w = route_dropless(cfg, p, logits, train=True)
         counts = jnp.sum(idx[..., None] == jnp.arange(R), axis=(0, 1),
                          dtype=jnp.float32)
         local = idx - first
@@ -774,12 +789,13 @@ def moe_serving_mlp(cfg, p: Dict, x: jax.Array,
         "nd,de->ne", tokens.astype(jnp.float32),
         p["router"].astype(jnp.float32),
     )
-    if cfg.moe_gate != "softmax":
-        # the router sees every expert of the layer; the ``E`` held here
-        # compute the tokens sent to them, with room for every real token
-        # (no drop), and the layer returns that partial sum
+    if cfg.moe_dropless:
+        # the router (either sigmoid one, or a softmax without a capacity)
+        # sees every expert of the layer; the ``E`` held here compute the
+        # tokens sent to them, with room for every real token (no drop),
+        # and the layer returns that partial sum
         capacity = int(budget_tokens)
-        idx, w = route_sigmoid(cfg, p, router_logits)
+        idx, w = route_dropless(cfg, p, router_logits)
         tok_of_slot, slot_valid, slot_of_tok, w_of_tok, fill, unrouted = (
             held_expert_tables(idx, w, valid, cfg.moe_first_expert, E,
                                capacity))

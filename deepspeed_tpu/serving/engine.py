@@ -95,8 +95,9 @@ def cache_partition_specs(quantized: bool, window_pool: bool = False
 
 def cache_token_bytes(cfg, storage_itemsize: int, quantized: bool) -> int:
     """Bytes one token keeps in one layer of the cache, scales left out:
-    keys and values of every KV head, or for latent attention its one
-    latent row (as the pool pads it) and its indexer key."""
+    keys and values of every KV head (and, under an indexer, its index key
+    as the pool pads it), or for latent attention its one latent row (as
+    the pool pads it) and its indexer key."""
     if cfg.mixer_types and cfg.block_sparse is not None:
         # a sparse layer's keys and values, and a page's one compressed key
         # spread over its tokens (the state layers keep no token)
@@ -110,6 +111,11 @@ def cache_token_bytes(cfg, storage_itemsize: int, quantized: bool) -> int:
         width = latent_row_width(cfg) + (
             cfg.index_dim // cfg.index_kpool if cfg.index_topk else 0)
         return width * storage_itemsize
+    if cfg.index_in_pages:  # K and V of every KV head, an index key's row
+        from ..models.decoding import index_row_width
+
+        return (2 * cfg.kv_heads * cfg.hd + index_row_width(cfg)
+                ) * storage_itemsize
     return 2 * cfg.kv_heads * cfg.hd * (1 if quantized else storage_itemsize)
 
 
@@ -598,9 +604,10 @@ def make_paged_step_fn(cfg, dtype, vocab: int, cache_shardings=None,
 
     def step(params, caches, seen, tokens, num_new, start_pos, page_table,
              cow_src, *rest, page_table_win=None):
-        if page_table_win is None and not cfg.mixer_types:
-            # (a model with window layers or with slot state shares no
-            # page: nothing to copy)
+        if (page_table_win is None and not cfg.mixer_types
+                and not cfg.index_in_pages):
+            # (a model with window layers, with slot state or with index
+            # keys beside its K and V shares no page: nothing to copy)
             caches = paged_cow_copy(caches, page_table, start_pos, cow_src)
         return slot_step(params, caches, seen, tokens, num_new, start_pos,
                          *rest, page_table=page_table,
@@ -948,6 +955,40 @@ class ServingEngine:
             if int(serving.fleet.prefill_replicas) > 0:
                 raise DeepSpeedConfigError(
                     f"serving.fleet.prefill_replicas is refused: {why}")
+        # ---- an indexer beside K / V pages (index_topk, no latent): the
+        # selection is held by the tests of the plain paged path alone ----
+        self.indexed = bool(getattr(mcfg, "index_in_pages", False))
+        if self.indexed:
+            from ..config import DeepSpeedConfigError
+
+            if not self.paged:
+                raise DeepSpeedConfigError(
+                    "serving.paged false is refused: an indexer "
+                    "(index_topk) scores its cached keys and the walk reads "
+                    "the selection's K and V through the page table")
+            why = (
+                "the model selects by an indexer (index_topk) whose keys lie "
+                "in a pool of their own beside K and V: a page that is "
+                "spilled or handed over is laid out as the k and v of KV "
+                "heads, and the index keys of the same tokens would be "
+                "missing")
+            if int(getattr(serving, "host_pages", 0) or 0) > 0:
+                raise DeepSpeedConfigError(
+                    f"serving.host_pages is refused: {why}")
+            if int(serving.fleet.prefill_replicas) > 0:
+                raise DeepSpeedConfigError(
+                    f"serving.fleet.prefill_replicas is refused: {why}")
+            if self.spec_enabled:
+                raise DeepSpeedConfigError(
+                    "serving.spec is refused: a draft row's selection "
+                    "(index_topk) is made over index keys of drafts that may "
+                    "be rejected, and no test holds the verify window under "
+                    "a selection")
+            if prefix_cache:
+                log_dist("serving: prefix cache off: a kept page would hold "
+                         "index keys (index_topk) beside K and V, and no "
+                         "test holds a selection over reused pages")
+                prefix_cache = False
         # ---- state layers: a lightning or kda layer's cache is leaves a
         # slot, begun at zero with its request and never shared; a page holds
         # the paged layers' keys alone (docs/serving.md "Layer kinds") ------
@@ -1178,7 +1219,10 @@ class ServingEngine:
         # XLA compile — the zero-recompiles-after-warmup assertion
         self.step_traces = 0
         # which attention the compiled step took — "paged_kernel" (Pallas,
-        # work follows each slot's length), "latent_sparse_kernel" (the
+        # work follows each slot's length), "paged_sparse_kernel" (the
+        # indexer's two calls and the walk of
+        # ops/pallas/sparse_paged_attention.py over the selection inside
+        # paged K / V), "latent_sparse_kernel" (the
         # indexer, selection and sparse latent attention calls of
         # ops/pallas/sparse_latent_attention.py), "decode_kernel" or "dense"
         # (the XLA lines, with the reasons a kernel declined) — chosen at
@@ -1197,6 +1241,12 @@ class ServingEngine:
         self.metrics.state_bytes = state_bytes(
             mcfg, N, jnp.dtype(engine.kv_cache_storage_dtype).itemsize)
         self.metrics.hyper_streams = int(getattr(mcfg, "hc_mult", 0))
+        if self.indexed:
+            from ..models.decoding import INDEX
+
+            self.metrics.index_pool_bytes = int(
+                np.prod(cache_shapes[INDEX].shape)
+                * cache_shapes[INDEX].dtype.itemsize)
         # a routed model with mixers: the held experts that got a row in the
         # step folded last (the device's own count, read with its tokens)
         self._experts_touched: Optional[int] = None
@@ -1222,12 +1272,14 @@ class ServingEngine:
                 self.attention_paths = dict(rec["kinds"])
                 self.attention_path = rec["kinds"].get("sparse", rec["path"])
             self.metrics.attention_paged_kernel = float(
-                self.attention_path in ("paged_kernel", "latent_sparse_kernel",
+                self.attention_path in ("paged_kernel", "paged_sparse_kernel",
+                                        "latent_sparse_kernel",
                                         "block_sparse_kernel", "kda_kernel",
                                         "latent_kernel", "retention_kernel")
             )
             self.metrics.attention_paged_kernel_kinds = {
-                kind: float(path == "paged_kernel" or bool(mcfg.mixer_types)
+                kind: float(path in ("paged_kernel", "paged_sparse_kernel")
+                            or bool(mcfg.mixer_types)
                             and path.endswith("_kernel"))
                 for kind, path in rec["kinds"].items()
             }
@@ -1599,7 +1651,7 @@ class ServingEngine:
                     paged_args += self._stage_args(plan)
                 # a one-kind model pays for the count only under the tracer
                 keys = (self._count_keys(plan)
-                        if self.kinds_paged or self.latent
+                        if self.kinds_paged or self.latent or self.indexed
                         or self.config.mixer_types
                         or self.tracer is not None else {})
                 if keys:
@@ -1717,7 +1769,9 @@ class ServingEngine:
             return {**out, **self._count_selected(plan)}
         if self.config.mixer_types:
             return {**out, **self._count_mixers(plan)}
-        for kind in ("full", "window")[:1 + self.kinds_paged]:
+        if self.indexed:  # the selection's counts in place of the full walk's
+            out.update(self._count_selected(plan))
+        for kind in ("full", "window")[:(not self.indexed) + self.kinds_paged]:
             attended, fetched = key_counts(
                 plan.start_pos, plan.num_new, self.page_size,
                 self.pages_per_slot, self.config.window_of(kind),
@@ -1735,7 +1789,8 @@ class ServingEngine:
         return out
 
     def _count_selected(self, plan: StepPlan) -> Dict[str, int]:
-        """A latent model's attention work of one layer, from the plan
+        """The attention work of one layer under an indexer (a latent
+        model's, or one that selects inside paged K / V), from the plan
         (host arithmetic, nothing read back): ``context_keys`` the cached
         tokens at or before every real query token, which its indexer
         scores; ``attended_sparse`` those of them a query attends, its
@@ -1841,6 +1896,18 @@ class ServingEngine:
             "shared_width": mcfg.moe_shared_width,
             "pool_pages": {"full": self.num_pages or 0,
                            "window": self.window_num_pages or 0},
+            # an indexer: heads x width, the keys a query attends, and the
+            # bytes of the pool its keys lie in (0: no indexer)
+            "indexer": {"heads": mcfg.index_heads, "dim": mcfg.index_dim,
+                        "topk": mcfg.index_topk,
+                        "pool_bytes": self.metrics.index_pool_bytes},
+            # one member's share of a routed layer: experts held of those
+            # the router chooses among, from which on, by which router
+            "experts": {"held": mcfg.num_experts,
+                        "routed": mcfg.routed_experts,
+                        "first": mcfg.moe_first_expert,
+                        "gate": mcfg.moe_gate if mcfg.is_moe else None,
+                        "dropless": bool(mcfg.is_moe and mcfg.moe_dropless)},
             "residual_streams": getattr(mcfg, "hc_mult", 0) or 1,
             "state_bytes": self.metrics.state_bytes,
             "state_leaves": {
@@ -1891,6 +1958,13 @@ class ServingEngine:
                 f"({self._state_kinds}) state that summed the same tokens is a "
                 "slot's and no page, so a hand-off would serve a model with "
                 "state layers a context its state never saw"
+            )
+        if self.indexed:
+            raise RuntimeError(
+                f"{what}: a handed-over page is laid out as the k and v of "
+                "KV heads; the index keys (index_topk) of the same tokens "
+                "lie in a pool of their own, so a hand-off would serve a "
+                "selection over keys it does not hold"
             )
         if self.kinds_paged:
             raise RuntimeError(

@@ -202,6 +202,9 @@ class ServingMetrics:
         self.state_resets = 0
         # the residual streams of a hyper-connected model (0 = the one)
         self.hyper_streams = 0
+        # the bytes of an indexer's key pool beside K / V pools (gauge, set
+        # once an engine; 0 = no such pool)
+        self.index_pool_bytes = 0
         self._max_slots = 1
         self._num_pages = 0
         self._host_pages = 0
@@ -500,6 +503,8 @@ class ServingMetrics:
             snap["state_resets"] = self.state_resets
         if self.hyper_streams:
             snap["hyper_streams"] = self.hyper_streams
+        if self.index_pool_bytes:
+            snap["index_pool_bytes"] = self.index_pool_bytes
         if "window" in self.attended_keys:
             snap.update({
                 "pages_free": self.pages_free,

@@ -717,6 +717,51 @@ def test_deepseek_slot_step_keeps_both_pools_in_place(one_chip, monkeypatch,
         assert name in text
 
 
+def test_keye_slot_step_keeps_the_three_pools_in_place(one_chip, monkeypatch,
+                                                       capsys):
+    """The one [4, 128] serving step of Keye-VL-2.0-30B-A3B's language model
+    at its published widths and the benchmark's cut (12 of 48 layers, 32 of
+    128 experts, a quarter of the vocabulary) over its arena of 16,640
+    pages: K, V and the indexer-key pool (64 values a token, stored 128
+    wide) ride the layer scan as one carry, so the compiled step holds no
+    copy of a pool's or a layer's size and every pool's scatter takes the
+    budget's rows; the indexer's two calls and the walk over the selection
+    are in it by name; and it fits the described chip."""
+    from deepspeed_tpu.models import keye
+    from deepspeed_tpu.models.decoding import init_paged_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = keye("keye-vl-2.0-30b-a3b", num_layers=12, num_experts=32,
+                 moe_routed_experts=128, vocab_size=37984)
+    N, W, ps, cap = 4, 128, 16, 66560
+    caches = jax.eval_shape(
+        lambda: init_paged_cache(model.config, 16640, ps, BF16))
+    assert {k: v.shape[2:] for k, v in caches.items()} == {
+        "k": (ps, 4, 128), "v": (ps, 4, 128), "ki": (ps, 128)}
+    compiled = _compile_slot_step(model, caches, one_chip, N, W,
+                                  -(-(cap + W) // ps))
+    m = compiled.memory_analysis()
+    pools = sum(a.size * a.dtype.itemsize for a in caches.values())
+    with capsys.disabled():
+        print(f"\nkeye slot step, described v5e: arguments "
+              f"{m.argument_size_in_bytes / GIB:.2f} GiB, temporaries "
+              f"{m.temp_size_in_bytes / GIB:.2f} GiB, aliased "
+              f"{m.alias_size_in_bytes / GIB:.2f} (the pools "
+              f"{pools / GIB:.2f})")
+    text = compiled.as_text()
+    assert _pool_copies(text, caches) == []
+    assert m.alias_size_in_bytes >= pools
+    _check_pool_writes_take_the_budget(
+        compiled, caches, ("k", "v", "ki"), N, W, "keye", capsys)
+    _check_placement_is_one_pass(compiled, W, model.config, "keye", capsys)
+    _check_weights_are_read_as_held(compiled, model, "keye", capsys)
+    for call in ("indexer_scores", "selection_topk",
+                 "sparse_paged_attention"):
+        assert call in text, call
+    assert "paged_attention_full" not in text
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.0 * GIB
+
+
 def test_minicpm_sala_slot_step_keeps_pools_and_states_in_place(
         one_chip, monkeypatch, capsys):
     """The one [4, 128] serving step of MiniCPM-SALA at its published widths
