@@ -13,9 +13,16 @@ engine compiles. Three calls a layer, each named in a device trace:
     over row tiles that follows the slot's real rows.
 ``selection_topk``  The exact ``topk`` largest scores of each row, as a
     threshold: 32 counting passes find the ``topk``-th largest value bit by
-    bit (the scores as order-preserving integers), 17 more the position up
-    to which its ties belong (ties go to the lower position). A row whose
-    context is within ``topk`` keeps it all. No sort, no index list.
+    bit (the scores as order-preserving integers), one more counts the keys
+    above it, its ties and the last of them. Where every row of a tile
+    needs all its ties (the rule: float32 sums seldom meet at the
+    threshold) that last position is the answer; else 17 passes more find
+    the position up to which the ties belong (ties go to the lower
+    position). A pass adds lane by lane over the key blocks the context
+    reaches and sums across the lanes once. A program a tile of 8 rows;
+    one that searches nothing (no real row, or a context within ``topk``,
+    which keeps it all) parks its block of scores on a neighbour's, so it
+    fetches none. No sort, no index list.
 ``sparse_latent_attention``  Softmax attention of the absorbed queries over
     the chosen tokens of the latent pool. One latent row is key AND value of
     all heads, so the heads of a query stack as the rows of one matmul
@@ -59,6 +66,7 @@ from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -239,8 +247,21 @@ def _sort_key(x):
     return i ^ ((i >> 31) & jnp.int32(0x7FFFFFFF))
 
 
-def _selection_kernel(cl_ref, nn_ref, s_ref, thr_ref, tie_ref, key_scr,
-                      *, topk, rows, group, pos_bits, kpool: int = 1):
+def _keys(tokens, kpool: int):
+    """tokens -> keys (with ``kpool``: the whole blocks of that many)."""
+    return tokens // kpool if kpool > 1 else tokens
+
+
+def _searches(cl, nn, r0, rows: int, topk: int, kpool: int):
+    """Whether the row tile at ``r0`` of a slot holds a real row whose
+    context passes ``topk``, that of the tile's last real row ``cl +
+    min(nn, r0 + rows)``: the tiles that have something to select."""
+    return (r0 < nn) & (_keys(cl + nn, kpool) > topk) & (
+        _keys(cl + r0 + rows, kpool) > topk)
+
+
+def _selection_kernel(cl_ref, nn_ref, at_ref, s_ref, thr_ref, tie_ref,
+                      key_scr, *, topk, rows, group, pos_bits, kpool: int = 1):
     b, t = pl.program_id(0), pl.program_id(1)
     cl, nn = cl_ref[b], nn_ref[b]
     bk = s_ref.shape[-1]
@@ -248,15 +269,15 @@ def _selection_kernel(cl_ref, nn_ref, s_ref, thr_ref, tie_ref, key_scr,
     # every key allowed: what a row inside ``topk`` (or a padded row) gets
     thr_ref[0] = jnp.full(thr_ref.shape[1:], INT_MIN, jnp.int32)
     tie_ref[0] = jnp.full(tie_ref.shape[1:], 2 ** 31 - 1, jnp.int32)
-    # tokens -> keys (with kpool: the whole blocks of that many tokens)
-    keys = (lambda n: n // kpool) if kpool > 1 else (lambda n: n)
 
-    @pl.when((r0 < nn) & (keys(cl + jnp.minimum(nn, r0 + rows)) > topk))
+    @pl.when(_searches(cl, nn, r0, rows, topk, kpool))
     def _search():
-        qpos = cl + r0 + lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1)
+        row = r0 + lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        qpos = cl + row
         if kpool > 1:  # the last block whose last token is at or before it
             qpos = (qpos + 1) // kpool - 1
-        n_groups = pl.cdiv(keys(cl + jnp.minimum(nn, r0 + rows)), group * bk)
+        n_groups = pl.cdiv(_keys(cl + jnp.minimum(nn, r0 + rows), kpool),
+                           group * bk)  # counting steps the context reaches
         shape = (group, rows, bk)
         in_group = lax.broadcasted_iota(jnp.int32, shape, 0) * bk + (
             lax.broadcasted_iota(jnp.int32, shape, 2))
@@ -265,21 +286,30 @@ def _selection_kernel(cl_ref, nn_ref, s_ref, thr_ref, tie_ref, key_scr,
             at = pl.ds(g * group, group)
             pos = g * group * bk + in_group
             key_scr[at] = jnp.where(
-                pos <= qpos, _sort_key(s_ref[0, at]), INT_MIN)
+                pos <= qpos[None], _sort_key(s_ref[0, at]), INT_MIN)
             return carry
 
         lax.fori_loop(0, n_groups, fill, 0)
 
-        def count(pred):
-            def one(g, acc):
-                pos = g * group * bk + in_group
-                hit = pred(key_scr[pl.ds(g * group, group)], pos)
-                return acc + jnp.sum(
-                    jnp.sum(hit.astype(jnp.int32), axis=0), axis=1,
-                    keepdims=True)
+        def wide(v):  # a value a row, over a group's lanes
+            return jnp.broadcast_to(v, (rows, bk))[None]
 
-            return lax.fori_loop(0, n_groups, one,
-                                 jnp.zeros((rows, 1), jnp.int32))
+        def over_groups(step, init):
+            """``step(acc, keys, positions)`` folded over the groups the
+            context reaches, lane by lane: the lanes meet once a pass."""
+            def one(g, acc):
+                return step(acc, key_scr[pl.ds(g * group, group)],
+                            g * group * bk + in_group)
+
+            return lax.fori_loop(0, n_groups, one, init)
+
+        zeros = jnp.zeros((rows, bk), jnp.int32)
+
+        def count(pred):
+            return jnp.sum(over_groups(
+                lambda acc, k, p: acc + jnp.sum(
+                    pred(k, p).astype(jnp.int32), axis=0), zeros),
+                axis=1, keepdims=True)
 
         # the topk-th largest key, from its sign down: the largest T with
         # count(key >= T) >= topk
@@ -287,24 +317,55 @@ def _selection_kernel(cl_ref, nn_ref, s_ref, thr_ref, tie_ref, key_scr,
 
         def value_bit(i, lo):
             cand = lo + jnp.left_shift(jnp.int32(1), 30 - i)
-            n = count(lambda k, p: k >= cand[None])
-            return jnp.where(n >= topk, cand, lo)
+            at = wide(cand)
+            return jnp.where(count(lambda k, p: k >= at) >= topk, cand, lo)
 
         lo = lax.fori_loop(0, 31, value_bit, lo)
-        need = topk - count(lambda k, p: k > lo[None])  # of its ties
+        thr = wide(lo)
 
-        def pos_bit(i, at):  # the largest p with count(tie, pos < p) < need
-            cand = at + jnp.left_shift(jnp.int32(1), pos_bits - 1 - i)
-            n = count(lambda k, p: (k == lo[None]) & (p < cand[None]))
-            return jnp.where(n < need, cand, at)
+        def ties(acc, k, p):  # keys above it, its ties, the last of them
+            above, equal, last = acc
+            tied = k == thr
+            return (above + jnp.sum((k > thr).astype(jnp.int32), axis=0),
+                    equal + jnp.sum(tied.astype(jnp.int32), axis=0),
+                    jnp.maximum(last, jnp.max(jnp.where(tied, p, -1), axis=0)))
 
-        tie = lax.fori_loop(0, pos_bits, pos_bit,
-                            jnp.zeros((rows, 1), jnp.int32))
-        inside = qpos[0] < topk  # such a row keeps its whole context
-        thr_ref[0] = jnp.broadcast_to(
-            jnp.where(inside, INT_MIN, lo), thr_ref.shape[1:])
-        tie_ref[0] = jnp.broadcast_to(
-            jnp.where(inside, 2 ** 31 - 1, tie), tie_ref.shape[1:])
+        above, equal, last = over_groups(
+            ties, (zeros, zeros, jnp.full((rows, bk), -1, jnp.int32)))
+        need = topk - jnp.sum(above, axis=1, keepdims=True)  # of its ties
+        equal = jnp.sum(equal, axis=1, keepdims=True)
+        last = jnp.max(last, axis=1, keepdims=True)
+        # such a row keeps its whole context (a padded one has no scores)
+        free = (qpos < topk) | (row >= nn)
+
+        def write(tie):
+            thr_ref[0] = jnp.broadcast_to(
+                jnp.where(free, INT_MIN, lo), thr_ref.shape[1:])
+            tie_ref[0] = jnp.broadcast_to(
+                jnp.where(free, 2 ** 31 - 1, tie), tie_ref.shape[1:])
+
+        # a row all of whose ties are needed takes them up to the last one
+        write(last)
+
+        @pl.when(jnp.sum(jnp.where((equal == need) | free, 0, 1)) > 0)
+        def _tie_search():  # some row's ties exceed its need
+            def pos_bit(i, at):  # the largest p: count(tie, pos < p) < need
+                cand = at + jnp.left_shift(jnp.int32(1), pos_bits - 1 - i)
+                end = wide(cand)
+                n = count(lambda k, p: (k == thr) & (p < end))
+                return jnp.where(n < need, cand, at)
+
+            write(lax.fori_loop(0, pos_bits, pos_bit,
+                                jnp.zeros((rows, 1), jnp.int32)))
+
+
+def selection_tiles(cache_len, num_new, S: int, topk: int, kpool: int = 1):
+    """bool [B, S / SELECT_ROWS] (numpy or jax, as the frontiers are): the
+    programs of :func:`select_topk`'s grid that search; the others write
+    "everything" and fetch no score."""
+    rows = min(SELECT_ROWS, S)
+    return _searches(cache_len[:, None], num_new[:, None],
+                     np.arange(0, S, rows)[None], rows, topk, kpool)
 
 
 def select_topk(scores, cache_len, num_new, topk: int,
@@ -317,17 +378,34 @@ def select_topk(scores, cache_len, num_new, topk: int,
     of them inside ``topk`` tokens). See :func:`_sort_key` for ``key``.
     ``kpool`` > 1: ``scores`` are of blocks of that many tokens, ``s`` and
     ``tie`` count blocks, and the row sees block ``s`` iff its last token
-    ``kpool s + kpool - 1`` is at or before the row."""
+    ``kpool s + kpool - 1`` is at or before the row. A row past ``num_new``
+    keeps everything, as a row inside ``topk`` does."""
     B, NB, S, bk = scores.shape
     rows = min(SELECT_ROWS, S)
+    tiles = S // rows
     group = min(SELECT_BLOCKS, NB)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     cl, nn = _frontiers(B, S, cache_len, num_new)
+    # where a program that searches nothing parks its block of scores: on
+    # the one the last searching program before it fetched, else on the one
+    # the next will, so the pipeline finds the block it holds or needs next
+    # and an idle tile moves no score
+    prog = jnp.arange(B * tiles, dtype=jnp.int32)
+    live = selection_tiles(cl, nn, S, int(topk), kpool).reshape(-1)
+    upto = prog[None, :] <= prog[:, None]
+    before = jnp.max(jnp.where(upto & live[None, :], prog[None, :], -1), 1)
+    after = jnp.min(jnp.where(~upto & live[None, :], prog[None, :],
+                              B * tiles), 1)
+    at = jnp.where(before >= 0, before, after % (B * tiles))  # (none: 0)
+
+    def parked(b, t, cl, nn, at):
+        p = at[b * tiles + t]
+        return p // tiles, 0, p % tiles, 0
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(B, S // rows),
-        in_specs=[pl.BlockSpec((1, NB, rows, bk),
-                               lambda b, t, *_: (b, 0, t, 0))],
+        num_scalar_prefetch=3, grid=(B, tiles),
+        in_specs=[pl.BlockSpec((1, NB, rows, bk), parked)],
         out_specs=[pl.BlockSpec((1, rows, LANES), lambda b, t, *_: (b, t, 0)),
                    pl.BlockSpec((1, rows, LANES), lambda b, t, *_: (b, t, 0))],
         scratch_shapes=[pltpu.VMEM((NB, rows, bk), jnp.int32)],
@@ -342,7 +420,7 @@ def select_topk(scores, cache_len, num_new, topk: int,
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret, name="selection_topk",
-    )(cl, nn, scores)
+    )(cl, nn, at, scores)
     return thr[:, :, 0], tie[:, :, 0]
 
 

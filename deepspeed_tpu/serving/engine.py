@@ -762,12 +762,14 @@ def _mla_counts(engine, cl, nn) -> Dict[str, int]:
     attends, those of its ``index_topk`` best blocks and its tail;
     ``tail_keys`` the tokens attended because they lie after a query's last
     whole block; ``chosen_min`` the fewest latent rows a slot's queries can
-    have chosen between them (its last query's). Books the attended keys on
-    the metrics."""
+    have chosen between them (its last query's); ``selection_tiles`` of
+    ``selection_tiles_grid`` as :func:`_selection_tiles` counts them. Books
+    the attended keys on the metrics."""
     cfg = engine.config
     kp, topk = int(cfg.index_kpool), int(cfg.index_topk) or (1 << 62)
     counts = dict.fromkeys(("context_keys", "index_keys", "index_rows",
                             "attended_sparse", "tail_keys", "chosen_min"), 0)
+    counts.update(_selection_tiles(cl, nn, engine.token_budget, topk, kp))
     for c, n in zip(cl[nn > 0], nn[nn > 0]):
         seen = np.arange(c, c + n) + 1  # a query's context, itself included
         whole = seen // kp
@@ -783,6 +785,20 @@ def _mla_counts(engine, cl, nn) -> Dict[str, int]:
     engine.metrics.on_keys("sparse", counts["attended_sparse"],
                            counts["chosen_min"])
     return counts
+
+
+def _selection_tiles(cl, nn, width: int, topk: int,
+                     kpool: int = 1) -> Dict[str, int]:
+    """What share of the selection's grid has something to select:
+    ``selection_tiles`` the 8-row tiles of the ``[max_slots, width]`` step
+    that hold a real row whose context passes ``topk`` (the programs of
+    ``selection_topk`` that search; the others fetch no score), of
+    ``selection_tiles_grid`` programs a call."""
+    from ..ops.pallas.sparse_latent_attention import selection_tiles
+
+    tiles = selection_tiles(cl, nn, width, topk, kpool)
+    return {"selection_tiles": int(tiles.sum()),
+            "selection_tiles_grid": int(tiles.size)}
 
 
 # what a step's plan says of one layer of each mixer kind ``mixer_types`` may
@@ -1797,8 +1813,9 @@ class ServingEngine:
         ``index_topk`` best; ``index_keys`` the tokens in the pages that
         hold a slot's context, which the indexer reads once a slot;
         ``chosen_min`` the fewest distinct latent rows a slot's queries can
-        have chosen between them (its last query's). Booked on the
-        metrics."""
+        have chosen between them (its last query's); ``selection_tiles`` of
+        ``selection_tiles_grid`` as :func:`_selection_tiles` counts them.
+        Booked on the metrics."""
         cl = plan.start_pos.astype(np.int64)
         nn = plan.num_new.astype(np.int64)
         topk = int(self.config.index_topk) or (1 << 62)
@@ -1813,6 +1830,7 @@ class ServingEngine:
             "attended_sparse": int(attended.sum()),
             "index_keys": int((-(-(cl + nn) // ps) * ps)[busy].sum()),
             "chosen_min": int(np.minimum(cl + nn, topk)[busy].sum()),
+            **_selection_tiles(cl, nn, self.token_budget, topk),
         }
         self.metrics.on_keys("sparse", counts["attended_sparse"],
                              counts["index_keys"])

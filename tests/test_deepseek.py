@@ -6,6 +6,7 @@ and a leading dense layer in a stack of its own, each against the plain
 reference ``benchmarks/families/deepseek.py``."""
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -177,6 +178,96 @@ def test_the_selection_kernel_chooses_the_references_set(ties):
     counts = np.asarray(got.sum(-1))
     assert list(counts[2, :9]) == [24] * 9 and list(counts[0]) == list(
         range(1, 17))
+
+
+def selection_by_sort(scores, cl, nn, topk, kpool=1):
+    """(thr, tie) as :func:`sla.select_topk` defines them, by a sort: the
+    ``topk``-th largest sort key of what a row sees and the position of the
+    ``need``-th of its ties, ``need`` what the keys above it leave of
+    ``topk``; a row inside ``topk`` keeps everything."""
+    key = sla._sort_key(sla.unblocked(scores))
+    qpos = sla.last_block(cl[:, None] + jnp.arange(key.shape[1])[None], kpool)
+    seen = jnp.arange(key.shape[-1])[None, None] <= qpos[..., None]
+    key = jnp.where(seen, key, sla.INT_MIN)
+    thr = jnp.sort(key, axis=-1)[..., -topk]
+    tied = key == thr[..., None]
+    need = topk - jnp.sum(key > thr[..., None], -1)
+    tie = jnp.argmax(tied & (jnp.cumsum(tied, -1) == need[..., None]), -1)
+    inside = qpos < topk
+    return (jnp.where(inside, sla.INT_MIN, thr),
+            jnp.where(inside, 2 ** 31 - 1, tie).astype(jnp.int32))
+
+
+SEL = dict(B=4, NB=24, S=16, bk=128, topk=24)  # 3 counting steps of 8 blocks
+
+
+def _selection_case(name):
+    """(scores [B, NB, S, bk], cache_len, num_new, kpool) of a case; what no
+    row may read (past its position, past ``num_new``) is NaN."""
+    B, NB, S, bk, topk = (SEL[k] for k in ("B", "NB", "S", "bk", "topk"))
+    rng = np.random.default_rng(sum(map(ord, name)))
+    flat = rng.normal(size=(B, S, NB * bk)).astype(np.float32)
+    kpool = 1
+    cl, nn = [0, 37, 150, 700], [16, 1, 9, 16]
+    if name == "ties":  # many equal scores, signed zeros among them
+        flat = np.round(flat * 2) / 2
+    elif name == "exact_beside_excess":
+        # one tile: row 0's ties at the threshold are just what it needs
+        # (4 of 4), row 1's exceed it (4 of 10), row 2's are its whole need
+        flat = np.round(flat) * 0.0  # signed zeros under every row
+        cl, nn = [100, 37, 150, 700], [3, 1, 9, 16]
+        for row, ties in ((0, 4), (1, 10), (2, 30)):
+            at = rng.permutation(100)
+            flat[0, row, at[:20]] = 2.0
+            flat[0, row, at[20:20 + ties]] = 1.0
+    elif name == "decode_far_past_topk":
+        cl, nn = [2900, 1500, 10, 2047], [1, 1, 1, 1]
+    elif name == "idle_first_and_between":
+        cl, nn = [500, 300, 900, 1100], [0, 5, 0, 16]
+    elif name == "idle_last_and_all_but_one":
+        cl, nn = [500, 300, 900, 1100], [0, 0, 12, 0]
+    elif name == "partial_last_group":  # 1,024 keys a counting step
+        cl, nn = [1500, 2041, 1016, 1023], [16, 9, 16, 2]
+    elif name == "kpool4":  # a key a block of 4 tokens
+        kpool = 4
+        cl, nn = [0, 150, 9000, 4093], [16, 1, 9, 16]
+    cl, nn = np.asarray(cl, np.int32), np.asarray(nn, np.int32)
+    qpos = (cl[:, None] + np.arange(S)[None] + 1) // kpool - 1
+    defined = (np.arange(NB * bk)[None, None] <= qpos[..., None]) & (
+        np.arange(S)[None] < nn[:, None])[..., None]
+    flat = np.where(defined, flat, np.nan).astype(np.float32)
+    return flat.reshape(B, S, NB, bk).swapaxes(1, 2), cl, nn, kpool
+
+
+@functools.lru_cache(maxsize=None)
+def _select(kpool):  # one program a shape
+    return (jax.jit(lambda s, cl, nn: sla.select_topk(
+                s, cl, nn, SEL["topk"], interpret=True, kpool=kpool)),
+            jax.jit(lambda s, cl, nn: selection_by_sort(
+                s, cl, nn, SEL["topk"], kpool)))
+
+
+@pytest.mark.parametrize("case", [
+    "distinct", "ties", "exact_beside_excess", "decode_far_past_topk",
+    "idle_first_and_between", "idle_last_and_all_but_one",
+    "partial_last_group", "kpool4"])
+def test_the_selection_is_the_sorts_threshold_and_tie(case):
+    """``select_topk``'s (threshold, tie position) EQUAL a sort's on every
+    real row, whatever the call skips: an idle tile's scores (parked on a
+    neighbour's, before or after it), the tie search where every row of a
+    tile needs all its ties, the counting steps past a context."""
+    scores, cl, nn, kpool = _selection_case(case)
+    kernel, by_sort = _select(kpool)
+    real = np.arange(SEL["S"])[None] < nn[:, None]
+    assert real.sum() == nn.sum() > 0
+    for got, want in zip(kernel(scores, cl, nn), by_sort(scores, cl, nn)):
+        np.testing.assert_array_equal(np.asarray(got)[real],
+                                      np.asarray(want)[real])
+    tiles = sla.selection_tiles(cl, nn, SEL["S"], SEL["topk"], kpool)
+    assert tiles.shape == (SEL["B"], 2) and not tiles[nn == 0].any()
+    if case == "decode_far_past_topk":  # the slot at 10 keeps its 11 keys
+        assert tiles.tolist() == [[True, False]] * 2 + [[False] * 2] + [
+            [True, False]]
 
 
 def test_the_attention_kernel_attends_the_chosen_rows_alone():

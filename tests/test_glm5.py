@@ -170,13 +170,15 @@ def test_engine_serves_what_the_reference_predicts(model, params, shape):
             "expert_touched_kernel"} <= set(snap)
     # the step's counts: one query at position 25 sees 6 whole blocks, 2
     # tail tokens; its selection (6 blocks) spares it nothing yet, one at
-    # position 41 attends 6 of its 10 blocks and its 2 tail tokens
+    # position 41 attends 6 of its 10 blocks and its 2 tail tokens: its row
+    # tile is the one program of the selection's grid that searches
     from deepspeed_tpu.serving.engine import _mla_counts
 
     got = _mla_counts(srv, np.asarray([25, 41, 0]), np.asarray([1, 1, 0]))
     assert got == dict(context_keys=26 + 42, index_keys=6 + 10,
                        index_rows=6 + 10, attended_sparse=26 + 26,
-                       tail_keys=2 + 2, chosen_min=26 + 26)
+                       tail_keys=2 + 2, chosen_min=26 + 26,
+                       selection_tiles=1, selection_tiles_grid=3 * W // 8)
 
 
 @pytest.mark.parametrize("fault", fam.FAULTS)
