@@ -14,14 +14,19 @@ that XLA maps onto the MXU as a batched matvec.
 from __future__ import annotations
 
 import contextlib
-from typing import Dict, Optional, Tuple
+import math
+from dataclasses import dataclass
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
+from ..config import DeepSpeedConfigError
 from .sharding import constrain
 from .transformer import (
+    MIXER_KINDS,
     Params,
     TransformerConfig,
     _norm,
@@ -347,101 +352,249 @@ def _pooled_index_write(cfg: TransformerConfig, pools: Cache, k_new, layer,
     }
 
 
+@dataclass(frozen=True)
+class Pool:
+    """One leaf of the paged arena, ``[layers, entries, *row]``: ``table``
+    says what indexes its second axis (``"page"``: the page table, the NULL
+    page after the last; ``"window"``: the window layers' table, likewise;
+    ``"slot"``: the slot, no page), ``row`` is one page's or one slot's
+    entry, ``spec`` the leaf's partition spec on a mesh (cache heads over
+    tp; a latent row, an index key and a family module's leaves whole)."""
+
+    name: str
+    table: str
+    layers: int
+    row: Tuple[int, ...]
+    dtype: Any
+    spec: P = P()
+
+    @property
+    def row_bytes(self) -> int:
+        """Bytes of one entry of one layer."""
+        return math.prod(self.row) * jnp.dtype(self.dtype).itemsize
+
+
+# What a cache admits: a row a property a model's cache can have, a column an
+# operation on its pages, slots or storage, an entry the reason the operation
+# is refused (none: admitted; ``{window}``, ``{state_kinds}``, ``{page_holds}``
+# come from the configuration). The columns, as the serving configuration
+# names them: "paged false" (a contiguous arena) and "int8", asked by whoever
+# builds the arena; "host_pages", "fleet.prefill_replicas" (and with it
+# export_kv_pages / import_kv_pages) and "spec", asked by the serving engine;
+# "prefix_cache", which the engine switches off with the reason, and the
+# step's copy-on-write with it: no page is shared.
+_WINDOW_MOVES = (
+    "the model has window layers ({window} keys): a page that is kept, "
+    "spilled or handed over holds the full layers' keys alone, and the "
+    "window layers' last {window} keys would be missing")
+_LATENT_MOVES = (
+    "the model caches latents (kv_latent_dim): a spilled or handed-over page "
+    "is laid out as the k and v of KV heads, which a latent pool and its "
+    "indexer keys are not")
+_INDEX_MOVES = (
+    "the model selects by an indexer (index_topk) whose keys lie in a pool of "
+    "their own beside K and V: a page that is spilled or handed over is laid "
+    "out as the k and v of KV heads, and the index keys of the same tokens "
+    "would be missing")
+_STATE_MOVES = (
+    "a page {page_holds}; the state layers' ({state_kinds}) state that "
+    "summed the same tokens is a slot's and no page, so a page that is kept, "
+    "spilled or handed over would serve a model with state layers a context "
+    "its state never saw")
+CACHE_ADMITS: Dict[str, Dict[str, str]] = {
+    # a second pool behind a window (layer_pattern names window layers)
+    "window pool": dict.fromkeys(
+        ("host_pages", "fleet.prefill_replicas", "prefix_cache"),
+        _WINDOW_MOVES),
+    # every layer's cache is latent rows (kv_latent_dim, no mixer_types)
+    "latent rows": {
+        "paged false": "latent attention (kv_latent_dim) attends its cached "
+                       "latents through the page table",
+        "int8": "a latent cache (kv_latent_dim) holds one normed latent a "
+                "token for all heads, and no per-head scale applies to it",
+        "host_pages": _LATENT_MOVES, "fleet.prefill_replicas": _LATENT_MOVES,
+    },
+    # index keys in a pool of their own beside K / V (index_topk, no latent)
+    "index keys": {
+        "paged false": "an indexer (index_topk) scores its cached keys and "
+                       "the walk reads the selection's K and V through the "
+                       "page table",
+        "int8": "the walk over an indexer's selection (index_topk) reads K "
+                "and V as they are computed",
+        "host_pages": _INDEX_MOVES, "fleet.prefill_replicas": _INDEX_MOVES,
+        "spec": "a draft row's selection (index_topk) is made over index "
+                "keys of drafts that may be rejected, and no test holds the "
+                "verify window under a selection",
+        "prefix_cache": "a kept page would hold index keys (index_topk) "
+                        "beside K and V, and no test holds a selection over "
+                        "reused pages",
+    },
+    # the layers are named (mixer_types): state a slot beside the page kinds
+    "slot state": {
+        "paged false": "a sparse or latent layer reads its keys through the "
+                       "page table and a state layer keeps its state a slot "
+                       "(mixer_types); all live in the paged arena",
+        "int8": "a sparse layer's compressed keys are means of its cached "
+                "keys, a latent layer's row takes no per-head scale, and a "
+                "state layer's state is float32",
+        "host_pages": _STATE_MOVES, "fleet.prefill_replicas": _STATE_MOVES,
+        "spec": "the model has state layers ({state_kinds}), whose state "
+                "sums every row it was fed; a rejected draft would need the "
+                "state rolled back to the last accepted token, and the step "
+                "keeps no such copy",
+        "prefix_cache": _STATE_MOVES,
+    },
+}
+
+
+@dataclass(frozen=True)
+class CacheLayout:
+    """What a model's cache is (:func:`cache_layout`): the rows of
+    ``CACHE_ADMITS`` it has and the words their reasons take; the page
+    tables a paged step takes (the suffixes of their pools' names); what a
+    step's plan counts, in order (``counted``: a layer kind whose pages
+    ``key_counts`` walks, or the serving engine's ``_count_<name>``); and
+    ``unit_page``, the smallest page every pool is whole in (a sparse layer
+    keeps one compressed key a page of ``kernel_stride`` tokens, an indexer
+    one key a block of ``index_kpool``), over which a token's bytes are
+    taken."""
+
+    cfg: TransformerConfig
+    rows: Tuple[str, ...]
+    words: Dict[str, Any]
+    tables: Tuple[str, ...]
+    counted: Tuple[str, ...]
+    unit_page: int
+
+    def refused(self, op: str) -> Optional[str]:
+        """Why this cache refuses ``op`` (a column of ``CACHE_ADMITS``), or
+        None where it admits it."""
+        return next((CACHE_ADMITS[r][op].format(**self.words)
+                     for r in self.rows if op in CACHE_ADMITS[r]), None)
+
+    def refuse(self, op: str) -> None:
+        """Raise where ``op`` is refused: a key of the serving configuration
+        by its name, the arena's storage in the words that
+        ``InferenceEngine``'s callers read too."""
+        why = self.refused(op)
+        if why:
+            said = {"paged false": "a contiguous KV arena is refused and "
+                                   "with it serving.paged false",
+                    "int8": "an int8 KV cache"}.get(op, "serving." + op)
+            raise DeepSpeedConfigError(f"{said} is refused: {why}")
+
+    def pools(self, page_size: int, dtype=jnp.bfloat16,
+              quantized: bool = False) -> Tuple[Pool, ...]:
+        """Every leaf of the paged arena. The leaves of a model that names
+        its layers are its family module's (``init_pools``), read here off
+        their shapes."""
+        cfg = self.cfg
+        if cfg.mixer_types:
+            from .mixers import family
+
+            slot = {n for k in cfg.mixer_types for n in cfg.slot_leaves_of(k)}
+            leaves = jax.eval_shape(lambda: family(cfg).init_pools(
+                cfg, 0, page_size, 1, dtype))
+            return tuple(Pool(n, "slot" if n in slot else "page", a.shape[0],
+                              a.shape[2:], a.dtype) for n, a in leaves.items())
+        L = cfg.total_layers
+        if cfg.is_latent:
+            widths = {LATENT: latent_row_width(cfg)}
+            if cfg.index_topk:
+                widths[INDEX] = cfg.index_dim
+            return tuple(Pool(n, "page", L, (page_size, w), dtype)
+                         for n, w in widths.items())
+        # int8 storage carries a float32 scale a (token, head), in the
+        # pre-transposed layout the decode kernel consumes
+        kv = dict(row=(page_size, cfg.kv_heads, cfg.hd),
+                  dtype=jnp.int8 if quantized else dtype,
+                  spec=P(None, None, None, "tp", None))
+        scale = dict(row=(cfg.kv_heads, page_size, SCALE_LANES),
+                     dtype=jnp.float32, spec=P(None, None, "tp", None, None))
+        leaves = {"k": kv, "v": kv}
+        if quantized:
+            leaves.update(k_scale=scale, v_scale=scale)
+        if cfg.has_window:  # pages by layer kind, a table each
+            return tuple(Pool(n + sfx, table, cfg.kind_count(kind), **at)
+                         for sfx, table, kind in (("", "page", "full"),
+                                                  (WIN, "window", "window"))
+                         for n, at in leaves.items())
+        out = [Pool(n, "page", L, **at) for n, at in leaves.items()]
+        if cfg.index_in_pages:  # an index key a token, on K and V's table
+            out.append(Pool(INDEX, "page", L,
+                            (page_size, index_row_width(cfg)), dtype))
+        return tuple(out)
+
+
+def cache_layout(cfg: TransformerConfig) -> CacheLayout:
+    """The ONE description of ``cfg``'s cache (host arithmetic over the
+    configuration): what ``init_paged_cache`` builds, what a token and a
+    slot weigh in it, what may be done to it and what a step counts are
+    read off this record. A new kind of cache is an entry of
+    ``MIXER_KINDS``, its pools and its row of ``CACHE_ADMITS``."""
+    kinds = dict.fromkeys(cfg.mixer_types)
+    paged = ", ".join(k for k in kinds if MIXER_KINDS[k].page)
+    words = {
+        "window": cfg.attn_window,
+        "state_kinds": ", ".join(k for k in kinds if cfg.slot_leaves_of(k)),
+        # what a page of this model holds (nothing, where no kind of its
+        # layers keeps a page)
+        "page_holds": (f"holds the paged layers' ({paged}) keys alone"
+                       if paged else
+                       "holds nothing (no layer of this model keeps a page)"),
+    }
+    has = {"window pool": cfg.has_window, "latent rows": cfg.is_latent,
+           "index keys": cfg.index_in_pages,
+           "slot state": bool(cfg.mixer_types)}
+    if cfg.mixer_types:
+        counted = ("mixers",)
+    elif cfg.is_latent:
+        counted = ("selected",)
+    elif cfg.index_in_pages:  # the selection's counts in place of the walk's
+        counted = ("selected", "share")
+    else:
+        counted = (*dict.fromkeys(cfg.layer_pattern or ("full",)), "share")
+    geom = cfg.block_sparse
+    return CacheLayout(
+        cfg, tuple(r for r in CACHE_ADMITS if has[r]), words,
+        ("", WIN) if cfg.has_window else ("",), counted,
+        geom.kernel_stride if geom is not None else cfg.index_kpool)
+
+
 def init_paged_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
                      dtype=jnp.bfloat16, quantized: bool = False,
                      window_pages: Optional[int] = None,
                      max_slots: Optional[int] = None) -> Cache:
-    """Block-paged KV pool for all layers (the serving engine's paged
-    arena): ``k``/``v`` are [L, num_pages + 1, page_size, KV, hd] — one
-    extra physical page at index ``num_pages`` is the NULL page, where
-    unmapped logical pages, a packed step's idle rows and (in the slot
-    layout) idle slots' padded chunk writes land (its bytes are garbage by
-    design and never attendable: every query masks at its own frontier). int8 storage carries per-(token, head)
-    scales in the pre-transposed [L, P+1, KV, page_size, SL] layout the
-    decode kernel consumes.
-
-    A model whose full layers select by an indexer (``cfg.index_topk``,
-    no latent) keeps ``ki`` [L, num_pages + 1, page_size, index_row_width]
-    beside ``k``/``v``: an index key a token, on the same page table.
-
-    A model with window layers (``cfg.has_window``) keeps pages by layer
-    kind: ``k``/``v`` hold the full layers alone, and ``k_win``/``v_win``
-    [L_window, window_pages + 1, ...] the window layers, whose pages a
-    slot gives back once every query still to come is past them.
-
-    A model that names its layers (``cfg.mixer_types``) keeps pages
-    for the layers whose kind keeps any (``MIXER_KINDS``: ``k``/``v`` and a
-    compressed key a page, ``kc``, for sparse layers; latent rows ``kv`` for
-    latent and mla layers, and an indexer's keys ``ki``, one a block of
-    ``index_kpool`` tokens), and for its state layers leaves that are indexed
-    by SLOT, not through the page table: ``state`` [L_state, max_slots,
-    heads, hd, hd] float32 and, for kda layers, ``conv`` [L_kda, max_slots,
-    conv_kernel - 1, 3 x heads x hd], the short convolution's last rows;
-    for mla layers under pooled index keys ``ki_tail`` [L_mla, max_slots,
-    index_kpool - 1, index_dim], the keys of the block not yet whole."""
+    """The serving engine's paged arena, the pools of :func:`cache_layout`
+    as zeros: a pool of pages is ``[L, pages + 1, *row]`` (``k``/``v`` rows
+    are [page_size, KV, hd]) — the extra physical page at index ``pages`` is
+    the NULL page, where unmapped logical pages, a packed step's idle rows
+    and (in the slot layout) idle slots' padded chunk writes land (its bytes
+    are garbage by design and never attendable: every query masks at its
+    own frontier). A model with window layers keeps pages by layer kind:
+    ``k``/``v`` hold the full layers alone and ``k_win``/``v_win``
+    [L_window, window_pages + 1, ...] the window layers, whose pages a slot
+    gives back once every query still to come is past them. A model that
+    names its layers keeps pages for the layers whose kind keeps any
+    (``MIXER_KINDS``) and, for its state layers, leaves indexed by SLOT and
+    not through the page table, ``[L_kind, max_slots, *row]``: its family
+    module's ``init_pools`` builds them."""
+    layout = cache_layout(cfg)
+    if quantized:
+        layout.refuse("int8")
     if cfg.mixer_types:
-        from ..config import DeepSpeedConfigError
-        from .mixers import family
-
-        if quantized:
-            raise DeepSpeedConfigError(
-                "an int8 KV cache is refused: a sparse layer's compressed "
-                "keys are means of its cached keys, a latent layer's row "
-                "takes no per-head scale, and a state layer's state is "
-                "float32")
         if max_slots is None:
             raise ValueError("a model with state layers needs max_slots")
+        from .mixers import family
+
         return family(cfg).init_pools(cfg, num_pages, page_size, max_slots,
                                       dtype)
-    if cfg.is_latent:
-        if quantized:
-            from ..config import DeepSpeedConfigError
-
-            raise DeepSpeedConfigError(
-                "an int8 KV cache is refused: a latent cache (kv_latent_dim) "
-                "holds one normed latent a token for all heads, and no "
-                "per-head scale applies to it")
-        P1 = int(num_pages) + 1
-        pools = {LATENT: jnp.zeros(
-            (cfg.total_layers, P1, page_size, latent_row_width(cfg)), dtype)}
-        if cfg.index_topk:
-            pools[INDEX] = jnp.zeros(
-                (cfg.total_layers, P1, page_size, cfg.index_dim), dtype)
-        return pools
-
-    def pool(layers, pages, sfx=""):
-        P1 = int(pages) + 1
-        shape = (layers, P1, page_size, cfg.kv_heads, cfg.hd)
-        if quantized:
-            sshape = (layers, P1, cfg.kv_heads, page_size, SCALE_LANES)
-            return {
-                "k" + sfx: jnp.zeros(shape, jnp.int8),
-                "v" + sfx: jnp.zeros(shape, jnp.int8),
-                "k_scale" + sfx: jnp.zeros(sshape, jnp.float32),
-                "v_scale" + sfx: jnp.zeros(sshape, jnp.float32),
-            }
-        return {
-            "k" + sfx: jnp.zeros(shape, dtype),
-            "v" + sfx: jnp.zeros(shape, dtype),
-        }
-
-    if cfg.index_in_pages:
-        if quantized:
-            from ..config import DeepSpeedConfigError
-
-            raise DeepSpeedConfigError(
-                "an int8 KV cache is refused: the walk over an indexer's "
-                "selection (index_topk) reads K and V as they are computed")
-        # an index key a token beside its K and V, on their page table
-        return {**pool(cfg.total_layers, num_pages), INDEX: jnp.zeros(
-            (cfg.total_layers, int(num_pages) + 1, page_size,
-             index_row_width(cfg)), dtype)}
-    if not cfg.has_window:
-        return pool(cfg.total_layers, num_pages)
-    if window_pages is None:
+    if cfg.has_window and window_pages is None:
         raise ValueError("a model with window layers needs window_pages")
-    return {**pool(cfg.kind_count("full"), num_pages),
-            **pool(cfg.kind_count("window"), window_pages, WIN)}
+    entries = {"page": num_pages, "window": window_pages}
+    return {p.name: jnp.zeros(
+        (p.layers, int(entries[p.table]) + 1, *p.row), p.dtype)
+        for p in layout.pools(page_size, dtype, quantized)}
 
 
 def _page_indices(cache_len: jax.Array, S: int, page_table: jax.Array,
@@ -564,28 +717,7 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
     halves KV HBM for long-context serving (reference: kv-cache quant in
     the inference engine family). Dequant happens at read (in-kernel on the
     Pallas decode path)."""
-    if cfg.mixer_types:
-        from ..config import DeepSpeedConfigError
-
-        raise DeepSpeedConfigError(
-            "a contiguous KV arena is refused: a sparse or latent layer "
-            "reads its keys through the page table and a state layer keeps "
-            "its state a slot (mixer_types); all live in the paged arena "
-            "(serving.paged)")
-    if cfg.is_latent:
-        from ..config import DeepSpeedConfigError
-
-        raise DeepSpeedConfigError(
-            "a contiguous KV arena is refused: latent attention "
-            "(kv_latent_dim) attends its cached latents through the page "
-            "table (serving.paged)")
-    if cfg.index_in_pages:
-        from ..config import DeepSpeedConfigError
-
-        raise DeepSpeedConfigError(
-            "a contiguous KV arena is refused: an indexer (index_topk) "
-            "scores its cached keys and the walk reads the selection's K "
-            "and V through the page table (serving.paged)")
+    cache_layout(cfg).refuse("paged false")
     shape = (cfg.total_layers, batch, max_len, cfg.kv_heads, cfg.hd)
     if quantized:
         # scales live pre-transposed as [B, KV, Smax, SL]: the Pallas decode

@@ -60,13 +60,16 @@ from jax.sharding import (NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from ..comm.topology import MeshTopology, ParallelDims
+from ..config import DeepSpeedConfigError
 from ..inference.engine import (InferenceEngine, _align_cache,
                                 init_inference)
-from ..models.decoding import (SCALE_LANES, WIN, forward_with_cache,
-                               init_cache, init_paged_cache, paged_cow_copy,
+from ..models.decoding import (INDEX, SCALE_LANES, cache_layout,
+                               forward_with_cache, init_cache,
+                               init_paged_cache, paged_cow_copy,
                                record_attention_path, row_layout,
                                staged_promote, verify_window_rows)
 from ..models.sharding import use_topology
+from ..models.transformer import LAYER_KINDS
 from ..profiling import steptrace as _steptrace
 from ..profiling.steptrace import Phase
 from ..utils.logging import log_dist
@@ -77,46 +80,25 @@ from .scheduler import Scheduler, StepPlan
 from .spec import spec_verify_stream, verify_window
 
 
-def cache_partition_specs(quantized: bool, window_pool: bool = False
-                          ) -> Dict[str, P]:
-    """KV-arena specs: cache heads over tp (slots stay unsharded — the
-    scheduler owns placement); the per-layer leading dim is stacked.
-    ``window_pool`` adds the window layers' pool leaves, laid out alike."""
-    value = P(None, None, None, "tp", None)
-    specs = {"k": value, "v": value}
-    if quantized:
-        scale = P(None, None, "tp", None, None)
-        specs["k_scale"] = scale
-        specs["v_scale"] = scale
-    if window_pool:
-        specs.update({k + WIN: v for k, v in list(specs.items())})
-    return specs
+def _storage(itemsize: int):
+    """The float type a cache of ``itemsize`` bytes a value is kept in."""
+    return jnp.float32 if int(itemsize) == 4 else jnp.bfloat16
 
 
 def cache_token_bytes(cfg, storage_itemsize: int, quantized: bool) -> int:
     """Bytes one token keeps in one layer of the cache, scales left out:
     keys and values of every KV head (and, under an indexer, its index key
     as the pool pads it), or for latent attention its one latent row (as
-    the pool pads it) and its indexer key."""
-    if cfg.mixer_types and cfg.block_sparse is not None:
-        # a sparse layer's keys and values, and a page's one compressed key
-        # spread over its tokens (the state layers keep no token)
-        geom = cfg.block_sparse
-        return (2 * geom.kernel_stride + 1) * cfg.kv_heads * cfg.hd * (
-            storage_itemsize) // geom.kernel_stride
-    if cfg.kv_latent_dim:  # every layer latent, or a model's latent layers
-        from ..models.decoding import latent_row_width
-
-        # (an index key a token, or one a block of index_kpool tokens)
-        width = latent_row_width(cfg) + (
-            cfg.index_dim // cfg.index_kpool if cfg.index_topk else 0)
-        return width * storage_itemsize
-    if cfg.index_in_pages:  # K and V of every KV head, an index key's row
-        from ..models.decoding import index_row_width
-
-        return (2 * cfg.kv_heads * cfg.hd + index_row_width(cfg)
-                ) * storage_itemsize
-    return 2 * cfg.kv_heads * cfg.hd * (1 if quantized else storage_itemsize)
+    the pool pads it) and its indexer key. Derived from the page table's
+    pools (``cache_layout``): each pool's page over the tokens of a page,
+    in whole bytes a pool (so a sparse layer's one compressed key a page is
+    spread over the page's tokens, and an indexer's one key a block of
+    ``index_kpool`` over the block's); nothing for a model that keeps no
+    page."""
+    lay = cache_layout(cfg)
+    return sum(p.row_bytes // lay.unit_page for p in lay.pools(
+        lay.unit_page, _storage(storage_itemsize), quantized)
+        if p.table == "page" and not p.name.endswith("_scale"))
 
 
 def serving_kv_stream(cfg, max_slots: int, capacity: int,
@@ -249,13 +231,11 @@ def paged_geometry(cfg, serving, max_tokens: int,
     IS its slot plan (admission by slot and ``max_tokens``, nothing to run
     dry, a table of one column), whatever ``serving.page_size`` and
     ``serving.num_pages`` ask."""
-    if cfg.mixer_types and not cfg.paged_layers:
+    if not cfg.paged_layers:
         return int(max_tokens) + int(serving.token_budget), 1, int(max_slots)
     pages_per_slot = serving.pages_per_slot(max_tokens)
     num_pages = int(serving.num_pages) or max_slots * pages_per_slot
     if num_pages < pages_per_slot:
-        from ..config import DeepSpeedConfigError
-
         # liveness floor: after evicting everything else, ONE request must
         # still be able to run to max_tokens, or forced eviction can never
         # make progress
@@ -267,17 +247,13 @@ def paged_geometry(cfg, serving, max_tokens: int,
 
 
 def state_bytes(cfg, max_slots: int, storage_itemsize: int = 2) -> int:
-    """Bytes of the arena that are no page: every leaf the model's state
-    layers keep a slot (``models/mixers.slot_leaves``: a float32 state a
-    lightning or kda layer, and a kda layer's convolution rows in the
-    storage type); 0 for a model without state layers."""
-    if not getattr(cfg, "has_state", False):
-        return 0
-    from ..models.mixers import slot_leaves
-
-    dtype = jnp.float32 if int(storage_itemsize) == 4 else jnp.bfloat16
-    return sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
-               for leaf in slot_leaves(cfg, max_slots, dtype).values())
+    """Bytes of the arena that are no page: every pool the model's state
+    layers keep a slot (``cache_layout``: a float32 state a lightning or
+    kda layer, and a kda layer's convolution rows in the storage type); 0
+    for a model without state layers."""
+    lay = cache_layout(cfg)
+    return sum(p.layers * int(max_slots) * p.row_bytes for p in lay.pools(
+        lay.unit_page, _storage(storage_itemsize)) if p.table == "slot")
 
 
 def kv_spill_page_bytes(cfg, page_size: int, codec_name: str,
@@ -601,19 +577,19 @@ def make_paged_step_fn(cfg, dtype, vocab: int, cache_shardings=None,
     sharing/divergence mix runs the same compiled program — zero
     recompiles after warmup."""
     slot_step = make_step_fn(cfg, dtype, vocab, cache_shardings, max_draft)
+    layout = cache_layout(cfg)
+    # (a cache that keeps no prefix shares no page: nothing to copy)
+    shares_pages = layout.refused("prefix_cache") is None
 
     def step(params, caches, seen, tokens, num_new, start_pos, page_table,
              cow_src, *rest, page_table_win=None):
-        if (page_table_win is None and not cfg.mixer_types
-                and not cfg.index_in_pages):
-            # (a model with window layers, with slot state or with index
-            # keys beside its K and V shares no page: nothing to copy)
+        if shares_pages:
             caches = paged_cow_copy(caches, page_table, start_pos, cow_src)
         return slot_step(params, caches, seen, tokens, num_new, start_pos,
                          *rest, page_table=page_table,
                          page_table_win=page_table_win)
 
-    if cfg.has_window:
+    if len(layout.tables) > 1:
         # pages by layer kind: the window layers' table rides beside the
         # full layers'
         def kinds_step(params, caches, seen, tokens, num_new, start_pos,
@@ -926,138 +902,36 @@ class ServingEngine:
         else:
             self.page_size = self.num_pages = self.pages_per_slot = None
             self.capacity = _align_cache(self.max_tokens + W)
-        # ---- pages by layer kind: a model with window layers keeps a
-        # second pool for them, whose pages a slot gives back behind its
-        # window (docs/serving.md "Layer kinds") ------------------------
-        self.kinds_paged = self.paged and bool(mcfg.has_window)
+        # ---- what the model's cache is and what it admits
+        # (models/decoding.py cache_layout, docs/serving.md "Layer kinds"):
+        # each operation the configuration switches on is asked once
+        self.cache = cache_layout(mcfg)
+        self.host_pages = int(getattr(serving, "host_pages", 0) or 0) \
+            if self.paged else 0
+        for op, on in (("paged false", not self.paged),
+                       ("host_pages", self.host_pages),
+                       ("fleet.prefill_replicas", self.paged and int(
+                           serving.fleet.prefill_replicas)),
+                       ("spec", self.spec_enabled)):
+            if on:
+                self.cache.refuse(op)
+        prefix_cache = self.paged and bool(serving.prefix_cache)
+        why = prefix_cache and self.cache.refused("prefix_cache")
+        if why:
+            log_dist(f"serving: prefix cache off: {why}")
+            prefix_cache = False
         self.window_pages_per_slot = self.window_num_pages = None
-        prefix_cache = bool(serving.prefix_cache) if self.paged else False
         if self.kinds_paged:
-            from ..config import DeepSpeedConfigError
-
-            why = (
-                f"the model has window layers ({mcfg.attn_window} keys): a "
-                "page that is kept, spilled or handed over holds the full "
-                "layers' keys alone, and the window layers' last "
-                f"{mcfg.attn_window} keys would be missing"
-            )
-            if int(getattr(serving, "host_pages", 0) or 0) > 0:
-                raise DeepSpeedConfigError(
-                    f"serving.host_pages is refused: {why}")
-            if int(serving.fleet.prefill_replicas) > 0:
-                raise DeepSpeedConfigError(
-                    f"serving.fleet.prefill_replicas is refused: {why}")
-            if prefix_cache:
-                log_dist(f"serving: prefix cache off: {why}")
-                prefix_cache = False
             # a slot's window pages: the keys row 0 of a chunk still sees
             # up to the chunk's last key, and one page of misalignment;
             # the pool holds every slot's most, so it never runs dry
             self.window_pages_per_slot = (
                 -(-(mcfg.attn_window + W) // self.page_size) + 1)
             self.window_num_pages = N * self.window_pages_per_slot
-        self.latent = self.paged and bool(mcfg.is_latent)
-        if self.latent:
-            from ..config import DeepSpeedConfigError
-
-            why = (
-                "the model caches latents (kv_latent_dim): a spilled or "
-                "handed-over page is laid out as the k and v of KV heads, "
-                "which a latent pool and its indexer keys are not"
-            )
-            if int(getattr(serving, "host_pages", 0) or 0) > 0:
-                raise DeepSpeedConfigError(
-                    f"serving.host_pages is refused: {why}")
-            if int(serving.fleet.prefill_replicas) > 0:
-                raise DeepSpeedConfigError(
-                    f"serving.fleet.prefill_replicas is refused: {why}")
-        # ---- an indexer beside K / V pages (index_topk, no latent): the
-        # selection is held by the tests of the plain paged path alone ----
-        self.indexed = bool(getattr(mcfg, "index_in_pages", False))
-        if self.indexed:
-            from ..config import DeepSpeedConfigError
-
-            if not self.paged:
-                raise DeepSpeedConfigError(
-                    "serving.paged false is refused: an indexer "
-                    "(index_topk) scores its cached keys and the walk reads "
-                    "the selection's K and V through the page table")
-            why = (
-                "the model selects by an indexer (index_topk) whose keys lie "
-                "in a pool of their own beside K and V: a page that is "
-                "spilled or handed over is laid out as the k and v of KV "
-                "heads, and the index keys of the same tokens would be "
-                "missing")
-            if int(getattr(serving, "host_pages", 0) or 0) > 0:
-                raise DeepSpeedConfigError(
-                    f"serving.host_pages is refused: {why}")
-            if int(serving.fleet.prefill_replicas) > 0:
-                raise DeepSpeedConfigError(
-                    f"serving.fleet.prefill_replicas is refused: {why}")
-            if self.spec_enabled:
-                raise DeepSpeedConfigError(
-                    "serving.spec is refused: a draft row's selection "
-                    "(index_topk) is made over index keys of drafts that may "
-                    "be rejected, and no test holds the verify window under "
-                    "a selection")
-            if prefix_cache:
-                log_dist("serving: prefix cache off: a kept page would hold "
-                         "index keys (index_topk) beside K and V, and no "
-                         "test holds a selection over reused pages")
-                prefix_cache = False
-        # ---- state layers: a lightning or kda layer's cache is leaves a
-        # slot, begun at zero with its request and never shared; a page holds
-        # the paged layers' keys alone (docs/serving.md "Layer kinds") ------
-        self.slot_state = bool(getattr(mcfg, "has_state", False))
-        if mcfg.mixer_types:
-            from ..config import DeepSpeedConfigError
-
-            if not self.paged:
-                raise DeepSpeedConfigError(
-                    "serving.paged false is refused: a sparse or latent "
-                    "layer reads its keys through the page table and a state "
-                    "layer keeps its state a slot (mixer_types); all live in "
-                    "the paged arena")
-            from ..models.transformer import MIXER_KINDS
-
-            kinds = dict.fromkeys(mcfg.mixer_types)
-            self._state_kinds = ", ".join(
-                k for k in kinds if mcfg.slot_leaves_of(k))
-            self._paged_kinds = ", ".join(
-                k for k in kinds if MIXER_KINDS[k].page)
-            # what a page of this model holds (nothing, where no kind of
-            # its layers keeps a page)
-            self._page_holds = (
-                f"holds the paged layers' ({self._paged_kinds}) keys alone"
-                if self._paged_kinds else
-                "holds nothing (no layer of this model keeps a page)")
-            why = (
-                f"the model has state layers ({self._state_kinds}), whose "
-                "cache is a recurrent state a slot and no page: a page that "
-                f"is kept, spilled or handed over {self._page_holds}, and "
-                "the state that summed the same tokens would be missing")
-            if int(getattr(serving, "host_pages", 0) or 0) > 0:
-                raise DeepSpeedConfigError(
-                    f"serving.host_pages is refused: {why}")
-            if int(serving.fleet.prefill_replicas) > 0:
-                raise DeepSpeedConfigError(
-                    f"serving.fleet.prefill_replicas is refused: {why}")
-            if self.spec_enabled:
-                raise DeepSpeedConfigError(
-                    "serving.spec is refused: the model has state "
-                    f"layers ({self._state_kinds}), whose state sums every "
-                    "row it was fed; a "
-                    "rejected draft would need the state rolled back to the "
-                    "last accepted token, and the step keeps no such copy")
-            if prefix_cache:
-                log_dist(f"serving: prefix cache off: {why}")
-                prefix_cache = False
         # ---- tiered KV (serving.host_pages > 0, ISSUE 18): a pinned-
         # host second tier behind the HBM pool. The ENGINE owns the
         # store + spiller (movement needs device access: export/encode on
         # demotion, decode/stage on promotion); the SCHEDULER owns policy
-        self.host_pages = int(getattr(serving, "host_pages", 0) or 0) \
-            if self.paged else 0
         self.tiered = self.host_pages > 0
         self._host_store = self._spiller = None
 
@@ -1160,7 +1034,7 @@ class ServingEngine:
             spiller=self._spiller,
             window=mcfg.attn_window if self.kinds_paged else 0,
             window_num_pages=self.window_num_pages,
-            slot_state=self.slot_state,
+            slot_state=mcfg.has_state,
         )
 
         # ---- the KV arena (contiguous slots, or a paged pool): its shapes
@@ -1186,12 +1060,14 @@ class ServingEngine:
         self._cache_shardings = None
         if self.topology.world_size > 1:
             mesh = self.topology.mesh
-            specs = cache_partition_specs(
-                engine.kv_cache_quantized, self.kinds_paged)
-            if mcfg.mixer_types:  # a selection is a kv group's: whole leaves
-                specs = {k: P() for k in cache_shapes}
+            # (a contiguous arena's leaves are laid out as the page pools
+            # of their names: cache heads over tp, the slots unsharded, the
+            # scheduler owns placement)
+            specs = {p.name: p.spec for p in self.cache.pools(
+                self.page_size or 1, engine.kv_cache_storage_dtype,
+                engine.kv_cache_quantized)}
             self._cache_shardings = {
-                k: NamedSharding(mesh, spec) for k, spec in specs.items()}
+                k: NamedSharding(mesh, specs[k]) for k in cache_shapes}
             rep = NamedSharding(mesh, P())
         else:
             rep = SingleDeviceSharding(self.topology.devices[0])
@@ -1257,9 +1133,7 @@ class ServingEngine:
         self.metrics.state_bytes = state_bytes(
             mcfg, N, jnp.dtype(engine.kv_cache_storage_dtype).itemsize)
         self.metrics.hyper_streams = int(getattr(mcfg, "hc_mult", 0))
-        if self.indexed:
-            from ..models.decoding import INDEX
-
+        if "index keys" in self.cache.rows:  # beside K and V
             self.metrics.index_pool_bytes = int(
                 np.prod(cache_shapes[INDEX].shape)
                 * cache_shapes[INDEX].dtype.itemsize)
@@ -1311,10 +1185,9 @@ class ServingEngine:
 
         paged_avals = ()
         if self.paged:
-            paged_avals = (vec(jnp.int32, self.pages_per_slot),
-                           vec(jnp.int32))
-            if self.kinds_paged:
-                paged_avals = (paged_avals[0], *paged_avals)
+            paged_avals = (
+                *[vec(jnp.int32, self.pages_per_slot)] * len(self.cache.tables),
+                vec(jnp.int32))
             if self.tiered:
                 paged_avals += (jax.tree.map(sds, self._stage_zero_np),
                                 sds(self._stage_dst_null))
@@ -1414,6 +1287,12 @@ class ServingEngine:
             self.healthwatch.set_comm_estimate_from_streams(
                 self.analytic_streams()
             )
+
+    @property
+    def kinds_paged(self) -> bool:
+        """The arena keeps pages by layer kind: a second pool and page table
+        for the window layers (docs/serving.md "Layer kinds")."""
+        return self.paged and len(self.cache.tables) > 1
 
     # ---------------------------------------------------- parameter layouts
     def _compile_step(self, counting_step) -> None:
@@ -1661,15 +1540,14 @@ class ServingEngine:
                 # padded W-wide writes land in the NULL sink page
                 start_pos = plan.start_pos
                 tables = (plan.page_table, plan.page_table_win)[
-                    :1 + self.kinds_paged]
+                    :len(self.cache.tables)]
                 paged_args = (*tables, plan.cow_src)
                 if self.tiered:
                     paged_args += self._stage_args(plan)
-                # a one-kind model pays for the count only under the tracer
+                # a plain one-kind cache pays for the count only under the
+                # tracer
                 keys = (self._count_keys(plan)
-                        if self.kinds_paged or self.latent or self.indexed
-                        or self.config.mixer_types
-                        or self.tracer is not None else {})
+                        if self.cache.rows or self.tracer is not None else {})
                 if keys:
                     dispatch_sp.annotate(**keys)
             else:
@@ -1781,28 +1659,28 @@ class ServingEngine:
         from ..ops.pallas.paged_attention import key_counts
 
         out = {"rows": int(plan.num_new.sum())}
-        if self.latent:
-            return {**out, **self._count_selected(plan)}
-        if self.config.mixer_types:
-            return {**out, **self._count_mixers(plan)}
-        if self.indexed:  # the selection's counts in place of the full walk's
-            out.update(self._count_selected(plan))
-        for kind in ("full", "window")[:(not self.indexed) + self.kinds_paged]:
+        for what in self.cache.counted:
+            if what not in LAYER_KINDS:  # _count_selected, _mixers, _share
+                out.update(getattr(self, "_count_" + what)(plan))
+                continue
             attended, fetched = key_counts(
                 plan.start_pos, plan.num_new, self.page_size,
-                self.pages_per_slot, self.config.window_of(kind),
+                self.pages_per_slot, self.config.window_of(what),
                 block_k=self.page_size)
-            self.metrics.on_keys(kind, attended, fetched)
-            out["attended_" + kind], out["fetched_" + kind] = attended, fetched
-        if self.config.moe_routed_experts and (
-                self._experts_touched is not None):
-            # one member's share of an expert layer: the device's own count
-            # of the step folded last (as ``_count_mixers`` carries it)
-            out["experts_touched"] = self._experts_touched
-            out["experts_held"] = (self.config.num_experts
-                                   * self.config.num_layers)
-            out["held_assignments"] = self._held_assignments
+            self.metrics.on_keys(what, attended, fetched)
+            out["attended_" + what], out["fetched_" + what] = attended, fetched
         return out
+
+    def _count_share(self, plan: StepPlan) -> Dict[str, int]:
+        """One member's share of an expert layer: the device's own count
+        of the step folded last (as ``_count_mixers`` carries it)."""
+        if not self.config.moe_routed_experts or (
+                self._experts_touched is None):
+            return {}
+        return {"experts_touched": self._experts_touched,
+                "experts_held": (self.config.num_experts
+                                 * self.config.num_layers),
+                "held_assignments": self._held_assignments}
 
     def _count_selected(self, plan: StepPlan) -> Dict[str, int]:
         """The attention work of one layer under an indexer (a latent
@@ -1882,8 +1760,7 @@ class ServingEngine:
 
         mcfg = self.config
         leaves = slot_leaves(mcfg, self.max_slots,
-                             self.engine.kv_cache_storage_dtype
-                             ) if mcfg.mixer_types else {}
+                             self.engine.kv_cache_storage_dtype)
         kinds = self.attention_paths or (
             {"full": self.attention_path} if self.attention_path else {})
         kda_heads = 0  # the heads a program of the delta-rule call takes
@@ -1970,27 +1847,9 @@ class ServingEngine:
 
     # ------------------------------------------------- fleet KV handoff
     def _refuse_page_moves(self, what: str) -> None:
-        if self.slot_state:
-            raise RuntimeError(
-                f"{what}: a page {self._page_holds}; the state layers' "
-                f"({self._state_kinds}) state that summed the same tokens is a "
-                "slot's and no page, so a hand-off would serve a model with "
-                "state layers a context its state never saw"
-            )
-        if self.indexed:
-            raise RuntimeError(
-                f"{what}: a handed-over page is laid out as the k and v of "
-                "KV heads; the index keys (index_topk) of the same tokens "
-                "lie in a pool of their own, so a hand-off would serve a "
-                "selection over keys it does not hold"
-            )
-        if self.kinds_paged:
-            raise RuntimeError(
-                f"{what}: a page id names the full layers' pool alone; the "
-                "window layers' keys of the same tokens lie in their own "
-                "pool (or were given back), so a hand-off would serve a "
-                "model with window layers keys it does not hold"
-            )
+        why = self.cache.refused("fleet.prefill_replicas")
+        if why:
+            raise RuntimeError(f"{what}: {why}")
 
     def export_kv_pages(self, page_ids) -> Dict[str, Any]:
         """Snapshot the payload of physical ``page_ids`` out of this
@@ -2269,9 +2128,8 @@ def trace_serving_step(model, ds_config, topology: Optional[MeshTopology]
         cache_shape = init_cache(
             mcfg, N, capacity, storage, quantized=quantized
         )
-    cache_specs = cache_partition_specs(quantized)
-    if mcfg.mixer_types:  # a selection is a kv group's: whole leaves
-        cache_specs = {k: P() for k in cache_shape}
+    cache_specs = {p.name: p.spec for p in cache_layout(mcfg).pools(
+        page_size if paged else 1, storage, quantized)}
     caches = {
         k: sds(v.shape, v.dtype, cache_specs[k])
         for k, v in cache_shape.items()

@@ -366,7 +366,8 @@ def engine(model, params):
 def test_the_engine_serves_through_the_kernels_and_says_what_it_held(
         engine, params, shape):
     srv = engine
-    assert srv.attention_path == "paged_sparse_kernel" and srv.indexed
+    assert srv.attention_path == "paged_sparse_kernel"
+    assert "index keys" in srv.cache.rows
     assert srv.scheduler.prefix_cache is None  # off, with its reason logged
     assert set(srv._caches) == {"k", "v", INDEX}
     assert srv._caches[INDEX].shape == (3, srv.num_pages + 1, PS, 128)

@@ -259,7 +259,7 @@ def test_two_pools_and_latent_pools_replay_the_oracle(routed):
     want = _replay(make, "serial", prompts, news, sampling)
     _same(got, want)
     srv = got[0]
-    assert srv.kinds_paged or srv.latent
+    assert srv.kinds_paged or "latent rows" in srv.cache.rows
     assert srv.metrics.moe_steps == srv.metrics.steps
     if srv.kinds_paged:
         pool = srv.scheduler.window_pool
