@@ -47,7 +47,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .decode_attention import LANES, NEG_INF, _tile_update
+from .decode_attention import LANES, NEG_INF, _normalized, _tile_update
+from .flash_attention import _lanes_to
 from .paged_attention import (DEFAULT_BLOCK_K, SMEM_TABLE_BYTES,
                               VMEM_LIMIT_BYTES, _block_pages, _frontiers,
                               _head_tiles)
@@ -191,21 +192,20 @@ def _block_select_kernel(cl_ref, nn_ref, q_ref, kc_ref, sel_ref, m_scr,
             for r in range(reach, geom.planes):
                 s, ok = scores(i, r)
                 s = jnp.where(ok, s, NEG_INF)
-                m_prev = m_scr[:, :1]
+                m_prev = m_scr[...]  # [GR, LANES], lane-replicated
                 m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
                 m_safe = jnp.where(m_new <= NEG_INF, 0.0, m_new)
-                p = jnp.where(ok, jnp.exp(s - m_safe), 0.0)
-                l_scr[...] = jnp.broadcast_to(
-                    l_scr[:, :1] * jnp.exp(m_prev - m_safe)
-                    + jnp.sum(p, axis=1, keepdims=True), l_scr.shape)
-                m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+                p = jnp.where(ok, jnp.exp(s - _lanes_to(m_safe, trip)), 0.0)
+                l_scr[...] = (l_scr[...] * jnp.exp(m_prev - m_safe)
+                              + jnp.sum(p, axis=1, keepdims=True))
+                m_scr[...] = m_new
             return c
 
         lax.fori_loop(0, n_trips, sums, 0)
-        m_fin = m_scr[:, :1]
-        m_fin = jnp.where(m_fin <= NEG_INF, 0.0, m_fin)
-        l_fin = l_scr[:, :1]
-        inv = 1.0 / jnp.where(l_fin == 0.0, 1.0, l_fin)
+        m_fin = m_scr[...]
+        m_fin = _lanes_to(jnp.where(m_fin <= NEG_INF, 0.0, m_fin), trip)
+        l_fin = l_scr[...]
+        inv = _lanes_to(1.0 / jnp.where(l_fin == 0.0, 1.0, l_fin), trip)
         pos = cl + r0 + lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
         first_kept = jnp.maximum(pos + 1 - geom.window_size, 0) // B_
 
@@ -431,9 +431,8 @@ def _block_sparse_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, sel_ref,
         lax.fori_loop(0, n_blocks, block, 0)
 
         def finish(kv, c):
-            l = l_scr[kv, :, :1]
-            o_ref[0, kv] = (acc_scr[kv] / jnp.where(l == 0.0, 1.0, l)
-                            ).astype(o_ref.dtype)
+            o_ref[0, kv] = _normalized(
+                l_scr.at[kv], acc_scr.at[kv]).astype(o_ref.dtype)
             return c
 
         lax.fori_loop(0, KV, finish, 0)

@@ -11,6 +11,18 @@ of the work.
 Layouts: q [B, KV, G, hd] (G = H/KV query heads per cache head — the GQA
 group shares one cache tile), k/v cache [B, Smax, KV, hd] (the engine's
 storage layout; no transpose on the hot path). cache_len rides in SMEM.
+
+``_tile_update`` and ``_normalized`` are the online softmax of every
+cached-KV kernel: the two decode kernels here, paged_attention's chunk
+kernel and the three selected walks (sparse_paged_attention,
+sparse_latent_attention, block_sparse_attention). The running max and sum
+live in [rows, LANES] float32 scratches with every lane of a row equal and
+are read, updated and written back WHOLE; they meet the [rows, block]
+scores and the [rows, width] accumulator through flash_attention's
+``_lanes_to`` (whole vregs tiled where the width is a multiple of the
+lanes, a lane prefix where it is narrower). Kept as [rows, 1] columns they
+cost a lane broadcast at every use on every key tile (PERF.md, PR 33 for
+the flash forward and PR 61 for these kernels).
 """
 
 from __future__ import annotations
@@ -23,6 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .flash_attention import _lanes_to
+
 
 LANES = 128
 NEG_INF = -1e30
@@ -32,13 +46,21 @@ DEFAULT_BLOCK_S = 256
 def _tile_update(q, k, v, ks, vs, start, cl, scale, m_scr, l_scr, acc_scr,
                  lo=None, allowed=None):
     """One [block_s, hd] K/V tile's contribution to the fp32 online
-    softmax (shared by the dense and paged kernels): dequantize when
-    scales ride along, mask past the row's frontier, fold into the
-    running (max, sum, acc) scratches. ``cl`` is the frontier: a scalar,
-    a per-(row, key) array (paged_attention's chunk rows), or None for a
-    tile wholly below every row's frontier. ``lo`` (with ``cl``; a window
-    layer) is the last key position a row no longer sees. ``allowed`` (a
-    per-(row, key) bool array; a learned selection) masks by itself."""
+    softmax (shared by the dense and paged kernels and the sparse walks):
+    dequantize when scales ride along, mask past the row's frontier, fold
+    into the running (max, sum, acc) scratches. ``cl`` is the frontier: a
+    scalar, a per-(row, key) array (paged_attention's chunk rows), or None
+    for a tile wholly below every row's frontier. ``lo`` (with ``cl``; a
+    window layer) is the last key position a row no longer sees.
+    ``allowed`` (a per-(row, key) bool array; a learned selection) masks by
+    itself.
+
+    ``m_scr`` / ``l_scr`` are [rows, LANES], every lane of a row the same
+    value, and stay so from scratch to scratch: the running max, its guard
+    and the correction are computed on whole vregs (a row reduction
+    broadcasts into them once) and meet the [rows, block_s] scores and the
+    [rows, width] accumulator through ``_lanes_to``, never as a [rows, 1]
+    column that every use would broadcast across the lanes again."""
     if ks is not None:
         # int8 cache: dequantize the tile with its per-token scales
         k = (k.astype(jnp.float32) * ks[:, :1]).astype(q.dtype)
@@ -60,25 +82,28 @@ def _tile_update(q, k, v, ks, vs, start, cl, scale, m_scr, l_scr, acc_scr,
     if allowed is not None:
         s = jnp.where(allowed, s, NEG_INF)
 
-    m_prev = m_scr[:, :1]
+    m_prev = m_scr[...]  # [G, LANES], lane-replicated
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    # rows with no visible key yet keep m = NEG_INF: guard the exp
     m_safe = jnp.where(m_new <= NEG_INF, 0.0, m_new)
-    p = jnp.exp(s - m_safe)
+    p = jnp.exp(s - _lanes_to(m_safe, s.shape[1]))
     corr = jnp.exp(m_prev - m_safe)
-    l_scr[:] = jnp.broadcast_to(
-        l_scr[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True), l_scr.shape
+    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * _lanes_to(corr, acc_scr.shape[1]) + (
+        jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
     )
-    acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    m_scr[...] = m_new
 
 
-def _finalize_out(o_ref, l_scr, acc_scr):
-    l = l_scr[:, :1]
+def _normalized(l_scr, acc_scr):
+    """The accumulator over its running sum ([rows, LANES], lane-replicated;
+    a row that saw no key has sum 0 and stays 0), float32."""
+    l = l_scr[...]
     l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+    return acc_scr[...] / _lanes_to(l_safe, acc_scr.shape[1])
 
 
 def _decode_kernel(*refs, scale, block_s, has_scales=False):
@@ -114,7 +139,7 @@ def _decode_kernel(*refs, scale, block_s, has_scales=False):
 
     @pl.when(si == ns - 1)
     def _finalize():
-        _finalize_out(o_ref, l_scr, acc_scr)
+        o_ref[0, 0] = _normalized(l_scr, acc_scr).astype(o_ref.dtype)
 
 
 def _paged_decode_kernel(*refs, scale, page_size, has_scales=False):
@@ -156,7 +181,7 @@ def _paged_decode_kernel(*refs, scale, page_size, has_scales=False):
 
     @pl.when(si == ns - 1)
     def _finalize():
-        _finalize_out(o_ref, l_scr, acc_scr)
+        o_ref[0, 0] = _normalized(l_scr, acc_scr).astype(o_ref.dtype)
 
 
 def _pick_block(S: int, preferred: int) -> Optional[int]:

@@ -49,7 +49,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .decode_attention import LANES, NEG_INF, _tile_update
+from .decode_attention import LANES, NEG_INF, _normalized, _tile_update
 
 # keys a loop trip: 512 beat 256 at every length tried on the v5e (by 15 %
 # with 16 slots at 300 tokens, by 27 % with 16 at 8k; PERF.md, PR 28)
@@ -220,9 +220,8 @@ def _paged_attention_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, k_hbm,
                       functools.partial(block, masked=True), 0)
 
         def finish(kv, c):
-            l = l_scr[kv, :, :1]
-            l_safe = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0, kv] = (acc_scr[kv] / l_safe).astype(o_ref.dtype)
+            o_ref[0, kv] = _normalized(
+                l_scr.at[kv], acc_scr.at[kv]).astype(o_ref.dtype)
             return c
 
         lax.fori_loop(0, KV, finish, 0)

@@ -71,7 +71,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .decode_attention import LANES, NEG_INF, _tile_update
+from .decode_attention import LANES, NEG_INF, _normalized, _tile_update
 from .paged_attention import (SMEM_TABLE_BYTES, VMEM_LIMIT_BYTES,
                               _block_pages, _frontiers)
 
@@ -506,9 +506,7 @@ def _sparse_attention_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, *refs,
             return carry
 
         lax.fori_loop(0, n_blocks, block, 0)
-        l = l_scr[:, :1]
-        o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)
-                    ).astype(o_ref.dtype)
+        o_ref[0] = _normalized(l_scr, acc_scr).astype(o_ref.dtype)
 
 
 def sparse_attention(q_abs, kv_pool, scores, thr, tie, cache_len, page_table,

@@ -43,7 +43,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .decode_attention import LANES, NEG_INF, _tile_update
+from .decode_attention import LANES, NEG_INF, _normalized, _tile_update
 from .paged_attention import (SMEM_TABLE_BYTES, VMEM_BUDGET_BYTES,
                               VMEM_LIMIT_BYTES, _block_pages, _frontiers,
                               _head_tiles)
@@ -171,9 +171,8 @@ def _sparse_paged_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, thr_ref,
         lax.fori_loop(0, n_blocks, block, 0)
 
         def finish(kv, c):
-            l = l_scr[kv, :, :1]
-            o_ref[0, kv] = (acc_scr[kv] / jnp.where(l == 0.0, 1.0, l)
-                            ).astype(o_ref.dtype)
+            o_ref[0, kv] = _normalized(
+                l_scr.at[kv], acc_scr.at[kv]).astype(o_ref.dtype)
             return c
 
         lax.fori_loop(0, KV, finish, 0)

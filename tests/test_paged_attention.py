@@ -217,3 +217,134 @@ def test_kernel_reads_its_layer_of_the_stack(L, layer, name, window):
         n = int(num_new[b])
         np.testing.assert_allclose(out[b, :n], ref[b, :n], atol=1e-5,
                                    rtol=1e-5)
+
+
+# --------------------------- the widths the online softmax's statistics meet
+# decode_attention._tile_update keeps its running max and sum [rows, 128],
+# every lane the same, and meets the [rows, block_k] scores and the
+# [rows, hd] accumulator through flash_attention._lanes_to, which branches
+# on the static width: whole vregs tiled (a multiple of 128), a lane prefix
+# (narrower), a broadcast (wider and odd). Each caller against its float32
+# oracle at each branch, at the tolerances the cases above hold.
+WIDE_PS, WIDE_MP = 16, 80  # 1,280 tokens a slot: three blocks of 512
+
+
+def _wide_pools(r, B, KV, hd, L=1):
+    """B slots of WIDE_MP pages each, the table a permutation, NULL last."""
+    P = B * WIDE_MP
+    k_pool = jnp.asarray(r.randn(L, P + 1, WIDE_PS, KV, hd), jnp.float32)
+    v_pool = jnp.asarray(r.randn(L, P + 1, WIDE_PS, KV, hd), jnp.float32)
+    pt = jnp.asarray(r.permutation(P).reshape(B, WIDE_MP), jnp.int32)
+    return k_pool, v_pool, pt
+
+
+def _heads(H, KV, hd):
+    return types.SimpleNamespace(num_heads=H, kv_heads=KV, hd=hd,
+                                 pos_embedding="rope")
+
+
+def _dense(q, k_pool, v_pool, pt, cache_len, window=None):
+    return np.asarray(_dense_cached_attention(
+        _heads(q.shape[2], *k_pool.shape[3:]), q,
+        _paged_gather(k_pool[0], pt), _paged_gather(v_pool[0], pt),
+        cache_len, window=window))
+
+
+@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("block_k", [128, 192, 256, 512])
+def test_paged_attention_at_every_score_and_accumulator_width(block_k, hd):
+    """Score tiles of 128, 256 and 512 keys (whole vregs), of 192 (no
+    multiple of the lanes), accumulators of 64 lanes (a prefix) and 128: a
+    chunk deep in a context of three 512-key blocks, a prompt from empty, a
+    slot with ``num_new`` 0 and a decoding slot, in float32 to 1e-5."""
+    S, KV, G = 8, 2, 2
+    r = np.random.RandomState(block_k + hd)
+    k_pool, v_pool, pt = _wide_pools(r, 4, KV, hd)
+    q = jnp.asarray(r.randn(4, S, KV * G, hd), jnp.float32)
+    cache_len = jnp.asarray([1070, 0, 300, 517], jnp.int32)
+    num_new = jnp.asarray([S, S - 3, 0, 1], jnp.int32)
+    out = np.asarray(paged_attention_kernel(
+        q, k_pool, v_pool, cache_len, pt, layer=0, num_new=num_new,
+        block_k=block_k))
+    ref = _dense(q, k_pool, v_pool, pt, cache_len)
+    for b in range(4):
+        n = int(num_new[b])
+        np.testing.assert_allclose(out[b, :n], ref[b, :n], atol=1e-5,
+                                   rtol=1e-5)
+    assert np.isfinite(out).all() and not out[2].any()  # the idle slot
+
+
+@pytest.mark.parametrize("block_k,hd", [(128, 64), (256, 128)])
+def test_a_row_with_no_visible_key_in_its_first_tile(block_k, hd):
+    """A window layer's loop starts at the block of ROW 0's oldest visible
+    key. With 128 rows under a window of 64 at a frontier of 200, row 0
+    sees keys 137..200 and the last row 264..327: the first block the loop
+    reads (keys 128..255, or 0..255) holds no key of rows 73 and later, so
+    their running max is still NEG_INF after it (the guard that keeps
+    ``exp(NEG_INF - NEG_INF)`` out) and their first real tile comes
+    second."""
+    S, KV, G, window = 128, 2, 1, 64
+    r = np.random.RandomState(hd)
+    k_pool, v_pool, pt = _wide_pools(r, 2, KV, hd)
+    q = jnp.asarray(r.randn(2, S, KV * G, hd), jnp.float32)
+    cache_len = jnp.asarray([200, 0], jnp.int32)
+    out = np.asarray(paged_attention_kernel(
+        q, k_pool, v_pool, cache_len, pt, layer=0, block_k=block_k,
+        window=window))
+    ref = _dense(q, k_pool, v_pool, pt, cache_len, window=window)
+    np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
+
+
+def _quantized(r, shape):
+    from deepspeed_tpu.models.decoding import _quantize_kv
+
+    return _quantize_kv(jnp.asarray(r.randn(*shape), jnp.float32))
+
+
+@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("block_s", [128, 192, 256, 512])
+def test_dense_decode_int8_at_every_tile_width(block_s, hd):
+    """The dense decode kernel over an int8 cache with its per-token
+    scales, one [block_s, hd] tile a grid step: the same four score widths
+    and two accumulator widths against the dequantize-then-attend lines."""
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        decode_attention_kernel)
+
+    B, Smax, H, KV = 2, 1536, 4, 2
+    r = np.random.RandomState(block_s + hd)
+    q = jnp.asarray(r.randn(B, 1, H, hd), jnp.float32)
+    kq, ks = _quantized(r, (B, Smax, KV, hd))
+    vq, vs = _quantized(r, (B, Smax, KV, hd))
+    ks, vs = jnp.swapaxes(ks, 1, 2), jnp.swapaxes(vs, 1, 2)  # as stored
+    cache_len = jnp.asarray([5, 1100], jnp.int32)
+    out = decode_attention_kernel(q, kq, vq, cache_len, k_scale=ks,
+                                  v_scale=vs, block_s=block_s)
+    ref = _dense_cached_attention(_heads(H, KV, hd), q, kq, vq, cache_len,
+                                  ks, vs)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("hd", [64, 128])
+def test_paged_decode_int8_gathers_scales_through_the_table(hd):
+    """The paged decode kernel's tile is a page (16 keys: a lane prefix of
+    the statistics): int8 pools with scales, shuffled pages, a slot on its
+    first page and one on its last."""
+    from deepspeed_tpu.models.decoding import _paged_gather_scale
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        paged_decode_attention_kernel)
+
+    B, mp, ps, H, KV, P = 3, 6, 16, 4, 2, 18
+    r = np.random.RandomState(hd)
+    q = jnp.asarray(r.randn(B, 1, H, hd), jnp.float32)
+    kq, ks = _quantized(r, (P + 1, ps, KV, hd))
+    vq, vs = _quantized(r, (P + 1, ps, KV, hd))
+    ks, vs = jnp.swapaxes(ks, 1, 2), jnp.swapaxes(vs, 1, 2)  # [P+1,KV,ps,SL]
+    pt = jnp.asarray(r.permutation(P).reshape(B, mp), jnp.int32)
+    cache_len = jnp.asarray([3, 95, 40], jnp.int32)
+    out = paged_decode_attention_kernel(q, kq, vq, cache_len, pt,
+                                        k_scale=ks, v_scale=vs)
+    ref = _dense_cached_attention(
+        _heads(H, KV, hd), q, _paged_gather(kq, pt), _paged_gather(vq, pt),
+        cache_len,
+        _paged_gather_scale(ks, pt), _paged_gather_scale(vs, pt))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)

@@ -254,3 +254,142 @@ def test_traced_block_mask_falls_back_with_reason():
     reasons = [r for key in logging_mod.fallback_log_seen
                for r in key[1]]
     assert any("trace-time static" in r for r in reasons), reasons
+
+
+# ------------------- the serving walks that share decode_attention's helper
+# sparse_paged_attention, sparse_attention / latent_attention and
+# block_sparse_attention fold every key tile through
+# decode_attention._tile_update, whose running max and sum are [rows, 128]
+# lane-replicated and meet the scores and the accumulator through
+# flash_attention._lanes_to. Each walk against its own float32 dense lines
+# at the widths that helper branches on: a key block of 128, 256 or 512
+# (whole vregs) or 192 (no multiple of the lanes), set by the pages a slot
+# may hold; accumulators of 64, 128 and 512 lanes. The selection is given,
+# not searched: a key is chosen where its score is positive (``thr`` the
+# sort key of 0.0, no tie taken), so about half of every tile is masked and
+# some rows have no chosen key in their first tile.
+WALK_PS = 16
+WALK_PAGES = {128: 8, 192: 12, 256: 16, 512: 72}  # block_k -> pages a slot
+
+
+def _walk_operands(seed, mp, row, B=4, S=16, L=2):
+    """Pools ``[L, P+1, 16, *row]`` under a shuffled table, frontiers that
+    end inside a block, and the four kinds of slot: a chunk deep in its
+    context, a prompt from empty, an idle slot, a decoding one."""
+    from deepspeed_tpu.ops.pallas import sparse_latent_attention as sla
+
+    r = np.random.default_rng(seed)
+    f = lambda *s: jnp.asarray(r.normal(size=s), jnp.float32)
+    P, cap = B * mp, mp * WALK_PS
+    bk = WALK_PS * min(sla.BLOCK_K // WALK_PS, mp)
+    cl = np.asarray([cap - 3 * S, 0, cap // 2, cap - S - 5], np.int32)
+    nn = jnp.asarray([S, S - 7, 0, 1], jnp.int32)
+    scores = r.normal(size=(B, -(-cap // bk), S, bk))
+    # a row's own key is always chosen (a row that chose nothing is zeros
+    # in the walks and the mean of every value in the dense lines)
+    own = cl[:, None] + np.arange(S)[None]
+    scores[np.arange(B)[:, None], own // bk, np.arange(S)[None],
+           own % bk] = 1.0
+    scores, cl = jnp.asarray(scores, jnp.float32), jnp.asarray(cl)
+    zero_key = sla._sort_key(jnp.zeros((B, S), jnp.float32))
+    seen = jnp.arange(cap)[None, None] <= (
+        cl[:, None] + jnp.arange(S)[None])[..., None]
+    return dict(
+        k=f(L, P + 1, WALK_PS, *row), v=f(L, P + 1, WALK_PS, *row),
+        pt=jnp.asarray(r.permutation(P).reshape(B, mp), jnp.int32),
+        cl=cl, nn=nn, scores=scores, thr=zero_key,
+        tie=jnp.full((B, S), -1, jnp.int32), seen=seen,
+        chosen=(sla.unblocked(scores)[:, :, :cap] > 0) & seen)
+
+
+def _view(pool, layer, pt):
+    from deepspeed_tpu.models.decoding import _paged_gather
+
+    return _paged_gather(pool[layer], pt)
+
+
+def _real_rows_match(got, want, nn, tol):
+    """Real rows to ``tol``; padded rows finite, an idle slot's zeros."""
+    got, want = np.asarray(got, np.float32), np.asarray(want)
+    for b, n in enumerate(np.asarray(nn)):
+        np.testing.assert_allclose(got[b, :n], want[b, :n], atol=tol,
+                                   rtol=tol)
+    assert np.isfinite(got).all() and not got[nn == 0].any()
+
+
+@pytest.mark.parametrize("block_k", [128, 192, 256, 512])
+def test_sparse_paged_walk_at_every_key_block_width(block_k):
+    from deepspeed_tpu.ops.pallas import sparse_paged_attention as spa
+
+    H, KV, hd, layer = 4, 2, 128, 1
+    o = _walk_operands(block_k, WALK_PAGES[block_k], (KV, hd))
+    assert o["scores"].shape[-1] == block_k
+    q = jnp.asarray(np.random.default_rng(1).normal(size=(4, 16, H, hd)),
+                    jnp.float32)
+    got = spa.sparse_paged_attention_kernel(
+        q, o["k"], o["v"], o["scores"], o["thr"], o["tie"], o["cl"],
+        o["pt"], layer=layer, num_new=o["nn"], interpret=True)
+    want = spa.dense_sparse_paged_attention(
+        q, _view(o["k"], layer, o["pt"]), _view(o["v"], layer, o["pt"]),
+        o["chosen"])
+    _real_rows_match(got, want, o["nn"], 2e-5)
+
+
+@pytest.mark.parametrize("selected", [True, False],
+                         ids=["selected", "every-key"])
+@pytest.mark.parametrize("block_k,width,v_width", [
+    (128, 128, 64), (192, 128, 128), (256, 640, 512), (512, 640, 512)])
+def test_latent_walk_at_every_key_block_and_value_width(block_k, width,
+                                                        v_width, selected):
+    """The latent walks (a row is key and, in its first ``v_width`` lanes,
+    value): accumulators of 64 lanes (a prefix of the statistics' vreg),
+    128 and 512 (tiled), with a selection (``sparse_latent_attention``) and
+    over every key (``latent_attention``)."""
+    from deepspeed_tpu.ops.pallas import sparse_latent_attention as sla
+
+    H, layer, scale = 2, 1, 0.07
+    o = _walk_operands(block_k + v_width, WALK_PAGES[block_k], (width,))
+    q = jnp.asarray(np.random.default_rng(2).normal(size=(4, 16, H, width)),
+                    jnp.float32)
+    sel = (o["scores"], o["thr"], o["tie"]) if selected else (None,) * 3
+    got = sla.sparse_attention(
+        q, o["k"], *sel, o["cl"], o["pt"], layer=layer, scale=scale,
+        v_width=v_width, num_new=o["nn"], interpret=True)
+    want = sla.dense_sparse_attention(
+        q, _view(o["k"], layer, o["pt"]),
+        o["chosen"] if selected else o["seen"], scale, v_width)
+    _real_rows_match(got, want, o["nn"], 1e-5)
+
+
+@pytest.mark.parametrize("block_k", [128, 256, 512])
+def test_block_sparse_walk_at_every_key_block_width(block_k):
+    """The block walk under GIVEN flags (one in three blocks kept beside a
+    row's own, the first not forced: a row may keep nothing in its first
+    trip, and its running max is NEG_INF until a later one), a trip of 2, 4
+    or 8 selected blocks of 64 tokens."""
+    from deepspeed_tpu.ops.pallas import block_sparse_attention as bsa
+
+    H, KV, hd, layer, S = 4, 2, 128, 1, 16
+    mp = WALK_PAGES[block_k]
+    geom = bsa.BlockSparse(kernel_stride=WALK_PS)
+    o = _walk_operands(block_k, mp, (KV, hd))
+    assert WALK_PS * bsa._attention_trip(WALK_PS, mp, geom) == block_k
+    r = np.random.default_rng(3)
+    q = jnp.asarray(r.normal(size=(4, S, H, hd)), jnp.float32)
+    chunks = bsa._padded_blocks(geom, mp * WALK_PS) // 128
+    kept = r.random((4, KV, chunks, S, 128)) < 1 / 3
+    # a row's own block is kept (a row that kept nothing is zeros in the
+    # walk and the mean of every value in the dense lines)
+    own = (np.asarray(o["cl"])[:, None] + np.arange(S)[None]) // 64
+    kept[np.arange(4)[:, None], :, own // 128, np.arange(S)[None],
+         own % 128] = True
+    kept = jnp.asarray(kept, jnp.float32)
+    got = bsa.block_sparse_attention(
+        q, o["k"], o["v"], kept, o["cl"], o["pt"], layer=layer, geom=geom,
+        num_new=o["nn"], interpret=True)
+    positions = o["cl"][:, None] + jnp.arange(S)[None]
+    want = bsa.dense_block_attention(
+        q, _view(o["k"], layer, o["pt"]), _view(o["v"], layer, o["pt"]),
+        bsa.unchunked(kept)[..., :geom.blocks(mp * WALK_PS)], positions,
+        geom)
+    _real_rows_match(got, want, o["nn"], 2e-5)

@@ -270,6 +270,95 @@ def test_paged_attention_compiles_at_16_heads_a_kv_head(one_chip, S, window):
     assert "tpu_custom_call" in text, text[:2000]
 
 
+@pytest.mark.parametrize("window", [None, 1024], ids=["full", "window"])
+def test_paged_attention_compiles_at_mellums_shapes(one_chip, window):
+    """Mellum's two calls (32 query heads on 4 KV heads of 128, 8 slots of
+    16,640 tokens under [8, 128]): one program a slot, its [4, 128 x 8, 128]
+    query block whole, over the full layers' pool and the window layers'."""
+    B, S, H, KV, hd, ps = 8, 128, 32, 4, 128, 16
+    L, pages = (3, 8385) if window is None else (9, 585)
+    assert pa.row_tile(S, H // KV, KV, hd, ps, 32, 2, 2) == S
+
+    def step(q, k, v, cl, nn, pt, layer):
+        return pa.paged_attention_kernel(
+            q, k, v, cl, pt, layer=layer, num_new=nn, interpret=False,
+            window=window,
+            name="paged_attention_" + ("full" if window is None else "window"))
+
+    text = _compile(
+        step, one_chip,
+        ((B, S, H, hd), BF16), ((L, pages, ps, KV, hd), BF16),
+        ((L, pages, ps, KV, hd), BF16), ((B,), I32), ((B,), I32),
+        ((B, 16640 // ps + S // ps), I32), ((), I32),
+    )
+    assert "tpu_custom_call" in text, text[:2000]
+
+
+@pytest.mark.parametrize("cell", ["keye", "deepseek", "glm5", "minicpm"])
+def test_the_selected_walks_compile_at_their_cells_shapes(one_chip, cell):
+    """The three walks that fold their key tiles through
+    decode_attention._tile_update beside the paged calls, alone at their
+    cells' shapes: Keye-VL-2.0's selection inside paged K / V ([4, 128], 32
+    heads on 4), DeepSeek-V3.2's over latent rows (128 heads, a 640-lane row
+    whose first 512 are the value), GLM-5.3's by pooled blocks of 4 tokens
+    (64 heads on a 512-lane row) and MiniCPM-SALA's block walk with its
+    selection (32 heads on 2, 132,096 tokens a slot). The statistics meet
+    512-key score tiles and accumulators of 128 and 512 lanes whole."""
+    from deepspeed_tpu.ops.pallas import block_sparse_attention as bsa
+    from deepspeed_tpu.ops.pallas import sparse_latent_attention as sla
+    from deepspeed_tpu.ops.pallas import sparse_paged_attention as spa
+
+    S, ps = 128, 16
+    frontiers = lambda B, mp: (((B,), I32), ((B,), I32), ((B, mp), I32),
+                               ((), I32))
+    if cell == "keye":
+        B, H, KV, hd, mp = 4, 32, 4, 128, 66560 // ps + S // ps
+        nb = -(-mp * ps // 512)
+
+        def walk(q, k, v, scores, thr, tie, cl, nn, pt, layer):
+            return spa.sparse_paged_attention_kernel(
+                q, k, v, scores, thr, tie, cl, pt, layer=layer, num_new=nn,
+                interpret=False)
+
+        shapes = (((B, S, H, hd), BF16), ((12, 16641, ps, KV, hd), BF16),
+                  ((12, 16641, ps, KV, hd), BF16), ((B, nb, S, 512), F32),
+                  ((B, S), I32), ((B, S), I32), *frontiers(B, mp))
+        name = "sparse_paged_attention"
+    elif cell in ("deepseek", "glm5"):
+        B, H, W, kpool, tokens = ((4, 128, 640, 1, 66560) if cell == "deepseek"
+                                  else (8, 64, 512, 4, 67584))
+        mp = tokens // ps + S // ps
+        nb = -(-mp * ps // 512)
+
+        def walk(q, pool, scores, thr, tie, cl, nn, pt, layer):
+            return sla.sparse_attention(
+                q, pool, scores, thr, tie, cl, pt, layer=layer,
+                scale=192 ** -0.5, v_width=512, num_new=nn, interpret=False,
+                kpool=kpool)
+
+        shapes = (((B, S, H, W), BF16), ((4, B * mp + 1, ps, W), BF16),
+                  ((B, nb, S, 512 // kpool), F32), ((B, S), I32),
+                  ((B, S), I32), *frontiers(B, mp))
+        name = "sparse_latent_attention"
+    else:
+        B, H, KV, hd, mp = 4, 32, 2, 128, 132096 // ps + S // ps
+        geom = bsa.BlockSparse()
+        nbp = bsa._padded_blocks(geom, mp * ps)
+
+        def walk(q, k, v, planes, cl, nn, pt, layer):
+            kept = bsa.block_select(q, planes, cl, nn, geom, interpret=False)
+            return bsa.block_sparse_attention(
+                q, k, v, kept, cl, pt, layer=layer, geom=geom, num_new=nn,
+                interpret=False)
+
+        shapes = (((B, S, H, hd), BF16), ((3, 33057, ps, KV, hd), BF16),
+                  ((3, 33057, ps, KV, hd), BF16),
+                  ((B, KV, geom.planes, nbp, hd), BF16), *frontiers(B, mp))
+        name = "block_sparse_attention"
+    text = _compile(walk, one_chip, *shapes)
+    assert name in text and "tpu_custom_call" in text, text[:2000]
+
+
 # --------------------------------- a whole slot step beside its arena
 GIB = 2.0 ** 30
 # the slot steps' temporaries before the caches rode the layer scan as its
