@@ -17,6 +17,7 @@ from .ling import ling, ling_config  # noqa: F401
 from .brumby import brumby, brumby_config  # noqa: F401
 from .cohere import cohere, cohere_config  # noqa: F401
 from .keye import keye, keye_config  # noqa: F401
+from .qwen3_next import qwen3_next, qwen3_next_config  # noqa: F401
 
 MODEL_REGISTRY = {
     "gpt2": gpt2,
@@ -32,6 +33,7 @@ MODEL_REGISTRY = {
     "brumby": brumby,
     "cohere": cohere,
     "keye": keye,
+    "qwen3_next": qwen3_next,
 }
 
 

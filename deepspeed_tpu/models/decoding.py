@@ -765,6 +765,10 @@ def _qkv(cfg: TransformerConfig, p: Params, x: jax.Array, positions: jax.Array,
     # one shared gather ring under overlap_comm when the prefill sequence
     # divides the tp ring; decode (S=1) and packed weights fall back
     qp, kp, vp = tp_in_proj(x, (p["wq"], p["wk"], p["wv"]))
+    gate = None
+    if cfg.attn_out_gate:  # a head of W_q is [q | the gate of its output]
+        qp = qp.reshape(B, S, nh, 2 * hd)
+        qp, gate = qp[..., :hd], qp[..., hd:]
     q = qp.reshape(B, S, nh, hd)
     k = kp.reshape(B, S, nkv, hd)
     v = vp.reshape(B, S, nkv, hd)
@@ -775,8 +779,15 @@ def _qkv(cfg: TransformerConfig, p: Params, x: jax.Array, positions: jax.Array,
     if cfg.qk_norm:
         q, k = _qk_norm(cfg, p, q, k)
     if cfg.pos_embedding == "rope" and cfg.rope_of(kind) is not None:
-        q, k = _rope(q, k, positions, cfg.rope_of(kind))
-    return q, k, v
+        rd = cfg.rotary_dim
+        if rd:  # the leading rd values of a head, pairs inside them
+            qr, kr = _rope(q[..., :rd], k[..., :rd], positions,
+                           cfg.rope_of(kind))
+            q = jnp.concatenate([qr, q[..., rd:]], axis=-1)
+            k = jnp.concatenate([kr, k[..., rd:]], axis=-1)
+        else:
+            q, k = _rope(q, k, positions, cfg.rope_of(kind))
+    return q, k, v, gate
 
 
 def _indexer(cfg: TransformerConfig, ix: Params, x: jax.Array, q_src,
@@ -874,7 +885,7 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
     B, S = rows.B, rows.S
     nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.hd
     window = cfg.window_of(kind)
-    q, k, v = _qkv(cfg, p, x, rows.positions, kind)
+    q, k, v, gate = _qkv(cfg, p, x, rows.positions, kind)
 
     quantized = k_scale is not None
     paged = page_table is not None
@@ -922,6 +933,9 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
 
     def project(out):
         out = rows.pack(out.astype(x.dtype).reshape(B, S, nh * hd))
+        if gate is not None:  # a gate a channel, on the computed rows
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32)).reshape(out.shape)).astype(x.dtype)
         out = _out_proj(out, p["wo"])
         if cfg.use_bias:
             out = out + p["bo"]
@@ -984,7 +998,9 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
             out, why_dense = paged_attention(
                 q, k_cache, v_cache, cache_len, page_table, layer=layer,
                 num_new=num_new, window=window,
-                name="paged_attention_" + kind if cfg.has_window else None,
+                # (a model that keeps its kinds apart names the call by kind)
+                name="paged_attention_" + kind if (
+                    cfg.has_window or cfg.mixer_types) else None,
             )
         if out is not None:
             _note_attention_path("paged_kernel", kind=kind)

@@ -332,6 +332,18 @@ def short_conv(cfg, taps, pre, rows, prev, num_new):
     return out, carry
 
 
+def carried_conv(cfg, taps, pre, rows, conv, index, cache_len, num_new):
+    """:func:`short_conv` of ``pre`` over the layer's carried rows
+    ``conv[index]`` (zeros where a slot's request begins): (the convolved
+    rows float32, the stack ``conv`` with ``[index]`` advanced in place)."""
+    prev = lax.dynamic_index_in_dim(conv, index, 0, False)
+    fresh = (cache_len == 0) & (num_new > 0)
+    prev = jnp.where(fresh[:, None, None], jnp.zeros((), prev.dtype), prev)
+    y, carry = short_conv(cfg, taps, pre, rows, prev, num_new)
+    return y, lax.dynamic_update_index_in_dim(
+        conv, carry.astype(conv.dtype), index, 0)
+
+
 def kda_mixer(cfg, p, x, rows, pools, index, cache_len, num_new, note):
     """A kda layer's mixer over the normed rows ``x`` that ``rows``
     computes: (out, in x's layout, and the pools with ``state[index]`` and
@@ -346,13 +358,8 @@ def kda_mixer(cfg, p, x, rows, pools, index, cache_len, num_new, note):
     wide = H * hd
     cache_len = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (rows.B,))
     pre = jnp.concatenate([x @ p["wq"], x @ p["wk"], x @ p["wv"]], axis=-1)
-    conv = pools[CONV]
-    prev = lax.dynamic_index_in_dim(conv, index, 0, False)
-    fresh = (cache_len == 0) & (num_new > 0)
-    prev = jnp.where(fresh[:, None, None], jnp.zeros((), prev.dtype), prev)
-    y, carry = short_conv(cfg, p["conv"], pre, rows, prev, num_new)
-    conv = lax.dynamic_update_index_in_dim(
-        conv, carry.astype(conv.dtype), index, 0)
+    y, conv = carried_conv(cfg, p["conv"], pre, rows, pools[CONV], index,
+                           cache_len, num_new)
     y = jax.nn.silu(y)
     q, k, v = (y[..., i * wide:(i + 1) * wide].reshape(Bc, Sc, H, hd)
                for i in range(3))

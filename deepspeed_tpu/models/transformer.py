@@ -41,20 +41,26 @@ class MixerKind:
     models/decoding.py): the pool leaves it keeps a SLOT (indexed
     by slot, no page: begun at zero with a request, never shared), those it
     keeps a PAGE (through the page table), the module of ``models/`` that
-    owns its parameters and pools, and what it needs of the configuration
-    beside its name (a field that must be set, and why)."""
+    owns its parameters and pools, what it needs of the configuration
+    beside its name (a field that must be set, and why) and, for a kind of
+    models/decoding.py, the modules that give it a stack of its own."""
 
     slot: Tuple[str, ...]
     page: Tuple[str, ...]
     family: str
     needs: Tuple[str, str] = ("", "")
+    # a kind of models/decoding.py that ``mixer_types`` may name: the
+    # modules that give it a stack beside their own kinds
+    stacked_by: Tuple[str, ...] = ()
 
 
 MIXER_KINDS: Dict[str, MixerKind] = {
     # attention over every key of the K / V pool (int8 with its scales) or,
     # with an indexer (``index_topk``), over the best by its keys, which the
-    # page keeps beside K and V (``ki``)
-    "full": MixerKind((), ("k", "v", "k_scale", "v_scale", "ki"), "decoding"),
+    # page keeps beside K and V (``ki``); named by ``mixer_types`` it lies in
+    # the stack models/qwen3_next.py gives it beside its state layers
+    "full": MixerKind((), ("k", "v", "k_scale", "v_scale", "ki"), "decoding",
+                      stacked_by=("qwen3_next",)),
     # attention over the last ``attn_window`` keys; a paged cache keeps the
     # window layers a pool and a page table of their own (``k_win`` ...)
     "window": MixerKind((), ("k", "v", "k_scale", "v_scale"), "decoding"),
@@ -64,7 +70,8 @@ MIXER_KINDS: Dict[str, MixerKind] = {
     # it lies in the stack models/ling.py gives it and, with ``index_kpool``
     # > 1, keeps the unfinished block's index keys a slot
     "mla": MixerKind(("ki_tail",), ("kv", "ki"), "decoding",
-                     ("kv_latent_dim", "the width of its cached latent")),
+                     ("kv_latent_dim", "the width of its cached latent"),
+                     stacked_by=("ling",)),
     # grouped-query attention over a learned selection of blocks of pages
     "sparse": MixerKind((), ("k", "v", "kc"), "minicpm",
                         ("block_sparse", "the geometry of its selection")),
@@ -79,6 +86,11 @@ MIXER_KINDS: Dict[str, MixerKind] = {
     # power retention (degree 2) with a gate a kv head: a float32 state of
     # the symmetric square of the keys and its normaliser a slot
     "retention": MixerKind(("state", "norm"), (), "brumby"),
+    # the gated delta rule with ONE decay a value head, unbounded (Gated
+    # DeltaNet): a float32 state a value head and the short convolution's
+    # last rows a slot
+    "gdn": MixerKind(("state", "conv"), (), "qwen3_next",
+                     ("gdn_value_heads", "the heads of its state")),
 }
 
 # the kinds ``layer_pattern`` may name: those of models/decoding.py that
@@ -163,6 +175,12 @@ class TransformerConfig:
     # both read it, and ``h = h + mixer(n) + mlp(n)`` in one sum.
     parallel_block: bool = False
     qk_norm: bool = False  # RMSNorm over each head of q and k, before rotary
+    # ``rotary_dim`` > 0: the leading values of a head that are rotated
+    # (half-split pairs inside them; 0 = the whole head). ``attn_out_gate``:
+    # ``W_q`` is twice as wide, a head's second half the gate of its output,
+    # ``y = W_o (attn * sigmoid(gate))``, a gate a channel.
+    rotary_dim: int = 0
+    attn_out_gate: bool = False
     # Latent attention (``kv_latent_dim`` > 0): queries through a
     # ``q_latent_dim``-wide latent, every head's keys and values
     # up-projected from ONE ``kv_latent_dim``-wide latent a token, beside
@@ -207,7 +225,9 @@ class TransformerConfig:
     moe_groups: int = 1
     moe_groups_kept: int = 1
     moe_routed_scale: float = 1.0
-    moe_shared_width: int = 0    # a shared expert of this width, always on
+    # a shared expert of this width beside the routed ones: always on, or
+    # times ``sigmoid(x w_sg)`` where the tree carries ``shared_gate``
+    moe_shared_width: int = 0
     # One member's share of an expert-parallel layer: the router's width
     # (0 = ``num_experts``, all held) and the first expert of the
     # ``num_experts`` held here; the layer returns its partial sum.
@@ -241,7 +261,12 @@ class TransformerConfig:
     # and "latent" (latent attention over every key of the latent pool) of
     # models/ling.py; "retention" (power retention of degree 2: a state a kv
     # head and its normaliser a slot, the denominator plus ``retention_eps``)
-    # of models/brumby.py. Each kind has a parameter stack of its own; the
+    # of models/brumby.py; "gdn" (the gated delta rule with one unbounded
+    # decay a value head: ``gdn_value_heads`` value heads over
+    # ``gdn_key_heads`` key heads of ``gdn_head_dim``, state and convolution
+    # rows a slot) beside "full" (gated grouped-query attention over K / V
+    # pages) of models/qwen3_next.py. Each kind has a parameter stack of its
+    # own; the
     # MLP of a layer (dense lead | routed) is independent of its mixer.
     # ``mixer_layer_ids`` gives each layer its index in the published model
     # of ``mixer_depth`` layers (a cut keeps both: the decay of a lightning
@@ -258,6 +283,9 @@ class TransformerConfig:
     # and one output gate a head): the decay through ``kda_gate_rank``
     # values, and an output gate a CHANNEL through as many
     kda_gate_rank: int = 0
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_head_dim: int = 0
     retention_eps: float = 1e-6
     # Hyper-connections (``hc_mult`` > 1, manifold-constrained: models/
     # mixers.py ``hyper_pre`` / ``hyper_post``): that many residual streams,
@@ -361,10 +389,10 @@ class TransformerConfig:
         """``mixer_types`` against the table of kinds: what each kind keeps
         and needs decides what the configuration must bring."""
         # (models/decoding.py's kinds are declared by layer_pattern and
-        # kv_latent_dim: they have no stack a kind, but mla, which the
-        # module that owns the model's other kinds gives one)
+        # kv_latent_dim: they have no stack a kind, but where the module
+        # that owns the model's other kinds gives one, ``stacked_by``)
         named = sorted(k for k, kind in MIXER_KINDS.items()
-                       if kind.family != "decoding" or k == "mla")
+                       if kind.family != "decoding" or kind.stacked_by)
         unknown = sorted(set(self.mixer_types) - set(named))
         if unknown:
             raise ValueError(
@@ -373,12 +401,15 @@ class TransformerConfig:
         names = list(dict.fromkeys(self.mixer_types))
         families = sorted({MIXER_KINDS[n].family for n in names}
                           - {"decoding"})
-        if len(families) != 1 or ("mla" in names and families != ["ling"]):
+        if len(families) != 1 or any(
+                families[0] not in MIXER_KINDS[n].stacked_by for n in names
+                if MIXER_KINDS[n].family == "decoding"):
             raise ValueError(
                 f"mixer_types mixes kinds of models/{families}: the kinds of "
                 "one model share the module that owns their parameter "
-                "stacks and pools (mla, of models/decoding.py, has a stack "
-                "beside the kinds of models/ling.py alone)")
+                "stacks and pools (a kind of models/decoding.py has a stack "
+                "beside the kinds of the modules MIXER_KINDS names for it: "
+                "mla of models/ling.py, full of models/qwen3_next.py)")
         for name in names:
             field, why = MIXER_KINDS[name].needs
             if field and not getattr(self, field):
@@ -391,6 +422,13 @@ class TransformerConfig:
                 "mixer_types names every layer (leading dense ones first), "
                 "each with its published index under mixer_depth, and is "
                 "the only list of layer kinds (no layer_pattern)")
+        if "gdn" in names and (
+                not self.gdn_key_heads or not self.gdn_head_dim
+                or self.gdn_value_heads % self.gdn_key_heads):
+            raise ValueError(
+                "a gdn mixer's gdn_value_heads value heads read "
+                "gdn_key_heads key heads of gdn_head_dim, a whole number "
+                "each")
         if self.kda_lower_bound > 0 or self.conv_kernel < 2:
             raise ValueError(
                 "kda_lower_bound bounds a log-decay (at most 0) and a short "
@@ -1278,7 +1316,8 @@ def _refuse_uncached(cfg: TransformerConfig) -> None:
         raise DeepSpeedConfigError(
             "the uncached forward (training, evaluation, forward) runs no "
             "mixer of mixer_types: a state layer's recurrence (lightning, "
-            "kda, retention) lives in a slot's state, a sparse layer's block "
+            "kda, gdn, retention) lives in a slot's state, a sparse layer's "
+            "block "
             "selection is made from cached compressed keys and a latent "
             "layer attends the latent pool, all in the paged arena alone; "
             "serve this "

@@ -875,7 +875,12 @@ def moe_serving_mlp(cfg, p: Dict, x: jax.Array,
         from ..models.transformer import _mlp
 
         # the shared expert: a dense MLP every token takes, beside the routed
-        out = out + _mlp(cfg, p["shared"], x, None, False, dense=True)[0]
+        shared = _mlp(cfg, p["shared"], x, None, False, dense=True)[0]
+        if "shared_gate" in p:  # times sigmoid(x w_sg), one value a token
+            shared = (shared.astype(jnp.float32) * jax.nn.sigmoid(jnp.einsum(
+                "bsd,do->bso", x, p["shared_gate"],
+                preferred_element_type=jnp.float32))).astype(shared.dtype)
+        out = out + shared
 
     # routed_tokens stays derivable (tokens_per_expert.sum()) — the
     # metrics layer re-derives it, so the step ships no redundant scalar

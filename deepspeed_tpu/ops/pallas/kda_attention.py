@@ -246,6 +246,20 @@ def _kda_kernel(cl_ref, nn_ref, layer_ref, park_ref, parkj_ref, q_ref, k_ref,
         _each_head(hb, head)
 
 
+def parking(nn, tiles: int):
+    """Where a slot without a chunk (``nn <= 1``) parks a call's big blocks:
+    (slot, head tile) [B] each: on the last block the nearest slot with a
+    chunk before it fetched, else on the first the next one will, so the
+    pipeline finds the block it holds or needs next."""
+    B = nn.shape[0]
+    slot = jnp.arange(B, dtype=jnp.int32)
+    chunk = nn > 1
+    before = lax.cummax(jnp.where(chunk, slot, -1))
+    after = lax.cummin(jnp.where(chunk, slot, B), reverse=True)
+    park = jnp.where(before >= 0, before, jnp.where(after < B, after, 0))
+    return park, jnp.where(before >= 0, tiles - 1, 0)
+
+
 def kda_attention(q, k, v, g, beta, state, cache_len, num_new, *, layer,
                   scale: float, interpret: Optional[bool] = None):
     """q/k/v ``[B, S, H, hd]`` of one chunk a slot (q and k unit vectors a
@@ -268,15 +282,7 @@ def kda_attention(q, k, v, g, beta, state, cache_len, num_new, *, layer,
     hb = heads_per_program(H, hd, S, q.dtype.itemsize)
     tiles, R, wide = H // hb, min(S, ROW_TILE), hb * hd
     nn = jnp.asarray(num_new, jnp.int32)
-    # where a slot without a chunk parks the big blocks: on the last block
-    # the nearest slot with a chunk before it fetched, else on the first the
-    # next one will, so the pipeline finds the block it holds or needs next
-    slot = jnp.arange(B, dtype=jnp.int32)
-    chunk = nn > 1
-    before = lax.cummax(jnp.where(chunk, slot, -1))
-    after = lax.cummin(jnp.where(chunk, slot, B), reverse=True)
-    park = jnp.where(before >= 0, before, jnp.where(after < B, after, 0))
-    park_tile = jnp.where(before >= 0, tiles - 1, 0)
+    park, park_tile = parking(nn, tiles)
     # rows by slot with a head's values side by side: a head is a block of
     # lanes, no transpose
     flat = lambda a: a.reshape(B, S, H * hd)

@@ -777,10 +777,27 @@ def _selection_tiles(cl, nn, width: int, topk: int,
             "selection_tiles_grid": int(tiles.size)}
 
 
+def _page_counts(engine, cl, nn, kind: str = "full") -> Dict[str, int]:
+    """A layer of ``kind`` whose pages ``key_counts`` walks (a kind of
+    ``layer_pattern``, or a full layer that ``mixer_types`` names):
+    ``attended_<kind>`` the keys visible to every real query token,
+    ``fetched_<kind>`` the keys in the pages that hold one of them,
+    whatever block the kernel reads them in. Books them on the metrics."""
+    from ..ops.pallas.paged_attention import key_counts
+
+    attended, fetched = key_counts(
+        cl, nn, engine.page_size, engine.pages_per_slot,
+        engine.config.window_of(kind), block_k=engine.page_size)
+    engine.metrics.on_keys(kind, attended, fetched)
+    return {"attended_" + kind: attended, "fetched_" + kind: fetched}
+
+
 # what a step's plan says of one layer of each mixer kind ``mixer_types`` may
-# name (the kinds of ``models/transformer.MIXER_KINDS`` outside
-# models/decoding.py): the keys ride the ``serve/device_step`` annotation
+# name (``models/transformer.MIXER_KINDS``): the keys ride the
+# ``serve/device_step`` annotation
 _KIND_COUNTS = {
+    "full": _page_counts,
+    "gdn": _state_counts("gdn_"),
     "sparse": _sparse_counts,
     "lightning": _state_counts(""),
     "kda": _state_counts("kda_", by_path=True),
@@ -1156,7 +1173,8 @@ class ServingEngine:
             if mcfg.mixer_types:
                 # a path a mixer kind: "block_sparse_kernel" / "dense" for
                 # sparse layers, "lightning_kernel", "kda_kernel",
-                # "latent_kernel" and "retention_kernel" likewise;
+                # "latent_kernel", "gdn_kernel" and "retention_kernel"
+                # likewise, "paged_kernel" / "dense" for full layers;
                 # attention_path is the sparse
                 # layers' (the last layer kind's where there is none)
                 self.attention_paths = dict(rec["kinds"])
@@ -1165,7 +1183,8 @@ class ServingEngine:
                 self.attention_path in ("paged_kernel", "paged_sparse_kernel",
                                         "latent_sparse_kernel",
                                         "block_sparse_kernel", "kda_kernel",
-                                        "latent_kernel", "retention_kernel")
+                                        "gdn_kernel", "latent_kernel",
+                                        "retention_kernel")
             )
             self.metrics.attention_paged_kernel_kinds = {
                 kind: float(path in ("paged_kernel", "paged_sparse_kernel")
@@ -1652,23 +1671,15 @@ class ServingEngine:
 
     def _count_keys(self, plan: StepPlan) -> Dict[str, int]:
         """The step's attention work by layer kind, from the plan (one
-        layer of the kind: ``attended_<kind>`` the keys visible to every
-        real query token, ``fetched_<kind>`` the keys in the pages that hold
-        one of them, whatever block the kernel reads them in; ``rows`` the
-        real query tokens), booked on the metrics."""
-        from ..ops.pallas.paged_attention import key_counts
-
+        layer of the kind: :func:`_page_counts`; ``rows`` the real query
+        tokens), booked on the metrics."""
         out = {"rows": int(plan.num_new.sum())}
         for what in self.cache.counted:
             if what not in LAYER_KINDS:  # _count_selected, _mixers, _share
                 out.update(getattr(self, "_count_" + what)(plan))
-                continue
-            attended, fetched = key_counts(
-                plan.start_pos, plan.num_new, self.page_size,
-                self.pages_per_slot, self.config.window_of(what),
-                block_k=self.page_size)
-            self.metrics.on_keys(what, attended, fetched)
-            out["attended_" + what], out["fetched_" + what] = attended, fetched
+            else:
+                out.update(_page_counts(self, plan.start_pos, plan.num_new,
+                                        what))
         return out
 
     def _count_share(self, plan: StepPlan) -> Dict[str, int]:

@@ -161,8 +161,13 @@ def test_a_pageless_engine_serves_what_the_reference_predicts(model, params,
     cl, nn = np.array([0, 40, 7]), np.array([5, 1, 0])
     assert _KIND_COUNTS["retention"](srv, cl, nn) == {
         "retention_rows": 6, "retention_state_slots": 2, "state_resets": 1}
-    assert set(_KIND_COUNTS) == {"sparse", "lightning", "kda", "latent",
-                                 "mla", "retention"}
+    # every kind ``mixer_types`` may name has its counter, and no other
+    from deepspeed_tpu.models.transformer import MIXER_KINDS
+
+    assert set(_KIND_COUNTS) == {
+        k for k, kind in MIXER_KINDS.items()
+        if kind.family != "decoding" or kind.stacked_by} >= {
+        "sparse", "lightning", "kda", "latent", "mla", "retention"}
 
 
 def test_the_kernel_path_is_taken_and_named(model, params):

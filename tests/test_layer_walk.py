@@ -22,7 +22,7 @@ import deepspeed_tpu.serving.engine as engine_mod
 from deepspeed_tpu.comm.topology import MeshTopology, ParallelDims
 from deepspeed_tpu.config import DeepSpeedConfig, DeepSpeedConfigError
 from deepspeed_tpu.models import (brumby, cohere, deepseek, glm5, keye, ling,
-                                  mellum, minicpm, mixtral)
+                                  mellum, minicpm, mixtral, qwen3_next)
 from deepspeed_tpu.models.decoding import (CACHE_ADMITS, WIN, cache_layout,
                                            forward_with_cache, init_cache,
                                            init_paged_cache)
@@ -64,8 +64,13 @@ FAMILIES = {
                [("layers", 1, (("window", R),) * 3 + (("full", R),))]),
     "keye": (lambda: keye("keye-tiny", num_experts=2, moe_routed_experts=8),
              [("layers", 3, (("full", R),))]),
+    "qwen3_next": (lambda: qwen3_next("qwen3next-tiny", num_experts=2,
+                                      moe_routed_experts=8),
+                   [("gdn_layers", 3, (("gdn", R),)),
+                    ("attn_layers", 1, (("full", R),))] * 2),
 }
-ROUTED = ("mixtral", "mellum", "deepseek", "ling", "glm5", "cohere", "keye")
+ROUTED = ("mixtral", "mellum", "deepseek", "ling", "glm5", "cohere", "keye",
+          "qwen3_next")
 
 
 @pytest.fixture(scope="module", params=list(FAMILIES))
@@ -212,6 +217,7 @@ REFUSED = {
     "deepseek": ("paged false", "int8", "host_pages",
                  "fleet.prefill_replicas"),
     "keye": OPS, "minicpm_sala": OPS, "ling": OPS, "brumby": OPS, "glm5": OPS,
+    "qwen3_next": OPS,
 }
 ASKED = {"paged false": dict(paged=False), "host_pages": dict(host_pages=8),
          "fleet.prefill_replicas": dict(fleet=dict(prefill_replicas=1)),

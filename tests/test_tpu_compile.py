@@ -294,6 +294,32 @@ def test_paged_attention_compiles_at_mellums_shapes(one_chip, window):
     assert "tpu_custom_call" in text, text[:2000]
 
 
+@pytest.mark.parametrize("S", [128, 256, 512])
+def test_paged_attention_compiles_at_256_wide_heads(one_chip, S):
+    """Qwen3-Next's calls (16 query heads on 2 KV heads of 256, 4 slots of
+    262,144 tokens in pages of 64 whose row is [2 KV, 256]): the first pool
+    with 256 lanes a head. The whole [2, S x 8, 256] query block with its
+    float32 accumulators passes the VMEM budget up to the cell's 256 rows
+    (one program a slot); at 512 the grid takes a program a slot and row
+    tile of 32 rows. The chip's compiler takes each."""
+    B, H, KV, hd, ps, per_slot = 4, 16, 2, 256, 64, 4096
+    rows = pa.row_tile(S, H // KV, KV, hd, ps, 8, 2, 2)
+    assert rows == (S if S <= 256 else 32)
+
+    def step(q, k, v, cl, nn, pt, layer):
+        return pa.paged_attention_kernel(
+            q, k, v, cl, pt, layer=layer, num_new=nn, interpret=False,
+            name="paged_attention_full")
+
+    text = _compile(
+        step, one_chip,
+        ((B, S, H, hd), BF16), ((3, 2049, ps, KV, hd), BF16),
+        ((3, 2049, ps, KV, hd), BF16), ((B,), I32), ((B,), I32),
+        ((B, per_slot), I32), ((), I32),
+    )
+    assert "tpu_custom_call" in text and "paged_attention_full" in text
+
+
 @pytest.mark.parametrize("cell", ["keye", "deepseek", "glm5", "minicpm"])
 def test_the_selected_walks_compile_at_their_cells_shapes(one_chip, cell):
     """The three walks that fold their key tiles through
@@ -848,6 +874,61 @@ def test_keye_slot_step_keeps_the_three_pools_in_place(one_chip, monkeypatch,
                  "sparse_paged_attention"):
         assert call in text, call
     assert "paged_attention_full" not in text
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.0 * GIB
+
+
+def test_qwen3_next_slot_step_keeps_pools_and_both_state_leaves_in_place(
+        one_chip, monkeypatch, capsys):
+    """The one [4, 256] serving step of Qwen3-Next-80B-A3B at its published
+    widths and the benchmark's cut (published layers 0-11, 64 of 512
+    experts, an eighth of the vocabulary) over its arena of 16,384 pages of
+    64 tokens: the three gated-attention layers' K and V pools ([2 KV, 256]
+    a row) ride the layer scans beside the nine Gated DeltaNet layers' two
+    slot leaves, the float32 state aliased into the ``gated_delta_attention``
+    call, so the compiled step holds no copy of a pool's, a leaf's or a
+    layer's size; both calls are in it by name; and it fits the described
+    chip beside 5.86 GB of weights."""
+    from deepspeed_tpu.models import qwen3_next
+    from deepspeed_tpu.models.decoding import init_paged_cache
+    from deepspeed_tpu.ops.pallas import gated_delta as gd
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = qwen3_next("qwen3-next-80b-a3b", layer_ids=list(range(12)),
+                       num_experts=64, moe_routed_experts=512,
+                       vocab_size=18992)
+    cfg = model.config
+    assert cfg.kind_count("gdn") == 9 and cfg.kind_count("full") == 3
+    N, W, ps, cap = 4, 256, 64, 262144
+    caches = jax.eval_shape(
+        lambda: init_paged_cache(cfg, 16384, ps, BF16, max_slots=N))
+    assert {k: (v.shape[2:], v.dtype) for k, v in caches.items()} == {
+        "k": ((ps, 2, 256), BF16), "v": ((ps, 2, 256), BF16),
+        "state": ((32, 128, 128), F32), "conv": ((3, 8192), BF16)}
+    compiled = _compile_slot_step(model, caches, one_chip, N, W, cap // ps)
+    m = compiled.memory_analysis()
+    pools = sum(a.size * a.dtype.itemsize for a in caches.values())
+    with capsys.disabled():
+        print(f"\nqwen3-next slot step, described v5e: "
+              f"{model.num_params():,} parameters, arguments "
+              f"{m.argument_size_in_bytes / GIB:.2f} GiB, temporaries "
+              f"{m.temp_size_in_bytes / GIB:.2f} GiB, aliased "
+              f"{m.alias_size_in_bytes / GIB:.2f} (the pools and leaves "
+              f"{pools / GIB:.2f})")
+    text = compiled.as_text()
+    # (5 rows an expert at a full step: the banks go through the einsum)
+    for call in ("gated_delta_attention", "paged_attention_full"):
+        assert call in text, call
+    assert "expert_bank" not in text
+    # 8 of the 16 key heads a program, with their 16 value heads
+    assert gd.key_heads_per_program(16, 2, 128, 128, W, 2) == 8
+    # (the convolution rows are 0.2 MB a layer: a layer's block is read and
+    # written by plain slices of the scan's carry, the layer's own work)
+    assert _pool_copies(
+        text, {k: v for k, v in caches.items() if k != "conv"}) == []
+    assert m.alias_size_in_bytes >= pools
+    _check_pool_writes_take_the_budget(
+        compiled, caches, ("k", "v"), N, W, "qwen3-next", capsys)
+    _check_weights_are_read_as_held(compiled, model, "qwen3-next", capsys)
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.0 * GIB
 
 
