@@ -14,7 +14,15 @@ is over the VMEM budget (16 query heads a KV head at a 256-row chunk), one
 per slot and ROW TILE (grid ``(B, S / rows)``, :func:`row_tile`): a tile is
 a chunk of its own whose frontier is the slot's plus the rows before it, so
 a tile past the slot's real rows is idle and a decoding slot computes one
-tile, not the chunk. The pools stay
+tile, not the chunk. Where the grid is one program a slot, the program
+reads the slot's real rows and runs the same block loops over the first
+``SMALL_ROWS`` rows of the stack alone when they hold every real row (row
+``r`` of the stack is query ``r // G``, so a slot's real rows are its first:
+a decoding slot's ``G`` rows, a verify window's), the whole stack otherwise;
+the rows past the small tile are padding and read zeros. ``SMALL_ROWS`` is
+one constant, taken from the call alone on the v5e at three cells' shapes
+(16 and 32 rows cost the same, 64 more; PERF.md section 6, PR 63);
+:func:`small_tile_slots` is the predicate over host vectors. The pools stay
 in HBM; a loop whose trip count is read from the slot's frontier fetches
 ``pages_per_block`` pages a trip (whole pages, in the pool's own
 ``[page_size, KV, hd]`` layout: all KV heads of a page are contiguous and
@@ -69,6 +77,11 @@ SMEM_TABLE_BYTES = 512 * 1024
 # 1,024 / 512 / 256 served 7,679 / 7,597 / 7,811 tokens/s and the two calls
 # took 15.4 / 14.5 / 10.7 ms of a traced step
 ROW_TILE_ROWS = 256
+# rows of the [S * G, hd] stack a program of the one-program-a-slot grid
+# takes where the slot's real rows fit them (a decoding slot's G rows, a
+# verify window's): a multiple of a bf16 sublane tile, chosen once from the
+# call alone on the v5e at three cells' shapes (PERF.md section 6, PR 63)
+SMALL_ROWS = 32
 
 
 def _head_tiles(buf, KV: int):
@@ -148,8 +161,14 @@ def _paged_attention_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, k_hbm,
         # invariant in models/decoding._cached_attention); keep them finite
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(nn > 0)
-    def _attend():
+    def attend(rows):
+        """The slot's three block loops over the first ``rows`` (static)
+        rows of its stack: all ``SG`` of them, or the small tile that holds
+        every real row (the rows past it are padding and read zeros, as an
+        idle slot's do)."""
+        # this body's rows of a head's [SG, ...] block (the whole stack
+        # takes its refs unsliced, as it always did)
+        rs = () if rows == SG else (slice(rows),)
         # keys needed: 0 .. cl + nn - 1 (rows past nn are padding and may
         # see less than their frontier)
         n_blocks = jnp.minimum(pl.cdiv(cl + nn, bk), pl.cdiv(mp * ps, bk))
@@ -167,9 +186,10 @@ def _paged_attention_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, k_hbm,
                 n_blocks)
             n_full = jnp.clip(n_full, lo_end, n_blocks)
 
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        m_scr[(slice(None), *rs)] = jnp.full(
+            (KV, rows, LANES), NEG_INF, m_scr.dtype)
+        l_scr[(slice(None), *rs)] = jnp.zeros((KV, rows, LANES), l_scr.dtype)
+        acc_scr[(slice(None), *rs)] = jnp.zeros((KV, rows, hd), acc_scr.dtype)
         start_fetch(first, 0 if window is None else lax.rem(first, 2))
 
         def block(i, carry, *, masked):
@@ -197,12 +217,12 @@ def _paged_attention_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, k_hbm,
                 if masked:
                     # row r of the [S * G, hd] stack is query (r // G)
                     frontier = cl + lax.broadcasted_iota(
-                        jnp.int32, (SG, bk), 0
+                        jnp.int32, (rows, bk), 0
                     ) // group
                 _tile_update(
-                    q_ref[0, kv], kh_scr[kv], vh_scr[kv], None, None, start,
-                    frontier, scale,
-                    m_scr.at[kv], l_scr.at[kv], acc_scr.at[kv],
+                    q_ref[(0, kv, *rs)], kh_scr[kv], vh_scr[kv], None, None,
+                    start, frontier, scale, m_scr.at[(kv, *rs)],
+                    l_scr.at[(kv, *rs)], acc_scr.at[(kv, *rs)],
                     lo=frontier - window
                     if masked and window is not None else None,
                 )
@@ -219,12 +239,26 @@ def _paged_attention_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, k_hbm,
         lax.fori_loop(n_full, n_blocks,
                       functools.partial(block, masked=True), 0)
 
+        if rs:
+            o_ref[...] = jnp.zeros_like(o_ref)
+
         def finish(kv, c):
-            o_ref[0, kv] = _normalized(
-                l_scr.at[kv], acc_scr.at[kv]).astype(o_ref.dtype)
+            o_ref[(0, kv, *rs)] = _normalized(
+                l_scr.at[(kv, *rs)], acc_scr.at[(kv, *rs)]
+            ).astype(o_ref.dtype)
             return c
 
         lax.fori_loop(0, KV, finish, 0)
+
+    if tiled or SG <= SMALL_ROWS:
+        pl.when(nn > 0)(functools.partial(attend, SG))
+    else:
+        # one program a slot: a slot whose real rows (its first nn * group
+        # stacked rows) fit the small tile computes that tile alone
+        # (a decoding slot, a verify window, a cached prompt's last token)
+        small = nn * group <= SMALL_ROWS
+        pl.when((nn > 0) & small)(functools.partial(attend, SMALL_ROWS))
+        pl.when(jnp.logical_not(small))(functools.partial(attend, SG))
 
 
 def _block_pages(block_k: int, page_size: int, max_pages: int) -> int:
@@ -283,6 +317,19 @@ def key_counts(cache_len, num_new, page_size: int, max_pages: int,
     n_blocks = np.minimum(-(-(cl + nn) // bk), -(-(max_pages * page_size) // bk))
     fetched = np.where(nn > 0, (n_blocks - first) * bk, 0)
     return int(attended.sum()), int(fetched.sum())
+
+
+def small_tile_slots(num_new, G: int, S: int,
+                     rows: Optional[int] = None) -> int:
+    """How many slots of host vector ``num_new`` [B] take the kernel's small
+    tile, by the kernel's own predicate: ``0 < nn * G <= SMALL_ROWS`` where
+    the grid is one program a slot whose ``[S * G, hd]`` stack is larger.
+    ``rows`` is the call's :func:`row_tile` (None: the whole chunk); a
+    row-tiled grid has no small tile and reads 0."""
+    if (rows is not None and rows < S) or S * G <= SMALL_ROWS:
+        return 0
+    nn = np.asarray(num_new, np.int64)
+    return int(((nn > 0) & (nn * G <= SMALL_ROWS)).sum())
 
 
 def _frontiers(B: int, S: int, cache_len, num_new):

@@ -782,14 +782,27 @@ def _page_counts(engine, cl, nn, kind: str = "full") -> Dict[str, int]:
     ``layer_pattern``, or a full layer that ``mixer_types`` names):
     ``attended_<kind>`` the keys visible to every real query token,
     ``fetched_<kind>`` the keys in the pages that hold one of them,
-    whatever block the kernel reads them in. Books them on the metrics."""
-    from ..ops.pallas.paged_attention import key_counts
+    whatever block the kernel reads them in; ``small_tile_slots_<kind>``
+    the slots whose program of the paged call computes the small tile of
+    its query stack alone (``small_tile_slots``: 0 where the grid is row
+    tiled, or the kind's path is not the kernel). Books them on the
+    metrics."""
+    from ..ops.pallas import paged_attention as pa
 
-    attended, fetched = key_counts(
-        cl, nn, engine.page_size, engine.pages_per_slot,
-        engine.config.window_of(kind), block_k=engine.page_size)
-    engine.metrics.on_keys(kind, attended, fetched)
-    return {"attended_" + kind: attended, "fetched_" + kind: fetched}
+    cfg, ps, mp = engine.config, engine.page_size, engine.pages_per_slot
+    attended, fetched = pa.key_counts(
+        cl, nn, ps, mp, cfg.window_of(kind), block_k=ps)
+    small = 0
+    if engine.metrics.attention_paged_kernel_kinds.get(kind):
+        G = cfg.num_heads // cfg.kv_heads
+        small = pa.small_tile_slots(nn, G, engine.token_budget, pa.row_tile(
+            engine.token_budget, G, cfg.kv_heads // engine.topology.tp_size,
+            cfg.hd, ps, pa._block_pages(pa.DEFAULT_BLOCK_K, ps, mp),
+            jnp.dtype(engine.dtype).itemsize,
+            jnp.dtype(engine.engine.kv_cache_storage_dtype).itemsize))
+    engine.metrics.on_keys(kind, attended, fetched, small)
+    return {"attended_" + kind: attended, "fetched_" + kind: fetched,
+            "small_tile_slots_" + kind: small}
 
 
 # what a step's plan says of one layer of each mixer kind ``mixer_types`` may

@@ -193,6 +193,10 @@ class ServingMetrics:
         # in the blocks the paged kernel's loops read for them
         self.attended_keys: Dict[str, int] = defaultdict(int)
         self.fetched_keys: Dict[str, int] = defaultdict(int)
+        # per kind whose pages the paged call walks: the slots whose program
+        # computed the small tile of its query stack alone, summed over steps
+        # (ops/pallas/paged_attention.py small_tile_slots)
+        self.small_tile_slots: Dict[str, int] = defaultdict(int)
         self.attention_paged_kernel_kinds: Dict[str, float] = {}
         # a model with state layers: the arena's bytes that are no page
         # (gauge, set once an engine) and the states that began at zero (a
@@ -430,10 +434,13 @@ class ServingMetrics:
         self.filter_steps += bool(filtered)
         self.overlapped_steps += bool(overlapped)
 
-    def on_keys(self, kind: str, attended: int, fetched: int) -> None:
+    def on_keys(self, kind: str, attended: int, fetched: int,
+                small_tile_slots: Optional[int] = None) -> None:
         """One step's attention work in one layer of ``kind``."""
         self.attended_keys[kind] += int(attended)
         self.fetched_keys[kind] += int(fetched)
+        if small_tile_slots is not None:
+            self.small_tile_slots[kind] += int(small_tile_slots)
 
     # ------------------------------------------------------ reporting
     @property
@@ -494,6 +501,8 @@ class ServingMetrics:
         for kind in self.attended_keys:
             snap[f"attended_keys_{kind}"] = self.attended_keys[kind]
             snap[f"fetched_keys_{kind}"] = self.fetched_keys[kind]
+        for kind, slots in self.small_tile_slots.items():
+            snap[f"small_tile_slots_{kind}"] = slots
         for kind, on in self.attention_paged_kernel_kinds.items():
             snap[f"attention_paged_kernel_{kind}"] = on
         if self.context_keys:

@@ -4,6 +4,7 @@ dense XLA lines of models/decoding.py on the same pools. The kernel takes
 the pools as init_paged_cache stacks them, [L, P+1, ps, KV, hd], and a
 layer's index; the dense lines read ``stack[layer]``."""
 
+import functools
 import types
 
 import jax
@@ -13,8 +14,11 @@ import pytest
 
 from deepspeed_tpu.models.decoding import (_dense_cached_attention,
                                            _paged_gather)
-from deepspeed_tpu.ops.pallas.paged_attention import (paged_attention,
-                                                      paged_attention_kernel)
+from deepspeed_tpu.ops.pallas.paged_attention import (SMALL_ROWS,
+                                                      paged_attention,
+                                                      paged_attention_kernel,
+                                                      row_tile,
+                                                      small_tile_slots)
 
 PS, MP, NULL = 8, 32, 40  # page size, pages a slot, the NULL page's index
 
@@ -348,3 +352,60 @@ def test_paged_decode_int8_gathers_scales_through_the_table(hd):
         cache_len,
         _paged_gather_scale(ks, pt), _paged_gather_scale(vs, pt))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+# ---- one program a slot: the small tile of a slot's stack (PR 63). A slot
+# whose real rows fit SMALL_ROWS stacked rows computes those alone; the
+# whole-stack body is forced by calling with every row real.
+SMALL_S = 16  # a 16-row chunk: [64, hd] and [128, hd] stacks at G = 4 and 8
+
+
+@pytest.mark.parametrize("window", [None, 200])
+@pytest.mark.parametrize("hd", [128, 256])
+@pytest.mark.parametrize("G", [4, 8])
+def test_a_slot_whose_real_rows_fit_the_small_tile_computes_it_alone(
+        G, hd, window):
+    """An idle slot, a decoding one, the most rows the small tile takes, one
+    more (the first that takes the whole stack) and a full chunk in one
+    call, at frontiers inside a page and inside a key block: real rows match
+    the dense lines and are the whole-stack body's to the bit, rows past the
+    small tile read zeros exactly where ``small_tile_slots`` says so."""
+    KV, S = 2, SMALL_S
+    fit = SMALL_ROWS // G
+    r = np.random.RandomState(G * hd + (window or 0))
+    k_pool, v_pool, pt = _wide_pools(r, 5, KV, hd)
+    k_pool, v_pool = k_pool.astype(jnp.bfloat16), v_pool.astype(jnp.bfloat16)
+    q = jnp.asarray(r.randn(5, S, KV * G, hd), jnp.bfloat16)
+    cache_len = jnp.asarray([1070, 517, 300, 1029, 0], jnp.int32)
+    num_new = np.asarray([1, fit, 0, fit + 1, S], np.int32)
+    call = jax.jit(functools.partial(
+        paged_attention_kernel, layer=0, window=window))
+    out = np.asarray(call(q, k_pool, v_pool, cache_len, pt,
+                          num_new=jnp.asarray(num_new)).astype(jnp.float32))
+    whole = np.asarray(call(q, k_pool, v_pool, cache_len, pt,
+                            num_new=jnp.full((5,), S, jnp.int32)
+                            ).astype(jnp.float32))
+    ref = _dense(q, k_pool, v_pool, pt, cache_len, window=window)
+    took_small = 0
+    for b, n in enumerate(num_new):
+        np.testing.assert_allclose(out[b, :n], ref[b, :n], atol=2e-2,
+                                   rtol=2e-2)
+        np.testing.assert_array_equal(out[b, :n], whole[b, :n])
+        took_small += bool(n) and not out[b, fit:].any()
+    assert np.isfinite(out).all() and not out[2].any()  # the idle slot
+    assert took_small == small_tile_slots(num_new, G, S) == 2
+
+
+def test_small_tile_slots_is_the_kernels_predicate():
+    """Nothing where the grid is a program a slot and row tile (Command
+    A+'s 16 query heads a KV head under a 256-row chunk), nor where the
+    whole stack is no larger than the small tile."""
+    nn = np.asarray([0, 1, 2, 3, 256])
+    rows = row_tile(256, 16, 8, 128, 16, 32, 2, 2)
+    assert rows < 256 and small_tile_slots(nn, 16, 256, rows) == 0
+    assert row_tile(256, 8, 2, 256, 64, 8, 2, 2) == 256  # Qwen3-Next's
+    assert small_tile_slots(nn, 8, 256, 256) == small_tile_slots(
+        nn, 8, 256) == 3
+    assert small_tile_slots(nn, 4, 256) == 3 and small_tile_slots(
+        [8, 9], 4, 256) == 1
+    assert small_tile_slots([1, 1], 4, SMALL_ROWS // 4) == 0

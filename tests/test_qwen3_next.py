@@ -198,8 +198,9 @@ def test_the_step_says_what_each_kind_did(model, params):
                               num_new=np.array([5, 0, 0])))
     counts = srv._count_keys(plan)
     # 5 rows at positions 16..20 see 17 + ... + 21 keys in two 16-token pages
+    # (a 16-row chunk of 2 query heads a KV head is the small tile's size)
     assert counts == {"rows": 5, "attended_full": 95, "fetched_full": 32,
-                      "gdn_rows": 5, "gdn_state_slots": 1, "state_resets": 0,
+                      "small_tile_slots_full": 0, "gdn_rows": 5, "gdn_state_slots": 1, "state_resets": 0,
                       **{k: counts[k] for k in counts if "experts" in k}}
 
 

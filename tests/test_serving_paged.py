@@ -145,6 +145,39 @@ def test_paged_attention_kernel_tp2_serves_the_dense_tokens():
     assert srv.step_traces == 1
 
 
+def test_decoding_slots_take_the_small_tile_and_the_step_says_so():
+    """4 query heads a KV head under a 16-row budget: a [64, hd] stack a
+    slot, of which a decoding slot computes the first 32 rows alone
+    (ops/pallas/paged_attention.py SMALL_ROWS). Greedy tokens equal the XLA
+    path's through steps that mix a prompt chunk with decoding slots, and
+    the step's counts say how many slots took the small tile: none on the
+    dense path."""
+    from deepspeed_tpu.ops.attention import attention_impl
+
+    # (on a tp=2 mesh: heads over tp divide the KV heads, not the group)
+    topo = MeshTopology(dims=ParallelDims(tp=2), devices=jax.devices()[:2])
+    eng = _engine(tiny_llama(num_heads=8, num_kv_heads=2), topology=topo,
+                  rng=jax.random.PRNGKey(4))
+    r = np.random.RandomState(8)
+    prompts = [r.randint(0, 128, size=(n,)) for n in (5, 37, 11)]
+    news = [6, 4, 5]
+    dense_srv = _serving(eng, paged=True, token_budget=16)
+    dense = _drive(dense_srv, prompts, news)
+    with attention_impl("flash"):
+        srv = _serving(eng, paged=True, token_budget=16)
+    kernel = _drive(srv, prompts, news)
+    for i, (d, k) in enumerate(zip(dense, kernel)):
+        np.testing.assert_array_equal(d.output(), k.output(),
+                                      err_msg=f"r{i}")
+    assert srv.attention_path == "paged_kernel"
+    # 8 rows of 4 heads fit the small tile, 9 do not, an idle slot has none
+    plan = type("P", (), dict(start_pos=np.array([16, 30, 7, 0]),
+                              num_new=np.array([9, 1, 8, 0])))
+    assert srv._count_keys(plan)["small_tile_slots_full"] == 2
+    assert srv.metrics.snapshot()["small_tile_slots_full"] == 2
+    assert dense_srv._count_keys(plan)["small_tile_slots_full"] == 0
+
+
 # ---------------------------------------------------------------------------
 # prefix cache + copy-on-write
 # ---------------------------------------------------------------------------

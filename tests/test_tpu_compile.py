@@ -82,6 +82,25 @@ def _compile(fn, one_chip, *shapes):
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
 
+def _kernel_products(fn, *shapes):
+    """The ``dot_general``s in the kernels of ``fn``'s Pallas calls, as
+    traced: a body of the paged call has two a block loop."""
+    def products(jaxpr, inside):
+        n = 0
+        for eqn in jaxpr.eqns:
+            n += inside and eqn.primitive.name == "dot_general"
+            for v in eqn.params.values():
+                for x in v if isinstance(v, (tuple, list)) else (v,):
+                    sub = getattr(x, "jaxpr", x)  # closed or open
+                    if hasattr(sub, "eqns"):
+                        n += products(
+                            sub, inside or eqn.primitive.name == "pallas_call")
+        return n
+
+    return products(jax.make_jaxpr(fn)(
+        *[jax.ShapeDtypeStruct(s, d) for s, d in shapes]).jaxpr, False)
+
+
 # ----------------------------------------------------------------- flash
 @pytest.mark.parametrize(
     "B,S,H,KV,hd,alibi,seg",
@@ -261,13 +280,18 @@ def test_paged_attention_compiles_at_16_heads_a_kv_head(one_chip, S, window):
             q, k, v, cl, pt, layer=layer, num_new=nn, interpret=False,
             window=window)
 
-    text = _compile(
-        step, one_chip,
+    shapes = (
         ((B, S, H, hd), BF16), ((1, 2049, ps, KV, hd), BF16),
         ((1, 2049, ps, KV, hd), BF16), ((B,), I32), ((B,), I32),
         ((B, per_slot), I32), ((), I32),
     )
+    text = _compile(step, one_chip, *shapes)
     assert "tpu_custom_call" in text, text[:2000]
+    # the row-tiled grid traces ONE body of two or three block loops; one
+    # program a slot traces the small tile's beside the whole stack's
+    loops = 2 if window is None else 3
+    assert _kernel_products(step, *shapes) == 2 * loops * (
+        1 if rows < S else 2)
 
 
 @pytest.mark.parametrize("window", [None, 1024], ids=["full", "window"])
@@ -311,13 +335,16 @@ def test_paged_attention_compiles_at_256_wide_heads(one_chip, S):
             q, k, v, cl, pt, layer=layer, num_new=nn, interpret=False,
             name="paged_attention_full")
 
-    text = _compile(
-        step, one_chip,
+    shapes = (
         ((B, S, H, hd), BF16), ((3, 2049, ps, KV, hd), BF16),
         ((3, 2049, ps, KV, hd), BF16), ((B,), I32), ((B,), I32),
         ((B, per_slot), I32), ((), I32),
     )
+    text = _compile(step, one_chip, *shapes)
     assert "tpu_custom_call" in text and "paged_attention_full" in text
+    # (two block loops of two products a body: the row-tiled grid at 512
+    # rows traces one, one program a slot the small tile's as well)
+    assert _kernel_products(step, *shapes) == 2 * 2 * (1 if rows < S else 2)
 
 
 @pytest.mark.parametrize("cell", ["keye", "deepseek", "glm5", "minicpm"])
