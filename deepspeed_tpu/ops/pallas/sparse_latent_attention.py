@@ -10,7 +10,19 @@ engine compiles. Three calls a layer, each named in a device trace:
     the indexer's heads, the keys read page by page through the slot's
     table from the indexer's own pool. One program a slot; a loop over key
     blocks whose trip count follows the slot's length, and inside it a loop
-    over row tiles that follows the slot's real rows.
+    over row tiles that follows the slot's real rows (:func:`score_tiles`
+    counts both). A tile is some queries with their heads stacked
+    query-major: one product against the key block, then ``relu``, the
+    weights and the sum over heads. A slot's rows go in LARGE tiles of
+    ``SCORE_STACK`` stacked rows (the key block enters the matrix unit once
+    for all of them) where at least half of one is real, and in tiles of
+    ``SCORE_ROWS`` queries for the rest, so a decoding slot pays for 16
+    rows. A block's pages (32 copies of 4 KiB at 16-token pages) are issued
+    as straight-line code and ``SCORE_RING`` blocks are in flight, keys
+    coming and scores going: a decoding slot's trip is one small product,
+    so it runs at the pace the copies are issued and land at. A block's
+    ``[S, block_k]`` scores go out whole; the rows of tiles that were not
+    computed hold whatever the buffer held.
 ``selection_topk``  The exact ``topk`` largest scores of each row, as a
     threshold: 32 counting passes find the ``topk``-th largest value bit by
     bit (the scores as order-preserving integers), one more counts the keys
@@ -77,6 +89,8 @@ from .paged_attention import (SMEM_TABLE_BYTES, VMEM_LIMIT_BYTES,
 
 BLOCK_K = 512        # keys a loop trip of the scoring and attention kernels
 SCORE_ROWS = 16      # query rows a tile of the scoring kernel
+SCORE_STACK = 2048   # stacked rows (queries x heads) of its large tile
+SCORE_RING = 4       # key blocks (and score blocks) it keeps in flight
 SELECT_ROWS = 8      # query rows a program of the selection kernel
 SELECT_BLOCKS = 8    # key blocks a counting step of the selection reads
 ATTN_ROWS = 8        # query rows a program of the attention kernel
@@ -95,9 +109,12 @@ def score_blocks(max_pages: int, page_size: int,
 
 
 # --------------------------------------------------------------- paging
-def _page_fetch(pt_ref, hbm, buf, sems, b, layer, ps, ppb, mp):
+def _page_fetch(pt_ref, hbm, buf, sems, b, layer, ps, ppb, mp,
+                unroll: bool = False):
     """(start, wait) of the block fetches of one slot: ``ppb`` whole pages
-    of ``hbm[layer]`` through row ``b`` of the table into ``buf[slot]``."""
+    of ``hbm[layer]`` through row ``b`` of the table into ``buf[slot]``.
+    ``unroll``: the ``ppb`` copies of a block are issued (and awaited) as
+    straight-line code, not as a loop of scalar trips."""
     def copy(slot, j, page):
         return pltpu.make_async_copy(
             hbm.at[layer, page], buf.at[slot, pl.ds(j * ps, ps)],
@@ -111,14 +128,14 @@ def _page_fetch(pt_ref, hbm, buf, sems, b, layer, ps, ppb, mp):
                  ).start()
             return c
 
-        lax.fori_loop(0, ppb, one, 0)
+        lax.fori_loop(0, ppb, one, 0, unroll=unroll)
 
     def wait(slot):
         def one(j, c):
             copy(slot, j, 0).wait()
             return c
 
-        lax.fori_loop(0, ppb, one, 0)
+        lax.fori_loop(0, ppb, one, 0, unroll=unroll)
 
     return start, wait
 
@@ -126,15 +143,24 @@ def _page_fetch(pt_ref, hbm, buf, sems, b, layer, ps, ppb, mp):
 # -------------------------------------------------------------- indexer
 def _index_scores_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, w_ref,
                          k_hbm, o_hbm, k_buf, o_buf, ksems, osems,
-                         *, page_size, pages_per_block, heads, rows,
+                         *, page_size, pages_per_block, heads, rows, large,
                          kpool: int = 1):
+    """A ring of ``k_buf.shape[0]`` key blocks: block ``i`` is computed
+    while the pages of the next ``ring - 1`` are on their way and the
+    scores of the last ``ring`` are on their way out. A slot's real rows
+    are covered by tiles of ``large`` rows where it has (most of) that many
+    and tiles of ``rows`` for the rest (:func:`_tiles`)."""
     ps, ppb = page_size, pages_per_block
     bk = ps * ppb
     mp = pt_ref.shape[1]
+    ring = k_buf.shape[0]
     b = pl.program_id(0)
     cl, nn, layer = cl_ref[b], nn_ref[b], layer_ref[0]
+    # a block of 16-token pages is 32 copies: issued by a loop, a decoding
+    # slot's trip waits for the scalar trips, not for the keys (PERF.md,
+    # PR 64)
     start_fetch, wait_fetch = _page_fetch(pt_ref, k_hbm, k_buf, ksems, b,
-                                          layer, ps, ppb, mp)
+                                          layer, ps, ppb, mp, unroll=True)
 
     def out_copy(slot, blk):
         return pltpu.make_async_copy(
@@ -146,46 +172,114 @@ def _index_scores_kernel(pt_ref, cl_ref, nn_ref, layer_ref, q_ref, w_ref,
         if kpool > 1:
             keys = keys // kpool
         n_blocks = jnp.minimum(pl.cdiv(keys, bk), pl.cdiv(mp * ps, bk))
-        n_tiles = pl.cdiv(nn, rows)
-        start_fetch(0, 0)
+        n_large, n_small = _tiles(nn, o_buf.shape[1], rows, large)
+
+        def first(i, c):
+            start_fetch(i, i)
+            return c
+
+        lax.fori_loop(0, jnp.minimum(ring - 1, n_blocks), first, 0)
 
         def block(i, carry):
-            slot = lax.rem(i, 2)
+            slot = lax.rem(i, ring)
 
-            @pl.when(i + 1 < n_blocks)
-            def _prefetch():
-                start_fetch(i + 1, 1 - slot)
+            @pl.when(i + ring - 1 < n_blocks)
+            def _prefetch():  # into the slot block i - 1 was computed from
+                start_fetch(i + ring - 1, lax.rem(i + ring - 1, ring))
 
             wait_fetch(slot)
 
-            @pl.when(i >= 2)
-            def _drain():  # the write that last used this half
-                out_copy(slot, i - 2).wait()
+            @pl.when(i >= ring)
+            def _drain():  # the write that last used this slot
+                out_copy(slot, i - ring).wait()
 
             k = k_buf[slot]
 
-            def tile(t, c):
-                r0 = pl.multiple_of(t * rows * heads, rows * heads)
-                q = q_ref[0, pl.ds(r0, rows * heads), :]
-                s = lax.dot_general(
-                    q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)  # [rows*heads, bk]
-                s = jnp.maximum(s, 0.0) * w_ref[0, pl.ds(r0, rows * heads), :1]
-                o_buf[slot, pl.ds(pl.multiple_of(t * rows, rows), rows), :] = (
-                    jnp.sum(s.reshape(rows, heads, bk), axis=1))
-                return c
+            def tile_of(size):
+                def tile(t, c):
+                    r0 = pl.multiple_of(t * size * heads, size * heads)
+                    q = q_ref[0, pl.ds(r0, size * heads), :]
+                    s = lax.dot_general(
+                        q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)  # [size*heads, bk]
+                    s = jnp.maximum(s, 0.0) * w_ref[
+                        0, pl.ds(r0, size * heads), :1]
+                    o_buf[slot, pl.ds(pl.multiple_of(t * size, size), size),
+                          :] = jnp.sum(s.reshape(size, heads, bk), axis=1)
+                    return c
 
-            lax.fori_loop(0, n_tiles, tile, 0)
+                return tile
+
+            if large > rows:
+                lax.fori_loop(0, n_large, tile_of(large), 0)
+            first_small = n_large * (large // rows)
+            lax.fori_loop(first_small, first_small + n_small, tile_of(rows), 0)
             out_copy(slot, i).start()
             return carry
 
         lax.fori_loop(0, n_blocks, block, 0)
 
-        @pl.when(n_blocks >= 2)
-        def _last_but_one():
-            out_copy(lax.rem(n_blocks, 2), n_blocks - 2).wait()
+        def last(i, c):  # the writes still on their way
+            out_copy(lax.rem(i, ring), i).wait()
+            return c
 
-        out_copy(lax.rem(n_blocks - 1, 2), n_blocks - 1).wait()
+        lax.fori_loop(jnp.maximum(n_blocks - ring, 0), n_blocks, last, 0)
+
+
+def score_rows(S: int, heads: int) -> Tuple[int, int]:
+    """(rows of a small tile, rows of a large one) of the scoring kernel
+    over chunks of ``S`` queries of ``heads`` index heads: the large tile
+    stacks ``SCORE_STACK`` rows (or the chunk), which loads a key block
+    into the matrix unit once for 8 (Keye), 4 or 2 small tiles' rows; a
+    small tile is what a decoding slot's one row pays for."""
+    rows = min(SCORE_ROWS, S)
+    return rows, min(S, max(SCORE_STACK // heads // rows, 1) * rows)
+
+
+def _at_most(x, cap: int):
+    """``min(x, cap)`` of a kernel scalar or a host vector alike."""
+    return x - (x - cap) * (x > cap)
+
+
+def _tiles(nn, S: int, rows: int, large: int):
+    """(large tiles, small tiles) that cover ``nn`` real rows of ``S`` (a
+    scalar of the kernel or a host vector): a large tile where at least
+    half of it is real, small tiles from where the large ones end to the
+    last real row."""
+    n_large = 0 * nn
+    if large > rows:
+        n_large = _at_most((nn + large // 2) // large, S // large)
+    rest = (nn + rows - 1) // rows - n_large * (large // rows)
+    return n_large, rest * (rest > 0)
+
+
+def score_grid(max_pages: int, page_size: int, kpool: int = 1
+               ) -> Tuple[int, int]:
+    """(key blocks a slot's table maps, keys a block) of the scoring
+    kernel's loop over a ``[B, max_pages]`` table of ``page_size``-token
+    pages; ``kpool`` > 1: over :func:`pooled_view`'s run of pooled keys."""
+    if kpool > 1:
+        pages = POOLED_BLOCK_K // (page_size // kpool)
+        return -(-max_pages // pages), POOLED_BLOCK_K
+    bk = page_size * _block_pages(BLOCK_K, page_size, max_pages)
+    return -(-(max_pages * page_size) // bk), bk
+
+
+def score_tiles(cache_len, num_new, S: int, heads: int, max_pages: int,
+                page_size: int, kpool: int = 1):
+    """(int [B], int) (numpy or jax, as the frontiers are): the (row tile,
+    key block) trips of :func:`index_scores`'s loops for each slot, in
+    small tiles (a large tile counts the small ones it covers), and the
+    trips a slot over a full grid. A tile is computed where it holds a real
+    row (or lies in a large tile that is half real), a block where it holds
+    a key at or before the slot's last real row; an idle slot runs none."""
+    blocks, bk = score_grid(max_pages, page_size, kpool)
+    rows, large = score_rows(S, heads)
+    n_large, n_small = _tiles(num_new, S, rows, large)
+    tiles = n_large * (large // rows) + n_small
+    n_blocks = _at_most((_keys(cache_len + num_new, kpool) + bk - 1) // bk,
+                        blocks)
+    return tiles * n_blocks, S // rows * blocks
 
 
 def index_scores(q_idx, w_idx, ki_pool, cache_len, page_table, *, layer,
@@ -196,15 +290,18 @@ def index_scores(q_idx, w_idx, ki_pool, cache_len, page_table, *, layer,
     rotated indexer queries, ``w_idx`` [B,S,Hi] float32 head weights (every
     constant factor folded in), ``ki_pool`` [L,P+1,ps,Di] the indexer keys,
     the chunk's own already written. An entry is defined for
-    ``s < cache_len[b] + num_new[b]`` and ``i < num_new[b]``. ``kpool`` > 1:
-    a key is a block of that many tokens (``ki_pool`` and ``page_table`` in
-    keys, :func:`pooled_view`'s), defined for ``s < (cache_len[b] +
-    num_new[b]) // kpool``."""
+    ``s < cache_len[b] + num_new[b]`` and ``i < num_new[b]``: the row tiles
+    past a slot's real rows are not computed (what is written there is
+    whatever the buffer held) and the blocks past its context are not
+    written (:func:`score_tiles` counts what is). ``kpool`` > 1: a key is a
+    block of that many tokens (``ki_pool`` and ``page_table`` in keys,
+    :func:`pooled_view`'s), defined for ``s < (cache_len[b] + num_new[b])
+    // kpool``."""
     B, S, Hi, Di = q_idx.shape
     ps, mp = ki_pool.shape[2], page_table.shape[1]
     ppb = _block_pages(block_k, ps, mp)
     NB, bk = score_blocks(mp, ps, block_k)
-    rows = min(SCORE_ROWS, S)
+    rows, large = score_rows(S, Hi)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     cl, nn = _frontiers(B, S, cache_len, num_new)
@@ -220,16 +317,16 @@ def index_scores(q_idx, w_idx, ki_pool, cache_len, page_table, *, layer,
         ],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
-            pltpu.VMEM((2, bk, Di), ki_pool.dtype),
-            pltpu.VMEM((2, S, bk), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((SCORE_RING, bk, Di), ki_pool.dtype),
+            pltpu.VMEM((SCORE_RING, S, bk), jnp.float32),
+            pltpu.SemaphoreType.DMA((SCORE_RING,)),
+            pltpu.SemaphoreType.DMA((SCORE_RING,)),
         ],
     )
     return pl.pallas_call(
         functools.partial(_index_scores_kernel, page_size=ps,
                           pages_per_block=ppb, heads=Hi, rows=rows,
-                          kpool=kpool),
+                          large=large, kpool=kpool),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, NB, S, bk), jnp.float32),
         compiler_params=pltpu.CompilerParams(

@@ -739,13 +739,14 @@ def _mla_counts(engine, cl, nn) -> Dict[str, int]:
     ``tail_keys`` the tokens attended because they lie after a query's last
     whole block; ``chosen_min`` the fewest latent rows a slot's queries can
     have chosen between them (its last query's); ``selection_tiles`` of
-    ``selection_tiles_grid`` as :func:`_selection_tiles` counts them. Books
-    the attended keys on the metrics."""
+    ``selection_tiles_grid`` and ``score_tiles`` of ``score_tiles_grid`` as
+    :func:`_selection_tiles` counts them. Books the attended keys on the
+    metrics."""
     cfg = engine.config
     kp, topk = int(cfg.index_kpool), int(cfg.index_topk) or (1 << 62)
     counts = dict.fromkeys(("context_keys", "index_keys", "index_rows",
                             "attended_sparse", "tail_keys", "chosen_min"), 0)
-    counts.update(_selection_tiles(cl, nn, engine.token_budget, topk, kp))
+    counts.update(_selection_tiles(engine, cl, nn, topk, kp))
     for c, n in zip(cl[nn > 0], nn[nn > 0]):
         seen = np.arange(c, c + n) + 1  # a query's context, itself included
         whole = seen // kp
@@ -763,18 +764,30 @@ def _mla_counts(engine, cl, nn) -> Dict[str, int]:
     return counts
 
 
-def _selection_tiles(cl, nn, width: int, topk: int,
+def _selection_tiles(engine, cl, nn, topk: int,
                      kpool: int = 1) -> Dict[str, int]:
-    """What share of the selection's grid has something to select:
-    ``selection_tiles`` the 8-row tiles of the ``[max_slots, width]`` step
-    that hold a real row whose context passes ``topk`` (the programs of
+    """What share of the indexer's two grids has work: ``selection_tiles``
+    the 8-row tiles of the ``[max_slots, token_budget]`` step that hold a
+    real row whose context passes ``topk`` (the programs of
     ``selection_topk`` that search; the others fetch no score), of
-    ``selection_tiles_grid`` programs a call."""
-    from ..ops.pallas.sparse_latent_attention import selection_tiles
+    ``selection_tiles_grid`` programs a call; ``score_tiles`` the (row
+    tile, key block) trips ``indexer_scores`` runs (a slot's real rows in
+    16-row tiles, those a large tile covers among them, x its context in
+    key blocks), of ``score_tiles_grid`` over every tile and every block
+    the tables map."""
+    from ..ops.pallas.sparse_latent_attention import (score_tiles,
+                                                      selection_tiles)
 
-    tiles = selection_tiles(cl, nn, width, topk, kpool)
+    tiles = selection_tiles(cl, nn, engine.token_budget, topk, kpool)
+    trips, full = 0 * cl, 0  # a latent model without an indexer scores none
+    if engine.config.index_heads:
+        trips, full = score_tiles(
+            cl, nn, engine.token_budget, engine.config.index_heads,
+            engine.pages_per_slot, engine.page_size, kpool)
     return {"selection_tiles": int(tiles.sum()),
-            "selection_tiles_grid": int(tiles.size)}
+            "selection_tiles_grid": int(tiles.size),
+            "score_tiles": int(trips.sum()),
+            "score_tiles_grid": int(full * len(cl))}
 
 
 def _page_counts(engine, cl, nn, kind: str = "full") -> Dict[str, int]:
@@ -1716,8 +1729,8 @@ class ServingEngine:
         hold a slot's context, which the indexer reads once a slot;
         ``chosen_min`` the fewest distinct latent rows a slot's queries can
         have chosen between them (its last query's); ``selection_tiles`` of
-        ``selection_tiles_grid`` as :func:`_selection_tiles` counts them.
-        Booked on the metrics."""
+        ``selection_tiles_grid`` and ``score_tiles`` of ``score_tiles_grid``
+        as :func:`_selection_tiles` counts them. Booked on the metrics."""
         cl = plan.start_pos.astype(np.int64)
         nn = plan.num_new.astype(np.int64)
         topk = int(self.config.index_topk) or (1 << 62)
@@ -1732,7 +1745,7 @@ class ServingEngine:
             "attended_sparse": int(attended.sum()),
             "index_keys": int((-(-(cl + nn) // ps) * ps)[busy].sum()),
             "chosen_min": int(np.minimum(cl + nn, topk)[busy].sum()),
-            **_selection_tiles(cl, nn, self.token_budget, topk),
+            **_selection_tiles(self, cl, nn, topk),
         }
         self.metrics.on_keys("sparse", counts["attended_sparse"],
                              counts["index_keys"])

@@ -270,6 +270,172 @@ def test_the_selection_is_the_sorts_threshold_and_tie(case):
             [True, False]]
 
 
+# the scoring call of the three cells that run it: (indexer heads, the key's
+# own width in its 128-lane row, tokens a pooled key)
+SCORE_SHAPES = {"keye": (16, 64, 1), "deepseek": (64, 128, 1),
+                "glm5-pooled": (32, 128, 4)}
+# (cache_len, num_new) of three slots in tokens a key, and the chunk's rows
+SCORE_CASES = {
+    "all-decoding": ([300, 37, 150], [1, 1, 1], 16),
+    "a-chunk-beside-decoders": ([300, 100, 150], [1, 16, 1], 16),
+    "an-idle-slot": ([300, 37, 150], [16, 0, 1], 16),
+    "rows-no-multiple-of-a-tile": ([300, 37, 150], [25, 5, 48], 48),
+    "a-chunk-under-a-tile": ([300, 37, 150], [8, 3, 1], 8),
+}
+
+
+def score_inputs(shape, case, seed=0):
+    """Operands of :func:`sla.index_scores` for a shape and a case, six key
+    blocks a slot (the kernel's ring of four goes round): 64-key blocks of
+    16-token pages, or pooled keys through :func:`sla.pooled_view`. Returns
+    (a call of the kernel, the dense scores over every key, cl, nn, kpool)."""
+    Hi, width, kpool = SCORE_SHAPES[shape]
+    cl, nn, S = SCORE_CASES[case]
+    B, Di, ps = 3, 128, 16
+    # six blocks a slot: of 64 tokens, or of POOLED_BLOCK_K pooled keys
+    mp = 6 * (sla.POOLED_BLOCK_K * kpool if kpool > 1 else 64) // ps
+    rng = np.random.default_rng(seed)
+    lanes = np.arange(Di) < width
+    f = lambda *s: jnp.asarray(rng.normal(size=s) * lanes, F32)
+    q_idx, w_idx = f(B, S, Hi, Di), jnp.asarray(rng.normal(size=(B, S, Hi)), F32)
+    pool = f(2, B * mp + 1, ps // kpool, Di)
+    table = jnp.asarray(rng.permutation(B * mp).reshape(B, mp), jnp.int32)
+    cl = jnp.asarray(cl, jnp.int32) * (8 if kpool > 1 else 1)
+    nn = jnp.asarray(nn, jnp.int32)
+    if kpool > 1:
+        def call():
+            view, counting = sla.pooled_view(pool, table, 1)
+            return sla.index_scores(
+                q_idx, w_idx, view, cl, counting, layer=0, num_new=nn,
+                block_k=sla.POOLED_BLOCK_K, kpool=kpool, interpret=True)
+    else:
+        def call():
+            return sla.index_scores(q_idx, w_idx, pool, cl, table, layer=1,
+                                    num_new=nn, block_k=64, interpret=True)
+    dense = sla.dense_index_scores(
+        q_idx[..., :width], w_idx, _paged_gather(pool[1], table)[..., :width])
+    return call, dense, cl, nn, kpool
+
+
+@pytest.mark.parametrize("case", SCORE_CASES)
+@pytest.mark.parametrize("shape", SCORE_SHAPES)
+def test_index_scores_is_its_dense_twin(shape, case):
+    """The scoring kernel at the three cells' head counts and key widths
+    against the plain lines, on every real row and every key of its
+    context, whatever the slots hold (a large tile of 128, 32 or 64 rows
+    where a slot has most of that many, 16-row tiles for the rest)."""
+    call, dense, cl, nn, kpool = score_inputs(shape, case)
+    got = np.asarray(sla.unblocked(jax.jit(call)()))
+    dense = np.asarray(dense)
+    cl, nn = np.asarray(cl), np.asarray(nn)
+    bk = sla.POOLED_BLOCK_K if kpool > 1 else 64
+    assert (cl[0] + nn[0]) // kpool > sla.SCORE_RING * bk  # the ring goes round
+    for b in range(3):
+        rows, keys = nn[b], (cl[b] + nn[b]) // kpool
+        np.testing.assert_allclose(got[b, :rows, :keys], dense[b, :rows, :keys],
+                                   rtol=1e-5, atol=1e-4)
+
+
+def test_score_tiles_are_the_trips_the_kernel_runs():
+    """Every slot scores its own constant, so a (16-row tile, key block) of
+    the output holds it exactly where that slot's program computed it: the
+    count of those is :func:`sla.score_tiles`: an idle slot's none, a
+    decoding slot's one tile a block of its context, a chunk's large tiles
+    (32 rows at 64 heads) where half of one is real and small ones for the
+    rest."""
+    B, S, Hi, Di, ps, mp = 6, 64, 64, 128, 16, 96  # 3 blocks of 512 a slot
+    cl = np.asarray([0, 1100, 1500, 700, 30, 500], np.int32)
+    nn = np.asarray([64, 1, 20, 0, 40, 15], np.int32)
+    own = jnp.arange(1, B + 1, dtype=F32)[:, None, None, None]
+    q_idx = jnp.broadcast_to(own, (B, S, Hi, Di))
+    w_idx = jnp.ones((B, S, Hi), F32)
+    pool = jnp.ones((1, B * mp + 1, ps, Di), F32)
+    table = jnp.arange(B * mp, dtype=jnp.int32).reshape(B, mp)
+    scores = np.asarray(sla.index_scores(q_idx, w_idx, pool, cl, table,
+                                         layer=0, num_new=nn, interpret=True))
+    assert sla.score_rows(S, Hi) == (16, 32)
+    tiled = scores.reshape(B, scores.shape[1], S // 16, 16, -1)
+    mine = (tiled == np.arange(1, B + 1).reshape(B, 1, 1, 1, 1) * Hi * Di
+            ).all(axis=(3, 4))
+    trips, full = sla.score_tiles(cl, nn, S, Hi, mp, ps)
+    # tiles: two large; one small; a large one (12 padded rows); none; a
+    # large one and a small one; one small (a large one would be half empty)
+    assert mine.sum(axis=(1, 2)).tolist() == trips.tolist() == [
+        4 * 1, 1 * 3, 2 * 3, 0, 3 * 1, 1 * 2]
+    assert full == 4 * 3 and sla.score_grid(mp, ps) == (3, 512)
+    assert sla.score_grid(264, 16, kpool=4) == (9, sla.POOLED_BLOCK_K)
+    assert sla.score_rows(128, 16) == (16, 128) and sla.score_rows(
+        8, 16) == (8, 8) and sla.score_rows(128, 32) == (16, 64)
+
+
+def _undefined_scores(scores, cl, nn, heads, kpool=1):
+    """``scores`` with NaN wherever :func:`sla.index_scores` defines
+    nothing: the row tiles past a slot's real rows, the blocks past its
+    context."""
+    B, NB, S, bk = scores.shape
+    rows, large = sla.score_rows(S, heads)
+    n_large, n_small = sla._tiles(np.asarray(nn), S, rows, large)
+    tiles = n_large * large + n_small * rows
+    blocks = -(-((np.asarray(cl) + np.asarray(nn)) // kpool) // bk)
+    written = (np.arange(S)[None, None, :, None] < tiles[:, None, None, None]
+               ) & (np.arange(NB)[None, :, None, None]
+                    < blocks[:, None, None, None])
+    return jnp.where(written, scores, jnp.nan)
+
+
+@pytest.mark.parametrize("walk", ["latent", "paged", "pooled"])
+def test_nothing_reads_a_score_the_kernel_does_not_define(walk):
+    """The selection and the three walks over scores whose undefined part
+    is NaN (the row tiles past a slot's real rows, the blocks past its
+    context, an idle slot's everything): the real rows of the layer's output
+    are finite and EQUAL those of the run on the scores as the kernel left
+    them."""
+    from deepspeed_tpu.ops.pallas import sparse_paged_attention as spa
+
+    B, S, H, Hi, W, ps, mp, topk = 3, 32, 2, 2, 128, 16, 64, 40
+    kpool = 4 if walk == "pooled" else 1
+    rng = np.random.default_rng(3)
+    f = lambda *s: jnp.asarray(rng.normal(size=s), F32)
+    P = B * mp
+    table = jnp.asarray(rng.permutation(P).reshape(B, mp), jnp.int32)
+    cl = jnp.asarray([400, 77, 300], jnp.int32)
+    nn = jnp.asarray([20, 5, 0], jnp.int32)  # a large tile, a small, idle
+    q_idx, w_idx = f(B, S, Hi, 128), f(B, S, Hi)
+    ki = f(1, P + 1, ps // kpool, 128)
+    kw = dict(num_new=nn, interpret=True)
+    if walk == "pooled":
+        view, counting = sla.pooled_view(ki, table, 0)
+        scores = sla.index_scores(q_idx, w_idx, view, cl, counting, layer=0,
+                                  block_k=sla.POOLED_BLOCK_K, kpool=kpool, **kw)
+    else:
+        scores = sla.index_scores(q_idx, w_idx, ki, cl, table, layer=0, **kw)
+    if walk == "paged":
+        q, k, v = f(B, S, 4, 16), f(1, P + 1, ps, 2, 16), f(1, P + 1, ps, 2, 16)
+    else:
+        q_abs, kv = f(B, S, H, W), f(1, P + 1, ps, W)
+
+    @jax.jit
+    def layer(scores):
+        thr, tie = sla.select_topk(scores, cl, nn, topk, interpret=True,
+                                   kpool=kpool)
+        if walk == "paged":
+            return spa.sparse_paged_attention_kernel(
+                q, k, v, scores, thr, tie, cl, table, layer=0, **kw)
+        return sla.sparse_attention(
+            q_abs, kv, scores, thr, tie, cl, table, layer=0, scale=0.1,
+            v_width=W, kpool=kpool, **kw)
+
+    dirty = _undefined_scores(scores, cl, nn, Hi, kpool)
+    assert bool(jnp.isnan(dirty[0, :, 31]).all()) is False  # in its tile
+    assert bool(jnp.isnan(dirty[1, :, 16:]).all()) and bool(
+        jnp.isnan(dirty[2]).all()) and bool(jnp.isnan(dirty[0, -1]).all())
+    clean, soiled = np.asarray(layer(scores)), np.asarray(layer(dirty))
+    for b in range(B):
+        n = int(nn[b])
+        assert np.isfinite(soiled[b, :n]).all()
+        np.testing.assert_array_equal(soiled[b, :n], clean[b, :n])
+
+
 def test_the_attention_kernel_attends_the_chosen_rows_alone():
     k = kernel_inputs(seed=2)
     layer, topk, S, V = 0, 24, 16, 64

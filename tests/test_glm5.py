@@ -178,7 +178,9 @@ def test_engine_serves_what_the_reference_predicts(model, params, shape):
     assert got == dict(context_keys=26 + 42, index_keys=6 + 10,
                        index_rows=6 + 10, attended_sparse=26 + 26,
                        tail_keys=2 + 2, chosen_min=26 + 26,
-                       selection_tiles=1, selection_tiles_grid=3 * W // 8)
+                       selection_tiles=1, selection_tiles_grid=3 * W // 8,
+                       # 6 and 10 pooled keys: one block of one tile a slot
+                       score_tiles=2, score_tiles_grid=3)
 
 
 @pytest.mark.parametrize("fault", fam.FAULTS)

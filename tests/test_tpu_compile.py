@@ -412,6 +412,54 @@ def test_the_selected_walks_compile_at_their_cells_shapes(one_chip, cell):
     assert name in text and "tpu_custom_call" in text, text[:2000]
 
 
+@pytest.mark.parametrize("cell", ["keye", "deepseek", "glm5"])
+def test_the_scoring_call_compiles_at_its_cells_shapes(one_chip, cell):
+    """``indexer_scores`` alone at the three cells that run it: Keye-VL-2.0
+    (16 heads over a 64-wide key in a 128-lane row), DeepSeek-V3.2 (64 heads
+    of 128) and GLM-5.3 (32 heads, keys pooled by 4 through ``pooled_view``),
+    a ``[slots, 128]`` step over 66,560-token slots. One body whatever the
+    shape (two products in one kernel: a large tile's and a small one's;
+    the ring of key blocks and the unrolled page copies are no second
+    path), and the host's count of its trips at the cell's frontiers: a
+    decoding slot at 26 k runs one small tile over 51 blocks of 512 keys,
+    a whole chunk 8 tiles' rows in large ones."""
+    from deepspeed_tpu.ops.pallas import sparse_latent_attention as sla
+
+    B, Hi, kpool, tokens = {"keye": (4, 16, 1, 66560),
+                            "deepseek": (4, 64, 1, 66560),
+                            "glm5": (8, 32, 4, 67584)}[cell]
+    S, ps, Di = 128, 16, 128
+    mp = tokens // ps + S // ps
+
+    def call(q, w, pool, cl, nn, pt, layer):
+        if kpool > 1:
+            view, counting = sla.pooled_view(pool, pt, layer)
+            return sla.index_scores(
+                q, w, view, cl, counting, layer=0, num_new=nn, kpool=kpool,
+                block_k=sla.POOLED_BLOCK_K, interpret=False)
+        return sla.index_scores(q, w, pool, cl, pt, layer=layer, num_new=nn,
+                                interpret=False)
+
+    shapes = (((B, S, Hi, Di), BF16), ((B, S, Hi), F32),
+              ((4, B * mp + 1, ps // kpool, Di), BF16), ((B,), I32),
+              ((B,), I32), ((B, mp), I32), ((), I32))
+    text = _compile(call, one_chip, *shapes)
+    assert "indexer_scores" in text and "tpu_custom_call" in text
+    assert _kernel_products(call, *shapes) == 2
+    assert sla.score_rows(S, Hi)[1] == {16: 128, 64: 32, 32: 64}[Hi]
+    blocks, bk = sla.score_grid(mp, ps, kpool)
+    assert (blocks, bk) == ((131, 512) if kpool == 1 else (133, 128))
+    cl = np.asarray([26000] * (B - 1) + [13000])
+    nn = np.asarray([1] * (B - 1) + [S])
+    trips, full = sla.score_tiles(cl, nn, S, Hi, mp, ps, kpool)
+    per = -(-26001 // (bk * kpool))  # key blocks a decoding slot's context
+    assert trips.tolist() == [per] * (B - 1) + [
+        8 * -(-(13000 + S) // (bk * kpool))]
+    assert per == 51 and full == 8 * blocks
+    idle, _ = sla.score_tiles(cl, 0 * nn, S, Hi, mp, ps, kpool)
+    assert not idle.any()
+
+
 # --------------------------------- a whole slot step beside its arena
 GIB = 2.0 ** 30
 # the slot steps' temporaries before the caches rode the layer scan as its
