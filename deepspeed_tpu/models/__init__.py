@@ -18,6 +18,7 @@ from .brumby import brumby, brumby_config  # noqa: F401
 from .cohere import cohere, cohere_config  # noqa: F401
 from .keye import keye, keye_config  # noqa: F401
 from .qwen3_next import qwen3_next, qwen3_next_config  # noqa: F401
+from .lfm2 import lfm2, lfm2_config  # noqa: F401
 
 MODEL_REGISTRY = {
     "gpt2": gpt2,
@@ -34,6 +35,7 @@ MODEL_REGISTRY = {
     "cohere": cohere,
     "keye": keye,
     "qwen3_next": qwen3_next,
+    "lfm2": lfm2,
 }
 
 

@@ -633,9 +633,13 @@ def _paged_write(pool: jax.Array, new: jax.Array, layer,
     [L, P+1, page_size, KV, hd], row ``t`` at ``page_rows`` = (physical
     page, offset) of :meth:`ChunkRows.page_rows`, in place: the stack comes
     back whole. One update row a computed row: ``token_budget`` of them in a
-    packed step, whatever the slots."""
+    packed step, whatever the slots. A pool that holds 64-wide heads two a
+    128-lane row (ops/pallas/paged_attention.lane_pairs) takes the same
+    values in the same order."""
     phys, off = page_rows
-    return pool.at[layer, phys, off].set(new)
+    row = pool.shape[3:]  # ([KV, hd], [KV / 2, 128] or a latent's [width])
+    return pool.at[layer, phys, off].set(
+        new.reshape(*new.shape[:new.ndim - len(row)], *row))
 
 
 def _paged_write_scale(pool: jax.Array, new: jax.Array, layer,
@@ -889,6 +893,8 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
 
     quantized = k_scale is not None
     paged = page_table is not None
+    # 64-wide heads held two a 128-lane row (paged_attention.lane_pairs)
+    held_paired = k_cache.shape[-1] != hd
     if paged:  # the rows as they were computed, each to its place
         put, put_scale, where = _paged_write, _paged_write_scale, page_rows
     else:  # a slot's chunk is one slice of its arena
@@ -973,7 +979,7 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
             q, view(k_cache), view(v_cache), chosen))
     if paged:
         out = None
-        if kernel_ok and S == 1 and window is None:
+        if kernel_ok and S == 1 and window is None and not held_paired:
             # single-token paged decode: the Pallas kernel gathers K/V
             # page-by-page through the table (scalar prefetch drives the
             # block index map) — no [B, capacity] view materializes
@@ -1009,8 +1015,10 @@ def _cached_attention(cfg: TransformerConfig, p: Params, x: jax.Array,
         # XLA path: gather the per-slot contiguous views (post-write, so
         # they reproduce the dense arena bitwise) and fall through to the
         # shared attention math below
-        k_att = _paged_gather(at_layer(k_cache), page_table)
-        v_att = _paged_gather(at_layer(v_cache), page_table)
+        k_att = _paged_gather(at_layer(k_cache), page_table).reshape(
+            B, -1, nkv, hd)
+        v_att = _paged_gather(at_layer(v_cache), page_table).reshape(
+            B, -1, nkv, hd)
         ks_att = _paged_gather_scale(at_layer(k_scale), page_table) \
             if quantized else None
         vs_att = _paged_gather_scale(at_layer(v_scale), page_table) \

@@ -3,8 +3,8 @@
 Each layer has a MIXER kind (``transformer.MIXER_KINDS``: "full" | "window" |
 "mla" of models/decoding.py, "sparse" | "lightning" of models/minicpm.py,
 "kda" | "latent" of models/ling.py, which also gives "mla" a stack beside
-them, "retention" of models/brumby.py, "gdn" of models/qwen3_next.py, which
-gives "full" a stack beside it) and,
+them, "retention" of models/brumby.py, "gdn" of models/qwen3_next.py and
+"conv" of models/lfm2.py, which each give "full" a stack beside theirs) and,
 independently, an MLP kind ("dense": a SwiGLU or GELU MLP of the layer's own
 width, the leading dense layers of a routed model among them; "routed": an
 expert layer or one member's share of one,
@@ -250,6 +250,11 @@ def _mix(kind: str, cfg, p, x, rows, pools, index, layer_id, cache_len,
 
         return gdn_mixer(cfg, p, x, rows, pools, index, cache_len, num_new,
                          note)
+    if kind == "conv":
+        from .lfm2 import conv_mixer
+
+        return conv_mixer(cfg, p, x, rows, pools, index, cache_len, num_new,
+                          note)
     if kind == "retention":
         from .brumby import retention_mixer
 

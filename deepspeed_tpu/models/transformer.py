@@ -58,9 +58,10 @@ MIXER_KINDS: Dict[str, MixerKind] = {
     # attention over every key of the K / V pool (int8 with its scales) or,
     # with an indexer (``index_topk``), over the best by its keys, which the
     # page keeps beside K and V (``ki``); named by ``mixer_types`` it lies in
-    # the stack models/qwen3_next.py gives it beside its state layers
+    # the stack models/qwen3_next.py or models/lfm2.py gives it beside its
+    # state layers
     "full": MixerKind((), ("k", "v", "k_scale", "v_scale", "ki"), "decoding",
-                      stacked_by=("qwen3_next",)),
+                      stacked_by=("qwen3_next", "lfm2")),
     # attention over the last ``attn_window`` keys; a paged cache keeps the
     # window layers a pool and a page table of their own (``k_win`` ...)
     "window": MixerKind((), ("k", "v", "k_scale", "v_scale"), "decoding"),
@@ -91,6 +92,9 @@ MIXER_KINDS: Dict[str, MixerKind] = {
     # last rows a slot
     "gdn": MixerKind(("state", "conv"), (), "qwen3_next",
                      ("gdn_value_heads", "the heads of its state")),
+    # a gated short convolution as the whole mixer (LFM2): the last rows of
+    # its gated input a slot, no state matrix
+    "conv": MixerKind(("conv",), (), "lfm2"),
 }
 
 # the kinds ``layer_pattern`` may name: those of models/decoding.py that
@@ -225,6 +229,9 @@ class TransformerConfig:
     moe_groups: int = 1
     moe_groups_kept: int = 1
     moe_routed_scale: float = 1.0
+    # what the sigmoid_groups router adds to the sum of the chosen scores it
+    # normalises by (0: the sum itself, guarded from zero)
+    moe_norm_eps: float = 0.0
     # a shared expert of this width beside the routed ones: always on, or
     # times ``sigmoid(x w_sg)`` where the tree carries ``shared_gate``
     moe_shared_width: int = 0
@@ -265,7 +272,9 @@ class TransformerConfig:
     # decay a value head: ``gdn_value_heads`` value heads over
     # ``gdn_key_heads`` key heads of ``gdn_head_dim``, state and convolution
     # rows a slot) beside "full" (gated grouped-query attention over K / V
-    # pages) of models/qwen3_next.py. Each kind has a parameter stack of its
+    # pages) of models/qwen3_next.py; "conv" (a gated short convolution of
+    # ``conv_kernel`` taps, the last rows of its gated input a slot) beside
+    # "full" of models/lfm2.py. Each kind has a parameter stack of its
     # own; the
     # MLP of a layer (dense lead | routed) is independent of its mixer.
     # ``mixer_layer_ids`` gives each layer its index in the published model
@@ -409,7 +418,8 @@ class TransformerConfig:
                 "one model share the module that owns their parameter "
                 "stacks and pools (a kind of models/decoding.py has a stack "
                 "beside the kinds of the modules MIXER_KINDS names for it: "
-                "mla of models/ling.py, full of models/qwen3_next.py)")
+                "mla of models/ling.py, full of models/qwen3_next.py and "
+                "models/lfm2.py)")
         for name in names:
             field, why = MIXER_KINDS[name].needs
             if field and not getattr(self, field):
@@ -1316,8 +1326,8 @@ def _refuse_uncached(cfg: TransformerConfig) -> None:
         raise DeepSpeedConfigError(
             "the uncached forward (training, evaluation, forward) runs no "
             "mixer of mixer_types: a state layer's recurrence (lightning, "
-            "kda, gdn, retention) lives in a slot's state, a sparse layer's "
-            "block "
+            "kda, gdn, retention) and a conv layer's carried rows live in a "
+            "slot's leaves, a sparse layer's block "
             "selection is made from cached compressed keys and a latent "
             "layer attends the latent pool, all in the paged arena alone; "
             "serve this "

@@ -299,7 +299,8 @@ def top_k_gating_indices(
 
 
 def sigmoid_group_gate(logits: jax.Array, sel_bias: jax.Array, top_k: int,
-                       groups: int, groups_kept: int, routed_scale: float):
+                       groups: int, groups_kept: int, routed_scale: float,
+                       norm_eps: float = 0.0):
     """The router of DeepSeek-V3 (``noaux_tc``): ``logits`` [N, E] float32
     -> (expert idx [N, K] int32, weight [N, K] float32).
 
@@ -308,7 +309,8 @@ def sigmoid_group_gate(logits: jax.Array, sel_bias: jax.Array, top_k: int,
     of its two best biased scores, the ``groups_kept`` best groups stay, the
     ``top_k`` best biased scores inside them are chosen (ties to the lower
     index), and the weights are the chosen experts' unbiased scores
-    normalised to one, times ``routed_scale``."""
+    normalised to one (over ``sum + norm_eps`` where a release adds one),
+    times ``routed_scale``."""
     N, E = logits.shape
     scores = jax.nn.sigmoid(logits)
     biased = scores + sel_bias.astype(jnp.float32)[None, :]
@@ -320,7 +322,8 @@ def sigmoid_group_gate(logits: jax.Array, sel_bias: jax.Array, top_k: int,
     masked = jnp.where(jnp.repeat(in_kept, per, axis=1), biased, -jnp.inf)
     _, idx = jax.lax.top_k(masked, top_k)
     w = jnp.take_along_axis(scores, idx, axis=1)
-    w = w / jnp.maximum(jnp.sum(w, axis=1, keepdims=True), 1e-20)
+    total = jnp.sum(w, axis=1, keepdims=True)
+    w = w / (total + norm_eps if norm_eps else jnp.maximum(total, 1e-20))
     return idx.astype(jnp.int32), w * routed_scale
 
 
@@ -356,7 +359,8 @@ def route_dropless(cfg, p: Dict, logits: jax.Array, train: bool = False):
         return sigmoid_gate(logits, cfg.moe_top_k)
     bias = jax.lax.stop_gradient(p["sel_bias"]) if train else p["sel_bias"]
     return sigmoid_group_gate(logits, bias, cfg.moe_top_k, cfg.moe_groups,
-                              cfg.moe_groups_kept, cfg.moe_routed_scale)
+                              cfg.moe_groups_kept, cfg.moe_routed_scale,
+                              cfg.moe_norm_eps)
 
 
 def held_expert_tables(idx, w, valid, first: int, held: int, capacity: int):

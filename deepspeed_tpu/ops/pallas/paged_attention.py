@@ -43,6 +43,20 @@ in SMEM beside page_table ``[B, max_pages]`` and the per-row frontiers (a
 layer sliced out of the stack would be a copy of the pool every call). Row
 ``i`` of slot ``b`` attends ``kpos <= cache_len[b] + i``; the chunk's own
 keys are already in the pool (the caller scatters first).
+
+Heads of 64 (:func:`lane_pairs`): the chip keeps no array whose rows are 64
+wide as it is written (such a pool is stored with its lanes padded to 128,
+twice the bytes, or with another axis innermost, which every call would
+re-lay), so a pool of ``KV`` (even) 64-wide heads is HELD with two heads a
+128-lane row, ``[L, P+1, page_size, KV / 2, 128]``: the bytes of a row-major
+``[page_size, KV, 64]`` page in the same order, and what the kernel is handed
+as a pool of ``KV / 2`` heads of 128. The ``2 G`` query heads of a lane pair
+``(a, b)`` stack a query as one group: a's ``G`` with zeros in lanes 64-127
+over b's ``G`` with zeros in lanes 0-63, so ``Q K^T`` over the 128 lanes is
+each row's own head's and ``P V`` carries both heads' values, of which a row
+keeps its own half. The products are twice the useful ones; the bytes of a
+walk are not, and a page is fetched once a slot. The kernel below is the one
+program at either width.
 """
 
 from __future__ import annotations
@@ -82,6 +96,51 @@ ROW_TILE_ROWS = 256
 # verify window's): a multiple of a bf16 sublane tile, chosen once from the
 # call alone on the v5e at three cells' shapes (PERF.md section 6, PR 63)
 SMALL_ROWS = 32
+
+
+def lane_pairs(hd: int, KV: int) -> int:
+    """How many KV heads of ``hd`` a 128-lane row of the pool holds: 2 where
+    heads of 64 pair (``KV`` even), else 1 (the head is the row)."""
+    return 2 if 2 * hd == LANES and KV % 2 == 0 else 1
+
+
+def kernel_heads(H: int, KV: int, hd: int) -> Tuple[int, int, int]:
+    """(query heads a group, KV heads, head width) as the kernel sees ``H``
+    query heads on ``KV`` heads of ``hd``: a lane pair is one KV head of 128
+    whose group is both heads' queries."""
+    n = lane_pairs(hd, KV)
+    return n * (H // KV), KV // n, n * hd
+
+
+def paired_pool_row(KV: int, hd: int) -> Tuple[int, int]:
+    """The ``[heads, lanes]`` of a token's row in a K / V pool as the chip
+    holds it: ``[KV / 2, 128]`` for heads of 64 that pair, else ``[KV,
+    hd]``."""
+    return kernel_heads(KV, KV, hd)[1:]
+
+
+def _second_of_pair(H: int, KV: int):
+    """[H, 1] bool: query head ``h`` reads KV head ``h // G``, the SECOND of
+    its lane pair where that is odd."""
+    return ((jnp.arange(H) // (H // KV)) % 2 == 1)[:, None]
+
+
+def _pair_queries(q, KV: int):
+    """q [B, S, H, 64] -> [B, S, H, 128]: the queries of the FIRST head of a
+    lane pair in lanes 0-63 (zeros in 64-127), those of the second in lanes
+    64-127."""
+    second = _second_of_pair(q.shape[2], KV)
+    zero = jnp.zeros_like(q)
+    return jnp.concatenate([jnp.where(second, zero, q),
+                            jnp.where(second, q, zero)], axis=-1)
+
+
+def _unpair_outputs(out, KV: int):
+    """[B, S, H, 128] -> [B, S, H, 64]: each query head's own half of its
+    lane pair's values (:func:`_pair_queries`)."""
+    hd = out.shape[3] // 2
+    return jnp.where(_second_of_pair(out.shape[2], KV),
+                     out[..., hd:], out[..., :hd])
 
 
 def _head_tiles(buf, KV: int):
@@ -358,7 +417,18 @@ def paged_attention_kernel(q, k_pool, v_pool, cache_len, page_table, *,
     count of real rows: the loop stops at the last key a real row needs,
     and a slot with none is skipped (its output rows are zeros). ``window``
     (static) bounds row i to ``kpos > cache_len[b] + i - window`` as well;
-    ``name`` is the call's name in a device trace. Returns [B,S,H,hd]."""
+    ``name`` is the call's name in a device trace. Returns [B,S,H,hd].
+    Heads of 64 over a pool held two a row (``[.., KV / 2, 128]``,
+    :func:`lane_pairs`; a ``[.., KV, 64]`` pool is read as one, which only
+    the interpreter does for free) run as ``KV / 2`` heads of 128."""
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    # the model's KV heads, however the pool's rows hold them
+    heads = k_pool.shape[3] * k_pool.shape[4] // q.shape[-1]
+    paired = lane_pairs(q.shape[-1], heads) > 1
+    if paired:
+        row = (*k_pool.shape[:3], *paired_pool_row(heads, q.shape[-1]))
+        k_pool, v_pool = k_pool.reshape(row), v_pool.reshape(row)
+        q = _pair_queries(q, heads)
     B, S, H, hd = q.shape
     ps, KV = k_pool.shape[2], k_pool.shape[3]
     mp = page_table.shape[1]
@@ -403,7 +473,7 @@ def paged_attention_kernel(q, k_pool, v_pool, cache_len, page_table, *,
     )
     out = pl.pallas_call(
         functools.partial(
-            _paged_attention_kernel, scale=1.0 / (hd**0.5), page_size=ps,
+            _paged_attention_kernel, scale=scale, page_size=ps,
             pages_per_block=ppb, group=G, window=window, tiled=tiled,
         ),
         grid_spec=grid_spec,
@@ -416,7 +486,8 @@ def paged_attention_kernel(q, k_pool, v_pool, cache_len, page_table, *,
         name=name or "paged_attention",
     )(pt, cl, nn, jnp.asarray(layer, jnp.int32).reshape(1), qg, k_pool,
       v_pool)
-    return out.reshape(B, KV, S, G, hd).swapaxes(1, 2).reshape(B, S, H, hd)
+    out = out.reshape(B, KV, S, G, hd).swapaxes(1, 2).reshape(B, S, H, hd)
+    return _unpair_outputs(out, heads) if paired else out
 
 
 def paged_attention(q, k_pool, v_pool, cache_len, page_table, *, layer,
@@ -430,7 +501,11 @@ def paged_attention(q, k_pool, v_pool, cache_len, page_table, *, layer,
     from ...models.sharding import current_topology
 
     B, S, H, hd = q.shape
-    ps, KV = k_pool.shape[2], k_pool.shape[3]
+    ps = k_pool.shape[2]
+    # the model's KV heads, however the pool's rows hold them: a pool of
+    # 64-wide heads held two a 128-lane row is [.., KV / 2, 128]
+    held_paired = k_pool.shape[4] != hd
+    KV = k_pool.shape[3] * k_pool.shape[4] // hd
     mp = page_table.shape[1]
     topo = current_topology()
     distributed = topo is not None and topo.world_size > 1
@@ -444,6 +519,15 @@ def paged_attention(q, k_pool, v_pool, cache_len, page_table, *, layer,
     if distributed and (H % tp != 0 or KV % tp != 0):
         reasons.append(f"H={H}/KV={KV} not divisible by tp={tp}")
     kv_local = KV // tp if KV % tp == 0 else KV
+    if held_paired and (
+            paired_pool_row(KV, hd) != k_pool.shape[3:] or kv_local % 2):
+        reasons.append(
+            f"a pool row {list(k_pool.shape[3:])} is not {kv_local} local "
+            f"KV heads of {hd} two a {LANES}-lane row")
+    # what the kernel runs: a lane pair is one KV head of 128
+    G, kv_local, lanes = kernel_heads(
+        H // KV * kv_local, kv_local, hd) if held_paired else (
+        H // KV, kv_local, hd)
     if k_pool.dtype not in (jnp.bfloat16, jnp.float32):
         reasons.append(f"{jnp.dtype(k_pool.dtype).name} KV pool")
     elif k_pool.dtype == jnp.bfloat16 and kv_local > 1 and kv_local % 2:
@@ -454,8 +538,12 @@ def paged_attention(q, k_pool, v_pool, cache_len, page_table, *, layer,
         # rows filling whole sublane tiles (as jax's
         # ragged_paged_attention asks of its combined heads)
         sublanes = kv_local * jnp.dtype(k_pool.dtype).itemsize // 4
-        if hd % LANES != 0:
-            reasons.append(f"head_dim {hd} not {LANES}-aligned")
+        if lanes % LANES != 0:
+            reasons.append(
+                f"head_dim {hd} not {LANES}-aligned" + (
+                    f" ({KV} KV heads a row: an even number of 64-wide "
+                    f"heads is taken where the pool holds two a {LANES}-lane "
+                    "row)" if hd < LANES else ""))
         if sublanes not in (1, 2, 4) and (sublanes == 0 or sublanes % 8):
             reasons.append(
                 f"{kv_local} local KV heads in "
@@ -471,12 +559,12 @@ def paged_attention(q, k_pool, v_pool, cache_len, page_table, *, layer,
             f"{SMEM_TABLE_BYTES >> 10} KiB of SMEM it may take"
         )
     if not reasons:
-        shape = (H // KV, KV // tp, hd, ps,
+        shape = (G, kv_local, lanes, ps,
                  _block_pages(DEFAULT_BLOCK_K, ps, mp),
                  jnp.dtype(q.dtype).itemsize, jnp.dtype(k_pool.dtype).itemsize)
         if row_tile(S, *shape) is None:
             reasons.append(
-                f"a [{S} x {H // KV}]-row chunk of {KV // tp} KV heads needs "
+                f"a [{S} x {G}]-row chunk of {kv_local} KV heads needs "
                 f"{_vmem_bytes(S, *shape) >> 20} MiB of VMEM (budget "
                 f"{VMEM_BUDGET_BYTES >> 20} MiB) and no tile of 8 or more of "
                 f"its rows (at most {ROW_TILE_ROWS} stacked) fits either"

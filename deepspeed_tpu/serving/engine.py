@@ -682,6 +682,16 @@ def _state_counts(prefix: str, by_path: bool = False):
     return count
 
 
+def _conv_counts(engine, cl, nn) -> Dict[str, int]:
+    """A convolution layer (its cache the rows a slot carries):
+    :func:`_state_counts`' ``conv_rows``, ``conv_state_slots`` and
+    ``state_resets``, and ``decode_slots`` the slots with exactly one real
+    row (a model of this kind runs many slots a step: how many of them
+    decode beside the prompt chunks)."""
+    return {**_state_counts("conv_")(engine, cl, nn),
+            "decode_slots": int((nn == 1).sum())}
+
+
 def _sparse_counts(engine, cl, nn) -> Dict[str, int]:
     """A sparse layer: ``context_keys`` the cached tokens at or before every
     real query token; ``attended_sparse`` those a query attends, all of them
@@ -807,10 +817,13 @@ def _page_counts(engine, cl, nn, kind: str = "full") -> Dict[str, int]:
         cl, nn, ps, mp, cfg.window_of(kind), block_k=ps)
     small = 0
     if engine.metrics.attention_paged_kernel_kinds.get(kind):
-        G = cfg.num_heads // cfg.kv_heads
+        # (the kernel's own heads: a lane pair of 64-wide heads is one)
+        G, KV, hd = pa.kernel_heads(
+            cfg.num_heads // engine.topology.tp_size,
+            cfg.kv_heads // engine.topology.tp_size, cfg.hd)
         small = pa.small_tile_slots(nn, G, engine.token_budget, pa.row_tile(
-            engine.token_budget, G, cfg.kv_heads // engine.topology.tp_size,
-            cfg.hd, ps, pa._block_pages(pa.DEFAULT_BLOCK_K, ps, mp),
+            engine.token_budget, G, KV,
+            hd, ps, pa._block_pages(pa.DEFAULT_BLOCK_K, ps, mp),
             jnp.dtype(engine.dtype).itemsize,
             jnp.dtype(engine.engine.kv_cache_storage_dtype).itemsize))
     engine.metrics.on_keys(kind, attended, fetched, small)
@@ -824,6 +837,7 @@ def _page_counts(engine, cl, nn, kind: str = "full") -> Dict[str, int]:
 _KIND_COUNTS = {
     "full": _page_counts,
     "gdn": _state_counts("gdn_"),
+    "conv": _conv_counts,
     "sparse": _sparse_counts,
     "lightning": _state_counts(""),
     "kda": _state_counts("kda_", by_path=True),
@@ -1200,11 +1214,14 @@ class ServingEngine:
                 # a path a mixer kind: "block_sparse_kernel" / "dense" for
                 # sparse layers, "lightning_kernel", "kda_kernel",
                 # "latent_kernel", "gdn_kernel" and "retention_kernel"
-                # likewise, "paged_kernel" / "dense" for full layers;
-                # attention_path is the sparse
-                # layers' (the last layer kind's where there is none)
+                # likewise, "paged_kernel" / "dense" for full layers,
+                # "short_conv" (plain lines on either backend) for conv
+                # layers; attention_path is the sparse layers', else the
+                # full layers' (the last layer kind's where there is
+                # neither)
                 self.attention_paths = dict(rec["kinds"])
-                self.attention_path = rec["kinds"].get("sparse", rec["path"])
+                self.attention_path = rec["kinds"].get(
+                    "sparse", rec["kinds"].get("full", rec["path"]))
             self.metrics.attention_paged_kernel = float(
                 self.attention_path in ("paged_kernel", "paged_sparse_kernel",
                                         "latent_sparse_kernel",
@@ -1845,6 +1862,15 @@ class ServingEngine:
             "state_leaves": {
                 name: int(np.prod(leaf.shape)) * leaf.dtype.itemsize
                 for name, leaf in leaves.items()},
+            # what ONE slot keeps of them (a conv layer's carried rows, a
+            # state layer's state)
+            "state_bytes_per_slot": state_bytes(mcfg, 1, jnp.dtype(
+                self.engine.kv_cache_storage_dtype).itemsize),
+            # KV heads a 128-lane row of the K / V pools (2: heads of 64
+            # held in pairs, which the paged kernel reads as one head)
+            "kv_heads_per_pool_row": (
+                mcfg.kv_heads // self._caches["k"].shape[3]
+                if self.paged and "k" in self._caches else 1),
         }
 
     def _stage_args(self, plan: StepPlan) -> tuple:

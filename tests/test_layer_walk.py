@@ -21,8 +21,8 @@ import deepspeed_tpu
 import deepspeed_tpu.serving.engine as engine_mod
 from deepspeed_tpu.comm.topology import MeshTopology, ParallelDims
 from deepspeed_tpu.config import DeepSpeedConfig, DeepSpeedConfigError
-from deepspeed_tpu.models import (brumby, cohere, deepseek, glm5, keye, ling,
-                                  mellum, minicpm, mixtral, qwen3_next)
+from deepspeed_tpu.models import (brumby, cohere, deepseek, glm5, keye, lfm2,
+                                  ling, mellum, minicpm, mixtral, qwen3_next)
 from deepspeed_tpu.models.decoding import (CACHE_ADMITS, WIN, cache_layout,
                                            forward_with_cache, init_cache,
                                            init_paged_cache)
@@ -68,9 +68,15 @@ FAMILIES = {
                                       moe_routed_experts=8),
                    [("gdn_layers", 3, (("gdn", R),)),
                     ("attn_layers", 1, (("full", R),))] * 2),
+    "lfm2": (lambda: lfm2("lfm2-tiny"),
+             [("conv_layers", 2, (("conv", D),)),
+              ("attn_layers", 1, (("full", R),)),
+              ("conv_layers", 3, (("conv", R),)),
+              ("attn_layers", 1, (("full", R),)),
+              ("conv_layers", 1, (("conv", R),))]),
 }
 ROUTED = ("mixtral", "mellum", "deepseek", "ling", "glm5", "cohere", "keye",
-          "qwen3_next")
+          "qwen3_next", "lfm2")
 
 
 @pytest.fixture(scope="module", params=list(FAMILIES))
@@ -217,7 +223,7 @@ REFUSED = {
     "deepseek": ("paged false", "int8", "host_pages",
                  "fleet.prefill_replicas"),
     "keye": OPS, "minicpm_sala": OPS, "ling": OPS, "brumby": OPS, "glm5": OPS,
-    "qwen3_next": OPS,
+    "qwen3_next": OPS, "lfm2": OPS,
 }
 ASKED = {"paged false": dict(paged=False), "host_pages": dict(host_pages=8),
          "fleet.prefill_replicas": dict(fleet=dict(prefill_replicas=1)),

@@ -347,6 +347,73 @@ def test_paged_attention_compiles_at_256_wide_heads(one_chip, S):
     assert _kernel_products(step, *shapes) == 2 * 2 * (1 if rows < S else 2)
 
 
+def test_paged_attention_compiles_at_two_64_wide_heads_a_lane_row(one_chip):
+    """LFM2-8B-A1B's call (32 query heads on 8 KV heads of 64, 64 slots of
+    8,192 tokens (132 pages a slot with the chunk in flight) under [64, 256],
+    pages of 64): the pool is held two KV heads
+    a 128-lane row, [.., 4, 128] (the bytes of a row-major [64, 8, 64] page
+    in the same order: the chip keeps no 64-lane row unpadded), and the
+    kernel runs 4 KV heads of 128 whose group is both heads' 8 queries: one
+    program a slot with the small tile for a decoding slot, the [64, 132]
+    page table 33 KiB of SMEM. A pool held a head a row is refused on the
+    chip with its reason, and so is a lone 64-wide KV head."""
+    B, S, H, KV, hd, ps, per_slot = 64, 256, 32, 8, 64, 64, 132
+    assert pa.lane_pairs(hd, KV) == 2 and pa.lane_pairs(hd, 1) == 1
+    assert pa.kernel_heads(H, KV, hd) == (8, 4, 128)
+    assert pa.paired_pool_row(KV, hd) == (4, 128)
+    assert pa.row_tile(S, 8, 4, 128, ps, 8, 2, 2) == S
+    assert pa.small_tile_slots([1, 0, 200, 4, 5], 8, S, S) == 2
+
+    def step(q, k, v, cl, nn, pt, layer):
+        out, why = pa.paged_attention(
+            q, k, v, cl, pt, layer=layer, num_new=nn, interpret=False,
+            name="paged_attention_full")
+        assert out is not None, why
+        return out
+
+    shapes = (
+        ((B, S, H, hd), BF16), ((3, 8193, ps, 4, 128), BF16),
+        ((3, 8193, ps, 4, 128), BF16), ((B,), I32), ((B,), I32),
+        ((B, per_slot), I32), ((), I32),
+    )
+    text = _compile(step, one_chip, *shapes)
+    assert "tpu_custom_call" in text and "paged_attention_full" in text
+    # the pools reach the call as they are held: no copy of one's size
+    assert not re.search(r"bf16\[3,8193,64,4,128\]\S* copy\(", text)
+    assert _kernel_products(step, *shapes) == 2 * 2 * 2
+    q, pt = jnp.zeros((2, 16, H, hd), BF16), jnp.zeros((2, 4), I32)
+    for pool, said in (((1, 5, ps, KV, hd), "two a 128-lane row"),
+                       ((1, 5, ps, 1, hd), "not 128-aligned")):
+        out, why = pa.paged_attention(
+            q[:, :, :H * pool[3] // KV], jnp.zeros(pool, BF16),
+            jnp.zeros(pool, BF16), jnp.zeros(2, I32), pt, layer=0,
+            interpret=False)
+        assert out is None and said in " ".join(why), why
+
+
+def test_a_128_wide_pools_call_is_lowered_as_before():
+    """The lane pairing is the wrapper's alone: at heads of 128 and 256 the
+    traced call (its jaxpr, kernel body included) has no ``select_n`` or
+    ``concatenate`` of a query stack round it and the same grid, scratches
+    and products as ever (Mixtral's and Qwen3-Next's shapes)."""
+    def step(q, k, v, cl, nn, pt, layer):
+        return pa.paged_attention_kernel(
+            q, k, v, cl, pt, layer=layer, num_new=nn, interpret=False)
+
+    for H, KV, hd, ps in ((32, 8, 128, 16), (16, 2, 256, 64)):
+        args = [jax.ShapeDtypeStruct(s, d) for s, d in (
+            ((4, 128, H, hd), BF16), ((3, 65, ps, KV, hd), BF16),
+            ((3, 65, ps, KV, hd), BF16), ((4,), I32), ((4,), I32),
+            ((4, 16), I32), ((), I32))]
+        jaxpr = jax.make_jaxpr(step)(*args).jaxpr
+        outside = [e.primitive.name for e in jaxpr.eqns]
+        assert "select_n" not in outside and "concatenate" not in outside
+        call = next(e for e in jaxpr.eqns if e.primitive.name == "pallas_call")
+        assert call.invars[4].aval.shape == (4, KV, 128 * H // KV, hd)
+        assert [v.aval.shape for v in call.invars[5:7]] == [
+            (3, 65, ps, KV, hd)] * 2
+
+
 @pytest.mark.parametrize("cell", ["keye", "deepseek", "glm5", "minicpm"])
 def test_the_selected_walks_compile_at_their_cells_shapes(one_chip, cell):
     """The three walks that fold their key tiles through
@@ -1004,6 +1071,53 @@ def test_qwen3_next_slot_step_keeps_pools_and_both_state_leaves_in_place(
     _check_pool_writes_take_the_budget(
         compiled, caches, ("k", "v"), N, W, "qwen3-next", capsys)
     _check_weights_are_read_as_held(compiled, model, "qwen3-next", capsys)
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.0 * GIB
+
+
+def test_lfm2_slot_step_keeps_its_pools_and_carried_rows_in_place(
+        one_chip, monkeypatch, capsys):
+    """The one [64, 256] serving step of LFM2-8B-A1B at its published widths
+    and the benchmark's cut (published layers 0-13: two leading dense layers
+    and three periods A c c c; all 32 experts, the whole vocabulary, the head
+    tied) over its arena of 8,192 pages of 64 tokens: the three attention
+    layers' K and V pools (two 64-wide KV heads a 128-lane row) ride the
+    layer scans beside the eleven convolutions' carried rows, the compiled
+    step holds no copy of a pool's size, the paged call is in it by name
+    (32 rows an expert at a full step: the banks go through the einsum);
+    and it fits the described chip beside 9.33 GB of weights."""
+    from deepspeed_tpu.models import lfm2
+    from deepspeed_tpu.models.decoding import init_paged_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = lfm2("lfm2-8b-a1b", layer_ids=list(range(14)), max_seq_len=8192)
+    cfg = model.config
+    assert cfg.kind_count("conv") == 11 and cfg.kind_count("full") == 3
+    assert (cfg.lead_dense_layers, cfg.num_layers) == (2, 12)
+    assert model.num_params() == 4_667_077_376  # 9.33 GB in bf16
+    N, W, ps, cap = 64, 256, 64, 8192 + 256  # a slot's table: 132 pages
+    caches = jax.eval_shape(
+        lambda: init_paged_cache(cfg, 8192, ps, BF16, max_slots=N))
+    assert {k: (v.shape[2:], v.dtype) for k, v in caches.items()} == {
+        "k": ((ps, 4, 128), BF16), "v": ((ps, 4, 128), BF16),
+        "conv": ((2, 2048), BF16)}
+    compiled = _compile_slot_step(model, caches, one_chip, N, W, cap // ps)
+    m = compiled.memory_analysis()
+    pools = sum(a.size * a.dtype.itemsize for a in caches.values())
+    with capsys.disabled():
+        print(f"\nlfm2 slot step, described v5e: "
+              f"{model.num_params():,} parameters, arguments "
+              f"{m.argument_size_in_bytes / GIB:.2f} GiB, temporaries "
+              f"{m.temp_size_in_bytes / GIB:.2f} GiB, aliased "
+              f"{m.alias_size_in_bytes / GIB:.2f} (the pools and leaves "
+              f"{pools / GIB:.2f})")
+    text = compiled.as_text()
+    assert "paged_attention_full" in text and "expert_bank" not in text
+    assert _pool_copies(
+        text, {k: v for k, v in caches.items() if k != "conv"}) == []
+    assert m.alias_size_in_bytes >= pools
+    _check_pool_writes_take_the_budget(
+        compiled, caches, ("k", "v"), N, W, "lfm2", capsys)
+    _check_weights_are_read_as_held(compiled, model, "lfm2", capsys)
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.0 * GIB
 
 
